@@ -31,9 +31,10 @@ lint-ci:
 		--format=sarif --stats > fslint.sarif
 	$(PY) -m fengshen_tpu.analysis --format=github
 
-# offline serving-throughput microbench (docs/serving.md): continuous
-# batching vs sequential per-request decode, one JSON line on CPU so
-# BENCH rounds can track serving throughput without a healthy relay
+# offline serving microbench (docs/serving.md): continuous batching vs
+# sequential per-request decode, one JSON line. Pinned to the CPU: it
+# checks the harness and the scheduler's counts, its rates say nothing
+# about the chip
 serve-bench:
 	JAX_PLATFORMS=cpu $(PY) -m fengshen_tpu.serving.bench
 
@@ -106,7 +107,7 @@ serve-bench-evac:
 serve-fleet:
 	@test -n "$(CONFIG)" || \
 		{ echo "usage: make serve-fleet CONFIG=<api config json> [N=3] [PORT=8080]"; exit 2; }
-	JAX_PLATFORMS=$${JAX_PLATFORMS:-cpu} $(PY) -m fengshen_tpu.fleet \
+	$(PY) -m fengshen_tpu.fleet \
 		--spawn $(or $(N),3) --config $(CONFIG) \
 		--port $(or $(PORT),8080)
 
@@ -135,10 +136,9 @@ kernel-bench:
 		$(PY) -m fengshen_tpu.ops.pallas.bench
 
 # bench trajectory comparator (docs/observability.md "benchdiff"):
-# classifies each BENCH_r*.json round (ok / wedged / failed), diffs
+# classifies each BENCH_r*.json round in --dir (ok / failed), diffs
 # every metric against the previous round carrying it (and
-# BASELINE.json's published table), and prints a deterministic
-# verdict — every future bench round lands with a trajectory readout
+# BASELINE.json's published table), and prints a deterministic verdict
 benchdiff:
 	$(PY) -m fengshen_tpu.observability.benchdiff
 

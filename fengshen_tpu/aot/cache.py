@@ -52,7 +52,15 @@ from fengshen_tpu.observability import MetricsRegistry, get_registry, span
 
 #: bump when the on-disk blob layout changes — older blobs become load
 #: errors (counted + recompiled), never crashes
-BLOB_VERSION = 1
+BLOB_VERSION = 2
+
+
+def _devices_of(compiled) -> tuple:
+    """The devices a compiled program is assigned to, in order."""
+    leaves = jax.tree_util.tree_leaves(
+        (compiled.input_shardings, compiled.output_shardings))
+    return leaves[0]._device_assignment if leaves else \
+        (jax.devices()[0],)
 
 #: file suffix for cache entries ("<name>__<key>.aotx")
 BLOB_SUFFIX = ".aotx"
@@ -302,18 +310,21 @@ class ExecutableCache:
                         f"running {jax.__version__}")
                 from jax.experimental.serialize_executable import \
                     deserialize_and_load
+                by_id = {d.id: d for d in jax.devices()}
+                devices = [by_id[i] for i in blob["device_ids"]]
                 if blob.get("tree_mode") == "flat":
                     if out_tree is None:
                         return None
                     in_surr, out_surr = _flat_treedefs(blob["n_in"],
                                                        blob["n_out"])
                     exe = _FlatCall(
-                        deserialize_and_load(blob["payload"], in_surr,
-                                             out_surr), out_tree)
+                        deserialize_and_load(
+                            blob["payload"], in_surr, out_surr,
+                            execution_devices=devices), out_tree)
                 else:
                     exe = deserialize_and_load(
                         blob["payload"], blob["in_tree"],
-                        blob["out_tree"])
+                        blob["out_tree"], execution_devices=devices)
             # touch: LRU recency for the size-cap purge
             try:
                 os.utime(path, None)
@@ -346,7 +357,13 @@ class ExecutableCache:
                 payload, in_tree, out_tree = serialize(compiled)
                 header = {"version": BLOB_VERSION,
                           "jax": jax.__version__,
-                          "name": name, "key": key, "payload": payload}
+                          "name": name, "key": key, "payload": payload,
+                          # the devices the program runs on, in
+                          # assignment order: deserialize_and_load
+                          # otherwise assumes every device of the
+                          # backend and wants one shard per device
+                          "device_ids": [d.id for d in _devices_of(
+                              compiled)]}
                 try:
                     blob = pickle.dumps({**header, "in_tree": in_tree,
                                          "out_tree": out_tree})

@@ -67,6 +67,7 @@ import argparse
 import dataclasses
 import importlib
 import json
+import threading
 import time
 from typing import Any, Optional
 
@@ -139,16 +140,35 @@ def load_config(path: str) -> tuple[ServerConfig, PipelineConfig]:
     return server, pipeline
 
 
+class Readiness(threading.Event):
+    """The warmup gate `/healthz` reads: set once the replica is warm
+    and its serve loop runs. A warmup that raised never sets it — a
+    program that did not compile must not turn `/healthz` green — and
+    leaves the exception text in `error`, which every 503 body then
+    carries. `settled` fires either way, so a caller can wait for the
+    outcome without polling."""
+
+    def __init__(self):
+        super().__init__()
+        self.error: Optional[str] = None
+        self.settled = threading.Event()
+
+
 def _healthz_payload(task: str, ready, draining) -> tuple[int, dict]:
     """The readiness contract BOTH server paths answer (pinned by
-    tests): 503 with `{"ready": false, "reason": "warmup"|"draining"}`
-    while the replica must not receive traffic, 200 with
-    `{"ready": true}` otherwise. The legacy `status` key stays for
+    tests): 503 with `{"ready": false, "reason": "warmup"|"draining"|
+    "warmup_failed"}` while the replica must not receive traffic, 200
+    with `{"ready": true}` otherwise. The legacy `status` key stays for
     pre-fleet monitors; the fleet router keys on `reason`."""
     if draining is not None and draining.is_set():
         return 503, {"status": "draining", "task": task,
                      "ready": False, "reason": "draining"}
     if ready is not None and not ready.is_set():
+        error = getattr(ready, "error", None)
+        if error is not None:
+            return 503, {"status": "failed", "task": task,
+                         "ready": False, "reason": "warmup_failed",
+                         "error": error}
         return 503, {"status": "warming", "task": task,
                      "ready": False, "reason": "warmup"}
     return 200, {"status": "ok", "task": task, "ready": True}
@@ -257,18 +277,14 @@ def _accepts_max_new_tokens(pipeline) -> bool:
         return False
 
 
-def warmup_pipeline(pipeline, task: str) -> Optional[float]:
+def warmup_pipeline(pipeline, task: str) -> float:
     """Issue one warmup request through the legacy path so the first
-    user request doesn't pay jit compilation; returns seconds (None on
-    failure — a broken warmup must not keep the server down)."""
+    user request doesn't pay jit compilation; returns seconds. A
+    pipeline that cannot answer its warmup request cannot answer a
+    user's either, so the failure propagates."""
     from fengshen_tpu.observability import record_warmup_seconds
     t0 = time.perf_counter()
-    try:
-        pipeline("warmup")
-    except Exception as e:  # noqa: BLE001 — warmup is best-effort
-        print(f"[serving] warmup request failed ({e}); first real "
-              "request will compile", flush=True)
-        return None
+    pipeline("warmup")
     dt = time.perf_counter() - t0
     record_warmup_seconds("pipeline", dt)
     print(f"[serving] warmup request for '{task}' compiled+ran in "
@@ -285,8 +301,10 @@ def create_continuous_engine(pipeline, engine_args: dict,
     (docs/aot_cache.md). `recorder` is an optional
     `observability.FlightRecorder` the engine feeds its event stream
     into and dumps through on tick errors."""
+    from fengshen_tpu.compile_cache import ensure_compile_cache
     from fengshen_tpu.serving import (ContinuousBatchingEngine,
                                       EngineConfig)
+    ensure_compile_cache()
     if not hasattr(pipeline, "engine_config_kwargs"):
         raise ValueError(
             "engine 'continuous' needs a generation pipeline exposing "
@@ -1174,14 +1192,18 @@ def install_drain_handler(server, draining, engine=None, recorder=None,
 
 def _start_warmup_thread(server_cfg: ServerConfig,
                          pipeline_cfg: PipelineConfig, pipeline,
-                         engine):
+                         engine) -> Readiness:
     """Warm up in the background while the server is already listening
     (docs/aot_cache.md "cold start"): /healthz answers 503 until the
-    returned event is set, then 200 — the load-balancer readiness
-    contract. With an AOT cache the warmup is mostly deserialization
-    and the 503 window shrinks to near zero."""
-    import threading
-    ready = threading.Event()
+    returned gate is set, then 200 — the load-balancer readiness
+    contract. With a warm compile cache the warmup is mostly
+    deserialization and the 503 window shrinks to near zero.
+
+    A warmup that raises leaves the gate shut for good: the engine's
+    serve loop is not started, /healthz keeps answering 503 with the
+    error, and `main()` exits non-zero. Serving on would mean every
+    request re-raises the same compile failure one at a time."""
+    ready = Readiness()
 
     def _warm():
         from fengshen_tpu.observability import record_build_info
@@ -1202,23 +1224,35 @@ def _start_warmup_thread(server_cfg: ServerConfig,
                       f"{dt:.1f}s", flush=True)
             elif server_cfg.warmup:
                 warmup_pipeline(pipeline, pipeline_cfg.task)
-        except Exception as e:  # noqa: BLE001 — warmup is best-effort;
-            # requests compile lazily (or surface the same error as a
-            # response) once the loop below starts
-            print(f"[serving] warmup failed ({e}); serving anyway — "
-                  "first requests will compile", flush=True)
-        finally:
-            # a failed warmup still opens the gate AND starts the serve
-            # loop: requests then compile lazily (or fail loudly) — a
-            # replica that reports ready while no loop drains its queue
-            # would hang every request to its full timeout instead
             if engine is not None:
                 engine.start()
             ready.set()
+        except Exception as e:  # noqa: BLE001 — the thread is the
+            # boundary: the failure is reported through the gate
+            import traceback
+            traceback.print_exc()
+            ready.error = f"{type(e).__name__}: {str(e)[:500]}"
+            print(f"[serving] warmup failed ({ready.error}); /healthz "
+                  "stays 503", flush=True)
+        finally:
+            ready.settled.set()
 
     threading.Thread(target=_warm, daemon=True,
                      name="fstpu-warmup").start()
     return ready
+
+
+def _stop_on_failed_warmup(ready: Readiness, stop) -> None:
+    """Once the warmup settles, call `stop()` if it failed — what turns
+    a compile failure into a process exit instead of a replica that
+    answers 503 forever."""
+    def _watch():
+        ready.settled.wait()
+        if ready.error is not None:
+            stop()
+
+    threading.Thread(target=_watch, daemon=True,
+                     name="fstpu-warmup-watch").start()
 
 
 def main(argv=None) -> None:
@@ -1259,7 +1293,6 @@ def main(argv=None) -> None:
     ready = _start_warmup_thread(server_cfg, pipeline_cfg, pipeline,
                                  engine)
     import os
-    import threading
     draining = threading.Event()
     # FSTPU_PEERS=http://host:port,... names the sibling replicas this
     # one may evacuate live lanes to on drain (the fleet launcher sets
@@ -1300,13 +1333,20 @@ def main(argv=None) -> None:
             "fastapi/uvicorn not installed"
         print(f"{why} — stdlib server on "
               f"{server_cfg.host}:{server_cfg.port}", flush=True)
+        _stop_on_failed_warmup(ready, server.shutdown)
         server.serve_forever()
         server.server_close()
         if engine is not None:
             engine.stop()
+        if ready.error is not None:
+            raise SystemExit(f"warmup failed: {ready.error}")
         return
-    uvicorn.run(app, host=server_cfg.host, port=server_cfg.port,
-                log_level=server_cfg.log_level)
+    uv_server = uvicorn.Server(uvicorn.Config(
+        app, host=server_cfg.host, port=server_cfg.port,
+        log_level=server_cfg.log_level))
+    _stop_on_failed_warmup(
+        ready, lambda: setattr(uv_server, "should_exit", True))
+    uv_server.run()
     # uvicorn installs its OWN signal handlers (replacing the chained
     # SIGTERM dump above) and returns here after its graceful
     # shutdown — dump on the way out so a drained uvicorn replica
@@ -1316,6 +1356,8 @@ def main(argv=None) -> None:
     except Exception:  # noqa: BLE001 — never fail process exit on
         # telemetry
         pass
+    if ready.error is not None:
+        raise SystemExit(f"warmup failed: {ready.error}")
 
 
 if __name__ == "__main__":

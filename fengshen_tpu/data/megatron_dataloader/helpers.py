@@ -2,8 +2,10 @@
 
 Replaces the reference's pybind11 `helpers` module and its on-demand build
 (reference: fengshen/data/megatron_dataloader/dataset_utils.py:77-88
-`compile_helper`). If the shared object is missing we build it with make;
-if that fails (no toolchain), pure-numpy fallbacks keep everything working.
+`compile_helper`). The shared object is never checked in: on first use
+it is built from `native/index_helpers.cpp` with make. Where that fails
+(no toolchain) the pure-numpy builders take over — same results, slower
+— and a log line says so.
 """
 
 from __future__ import annotations
@@ -11,6 +13,7 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
+import sys
 from typing import Optional
 
 import numpy as np
@@ -23,16 +26,17 @@ _lib: Optional[ctypes.CDLL] = None
 _lib_tried = False
 
 
-def compile_helper() -> bool:
-    """Build the shared object (reference: dataset_utils.py:77-88)."""
+def compile_helper() -> Optional[str]:
+    """Build the shared object (reference: dataset_utils.py:77-88).
+    Returns None on success, else why it could not be built."""
     try:
         subprocess.run(["make", "-C", _NATIVE_DIR], check=True,
-                       capture_output=True)
-        return True
-    except (OSError, subprocess.SubprocessError):
-        # make missing (OSError) or the build failed (CalledProcessError)
-        # — caller falls back to the pure-python index builders
-        return False
+                       capture_output=True, text=True)
+    except OSError as e:                       # no make on this machine
+        return f"{type(e).__name__}: {e}"
+    except subprocess.CalledProcessError as e:  # no compiler / bad build
+        return f"make failed: {(e.stderr or '').strip()[-300:]}"
+    return None
 
 
 def _get_lib() -> Optional[ctypes.CDLL]:
@@ -40,12 +44,16 @@ def _get_lib() -> Optional[ctypes.CDLL]:
     if _lib is not None or _lib_tried:
         return _lib
     _lib_tried = True
-    if not os.path.exists(_LIB_PATH):
-        if not compile_helper():
-            return None
-    try:
-        lib = ctypes.CDLL(_LIB_PATH)
-    except OSError:
+    why = None if os.path.exists(_LIB_PATH) else compile_helper()
+    if why is None:
+        try:
+            lib = ctypes.CDLL(_LIB_PATH)
+        except OSError as e:
+            why = f"{type(e).__name__}: {e}"
+    if why is not None:
+        print("[fengshen-tpu] native index helpers unavailable "
+              f"({why}); the numpy index builders are in use",
+              file=sys.stderr, flush=True)
         return None
     i32p = ctypes.POINTER(ctypes.c_int32)
     i64p = ctypes.POINTER(ctypes.c_int64)

@@ -64,6 +64,7 @@ from typing import List, Tuple
 from fengshen_tpu.fleet.bench import (_IntTokenizer, _buckets, _drive,
                                       _emit, _fake_result,
                                       _make_router)
+from fengshen_tpu.fleet.launcher import replica_backend, replica_env
 
 
 def _env(name: str, default: int) -> int:
@@ -331,9 +332,12 @@ def _spawn_real_replicas(phases: List[str], base_port: int
     procs, targets = [], []
     for i, phase in enumerate(phases):
         port = base_port + i
+        # chips are numbered like the ports: the homogeneous and the
+        # disaggregated fleet are alive together and share no chip
         procs.append(subprocess.Popen(
             [sys.executable, "-m", "fengshen_tpu.disagg.bench",
-             "--replica", "--port", str(port), "--phase", phase]))
+             "--replica", "--port", str(port), "--phase", phase],
+            env=replica_env(port - _env("BASE_PORT", 8260))))
         targets.append(f"127.0.0.1:{port}")
     return targets, procs
 
@@ -439,11 +443,7 @@ def main(argv=None) -> None:
 
         tps_h = homog["tokens_per_sec"]
         tps_d = disagg["tokens_per_sec"]
-        if fake:
-            backend = "fake"
-        else:
-            import jax
-            backend = jax.default_backend()
+        backend = "fake" if fake else replica_backend(h_targets[0])
         _emit({
             "metric": "disagg_tokens_per_sec",
             "value": round(tps_d, 1),

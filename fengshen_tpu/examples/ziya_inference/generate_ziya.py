@@ -1,8 +1,8 @@
 """Ziya-LLaMA inference demo.
 
 Port of reference: fengshen/examples/ziya_inference/ (HF generation demo;
-the reference also ships 8-bit/llama.cpp variants — quantized serving is a
-round-2 item, see NOTES.md). Loads an HF llama checkpoint, applies the
+the reference also ships 8-bit/llama.cpp variants — see
+generate_ziya_int8.py). Loads an HF llama checkpoint, applies the
 "<human>:/<bot>:" chat format, and generates with sampling.
 
     python -m fengshen_tpu.examples.ziya_inference.generate_ziya \

@@ -49,6 +49,7 @@ import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, List, Optional, Tuple
 
+from fengshen_tpu.fleet.launcher import replica_backend, replica_env
 from fengshen_tpu.fleet.router import FleetConfig, FleetRouter
 
 
@@ -214,7 +215,7 @@ def _spawn_real_replicas(n: int, base_port: int
         port = base_port + i
         procs.append(subprocess.Popen(
             [sys.executable, "-m", "fengshen_tpu.fleet.bench",
-             "--replica", "--port", str(port)]))
+             "--replica", "--port", str(port)], env=replica_env(i)))
         targets.append(f"127.0.0.1:{port}")
     return targets, procs
 
@@ -369,11 +370,7 @@ def main(argv=None) -> None:
 
         tps1 = single["tokens_per_sec"]
         tpsn = full["tokens_per_sec"]
-        if fake:
-            backend = "fake"
-        else:
-            import jax
-            backend = jax.default_backend()
+        backend = "fake" if fake else replica_backend(targets[0])
         _emit({
             "metric": "fleet_router_tokens_per_sec",
             "value": round(tpsn, 1),

@@ -62,6 +62,7 @@ from typing import List, Optional
 
 from fengshen_tpu.fleet.bench import (_buckets, _drive, _emit,
                                       _IntTokenizer, _make_router)
+from fengshen_tpu.fleet.launcher import replica_backend, replica_env
 
 
 def _env(name: str, default: int) -> int:
@@ -427,12 +428,12 @@ def _spawn_fleet(base_port: int) -> tuple:
     ports = [base_port, base_port + 1, base_port + 2]
     peers = [f"http://127.0.0.1:{ports[1]}", "", ""]
     procs = []
-    for port, peer in zip(ports, peers):
+    for i, (port, peer) in enumerate(zip(ports, peers)):
         cmd = [sys.executable, "-m", "fengshen_tpu.fleet.evac_bench",
                "--replica", "--port", str(port)]
         if peer:
             cmd += ["--peers", peer]
-        procs.append(subprocess.Popen(cmd))
+        procs.append(subprocess.Popen(cmd, env=replica_env(i)))
     targets = [f"127.0.0.1:{p}" for p in ports]
     return targets, procs
 
@@ -497,6 +498,8 @@ def main(argv=None) -> None:
                         for _ in range(prompt_len))
                for _ in range(n_req)]
 
+    backends: set = set()
+
     def fresh_fleet(rung):
         """(router_targets, drain_a, kill_b, counters, cleanup)."""
         if fake:
@@ -519,6 +522,7 @@ def main(argv=None) -> None:
         targets, procs = _spawn_fleet(_env("BASE_PORT", 8470))
         for t in targets:
             _wait_healthy(t)
+        backends.add(replica_backend(targets[0]))
         return ([targets[0], targets[2]],
                 lambda: procs[0].send_signal(signal.SIGTERM),
                 lambda: procs[1].kill(), None, lambda: _reap(procs))
@@ -576,11 +580,7 @@ def main(argv=None) -> None:
     hard_resume = sections["sigkill"]["resume"]
     resumed = int(hard_resume.get("resumed", 0))
     resumed_tokens = int(sections["sigkill"]["resume_tokens"])
-    if fake:
-        backend = "fake"
-    else:
-        import jax
-        backend = jax.default_backend()
+    backend = "fake" if fake else ",".join(sorted(backends))
     # recovered-request overhead vs regenerate-from-zero: the share of
     # a recovered request's tokens that had to be decoded AGAIN — 1.0
     # would mean the journal saved nothing, < 1.0 is the win

@@ -9,19 +9,56 @@ router then fronts them; its health gating keeps traffic off each
 replica until its warmup 503 window closes, and its drain handler
 SIGTERMs the children (each drains gracefully, docs/fleet.md "Drain
 runbook") once the router itself has drained.
+
+One replica, one chip (docs/fleet.md "Replicas and chips"): a TPU chip
+belongs to one process and a jax process claims every chip it can see,
+so replica i is started seeing chip i and only that chip
+(`replica_env`). Nothing here imports jax — a parent that touched it
+would hold the chips its replicas need.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import re
 import signal
 import subprocess
 import sys
 import tempfile
+import urllib.request
 from typing import List, Sequence, Tuple
 
 from fengshen_tpu.disagg.policy import validate_phase
+
+
+def replica_env(index: int) -> dict:
+    """The environment replica `index` of a local fleet starts in: this
+    process's, plus what shows it chip `index` of the host and no
+    other. The three variables are libtpu's (verified on a four-chip
+    v5e host, libtpu 0.0.34: four such processes each see one device
+    and run side by side); other backends ignore them. A replica whose
+    index has no chip fails at start-up ("No jellyfish device found")
+    instead of hanging on a chip another replica holds."""
+    return {**os.environ,
+            "TPU_VISIBLE_CHIPS": str(index),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1"}
+
+
+def replica_backend(target: str, timeout_s: float = 10.0) -> str:
+    """The jax backend a running replica reports (the `backend` label
+    of `fstpu_build_info` on its `/metrics`) — how a bench parent names
+    the backend its rows came from without importing jax, which would
+    take the chip its replicas need."""
+    with urllib.request.urlopen(f"http://{target}/metrics",
+                                timeout=timeout_s) as r:
+        text = r.read().decode()
+    found = re.search(r'fstpu_build_info\{[^}]*backend="([^"]+)"', text)
+    if found is None:
+        raise RuntimeError(f"replica {target} exposes no "
+                           "fstpu_build_info on /metrics")
+    return found.group(1)
 
 
 def spawn_replicas(config_path: str, n: int, base_port: int,
@@ -31,11 +68,12 @@ def spawn_replicas(config_path: str, n: int, base_port: int,
                    ) -> Tuple[List[str], list]:
     """Write derived configs and start N replica subprocesses. Returns
     (targets, processes) where targets are "host:port" strings for
-    `FleetConfig.replicas`. Replicas inherit this process's env (so
-    `JAX_PLATFORMS` etc. flow through) plus `FSTPU_API_SERVER=stdlib`:
-    only the stdlib server path has the SIGTERM graceful drain the
-    fleet's rolling restarts depend on — a uvicorn replica would die
-    with its in-flight requests instead of draining.
+    `FleetConfig.replicas`. Replica i starts in `replica_env(i)` — this
+    process's env (so `JAX_PLATFORMS` etc. flow through) narrowed to
+    chip i — plus `FSTPU_API_SERVER=stdlib`: only the stdlib server
+    path has the SIGTERM graceful drain the fleet's rolling restarts
+    depend on — a uvicorn replica would die with its in-flight
+    requests instead of draining.
 
     `phases` assigns replica i the serving phase `phases[i]`
     (`prefill` | `decode` | `both`, docs/disaggregation.md) via its
@@ -68,7 +106,7 @@ def spawn_replicas(config_path: str, n: int, base_port: int,
         procs.append(subprocess.Popen(
             [sys.executable, "-m", "fengshen_tpu.api.main",
              "--config", path],
-            env={**os.environ, "FSTPU_API_SERVER": "stdlib"}))
+            env={**replica_env(i), "FSTPU_API_SERVER": "stdlib"}))
         targets.append(f"{host}:{port}")
     return targets, procs
 

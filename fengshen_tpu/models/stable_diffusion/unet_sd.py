@@ -110,7 +110,7 @@ def sd_timestep_embedding(timesteps: jax.Array, dim: int,
     # back-propagates downstream weight shards onto it, and a
     # concatenate consumed through a sharded matmul contraction
     # mispartitions on the CPU XLA build (docs/sharding.md "Root
-    # cause") — this constraint is the fix for NOTES.md item 3
+    # cause") — this constraint is the fix
     return with_logical_constraint(emb, ("batch", "relpos"))
 
 

@@ -286,9 +286,8 @@ class TransfoXLModel(nn.Module):
 #: holds whole heads of each of q/k/v). `relative` must be
 #: column-parallel too: its input is the sin|cos positional concat,
 #: and a concatenate consumed through a sharded matmul contraction
-#: mispartitions on this XLA build (the NOTES.md item 4 root cause,
-#: docs/sharding.md "Root cause") — hence `relpos` (→ None), never
-#: `embed`, on its contraction dim.
+#: mispartitions on this XLA build (docs/sharding.md "Root cause") —
+#: hence `relpos` (→ None), never `embed`, on its contraction dim.
 XL_PARAM_LOGICAL_AXES: list[tuple[str, tuple]] = [
     (r"word_embeddings/embedding", ("vocab", "embed")),
     (r"layer_\d+/attention/query_key_value/kernel", ("embed", "heads")),

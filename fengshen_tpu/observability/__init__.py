@@ -17,9 +17,8 @@ from fengshen_tpu.observability.exposition import (CONTENT_TYPE_LATEST,
                                                    MetricsServer,
                                                    render_prometheus,
                                                    start_metrics_server)
-from fengshen_tpu.observability.flightrecorder import (FlightRecorder,
-                                                       get_flight_recorder)
-from fengshen_tpu.observability.flops import (NOMINAL_FALLBACK_FLOPS,
+from fengshen_tpu.observability.flightrecorder import FlightRecorder
+from fengshen_tpu.observability.flops import (CPU_NOMINAL_FLOPS,
                                               PEAK_FLOPS,
                                               estimate_flops_per_token,
                                               peak_flops_per_chip)
@@ -39,11 +38,11 @@ from fengshen_tpu.observability.tracing import (current_span_stack, span)
 __all__ = [
     "BUILD_INFO_METRIC", "CONTENT_TYPE_LATEST", "Counter",
     "FlightRecorder", "Gauge", "Histogram", "JsonlSink",
-    "MetricsRegistry", "MetricsServer", "NOMINAL_FALLBACK_FLOPS",
+    "MetricsRegistry", "MetricsServer", "CPU_NOMINAL_FLOPS",
     "PEAK_FLOPS", "PHASE_NAMES", "RequestTimeline", "SpanLedger",
     "StepStats", "TraceContext", "TraceIds", "WARMUP_METRIC",
     "assemble_trace", "current_span_stack", "estimate_flops_per_token",
-    "get_flight_recorder", "get_registry", "parse_traceparent",
+    "get_registry", "parse_traceparent",
     "peak_flops_per_chip", "percentile", "record_build_info",
     "record_warmup_seconds", "render_prometheus", "span",
     "start_metrics_server",

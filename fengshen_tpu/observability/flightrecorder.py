@@ -209,20 +209,3 @@ class FlightRecorder:
         except (ValueError, OSError):
             return False
         return True
-
-
-_GLOBAL: Optional[FlightRecorder] = None
-_GLOBAL_LOCK = threading.Lock()
-
-
-def get_flight_recorder() -> FlightRecorder:
-    """The process-global recorder (bench rows, ad-hoc embedders); the
-    dump directory honors FSTPU_FLIGHT_DIR. Servers and Trainers build
-    their OWN recorders so concurrent engines never share a ring."""
-    global _GLOBAL
-    with _GLOBAL_LOCK:
-        if _GLOBAL is None:
-            _GLOBAL = FlightRecorder(
-                dump_dir=os.environ.get("FSTPU_FLIGHT_DIR",
-                                        "fstpu_dumps"))
-        return _GLOBAL

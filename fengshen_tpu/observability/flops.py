@@ -20,19 +20,21 @@ family of approximations, exact enough that MFU deltas track real
 optimization work. For a non-GQA model this reduces to the familiar
 ``6*(l*(4h^2 + 3*h*inter) + h*v)``.
 
-Peak FLOP/s comes from the TPU table below (bf16), the
-``FSTPU_PEAK_FLOPS`` env override (benchmarking on an unlisted chip),
-or a nominal CPU figure — nominal so that MFU stays FINITE and
-monotonic in CI/CPU runs; absolute CPU MFU values are indicative only.
+Peak FLOP/s comes from the table below (bf16), keyed by the
+``device_kind`` jax reports. An accelerator that is not in the table
+is an error, not a default: a made-up peak makes a made-up MFU. The
+CPU backend alone gets a nominal figure, so that MFU stays FINITE and
+monotonic in CI runs; a CPU MFU is never a hardware claim.
 """
 
 from __future__ import annotations
 
-import os
 from typing import Any, Optional
 
 #: peak bf16 FLOP/s per chip (the table that lived in trainer.py;
-#: trainer re-exports it for compatibility)
+#: trainer re-exports it for compatibility). Source: Google Cloud TPU
+#: documentation, per-generation system architecture pages. The v5e
+#: reports itself as "TPU v5 lite" (libtpu 0.0.34, PR 21 chip run).
 PEAK_FLOPS = {
     "TPU v2": 45e12,
     "TPU v3": 123e12,
@@ -44,32 +46,25 @@ PEAK_FLOPS = {
     "TPU v6e": 918e12,
 }
 
-#: nominal figure for backends not in the table (CPU CI runs): a
-#: round 1 TFLOP/s so mfu is finite and comparable run-to-run on the
-#: same host, never a hardware claim
-NOMINAL_FALLBACK_FLOPS = 1e12
-
-#: env override: FSTPU_PEAK_FLOPS=9.2e14 for an unlisted accelerator
-PEAK_FLOPS_ENV = "FSTPU_PEAK_FLOPS"
+#: nominal figure for the CPU backend (CI runs): a round 1 TFLOP/s so
+#: mfu is finite and comparable run-to-run on the same host
+CPU_NOMINAL_FLOPS = 1e12
 
 
 def peak_flops_per_chip(device_kind: Optional[str] = None) -> float:
     """Peak FLOP/s for one chip of ``device_kind`` (default: the first
-    visible jax device). Resolution order: env override, TPU table,
-    nominal fallback. Always positive and finite."""
-    env = os.environ.get(PEAK_FLOPS_ENV)
-    if env:
-        peak = float(env)
-        if peak <= 0:
-            raise ValueError(f"{PEAK_FLOPS_ENV}={env!r} must be > 0")
-        return peak
+    visible jax device). Raises for an accelerator the table does not
+    know."""
     if device_kind is None:
-        try:
-            import jax
-            device_kind = jax.devices()[0].device_kind
-        except Exception:  # noqa: BLE001 — no jax/backend: use fallback
-            device_kind = ""
-    return PEAK_FLOPS.get(device_kind, NOMINAL_FALLBACK_FLOPS)
+        import jax
+        device_kind = jax.devices()[0].device_kind
+    if device_kind in PEAK_FLOPS:
+        return PEAK_FLOPS[device_kind]
+    if device_kind.lower() == "cpu":
+        return CPU_NOMINAL_FLOPS
+    raise ValueError(
+        f"no peak FLOP/s on record for device_kind {device_kind!r}; add "
+        f"it to PEAK_FLOPS with its source (known: {sorted(PEAK_FLOPS)})")
 
 
 def estimate_flops_per_token(config: Any,

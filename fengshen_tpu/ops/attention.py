@@ -76,21 +76,29 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if impl == "sparse" and sparse_layout is not None:
         import numpy as np
 
-        from fengshen_tpu.ops.pallas import probe
+        from fengshen_tpu.ops.pallas import (resolve_dispatch,
+                                             run_per_shard)
         layout = np.asarray(sparse_layout)
         blk = sparse_block_size
         eligible = (
             bias is None and mask is None and
             (deterministic or dropout_rate == 0.0) and
-            probe().pallas_tpu and
             q.shape[1] % blk == 0 and k.shape[1] % blk == 0 and
             blk % 128 == 0 and q.shape[-1] % 128 == 0 and
             layout.shape == (q.shape[1] // blk, k.shape[1] // blk))
-        if eligible:
+        took = resolve_dispatch(
+            "block_sparse_attention",
+            f"q={tuple(q.shape)} kv={tuple(k.shape)}:{q.dtype.name} "
+            f"block={blk}",
+            None if eligible else "bias, mask, dropout or unaligned "
+                                  "shapes")
+        if took == "pallas":
             from fengshen_tpu.ops.pallas.block_sparse_attention import (
                 block_sparse_attention)
-            return block_sparse_attention(q, k, v, layout, blk)
-        # fall back: expand the block layout to a dense mask
+            return run_per_shard(
+                lambda q, k, v: block_sparse_attention(q, k, v, layout,
+                                                       blk), q, k, v)
+        # otherwise: the block layout expanded to a dense mask
         expanded = jnp.asarray(
             np.kron(layout, np.ones((blk, blk), dtype=bool)))
         mask = expanded[None, None] if mask is None else \

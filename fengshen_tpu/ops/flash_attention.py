@@ -166,11 +166,23 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if q_seg is not None:
         q_seg = q_seg.astype(jnp.int32)
         kv_seg = kv_seg.astype(jnp.int32)
-    if _pallas_eligible(q, k, v, bias, causal):
+    from fengshen_tpu.ops.pallas import resolve_dispatch, run_per_shard
+    impl = resolve_dispatch(
+        "flash_attention",
+        f"q={tuple(q.shape)} kv={tuple(k.shape)}:{q.dtype.name} "
+        f"causal={causal} segments={q_seg is not None}",
+        _pallas_ineligible_reason(q, k, bias))
+    if impl == "pallas":
         from fengshen_tpu.ops.pallas.flash_attention import (
             pallas_flash_attention)
-        return pallas_flash_attention(q, k, v, q_seg, kv_seg, causal)
-    if k.shape[2] != q.shape[2]:  # GQA fallback: repeat for blockwise
+        if q_seg is None:
+            return run_per_shard(
+                lambda q, k, v: pallas_flash_attention(
+                    q, k, v, None, None, causal), q, k, v)
+        return run_per_shard(
+            lambda q, k, v, q_seg, kv_seg: pallas_flash_attention(
+                q, k, v, q_seg, kv_seg, causal), q, k, v, q_seg, kv_seg)
+    if k.shape[2] != q.shape[2]:  # GQA on blockwise: repeat the KV heads
         rep = q.shape[2] // k.shape[2]
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
@@ -179,19 +191,20 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                                q_segment_ids=q_seg, kv_segment_ids=kv_seg)
 
 
-def _pallas_eligible(q, k, v, bias, causal) -> bool:
-    """Kernel-eligibility check in the spirit of the reference's
+def _pallas_ineligible_reason(q, k, bias) -> Optional[str]:
+    """Why these shapes cannot take the Pallas kernel, or None when
+    they can — a kernel-eligibility check in the spirit of the reference's
     `FusedScaleMaskSoftmax.is_kernel_available`
     (reference: layers/fused_softmax.py:148-168). GQA (fewer KV heads)
     is kernel-native — the grid index maps read each KV head once per
     group — as long as the head counts divide."""
     if bias is not None:
-        return False
-    from fengshen_tpu.ops.pallas import probe
-    if not probe().pallas_tpu:
-        return False
+        return "additive bias"
     _, q_len, n_heads, head_dim = q.shape
     k_len, kv_heads = k.shape[1], k.shape[2]
     if n_heads % kv_heads != 0:
-        return False
-    return (head_dim % 128 == 0 and q_len % 128 == 0 and k_len % 128 == 0)
+        return f"{n_heads} heads not a multiple of {kv_heads} kv heads"
+    if head_dim % 128 or q_len % 128 or k_len % 128:
+        return (f"head_dim {head_dim} / q_len {q_len} / k_len {k_len} "
+                "not all multiples of 128")
+    return None

@@ -24,8 +24,14 @@ and callers route through one seam:
 - :func:`kernel_fingerprint` — the dispatch table serialized for the
   AOT cache key (docs/aot_cache.md): a pallas-compiled executable must
   never be replayed on an xla-dispatch process and vice versa.
-- :func:`log_dispatch` — THE loud line (PR 9 doctrine: degrade loudly,
-  never fail) + the ``fstpu_kernel_dispatch{op,impl}`` gauge.
+- :func:`resolve_dispatch` — what each TRACED call site actually
+  takes: the table says which implementation an op prefers on this
+  backend, but a seam still routes a shape its kernel cannot tile to
+  the xla lowering. Every seam makes that choice (and records why)
+  here at trace time, so it is never made without a word.
+- :func:`log_dispatch` — THE loud line: the table, the probe, and the
+  call sites traced so far, plus the ``fstpu_kernel_dispatch{op,impl}``
+  gauge.
 
 See docs/kernels.md for the dispatch ladder and the
 writing-a-kernel checklist.
@@ -72,9 +78,11 @@ _PROBE_CACHE: Dict[tuple, KernelProbe] = {}
 
 
 def probe(refresh: bool = False) -> KernelProbe:
-    """Cached capability probe. Never raises: a backend that cannot
-    run Mosaic answers ``pallas_tpu=False`` with the reason, and every
-    op degrades to its xla lowering (loudly — see log_dispatch)."""
+    """Cached capability probe. A backend that is not a TPU answers
+    ``pallas_tpu=False`` with the reason and every op takes its xla
+    lowering. On the ``tpu`` backend the kernels are the
+    implementation, so a jax build whose pallas does not import is an
+    error there, not a quiet change of lowering."""
     import jax
 
     forced = os.environ.get(FORCE_ENV, "").strip().lower() or None
@@ -96,15 +104,10 @@ def probe(refresh: bool = False) -> KernelProbe:
                              "kernels; xla lowering (CPU tier-1 pins "
                              "parity against it)")
     else:
-        try:
-            from jax.experimental import pallas as _pl  # noqa: F401
-            from jax.experimental.pallas import tpu as _pltpu  # noqa: F401
-            result = KernelProbe(backend, True, None,
-                                 "tpu backend + pallas importable")
-        except Exception as exc:  # noqa: BLE001 — a jax build without
-            # pallas still serves/trains on the stock lowering
-            result = KernelProbe(backend, False, None,
-                                 f"pallas import failed: {exc!r}")
+        from jax.experimental import pallas as _pl  # noqa: F401
+        from jax.experimental.pallas import tpu as _pltpu  # noqa: F401
+        result = KernelProbe(backend, True, None,
+                             "tpu backend + pallas importable")
     _PROBE_CACHE[cache_key] = result
     return result
 
@@ -160,13 +163,47 @@ def kernel_fingerprint() -> str:
     return f"kernels={table};backend={probe().backend}"
 
 
+#: (op, impl, detail) of every dispatch decision a seam took while
+#: tracing, in first-seen order. Process-wide like the probe cache: the
+#: seams are called from inside flax modules with no object to carry it.
+_TRACED: Dict[tuple, None] = {}
+
+
+def resolve_dispatch(op: str, detail: str,
+                     ineligible: Optional[str]) -> str:
+    """The implementation one traced call site of ``op`` takes:
+    ``"pallas"`` when the backend can run Mosaic and the seam found
+    nothing against the shape (``ineligible`` is None), else ``"xla"``.
+    ``detail`` names the shapes. The decision is recorded with its
+    reason, and on a Mosaic-capable backend each distinct one is also
+    printed once, so an ineligible shape is never routed to the xla
+    lowering in silence."""
+    why = ineligible if probe().pallas_tpu else \
+        "backend cannot run Mosaic"
+    impl = "xla" if why else "pallas"
+    key = (op, impl, f"{detail} ({why})" if why else detail)
+    if key not in _TRACED:
+        _TRACED[key] = None
+        if probe().pallas_tpu:
+            print(f"[fengshen-tpu] kernel dispatch: {op} -> {impl} "
+                  f"[{key[2]}]", file=sys.stderr, flush=True)
+    return impl
+
+
+def traced_dispatch() -> list:
+    """Every call-site decision recorded so far, oldest first."""
+    return [{"op": op, "impl": impl, "detail": detail}
+            for op, impl, detail in _TRACED]
+
+
 def log_dispatch(log: Optional[Callable[[dict], None]] = None,
                  registry=None) -> Dict[str, str]:
-    """THE loud line: state every kernel's dispatch decision once at
-    startup (structured sink when one exists, stderr otherwise) and set
-    the ``fstpu_kernel_dispatch{op,impl}`` gauge — 1 for the chosen
-    impl, 0 for the alternative, so a scraper can alert on a fleet
-    silently degrading to xla. Returns the dispatch table."""
+    """THE loud line: state every kernel's dispatch decision (structured
+    sink when one exists, stderr otherwise) with the call sites traced
+    so far, and set the ``fstpu_kernel_dispatch{op,impl}`` gauge — 1
+    for the chosen impl, 0 for the alternative, so a scraper can alert
+    on a fleet that is not running its kernels. Returns the dispatch
+    table."""
     from fengshen_tpu.observability.registry import get_registry
 
     info = probe()
@@ -181,13 +218,50 @@ def log_dispatch(log: Optional[Callable[[dict], None]] = None,
             gauge.labels(op, impl).set(1 if impl == chosen else 0)
     if log is not None:
         log({"event": "kernel_dispatch", "table": table,
-             **info.describe()})
+             "call_sites": traced_dispatch(), **info.describe()})
     else:
         summary = " ".join(f"{op}={impl}" for op, impl in table.items())
         print(f"[fengshen-tpu] kernel dispatch: {summary} "
               f"(backend={info.backend}) — {info.reason}",
               file=sys.stderr, flush=True)
     return table
+
+
+def run_per_shard(kernel: Callable, q, k, v, *per_token):
+    """Run an attention-shaped Mosaic kernel under a device mesh.
+
+    GSPMD cannot partition a Mosaic custom call ("Mosaic kernels cannot
+    be automatically partitioned. Please wrap the call in a
+    shard_map"), so under a multi-device mesh the kernel runs inside a
+    shard_map: batch over the batch axes and heads over ``tensor`` —
+    attention is independent across both, so no collective is needed —
+    with the sequence dimension whole. ``q/k/v`` are ``[B, S, H, D]``;
+    ``per_token`` are ``[B, S]`` operands (segment ids) that shard like
+    the batch. A dimension the axes do not divide stays replicated (the
+    init pass runs batch 1). A call that is already inside a shard_map
+    body (ring / Ulysses blocks, pipeline stages) sees local shards and
+    runs the kernel as it is."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from fengshen_tpu.parallel.mesh import (BATCH_AXES, TENSOR_AXIS,
+                                            get_mesh)
+    mesh = get_mesh()
+    if mesh is None or mesh.size == 1 or \
+            jax.sharding.get_abstract_mesh().manual_axes:
+        return kernel(q, k, v, *per_token)
+    n_batch = 1
+    for axis in BATCH_AXES:
+        n_batch *= mesh.shape[axis]
+    tensor = mesh.shape[TENSOR_AXIS]
+    batch_axes = BATCH_AXES if q.shape[0] % n_batch == 0 else None
+    head_axis = TENSOR_AXIS if q.shape[2] % tensor == 0 and \
+        k.shape[2] % tensor == 0 else None
+    qkv = P(batch_axes, None, head_axis, None)
+    return jax.shard_map(
+        kernel, mesh=mesh,
+        in_specs=(qkv,) * 3 + (P(batch_axes, None),) * len(per_token),
+        out_specs=qkv, check_vma=False)(q, k, v, *per_token)
 
 
 # -- registrations ------------------------------------------------------
@@ -221,6 +295,7 @@ register_kernel("fused_ce", "xla", xla_fused_ce)
 __all__ = [
     "KernelProbe", "probe", "register_kernel", "kernel_choice",
     "get_kernel", "dispatch_table", "kernel_fingerprint", "log_dispatch",
+    "resolve_dispatch", "traced_dispatch", "run_per_shard",
     "decode_attention", "xla_decode_attention", "pallas_decode_attention",
     "pallas_decode_eligible", "fused_ce_loss", "pallas_fused_ce",
     "xla_fused_ce", "pallas_flash_attention",

@@ -15,26 +15,30 @@ shape routes through (see fengshen_tpu/ops/pallas/__init__.py):
   **through the block table directly**: the block-table row rides in as
   a scalar-prefetch operand, so each grid step's BlockSpec index map
   picks the lane's physical block out of HBM — no gather copy, no
-  virtual-lane materialization. The int8 per-(token, head) dequant
-  (``ops/int8_matmul.quantize_kv`` scales) happens in registers on the
-  ``[block_size, head_dim]`` tile, and GQA reads each KV head once per
-  query-head group via the index map (no HBM ``jnp.repeat``). Slot-pool
-  (contiguous ``[B, max_len]``) caches reuse the same kernel by
-  reshaping into ``max_len // block_size`` blocks per lane with an
-  arange block table. Serves both the ``[B, 1]`` decode tick and the
-  ``[B, gamma+1]`` speculative verify window (one sequential grid axis
-  over blocks, online softmax across them).
+  virtual-lane materialization, no head-major transpose. A block
+  arrives with all its KV heads (the only shape Mosaic can DMA out of
+  a ``[.., block_size, KVH, D]`` pool) and the kernel folds tokens and
+  heads into one key axis, masking the columns of other heads
+  (see :func:`_decode_kernel`). int8 pools stay int8 in VMEM; the
+  per-(token, head) scales (``ops/int8_matmul.quantize_kv``) multiply
+  the score and probability tiles. GQA needs no ``jnp.repeat``: a query
+  head's mask row selects its group's KV head. Slot-pool (contiguous
+  ``[B, max_len]``) caches reuse the same kernel by reshaping into
+  ``max_len // block_size`` blocks per lane with an arange block table.
+  Serves both the ``[B, 1]`` decode tick and the ``[B, gamma+1]``
+  speculative verify window (one sequential grid axis over blocks,
+  online softmax across them).
 - :func:`xla_decode_attention` — the stock lowering, op-for-op the
   sequence the model ran before this seam existed (take-gather →
   dequantize → GQA repeat → dense attention), so CPU tier-1 pins
   greedy decode through the dispatcher token-identical to the
   pre-kernel path.
 
-Tiling (docs/kernels.md): the Mosaic lane dim must be a 128-multiple,
-so the pallas path requires ``head_dim % 128 == 0`` and
-``block_size % 128 == 0`` (the validity mask streams as
-``[S, block_size]`` tiles). Pools with small pages stay on the xla
-lowering — eligibility is part of the dispatch, not an error.
+Tiling (docs/kernels.md): the pallas path requires
+``head_dim % 128 == 0``, ``block_size % 128 == 0`` and
+``KVH % 8 == 0`` (the token×head fold is a free reshape only on f32
+sublane tiles). Other shapes are the xla lowering's; which one a traced
+call site took is recorded through ``ops.pallas.resolve_dispatch``.
 """
 
 from __future__ import annotations
@@ -58,6 +62,18 @@ _NEG_INF = -1e30
 #: belong on the flash/dense paths
 _MAX_QUERY_WINDOW = 8
 
+#: scoped-VMEM ceiling the kernel asks Mosaic for: a whole K and a
+#: whole V block (all heads), double-buffered, plus their f32 copies
+#: outgrow the 16 MiB default at LLaMA-13B's 40 heads x 128
+_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+
+#: tokens per grid step when a slot cache is viewed as blocks
+_SLOT_BLOCK = 128
+
+#: largest K (or V) block, in elements, the kernel will stream: 128
+#: tokens of 64 heads x 128 — its f32 copy is 4 MiB of the limit above
+_MAX_BLOCK_ELEMS = 128 * 64 * 128
+
 
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      valid: jax.Array, *,
@@ -80,10 +96,12 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     ``None`` asks the capability probe + shape eligibility.
     """
     if impl is None:
-        from fengshen_tpu.ops.pallas import probe
-        use_pallas = probe().pallas_tpu and pallas_decode_eligible(
-            q, k, v, k_scale=k_scale, block_table=block_table)
-        impl = "pallas" if use_pallas else "xla"
+        from fengshen_tpu.ops.pallas import resolve_dispatch
+        impl = resolve_dispatch(
+            "decode_attention",
+            f"q={tuple(q.shape)} kv={tuple(k.shape)}:{k.dtype.name} "
+            f"{'paged' if block_table is not None else 'slot'}",
+            _ineligible_reason(q, k, block_table))
     if impl == "pallas":
         return pallas_decode_attention(
             q, k, v, valid, k_scale=k_scale, v_scale=v_scale,
@@ -94,24 +112,35 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         block_table=block_table, dequant_dtype=dequant_dtype)
 
 
-def pallas_decode_eligible(q, k, v, k_scale=None,
-                           block_table=None) -> bool:
-    """Shape eligibility for the Mosaic kernel (the backend capability
-    itself is the registry probe's job). Mirrors `_pallas_eligible` in
-    ops.flash_attention: tile-aligned or stay on the stock lowering."""
-    del v, k_scale
+def _ineligible_reason(q, k, block_table) -> Optional[str]:
+    """Why this shape cannot take the Mosaic kernel, or None when it
+    can (the backend capability itself is `resolve_dispatch`'s job)."""
     _, s, n_heads, head_dim = q.shape
     kv_heads = k.shape[-2]
     if s > _MAX_QUERY_WINDOW:
-        return False
-    if n_heads % kv_heads != 0:
-        return False
+        return f"query window {s} > {_MAX_QUERY_WINDOW}"
+    if n_heads % kv_heads != 0 or kv_heads % 8 != 0:
+        return f"kv heads {kv_heads} not a multiple of 8 dividing " \
+               f"{n_heads}"
     if head_dim % 128 != 0:
-        return False
-    if block_table is not None:
-        block_size = k.shape[1]
-        return block_size % 128 == 0
-    return k.shape[1] % 128 == 0
+        return f"head_dim {head_dim} % 128 != 0"
+    if k.shape[1] % 128 != 0:
+        what = "block_size" if block_table is not None else "cache length"
+        return f"{what} {k.shape[1]} % 128 != 0"
+    block = k.shape[1] if block_table is not None else _SLOT_BLOCK
+    if block * kv_heads * head_dim > _MAX_BLOCK_ELEMS:
+        return f"a block of {block} tokens x {kv_heads} heads x " \
+               f"{head_dim} outgrows VMEM"
+    return None
+
+
+def pallas_decode_eligible(q, k, v=None, k_scale=None,
+                           block_table=None) -> bool:
+    """Shape eligibility for the Mosaic kernel: tile-aligned or stay on
+    the stock lowering (mirrors `_pallas_ineligible_reason` in
+    ops.flash_attention)."""
+    del v, k_scale
+    return _ineligible_reason(q, k, block_table) is None
 
 
 def xla_decode_attention(q, k, v, valid, *, k_scale=None, v_scale=None,
@@ -150,19 +179,32 @@ def xla_decode_attention(q, k, v, valid, *, k_scale=None, v_scale=None,
     return dot_product_attention(q, k, v, mask=valid[:, None])
 
 
-def _decode_kernel(table_ref, *refs, scale, n_blocks, quantized, dt):
-    """One (lane, query head, block) grid step: the BlockSpec index
-    maps already routed the lane's j-th physical block into VMEM via
-    ``table_ref`` — the kernel only sees ``[block_size, head_dim]``
-    tiles and keeps online-softmax stats in scratch across the
-    sequential block axis (same scheme as block_sparse_attention)."""
+def _decode_kernel(table_ref, *refs, scale, n_blocks, n_query, rep,
+                   quantized, dt):
+    """One (lane, block) grid step over ALL heads at once.
+
+    The pool block arrives whole — ``[block_size, KVH, D]`` is one
+    contiguous slab of HBM, and Mosaic cannot DMA a single head out of
+    it (a block's last two dims must tile by (8, 128) or span the
+    array's, and one bf16 head row is half a packed sublane). So the
+    kernel folds the token and head axes into one key axis of
+    ``C = block_size * KVH`` columns and runs a dense
+    ``[H, D] x [D, C]`` matmul per query position; columns whose KV
+    head is not the row's are masked out with the invalid positions.
+    The MXU work this wastes (KVH x) is idle in a bandwidth-bound
+    decode; what the kernel buys is that K and V are read from HBM
+    exactly once, through the block table, with no gather copy and no
+    head-major transpose. The fold is a free reshape only in f32 tiles
+    (hence ``KVH % 8 == 0`` in the eligibility rule), so K/V are
+    widened in VMEM first. Online-softmax stats live in scratch across
+    the sequential block axis."""
     if quantized:
-        (q_ref, k_ref, v_ref, ks_ref, vs_ref, mask_ref, o_ref,
-         acc_ref, m_ref, l_ref) = refs
+        (q_ref, k_ref, v_ref, ks_ref, vs_ref, mask_ref, colhead_ref,
+         o_ref, acc_ref, m_ref, l_ref) = refs
     else:
-        (q_ref, k_ref, v_ref, mask_ref, o_ref,
+        (q_ref, k_ref, v_ref, mask_ref, colhead_ref, o_ref,
          acc_ref, m_ref, l_ref) = refs
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -170,40 +212,54 @@ def _decode_kernel(table_ref, *refs, scale, n_blocks, quantized, dt):
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)          # [S, D]
-    k = k_ref[0, :, 0, :]                        # [block, D]
-    v = v_ref[0, :, 0, :]
-    if quantized:
-        # in-register per-(token, head) dequant — the pool stays int8
-        # in HBM; rounding through `dt` mirrors ops.int8_matmul.
-        # dequantize_kv so margins match the xla lowering
-        k = (k.astype(jnp.float32) * ks_ref[0, :, 0][:, None]).astype(dt)
-        v = (v.astype(jnp.float32) * vs_ref[0, :, 0][:, None]).astype(dt)
-    scores = jax.lax.dot_general(
-        q, k.astype(jnp.float32), (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale      # [S, block]
-    scores = jnp.where(mask_ref[0] > 0, scores, _NEG_INF)
+    block_size, kv_heads, head_dim = k_ref.shape[1:]
+    n_cols = block_size * kv_heads
+    n_heads = q_ref.shape[2]
+    k = k_ref[0].astype(jnp.float32).reshape(n_cols, head_dim)
+    v = v_ref[0].astype(jnp.float32).reshape(n_cols, head_dim)
+    row_kv = jax.lax.broadcasted_iota(jnp.int32, (n_heads, n_cols), 0)
+    if rep > 1:
+        row_kv = row_kv // rep
+    own_head = colhead_ref[...] == row_kv                # [H, C]
 
-    m_prev, l_prev = m_ref[...], l_ref[...]              # [S, 1]
-    m_new = jnp.maximum(m_prev, scores.max(-1, keepdims=True))
-    correction = jnp.exp(m_prev - m_new)
-    probs = jnp.exp(scores - m_new)
-    l_ref[...] = l_prev * correction + probs.sum(-1, keepdims=True)
-    pv = jax.lax.dot_general(
-        probs, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)              # [S, D]
-    acc_ref[...] = acc_ref[...] * correction + pv
-    m_ref[...] = m_new
+    for s in range(n_query):
+        q = q_ref[0, s].astype(jnp.float32) * scale      # [H, D]
+        scores = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)          # [H, C]
+        if quantized:
+            # per-(token, head) dequant applied to the product: the
+            # pool stays int8 in HBM and in VMEM
+            scores = scores * ks_ref[0]
+        allowed = own_head & (mask_ref[0, s:s + 1, :] > 0)
+        scores = jnp.where(allowed, scores, _NEG_INF)
+
+        m_prev, l_prev = m_ref[s], l_ref[s]              # [H, 1]
+        m_new = jnp.maximum(m_prev, scores.max(-1, keepdims=True))
+        correction = jnp.exp(m_prev - m_new)
+        probs = jnp.exp(scores - m_new)
+        l_ref[s] = l_prev * correction + probs.sum(-1, keepdims=True)
+        if quantized:
+            probs = probs * vs_ref[0]
+        # round the probabilities through the compute dtype like the
+        # xla lowering does before its PV matmul
+        pv = jax.lax.dot_general(
+            probs.astype(dt).astype(jnp.float32), v,
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)          # [H, D]
+        acc_ref[s] = acc_ref[s] * correction + pv
+        m_ref[s] = m_new
 
     @pl.when(j == n_blocks - 1)
     def _finalize():
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] /
+                    jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def pallas_decode_attention(q, k, v, valid, *, k_scale=None,
                             v_scale=None, block_table=None,
-                            dequant_dtype=None, block_size: int = 128,
+                            dequant_dtype=None,
+                            block_size: int = _SLOT_BLOCK,
                             interpret: bool = False):
     """Fused paged decode attention. Same contract as
     :func:`decode_attention`; slot caches (``block_table=None``) are
@@ -227,62 +283,62 @@ def pallas_decode_attention(q, k, v, valid, *, k_scale=None,
                       kv_heads, head_dim)
         v = v.reshape(batch * blocks_per_lane, block_size,
                       kv_heads, head_dim)
-        if quantized:
-            k_scale = k_scale.reshape(batch * blocks_per_lane,
-                                      block_size, kv_heads)
-            v_scale = v_scale.reshape(batch * blocks_per_lane,
-                                      block_size, kv_heads)
         block_table = (jnp.arange(batch, dtype=jnp.int32)[:, None] *
                        blocks_per_lane +
                        jnp.arange(blocks_per_lane, dtype=jnp.int32)[None])
     else:
         block_size = k.shape[1]
         blocks_per_lane = block_table.shape[-1]
+    n_cols = block_size * kv_heads
 
-    qt = q.transpose(0, 2, 1, 3)                 # [B, H, S, D]
-    mask = valid.astype(jnp.int32)               # [B, S, virt_len]
+    # key column c of a block is (token c // KVH, kv head c % KVH):
+    # the validity mask and the int8 scales are laid out to match
+    mask = jnp.repeat(valid.astype(jnp.int32), kv_heads, axis=-1)
+    col_head = jnp.tile(jnp.arange(kv_heads, dtype=jnp.int32),
+                        block_size)[None]                # [1, C]
 
-    def kv_map(b, h, j, table):
+    def kv_map(b, j, table):
         # the whole point: the lane's j-th PHYSICAL block comes out of
         # the pool directly — no gather into a virtual lane
-        return (table[b, j], 0, h // rep, 0)
+        return (table[b, j], 0, 0, 0)
 
-    def scale_map(b, h, j, table):
-        return (table[b, j], 0, h // rep)
+    def scale_map(b, j, table):
+        return (table[b, j], 0, 0)
 
-    in_specs = [
-        pl.BlockSpec((1, 1, s, head_dim),
-                     lambda b, h, j, table: (b, h, 0, 0)),      # q
-        pl.BlockSpec((1, block_size, 1, head_dim), kv_map),     # k pool
-        pl.BlockSpec((1, block_size, 1, head_dim), kv_map),     # v pool
-    ]
-    operands = [qt, k, v]
+    kv_spec = pl.BlockSpec((1, block_size, kv_heads, head_dim), kv_map)
+    qo_spec = pl.BlockSpec((1, s, n_heads, head_dim),
+                           lambda b, j, table: (b, 0, 0, 0))
+    in_specs = [qo_spec, kv_spec, kv_spec]
+    operands = [q, k, v]
     if quantized:
-        in_specs += [pl.BlockSpec((1, block_size, 1), scale_map),
-                     pl.BlockSpec((1, block_size, 1), scale_map)]
-        operands += [k_scale, v_scale]
-    in_specs.append(pl.BlockSpec((1, s, block_size),
-                                 lambda b, h, j, table: (b, 0, j)))
-    operands.append(mask)
+        in_specs += [pl.BlockSpec((1, 1, n_cols), scale_map)] * 2
+        operands += [k_scale.reshape(-1, 1, n_cols),
+                     v_scale.reshape(-1, 1, n_cols)]
+    in_specs += [pl.BlockSpec((1, s, n_cols),
+                              lambda b, j, table: (b, 0, j)),
+                 pl.BlockSpec((1, n_cols), lambda b, j, table: (0, 0))]
+    operands += [mask, col_head]
 
     kernel = functools.partial(
         _decode_kernel, scale=1.0 / math.sqrt(head_dim),
-        n_blocks=blocks_per_lane, quantized=quantized, dt=dt)
+        n_blocks=blocks_per_lane, n_query=s, rep=rep,
+        quantized=quantized, dt=dt)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(batch, n_heads, blocks_per_lane),
+        grid=(batch, blocks_per_lane),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, s, head_dim),
-                               lambda b, h, j, table: (b, h, 0, 0)),
+        out_specs=qo_spec,
         scratch_shapes=[
-            pltpu.VMEM((s, head_dim), jnp.float32),
-            pltpu.VMEM((s, 1), jnp.float32),
-            pltpu.VMEM((s, 1), jnp.float32),
+            pltpu.VMEM((s, n_heads, head_dim), jnp.float32),
+            pltpu.VMEM((s, n_heads, 1), jnp.float32),
+            pltpu.VMEM((s, n_heads, 1), jnp.float32),
         ],
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(block_table.astype(jnp.int32), *operands)
-    return out.transpose(0, 2, 1, 3)

@@ -409,7 +409,7 @@ def pallas_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     segment ids: int32 [B, S]; tokens attend only within equal ids (pads are
     segment 0 when derived from an attention_mask). Requires Sq % blk_q == 0,
-    Sk % blk_k == 0 (the `_pallas_eligible` dispatch in ops.flash_attention
+    Sk % blk_k == 0 (the eligibility rule in ops.flash_attention.flash_attention
     guarantees tile-aligned shapes, in the spirit of the reference's
     fused-kernel availability check, reference:
     fengshen/models/megatron/layers/fused_softmax.py:148-168).

@@ -20,8 +20,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from fengshen_tpu.compat import axis_size as _axis_size, shard_map
 
 from fengshen_tpu.parallel.mesh import BATCH_AXES, SEQUENCE_AXIS, get_mesh
 
@@ -46,7 +46,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     `axis_name` determines global positions (contiguous layout: shard i
     holds positions [i*S_local, (i+1)*S_local)).
     """
-    ring_size = _axis_size(axis_name)
+    ring_size = jax.lax.axis_size(axis_name)
     my_idx = jax.lax.axis_index(axis_name)
     batch, s_local, num_heads, head_dim = q.shape
     scale = 1.0 / jnp.sqrt(head_dim).astype(jnp.float32)

@@ -24,7 +24,6 @@ from functools import partial
 from typing import Optional
 
 import jax
-from fengshen_tpu.compat import axis_size as _axis_size
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
@@ -44,7 +43,7 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     """
     from fengshen_tpu.ops.flash_attention import flash_attention
 
-    sp = _axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     num_heads = q.shape[2]
     if num_heads % sp:
         raise ValueError(

@@ -16,9 +16,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from fengshen_tpu.compat import shard_map
 
 from fengshen_tpu.parallel.mesh import (BATCH_AXES, SEQUENCE_AXIS,
                                         TENSOR_AXIS, get_mesh)

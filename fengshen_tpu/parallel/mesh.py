@@ -135,18 +135,15 @@ def make_mesh(config: Optional[MeshConfig] = None,
         devices = jax.devices()
     shape = config.resolve(len(devices))
     # Auto axis types: we drive sharding with GSPMD constraints + shard_map,
-    # not the explicit-sharding type system. jax<0.6 has no AxisType (Auto
-    # is the only behavior there), so the kwarg is gated.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    kwargs = {} if axis_type is None else \
-        {"axis_types": (axis_type.Auto,) * len(MESH_AXES)}
-    try:
-        if list(devices) == list(jax.devices()):
-            return jax.make_mesh(shape, MESH_AXES, **kwargs)
-    except Exception:  # pragma: no cover - make_mesh can reject odd topologies
-        pass
+    # not the explicit-sharding type system. Passed on both branches —
+    # jax.make_mesh would default to Explicit axes, Mesh(...) to Auto.
+    axis_types = (jax.sharding.AxisType.Auto,) * len(MESH_AXES)
+    if list(devices) == list(jax.devices()):
+        # a topology jax.make_mesh rejects is an error: a plain reshape
+        # in its place would ignore the ICI layout without saying so
+        return jax.make_mesh(shape, MESH_AXES, axis_types=axis_types)
     dev_array = np.asarray(devices).reshape(shape)
-    return Mesh(dev_array, MESH_AXES, **kwargs)
+    return Mesh(dev_array, MESH_AXES, axis_types=axis_types)
 
 
 _GLOBAL_MESH: Optional[Mesh] = None

@@ -119,15 +119,8 @@ def make_shardings(rules_or_specs: Any,
     mesh = mesh or get_mesh()
     if mesh is None:
         raise ValueError("no mesh installed; call make_mesh()/set_mesh() first")
-    # a bare PartitionSpec must not be mistaken for a rules table: on
-    # jax<0.6 PartitionSpec subclasses tuple, so the isinstance probe
-    # below would otherwise "match" a multi-axis spec like P(("data",
-    # "fsdp"), "sequence")
-    if isinstance(rules_or_specs, P):
-        specs = rules_or_specs
-    elif isinstance(rules_or_specs, (list, tuple)) and rules_or_specs \
-            and isinstance(rules_or_specs[0], tuple) \
-            and not isinstance(rules_or_specs[0], P):
+    if isinstance(rules_or_specs, (list, tuple)) and rules_or_specs \
+            and isinstance(rules_or_specs[0], tuple):
         specs = match_partition_rules(rules_or_specs, tree)
     else:
         specs = rules_or_specs
@@ -175,16 +168,8 @@ def with_sharding_constraint(x: Any, spec: P, mesh: Optional[Mesh] = None):
     # are illegal — strip manual axes from the spec (model code then runs
     # unchanged whether it executes under GSPMD or inside a shard_map
     # stage, e.g. the pipeline-parallel body).
-    try:
-        abstract = jax.sharding.get_abstract_mesh()
-        manual = {name for name, t in zip(abstract.axis_names,
-                                          abstract.axis_types)
-                  if "Manual" in str(t)} if abstract is not None and \
-            abstract.axis_names else set()
-    except Exception:  # pragma: no cover - jax version probe (older
-        # jax lacks get_abstract_mesh / AxisType; degrade to "no
-        # manual axes" rather than pinning one jax API surface)
-        abstract, manual = None, set()
+    abstract = jax.sharding.get_abstract_mesh()
+    manual = set(abstract.manual_axes)
     if manual:
         def strip(entry):
             if entry is None:

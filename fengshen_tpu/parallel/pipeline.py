@@ -26,9 +26,17 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from fengshen_tpu.compat import (axis_size as _axis_size,
-                                 pvary as _pvary, shard_map)
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
+
+
+def _pvary(x, axis_name):
+    """Mark `x` varying over `axis_name` for shard_map's vma type
+    system; a value that already varies is returned as it is (pcast
+    rejects re-application)."""
+    if axis_name in jax.typeof(x).vma:
+        return x
+    return jax.lax.pcast(x, axis_name, to="varying")
 
 
 def _pipeline_body(stage_params: Any, microbatches: jax.Array,
@@ -37,7 +45,7 @@ def _pipeline_body(stage_params: Any, microbatches: jax.Array,
     """shard_map body. stage_params: this stage's params (leading stage dim
     already split away by sharding). microbatches: [M, mb, ...] replicated.
     Returns [M, mb, ...] outputs valid on the LAST stage."""
-    n_stages = _axis_size(axis_name)
+    n_stages = jax.lax.axis_size(axis_name)
     stage_idx = jax.lax.axis_index(axis_name)
     is_first = stage_idx == 0
     is_last = stage_idx == n_stages - 1
@@ -128,7 +136,7 @@ def _1f1b_body(stage_params: Any, micro_inputs: jax.Array,
     m + 2S-1 - s. The backward recomputes the stage forward from the stored
     input (activation recompute, the standard TPU memory/flop trade).
     """
-    S = _axis_size(axis_name)
+    S = jax.lax.axis_size(axis_name)
     sid = jax.lax.axis_index(axis_name)
     is_first = sid == 0
     is_last = sid == S - 1

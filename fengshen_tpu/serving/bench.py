@@ -2,8 +2,8 @@
 legacy one-request-at-a-time path.
 
 Runs entirely offline (no HTTP) on whatever backend JAX picks — the
-`make serve-bench` target pins CPU so the number is reproducible in CI
-and BENCH rounds can track it without a healthy relay. Prints ONE JSON
+`make serve-bench` target pins CPU, where it checks the harness and
+the scheduler's counts (a CPU rate is not a device number). Prints ONE JSON
 line in the BENCH schema ({"metric", "value", "unit", "vs_baseline"},
 value = engine tokens/s, vs_baseline = speedup over sequential) plus
 ttft and config echo keys.

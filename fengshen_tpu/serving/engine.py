@@ -336,9 +336,9 @@ class ContinuousBatchingEngine:
             self._log = recorder.wrap_sink(self._log)
             recorder.attach("engine", self._debug_bundle)
         # THE loud kernel line (docs/kernels.md): state the dispatch
-        # decision for every registered kernel once at startup and set
-        # the fstpu_kernel_dispatch gauge — a fleet that silently
-        # degraded to the xla lowering must be visible to a scraper
+        # decision for every registered kernel at startup and set the
+        # fstpu_kernel_dispatch gauge — a fleet that is not running its
+        # kernels must be visible to a scraper
         log_dispatch(self._log)
         self.max_len = int(model.config.max_position_embeddings)
         self.paged = config.kv_layout == "paged"
@@ -1713,6 +1713,9 @@ class ContinuousBatchingEngine:
         if replay is not None:
             entry["aot_replayed"] = replay["replayed"]
         self._log(entry)
+        # every program is traced now: restate the dispatch with the
+        # choice each decode/prefill call site actually took
+        log_dispatch(self._log)
         return dt
 
     def _kv_stats_locked(self) -> dict:

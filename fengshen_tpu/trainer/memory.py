@@ -168,17 +168,12 @@ def probe_memory_capabilities(refresh: bool = False) -> MemoryCapabilities:
     if not refresh and cache_key in _PROBE_CACHE:
         return _PROBE_CACHE[cache_key]
     device = devices[0]
-    try:
-        default_kind = device.default_memory().kind
-    except Exception:  # noqa: BLE001 — older runtimes lack the API;
-        # "device" is the conventional default-space name there
-        default_kind = "device"
     caps = MemoryCapabilities(
         backend=jax.default_backend(),
         device_count=len(devices),
         supported={kind: _kind_supported(kind, device)
                    for kind in HOST_MEMORY_KINDS},
-        device_memory_kind=default_kind,
+        device_memory_kind=device.default_memory().kind,
         device_bytes=_device_budget_bytes(device),
         host_bytes=_host_ram_bytes(),
     )

@@ -20,12 +20,14 @@ import numpy as np
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from fengshen_tpu.compile_cache import ensure_compile_cache
 from fengshen_tpu.observability import (FlightRecorder, JsonlSink,
                                         StepStats, record_build_info,
                                         span)
 # re-exported for compatibility (the table moved to observability.flops,
 # the single home of the MFU accounting)
 from fengshen_tpu.observability.flops import PEAK_FLOPS  # noqa: F401
+from fengshen_tpu.ops.pallas import log_dispatch
 from fengshen_tpu.parallel.mesh import MeshConfig, make_mesh, set_mesh
 from fengshen_tpu.parallel.partition import make_shardings
 from fengshen_tpu.trainer.module import TrainModule
@@ -242,6 +244,7 @@ class Trainer:
     def __init__(self, args: Any, mesh_config: Optional[MeshConfig] = None,
                  logger: Optional[Any] = None):
         self.args = args
+        ensure_compile_cache()
         self.mesh_config = mesh_config or MeshConfig.from_argparse_args(args)
         self.mesh = make_mesh(self.mesh_config)
         set_mesh(self.mesh)
@@ -979,6 +982,7 @@ class Trainer:
         skips_credited = 0  # loader skips already folded into consumed
 
         epoch = 0
+        first_step = int(self.global_step)
         # a run restored at (or past) its step budget must not execute
         # even one group — the loop body only checks max_steps AFTER an
         # execution, which would overshoot the LR schedule
@@ -996,6 +1000,11 @@ class Trainer:
                 with span("train/step"):
                     state, metrics = step_fn(state, device_batch, rng)
                 prev_step = int(self.global_step)
+                if prev_step == first_step:
+                    # the first execution traced the step: say which
+                    # kernel each attention/CE call site took
+                    # (docs/kernels.md)
+                    log_dispatch(self._log)
                 self.global_step = prev_step + len(group)
                 # callbacks (e.g. every-n checkpointing) need the span
                 # of this execution to detect crossed boundaries

@@ -18,8 +18,15 @@ import os
 from typing import Any, Optional
 
 import jax
-import numpy as np
 import orbax.checkpoint as ocp
+
+
+#: Target size of one checkpoint data file. Orbax's own default is 2 GiB
+#: and a file may overshoot its target by one chunk, which a per-file
+#: size limit (`ulimit -f`, an object store's part size) refuses with
+#: EFBIG in the middle of a save. Arrays larger than this are written in
+#: chunks of at most this size, so no file exceeds twice it.
+DATA_FILE_BYTES = 128 * 2 ** 20
 
 
 class CheckpointStructureMismatch(ValueError):
@@ -100,23 +107,19 @@ class UniversalCheckpoint:
         meta = {"global_step": step,
                 "consumed_samples": int(trainer.consumed_samples),
                 "global_samples": int(trainer.consumed_samples)}
-        self._get_manager().save(
+        mgr.save(
             step, args=ocp.args.Composite(
-                state=ocp.args.StandardSave(payload),
+                state=ocp.args.PyTreeSave(
+                    payload, ocdbt_target_data_file_size=DATA_FILE_BYTES),
                 meta=ocp.args.JsonSave(meta)))
         if sync or not getattr(self.args, "async_save", False):
-            self._get_manager().wait_until_finished()
+            mgr.wait_until_finished()
             # verify the commit actually landed (orbax finalizes a step
             # by atomic rename): a save that silently failed must not
             # masquerade as a restore point while older steps get
             # pruned out from under it
-            mgr = self._get_manager()
-            if hasattr(mgr, "reload"):
-                mgr.reload()  # re-read the step list from disk
-                committed = mgr.all_steps()
-            else:  # pragma: no cover - pre-`reload` orbax
-                committed = mgr.all_steps(read=True)
-            if step not in committed:
+            mgr.reload()  # re-read the step list from disk
+            if step not in mgr.all_steps():
                 raise RuntimeError(
                     f"checkpoint step {step} did not commit under "
                     f"{self.save_path}")
@@ -147,7 +150,10 @@ class UniversalCheckpoint:
                 payload)
             return mgr.restore(
                 step, args=ocp.args.Composite(
-                    state=ocp.args.StandardRestore(abstract),
+                    state=ocp.args.PyTreeRestore(
+                        item=abstract,
+                        restore_args=ocp.checkpoint_utils
+                        .construct_restore_args(abstract)),
                     meta=ocp.args.JsonRestore()))
 
         if weights_only:
@@ -160,26 +166,13 @@ class UniversalCheckpoint:
                     sharding=getattr(x, "sharding", None)),
                 state.params)}
             try:
-                pytree_args = ocp.args.PyTreeRestore(
-                    item=abstract, partial_restore=True)
-            except TypeError:
-                # older orbax (<0.9) spells partial restore as empty
-                # `transforms` + per-leaf restore_args
-                def _rarg(x):
-                    sharding = getattr(x, "sharding", None)
-                    if sharding is not None:
-                        return ocp.ArrayRestoreArgs(
-                            sharding=sharding, global_shape=x.shape,
-                            dtype=x.dtype)
-                    return ocp.RestoreArgs()
-
-                pytree_args = ocp.args.PyTreeRestore(
-                    item=abstract, transforms={},
-                    restore_args=jax.tree_util.tree_map(_rarg, abstract))
-            try:
                 return mgr.restore(
                     step, args=ocp.args.Composite(
-                        state=pytree_args,
+                        state=ocp.args.PyTreeRestore(
+                            item=abstract,
+                            restore_args=ocp.checkpoint_utils
+                            .construct_restore_args(abstract),
+                            partial_restore=True),
                         meta=ocp.args.JsonRestore()))
             except ValueError as e:
                 # same classification as the full path: a wrong-model

@@ -592,27 +592,41 @@ def test_unpicklable_treedef_falls_back_to_flat_blob(tmp_path):
     assert _counts(reg, "fstpu_aot_cache_errors_total") == {}
 
 
-def test_failed_engine_warmup_still_starts_serve_loop(tiny, capsys):
-    """A warmup crash must not leave a replica that reports ready while
-    no serve loop drains its queue (every request would hang to its
-    full timeout): the gate opens AND the engine starts, so requests
-    compile lazily."""
+def test_failed_engine_warmup_keeps_the_gate_shut(tiny, capsys):
+    """A warmup that raises (a program that did not compile) must not
+    turn /healthz green: the ready event stays unset, /healthz keeps
+    answering 503 with the error, and the serve loop is not started —
+    serving on would re-raise the same failure one request at a time."""
     from fengshen_tpu.api.main import (PipelineConfig, ServerConfig,
-                                       _start_warmup_thread)
+                                       _start_warmup_thread,
+                                       build_stdlib_server)
     model, params = tiny
     eng = ContinuousBatchingEngine(
         model, params, EngineConfig(num_slots=1, buckets=(8,),
                                     max_new_tokens=4, max_queue=4))
     eng.warmup = lambda: (_ for _ in ()).throw(
-        RuntimeError("compile OOM"))
-    ready = _start_warmup_thread(
-        ServerConfig(engine="continuous"),
-        PipelineConfig(task="text_generation"), None, eng)
-    assert ready.wait(30)
+        RuntimeError("Mosaic failed to compile"))
+    server_cfg = ServerConfig(host="127.0.0.1", port=0,
+                              engine="continuous")
+    pipeline_cfg = PipelineConfig(task="text_generation")
+    ready = _start_warmup_thread(server_cfg, pipeline_cfg, None, eng)
+    assert ready.settled.wait(30)
+    assert not ready.is_set()
+    assert ready.error == "RuntimeError: Mosaic failed to compile"
+    assert eng._thread is None          # no serve loop behind the gate
+    server = build_stdlib_server(server_cfg, pipeline_cfg,
+                                 pipeline=_DummyPipeline(), engine=eng,
+                                 ready=ready)
+    thread = threading.Thread(target=server.serve_forever, daemon=True)
+    thread.start()
     try:
-        assert eng._thread is not None and eng._thread.is_alive()
-        req = eng.submit(np.asarray([5, 7], np.int32))
-        assert req.wait(60) and req.state == "finished"
+        code, body = _get(
+            "http://127.0.0.1:%d/healthz" % server.server_address[1])
+        assert code == 503 and body["ready"] is False
+        assert body["reason"] == "warmup_failed"
+        assert "Mosaic failed to compile" in body["error"]
     finally:
-        eng.stop()
+        server.shutdown()
+        server.server_close()
+        thread.join(timeout=10)
     assert "warmup failed" in capsys.readouterr().out

@@ -691,22 +691,6 @@ def test_trainer_rewind_dumps_postmortem(tmp_path):
 
 # ---- benchdiff ----------------------------------------------------------
 
-def test_benchdiff_classifies_repo_trajectory(capsys):
-    """`make benchdiff` over the checked-in BENCH_r01..r05 rounds:
-    deterministic classification, no crash on wedged (parsed: null)
-    rounds."""
-    from fengshen_tpu.observability import benchdiff
-
-    assert benchdiff.main(["--dir", REPO]) == 0
-    out1 = capsys.readouterr().out
-    assert benchdiff.main(["--dir", REPO]) == 0
-    out2 = capsys.readouterr().out
-    assert out1 == out2
-    assert "verdict:" in out1
-    for n in range(1, 6):
-        assert f"r{n:02d} " in out1
-
-
 def _write_round(directory, n, rows, rc=0, tail=""):
     payload = {"n": n, "cmd": "bench", "rc": rc, "tail": tail,
                "parsed": rows}
@@ -721,9 +705,7 @@ def test_benchdiff_flags_regressions(tmp_path):
     d = str(tmp_path)
     _write_round(d, 1, [{"metric": "tps", "value": 100.0,
                          "unit": "tok/s", "vs_baseline": 1.0}])
-    _write_round(d, 2, None, rc=1,
-                 tail="bench watchdog: accelerator unresponsive, "
-                      "aborting\n")
+    _write_round(d, 2, None, rc=1, tail="ValueError: broke\n")
     _write_round(d, 3, [{"metric": "tps", "value": 50.0,
                          "unit": "tok/s", "vs_baseline": 0.5},
                         {"metric": "mfu_row", "value": 0.5,
@@ -746,7 +728,7 @@ def test_benchdiff_flags_regressions(tmp_path):
     assert report["verdict"] == "REGRESSED"
     by_key = {(c["metric"], c["round"]): c
               for c in report["comparisons"]}
-    # r03 tps regressed vs r01 (the wedged r02 is skipped over)
+    # r03 tps regressed vs r01 (the failed r02 is skipped over)
     assert by_key[("tps", 3)]["status"] == "regression"
     assert by_key[("tps", 3)]["prev_round"] == 1
     assert by_key[("tps", 4)]["status"] == "flat"
@@ -756,7 +738,7 @@ def test_benchdiff_flags_regressions(tmp_path):
     # a move off a zero-valued metric is a change, never "flat +0%"
     assert by_key[("zero_row", 6)]["status"] == "improvement"
     assert by_key[("zero_row", 6)]["delta_pct"] is None
-    assert report["counts"] == {"ok": 5, "wedged": 1, "failed": 0}
+    assert report["counts"] == {"ok": 5, "failed": 1}
     # --strict exits 3 on REGRESSED
     assert benchdiff.main(["--dir", d, "--strict"]) == 3
     assert benchdiff.main(["--dir", d]) == 0

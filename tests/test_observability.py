@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 
 from fengshen_tpu.observability import (JsonlSink, MetricsRegistry,
-                                        NOMINAL_FALLBACK_FLOPS, PEAK_FLOPS,
+                                        CPU_NOMINAL_FLOPS, PEAK_FLOPS,
                                         StepStats, current_span_stack,
                                         estimate_flops_per_token,
                                         get_registry, peak_flops_per_chip,
@@ -193,28 +193,30 @@ def test_flops_estimator_hand_computed_llama_shape():
     assert estimate_flops_per_token(_Cfg(d_model=768)) is None
 
 
-def test_peak_flops_resolution(monkeypatch):
+def test_peak_flops_resolution():
     assert peak_flops_per_chip("TPU v5e") == PEAK_FLOPS["TPU v5e"]
-    assert peak_flops_per_chip("weird chip") == NOMINAL_FALLBACK_FLOPS
-    monkeypatch.setenv("FSTPU_PEAK_FLOPS", "2.5e13")
-    assert peak_flops_per_chip("TPU v5e") == 2.5e13
-    monkeypatch.setenv("FSTPU_PEAK_FLOPS", "-1")
-    with pytest.raises(ValueError):
-        peak_flops_per_chip()
+    assert peak_flops_per_chip("TPU v5 lite") == 197e12
+    # the CPU backend (CI) alone gets the nominal figure ...
+    assert peak_flops_per_chip("cpu") == CPU_NOMINAL_FLOPS
+    assert peak_flops_per_chip() == CPU_NOMINAL_FLOPS
+    # ... an accelerator the table does not know is an error, never a
+    # made-up peak under a made-up MFU
+    with pytest.raises(ValueError, match="weird chip"):
+        peak_flops_per_chip("weird chip")
 
 
 def test_stepstats_mfu_and_goodput():
     r = MetricsRegistry()
     clock = [0.0]
     stats = StepStats(flops_per_token=100.0, n_devices=2,
-                      device_kind="weird chip", registry=r,
+                      device_kind="cpu", registry=r,
                       clock=lambda: clock[0])
     stats.record_execution(n_steps=2, n_tokens=1000)
     clock[0] = 2.0
     entry = stats.window_entry(global_step=2, bad_step_count=0)
     assert entry["tokens_per_sec"] == 500.0
     assert entry["mfu"] == pytest.approx(
-        500.0 * 100.0 / (2 * NOMINAL_FALLBACK_FLOPS))
+        500.0 * 100.0 / (2 * CPU_NOMINAL_FLOPS))
     assert entry["goodput"] == 1.0
     # window resets: no tokens since -> 0 tps
     clock[0] = 3.0

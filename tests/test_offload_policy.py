@@ -37,15 +37,18 @@ def _fake_caps(pinned=True, unpinned=True, device_bytes=None,
 
 
 def test_probe_reports_this_backends_kinds():
-    caps = probe_memory_capabilities()
+    """The probe agrees with what the live device itself lists — not
+    with what one jax build's CPU backend happened to offer (0.4.37 had
+    no pinned_host; 0.9.0 has both host kinds)."""
+    caps = probe_memory_capabilities(refresh=True)
     assert caps.backend == "cpu"  # conftest pins the CPU mesh
-    # this jax build's CPU backend: only unpinned_host exists, and it
-    # is ALSO the device default (NOTES.md) — the exact environment
-    # that made the hard-coded pinned_host offload raise since seed
-    assert caps.supported["unpinned_host"] is True
-    assert caps.supported["pinned_host"] is False
-    assert caps.host_kind == "unpinned_host"
-    assert caps.device_memory_kind == "unpinned_host"
+    device = jax.devices()[0]
+    listed = {m.kind for m in device.addressable_memories()}
+    for kind in HOST_MEMORY_KINDS:
+        assert caps.supported[kind] is (kind in listed), (kind, listed)
+    assert caps.host_kind == next(
+        (k for k in HOST_MEMORY_KINDS if k in listed), None)
+    assert caps.device_memory_kind == device.default_memory().kind
     assert caps.device_bytes is None  # CPU reports no budget
     assert caps.host_bytes and caps.host_bytes > 0
 
@@ -284,10 +287,22 @@ def test_offload_opt_state_shardings_no_longer_raises():
     assert out.params["w"].memory_kind != "pinned_host"
 
 
-def test_offload_opt_state_shardings_rejects_unsupported_kind():
+def test_offload_opt_state_shardings_rejects_unsupported_kind(
+        monkeypatch):
+    """Forcing a kind the probe reports missing raises with the probe's
+    findings; a kind it reports present is taken."""
     from fengshen_tpu.trainer.train_state import \
         offload_opt_state_shardings
     mesh = Mesh(np.array(jax.devices()).reshape(-1), ("data",))
+    real = probe_memory_capabilities()
+    present = real.host_kind
+    placed = offload_opt_state_shardings(_tiny_sharding_state(mesh),
+                                         memory_kind=present)
+    assert {s.memory_kind for s in jax.tree_util.tree_leaves(
+        placed.opt_state)} == {present}
+    monkeypatch.setattr(
+        mem, "probe_memory_capabilities", lambda refresh=False:
+        _fake_caps(pinned=False, unpinned=True))
     with pytest.raises(ValueError, match="pinned_host"):
         offload_opt_state_shardings(_tiny_sharding_state(mesh),
                                     memory_kind="pinned_host")

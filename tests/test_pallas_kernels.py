@@ -129,9 +129,10 @@ def _stock_decode(q, k, v, valid, k_scale=None, v_scale=None,
     return dot_product_attention(q, k, v, mask=valid[:, None])
 
 
-def _decode_case(layout, quant, s, rng, batch=2, n_heads=4, kv_heads=2,
+def _decode_case(layout, quant, s, rng, batch=2, n_heads=16, kv_heads=8,
                  head_dim=128, block_size=128, blocks_per_lane=2):
-    """One (layout, dtype, spec_mode) decode combo's operands."""
+    """One (layout, dtype, spec_mode) decode combo's operands (8 KV
+    heads: the Mosaic kernel's fold needs a multiple of 8)."""
     virt = block_size * blocks_per_lane
     q = jnp.asarray(rng.randn(batch, s, n_heads, head_dim) * 0.3,
                     jnp.float32)
@@ -198,14 +199,25 @@ def test_pallas_decode_interpret_parity(layout, quant, s):
 
 def test_decode_dispatcher_eligibility():
     """Ineligible shapes (tiny pages, odd head_dim, prefill-length
-    windows) stay on the xla lowering instead of erroring."""
+    windows, KV heads the fold cannot tile) stay on the xla lowering
+    instead of erroring, and the seam records which way each went."""
+    from fengshen_tpu.ops.pallas import traced_dispatch
+
     rng = np.random.RandomState(7)
-    q = jnp.asarray(rng.randn(2, 1, 4, 64), jnp.float32)  # D=64
-    k = jnp.asarray(rng.randn(2, 256, 2, 64), jnp.float32)
+    q = jnp.asarray(rng.randn(2, 1, 8, 64), jnp.float32)  # D=64
+    k = jnp.asarray(rng.randn(2, 256, 8, 64), jnp.float32)
     assert not pallas_decode_eligible(q, k, k)
-    q2 = jnp.asarray(rng.randn(2, 16, 4, 128), jnp.float32)  # S=16
-    k2 = jnp.asarray(rng.randn(2, 256, 2, 128), jnp.float32)
+    q2 = jnp.asarray(rng.randn(2, 16, 8, 128), jnp.float32)  # S=16
+    k2 = jnp.asarray(rng.randn(2, 256, 8, 128), jnp.float32)
     assert not pallas_decode_eligible(q2, k2, k2)
+    q4 = jnp.asarray(rng.randn(2, 1, 4, 128), jnp.float32)  # KVH=2
+    k4 = jnp.asarray(rng.randn(2, 256, 2, 128), jnp.float32)
+    assert not pallas_decode_eligible(q4, k4, k4)
+    decode_attention(q4, k4, k4, jnp.ones((2, 1, 256), bool))
+    assert {"op": "decode_attention", "impl": "xla",
+            "detail": "q=(2, 1, 4, 128) kv=(2, 256, 2, 128):float32 "
+                      "slot (backend cannot run Mosaic)"} \
+        in traced_dispatch()
     # eligible shape, impl override pins each path explicitly
     q3, k3, v3, valid, kw = _decode_case("slot", False, 1,
                                          np.random.RandomState(8))
@@ -258,6 +270,41 @@ def test_block_sparse_orphan_interpret_parity():
                                rtol=2e-5, atol=2e-5)
 
 
+def test_run_per_shard_under_a_mesh(mesh8):
+    """GSPMD cannot partition a Mosaic call, so under a multi-device
+    mesh the attention kernels run inside a shard_map — batch over the
+    batch axes, heads over `tensor`, the sequence whole — and the
+    result is the unsharded one."""
+    from fengshen_tpu.ops.flash_attention import blockwise_attention
+    from fengshen_tpu.ops.pallas import run_per_shard
+
+    rng = np.random.RandomState(13)
+    q, k, v = (jnp.asarray(rng.randn(4, 64, 4, 32) * 0.3, jnp.float32)
+               for _ in range(3))
+    seg = jnp.asarray(rng.randint(1, 3, (4, 64)), jnp.int32)
+    seen = []
+
+    def kernel(q, k, v, seg):
+        seen.append((q.shape, seg.shape))
+        return blockwise_attention(q, k, v, causal=True,
+                                   q_segment_ids=seg, kv_segment_ids=seg)
+
+    out = jax.jit(lambda *a: run_per_shard(kernel, *a))(q, k, v, seg)
+    # data x fsdp = 4 ways over the batch, tensor = 2 ways over heads
+    assert seen == [((1, 64, 2, 32), (1, 64))]
+    from fengshen_tpu.parallel import set_mesh
+    set_mesh(None)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(kernel(q, k, v, seg)),
+                               rtol=1e-6, atol=1e-6)
+    set_mesh(mesh8)
+    # a batch the axes do not divide (the init pass) stays replicated
+    seen.clear()
+    jax.jit(lambda *a: run_per_shard(kernel, *a))(
+        q[:1], k[:1], v[:1], seg[:1])
+    assert seen == [((1, 64, 2, 32), (1, 64))]
+
+
 # -- fused CE -----------------------------------------------------------
 
 
@@ -285,6 +332,19 @@ def test_fused_ce_dispatch_is_stock_on_cpu():
     stock = fused_lm_head_ce(hidden, kernel, labels, num_chunks=4)
     for a, b in zip(seam, stock):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_fused_ce_stays_on_xla_under_a_mesh(mesh8):
+    """The Mosaic CE is not partitioned: under a multi-device mesh the
+    seam takes the xla lowering and says why."""
+    from fengshen_tpu.ops.pallas.fused_ce import (_ineligible_reason,
+                                                  pallas_ce_eligible)
+
+    hidden, kernel, _ = _ce_case(np.random.RandomState(14))
+    assert "8-device mesh" in _ineligible_reason(hidden, kernel)
+    from fengshen_tpu.parallel import set_mesh
+    set_mesh(None)
+    assert pallas_ce_eligible(hidden, kernel)
 
 
 def test_pallas_fused_ce_interpret_parity_and_grads():

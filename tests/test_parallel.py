@@ -30,6 +30,24 @@ def test_mesh_shapes():
 def test_mesh_build(mesh8):
     assert dict(mesh8.shape) == {"data": 2, "fsdp": 2, "expert": 1,
                                  "pipe": 1, "sequence": 1, "tensor": 2}
+    # Auto axes on both construction branches (jax.make_mesh alone
+    # would default to Explicit)
+    auto = (jax.sharding.AxisType.Auto,) * len(mesh8.axis_names)
+    assert mesh8.axis_types == auto
+    subset = make_mesh(MeshConfig(data=2, fsdp=1, tensor=2),
+                       devices=jax.devices()[:4])
+    assert subset.axis_types == auto
+
+
+def test_make_mesh_propagates_a_topology_error(monkeypatch):
+    """A topology jax.make_mesh rejects is an error; a naive reshape in
+    its place would ignore the ICI layout without saying so."""
+    def reject(*args, **kwargs):
+        raise ValueError("no mesh for this topology")
+
+    monkeypatch.setattr(jax, "make_mesh", reject)
+    with pytest.raises(ValueError, match="no mesh for this topology"):
+        make_mesh(MeshConfig(data=2, fsdp=2, tensor=2))
 
 
 def test_match_partition_rules():

@@ -77,8 +77,8 @@ def test_llama_streamed_step_matches_monolithic(scan):
     np.testing.assert_allclose(losses, ref_losses, atol=1e-5)
     # 5e-5, not the default 2e-5: the scan-layers variant reassociates
     # the per-layer grad reductions and this jax/CPU build lands one
-    # v_proj element at 2.16e-5 off after two adamw steps (NOTES.md
-    # tier-1 triage) — same math, looser float path
+    # v_proj element at 2.16e-5 off after two adamw steps — same math,
+    # looser float path
     _assert_tree_close(eng.params(), ref_params, atol=5e-5)
 
 

@@ -30,10 +30,10 @@ def test_quantize_roundtrip_error_small():
 
 
 def test_quantized_generation_matches_fp_greedy():
-    """Greedy decode with int8 weights, judged margin-aware (NOTES.md
-    triage item 2 — the old fixture xfailed at 0.8125 raw agreement
-    because random-init tiny-model logits sit in near-ties that int8
-    rounding legitimately flips).
+    """Greedy decode with int8 weights, judged margin-aware (the old
+    fixture xfailed at 0.8125 raw agreement because random-init
+    tiny-model logits sit in near-ties that int8 rounding legitimately
+    flips).
 
     The margin-aware bar: quantization noise must never flip a
     CONFIDENT decision. The lm_head is scaled up so top-2 logit gaps

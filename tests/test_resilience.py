@@ -582,7 +582,10 @@ def test_save_verifies_commit(tmp_path):
         def wait_until_finished(self):
             pass
 
-        def all_steps(self, read=False):
+        def reload(self):
+            pass
+
+        def all_steps(self):
             return []
 
     cb._manager = _Mgr()

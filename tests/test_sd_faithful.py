@@ -577,7 +577,7 @@ def test_sd_unet_sharded_matches_replicated(mesh8):
     without changing the math (the 860M Taiyi-SD finetune must shard on
     a pod, not replicate).
 
-    Formerly a non-strict xfail (seed NOTES.md item 3): the divergence
+    Formerly a non-strict xfail: the divergence
     was GSPMD back-propagating downstream weight shards onto the
     timestep sin|cos concat / up-block skip concats, whose dims then
     became sharded matmul contractions — mispartitioned on this XLA

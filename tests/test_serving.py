@@ -466,8 +466,10 @@ def test_warmup_pipeline_logs_seconds(tiny, capsys):
     def broken(text):
         raise RuntimeError("no params")
 
-    assert warmup_pipeline(broken, "t") is None
-    assert "warmup request failed" in capsys.readouterr().out
+    # a pipeline that cannot answer its warmup request cannot answer a
+    # user's either: the failure propagates (and keeps /healthz at 503)
+    with pytest.raises(RuntimeError, match="no params"):
+        warmup_pipeline(broken, "t")
 
 
 # ---- code-review hardening ----------------------------------------------

@@ -262,7 +262,7 @@ def test_transfo_xl_sharded_matches_replicated(mesh8):
     without changing the math (the import path for the published 1.1B
     checkpoints must run sharded on a pod).
 
-    Formerly a non-strict xfail (seed NOTES.md item 4): the fused qkv
+    Formerly a non-strict xfail: the fused qkv
     was innocent — the divergence was the `relative` projection's
     contraction dim sharded over the sin|cos positional concat (the
     concat-contraction mispartition, docs/sharding.md "Root cause").

@@ -83,6 +83,55 @@ def test_checkpoint_weights_only_restore_into_full_run(tmp_path, mesh8):
     chex.assert_trees_all_equal(restored.opt_state, fresh.opt_state)
 
 
+def test_checkpoint_files_stay_under_twice_the_target(tmp_path, mesh8,
+                                                     monkeypatch):
+    """No checkpoint file may outgrow 2 x DATA_FILE_BYTES: a machine with
+    a per-file size limit refuses a larger one with EFBIG mid-save. The
+    callback that saved then restores weights-only AND in full."""
+    import os
+
+    import optax
+    from fengshen_tpu.trainer.train_state import TrainState
+    from fengshen_tpu.utils import universal_checkpoint as uc
+
+    target = 64 * 1024
+    monkeypatch.setattr(uc, "DATA_FILE_BYTES", target)
+    # incompressible, and several times the target per array
+    keys = jax.random.split(jax.random.PRNGKey(0), 2)
+    params = {"w": jax.random.normal(keys[0], (256, 512)),
+              "v": jax.random.normal(keys[1], (128, 512))}
+    tx = optax.adamw(1e-3)
+    state = TrainState.create(apply_fn=lambda: None, params=params, tx=tx)
+    state = state.apply_gradients(grads=params)
+
+    parser = argparse.ArgumentParser()
+    uc.UniversalCheckpoint.add_argparse_args(parser)
+    args = parser.parse_args(["--save_ckpt_path", str(tmp_path / "ck"),
+                              "--load_ckpt_path", str(tmp_path / "ck")])
+
+    class FakeTrainer:
+        global_step = 2
+        consumed_samples = 20
+
+    cb = uc.UniversalCheckpoint(args)
+    cb.save(state, FakeTrainer())
+    sizes = [os.path.getsize(os.path.join(root, name))
+             for root, _, names in os.walk(tmp_path / "ck")
+             for name in names]
+    assert sum(sizes) > 8 * target        # the bound was exercised
+    assert max(sizes) <= 2 * target, sorted(sizes)[-3:]
+
+    fresh = TrainState.create(apply_fn=lambda: None,
+                              params=jax.tree_util.tree_map(
+                                  jnp.zeros_like, params), tx=tx)
+    weights = cb.maybe_restore(fresh, FakeTrainer(), weights_only=True)
+    np.testing.assert_array_equal(weights.params["w"], state.params["w"])
+    full = cb.maybe_restore(fresh, FakeTrainer())
+    np.testing.assert_array_equal(full.params["v"], state.params["v"])
+    chex = __import__("chex")
+    chex.assert_trees_all_equal(full.opt_state, state.opt_state)
+
+
 def test_checkpoint_missing_load_path_silently_skipped(tmp_path):
     import optax
     from fengshen_tpu.trainer.train_state import TrainState
