@@ -12,8 +12,12 @@ both).
 `phases()` derives the latency waterfall the debug endpoints and the
 `fstpu_request_phase_seconds{phase}` histograms expose:
 
-- ``queue_wait_s``: submit → prefill_start (admission wait + any paged
-  block-exhaustion deferral);
+- ``queue_wait_s``: submit → prefill_start (the wait for the
+  scheduler's lock + admission wait + any paged block-exhaustion
+  deferral);
+- ``lock_wait_s``: submit → enqueued, the part of queue_wait_s the
+  submitter spent waiting for the scheduler's lock (`enqueued` is
+  stamped once the lock is held);
 - ``prefill_s``: prefill_start → first_token (the bucketed prefill
   dispatch, i.e. TTFT minus queue wait);
 - ``decode_s``: first_token → terminal (the decode-tick share);
@@ -128,8 +132,11 @@ class RequestTimeline:
                 for _, name, attrs in self.events
                 if name == "commit")
         decode = max(end - ft, 0.0)
+        enqueued = self.mark("enqueued")
         return {
             "queue_wait_s": round(max(ps, 0.0), 6),
+            "lock_wait_s": round(min(max(enqueued or 0.0, 0.0),
+                                     max(ps, 0.0)), 6),
             "prefill_s": round(max(ft - ps, 0.0), 6),
             "decode_s": round(decode, 6),
             "decode_stall_s": round(max(decode - tick_s, 0.0), 6),

@@ -14,6 +14,11 @@ Spans nest: the recorded label is the "/"-joined stack ("fit/step"
 inside ``span("fit")`` + ``span("step")``), kept per-thread so the
 serving engine thread and the main thread never interleave stacks.
 
+Keyword attributes (``span("serving/prefill", bucket=512)``) go to the
+``TraceAnnotation`` only: the profiler stores them as the event's
+stats beside the plain name, and the histogram's label never carries
+them (a request id there would be one time series per request).
+
 The profiler hook degrades to timing-only when jax (or jax.profiler) is
 missing or broken — the registry side is pure stdlib.
 """
@@ -56,8 +61,9 @@ def current_span_stack() -> tuple:
 
 
 @contextmanager
-def span(name: str, registry: Optional[MetricsRegistry] = None):
-    """Time a section; annotate the profiler trace when available."""
+def span(name: str, registry: Optional[MetricsRegistry] = None, **attrs):
+    """Time a section; annotate the profiler trace when available.
+    `attrs` reach the trace event only, never the histogram's label."""
     stack = getattr(_local, "stack", None)
     if stack is None:
         stack = _local.stack = []
@@ -67,7 +73,7 @@ def span(name: str, registry: Optional[MetricsRegistry] = None):
     annotation = None
     if cls is not None:
         try:
-            annotation = cls(label)
+            annotation = cls(label, **attrs)
             annotation.__enter__()
         except Exception:  # noqa: BLE001 — profiler refused: time anyway
             annotation = None

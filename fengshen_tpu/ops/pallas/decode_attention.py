@@ -67,6 +67,11 @@ _MAX_QUERY_WINDOW = 8
 #: outgrow the 16 MiB default at LLaMA-13B's 40 heads x 128
 _VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
+#: the name both lowerings carry into HLO and the profiler's trace, so
+#: the operation is found by name whichever path ran (a flax scope and a
+#: compiler counter named it `self_attn.7` before)
+TRACE_NAME = "fstpu_decode_attention"
+
 #: tokens per grid step when a slot cache is viewed as blocks
 _SLOT_BLOCK = 128
 
@@ -107,9 +112,10 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             q, k, v, valid, k_scale=k_scale, v_scale=v_scale,
             block_table=block_table, dequant_dtype=dequant_dtype,
             interpret=interpret)
-    return xla_decode_attention(
-        q, k, v, valid, k_scale=k_scale, v_scale=v_scale,
-        block_table=block_table, dequant_dtype=dequant_dtype)
+    with jax.named_scope(TRACE_NAME):
+        return xla_decode_attention(
+            q, k, v, valid, k_scale=k_scale, v_scale=v_scale,
+            block_table=block_table, dequant_dtype=dequant_dtype)
 
 
 def _ineligible_reason(q, k, block_table) -> Optional[str]:
@@ -334,11 +340,12 @@ def pallas_decode_attention(q, k, v, valid, *, k_scale=None,
             pltpu.VMEM((s, n_heads, 1), jnp.float32),
         ],
     )
-    return pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
-        interpret=interpret,
-    )(block_table.astype(jnp.int32), *operands)
+    with jax.named_scope(TRACE_NAME):
+        return pl.pallas_call(
+            kernel, grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+                vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+            interpret=interpret, name=TRACE_NAME,
+        )(block_table.astype(jnp.int32), *operands)

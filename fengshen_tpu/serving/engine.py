@@ -974,10 +974,11 @@ class ContinuousBatchingEngine:
                 raise PromptTooLong(
                     f"request needs {need} KV blocks but the pool "
                     f"only has {self._allocator.total_blocks}")
-        now = self._clock()
+        submitted = self._clock()
         req = Request(ids, max_new, request_id,
-                      None if deadline_s is None else now + deadline_s,
-                      now, epoch=self._wall())
+                      None if deadline_s is None
+                      else submitted + deadline_s,
+                      submitted, epoch=self._wall())
         req.timeline.trace_id = trace_id
         req.timeline.parent_span_id = parent_span_id
         # resolve the per-request sampling seed: an explicit client
@@ -993,65 +994,82 @@ class ContinuousBatchingEngine:
             req.resume = resume
             req.resume_source = resume_source
             req.tokens = list(resume)
-        with span("serving/admit"), self._cv:
-            if self._draining:
-                self.metrics.count("rejected_draining")
-                self._log({"event": "serving_reject",
-                           "reason": "draining"})
-                raise Draining("engine is draining; not admitting")
-            if request_id is not None:
-                # idempotent-safe retry contract (docs/fleet.md): an
-                # explicit id may never run twice concurrently here —
-                # a router retrying a request this replica may still
-                # be executing must be REJECTED, not doubled. (No
-                # debug-ring entry: the ORIGINAL request owns the id
-                # there; the counter + log line carry the 409s.)
-                for live in list(self._queue) + [
-                        r for r in self._slot_req if r is not None]:
-                    if live.request_id == request_id:
-                        self.metrics.count("rejected_duplicate")
-                        self._log({"event": "serving_reject",
-                                   "reason": "duplicate_request_id",
-                                   "request_id": request_id,
-                                   "live_state": live.state})
-                        raise DuplicateRequest(
-                            f"request_id {request_id!r} is already "
-                            f"{live.state} on this replica")
-            if len(self._queue) >= self.config.max_queue:
-                self.metrics.count("rejected_queue_full")
-                self._log({"event": "serving_reject",
-                           "reason": "queue_full",
-                           "queue_depth": len(self._queue)})
-                # rejected timelines join the debug ring: "who was 429'd
-                # and when" is exactly the overload question
-                self._record_rejection_locked(
-                    req, "queue_full", queue_depth=len(self._queue))
-                raise QueueFull(
-                    f"admission queue at max_queue="
-                    f"{self.config.max_queue}")
-            self._queue.append(req)
-            req.timeline.add(now, "enqueued",
-                             prompt_tokens=int(len(ids)), bucket=bucket,
-                             queue_depth=len(self._queue))
-            if resume:
-                # the initial resume mark (the `evacuated` event's
-                # cross-replica counterpart): where the committed
-                # prefix came from and how long it is
-                req.timeline.add(now, "resumed_from",
-                                 tokens=len(resume),
-                                 source=resume_source)
-            self._journal_add_locked(req)
-            if stream:
-                # open the live stream BEFORE any token can commit so
-                # the reader never misses the head; open() replays
-                # req.tokens, so a resumed stream starts at k, not 0
-                self.streams.open(req)
-            self.metrics.count("admitted")
-            self._log({"event": "serving_admit",
-                       "request_id": req.request_id, "bucket": bucket,
-                       "queue_depth": len(self._queue)})
-            self._cv.notify_all()
+        with span("serving/admit"):
+            with span("lock_wait"):
+                self._cv.acquire()
+            try:
+                self._enqueue_locked(req, bucket, request_id is not None,
+                                     stream)
+            finally:
+                self._cv.release()
         return req
+
+    def _enqueue_locked(self, req: Request, bucket: int,
+                        explicit_id: bool, stream: bool) -> None:
+        """submit()'s part under the scheduler's lock: refuse (draining,
+        a live duplicate of an explicit id, a full queue) or queue."""
+        # the clock is read AFTER the lock is held: `enqueued` minus
+        # submit is the wait for the scheduler's lock, which
+        # `phases()` reports as lock_wait_s
+        now = self._clock()
+        self.metrics.record_submit_lock_wait(now - req.submit_time)
+        if self._draining:
+            self.metrics.count("rejected_draining")
+            self._log({"event": "serving_reject",
+                       "reason": "draining"})
+            raise Draining("engine is draining; not admitting")
+        if explicit_id:
+            # idempotent-safe retry contract (docs/fleet.md): an
+            # explicit id may never run twice concurrently here —
+            # a router retrying a request this replica may still
+            # be executing must be REJECTED, not doubled. (No
+            # debug-ring entry: the ORIGINAL request owns the id
+            # there; the counter + log line carry the 409s.)
+            for live in list(self._queue) + [
+                    r for r in self._slot_req if r is not None]:
+                if live.request_id == req.request_id:
+                    self.metrics.count("rejected_duplicate")
+                    self._log({"event": "serving_reject",
+                               "reason": "duplicate_request_id",
+                               "request_id": req.request_id,
+                               "live_state": live.state})
+                    raise DuplicateRequest(
+                        f"request_id {req.request_id!r} is already "
+                        f"{live.state} on this replica")
+        if len(self._queue) >= self.config.max_queue:
+            self.metrics.count("rejected_queue_full")
+            self._log({"event": "serving_reject",
+                       "reason": "queue_full",
+                       "queue_depth": len(self._queue)})
+            # rejected timelines join the debug ring: "who was 429'd
+            # and when" is exactly the overload question
+            self._record_rejection_locked(
+                req, "queue_full", queue_depth=len(self._queue))
+            raise QueueFull(
+                f"admission queue at max_queue="
+                f"{self.config.max_queue}")
+        self._queue.append(req)
+        req.timeline.add(now, "enqueued",
+                         prompt_tokens=int(len(req.prompt)), bucket=bucket,
+                         queue_depth=len(self._queue))
+        if req.resume:
+            # the initial resume mark (the `evacuated` event's
+            # cross-replica counterpart): where the committed
+            # prefix came from and how long it is
+            req.timeline.add(now, "resumed_from",
+                             tokens=len(req.resume),
+                             source=req.resume_source)
+        self._journal_add_locked(req)
+        if stream:
+            # open the live stream BEFORE any token can commit so
+            # the reader never misses the head; open() replays
+            # req.tokens, so a resumed stream starts at k, not 0
+            self.streams.open(req)
+        self.metrics.count("admitted")
+        self._log({"event": "serving_admit",
+                   "request_id": req.request_id, "bucket": bucket,
+                   "queue_depth": len(self._queue)})
+        self._cv.notify_all()
 
     def cancel(self, request_id: str) -> bool:
         """Cancel a queued or running request; a running one frees its
@@ -1073,11 +1091,17 @@ class ContinuousBatchingEngine:
     def step(self) -> int:
         """One tick: reclaim → admit → one jitted decode over the pool.
         Returns the number of lanes still active after the tick."""
-        with self._cv:
+        # the wait for the lock has a span of its own, so a trace tells
+        # a scheduler starved by submitters from one at work
+        with span("serving/lock_wait"):
+            self._cv.acquire()
+        try:
             # the tick IS the critical section: the scheduler owns all
             # device state under _cv by design; admission threads wait
             # at most one tick (docs/serving.md "Threading")
-            return self._step_locked()  # fslint: disable=blocking-under-lock; deliberate scheduler design
+            return self._step_locked()
+        finally:
+            self._cv.release()
 
     def _step_locked(self) -> int:
         now = self._clock()
@@ -1099,93 +1123,122 @@ class ContinuousBatchingEngine:
         active_idx = np.nonzero(self._active)[0]
         if len(active_idx) == 0:
             return 0
+        lanes = len(active_idx)
+        # real cached tokens this tick's attention reads: the logical
+        # cursor, not `_phys`, which counts the bucket's padding
+        kv_tokens = int(self._pos[active_idx].sum()) + lanes
         t0 = time.perf_counter()
         if self.spec:
             with span("serving/decode"):
-                if self.self_draft:
-                    (self._cache, self._draft_cache, self._history,
-                     self._keys, n_r, win) = self._decode_jit(
-                        self.params, self._draft_params, self._cache,
-                        self._draft_cache, self._history, self._mask,
-                        self._last_tok, self._pos, self._phys,
-                        self._active, self._keys)
-                else:
-                    (self._cache, self._history, self._keys, n_r,
-                     win) = self._decode_jit(
-                        self.params, self._cache, self._history,
-                        self._mask, self._last_tok, self._pos,
-                        self._phys, self._active, self._keys)
+                # dispatch: the four host cursors are uploaded and the
+                # program enqueued; fetch: the wait for the device and
+                # the copy back
+                with span("dispatch", lanes=lanes):
+                    if self.self_draft:
+                        (self._cache, self._draft_cache, self._history,
+                         self._keys, n_r, win) = self._decode_jit(
+                            self.params, self._draft_params, self._cache,
+                            self._draft_cache, self._history, self._mask,
+                            self._last_tok, self._pos, self._phys,
+                            self._active, self._keys)
+                    else:
+                        (self._cache, self._history, self._keys, n_r,
+                         win) = self._decode_jit(
+                            self.params, self._cache, self._history,
+                            self._mask, self._last_tok, self._pos,
+                            self._phys, self._active, self._keys)
                 # host sync: the scheduler needs the accept counts and
                 # the committed window (copies — the device views are
                 # read-only and lanes are overwritten on admission)
-                n_r = np.array(n_r)
-                win = np.array(win)
+                with span("fetch"):
+                    n_r = np.array(n_r)
+                    win = np.array(win)
             dt = time.perf_counter() - t0
             # per-lane commit: accepted prefix + the correction token,
             # so each lane's cursor advances INDEPENDENTLY (the whole
             # point over generate's batched min-advance)
             commit = np.where(self._active, n_r + 1, 0)
-            last = win[np.arange(win.shape[0]),
-                       np.maximum(commit - 1, 0)]
-            self._last_tok = np.where(self._active, last,
-                                      self.config.pad_token_id
-                                      ).astype(np.int32)
-            self._pos = (self._pos + commit).astype(np.int32)
-            self._phys = (self._phys + commit).astype(np.int32)
-            # metrics count DELIVERED tokens, not the raw window: a
-            # lane finishing mid-window (eos, or the max_new cap)
-            # discards the tail, and counting it would inflate
-            # decode_tokens and the acceptance rate the bench's
-            # committed-per-forward headline is derived from
-            delivered = 0
-            accepted_delivered = 0
-            t_commit = self._clock()
-            for i in active_idx:
-                req = self._slot_req[i]
-                k = 0
-                fin = None
-                for tok in (int(t) for t in win[i, :commit[i]]):
-                    req.tokens.append(tok)
-                    k += 1
-                    if self.config.eos_token_id is not None and \
-                            tok == self.config.eos_token_id:
-                        fin = "eos"
-                        break
-                    if len(req.tokens) >= req.max_new_tokens:
-                        fin = "length"
-                        break
-                # the commit event must precede a release: _finish
-                # snapshots the timeline into the debug ring
-                req.timeline.add(t_commit, "commit", n=k,
-                                 accepted=min(int(n_r[i]), k),
-                                 tick_s=round(dt, 6))
-                self._sync_stream(req)
-                if fin is not None:
-                    self._release(i, FINISHED, fin)
-                delivered += k
-                # delivered tokens at offsets < n_r are accepted
-                # drafts; the one at offset n_r is the correction
-                accepted_delivered += min(int(n_r[i]), k)
-            self.metrics.record_tick(len(active_idx),
-                                     self.config.num_slots, dt,
-                                     tokens=delivered)
-            self.metrics.record_spec(
-                self.config.spec_gamma * len(active_idx),
-                accepted_delivered)
-            return int(self._active.sum())
+            with span("serving/commit", lanes=lanes,
+                      tokens=int(commit.sum())):
+                return self._commit_spec(active_idx, n_r, win, commit,
+                                         dt, kv_tokens)
         with span("serving/decode"):
-            self._cache, self._history, self._keys, nxt = \
-                self._decode_jit(
-                    self.params, self._cache, self._history, self._mask,
-                    self._last_tok, self._pos, self._phys, self._active,
-                    self._keys)
+            with span("dispatch", lanes=lanes):
+                self._cache, self._history, self._keys, nxt = \
+                    self._decode_jit(
+                        self.params, self._cache, self._history,
+                        self._mask, self._last_tok, self._pos,
+                        self._phys, self._active, self._keys)
             # host sync: the scheduler needs the tokens (copy — the
             # device view is read-only and lanes are overwritten on
             # admission)
-            nxt = np.array(nxt)
+            with span("fetch"):
+                nxt = np.array(nxt)
         dt = time.perf_counter() - t0
+        with span("serving/commit", lanes=lanes, tokens=lanes):
+            return self._commit_plain(active_idx, nxt, dt, kv_tokens)
+
+    def _commit_spec(self, active_idx, n_r, win, commit, dt: float,
+                     kv_tokens: int) -> int:
+        """Host side of a speculative tick after the fetch: cursors,
+        token append, timeline, stream, release. Returns the lanes
+        still active."""
+        last = win[np.arange(win.shape[0]),
+                   np.maximum(commit - 1, 0)]
+        self._last_tok = np.where(self._active, last,
+                                  self.config.pad_token_id
+                                  ).astype(np.int32)
+        self._pos = (self._pos + commit).astype(np.int32)
+        self._phys = (self._phys + commit).astype(np.int32)
+        # metrics count DELIVERED tokens, not the raw window: a
+        # lane finishing mid-window (eos, or the max_new cap)
+        # discards the tail, and counting it would inflate
+        # decode_tokens and the acceptance rate the bench's
+        # committed-per-forward headline is derived from
+        delivered = 0
+        accepted_delivered = 0
+        t_commit = self._clock()
+        for i in active_idx:
+            req = self._slot_req[i]
+            k = 0
+            fin = None
+            for tok in (int(t) for t in win[i, :commit[i]]):
+                req.tokens.append(tok)
+                k += 1
+                if self.config.eos_token_id is not None and \
+                        tok == self.config.eos_token_id:
+                    fin = "eos"
+                    break
+                if len(req.tokens) >= req.max_new_tokens:
+                    fin = "length"
+                    break
+            # the commit event must precede a release: _finish
+            # snapshots the timeline into the debug ring
+            req.timeline.add(t_commit, "commit", n=k,
+                             accepted=min(int(n_r[i]), k),
+                             tick_s=round(dt, 6))
+            self._sync_stream(req)
+            if fin is not None:
+                self._release(i, FINISHED, fin)
+            delivered += k
+            # delivered tokens at offsets < n_r are accepted
+            # drafts; the one at offset n_r is the correction
+            accepted_delivered += min(int(n_r[i]), k)
+        self.metrics.record_tick(len(active_idx),
+                                 self.config.num_slots, dt,
+                                 tokens=delivered, kv_tokens=kv_tokens)
+        self.metrics.record_spec(
+            self.config.spec_gamma * len(active_idx),
+            accepted_delivered)
+        return int(self._active.sum())
+
+    def _commit_plain(self, active_idx, nxt, dt: float,
+                      kv_tokens: int) -> int:
+        """Host side of a plain tick after the fetch: cursors, token
+        append, timeline, stream, release. Returns the lanes still
+        active."""
         self.metrics.record_tick(len(active_idx), self.config.num_slots,
-                                 dt)
+                                 dt, kv_tokens=kv_tokens)
         self._last_tok = nxt
         self._pos[self._active] += 1
         self._phys[self._active] += 1
@@ -1278,7 +1331,9 @@ class ContinuousBatchingEngine:
                                  bucket=int(bucket))
                 req.timeline.add(self._clock(), "prefill_start",
                                  bucket=int(bucket))
-                with span("serving/prefill"):
+                with span("serving/prefill", request_id=req.request_id,
+                          bucket=int(bucket),
+                          prompt_tokens=int(len(prefill_ids))):
                     if self.self_draft:
                         primed, d_primed, tok = self._prefill_jit(
                             self.params, self._draft_params, row[None],
@@ -1287,7 +1342,7 @@ class ContinuousBatchingEngine:
                         primed, tok = self._prefill_jit(
                             self.params, row[None], mask_row[None], key)
                     tok = int(np.asarray(tok)[0])
-                self.metrics.record_prefill(bucket)
+                self.metrics.record_prefill(bucket, len(prefill_ids))
                 t_first = self._clock()
                 req.ttft_s = t_first - req.submit_time
                 self.metrics.record_ttft(req.ttft_s)
@@ -1315,65 +1370,75 @@ class ContinuousBatchingEngine:
                         blocks = None
                     self._finish(req, FINISHED, "length")
                     continue
-                # history/mask lanes: padded prompt, mask open from
-                # the bucket edge on (causal validity bounds the open
-                # tail)
-                L = self.seq_capacity
-                hist_row = np.zeros((L,), np.int32)
-                hist_row[:bucket] = row
-                full_mask = np.ones((L,), np.int32)
-                full_mask[:bucket] = mask_row
-                if self.paged:
-                    table_row = np.zeros((self.max_blocks_per_slot,),
-                                         np.int32)
-                    table_row[:len(blocks)] = blocks
             except BaseException:  # noqa: BLE001 — release + re-raise
                 # a failed prefill must not strand the request's KV
                 # blocks: return them to the pool before propagating
                 if blocks is not None:
                     self._allocator.free(blocks)
                 raise
-            if self.self_draft:
-                if self.paged:
-                    self._slot_blocks[slot] = blocks
-                    (self._cache, self._draft_cache, self._history,
-                     self._mask) = self._assign_jit(
-                        self._cache, self._draft_cache, self._history,
-                        self._mask, primed, d_primed, hist_row,
-                        full_mask, table_row, np.int32(slot))
-                else:
-                    (self._cache, self._draft_cache, self._history,
-                     self._mask) = self._assign_jit(
-                        self._cache, self._draft_cache, self._history,
-                        self._mask, primed, d_primed, hist_row,
-                        full_mask, np.int32(slot))
-            elif self.paged:
-                self._slot_blocks[slot] = blocks
-                self._cache, self._history, self._mask = \
-                    self._assign_jit(self._cache, self._history,
-                                     self._mask, primed, hist_row,
-                                     full_mask, table_row,
-                                     np.int32(slot))
-            else:
-                self._cache, self._history, self._mask = \
-                    self._assign_jit(self._cache, self._history,
-                                     self._mask, primed, hist_row,
-                                     full_mask, np.int32(slot))
-            req.state = RUNNING
-            req.slot = slot
-            self._slot_req[slot] = req
-            self._active[slot] = True
-            self._last_tok[slot] = tok
-            # logical pos of last_tok: len(prompt) for a fresh lane
-            # (tokens == [tok]); a resumed lane holds k committed
-            # tokens, the same invariant pos = P + len(tokens) - 1
-            self._pos[slot] = len(req.prompt) + len(req.tokens) - 1
-            self._phys[slot] = bucket           # physical cursor
-            if self.config.do_sample:
-                # install the lane's ring entry; greedy engines keep
-                # the zero ring and never consume it
-                self._keys = self._keys.at[slot].set(lane_key)
+            if self.paged:
+                self._slot_blocks[slot] = blocks    # the lane owns them
+            with span("serving/assign", request_id=req.request_id,
+                      slot=slot):
+                self._assign(req, slot, bucket, row, mask_row, primed,
+                             d_primed if self.self_draft else None, tok,
+                             lane_key)
         return
+
+    def _assign(self, req: Request, slot: int, bucket: int, row,
+                mask_row, primed, d_primed, tok: int, lane_key) -> None:
+        """Install a prefilled request in lane `slot`: the lane's
+        history / mask / block-table rows, the assign program's
+        dispatch, and the host cursors."""
+        # history/mask lanes: padded prompt, mask open from the bucket
+        # edge on (causal validity bounds the open tail)
+        L = self.seq_capacity
+        hist_row = np.zeros((L,), np.int32)
+        hist_row[:bucket] = row
+        full_mask = np.ones((L,), np.int32)
+        full_mask[:bucket] = mask_row
+        if self.paged:
+            blocks = self._slot_blocks[slot]
+            table_row = np.zeros((self.max_blocks_per_slot,), np.int32)
+            table_row[:len(blocks)] = blocks
+        if self.self_draft:
+            if self.paged:
+                (self._cache, self._draft_cache, self._history,
+                 self._mask) = self._assign_jit(
+                    self._cache, self._draft_cache, self._history,
+                    self._mask, primed, d_primed, hist_row,
+                    full_mask, table_row, np.int32(slot))
+            else:
+                (self._cache, self._draft_cache, self._history,
+                 self._mask) = self._assign_jit(
+                    self._cache, self._draft_cache, self._history,
+                    self._mask, primed, d_primed, hist_row,
+                    full_mask, np.int32(slot))
+        elif self.paged:
+            self._cache, self._history, self._mask = \
+                self._assign_jit(self._cache, self._history,
+                                 self._mask, primed, hist_row,
+                                 full_mask, table_row,
+                                 np.int32(slot))
+        else:
+            self._cache, self._history, self._mask = \
+                self._assign_jit(self._cache, self._history,
+                                 self._mask, primed, hist_row,
+                                 full_mask, np.int32(slot))
+        req.state = RUNNING
+        req.slot = slot
+        self._slot_req[slot] = req
+        self._active[slot] = True
+        self._last_tok[slot] = tok
+        # logical pos of last_tok: len(prompt) for a fresh lane
+        # (tokens == [tok]); a resumed lane holds k committed
+        # tokens, the same invariant pos = P + len(tokens) - 1
+        self._pos[slot] = len(req.prompt) + len(req.tokens) - 1
+        self._phys[slot] = bucket           # physical cursor
+        if self.config.do_sample:
+            # install the lane's ring entry; greedy engines keep
+            # the zero ring and never consume it
+            self._keys = self._keys.at[slot].set(lane_key)
 
     def _release(self, slot: int, state: str, reason: str) -> None:
         req = self._slot_req[slot]
@@ -1491,7 +1556,8 @@ class ContinuousBatchingEngine:
             if n == 0:
                 with self._cv:
                     if not self._queue and not self._stop_flag:
-                        self._cv.wait(timeout=0.02)
+                        with span("serving/idle_wait"):
+                            self._cv.wait(timeout=0.02)
 
     def _reset_pool_locked(self) -> None:
         """Fail every queued/running request and rebuild the slot pool

@@ -70,7 +70,22 @@ class EngineMetrics:
             "tokens generated by decode ticks")
         self._decode_time = r.counter(
             "fstpu_serving_decode_seconds_total",
-            "wall seconds inside the jitted decode dispatch")
+            "wall seconds of the decode ticks: dispatch, the device's "
+            "run and the fetch of the tokens")
+        self._prefill_tokens = r.counter(
+            "fstpu_serving_prefill_tokens_total",
+            "real prompt tokens prefilled (a resumed request's "
+            "committed prefix in)")
+        self._prefill_padded = r.counter(
+            "fstpu_serving_prefill_padded_tokens_total",
+            "bucket widths prefilled: what the device paid for")
+        self._kv_attended = r.counter(
+            "fstpu_serving_kv_tokens_attended_total",
+            "per-tick sum over active lanes of the real cached tokens "
+            "the tick's attention reads (bucket padding out)")
+        self._submit_lock_wait = r.histogram(
+            "fstpu_serving_submit_lock_wait_seconds",
+            "submit()'s wait for the scheduler's lock", window=window)
         self._occupied_ticks = r.counter(
             "fstpu_serving_occupied_slot_ticks_total",
             "per-tick sum of active lanes")
@@ -137,17 +152,24 @@ class EngineMetrics:
     def count(self, field: str, n: int = 1) -> None:
         self._counters[field].inc(n)
 
-    def record_prefill(self, bucket: int) -> None:
+    def record_prefill(self, bucket: int, prompt_tokens: int) -> None:
         self._prefills.labels(bucket).inc()
+        self._prefill_tokens.inc(prompt_tokens)
+        self._prefill_padded.inc(bucket)
+
+    def record_submit_lock_wait(self, seconds: float) -> None:
+        self._submit_lock_wait.observe(seconds)
 
     def record_tick(self, n_active: int, num_slots: int,
-                    seconds: float,
-                    tokens: Optional[int] = None) -> None:
+                    seconds: float, tokens: Optional[int] = None,
+                    kv_tokens: int = 0) -> None:
         """`tokens` defaults to `n_active` (one committed token per
         active lane — the plain decode tick); the speculative tick
         passes the real committed count, which exceeds the lane count
-        whenever draft proposals were accepted."""
+        whenever draft proposals were accepted. `kv_tokens`: the real
+        cached tokens this tick's attention read, over all lanes."""
         self._decode_ticks.inc()
+        self._kv_attended.inc(kv_tokens)
         self._decode_tokens.inc(n_active if tokens is None else tokens)
         self._decode_time.inc(seconds)
         self._occupied_ticks.inc(n_active)

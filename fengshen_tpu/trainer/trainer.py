@@ -1024,26 +1024,40 @@ class Trainer:
                                     for b in group))
 
                 if crossed(prev_step, self.global_step, log_every):
-                    metrics = {k: float(v) for k, v in metrics.items()}
-                    entry = {"step": self.global_step,
-                             "lr": float(self._schedule(self.global_step)),
-                             "consumed_samples": self.consumed_samples,
-                             **metrics}
-                    # tokens_per_sec / mfu / goodput over the window
-                    # since the last entry; closes the window
-                    entry.update(self._stepstats.window_entry(
-                        self.global_step,
-                        bad_step_count=int(
-                            metrics.get("bad_step_count", 0))))
-                    self._log(entry)
+                    # float(v) waits for the step just dispatched: the
+                    # device idles from that step's end until the next
+                    # dispatch, and this span names the gap
+                    with span("train/log", step=self.global_step):
+                        metrics = {k: float(v)
+                                   for k, v in metrics.items()}
+                        entry = {"step": self.global_step,
+                                 "lr": float(
+                                     self._schedule(self.global_step)),
+                                 "consumed_samples": self.consumed_samples,
+                                 **metrics}
+                        # tokens_per_sec / mfu / goodput over the window
+                        # since the last entry; closes the window
+                        entry.update(self._stepstats.window_entry(
+                            self.global_step,
+                            bad_step_count=int(
+                                metrics.get("bad_step_count", 0))))
+                        self._log(entry)
 
                 if crossed(prev_step, self.global_step, val_interval):
-                    self._run_validation(module, datamodule, state, rng)
+                    with span("train/validate"):
+                        self._run_validation(module, datamodule, state,
+                                             rng)
                 for cb in self.callbacks:
                     if hasattr(cb, "on_train_step_end"):
-                        # every-n checkpointing lives here; the span
-                        # makes save stalls visible next to step time
-                        with span("train/checkpoint"):
+                        # `train/checkpoint` only where a save happens,
+                        # so save stalls stand next to step time under
+                        # their own name; any other callback's time is
+                        # `train/callback`
+                        due = getattr(cb, "save_due", None)
+                        name = "train/checkpoint" \
+                            if due is not None and due(self) \
+                            else "train/callback"
+                        with span(name, callback=type(cb).__name__):
                             cb.on_train_step_end(self, state)
                 if max_consec:
                     bad_total = int(metrics["bad_step_count"])
@@ -1115,7 +1129,8 @@ class Trainer:
                     epoch >= max(getattr(args, "max_epochs", 1), 1):
                 done = True
             if not val_interval:
-                self._run_validation(module, datamodule, state, rng)
+                with span("train/validate"):
+                    self._run_validation(module, datamodule, state, rng)
 
         if profile_range is not None and getattr(self, "_profiling", False):
             jax.profiler.stop_trace()

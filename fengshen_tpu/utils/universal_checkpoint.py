@@ -312,16 +312,22 @@ class UniversalCheckpoint:
         return new
 
     # -- trainer hooks --------------------------------------------------------
-    def on_train_step_end(self, trainer: Any, state: Any) -> None:
+    def save_due(self, trainer: Any) -> bool:
+        """Whether this execution crossed an every-n boundary (the
+        Trainer also asks, to name the span `train/checkpoint` only
+        where a save happens)."""
         if not self.every_n_train_steps:
-            return
+            return False
         # boundary-CROSSING, not equality: under --steps_per_execution K
         # global_step advances K at a time and can jump over the exact
         # multiple (trainer sets prev_global_step per execution)
         prev = int(getattr(trainer, "prev_global_step",
                            trainer.global_step - 1))
-        if (trainer.global_step // self.every_n_train_steps) > \
-                (prev // self.every_n_train_steps):
+        return (trainer.global_step // self.every_n_train_steps) > \
+            (prev // self.every_n_train_steps)
+
+    def on_train_step_end(self, trainer: Any, state: Any) -> None:
+        if self.save_due(trainer):
             self.save(state, trainer)
 
     def on_fit_end(self, trainer: Any, state: Any) -> None:

@@ -106,7 +106,8 @@ def test_timeline_phases_and_event_cap():
     tl.add(103.0, "finished", reason="length")
     assert [e[1] for e in tl.events][-1] == "finished"
     ph = tl.phases(now=999.0)                   # terminal wins over now
-    assert ph == {"queue_wait_s": 0.5, "prefill_s": 0.5,
+    assert ph == {"queue_wait_s": 0.5, "lock_wait_s": 0.0,
+                  "prefill_s": 0.5,
                   "decode_s": 2.0, "decode_stall_s": 1.5,
                   "total_s": 3.0}
     # a terminal event pins the end regardless of `now`
@@ -117,6 +118,20 @@ def test_timeline_phases_and_event_cap():
     assert ph2["total_s"] == 1.0
     assert ph2["queue_wait_s"] == 1.0      # never admitted: all wait
     assert ph2["prefill_s"] == 0.0 and ph2["decode_s"] == 0.0
+    # `enqueued` is stamped once the scheduler's lock is held: submit
+    # to enqueued is lock_wait_s, a PART of queue_wait_s (the three
+    # telescoping phases do not change)
+    tl3 = RequestTimeline(t0=10.0)
+    tl3.add(10.4, "enqueued")
+    tl3.add(11.0, "prefill_start")
+    tl3.add(11.5, "first_token")
+    tl3.add(12.0, "finished", reason="length")
+    ph3 = tl3.phases()
+    assert ph3["lock_wait_s"] == 0.4 and ph3["queue_wait_s"] == 1.0
+    assert ph3["queue_wait_s"] + ph3["prefill_s"] + ph3["decode_s"] == \
+        ph3["total_s"] == 2.0
+    # a request rejected while it still waited has no `enqueued` mark
+    assert RequestTimeline(t0=0.0).phases(now=3.0)["lock_wait_s"] == 0.0
 
 
 # ---- engine waterfall + parity (the tentpole contract) ------------------

@@ -27,6 +27,16 @@ from fengshen_tpu.observability import (JsonlSink, MetricsRegistry,
                                         span, start_metrics_server)
 
 
+@pytest.fixture(autouse=True)
+def _leave_no_mesh_behind():
+    """`Trainer.fit` sets the process-global mesh and leaves it set; a
+    serving engine built by a later test of this worker would shard
+    over it and compile its decode program a second time."""
+    yield
+    from fengshen_tpu.parallel import set_mesh
+    set_mesh(None)
+
+
 # -- registry -------------------------------------------------------------
 
 def test_counter_gauge_histogram_basics():
@@ -162,6 +172,56 @@ def test_span_records_on_exception():
             raise RuntimeError("x")
     assert r.get("fstpu_span_seconds").labels("boom").count == 1
     assert current_span_stack() == ()
+
+
+def _record_annotations(monkeypatch) -> list:
+    """Stand a recorder in for `TraceAnnotation`; returns the list it
+    fills with (label, attributes) per span entered."""
+    import fengshen_tpu.observability.tracing as tracing
+    seen = []
+
+    class Annotation:
+        def __init__(self, label, **attrs):
+            seen.append((label, attrs))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(tracing, "_TRACE_ANNOTATION", Annotation)
+    return seen
+
+
+def test_span_attributes_reach_the_annotation_never_the_label(
+        monkeypatch):
+    """`span(name, **attrs)`: the attributes ride on the profiler's
+    event (a request id, a bucket), the histogram's label is the span
+    path alone, or /metrics would grow a series per request."""
+    import fengshen_tpu.observability.tracing as tracing
+    seen = _record_annotations(monkeypatch)
+    r = MetricsRegistry()
+    with span("serving/prefill", registry=r, request_id="req-7",
+              bucket=512):
+        with span("inner", registry=r, lanes=3):
+            pass
+    with span("serving/prefill", registry=r, request_id="req-8",
+              bucket=128):
+        pass
+    assert seen == [
+        ("serving/prefill", {"request_id": "req-7", "bucket": 512}),
+        ("serving/prefill/inner", {"lanes": 3}),
+        ("serving/prefill", {"request_id": "req-8", "bucket": 128})]
+    metric = r.get("fstpu_span_seconds")
+    assert sorted(v for v, _ in metric.children()) == [
+        ("serving/prefill",), ("serving/prefill/inner",)]
+    assert metric.labels("serving/prefill").count == 2
+    # the real TraceAnnotation takes the same call
+    monkeypatch.setattr(tracing, "_TRACE_ANNOTATION", tracing._UNRESOLVED)
+    with span("attrs/real", registry=r, step=3, callback="Probe"):
+        pass
+    assert r.get("fstpu_span_seconds").labels("attrs/real").count == 1
 
 
 # -- flops / mfu ----------------------------------------------------------
@@ -359,8 +419,8 @@ def test_engine_metrics_snapshot_shape_pinned():
     m = EngineMetrics()
     m.count("admitted", 2)
     m.count("completed")
-    m.record_prefill(64)
-    m.record_prefill(64)
+    m.record_prefill(64, 50)
+    m.record_prefill(64, 50)
     m.record_tick(3, 8, 0.5)
     m.record_ttft(0.2)
     m.record_ttft(0.4)
@@ -494,3 +554,88 @@ def test_trainer_fit_logs_finite_mfu_and_goodput(tmp_path):
                    reg.get("fstpu_span_seconds").children()}
     assert "train/load" in span_labels
     assert "train/step" in span_labels
+
+
+def test_trainer_loop_spans_name_log_callbacks_and_real_saves(
+        tmp_path, monkeypatch):
+    """The step loop after the dispatch is covered: `train/log` around
+    the fetch of the logged metrics, each callback under
+    `train/callback` with its class, `train/checkpoint` only where a
+    save happens; `train/step` and `train/load` keep their names, and
+    the step program keeps the name jit gives it from its function."""
+    from fengshen_tpu.data import UniversalDataModule
+    from fengshen_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    from fengshen_tpu.observability import get_registry
+    from fengshen_tpu.trainer import Trainer
+    from fengshen_tpu.trainer.modules import CausalLMModule
+
+    seen = _record_annotations(monkeypatch)
+    modules = []
+
+    def naming_wrap(self, jitted, name):
+        def call(*args):
+            if not modules:
+                modules.append(jitted.lower(*args).as_text()[:80])
+            return jitted(*args)
+        return call
+    monkeypatch.setattr(Trainer, "_maybe_aot_wrap", naming_wrap)
+
+    args = _parse(["--train_batchsize", "4", "--learning_rate", "1e-3",
+                   "--warmup_steps", "1", "--log_every_n_steps", "1",
+                   "--max_steps", "3",
+                   "--default_root_dir", str(tmp_path)])
+    cfg = LlamaConfig(vocab_size=64, hidden_size=16,
+                      intermediate_size=32, num_hidden_layers=1,
+                      num_attention_heads=2,
+                      max_position_embeddings=32, dtype="float32")
+    rng = np.random.RandomState(0)
+    rows = [{"input_ids": rng.randint(0, 63, 16).tolist()}
+            for _ in range(16)]
+
+    class DS:
+        def __len__(self):
+            return len(rows)
+
+        def __getitem__(self, i):
+            return rows[i]
+
+    class Probe:
+        def on_train_step_end(self, trainer, state):
+            pass
+
+    class EverySecondStep:
+        saved = []
+
+        def save_due(self, trainer):
+            return trainer.global_step % 2 == 0
+
+        def on_train_step_end(self, trainer, state):
+            if self.save_due(trainer):
+                self.saved.append(trainer.global_step)
+
+    def counts():
+        metric = get_registry().get("fstpu_span_seconds")
+        return {} if metric is None else {
+            v[0]: c.count for v, c in metric.children()}
+
+    before = counts()
+    trainer = Trainer(args)
+    trainer.callbacks += [Probe(), EverySecondStep()]
+    state = trainer.fit(CausalLMModule(args, LlamaForCausalLM(cfg), cfg),
+                        UniversalDataModule(args=args,
+                                            datasets={"train": DS()}))
+    assert int(state.step) == 3
+    grew = {k: n - before.get(k, 0) for k, n in counts().items()
+            if n > before.get(k, 0)}
+    assert grew["train/step"] == 3 and grew["train/load"] >= 3
+    assert grew["train/log"] == 3
+    assert grew["train/callback"] == 3 + 2     # Probe, and no save due
+    assert grew["train/checkpoint"] == 1 and EverySecondStep.saved == [2]
+    assert grew["train/validate"] == 1         # the epoch's end
+    assert [a for n, a in seen if n == "train/log"] == [
+        {"step": 1}, {"step": 2}, {"step": 3}]
+    assert [a["callback"] for n, a in seen if n == "train/checkpoint"] \
+        == ["EverySecondStep"]
+    assert {a["callback"] for n, a in seen if n == "train/callback"} == \
+        {"Probe", "EverySecondStep"}
+    assert modules and modules[0].startswith("module @jit_train_step")

@@ -611,3 +611,191 @@ def test_pipeline_honors_cli_args(tiny):
     assert pipe.max_new_tokens == 3
     assert pipe.sample_kw["temperature"] == 0.7
     assert len(pipe("5 7 9").split()) == 3
+
+
+# ---- spans and counters at the host/device boundaries (ISSUE 24) --------
+
+def _span_counts():
+    from fengshen_tpu.observability import get_registry
+    metric = get_registry().get("fstpu_span_seconds")
+    if metric is None:
+        return {}
+    return {values[0]: child.count for values, child in metric.children()}
+
+
+def test_scheduler_spans_cover_the_tick_and_keep_the_old_names(tiny):
+    """The new spans are children or siblings of the ones the
+    benchmark's readers match by exact name, never parents: after a few
+    ticks every new label is there and the old ones are, letter for
+    letter. Spans add no traced work: still one decode program."""
+    import time
+    model, params = tiny
+    eng = ContinuousBatchingEngine(
+        model, params, EngineConfig(num_slots=2, buckets=(8, 16),
+                                    max_new_tokens=6, max_queue=16))
+    before = _span_counts()
+    reqs = [eng.submit(p) for p in _prompts((5, 11, 16))]
+    eng.run_until_idle()
+    eng.start()                  # the serve loop: lock and idle waits
+    try:
+        late = eng.submit(_prompts((7,))[0])
+        assert late.wait(timeout=60)
+        deadline = time.monotonic() + 10
+        while _span_counts().get("serving/idle_wait", 0) == \
+                before.get("serving/idle_wait", 0):
+            assert time.monotonic() < deadline, "no idle wait recorded"
+            time.sleep(0.01)
+    finally:
+        eng.stop()
+    assert all(r.state == "finished" for r in reqs + [late])
+    grew = {k for k, n in _span_counts().items() if n > before.get(k, 0)}
+    assert {"serving/decode", "serving/prefill", "serving/admit"} <= grew
+    assert {"serving/decode/dispatch", "serving/decode/fetch",
+            "serving/commit", "serving/assign", "serving/lock_wait",
+            "serving/idle_wait", "serving/admit/lock_wait"} <= grew
+    after = _span_counts()
+    ticks = after["serving/decode"] - before.get("serving/decode", 0)
+    assert ticks == eng.stats()["decode_ticks"]
+    for child in ("serving/decode/dispatch", "serving/decode/fetch",
+                  "serving/commit"):
+        assert after[child] - before.get(child, 0) == ticks
+    assert after["serving/assign"] - before.get("serving/assign", 0) == 4
+    if hasattr(eng._decode_jit, "_cache_size"):
+        assert eng._decode_jit._cache_size() == 1
+
+
+def test_submit_lock_wait_is_measured_and_enqueued_is_stamped_after_it(
+        tiny):
+    """A submitter that waits for the scheduler's lock shows the wait:
+    `enqueued` carries the clock read AFTER the lock is held,
+    `lock_wait_s` is that wait and a part of `queue_wait_s`, and the
+    histogram on /metrics records it."""
+    model, params = tiny
+    now = [0.0]
+    first_read = threading.Event()
+
+    def clock():
+        first_read.set()
+        return now[0]
+
+    eng = ContinuousBatchingEngine(
+        model, params, EngineConfig(num_slots=1, buckets=(8,),
+                                    max_new_tokens=3, max_queue=4),
+        clock=clock)
+    free = eng.submit(_prompts((5,))[0], request_id="free")
+    first_read.clear()
+    out = {}
+    with eng._cv:                       # the scheduler mid-tick
+        t = threading.Thread(target=lambda: out.update(req=eng.submit(
+            _prompts((6,))[0], request_id="starved")))
+        t.start()
+        assert first_read.wait(timeout=30)     # submit read its clock
+        now[0] = 2.0                           # ... and waits 2 s
+    t.join(timeout=30)
+    assert not t.is_alive()
+    now[0] = 3.0
+    eng.run_until_idle()
+    starved = eng.debug_request("starved")
+    assert starved["events"][0] == {
+        "t_s": 2.0, "event": "enqueued", "prompt_tokens": 6, "bucket": 8,
+        "queue_depth": 2}
+    assert starved["phases"]["lock_wait_s"] == 2.0
+    assert 2.0 <= starved["phases"]["queue_wait_s"]
+    ph = eng.debug_request("free")["phases"]
+    assert ph["lock_wait_s"] == 0.0 <= ph["queue_wait_s"]
+    for d in (starved, eng.debug_request("free")):
+        p = d["phases"]
+        assert abs(p["queue_wait_s"] + p["prefill_s"] + p["decode_s"]
+                   - p["total_s"]) <= 1e-3
+    hist = eng.metrics.registry.get(
+        "fstpu_serving_submit_lock_wait_seconds")
+    assert hist.window_values() == [0.0, 2.0]
+    assert free.state == out["req"].state == "finished"
+
+
+@pytest.mark.parametrize("extra", [
+    {}, {"kv_layout": "paged", "kv_block_size": 16},
+    {"spec_mode": "prompt_lookup", "spec_gamma": 4}],
+    ids=["plain", "paged", "speculative"])
+def test_prefill_and_attended_token_counters_equal_hand_sums(tiny, extra):
+    """Real and padded prompt tokens prefilled, and the real cached
+    tokens each tick's attention reads (the logical cursor + 1, padding
+    out), against sums made by hand from the prompts, their buckets and
+    the committed tokens of every tick."""
+    model, params = tiny
+    lengths, buckets = (5, 11, 16, 7), (8, 16)
+    eng = ContinuousBatchingEngine(
+        model, params, EngineConfig(num_slots=2, buckets=buckets,
+                                    max_new_tokens=6, max_queue=16,
+                                    **extra))
+    reqs = [eng.submit(p) for p in _prompts(lengths)]
+    eng.run_until_idle()
+    reg = eng.metrics.registry
+    assert reg.get("fstpu_serving_prefill_tokens_total").value() == \
+        sum(lengths) == 39
+    assert reg.get("fstpu_serving_prefill_padded_tokens_total").value() \
+        == sum(min(b for b in buckets if b >= n) for n in lengths) == 48
+    # a tick reads, for each lane, the prompt and every token committed
+    # before it, the one it decodes from included
+    attended = 0
+    for req, n in zip(reqs, lengths):
+        held = 1                         # the prefill's token
+        for e in eng.debug_request(req.request_id)["events"]:
+            if e["event"] == "commit":
+                attended += n + held
+                held += e["n"]
+        assert held == len(req.tokens) == 6
+    assert reg.get("fstpu_serving_kv_tokens_attended_total").value() == \
+        attended
+    if "spec_mode" not in extra:
+        # one token a tick: 5 ticks a request at P+1 .. P+5
+        assert attended == sum(5 * n + 15 for n in lengths) == 255
+        assert eng.stats()["decode_tokens"] == 20
+
+
+def test_resumed_prefill_counts_its_committed_prefix(tiny):
+    """A resumed request prefills prompt + resume[:-1] in one bucket:
+    the real-token counter counts what was prefilled."""
+    model, params = tiny
+    eng = ContinuousBatchingEngine(
+        model, params, EngineConfig(num_slots=1, buckets=(8, 16),
+                                    max_new_tokens=6, max_queue=4))
+    eng.submit(_prompts((5,))[0], resume_tokens=[7, 8, 9, 10])
+    eng.run_until_idle()
+    reg = eng.metrics.registry
+    assert reg.get("fstpu_serving_prefill_tokens_total").value() == 5 + 3
+    assert reg.get(
+        "fstpu_serving_prefill_padded_tokens_total").value() == 8
+
+
+def test_serving_programs_and_decode_attention_keep_their_trace_names(
+        tiny):
+    """The trace's module line names a program after its function and
+    the decode attention after `TRACE_NAME`, whichever lowering the
+    dispatch seam took: both are what the benchmark's readers match."""
+    from fengshen_tpu.ops.pallas.decode_attention import (TRACE_NAME,
+                                                          decode_attention)
+    model, params = tiny
+    eng = ContinuousBatchingEngine(
+        model, params, EngineConfig(num_slots=2, buckets=(8,),
+                                    max_new_tokens=4, max_queue=4))
+    eng.submit(_prompts((5,))[0])
+    eng.step()
+    decode = eng._decode_jit.lower(
+        eng.params, eng._cache, eng._history, eng._mask, eng._last_tok,
+        eng._pos, eng._phys, eng._active, eng._keys
+    ).as_text(debug_info=True)
+    assert "module @jit_decode_fn" in decode
+    assert TRACE_NAME == "fstpu_decode_attention" and TRACE_NAME in decode
+    assert eng._prefill_jit.__name__ == "prefill_fn"
+    assert eng._assign_jit.__name__ == "assign_fn"
+
+    q = jnp.zeros((2, 1, 8, 128), jnp.float32)
+    kv = jnp.zeros((3, 128, 8, 128), jnp.float32)
+    valid = jnp.ones((2, 1, 256), bool)
+    table = jnp.zeros((2, 2), jnp.int32)
+    for impl in ("pallas", "xla"):
+        text = jax.jit(lambda q, k, v, m, t, impl=impl: decode_attention(
+            q, k, v, m, block_table=t, impl=impl, interpret=True)).lower(
+            q, kv, kv, valid, table).as_text(debug_info=True)
+        assert TRACE_NAME in text, impl

@@ -778,6 +778,30 @@ def test_every_n_checkpoint_fires_on_crossed_boundary():
     assert saved == [8, 16]
 
 
+def test_save_due_is_the_callbacks_own_save_decision():
+    """The Trainer names a callback's span `train/checkpoint` only where
+    `save_due` says a save happens: it has to agree with what
+    `on_train_step_end` then does, and read nothing but the counters."""
+    from fengshen_tpu.utils import UniversalCheckpoint
+
+    class _T:
+        pass
+
+    cb = UniversalCheckpoint.__new__(UniversalCheckpoint)
+    saved = []
+    cb.save = lambda state, trainer, **kw: saved.append(
+        trainer.global_step)
+    t = _T()
+    for every in (0, 1, 4):
+        cb.every_n_train_steps = every
+        for prev, cur in [(0, 3), (3, 6), (6, 7), (7, 8), (8, 9)]:
+            t.prev_global_step, t.global_step = prev, cur
+            saved.clear()
+            due = cb.save_due(t)
+            cb.on_train_step_end(t, state=None)
+            assert due == bool(saved), (every, prev, cur)
+
+
 def test_sigterm_preemption_saves_and_resumes(mesh8, tmp_path):
     """A REAL SIGTERM mid-fit (delivered by the fault-injection
     harness) saves a sync checkpoint at the next step boundary and
