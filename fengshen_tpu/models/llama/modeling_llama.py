@@ -22,6 +22,7 @@ from typing import Any, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
+from flax.traverse_util import flatten_dict
 from fengshen_tpu.models.llama.configuration_llama import LlamaConfig
 from fengshen_tpu.ops.attention import dot_product_attention
 from fengshen_tpu.ops.pallas.decode_attention import decode_attention
@@ -80,7 +81,8 @@ class CacheView(NamedTuple):
     """What `_update_cache` hands the decode_attention dispatch seam
     (fengshen_tpu/ops/pallas/decode_attention.py): the cache in its
     NATIVE layout — the paged pool stays `[num_blocks, block_size, kv,
-    hd]` behind its `block_table` (the Mosaic kernel reads it through
+    hd]` (or the `[L, ...]` stack of them a scan_layers model carries)
+    behind its `block_table` (the Mosaic kernel reads it through
     the table; the xla lowering gathers), and int8 pools stay int8
     with their per-(token, head) scales (dequant happens inside the
     attention read on either path)."""
@@ -92,6 +94,9 @@ class CacheView(NamedTuple):
     block_table: Optional[jax.Array]
     #: [B, Sq, L] bool over the (virtual) lane
     valid: jax.Array
+    #: set when k/v (and the scales) are the `[L, num_blocks, ...]`
+    #: stacks a scan_layers model carries: the layer to read
+    layer: Optional[jax.Array] = None
 
 
 class LlamaMLP(nn.Module):
@@ -130,7 +135,8 @@ class LlamaAttention(nn.Module):
 
     @nn.compact
     def __call__(self, hidden, attention_mask=None, position_ids=None,
-                 init_cache: bool = False, deterministic: bool = True):
+                 init_cache: bool = False, deterministic: bool = True,
+                 layer=None):
         cfg = self.config
         n_heads, n_kv = cfg.num_attention_heads, cfg.num_key_value_heads
         head_dim = cfg.head_dim
@@ -162,11 +168,12 @@ class LlamaAttention(nn.Module):
             # lowering replays the stock gather → dequant → GQA repeat
             # → dense chain op-for-op, so CPU tier-1 pins decode
             # token-identical through the seam
-            view = self._update_cache(k, v, attention_mask)
+            view = self._update_cache(k, v, attention_mask, layer)
             out = decode_attention(
                 q, view.k, view.v, view.valid,
                 k_scale=view.k_scale, v_scale=view.v_scale,
-                block_table=view.block_table, dequant_dtype=_dt(cfg))
+                block_table=view.block_table, layer=view.layer,
+                dequant_dtype=_dt(cfg))
         else:
             mask = causal_mask(seq, k.shape[1])[None, None]
             if attention_mask is not None:
@@ -209,7 +216,7 @@ class LlamaAttention(nn.Module):
         out = out.reshape(batch, seq, n_heads * head_dim)
         return dense(cfg.hidden_size, "o_proj")(out)
 
-    def _update_cache(self, k, v, attention_mask):
+    def _update_cache(self, k, v, attention_mask, layer=None):
         """flax mutable-cache decode (same role as the reference's KV concat,
         reference: transformer.py:529-537, but with static shapes for XLA:
         the cache is preallocated at max length and updated in place).
@@ -234,7 +241,7 @@ class LlamaAttention(nn.Module):
         batch, seq, n_kv, head_dim = k.shape
         max_len = cfg.max_position_embeddings
         if self.has_variable("cache", "block_table"):
-            return self._update_paged_cache(k, v, attention_mask)
+            return self._update_paged_cache(k, v, attention_mask, layer)
         # when the variables are being created (the init_cache=True init
         # pass), skip the update so the returned cache starts at index 0
         is_initialized = self.has_variable("cache", "cached_key")
@@ -307,7 +314,7 @@ class LlamaAttention(nn.Module):
             valid = valid & full[:, None, :].astype(bool)
         return CacheView(k_all, v_all, ks_all, vs_all, None, valid)
 
-    def _update_paged_cache(self, k, v, attention_mask):
+    def _update_paged_cache(self, k, v, attention_mask, layer=None):
         """Paged decode (fengshen_tpu/serving/paged_cache.py): K/V live
         in a shared `[num_blocks, block_size, kv, hd]` pool; each lane's
         logical positions map through its `block_table` row to physical
@@ -332,10 +339,21 @@ class LlamaAttention(nn.Module):
         `assign_paged` — a whole prompt through this path would
         overrun the lane, hence the seq bound below.
 
+        Under `scan_layers` the layer loop CARRIES the cache
+        (`LlamaModel`): the variables here are then the whole stacks,
+        `[L, num_blocks, ...]` pools and `[L, B, ...]` cursors/tables,
+        and `layer` is this iteration's index. The stack is addressed
+        as ONE pool of `L * num_blocks` blocks — a free reshape — in
+        which layer `l` owns blocks `l * num_blocks ...`: the write
+        scatters the step's rows at `layer * num_blocks * bs + ...`
+        into the loop-carried buffer (in place), and the read gets the
+        stacks and `layer` and reads through `block_table + layer *
+        num_blocks` (`decode_attention`). No layer's pool is ever
+        sliced out of, or written back into, the stack.
+
         An int8 pool (marked by `cached_key_scale`) stores per-(token,
         head) absmax scales alongside and dequantizes inside the read.
         """
-        cfg = self.config
         batch, seq, n_kv, head_dim = k.shape
         cached_k = self.variable("cache", "cached_key", jnp.zeros,
                                  (1, 1, n_kv, head_dim), k.dtype)
@@ -345,8 +363,15 @@ class LlamaAttention(nn.Module):
                                     lambda: jnp.zeros((batch,), jnp.int32))
         table = self.variable("cache", "block_table",
                               lambda: jnp.zeros((batch, 1), jnp.int32))
-        num_blocks, block_size = cached_k.value.shape[:2]
-        max_blocks = table.value.shape[-1]
+        num_blocks, block_size = cached_k.value.shape[-4:-2]
+        if layer is None:
+            idx, lane_table, first_block = cache_index.value, table.value, 0
+        else:
+            idx, lane_table = cache_index.value[layer], table.value[layer]
+            # layer `layer`'s block b is block `layer * num_blocks + b`
+            # of the flat stack
+            first_block = layer * num_blocks
+        max_blocks = lane_table.shape[-1]
         virt_len = max_blocks * block_size   # the lane's logical extent
         if seq > virt_len:
             # a window that cannot fit any lane (e.g. prefilling a
@@ -358,19 +383,22 @@ class LlamaAttention(nn.Module):
                 f"length {virt_len} tokens per step (decode tick or "
                 f"speculative verify window); got seq={seq}. Prefill "
                 "runs on a contiguous batch-1 cache.")
-        idx = cache_index.value              # [B] physical cursors
         quantized = self.has_variable("cache", "cached_key_scale")
 
         # scatter this step's K/V at each lane's physical positions
         # (lanes parked on the null block collide there by design —
         # whichever garbage write wins is never read unmasked)
         p = idx[:, None] + jnp.arange(seq)[None, :]        # [B, seq]
-        blk = jnp.take_along_axis(table.value, p // block_size, axis=-1)
-        pos = (blk * block_size + p % block_size).reshape(-1)
-        flat_k = cached_k.value.reshape(num_blocks * block_size,
-                                        n_kv, head_dim)
-        flat_v = cached_v.value.reshape(num_blocks * block_size,
-                                        n_kv, head_dim)
+        blk = jnp.take_along_axis(lane_table, p // block_size, axis=-1)
+        pos = ((first_block + blk) * block_size +
+               p % block_size).reshape(-1)
+
+        def put(pool, rows):
+            flat = pool.reshape((-1,) + rows.shape[2:])
+            return flat.at[pos].set(
+                rows.reshape(batch * seq, *rows.shape[2:]).astype(
+                    flat.dtype)).reshape(pool.shape)
+
         if quantized:
             from fengshen_tpu.ops.int8_matmul import quantize_kv
             k_scale = self.variable(
@@ -379,30 +407,14 @@ class LlamaAttention(nn.Module):
             v_scale = self.variable(
                 "cache", "cached_value_scale", jnp.zeros,
                 (num_blocks, block_size, n_kv), jnp.float32)
-            kq, ks = quantize_kv(k)
-            vq, vs = quantize_kv(v)
-            flat_k = flat_k.at[pos].set(
-                kq.reshape(batch * seq, n_kv, head_dim))
-            flat_v = flat_v.at[pos].set(
-                vq.reshape(batch * seq, n_kv, head_dim))
-            flat_ks = k_scale.value.reshape(-1, n_kv).at[pos].set(
-                ks.reshape(batch * seq, n_kv))
-            flat_vs = v_scale.value.reshape(-1, n_kv).at[pos].set(
-                vs.reshape(batch * seq, n_kv))
-            k_scale.value = flat_ks.reshape(num_blocks, block_size, n_kv)
-            v_scale.value = flat_vs.reshape(num_blocks, block_size, n_kv)
-        else:
-            flat_k = flat_k.at[pos].set(
-                k.reshape(batch * seq, n_kv, head_dim).astype(
-                    flat_k.dtype))
-            flat_v = flat_v.at[pos].set(
-                v.reshape(batch * seq, n_kv, head_dim).astype(
-                    flat_v.dtype))
-        cached_k.value = flat_k.reshape(num_blocks, block_size,
-                                        n_kv, head_dim)
-        cached_v.value = flat_v.reshape(num_blocks, block_size,
-                                        n_kv, head_dim)
-        cache_index.value = idx + seq
+            k, ks = quantize_kv(k)
+            v, vs = quantize_kv(v)
+            k_scale.value = put(k_scale.value, ks)
+            v_scale.value = put(v_scale.value, vs)
+        cached_k.value = put(cached_k.value, k)
+        cached_v.value = put(cached_v.value, v)
+        cache_index.value = idx + seq if layer is None else \
+            cache_index.value.at[layer].add(seq)
 
         # NO gather: the pool stays put and the CacheView carries the
         # block table — the attention read resolves the indirection
@@ -410,8 +422,7 @@ class LlamaAttention(nn.Module):
         # xla lowering reconstructs the stock jnp.take virtual lane)
         # per-lane causal validity over the virtual lane (same law as
         # the slot path: query at idx[b] sees positions <= idx[b])
-        q_pos = idx[:, None] + jnp.arange(seq)[None, :]
-        valid = jnp.arange(virt_len)[None, None, :] <= q_pos[:, :, None]
+        valid = jnp.arange(virt_len)[None, None, :] <= p[:, :, None]
         if attention_mask is not None:
             m = attention_mask[:, :virt_len]
             if m.shape[1] < virt_len:
@@ -421,7 +432,14 @@ class LlamaAttention(nn.Module):
         return CacheView(cached_k.value, cached_v.value,
                          k_scale.value if quantized else None,
                          v_scale.value if quantized else None,
-                         table.value, valid)
+                         lane_table, valid, layer)
+
+
+def _holds_block_table(cache) -> bool:
+    """Whether a cache collection holds a paged pool (static under jit:
+    it reads the pytree's keys, never a value)."""
+    return any(path[-1] == "block_table"
+               for path in flatten_dict(cache))
 
 
 class LlamaDecoderLayer(nn.Module):
@@ -429,11 +447,12 @@ class LlamaDecoderLayer(nn.Module):
 
     @nn.compact
     def __call__(self, hidden, attention_mask=None, position_ids=None,
-                 init_cache=False, deterministic=True):
+                 init_cache=False, deterministic=True, layer=None):
         cfg = self.config
         h = RMSNorm(epsilon=cfg.rms_norm_eps, name="input_layernorm")(hidden)
         h = LlamaAttention(cfg, name="self_attn")(
-            h, attention_mask, position_ids, init_cache, deterministic)
+            h, attention_mask, position_ids, init_cache, deterministic,
+            layer)
         hidden = hidden + h
         h = RMSNorm(epsilon=cfg.rms_norm_eps,
                     name="post_attention_layernorm")(hidden)
@@ -470,9 +489,10 @@ class _ScanDecoderLayer(nn.Module):
 
     @nn.compact
     def __call__(self, hidden, attention_mask, position_ids, init_cache,
-                 deterministic):
+                 deterministic, layer=None):
         out = LlamaDecoderLayer(self.config, name="layer")(
-            hidden, attention_mask, position_ids, init_cache, deterministic)
+            hidden, attention_mask, position_ids, init_cache, deterministic,
+            layer)
         return out, None
 
 
@@ -508,15 +528,28 @@ class LlamaModel(nn.Module):
                     body, static_argnums=(4, 5),
                     policy=remat_policy,
                     prevent_cse=False)
-            scan = nn.scan(
-                body,
+            scan_kw = dict(
                 variable_axes={"params": 0, "cache": 0, "losses": 0},
                 split_rngs={"params": True, "dropout": True},
                 in_axes=(nn.broadcast,) * 4,
                 length=cfg.num_hidden_layers)
-            hidden, _ = scan(cfg, name="layers")(
-                hidden, attention_mask, position_ids, init_cache,
-                deterministic)
+            args = (hidden, attention_mask, position_ids, init_cache,
+                    deterministic)
+            if _holds_block_table(self.variables.get("cache", {})):
+                # a paged KV pool is loop STATE, not a scanned
+                # input/output: as xs/ys every iteration would slice a
+                # layer's pool out of the stack and write it into a
+                # second stack (two pool-sized copies a step, a third
+                # to reconcile the donated argument); carried, the
+                # step's rows are scattered into the one buffer in
+                # place and the layer finds its blocks by its index
+                # (`_update_paged_cache`)
+                scan_kw.update(
+                    variable_axes={"params": 0, "losses": 0},
+                    variable_carry="cache",
+                    in_axes=(nn.broadcast,) * 4 + (0,))
+                args += (jnp.arange(cfg.num_hidden_layers),)
+            hidden, _ = nn.scan(body, **scan_kw)(cfg, name="layers")(*args)
         else:
             layer_cls = LlamaDecoderLayer
             if cfg.gradient_checkpointing:

@@ -85,6 +85,7 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      k_scale: Optional[jax.Array] = None,
                      v_scale: Optional[jax.Array] = None,
                      block_table: Optional[jax.Array] = None,
+                     layer: Optional[jax.Array] = None,
                      dequant_dtype=None,
                      impl: Optional[str] = None,
                      interpret: bool = False) -> jax.Array:
@@ -97,25 +98,42 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     (``[B, max_blocks]`` int32) is given. int8 caches pass the
     per-(token, head) absmax scales (``k_scale``/``v_scale``) and the
     compute dtype ``dequant_dtype``. ``valid``: ``[B, S, L]`` bool over
-    the (virtual) lane. ``impl`` forces ``"pallas"``/``"xla"``;
-    ``None`` asks the capability probe + shape eligibility.
+    the (virtual) lane. With ``layer`` (an int32 scalar, may be traced)
+    the paged pools and scales are whole ``[L, num_blocks, ...]``
+    stacks, as a ``scan_layers`` model carries them, and the read is
+    layer ``layer``'s: see :func:`_layer_of_stack`. ``impl`` forces
+    ``"pallas"``/``"xla"``; ``None`` asks the capability probe + shape
+    eligibility.
     """
     if impl is None:
         from fengshen_tpu.ops.pallas import resolve_dispatch
         impl = resolve_dispatch(
             "decode_attention",
-            f"q={tuple(q.shape)} kv={tuple(k.shape)}:{k.dtype.name} "
+            f"q={tuple(q.shape)} kv={tuple(k.shape[-4:])}:{k.dtype.name} "
             f"{'paged' if block_table is not None else 'slot'}",
             _ineligible_reason(q, k, block_table))
     if impl == "pallas":
         return pallas_decode_attention(
             q, k, v, valid, k_scale=k_scale, v_scale=v_scale,
-            block_table=block_table, dequant_dtype=dequant_dtype,
-            interpret=interpret)
+            block_table=block_table, layer=layer,
+            dequant_dtype=dequant_dtype, interpret=interpret)
     with jax.named_scope(TRACE_NAME):
         return xla_decode_attention(
             q, k, v, valid, k_scale=k_scale, v_scale=v_scale,
-            block_table=block_table, dequant_dtype=dequant_dtype)
+            block_table=block_table, layer=layer,
+            dequant_dtype=dequant_dtype)
+
+
+def _layer_of_stack(k, v, block_table, layer):
+    """Layer ``layer`` of ``[L, num_blocks, block_size, KVH, D]`` pool
+    stacks WITHOUT slicing it out (that slice is a copy of a layer's
+    whole pool, every layer of every tick): the stack is one pool of
+    ``L * num_blocks`` blocks — a free reshape of a contiguous array —
+    in which the layer's block ``b`` is block ``layer * num_blocks +
+    b``. Returns the flat pools and the table that reads them."""
+    num_blocks = k.shape[1]
+    return (k.reshape((-1,) + k.shape[2:]), v.reshape((-1,) + v.shape[2:]),
+            block_table + layer * num_blocks)
 
 
 def _ineligible_reason(q, k, block_table) -> Optional[str]:
@@ -130,10 +148,10 @@ def _ineligible_reason(q, k, block_table) -> Optional[str]:
                f"{n_heads}"
     if head_dim % 128 != 0:
         return f"head_dim {head_dim} % 128 != 0"
-    if k.shape[1] % 128 != 0:
+    if k.shape[-3] % 128 != 0:
         what = "block_size" if block_table is not None else "cache length"
-        return f"{what} {k.shape[1]} % 128 != 0"
-    block = k.shape[1] if block_table is not None else _SLOT_BLOCK
+        return f"{what} {k.shape[-3]} % 128 != 0"
+    block = k.shape[-3] if block_table is not None else _SLOT_BLOCK
     if block * kv_heads * head_dim > _MAX_BLOCK_ELEMS:
         return f"a block of {block} tokens x {kv_heads} heads x " \
                f"{head_dim} outgrows VMEM"
@@ -150,7 +168,7 @@ def pallas_decode_eligible(q, k, v=None, k_scale=None,
 
 
 def xla_decode_attention(q, k, v, valid, *, k_scale=None, v_scale=None,
-                         block_table=None, dequant_dtype=None):
+                         block_table=None, layer=None, dequant_dtype=None):
     """The stock lowering, kept op-for-op identical to the pre-seam
     model path so greedy decode through the dispatcher is
     token-identical on CPU tier-1: paged pools gather into the
@@ -158,6 +176,8 @@ def xla_decode_attention(q, k, v, valid, *, k_scale=None, v_scale=None,
     gathered window), slot int8 caches dequantize in place, GQA
     repeats KV heads, and the dense fused softmax chain finishes."""
     dt = dequant_dtype if dequant_dtype is not None else jnp.float32
+    if layer is not None:
+        k, v, block_table = _layer_of_stack(k, v, block_table, layer)
     if block_table is not None:
         num_blocks, block_size = k.shape[:2]
         batch = q.shape[0]
@@ -185,7 +205,7 @@ def xla_decode_attention(q, k, v, valid, *, k_scale=None, v_scale=None,
     return dot_product_attention(q, k, v, mask=valid[:, None])
 
 
-def _decode_kernel(table_ref, *refs, scale, n_blocks, n_query, rep,
+def _decode_kernel(tables_ref, *refs, scale, n_blocks, n_query, rep,
                    quantized, dt):
     """One (lane, block) grid step over ALL heads at once.
 
@@ -263,7 +283,7 @@ def _decode_kernel(table_ref, *refs, scale, n_blocks, n_query, rep,
 
 
 def pallas_decode_attention(q, k, v, valid, *, k_scale=None,
-                            v_scale=None, block_table=None,
+                            v_scale=None, block_table=None, layer=None,
                             dequant_dtype=None,
                             block_size: int = _SLOT_BLOCK,
                             interpret: bool = False):
@@ -293,8 +313,16 @@ def pallas_decode_attention(q, k, v, valid, *, k_scale=None,
                        blocks_per_lane +
                        jnp.arange(blocks_per_lane, dtype=jnp.int32)[None])
     else:
-        block_size = k.shape[1]
+        block_size = k.shape[-3]
         blocks_per_lane = block_table.shape[-1]
+    # the scales are re-laid out below, which copies them: of a stack
+    # that copy takes the layer's own slice (small) and its own table
+    scale_table = block_table
+    if layer is not None:
+        k, v, block_table = _layer_of_stack(k, v, block_table, layer)
+        if quantized:
+            k_scale, v_scale = k_scale[layer], v_scale[layer]
+    tables = jnp.stack([block_table, scale_table]).astype(jnp.int32)
     n_cols = block_size * kv_heads
 
     # key column c of a block is (token c // KVH, kv head c % KVH):
@@ -303,17 +331,17 @@ def pallas_decode_attention(q, k, v, valid, *, k_scale=None,
     col_head = jnp.tile(jnp.arange(kv_heads, dtype=jnp.int32),
                         block_size)[None]                # [1, C]
 
-    def kv_map(b, j, table):
+    def kv_map(b, j, tables):
         # the whole point: the lane's j-th PHYSICAL block comes out of
         # the pool directly — no gather into a virtual lane
-        return (table[b, j], 0, 0, 0)
+        return (tables[0, b, j], 0, 0, 0)
 
-    def scale_map(b, j, table):
-        return (table[b, j], 0, 0)
+    def scale_map(b, j, tables):
+        return (tables[1, b, j], 0, 0)
 
     kv_spec = pl.BlockSpec((1, block_size, kv_heads, head_dim), kv_map)
     qo_spec = pl.BlockSpec((1, s, n_heads, head_dim),
-                           lambda b, j, table: (b, 0, 0, 0))
+                           lambda b, j, tables: (b, 0, 0, 0))
     in_specs = [qo_spec, kv_spec, kv_spec]
     operands = [q, k, v]
     if quantized:
@@ -321,8 +349,8 @@ def pallas_decode_attention(q, k, v, valid, *, k_scale=None,
         operands += [k_scale.reshape(-1, 1, n_cols),
                      v_scale.reshape(-1, 1, n_cols)]
     in_specs += [pl.BlockSpec((1, s, n_cols),
-                              lambda b, j, table: (b, 0, j)),
-                 pl.BlockSpec((1, n_cols), lambda b, j, table: (0, 0))]
+                              lambda b, j, tables: (b, 0, j)),
+                 pl.BlockSpec((1, n_cols), lambda b, j, tables: (0, 0))]
     operands += [mask, col_head]
 
     kernel = functools.partial(
@@ -348,4 +376,4 @@ def pallas_decode_attention(q, k, v, valid, *, k_scale=None,
                 dimension_semantics=("parallel", "arbitrary"),
                 vmem_limit_bytes=_VMEM_LIMIT_BYTES),
             interpret=interpret, name=TRACE_NAME,
-        )(block_table.astype(jnp.int32), *operands)
+        )(tables, *operands)

@@ -738,10 +738,16 @@ class ContinuousBatchingEngine:
 
         # one compile per bucket width / exactly one for decode — the
         # parity + compile-count tests pin this via _cache_size().
-        # Donation keeps the pool cache in place across ticks (a
-        # num_slots × max_len KV pool re-copied every tick would cost
-        # more than the decode itself); every donated arg is reassigned
-        # from the outputs wherever these are called.
+        # The KV pool and the rings beside it are donated, and every
+        # donated arg is reassigned from the outputs wherever these
+        # are called. Donation alone only lends the program the
+        # buffer: the pool stays in place across a tick because the
+        # model also writes it in place — a scatter into each layer's
+        # own leaf when the layers are unrolled, a scatter into the
+        # stack that the layer loop carries under scan_layers (scanned
+        # as xs/ys, the paged pool was copied six times a tick;
+        # PERF.md, PR 25). tests/test_serving_paged.py reads the
+        # aliasing off the compiled program.
         self._aot = aot
         # self-draft programs carry two extra donated buffers (the
         # draft pool in both, plus the draft params slot shifting the

@@ -10,9 +10,14 @@ byte budget into fixed-size blocks instead (the paged-attention idea):
   shape-static `[num_slots, max_blocks_per_slot]` `block_table` of
   block ids and a `[num_slots]` `cache_index` of physical cursors.
   `modeling_llama._update_paged_cache` scatters each decode step at
-  `table[lane, idx // bs] * bs + idx % bs` and gathers the lane's
-  blocks back into a contiguous virtual lane with `jnp.take` — pure
-  gather/scatter, so XLA-CPU tier-1 runs it unchanged;
+  `table[lane, idx // bs] * bs + idx % bs`; the read belongs to the
+  `decode_attention` seam (the Mosaic kernel walks the table, the xla
+  lowering gathers the lane's blocks into a contiguous virtual lane
+  with `jnp.take` — pure gather/scatter, so XLA-CPU tier-1 runs it
+  unchanged). Under `scan_layers` every leaf gains a leading `[L]`
+  axis; the model's layer loop carries those stacks as its state and
+  addresses them as one pool of `L * num_blocks` blocks, so the decode
+  program, jitted with the pool donated, updates it in place;
 - host side: `BlockAllocator`, a plain free list. ALL allocation math
   (alloc/free/accounting) stays in Python on the scheduler thread —
   nothing here is ever traced (the fslint fixture

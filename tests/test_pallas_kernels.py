@@ -197,6 +197,39 @@ def test_pallas_decode_interpret_parity(layout, quant, s):
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("quant", [False, True])
+@pytest.mark.parametrize("s", [1, 4])
+def test_pallas_decode_reads_a_layer_of_the_stack(quant, s):
+    """Under scan_layers the model never slices a layer's pool out of
+    the `[L, num_blocks, ...]` stack: it hands the seam the stacks and
+    `layer`, and the read views them as ONE pool of `L * num_blocks`
+    blocks behind `block_table + layer * num_blocks`. The kernel over
+    that view must be the kernel over layer `layer`'s own pool, bit
+    for bit, and the xla lowering its twin."""
+    rng = np.random.RandomState(7 + s + 2 * quant)
+    q, _, _, valid, kw = _decode_case("paged", quant, s, rng)
+    table = kw["block_table"]
+    layers = [_decode_case("paged", quant, s, rng) for _ in range(3)]
+    k_stack = jnp.stack([case[1] for case in layers])
+    v_stack = jnp.stack([case[2] for case in layers])
+    scales = {name: jnp.stack([case[4][name] for case in layers])
+              for name in (("k_scale", "v_scale") if quant else ())}
+    assert pallas_decode_eligible(q, k_stack, block_table=table)
+    for layer, (_, k, v, _, own) in enumerate(layers):
+        own = {**own, "block_table": table}
+        want = pallas_decode_attention(q, k, v, valid, interpret=True,
+                                       **own)
+        got = pallas_decode_attention(
+            q, k_stack, v_stack, valid, interpret=True, block_table=table,
+            layer=jnp.int32(layer), **scales)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        np.testing.assert_array_equal(
+            np.asarray(xla_decode_attention(
+                q, k_stack, v_stack, valid, block_table=table,
+                layer=jnp.int32(layer), **scales)),
+            np.asarray(xla_decode_attention(q, k, v, valid, **own)))
+
+
 def test_decode_dispatcher_eligibility():
     """Ineligible shapes (tiny pages, odd head_dim, prefill-length
     windows, KV heads the fold cannot tile) stay on the xla lowering

@@ -31,11 +31,11 @@ from fengshen_tpu.serving import (BlockAllocator, ContinuousBatchingEngine,
 from fengshen_tpu.utils.generate import generate
 
 
-def _make(scan=False, kv_heads=None):
+def _make(scan=False, kv_heads=None, layers=2, dtype="float32"):
     cfg = LlamaConfig(vocab_size=97, hidden_size=32, intermediate_size=64,
-                      num_hidden_layers=2, num_attention_heads=4,
+                      num_hidden_layers=layers, num_attention_heads=4,
                       num_key_value_heads=kv_heads,
-                      max_position_embeddings=64, dtype="float32",
+                      max_position_embeddings=64, dtype=dtype,
                       scan_layers=scan)
     model = LlamaForCausalLM(cfg)
     params = model.init(jax.random.PRNGKey(0),
@@ -473,3 +473,122 @@ def test_reset_free_slots_parks_block_tables(tiny):
             np.testing.assert_array_equal(np.asarray(leaf), [5, 0, 5])
         return leaf
     jax.tree_util.tree_map_with_path(check, out)
+
+
+# ---- the pool is the layer loop's state under scan_layers (ISSUE 25) ----
+
+TICKS = {
+    "plain": {},
+    "prompt_lookup": dict(spec_mode="prompt_lookup", spec_gamma=3),
+    "self_draft": dict(spec_mode="self_draft", spec_gamma=3,
+                       spec_draft_layers=1),
+}
+#: pool name -> (model dtype, EngineConfig.kv_dtype); "fp32" there means
+#: "the model's own dtype"
+POOLS = {"bf16": ("bfloat16", "fp32"), "fp32": ("float32", "fp32"),
+         "int8": ("float32", "int8")}
+
+
+def _decode_args(eng):
+    """The engine's own decode call (`ContinuousBatchingEngine._tick`)."""
+    head = (eng.params, eng._draft_params, eng._cache, eng._draft_cache) \
+        if eng.self_draft else (eng.params, eng._cache)
+    return head + (eng._history, eng._mask, eng._last_tok, eng._pos,
+                   eng._phys, eng._active, eng._keys)
+
+
+@pytest.mark.parametrize("pool", sorted(POOLS))
+@pytest.mark.parametrize("tick", sorted(TICKS))
+def test_scanned_paged_decode_updates_pool_in_place(tick, pool):
+    """Under scan_layers the paged pool is carried through the layer
+    loop, not scanned: the compiled decode program holds no copy,
+    dynamic-slice or dynamic-update-slice of the stack's or of one
+    layer's pool shape, no second pool's worth of temporaries, and
+    its donated pool is aliased to the returned one. The property,
+    not a speed: as xs/ys of the scan every tick copied the whole pool
+    six times on the chip (PERF.md, PR 25)."""
+    import re
+    dtype, kv_dtype = POOLS[pool]
+    model, params = _make(scan=True, kv_heads=2, layers=4, dtype=dtype)
+    eng = ContinuousBatchingEngine(
+        model, params, EngineConfig(num_slots=2, buckets=(8, 16),
+                                    max_new_tokens=8, max_queue=8,
+                                    kv_dtype=kv_dtype, kv_num_blocks=33,
+                                    **TICKS[tick], **PAGED))
+    stacks = [leaf for path, leaf in
+              jax.tree_util.tree_flatten_with_path(eng._cache)[0]
+              if getattr(path[-1], "key", "").startswith("cached_")]
+    assert stacks[0].shape == (4, 33, 16, 2, 8)
+    assert stacks[0].dtype.name == {"bf16": "bfloat16", "fp32": "float32",
+                                    "int8": "int8"}[pool]
+    pool_bytes = sum(leaf.nbytes for leaf in stacks)
+    compiled = eng._decode_jit.lower(*_decode_args(eng)).compile()
+
+    shapes = set()
+    for leaf in stacks:
+        shapes |= {leaf.shape, (1,) + leaf.shape[1:], leaf.shape[1:]}
+    found = []
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* "
+                     r"(copy|dynamic-slice|dynamic-update-slice)\(", line)
+        if m and tuple(int(d) for d in m.group(1).split(",") if d) \
+                in shapes:
+            found.append(line.strip()[:120])
+    assert not found, found
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    if pool != "bf16":
+        # (the CPU backend widens a bf16 model's weights into float32
+        # temporaries that outweigh a pool this small)
+        assert mem.temp_size_in_bytes < pool_bytes
+
+
+def _stack_layers(params, n_layers):
+    """Unrolled `layers_<i>` params in the scanned layout."""
+    model_p = {k: v for k, v in params["model"].items()
+               if not k.startswith("layers_")}
+    model_p["layers"] = {"layer": jax.tree_util.tree_map(
+        lambda *xs: jnp.stack(xs),
+        *[params["model"][f"layers_{i}"] for i in range(n_layers)])}
+    return {**params, "model": model_p}
+
+
+@pytest.mark.parametrize("kv_dtype", ["fp32", "int8"])
+@pytest.mark.parametrize("tick", sorted(TICKS))
+def test_scanned_and_unrolled_paged_engines_agree(tick, kv_dtype):
+    """The same weights, the same staggered admissions, lanes reclaimed
+    mid-run: the scanned engine (pool carried through the layer loop)
+    and the unrolled one (a pool a layer) return identical tokens and
+    leave identical pools, block tables and cursors."""
+    flat_model, flat_params = _make(kv_heads=2, layers=4)
+    scan_model, _ = _make(scan=True, kv_heads=2, layers=4)
+    prompts = _prompts((5, 11, 16, 7, 9), seed=2)
+
+    def run(model, params):
+        eng = ContinuousBatchingEngine(
+            model, params, EngineConfig(num_slots=2, buckets=(8, 16),
+                                        max_new_tokens=9, max_queue=16,
+                                        kv_dtype=kv_dtype,
+                                        **TICKS[tick], **PAGED))
+        reqs = [eng.submit(p) for p in prompts[:2]]
+        for _ in range(3):
+            eng.step()
+        reqs += [eng.submit(p) for p in prompts[2:]]
+        eng.run_until_idle()
+        assert all(r.state == "finished" for r in reqs)
+        return [r.tokens for r in reqs], eng._cache
+
+    flat_tokens, flat_cache = run(flat_model, flat_params)
+    scan_tokens, scan_cache = run(scan_model, _stack_layers(flat_params, 4))
+    assert scan_tokens == flat_tokens
+    if tick == "plain" and kv_dtype == "fp32":
+        assert flat_tokens == [_ref(flat_model, flat_params, p, 9)
+                               for p in prompts]
+    scanned = scan_cache["model"]["layers"]["layer"]["self_attn"]
+    for i in range(4):
+        unrolled = flat_cache["model"][f"layers_{i}"]["self_attn"]
+        assert sorted(unrolled) == sorted(scanned)
+        for name, leaf in unrolled.items():
+            np.testing.assert_array_equal(
+                np.asarray(scanned[name][i]), np.asarray(leaf),
+                err_msg=f"layer {i} {name}")
