@@ -1,0 +1,84 @@
+"""Device time by the program's `jax.named_scope` names.
+
+`lib.xplane.load` keeps an operation's own name and result shape; a
+named scope is not in those, nor in anything `ProfileData` hands out
+of an event (my chip run, PR 26: 64,731 operations, none carried one).
+The profiler keeps the HLO `op_name`, which holds the scope path, once
+per kind of event, so this module reads the run's `.xplane.pb` once
+more, takes those attributes off the wire (`lib.xplane_meta`) and
+keeps, for device 0's operations, every string it finds:
+
+    [[text, start_s, dur_s], ...]
+
+on the trace's own clock, as in `lib.xplane`. A reader asks for the
+busy seconds, inside a host span, of the operations whose text holds a
+scope's name. The harness keeps no path, so the file is found where it
+wrote it: `.bench_run/<cell>/trace` (as `lib.xplane_attrs` does).
+"""
+
+from __future__ import annotations
+
+import os
+import statistics
+
+from benchmarks.lib import manifest, obsutil, xplane, xplane_meta
+
+
+def load(path: str) -> list:
+    from jax.profiler import ProfileData
+    data = ProfileData.from_file(path)
+    device = None
+    for plane in data.planes:
+        if xplane.DEVICE_PLANE.match(plane.name) and (
+                device is None or plane.name < device.name):
+            device = plane
+    out = []
+    if device is None:
+        return out
+    kinds = xplane_meta.load(path, lambda name: name == device.name).get(
+        device.name, {})
+    for line in device.lines:
+        if line.name != xplane.OPS_LINE:
+            continue
+        for e in line.events:
+            text = " ".join([e.name, kinds.get(e.name, "")] +
+                            [v for _, v in e.stats if isinstance(v, str)])
+            out.append([text, e.start_ns * 1e-9, e.duration_ns * 1e-9])
+    return out
+
+
+def of(obs: dict):
+    """The run's operations with their texts, or None without a trace,
+    a device plane or the file. Read once a run, kept on `obs`."""
+    if "scope_ops" not in obs:
+        obs["scope_ops"] = None
+        if obsutil.traced(obs) is not None:
+            trace_dir = os.path.join(manifest.ROOT, ".bench_run",
+                                     obs["cell"]["name"], "trace")
+            try:
+                obs["scope_ops"] = load(xplane.find_xplane(trace_dir))
+            except FileNotFoundError:
+                pass
+    return obs["scope_ops"]
+
+
+def seconds_per_span(obs: dict, scope, span: str):
+    """Mean, over the traced host spans `span` that hold any, of the
+    device seconds of the operations under `scope` inside the span (the
+    union of their intervals: a `while` and the operations of its body
+    lie on one line and may both carry the scope). `scope` is one
+    string or several: an operation counts if its text holds any. None
+    where the run has no trace, no such span or no such operation."""
+    t, ops = obsutil.traced(obs), of(obs)
+    if t is None or not ops:
+        return None
+    trace, lo, hi = t
+    marks = (scope,) if isinstance(scope, str) else tuple(scope)
+    under = sorted(([n, s, d] for n, s, d in ops
+                    if any(m in n for m in marks)), key=lambda e: e[1])
+    per = []
+    for a, b in xplane.spans(trace, span, lo, hi):
+        inside = [e for e in under if a <= e[1] and e[1] + e[2] <= b]
+        if inside:
+            per.append(sum(y - x for x, y in xplane.merged(inside, a, b)))
+    return statistics.mean(per) if per else None

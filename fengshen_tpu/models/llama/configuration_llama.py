@@ -54,10 +54,10 @@ class LlamaConfig:
     # `multiple_of` rounding of the SwiGLU hidden dim
     # (reference: fengshen/models/megatron/layers/transformer.py:589-590)
     multiple_of: int = 256
-    # MoE: >0 replaces the dense MLP with a SwitchMoE of that many
-    # experts, sharded over the 'expert' mesh axis (beyond-reference)
+    # MoE: >0 replaces the dense MLP with that many top-1 routed
+    # experts (ops/moe.py RoutedExperts), sharded over the 'expert'
+    # mesh axis (beyond-reference)
     moe_experts: int = 0
-    moe_capacity_factor: float = 1.25
     moe_aux_weight: float = 0.01  # Switch aux-loss coefficient (α)
     # sequence packing: attention_mask carries per-example segment ids
     # (0 = pad) and position ids restart per example — the flash kernel's

@@ -457,9 +457,10 @@ class LlamaDecoderLayer(nn.Module):
         h = RMSNorm(epsilon=cfg.rms_norm_eps,
                     name="post_attention_layernorm")(hidden)
         if cfg.moe_experts > 0:
-            # routed expert MLP instead of the dense one (beyond-reference
-            # capability; aux loss sowed under ("losses","moe_aux_loss"))
-            from fengshen_tpu.ops.moe import SwitchMoE
+            # routed expert MLP instead of the dense one, in its Switch
+            # setting: softmax router, top-1, no token dropped (aux
+            # loss sowed under ("losses","moe_aux_loss"))
+            from fengshen_tpu.ops.moe import RoutedExperts
             # cached decode feeds a 1-token hidden with the full-prompt
             # mask; the live decode token is always real, so no mask
             tok_mask = attention_mask
@@ -468,15 +469,13 @@ class LlamaDecoderLayer(nn.Module):
             elif tok_mask is not None:
                 # packed rows carry segment ids; MoE only needs real/pad
                 tok_mask = (tok_mask > 0).astype(jnp.int32)
-            h, _ = SwitchMoE(
+            h = RoutedExperts(
                 hidden_size=cfg.hidden_size,
                 intermediate_size=cfg.intermediate_size,
-                num_experts=cfg.moe_experts,
-                capacity_factor=cfg.moe_capacity_factor,
+                num_experts=cfg.moe_experts, aux_loss=True,
                 dtype=_dt(cfg),
                 param_dtype=jnp.dtype(cfg.param_dtype),
-                name="moe_mlp")(h, token_mask=tok_mask,
-                                deterministic=deterministic)
+                name="moe_mlp")(h, token_mask=tok_mask)
         else:
             h = LlamaMLP(cfg, name="mlp")(h)
         return hidden + h

@@ -27,7 +27,7 @@ from fengshen_tpu.ops.attention import dot_product_attention
 from fengshen_tpu.ops.ulysses_attention import (
     ulysses_attention_sharded, sequence_parallel_attention)
 from fengshen_tpu.ops.init_functions import get_init_methods
-from fengshen_tpu.ops.moe import (SwitchMoE,
+from fengshen_tpu.ops.moe import (RoutedExperts,
                                   load_balancing_loss,
                                   MOE_PARTITION_RULES)
 from fengshen_tpu.ops.gmlp import GMLPBlock, SpatialGatingUnit, TinyAttention
@@ -45,7 +45,7 @@ __all__ = [
     "dot_product_attention",
     "ulysses_attention_sharded", "sequence_parallel_attention",
     "get_init_methods",
-    "SwitchMoE", "load_balancing_loss", "MOE_PARTITION_RULES",
+    "RoutedExperts", "load_balancing_loss", "MOE_PARTITION_RULES",
     "GMLPBlock", "SpatialGatingUnit", "TinyAttention",
     "SoftEmbedding",
 ]

@@ -1,31 +1,35 @@
-"""Mixture-of-Experts (Switch-style) with expert parallelism.
+"""Routed experts: top-k routing without dropped tokens.
 
-No reference equivalent — the reference framework has no MoE. This is a
-beyond-reference, TPU-native capability: experts live as stacked
-[E, ...] parameter tables sharded over the 'expert' mesh axis, tokens are
-dispatched with the static-shape capacity formulation (Shazeer et al.
-Mesh-TF / Fedus et al. Switch Transformer — public techniques,
-re-implemented on einsum + GSPMD), and the compiler inserts the
-token all-to-all from the sharding constraints instead of hand-coded
-collectives.
+No reference equivalent — the reference framework has no MoE. One layer
+serves every routed-expert model here: the router runs in float32 over
+ALL `num_experts` outputs, picks `top_k` of them per token, and the
+`tokens x top_k` assignments are sorted by expert so that the experts
+held here run as one grouped (ragged) matrix product over their stacked
+`[E_held, ...]` tables — every token reaches every expert it picked, no
+capacity, no `[T, E, C]` dispatch tensor. The published DeepSeek-V3
+router (sigmoid scores, a selection-only correction bias, normalised
+and scaled weights, a shared expert) and the Switch router (softmax,
+top-1) are settings of it.
 
-Shapes are fully static (capacity C per expert; overflow tokens drop and
-pass through the residual), so the whole layer jits into one program —
-no data-dependent gather/scatter.
+`experts_held = (first, count)` is a chip's share of an expert-parallel
+deployment (docs/sharding.md): the router keeps all `num_experts`
+outputs and its `top_k`, the tables hold `count` experts, assignments to
+experts outside `[first, first + count)` contribute nothing, and the
+shared expert is computed wherever `shared_here` says (once over all
+shares). On one chip the layer runs without its exchange; nothing here
+stands in for absent chips.
 """
 
 from __future__ import annotations
 
-import math
-from typing import Any, Tuple
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
 from jax.sharding import PartitionSpec as P
 
-from fengshen_tpu.parallel.mesh import BATCH_AXES, EXPERT_AXIS
-from fengshen_tpu.parallel.partition import with_sharding_constraint
+from fengshen_tpu.parallel.mesh import EXPERT_AXIS
 
 #: partition rules for the stacked expert tables ([E, in, out]) and router
 MOE_PARTITION_RULES: list[tuple[str, P]] = [
@@ -33,6 +37,15 @@ MOE_PARTITION_RULES: list[tuple[str, P]] = [
     (r".*experts_(gate|up)", P(EXPERT_AXIS, None, "tensor")),
     (r".*experts_down", P(EXPERT_AXIS, "tensor", None)),
 ]
+
+#: device scopes the trace's operations carry (docs/observability.md)
+ROUTE_SCOPE = "fstpu_moe_route"
+EXPERTS_SCOPE = "fstpu_moe_experts"
+SHARED_SCOPE = "fstpu_moe_shared"
+#: collection each layer's picks are sowed under for whoever asks
+#: (`mutable=["moe_stats"]`): "assignments", `[T, E]` int32, how many
+#: of token t's `top_k` picks went to expert e (0 or 1)
+STATS_COLLECTION = "moe_stats"
 
 
 def load_balancing_loss(router_probs: jax.Array,
@@ -55,99 +68,153 @@ def load_balancing_loss(router_probs: jax.Array,
     return num_experts * jnp.sum(f * p)
 
 
-class SwitchMoE(nn.Module):
-    """Top-1 (switch) routed SwiGLU expert MLP, drop-in for a dense MLP.
+def route(scores: jax.Array, bias: Optional[jax.Array], top_k: int,
+          norm_topk_prob: bool, scaling: float
+          ) -> Tuple[jax.Array, jax.Array]:
+    """(`[T, top_k]` expert ids, `[T, top_k]` float32 weights) from the
+    router's `[T, E]` float32 scores. The `top_k` largest of `scores +
+    bias` are picked; the weights are the picked entries of `scores`
+    itself (the bias changes the pick, never the weight), divided by
+    their sum + 1e-20 when `norm_topk_prob`, times `scaling`."""
+    choice = scores if bias is None else scores + bias
+    _, index = jax.lax.top_k(choice, top_k)
+    weight = jnp.take_along_axis(scores, index, axis=-1)
+    if norm_topk_prob:
+        weight = weight / (weight.sum(-1, keepdims=True) + 1e-20)
+    return index.astype(jnp.int32), weight * scaling
 
-    Returns (output, aux_loss). The aux loss is also sowed under
-    ("losses", "moe_aux_loss") so deeply nested callers can collect it
-    with `mutable=["losses"]` instead of threading it manually.
-    """
+
+def grouped_swiglu(x: jax.Array, index: jax.Array, weight: jax.Array,
+                   w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
+                   first: int = 0) -> jax.Array:
+    """`sum_k weight[t, k] * E_{index[t, k]}(x[t])` over the experts the
+    `[count, ...]` tables hold (`first ...`), float32 `[T, H]`.
+
+    The `T * top_k` assignments are sorted by expert (stable, so each
+    expert sees its tokens in token order), the rows gathered once, and
+    the three SwiGLU products run as `jax.lax.ragged_dot` over the
+    group sizes — on a TPU one native grouped matmul each. Assignments
+    to experts not held sort past the last group; their rows are zeroed
+    rather than trusted."""
+    tokens, top_k = index.shape
+    count = w_gate.shape[0]
+    local = index.reshape(-1) - first
+    held = (local >= 0) & (local < count)
+    local = jnp.where(held, local, count)
+    order = jnp.argsort(local, stable=True)
+    sizes = jnp.bincount(local, length=count + 1)[:count].astype(jnp.int32)
+    rows = x[order // top_k]
+    gate = jax.lax.ragged_dot(rows, w_gate, sizes)
+    up = jax.lax.ragged_dot(rows, w_up, sizes)
+    out = jax.lax.ragged_dot(nn.silu(gate) * up, w_down, sizes)
+    scale = weight.reshape(-1)[order][:, None]
+    out = jnp.where(held[order][:, None],
+                    out.astype(jnp.float32) * scale, 0.0)
+    # back to assignment order, then the top_k of a token are adjacent
+    out = out[jnp.argsort(order)]
+    return out.reshape(tokens, top_k, -1).sum(axis=1)
+
+
+class SwiGLU(nn.Module):
+    """`down(silu(gate(x)) * up(x))`, no biases."""
 
     hidden_size: int
     intermediate_size: int
-    num_experts: int
-    capacity_factor: float = 1.25
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
-    router_jitter: float = 0.0  # train-time multiplicative jitter
+    initializer_range: float = 0.02
 
     @nn.compact
-    def __call__(self, x: jax.Array, token_mask: jax.Array | None = None,
-                 deterministic: bool = True
-                 ) -> Tuple[jax.Array, jax.Array]:
-        """x: [B, S, H]; token_mask: [B, S] (1 = real token) — pads are
-        excluded from dispatch (they neither consume expert capacity nor
-        skew the load-balance statistics) and output zeros, which the
-        caller's residual carries through."""
+    def __call__(self, x):
+        dense = lambda feats, name: nn.Dense(  # noqa: E731
+            feats, use_bias=False, dtype=self.dtype,
+            param_dtype=self.param_dtype, name=name,
+            kernel_init=nn.initializers.normal(self.initializer_range))
+        h = nn.silu(dense(self.intermediate_size, "gate_proj")(x)) * \
+            dense(self.intermediate_size, "up_proj")(x)
+        return dense(self.hidden_size, "down_proj")(h)
+
+
+class RoutedExperts(nn.Module):
+    """Top-k routed SwiGLU experts (+ shared experts), drop-in for a
+    dense MLP. Returns the layer's output; with `aux_loss` the Switch
+    load-balancing term is sowed under ("losses", "moe_aux_loss"), and
+    the picks always under (`STATS_COLLECTION`, "assignments")."""
+
+    hidden_size: int
+    intermediate_size: int            # one expert's width
+    num_experts: int
+    top_k: int = 1
+    scoring: str = "softmax"          # softmax | sigmoid
+    score_bias: bool = False          # e_score_correction_bias
+    norm_topk_prob: bool = False
+    routed_scaling_factor: float = 1.0
+    n_shared_experts: int = 0
+    #: (first, count) of the experts held here; None = all of them
+    experts_held: Optional[Tuple[int, int]] = None
+    #: whether this share adds the shared experts (one share does)
+    shared_here: bool = True
+    aux_loss: bool = False
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+    initializer_range: float = 0.02
+
+    @nn.compact
+    def __call__(self, x: jax.Array,
+                 token_mask: jax.Array | None = None) -> jax.Array:
+        """x: [B, S, H]; token_mask: [B, S] (1 = real token) — pads
+        give zeros and stay out of the aux statistics."""
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown scoring {self.scoring!r}")
         batch, seq, hidden = x.shape
-        E = self.num_experts
-        tokens = batch * seq
-        capacity = max(1, int(math.ceil(
-            tokens / E * self.capacity_factor)))
+        E, F = self.num_experts, self.intermediate_size
+        first, count = self.experts_held or (0, E)
+        xt = x.reshape(batch * seq, hidden)
+        init = nn.initializers.normal(self.initializer_range)
 
-        xt = x.reshape(tokens, hidden)
+        with jax.named_scope(ROUTE_SCOPE):
+            # float32 with every pass: on a TPU a float32 matmul takes
+            # fewer bf16 passes unless asked, and a rounded score flips
+            # picks
+            logits = nn.Dense(
+                E, use_bias=False, dtype=jnp.float32,
+                param_dtype=jnp.float32, kernel_init=init,
+                precision=jax.lax.Precision.HIGHEST,
+                name="router")(xt.astype(jnp.float32))
+            scores = jax.nn.sigmoid(logits) if self.scoring == "sigmoid" \
+                else jax.nn.softmax(logits, axis=-1)
+            bias = self.param("e_score_correction_bias",
+                              nn.initializers.zeros, (E,), jnp.float32) \
+                if self.score_bias else None
+            index, weight = route(scores, bias, self.top_k,
+                                  self.norm_topk_prob,
+                                  self.routed_scaling_factor)
+        self.sow(STATS_COLLECTION, "assignments",
+                 jax.nn.one_hot(index, E, dtype=jnp.int32).sum(axis=1),
+                 init_fn=lambda: None, reduce_fn=lambda _, new: new)
         tm = None if token_mask is None else \
-            token_mask.reshape(tokens).astype(jnp.float32)
+            token_mask.reshape(-1).astype(jnp.float32)
+        if self.aux_loss:
+            self.sow("losses", "moe_aux_loss", load_balancing_loss(
+                scores, index[:, 0], E, token_mask=tm))
 
-        # --- router (fp32 for a stable softmax) ---
-        router_in = xt
-        if self.router_jitter > 0.0 and not deterministic:
-            key = self.make_rng("dropout")
-            router_in = router_in * jax.random.uniform(
-                key, router_in.shape, router_in.dtype,
-                1.0 - self.router_jitter, 1.0 + self.router_jitter)
-        logits = nn.Dense(E, use_bias=False, dtype=jnp.float32,
-                          param_dtype=jnp.float32,
-                          name="router")(router_in.astype(jnp.float32))
-        probs = jax.nn.softmax(logits, axis=-1)                 # [T, E]
-        gate = probs.max(axis=-1)                               # [T]
-        expert_index = probs.argmax(axis=-1).astype(jnp.int32)  # [T]
-
-        aux = load_balancing_loss(probs, expert_index, E, token_mask=tm)
-        self.sow("losses", "moe_aux_loss", aux)
-
-        # --- static-capacity dispatch (Mesh-TF formulation) ---
-        onehot = jax.nn.one_hot(expert_index, E, dtype=jnp.float32)
-        if tm is not None:
-            onehot = onehot * tm[:, None]  # pads claim no capacity slot
-        # position of each token within its expert's queue
-        pos = jnp.einsum("te,te->t", jnp.cumsum(onehot, axis=0) - 1.0,
-                         onehot).astype(jnp.int32)              # [T]
-        keep = pos < capacity
-        dispatch = (onehot * keep[:, None].astype(jnp.float32))[..., None] \
-            * jax.nn.one_hot(jnp.clip(pos, 0, capacity - 1), capacity,
-                             dtype=jnp.float32)[:, None, :]     # [T, E, C]
-        combine = dispatch * gate[:, None, None]                # [T, E, C]
-
-        expert_in = jnp.einsum("tec,th->ech", dispatch,
-                               xt.astype(jnp.float32)
-                               ).astype(self.dtype)             # [E, C, H]
-        expert_in = with_sharding_constraint(
-            expert_in, P(EXPERT_AXIS, None, None))
-
-        # --- per-expert SwiGLU over stacked tables ---
-        init = nn.initializers.normal(0.02)
-        w_gate = self.param("experts_gate", init,
-                            (E, hidden, self.intermediate_size),
+        w_gate = self.param("experts_gate", init, (count, hidden, F),
                             self.param_dtype)
-        w_up = self.param("experts_up", init,
-                          (E, hidden, self.intermediate_size),
+        w_up = self.param("experts_up", init, (count, hidden, F),
                           self.param_dtype)
-        w_down = self.param("experts_down", init,
-                            (E, self.intermediate_size, hidden),
+        w_down = self.param("experts_down", init, (count, F, hidden),
                             self.param_dtype)
-        g = jnp.einsum("ech,ehf->ecf", expert_in,
-                       w_gate.astype(self.dtype))
-        u = jnp.einsum("ech,ehf->ecf", expert_in,
-                       w_up.astype(self.dtype))
-        h = nn.silu(g) * u
-        h = with_sharding_constraint(h, P(EXPERT_AXIS, None, "tensor"))
-        expert_out = jnp.einsum("ecf,efh->ech", h,
-                                w_down.astype(self.dtype))      # [E, C, H]
-
-        # --- combine (dropped tokens get zeros → caller's residual) ---
-        out = jnp.einsum("tec,ech->th", combine,
-                         expert_out.astype(jnp.float32))
-        out = out.reshape(batch, seq, hidden).astype(x.dtype)
-        out = with_sharding_constraint(out, P(BATCH_AXES, "sequence", None))
-        return out, aux
+        with jax.named_scope(EXPERTS_SCOPE):
+            out = grouped_swiglu(
+                xt.astype(self.dtype), index, weight,
+                w_gate.astype(self.dtype), w_up.astype(self.dtype),
+                w_down.astype(self.dtype), first)
+        if self.n_shared_experts and self.shared_here:
+            with jax.named_scope(SHARED_SCOPE):
+                out = out + SwiGLU(
+                    hidden, F * self.n_shared_experts, self.dtype,
+                    self.param_dtype, self.initializer_range,
+                    name="shared_experts")(xt).astype(jnp.float32)
+        if tm is not None:
+            out = out * tm[:, None]
+        return out.reshape(batch, seq, hidden).astype(x.dtype)

@@ -377,3 +377,81 @@ def pallas_decode_attention(q, k, v, valid, *, k_scale=None,
                 vmem_limit_bytes=_VMEM_LIMIT_BYTES),
             interpret=interpret, name=TRACE_NAME,
         )(tables, *operands)
+
+
+# -- the latent (MLA) read ----------------------------------------------
+
+#: the name the latent read carries into HLO and the trace
+MLA_TRACE_NAME = "fstpu_mla_decode_attention"
+
+#: why every latent read takes the xla lowering today: the kernel above
+#: folds tokens x KV heads into one key axis (`KVH % 8`, `head_dim %
+#: 128`); a latent row is ONE shared head of `rank + rope` values
+_NO_LATENT_KERNEL = "no Mosaic kernel walks one shared latent head yet"
+
+
+def mla_decode_attention(q_latent: jax.Array, q_rope: jax.Array,
+                         kv: jax.Array, valid: jax.Array, *, scale: float,
+                         block_table: Optional[jax.Array] = None,
+                         layer: Optional[jax.Array] = None) -> jax.Array:
+    """The seam's latent entry: absorbed multi-head latent attention
+    over a cache of one row `[c_kv (rank) | k_rope | zeros]` per token
+    (the zeros pad the row to whole lanes; the row is as wide as the
+    cache says).
+
+    q_latent: ``[B, S, H, rank]`` (the no-position query already
+    multiplied into the latent space), q_rope: ``[B, S, H, rope]``.
+    kv: ``[B, max_len, 1, width]`` slot/lockstep cache, or the
+    shared ``[num_blocks, block_size, 1, width]`` pool behind
+    ``block_table``; with ``layer`` the ``[L, ...]`` stack of either,
+    read in place as :func:`_layer_of_stack` reads K/V. ``valid``:
+    ``[B, S, L]`` bool. All ``H`` query heads share the row: its first
+    ``rank`` values are the key's no-position part AND the value.
+    Returns ``[B, S, H, rank]``, still latent: the caller's
+    up-projection turns it into heads of values."""
+    from fengshen_tpu.ops.pallas import resolve_dispatch
+    # recorded, not decided: the xla lowering is the only one there is
+    resolve_dispatch(
+        "mla_decode_attention",
+        f"q={tuple(q_latent.shape)}+{q_rope.shape[-1]} "
+        f"kv={tuple(kv.shape[-4:])}:{kv.dtype.name} "
+        f"{'paged' if block_table is not None else 'slot'}",
+        _NO_LATENT_KERNEL)
+    with jax.named_scope(MLA_TRACE_NAME):
+        return xla_mla_decode_attention(
+            q_latent, q_rope, kv, valid, scale=scale,
+            block_table=block_table, layer=layer)
+
+
+def xla_mla_decode_attention(q_latent, q_rope, kv, valid, *, scale,
+                             block_table=None, layer=None):
+    """The stock lowering and the CPU tier-1 truth: a paged pool
+    gathers into the contiguous virtual lane (a copy of every attended
+    row: what a kernel would save) a whole BLOCK at a time — one
+    `block_size x width` slab per table entry; gathered row by row the
+    same copy ran at a seventh of the memory's rate on the chip
+    (PERF.md, PR 26) — one product of the concatenated query against
+    the whole row gives the scores, and the probabilities weigh the
+    row's first ``rank`` values."""
+    rank = q_latent.shape[-1]
+    if block_table is not None:
+        if layer is not None:
+            num_blocks = kv.shape[1]
+            kv = kv.reshape((-1,) + kv.shape[2:])
+            block_table = block_table + layer * num_blocks
+        blocks = jnp.take(kv[:, :, 0, :], block_table, axis=0,
+                          mode="clip")                   # [B, mb, bs, R]
+        rows = blocks.reshape(blocks.shape[0], -1, blocks.shape[-1])
+    else:
+        if layer is not None:
+            kv = kv[layer]
+        rows = kv[:, :, 0, :]
+    pad = jnp.zeros(q_rope.shape[:-1] + (
+        rows.shape[-1] - rank - q_rope.shape[-1],), q_rope.dtype)
+    q = jnp.concatenate([q_latent, q_rope, pad], axis=-1).astype(rows.dtype)
+    scores = jnp.einsum("bshd,btd->bhst", q, rows,
+                        preferred_element_type=jnp.float32) * scale
+    scores = jnp.where(valid[:, None], scores, _NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1).astype(rows.dtype)
+    return jnp.einsum("bhst,btc->bshc", probs,
+                      rows[..., :rank]).astype(q_latent.dtype)

@@ -11,8 +11,9 @@ request into a free lane, and reset reclaimed lanes — all shape-static,
 so ONE jitted decode step serves every in-flight mix.
 
 Leaf layout contract (holds for the whole zoo, scan_layers or not):
-`cached_key`/`cached_value` end in (..., batch, max_len, kv_heads,
-head_dim) and `cache_index` is scalar per layer — identified by path
+the row leaves a model declares (`cached_key`/`cached_value`, or one
+`cached_latent` under latent attention) end in (..., batch, max_len,
+heads, dim) and `cache_index` is scalar per layer — identified by path
 via `utils.generate.is_cache_index_path`, the same predicate
 `_rollback_cache` keys on.
 """
@@ -25,15 +26,23 @@ import jax.numpy as jnp
 from fengshen_tpu.utils.generate import is_cache_index_path
 
 
-def init_slot_cache(model, num_slots: int):
-    """Zeros cache pytree with `num_slots` lanes and VECTOR cache_index
-    leaves (`[num_slots]`, or `[layers, num_slots]` under scan_layers).
-    Abstract-init only — no param materialisation (same trick as
+def abstract_init(model, num_slots: int):
+    """The model's `init_cache` pass over one token a lane, abstract:
+    every collection it creates ("cache", and whatever it sows) as
+    shapes, no param materialised (same trick as
     `utils.generate._prefill_cache`)."""
-    abstract = jax.eval_shape(
+    return jax.eval_shape(
         lambda: model.init(jax.random.PRNGKey(0),
                            jnp.zeros((num_slots, 1), jnp.int32),
                            init_cache=True))
+
+
+def init_slot_cache(model, num_slots: int, abstract=None):
+    """Zeros cache pytree with `num_slots` lanes and VECTOR cache_index
+    leaves (`[num_slots]`, or `[layers, num_slots]` under scan_layers).
+    `abstract`: an `abstract_init` the caller already made."""
+    if abstract is None:
+        abstract = abstract_init(model, num_slots)
 
     def build(path, leaf):
         if is_cache_index_path(path):

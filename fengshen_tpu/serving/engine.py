@@ -75,8 +75,9 @@ from fengshen_tpu.observability import (RequestTimeline,
                                         record_warmup_seconds, span)
 from fengshen_tpu.ops.pallas import kernel_fingerprint, log_dispatch
 from fengshen_tpu.serving.buckets import DEFAULT_BUCKETS, BucketLadder
-from fengshen_tpu.serving.cache import (assign_slot, init_slot_cache,
-                                        reset_free_slots, rollback_slots)
+from fengshen_tpu.serving.cache import (abstract_init, assign_slot,
+                                        init_slot_cache, reset_free_slots,
+                                        rollback_slots)
 from fengshen_tpu.serving.paged_cache import (BlockAllocator,
                                               assign_paged,
                                               assign_slot_quantized,
@@ -288,11 +289,38 @@ class Request:
         return self._done.is_set()
 
 
+def _moe_stats_shape(abstract, num_slots: int):
+    """(expert layers, experts) of the routing a model sows under
+    "moe_stats" on a decode tick (`ops/moe.py`), or None where it sows
+    none; `abstract` is the model's `abstract_init` over `num_slots`
+    lanes."""
+    leaves = jax.tree_util.tree_leaves(abstract.get("moe_stats", {}))
+    if not leaves:
+        return None
+    experts = leaves[0].shape[-1]
+    return (sum(leaf.size for leaf in leaves) // (num_slots * experts),
+            experts)
+
+
+def _live_assignments(moe_stats, active):
+    """`[expert layers, experts]` int32: the assignments of the live
+    lanes' tokens. Leaves are `[tokens, experts]` a layer, or their
+    `[layers, ...]` stack under a layer scan; a lane's tokens are
+    adjacent."""
+    lanes = active.shape[0]
+    per_layer = jnp.concatenate([
+        leaf.reshape((-1, lanes, leaf.shape[-2] // lanes, leaf.shape[-1]))
+        for leaf in jax.tree_util.tree_leaves(moe_stats)])
+    return (per_layer * active[None, :, None, None]).sum(axis=(1, 2))
+
+
 class ContinuousBatchingEngine:
     """Slot-pool continuous batching over one decoder-only model.
 
     `model` must use the repo's preallocated flax cache contract
-    (cached_key/cached_value/cache_index — the LLaMA family). `clock`
+    (`cached_*` row leaves beside a `cache_index`: `cached_key` /
+    `cached_value` in the LLaMA family, one `cached_latent` under
+    latent attention; `paged_cache.row_leaves`). `clock`
     is injectable for deterministic deadline tests. `aot` is an
     optional `fengshen_tpu.aot.AotSetup`: when given, the prefill /
     assign / decode programs route through the persistent executable
@@ -409,7 +437,13 @@ class ContinuousBatchingEngine:
             self._draft_cache = init_slot_cache(self._draft_model, S)
 
         L = self.seq_capacity
+        #: the model's init_cache pass as shapes: the pool is built
+        #: from its "cache", the routing's shape read off its "moe_stats"
+        self._abstract_init = abstract_init(model, S)
         self._cache = self._init_pool()
+        #: (expert layers, experts) where the model sows its routing
+        #: ("moe_stats"); None for a model without routed experts
+        self._moe_shape = _moe_stats_shape(self._abstract_init, S)
         self._kv_bytes = sum(
             leaf.nbytes for path, leaf in
             jax.tree_util.tree_flatten_with_path(self._cache)[0]
@@ -536,6 +570,7 @@ class ContinuousBatchingEngine:
                     return cache, dpool, history, mask
 
         gamma, ngram = cfg.spec_gamma, cfg.spec_ngram
+        moe_shape = self._moe_shape
         if self.self_draft:
             draft_model = self._draft_model
 
@@ -716,7 +751,8 @@ class ContinuousBatchingEngine:
                 logits, mutated = model.apply(
                     {"params": params, "cache": cache}, tokens[:, None],
                     attention_mask=mask, position_ids=pos[:, None],
-                    init_cache=True, mutable=["cache"])
+                    init_cache=True, mutable=["cache"] + (
+                        ["moe_stats"] if moe_shape else []))
                 cache = mutated["cache"] if paged else \
                     reset_free_slots(mutated["cache"], active)
                 step_logits = logits[:, -1]
@@ -734,7 +770,13 @@ class ContinuousBatchingEngine:
                                         cfg.temperature, cfg.top_k,
                                         cfg.top_p)
                 nxt = jnp.where(active, nxt, cfg.pad_token_id)
-                return cache, history, keys_out, nxt.astype(jnp.int32)
+                nxt = nxt.astype(jnp.int32)
+                if moe_shape:
+                    # the live lanes' routing rides the tokens' fetch:
+                    # one array, one transfer a tick
+                    nxt = jnp.concatenate([nxt, _live_assignments(
+                        mutated["moe_stats"], active).reshape(-1)])
+                return cache, history, keys_out, nxt
 
         # one compile per bucket width / exactly one for decode — the
         # parity + compile-count tests pin this via _cache_size().
@@ -796,15 +838,18 @@ class ContinuousBatchingEngine:
         """Zeros KV pool in the configured (layout, dtype)."""
         cfg = self.config
         if not self.paged and cfg.kv_dtype == "fp32":
-            return init_slot_cache(self.model, cfg.num_slots)
+            return init_slot_cache(self.model, cfg.num_slots,
+                                   self._abstract_init)
         if self.paged:
             return init_pool_cache(
                 self.model, cfg.num_slots, layout="paged",
                 kv_dtype=cfg.kv_dtype, num_blocks=self.num_blocks,
                 block_size=self.block_size,
-                max_blocks_per_slot=self.max_blocks_per_slot)
+                max_blocks_per_slot=self.max_blocks_per_slot,
+                abstract=self._abstract_init)
         return init_pool_cache(self.model, cfg.num_slots, layout="slot",
-                               kv_dtype=cfg.kv_dtype)
+                               kv_dtype=cfg.kv_dtype,
+                               abstract=self._abstract_init)
 
     # ---- submission side -------------------------------------------
 
@@ -1181,6 +1226,10 @@ class ContinuousBatchingEngine:
             with span("fetch"):
                 nxt = np.array(nxt)
         dt = time.perf_counter() - t0
+        if self._moe_shape:
+            S = self.config.num_slots
+            self.metrics.record_moe(nxt[S:].reshape(self._moe_shape))
+            nxt = nxt[:S]
         with span("serving/commit", lanes=lanes, tokens=lanes):
             return self._commit_plain(active_idx, nxt, dt, kv_tokens)
 

@@ -45,7 +45,7 @@ import numpy as np
 from fengshen_tpu.disagg import transfer
 from fengshen_tpu.ops.int8_matmul import dequantize_kv, quantize_kv
 from fengshen_tpu.serving.engine import RUNNING, Request
-from fengshen_tpu.serving.paged_cache import (_map_attn_dicts,
+from fengshen_tpu.serving.paged_cache import (_map_attn_dicts, row_leaves,
                                               blocks_for_tokens)
 
 #: wire header constants — adopt declines any mismatch with "version"
@@ -128,6 +128,26 @@ def _scatter_lane(leaf, axis: int, val, slot: Optional[int],
     return flat.at[idx].set(val).reshape(leaf.shape)
 
 
+#: the row leaves the wire format carries (its "k" and "v" entries)
+WIRE_LEAVES = ["cached_key", "cached_value"]
+
+
+def _undeclared_on_wire(engine) -> Optional[str]:
+    """None when the engine's cache declares exactly the K/V pair the
+    wire carries; else the one loud sentence both sides refuse with.
+    A latent cache (one `cached_latent` row a token) has no wire
+    format yet (ROADMAP D4)."""
+    found: List[list] = []
+    _map_attn_dicts(engine._cache,
+                    lambda d: found.append(row_leaves(d)) or d)
+    odd = [names for names in found if names != WIRE_LEAVES]
+    if not odd:
+        return None
+    return (f"the handoff wire carries {WIRE_LEAVES} lanes; this "
+            f"engine's cache declares {odd[0]}, which has no wire "
+            "format yet")
+
+
 def export_lane(engine, request_id: str) -> dict:
     """Serialize the RUNNING request `request_id` into a sealed wire
     payload. The engine keeps decoding the lane afterwards — export is
@@ -143,6 +163,9 @@ def export_lane(engine, request_id: str) -> dict:
             raise HandoffError(
                 "speculative engines do not export lanes "
                 "(no committed cursor inside a verify window)")
+        refusal = _undeclared_on_wire(engine)
+        if refusal:
+            raise HandoffError(refusal)
         req = None
         for r in engine._slot_req:
             if r is not None and r.request_id == request_id:
@@ -364,6 +387,9 @@ def _scatter_payload(engine, payload: dict, slot: int, phys: int,
     handoff never round-trips through float); fp32 receivers store the
     dequantized prefix. Raises AdoptDecline("shape") before touching
     anything when any layer disagrees with the local pool geometry."""
+    refusal = _undeclared_on_wire(engine)
+    if refusal:
+        raise AdoptDecline("shape", refusal)
     int8_dst = engine.config.kv_dtype == "int8"
     layers = payload["layers"]
     n_layers = [0]

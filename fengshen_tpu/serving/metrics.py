@@ -83,6 +83,23 @@ class EngineMetrics:
             "fstpu_serving_kv_tokens_attended_total",
             "per-tick sum over active lanes of the real cached tokens "
             "the tick's attention reads (bucket padding out)")
+        # routed-expert models only (engine._commit_plain): what a
+        # tick's live lanes asked of each expert layer
+        self._moe_assignments = r.counter(
+            "fstpu_moe_assignments_total",
+            "token-to-expert assignments of live lanes, over the "
+            "expert layers of every plain decode tick")
+        self._moe_touched = r.counter(
+            "fstpu_moe_experts_touched_total",
+            "experts with at least one assignment, summed over "
+            "layer-ticks")
+        self._moe_layer_ticks = r.counter(
+            "fstpu_moe_layer_ticks_total",
+            "expert layers run by plain decode ticks")
+        self._moe_max_tokens = r.counter(
+            "fstpu_moe_max_expert_tokens_total",
+            "assignments of the busiest expert, summed over "
+            "layer-ticks (the straggler)")
         self._submit_lock_wait = r.histogram(
             "fstpu_serving_submit_lock_wait_seconds",
             "submit()'s wait for the scheduler's lock", window=window)
@@ -177,6 +194,14 @@ class EngineMetrics:
         if n_active > self._peak_active:
             self._peak_active = n_active
             self._g_peak.set(n_active)
+
+    def record_moe(self, histogram) -> None:
+        """`histogram`: `[expert layers, experts]` assignments of one
+        tick's live lanes (numpy, already on the host)."""
+        self._moe_assignments.inc(int(histogram.sum()))
+        self._moe_touched.inc(int((histogram > 0).sum()))
+        self._moe_layer_ticks.inc(histogram.shape[0])
+        self._moe_max_tokens.inc(int(histogram.max(axis=1).sum()))
 
     def record_spec(self, drafted: int, accepted: int) -> None:
         """One speculative verify's draft/accept tallies; keeps the

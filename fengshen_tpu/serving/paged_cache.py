@@ -5,8 +5,10 @@ per slot, so a replica's concurrency is bounded by WORST-CASE sequence
 length even when most requests are short. This module carves the same
 byte budget into fixed-size blocks instead (the paged-attention idea):
 
-- device side: per layer, `cached_key`/`cached_value` become a shared
-  `[num_blocks, block_size, kv_heads, head_dim]` pool plus a
+- device side: per layer, each row leaf the model declares
+  (`row_leaves`: `cached_key`/`cached_value`, or one `cached_latent`
+  under latent attention) becomes a shared
+  `[num_blocks, block_size, heads, dim]` pool plus a
   shape-static `[num_slots, max_blocks_per_slot]` `block_table` of
   block ids and a `[num_slots]` `cache_index` of physical cursors.
   `modeling_llama._update_paged_cache` scatters each decode step at
@@ -36,12 +38,14 @@ the slot layout (`init_pool_cache(layout="slot", kv_dtype="int8")`).
 
 from __future__ import annotations
 
+import math
 from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
 
 from fengshen_tpu.ops.int8_matmul import quantize_kv
+from fengshen_tpu.serving.cache import abstract_init
 
 #: the reserved garbage block free lanes point at (never allocated)
 NULL_BLOCK = 0
@@ -111,12 +115,23 @@ class BlockAllocator:
             self._free.append(b)
 
 
+def row_leaves(d: dict) -> list:
+    """The per-token state an attention-cache dict declares: its
+    `cached_*` leaves (row shape `[heads, dim]`), the int8 scales
+    beside them left out. A K/V model declares `cached_key` and
+    `cached_value`, a latent-attention model one `cached_latent`; the
+    pool is built, filled and read from whatever is declared here."""
+    return sorted(k for k in d if k.startswith("cached_")
+                  and not k.endswith("_scale"))
+
+
 def _map_attn_dicts(tree, fn):
     """Rebuild a cache pytree, applying `fn` to every attention-cache
-    dict (the one holding `cached_key`). Works for scan and non-scan
-    layouts alike — the structure is nested plain dicts either way."""
+    dict (the one holding `cache_index` beside its row leaves). Works
+    for scan and non-scan layouts alike — the structure is nested plain
+    dicts either way."""
     if isinstance(tree, dict):
-        if "cached_key" in tree:
+        if "cache_index" in tree:
             return fn(tree)
         return {k: _map_attn_dicts(v, fn) for k, v in tree.items()}
     return tree
@@ -126,7 +141,7 @@ def _zip_attn_dicts(pool, primed, fn):
     """Like `_map_attn_dicts` but walks the pool and a primed batch-1
     cache (which lacks the paged/scale leaves) in lockstep."""
     if isinstance(pool, dict):
-        if "cached_key" in pool:
+        if "cache_index" in pool:
             return fn(pool, primed)
         return {k: _zip_attn_dicts(v, primed[k], fn) for k, v in
                 pool.items()}
@@ -143,40 +158,36 @@ def _vmap_layers(fn, lead: int):
 
 def init_pool_cache(model, num_slots: int, *, layout: str = "slot",
                     kv_dtype: str = "fp32", num_blocks: int = 0,
-                    block_size: int = 0, max_blocks_per_slot: int = 0):
+                    block_size: int = 0, max_blocks_per_slot: int = 0,
+                    abstract=None):
     """Zeros KV pool for the engine — the one constructor for all four
     (layout, dtype) combinations. Abstract-init only, like
     `cache.init_slot_cache` (which this generalizes; the fp32 slot
-    result is structurally identical to it)."""
+    result is structurally identical to it); `abstract` is a
+    `cache.abstract_init` the caller already made."""
     if layout not in ("slot", "paged"):
         raise ValueError(f"unknown kv layout {layout!r}")
     if kv_dtype not in ("fp32", "int8"):
         raise ValueError(f"unknown kv dtype {kv_dtype!r}")
-    abstract = jax.eval_shape(
-        lambda: model.init(jax.random.PRNGKey(0),
-                           jnp.zeros((num_slots, 1), jnp.int32),
-                           init_cache=True))
+    if abstract is None:
+        abstract = abstract_init(model, num_slots)
 
     def build(d):
-        ck = d["cached_key"]
-        lead = ck.shape[:-4]                 # (layers,) under scan
-        n_kv, head_dim = ck.shape[-2:]
-        pool_dt = jnp.int8 if kv_dtype == "int8" else ck.dtype
-        if layout == "paged":
-            val_shape = lead + (num_blocks, block_size, n_kv, head_dim)
-            scale_shape = lead + (num_blocks, block_size, n_kv)
-        else:
-            val_shape = lead + d["cached_key"].shape[-4:]
-            scale_shape = lead + ck.shape[-4:-1]
-        out = {
-            "cached_key": jnp.zeros(val_shape, pool_dt),
-            "cached_value": jnp.zeros(val_shape, pool_dt),
-            "cache_index": jnp.zeros(lead + (num_slots,), jnp.int32),
-        }
-        if kv_dtype == "int8":
-            out["cached_key_scale"] = jnp.zeros(scale_shape, jnp.float32)
-            out["cached_value_scale"] = jnp.zeros(scale_shape,
-                                                  jnp.float32)
+        out = {}
+        for name in row_leaves(d):
+            leaf = d[name]
+            lead = leaf.shape[:-4]           # (layers,) under scan
+            heads, dim = leaf.shape[-2:]
+            if layout == "paged":
+                val_shape = lead + (num_blocks, block_size, heads, dim)
+            else:
+                val_shape = leaf.shape
+            out[name] = jnp.zeros(
+                val_shape, jnp.int8 if kv_dtype == "int8" else leaf.dtype)
+            if kv_dtype == "int8":
+                out[name + "_scale"] = jnp.zeros(val_shape[:-1],
+                                                 jnp.float32)
+        out["cache_index"] = jnp.zeros(lead + (num_slots,), jnp.int32)
         if layout == "paged":
             out["block_table"] = jnp.zeros(
                 lead + (num_slots, max_blocks_per_slot), jnp.int32)
@@ -189,27 +200,20 @@ def assign_slot_quantized(pool, primed, slot):
     lane (the direct `_prefill_cache` output) per (token, head) while
     scattering it into int8 lane `slot`. `slot` may be traced."""
     def put(pool_d, prim_d):
-        lead = pool_d["cached_key"].ndim - 4
-
         def vals(pool_leaf, prim_leaf, pick):
             def one(p, s):
                 return jax.lax.dynamic_update_slice(
                     p, pick(quantize_kv(s[0]))[None], (slot,) +
                     (0,) * (p.ndim - 1))
-            return _vmap_layers(one, lead)(pool_leaf, prim_leaf)
+            return _vmap_layers(one, prim_leaf.ndim - 4)(pool_leaf,
+                                                         prim_leaf)
 
         out = dict(pool_d)
-        out["cached_key"] = vals(pool_d["cached_key"],
-                                 prim_d["cached_key"], lambda qs: qs[0])
-        out["cached_value"] = vals(pool_d["cached_value"],
-                                   prim_d["cached_value"],
-                                   lambda qs: qs[0])
-        out["cached_key_scale"] = vals(pool_d["cached_key_scale"],
-                                       prim_d["cached_key"],
-                                       lambda qs: qs[1])
-        out["cached_value_scale"] = vals(pool_d["cached_value_scale"],
-                                         prim_d["cached_value"],
-                                         lambda qs: qs[1])
+        for name in row_leaves(pool_d):
+            out[name] = vals(pool_d[name], prim_d[name],
+                             lambda qs: qs[0])
+            out[name + "_scale"] = vals(pool_d[name + "_scale"],
+                                        prim_d[name], lambda qs: qs[1])
         out["cache_index"] = pool_d["cache_index"].at[..., slot].set(
             prim_d["cache_index"].astype(pool_d["cache_index"].dtype))
         return out
@@ -228,37 +232,45 @@ def assign_paged(pool, primed, slot, table_row):
     bucket, mirroring `assign_slot`. Quantizes on the way in when the
     pool is int8."""
     def put(pool_d, prim_d):
-        ck = pool_d["cached_key"]
-        lead = ck.ndim - 4
-        num_blocks, block_size = ck.shape[-4:-2]
+        names = row_leaves(pool_d)
+        first = pool_d[names[0]]
+        lead = first.ndim - 4
+        num_blocks, block_size = first.shape[-4:-2]
         max_blocks = pool_d["block_table"].shape[-1]
         virt_len = max_blocks * block_size
-        int8 = "cached_key_scale" in pool_d
-        positions = ((table_row * block_size)[:, None] +
-                     jnp.arange(block_size)[None, :]).reshape(-1)
+
+        # every layer's blocks in ONE scatter of whole blocks into the
+        # stack viewed as `layers * num_blocks` blocks (layer l's block
+        # b is block l * num_blocks + b): the buffer is updated in
+        # place, and the scatter pays its per-index cost once a block.
+        # A scatter mapped over the layers re-laid a one-head pool out
+        # and back; one index a row took 3.6 times as long (PERF.md,
+        # PR 26)
+        layers = math.prod(first.shape[:lead])
+        blocks = (jnp.arange(layers)[:, None] * num_blocks +
+                  table_row[None, :]).reshape(-1)
 
         def vals(pool_leaf, prim_leaf, pick):
-            def one(p, s):
-                src = s[0, :virt_len]            # [V, kv, hd] fp32
-                val = pick(quantize_kv(src)) if int8 else \
-                    src.astype(p.dtype)
-                flat = p.reshape((num_blocks * block_size,) + p.shape[2:])
-                return flat.at[positions].set(val).reshape(p.shape)
-            return _vmap_layers(one, lead)(pool_leaf, prim_leaf)
+            rest = pool_leaf.shape[lead + 1:]    # (block_size, heads[, dim])
+            src = prim_leaf.reshape((layers,) + prim_leaf.shape[lead:])[
+                :, 0, :virt_len]                 # [L, V, heads, dim] fp
+            val = src.astype(pool_leaf.dtype) if pick is None else \
+                pick(quantize_kv(src))
+            flat = pool_leaf.reshape((layers * num_blocks,) + rest)
+            return flat.at[blocks].set(
+                val.reshape((layers * max_blocks,) + rest)
+            ).reshape(pool_leaf.shape)
 
         out = dict(pool_d)
-        out["cached_key"] = vals(pool_d["cached_key"],
-                                 prim_d["cached_key"], lambda qs: qs[0])
-        out["cached_value"] = vals(pool_d["cached_value"],
-                                   prim_d["cached_value"],
-                                   lambda qs: qs[0])
-        if int8:
-            out["cached_key_scale"] = vals(pool_d["cached_key_scale"],
-                                           prim_d["cached_key"],
-                                           lambda qs: qs[1])
-            out["cached_value_scale"] = vals(
-                pool_d["cached_value_scale"], prim_d["cached_value"],
-                lambda qs: qs[1])
+        for name in names:
+            if name + "_scale" in pool_d:        # an int8 pool
+                out[name] = vals(pool_d[name], prim_d[name],
+                                 lambda qs: qs[0])
+                out[name + "_scale"] = vals(
+                    pool_d[name + "_scale"], prim_d[name],
+                    lambda qs: qs[1])
+            else:
+                out[name] = vals(pool_d[name], prim_d[name], None)
         out["cache_index"] = pool_d["cache_index"].at[..., slot].set(
             prim_d["cache_index"].astype(pool_d["cache_index"].dtype))
         out["block_table"] = pool_d["block_table"].at[

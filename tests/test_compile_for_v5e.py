@@ -1,0 +1,172 @@
+"""Programs of the serving path compiled for a DESCRIBED TPU v5e (no
+chip attached; the TPU's compiler is installed here): what the CPU
+backend cannot show. Nothing runs, so nothing here is a result or a
+time. All such compiles live in this one file, behind one fixture, so
+that one pytest worker loads the TPU's library (on-chip-measurement
+guide, section 2).
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means no compiler
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_compile_cache():
+    """A compile for a described device is written to the persistent
+    cache and cannot be read back without a chip."""
+    from jax.experimental.compilation_cache import compilation_cache
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _abstract(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def test_latent_decode_tick_updates_its_pool_in_place(one_chip,
+                                                      no_compile_cache):
+    """The continuous-batching engine's decode program over a paged
+    latent pool (rows of 128 values: whole lanes, as the published
+    model's 640): no copy, dynamic-slice or dynamic-update-slice of the
+    stack's or of one layer's pool shape, and the donated pool aliased
+    to the returned one. A row that is not whole lanes wide gets a
+    transposed default layout and the program then copies the whole
+    pool twice a tick (PERF.md, PR 26)."""
+    from fengshen_tpu.models.joyai import JoyAIConfig, JoyAIForCausalLM
+    from fengshen_tpu.serving import ContinuousBatchingEngine, EngineConfig
+    cfg = JoyAIConfig.small_test_config(dtype="bfloat16",
+                                        param_dtype="bfloat16")
+    model = JoyAIForCausalLM(cfg)
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    eng = ContinuousBatchingEngine(
+        model, params, EngineConfig(num_slots=8, buckets=(16, 32),
+                                    max_new_tokens=16, kv_layout="paged",
+                                    kv_block_size=16, kv_num_blocks=33))
+    pool = eng._cache["model"]["cached_latent"]
+    assert pool.shape == (3, 33, 16, 1, 128)
+    args = _abstract((params, eng._cache, eng._history, eng._mask,
+                      jnp.asarray(eng._last_tok), jnp.asarray(eng._pos),
+                      jnp.asarray(eng._phys), jnp.asarray(eng._active),
+                      eng._keys), one_chip)
+    compiled = eng._decode_jit.lower(*args).compile()
+    shapes = {pool.shape, (1,) + pool.shape[1:], pool.shape[1:]}
+    found = []
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* "
+                     r"(copy|dynamic-slice|dynamic-update-slice)\(", line)
+        if m and tuple(int(d) for d in m.group(1).split(",") if d) \
+                in shapes:
+            found.append(line.strip()[:120])
+    assert not found, found
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool.nbytes
+
+
+def _tiny_model(kind):
+    if kind == "latent":
+        from fengshen_tpu.models.joyai import JoyAIConfig, JoyAIForCausalLM
+        return JoyAIForCausalLM(JoyAIConfig.small_test_config(
+            dtype="bfloat16", param_dtype="bfloat16"))
+    from fengshen_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    return LlamaForCausalLM(LlamaConfig(
+        vocab_size=256, hidden_size=1024, intermediate_size=512,
+        num_hidden_layers=3, num_attention_heads=8, num_key_value_heads=8,
+        max_position_embeddings=128, dtype="bfloat16",
+        param_dtype="bfloat16", scan_layers=True))
+
+
+@pytest.mark.parametrize("kind", ["latent", "key_value"])
+def test_assign_scatters_a_prompt_into_the_pool_in_place(one_chip,
+                                                         no_compile_cache,
+                                                         kind):
+    """The engine's assign program (a prefilled prompt's rows into the
+    lane's blocks, every layer in one scatter of whole blocks) for a
+    one-leaf latent pool and for a scanned K/V pool: no copy or
+    transpose of a pool-shaped array, the donated pool aliased to the
+    returned one. A scatter mapped over the layers copied the whole
+    one-head pool into another layout and back (PERF.md, PR 26). Rows
+    are whole tiles here as at the published widths (8 KV heads of
+    128; a latent row of 128): fewer heads get a layout of their own."""
+    from fengshen_tpu.serving import ContinuousBatchingEngine, EngineConfig
+    model = _tiny_model(kind)
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    eng = ContinuousBatchingEngine(
+        model, params, EngineConfig(num_slots=8, buckets=(16, 32),
+                                    max_new_tokens=16, kv_layout="paged",
+                                    kv_block_size=16, kv_num_blocks=33))
+    pools = [leaf for leaf in jax.tree_util.tree_leaves(eng._cache)
+             if leaf.ndim == 5]
+    assert len(pools) == (1 if kind == "latent" else 2)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=one_chip)
+    primed, _ = jax.eval_shape(
+        eng._prefill_jit, params, i32(1, 32), i32(1, 32),
+        jax.ShapeDtypeStruct((2,), jnp.uint32))
+    args = _abstract((eng._cache, eng._history, eng._mask, primed),
+                     one_chip) + (i32(eng.seq_capacity),
+                                  i32(eng.seq_capacity),
+                                  i32(eng.max_blocks_per_slot), i32())
+    compiled = eng._assign_jit.lower(*args).compile()
+    shapes = {p.shape for p in pools}
+    found = []
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* "
+                     r"(copy|transpose)\(", line)
+        if m and tuple(int(d) for d in m.group(1).split(",") if d) \
+                in shapes:
+            found.append(line.strip()[:120])
+    assert not found, found
+    assert compiled.memory_analysis().alias_size_in_bytes >= \
+        sum(p.nbytes for p in pools)
+
+
+def test_routed_experts_compile_to_the_grouped_matmul_at_published_widths(
+        one_chip, no_compile_cache):
+    """64 tokens x 8 picks over 256 experts of 2048 x 768: the three
+    products are the TPU's native ragged dot (custom calls), the
+    program holds no `[tokens, experts, ...]` dispatch tensor, and its
+    operations are those of 512 rows through ONE expert each, not of
+    every row through all 256."""
+    from fengshen_tpu.ops.moe import grouped_swiglu
+    T, K, E, H, F = 64, 8, 256, 2048, 768
+    bf16, sd = jnp.bfloat16, jax.ShapeDtypeStruct
+    args = (sd((T, H), bf16, sharding=one_chip),
+            sd((T, K), jnp.int32, sharding=one_chip),
+            sd((T, K), jnp.float32, sharding=one_chip),
+            sd((E, H, F), bf16, sharding=one_chip),
+            sd((E, H, F), bf16, sharding=one_chip),
+            sd((E, F, H), bf16, sharding=one_chip))
+    compiled = jax.jit(grouped_swiglu).lower(*args).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"ragged-dot[\w.\-]* = [^\n]*custom-call\(",
+                          text)) >= 3
+    once = 2 * T * K * H * F * 3
+    assert compiled.cost_analysis()["flops"] < 2 * once
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20      # no [T, E, C]
