@@ -1,0 +1,5 @@
+"""Decode ticks enqueued one ahead over all decode ticks, counter deltas
+over the window (as `decode_ahead_share.chat`)."""
+from benchmarks.lib import manifest
+
+read = manifest.reader("decode_ahead_share.chat")

@@ -20,7 +20,7 @@ socket read. This module builds that view:
   aliases, ``self.method``, typed attributes, and module singletons
   (``REGISTRY = MetricsRegistry()`` then ``registry.REGISTRY.count``)
 - **fixpoints** over the graph: functions *always* called with a lock
-  held (so ``_step_locked``-style helpers don't read as unguarded),
+  held (so ``_tick_locked``-style helpers don't read as unguarded),
   the transitive blocking-call closure, the transitive lock-
   acquisition closure, and thread-confined private methods
 

@@ -14,7 +14,7 @@ Escape hatches, in line with the serving stack's actual doctrine:
 - ``__init__``-family writes: construction happens-before sharing
 - guard inference through the call graph: a helper that every
   resolved call site enters with the lock held (``step()`` →
-  ``_step_locked()``) is guarded, as is anything honouring the
+  ``_tick_locked()``) is guarded, as is anything honouring the
   ``*_locked`` naming convention
 - thread confinement: private methods that only ever run on the
   class's own dedicated thread (``threading.Thread(target=self._loop)``

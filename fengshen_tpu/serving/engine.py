@@ -14,6 +14,11 @@ onto a fixed pool of `num_slots` KV-cache lanes:
   per-lane `cache_index` vectors (modeling_llama's vector-index path)
   let lanes sit at different write positions, so the step never
   recompiles as requests come and go;
+- one tick ahead: the serve loop enqueues tick k+1 from the token
+  array tick k left on the device and from cursors that advance by
+  one per live lane, and only then fetches and commits tick k — the
+  host's part of a tick runs under the device's (docs/serving.md
+  "Threading"); `step()` runs the same two calls back to back;
 - reclaim: a finished/cancelled/expired lane is immediately handed to
   the next queued request — no drain barrier, no recompilation;
 - backpressure: a bounded admission queue; `submit` raises `QueueFull`
@@ -289,6 +294,22 @@ class Request:
         return self._done.is_set()
 
 
+@dataclasses.dataclass
+class _Tick:
+    """One enqueued decode tick whose outputs the host has not
+    committed: what `_enqueue_tick_locked` hands to `_fetch_locked` and the
+    commit. Engine state under `_cv` while it is `_inflight`."""
+
+    out: tuple              # device arrays the commit needs on the host
+    lanes: np.ndarray       # lane indices the tick ran
+    reqs: list              # the request in each of them at enqueue
+    kv_tokens: int          # real cached tokens its attention reads
+    t0: float               # perf_counter at enqueue
+    ahead: bool             # enqueued with the previous tick unfetched
+    host: tuple = ()        # `out` on the host, once fetched
+    seconds: float = 0.0    # the tick's share of the wall, once fetched
+
+
 def _moe_stats_shape(abstract, num_slots: int):
     """(expert layers, experts) of the routing a model sows under
     "moe_stats" on a decode tick (`ops/moe.py`), or None where it sows
@@ -451,12 +472,29 @@ class ContinuousBatchingEngine:
                    for k in path))
         self._history = jnp.zeros((S, L), jnp.int32)
         self._mask = jnp.zeros((S, L), jnp.int32)
-        # host-side per-slot state (authoritative for scheduling)
-        self._last_tok = np.zeros((S,), np.int32)
+        # each lane's next input token stays on the device: the decode
+        # program's token output (a routed-expert model's histogram
+        # behind the first S entries) IS the next call's `tokens`, and
+        # the assign program writes an admitted lane's first token
+        # into it — the host's fetched copy is for the commit only
+        self._last_tok = self._zero_tokens()
+        # host-side per-slot state (authoritative for scheduling): the
+        # cursors of the next tick to ENQUEUE. A plain tick advances
+        # them by one per lane as it is enqueued, a speculative tick by
+        # its fetched accept counts. Arrays handed to the decode
+        # program are never written again (the upload may still read
+        # them): every update rebinds or precedes the next enqueue.
         self._pos = np.zeros((S,), np.int32)    # logical position of last_tok
         self._phys = np.zeros((S,), np.int32)   # physical cache cursor
-        self._active = np.zeros((S,), bool)
+        self._active = np.zeros((S,), bool)     # lane holds a request
+        #: ticks a lane may still be enqueued in: max_new_tokens less
+        #: the tokens committed or in flight. At 0 the lane sits out
+        #: until the commit of its last tick releases it.
+        self._ticks_left = np.zeros((S,), np.int32)
         self._slot_req: list[Optional[Request]] = [None] * S
+        #: the one decode tick enqueued and not yet fetched, or None
+        self._inflight: Optional[_Tick] = None
+        self._fetched_at = 0.0      # perf_counter of the last fetch
 
         self._queue: deque[Request] = deque()
         # commit journal: request_id -> the live Request object, a
@@ -524,50 +562,44 @@ class ContinuousBatchingEngine:
                 return cache, d_cache, tok
 
         paged = self.paged
+        # the assign program also writes the lane's first token into
+        # the device token array (not donated: an in-flight tick's
+        # record still fetches the array it derives from)
         if paged:
-            def assign_fn(cache, history, mask, primed, prompt_row,
-                          mask_row, table_row, slot):
+            def assign_fn(cache, history, mask, tokens, primed,
+                          prompt_row, mask_row, table_row, slot, tok):
                 cache = assign_paged(cache, primed, slot, table_row)
                 history = history.at[slot].set(prompt_row)
                 mask = mask.at[slot].set(mask_row)
-                return cache, history, mask
+                return cache, history, mask, tokens.at[slot].set(tok)
         elif config.kv_dtype == "int8":
-            def assign_fn(cache, history, mask, primed, prompt_row,
-                          mask_row, slot):
+            def assign_fn(cache, history, mask, tokens, primed,
+                          prompt_row, mask_row, slot, tok):
                 cache = assign_slot_quantized(cache, primed, slot)
                 history = history.at[slot].set(prompt_row)
                 mask = mask.at[slot].set(mask_row)
-                return cache, history, mask
+                return cache, history, mask, tokens.at[slot].set(tok)
         else:
-            def assign_fn(cache, history, mask, primed, prompt_row,
-                          mask_row, slot):
+            def assign_fn(cache, history, mask, tokens, primed,
+                          prompt_row, mask_row, slot, tok):
                 cache = assign_slot(cache, primed, slot)
                 history = history.at[slot].set(prompt_row)
                 mask = mask.at[slot].set(mask_row)
-                return cache, history, mask
+                return cache, history, mask, tokens.at[slot].set(tok)
 
         if self.self_draft:
             # the draft pool is a plain slot pool regardless of the
             # target layout, so its lane assignment is always the
             # unquantized scatter
             base_assign = assign_fn
-            if paged:
-                def assign_fn(cache, dpool, history, mask, primed,
-                              d_primed, prompt_row, mask_row, table_row,
-                              slot):
-                    cache, history, mask = base_assign(
-                        cache, history, mask, primed, prompt_row,
-                        mask_row, table_row, slot)
-                    dpool = assign_slot(dpool, d_primed, slot)
-                    return cache, dpool, history, mask
-            else:
-                def assign_fn(cache, dpool, history, mask, primed,
-                              d_primed, prompt_row, mask_row, slot):
-                    cache, history, mask = base_assign(
-                        cache, history, mask, primed, prompt_row,
-                        mask_row, slot)
-                    dpool = assign_slot(dpool, d_primed, slot)
-                    return cache, dpool, history, mask
+
+            def assign_fn(cache, dpool, history, mask, tokens, primed,
+                          d_primed, *rows):
+                # rows: prompt_row, mask_row, [table_row,] slot, tok
+                cache, *rings = base_assign(cache, history, mask, tokens,
+                                            primed, *rows)
+                dpool = assign_slot(dpool, d_primed, rows[-2])
+                return (cache, dpool, *rings)
 
         gamma, ngram = cfg.spec_gamma, cfg.spec_ngram
         moe_shape = self._moe_shape
@@ -665,7 +697,8 @@ class ContinuousBatchingEngine:
                 history = jax.vmap(
                     lambda row, wrow, p: jax.lax.dynamic_update_slice(
                         row, wrow, (p,)))(history, win, phys + 1)
-                return cache, dpool, history, keys_out, n_r, win
+                return (cache, dpool, history, keys_out,
+                        win[jnp.arange(n), n_r], n_r, win)
         elif self.spec:
             def decode_fn(params, cache, history, mask, tokens, pos,
                           phys, active, keys):
@@ -723,11 +756,17 @@ class ContinuousBatchingEngine:
                 history = jax.vmap(
                     lambda row, wrow, p: jax.lax.dynamic_update_slice(
                         row, wrow, (p,)))(history, win, phys + 1)
-                return cache, history, keys, n_r, win
+                # the last committed token (pad on a free lane) is the
+                # next tick's input: it stays on the device
+                return (cache, history, keys, win[jnp.arange(n), n_r],
+                        n_r, win)
         else:
             def decode_fn(params, cache, history, mask, tokens, pos,
                           phys, active, keys):
-                n = tokens.shape[0]
+                # `tokens` is the previous tick's whole output, still
+                # on the device: its first n entries are the tokens
+                n = active.shape[0]
+                tokens = tokens[:n]
                 if paged:
                     # clamp BEFORE the forward: a reclaimed lane's
                     # blocks may already belong to another request, so
@@ -794,6 +833,8 @@ class ContinuousBatchingEngine:
         # self-draft programs carry two extra donated buffers (the
         # draft pool in both, plus the draft params slot shifting the
         # argnums); the key ring is donated everywhere it is threaded
+        # (the token array is never donated: the in-flight tick's record
+        # fetches it after the next call has taken it as an input)
         if self.self_draft:
             assign_donate = (0, 1, 2, 3)
             decode_donate = (2, 3, 4, 10)
@@ -1141,7 +1182,12 @@ class ContinuousBatchingEngine:
 
     def step(self) -> int:
         """One tick: reclaim → admit → one jitted decode over the pool.
-        Returns the number of lanes still active after the tick."""
+        Returns the number of lanes still active after the tick; on
+        return the tick's tokens are committed and nothing is in
+        flight."""
+        return self._tick(ahead=False)
+
+    def _tick(self, ahead: bool) -> int:
         # the wait for the lock has a span of its own, so a trace tells
         # a scheduler starved by submitters from one at work
         with span("serving/lock_wait"):
@@ -1150,11 +1196,20 @@ class ContinuousBatchingEngine:
             # the tick IS the critical section: the scheduler owns all
             # device state under _cv by design; admission threads wait
             # at most one tick (docs/serving.md "Threading")
-            return self._step_locked()
+            return self._tick_locked(ahead)
         finally:
             self._cv.release()
 
-    def _step_locked(self) -> int:
+    def _tick_locked(self, ahead: bool) -> int:
+        """Reclaim, admit, ENQUEUE a tick, FETCH and commit a tick.
+        With `ahead` the fetched tick is the one the previous call
+        enqueued, so the device runs the new tick while the host
+        fetches, commits and comes round again; without it the tick
+        just enqueued is fetched. A speculative tick's next cursors are
+        its fetched accept counts, so it is never run ahead."""
+        ahead = ahead and not self.spec
+        if not ahead:
+            self._drain_locked()
         now = self._clock()
         # a queued request whose deadline already passed will never be
         # worth prefilling — drop it while it waits, not just at pop
@@ -1163,6 +1218,8 @@ class ContinuousBatchingEngine:
         for req in expired:
             self._queue.remove(req)
             self._finish(req, EXPIRED, "deadline")
+        # a lane released here may have a token in flight: its record
+        # no longer names the lane's request, so the commit drops it
         for i, req in enumerate(self._slot_req):
             if req is None:
                 continue
@@ -1170,81 +1227,129 @@ class ContinuousBatchingEngine:
                 self._release(i, CANCELLED, "cancelled")
             elif req.deadline is not None and now > req.deadline:
                 self._release(i, EXPIRED, "deadline")
-        self._admit()
-        active_idx = np.nonzero(self._active)[0]
-        if len(active_idx) == 0:
+        admitted = self._admit()
+        prev = self._inflight
+        run = self._active & (self._ticks_left > 0)
+        lanes = np.nonzero(run)[0]
+        if prev is None and not len(lanes):
             return 0
-        lanes = len(active_idx)
+        with span("serving/decode"):
+            # an admission's first-token fetch has already waited for
+            # everything queued, so the tick after it is not ahead
+            tick = self._enqueue_tick_locked(
+                run, lanes, prev is not None and not admitted) \
+                if len(lanes) else None
+            if not ahead:
+                prev, tick = tick, None
+            self._inflight = tick
+            if prev is not None:
+                self._fetch_locked(prev)
+        if prev is not None:
+            self._commit_locked(prev)
+        return int(self._active.sum())
+
+    def _zero_tokens(self):
+        """The device token array before any tick: the shape of the
+        decode program's token output."""
+        n = self.config.num_slots
+        if self._moe_shape and not self.spec:
+            n += self._moe_shape[0] * self._moe_shape[1]
+        return jnp.zeros((n,), jnp.int32)
+
+    def _decode_args(self, active) -> tuple:
+        """The decode program's arguments with `active` as its live
+        mask (warmup lowers with the same)."""
+        head = (self.params, self._draft_params, self._cache,
+                self._draft_cache) if self.self_draft else \
+            (self.params, self._cache)
+        return head + (self._history, self._mask, self._last_tok,
+                       self._pos, self._phys, active, self._keys)
+
+    def _run_decode(self, active) -> tuple:
+        """Enqueue the decode program. Every donated argument and the
+        token array are rebound from its outputs; returns the device
+        arrays the commit needs on the host."""
+        out = self._decode_jit(*self._decode_args(active))
+        if self.self_draft:
+            self._cache, self._draft_cache, *out = out
+        else:
+            self._cache, *out = out
+        self._history, self._keys, self._last_tok, *fetch = out
+        return tuple(fetch) or (self._last_tok,)
+
+    def _enqueue_tick_locked(self, run, lanes, ahead: bool) -> _Tick:
+        """Enqueue one decode tick over `lanes` (`run`: the same as a
+        fresh bool mask) and advance what the host knows without its
+        tokens: a plain tick moves each of its lanes one position on."""
         # real cached tokens this tick's attention reads: the logical
         # cursor, not `_phys`, which counts the bucket's padding
-        kv_tokens = int(self._pos[active_idx].sum()) + lanes
+        kv_tokens = int(self._pos[lanes].sum()) + len(lanes)
         t0 = time.perf_counter()
-        if self.spec:
-            with span("serving/decode"):
-                # dispatch: the four host cursors are uploaded and the
-                # program enqueued; fetch: the wait for the device and
-                # the copy back
-                with span("dispatch", lanes=lanes):
-                    if self.self_draft:
-                        (self._cache, self._draft_cache, self._history,
-                         self._keys, n_r, win) = self._decode_jit(
-                            self.params, self._draft_params, self._cache,
-                            self._draft_cache, self._history, self._mask,
-                            self._last_tok, self._pos, self._phys,
-                            self._active, self._keys)
-                    else:
-                        (self._cache, self._history, self._keys, n_r,
-                         win) = self._decode_jit(
-                            self.params, self._cache, self._history,
-                            self._mask, self._last_tok, self._pos,
-                            self._phys, self._active, self._keys)
-                # host sync: the scheduler needs the accept counts and
-                # the committed window (copies — the device views are
-                # read-only and lanes are overwritten on admission)
-                with span("fetch"):
-                    n_r = np.array(n_r)
-                    win = np.array(win)
-            dt = time.perf_counter() - t0
-            # per-lane commit: accepted prefix + the correction token,
-            # so each lane's cursor advances INDEPENDENTLY (the whole
-            # point over generate's batched min-advance)
-            commit = np.where(self._active, n_r + 1, 0)
-            with span("serving/commit", lanes=lanes,
-                      tokens=int(commit.sum())):
-                return self._commit_spec(active_idx, n_r, win, commit,
-                                         dt, kv_tokens)
-        with span("serving/decode"):
-            with span("dispatch", lanes=lanes):
-                self._cache, self._history, self._keys, nxt = \
-                    self._decode_jit(
-                        self.params, self._cache, self._history,
-                        self._mask, self._last_tok, self._pos,
-                        self._phys, self._active, self._keys)
-            # host sync: the scheduler needs the tokens (copy — the
-            # device view is read-only and lanes are overwritten on
-            # admission)
-            with span("fetch"):
-                nxt = np.array(nxt)
-        dt = time.perf_counter() - t0
-        if self._moe_shape:
-            S = self.config.num_slots
-            self.metrics.record_moe(nxt[S:].reshape(self._moe_shape))
-            nxt = nxt[:S]
-        with span("serving/commit", lanes=lanes, tokens=lanes):
-            return self._commit_plain(active_idx, nxt, dt, kv_tokens)
+        # dispatch: the host cursors are uploaded and the program
+        # enqueued behind whatever the device is still running
+        with span("dispatch", lanes=len(lanes)):
+            out = self._run_decode(run)
+            for x in out:
+                # the copy back starts when the device is done, not
+                # when the host comes to ask
+                x.copy_to_host_async()
+        self._ticks_left = self._ticks_left - run
+        if not self.spec:
+            self._pos = self._pos + run
+            self._phys = self._phys + run
+        return _Tick(out, lanes, [self._slot_req[i] for i in lanes],
+                     kv_tokens, t0, ahead)
 
-    def _commit_spec(self, active_idx, n_r, win, commit, dt: float,
-                     kv_tokens: int) -> int:
-        """Host side of a speculative tick after the fetch: cursors,
-        token append, timeline, stream, release. Returns the lanes
-        still active."""
-        last = win[np.arange(win.shape[0]),
-                   np.maximum(commit - 1, 0)]
-        self._last_tok = np.where(self._active, last,
-                                  self.config.pad_token_id
-                                  ).astype(np.int32)
-        self._pos = (self._pos + commit).astype(np.int32)
-        self._phys = (self._phys + commit).astype(np.int32)
+    def _fetch_locked(self, tick: _Tick) -> None:
+        """Block until `tick`'s outputs are on the host (copies — the
+        device views are read-only)."""
+        with span("fetch"):
+            tick.host = tuple(np.array(x) for x in tick.out)
+        now = time.perf_counter()
+        # its share of the wall: from its enqueue, or from the fetch
+        # before it where it was enqueued behind a running tick
+        tick.seconds = now - max(tick.t0, self._fetched_at)
+        self._fetched_at = now
+
+    def _drain_locked(self) -> None:
+        """Fetch and commit the in-flight tick, if any: what every
+        reader of committed state (lane export, drain, stop) and every
+        depth-0 tick does first."""
+        tick, self._inflight = self._inflight, None
+        if tick is not None:
+            with span("serving/decode"):
+                self._fetch_locked(tick)
+            self._commit_locked(tick)
+
+    def _commit_locked(self, tick: _Tick) -> None:
+        """Host side of a fetched tick: token append, timeline, stream,
+        release — for the lanes that still hold the request the tick
+        ran for. A lane released since (cancel, deadline, an EOS one
+        tick back, a detach) has its token dropped."""
+        live = [(i, req) for i, req in zip(tick.lanes, tick.reqs)
+                if self._slot_req[i] is req]
+        # a speculative tick's first output is its accept counts
+        tokens = len(live) if not self.spec else \
+            int(tick.host[0][tick.lanes].sum()) + len(tick.lanes)
+        with span("serving/commit", lanes=len(tick.lanes), tokens=tokens):
+            if self.spec:
+                self._commit_spec(tick, live)
+            else:
+                self._commit_plain(tick, live)
+        if self._inflight is not None and not self._active.any():
+            # every lane of the tick in flight was just released:
+            # nobody waits for its tokens
+            self._inflight = None
+
+    def _commit_spec(self, tick: _Tick, live) -> None:
+        n_r, win = tick.host
+        # per-lane commit: accepted prefix + the correction token,
+        # so each lane's cursor advances INDEPENDENTLY (the whole
+        # point over generate's batched min-advance)
+        commit = np.zeros_like(self._pos)
+        commit[tick.lanes] = n_r[tick.lanes] + 1
+        self._pos = self._pos + commit
+        self._phys = self._phys + commit
         # metrics count DELIVERED tokens, not the raw window: a
         # lane finishing mid-window (eos, or the max_new cap)
         # discards the tail, and counting it would inflate
@@ -1253,8 +1358,7 @@ class ContinuousBatchingEngine:
         delivered = 0
         accepted_delivered = 0
         t_commit = self._clock()
-        for i in active_idx:
-            req = self._slot_req[i]
+        for i, req in live:
             k = 0
             fin = None
             for tok in (int(t) for t in win[i, :commit[i]]):
@@ -1271,7 +1375,7 @@ class ContinuousBatchingEngine:
             # snapshots the timeline into the debug ring
             req.timeline.add(t_commit, "commit", n=k,
                              accepted=min(int(n_r[i]), k),
-                             tick_s=round(dt, 6))
+                             tick_s=round(tick.seconds, 6))
             self._sync_stream(req)
             if fin is not None:
                 self._release(i, FINISHED, fin)
@@ -1279,40 +1383,45 @@ class ContinuousBatchingEngine:
             # delivered tokens at offsets < n_r are accepted
             # drafts; the one at offset n_r is the correction
             accepted_delivered += min(int(n_r[i]), k)
-        self.metrics.record_tick(len(active_idx),
-                                 self.config.num_slots, dt,
-                                 tokens=delivered, kv_tokens=kv_tokens)
+        self.metrics.record_tick(len(tick.lanes),
+                                 self.config.num_slots, tick.seconds,
+                                 tokens=delivered,
+                                 kv_tokens=tick.kv_tokens)
         self.metrics.record_spec(
-            self.config.spec_gamma * len(active_idx),
+            self.config.spec_gamma * len(tick.lanes),
             accepted_delivered)
-        return int(self._active.sum())
 
-    def _commit_plain(self, active_idx, nxt, dt: float,
-                      kv_tokens: int) -> int:
-        """Host side of a plain tick after the fetch: cursors, token
-        append, timeline, stream, release. Returns the lanes still
-        active."""
-        self.metrics.record_tick(len(active_idx), self.config.num_slots,
-                                 dt, kv_tokens=kv_tokens)
-        self._last_tok = nxt
-        self._pos[self._active] += 1
-        self._phys[self._active] += 1
+    def _commit_plain(self, tick: _Tick, live) -> None:
+        nxt, S = tick.host[0], self.config.num_slots
+        if self._moe_shape:
+            self.metrics.record_moe(nxt[S:].reshape(self._moe_shape))
+        # credited at commit, and only what is delivered: the counter
+        # keeps matching the clients' count
+        self.metrics.record_tick(len(tick.lanes), S, tick.seconds,
+                                 tokens=len(live),
+                                 kv_tokens=tick.kv_tokens,
+                                 ahead=tick.ahead)
         t_commit = self._clock()
-        for i in active_idx:
-            req = self._slot_req[i]
+        for i, req in live:
             tok = int(nxt[i])
             req.tokens.append(tok)
             req.timeline.add(t_commit, "commit", n=1,
-                             tick_s=round(dt, 6))
+                             tick_s=round(tick.seconds, 6))
             self._sync_stream(req)
             if self.config.eos_token_id is not None and \
                     tok == self.config.eos_token_id:
+                # the host learns an EOS a tick late: the lane's extra
+                # tick, if one is in flight, wrote inside blocks it
+                # still owned and its token is dropped above
                 self._release(i, FINISHED, "eos")
             elif len(req.tokens) >= req.max_new_tokens:
                 self._release(i, FINISHED, "length")
-        return int(self._active.sum())
 
-    def _admit(self) -> None:
+    def _admit(self) -> int:
+        """Fill free lanes from the queue head; returns the prefills
+        run (each one waited for its first token, and with it for
+        everything the device had queued)."""
+        prefills = 0
         for slot in range(self.config.num_slots):
             if self._active[slot] or not self._queue:
                 continue
@@ -1365,7 +1474,7 @@ class ContinuousBatchingEngine:
                                    "blocks_needed": need,
                                    "blocks_free":
                                        self._allocator.free_blocks})
-                    return
+                    return prefills
                 self._deferred_req = None
             try:
                 row, mask_row = self.ladder.pad_prompt(
@@ -1397,6 +1506,7 @@ class ContinuousBatchingEngine:
                         primed, tok = self._prefill_jit(
                             self.params, row[None], mask_row[None], key)
                     tok = int(np.asarray(tok)[0])
+                prefills += 1
                 self.metrics.record_prefill(bucket, len(prefill_ids))
                 t_first = self._clock()
                 req.ttft_s = t_first - req.submit_time
@@ -1438,13 +1548,14 @@ class ContinuousBatchingEngine:
                 self._assign(req, slot, bucket, row, mask_row, primed,
                              d_primed if self.self_draft else None, tok,
                              lane_key)
-        return
+        return prefills
 
     def _assign(self, req: Request, slot: int, bucket: int, row,
                 mask_row, primed, d_primed, tok: int, lane_key) -> None:
         """Install a prefilled request in lane `slot`: the lane's
         history / mask / block-table rows, the assign program's
-        dispatch, and the host cursors."""
+        dispatch (which also writes `tok`, the lane's next input, into
+        the device token array), and the host cursors."""
         # history/mask lanes: padded prompt, mask open from the bucket
         # edge on (causal validity bounds the open tail)
         L = self.seq_capacity
@@ -1456,35 +1567,23 @@ class ContinuousBatchingEngine:
             blocks = self._slot_blocks[slot]
             table_row = np.zeros((self.max_blocks_per_slot,), np.int32)
             table_row[:len(blocks)] = blocks
+        rows = (hist_row, full_mask) + \
+            ((table_row,) if self.paged else ()) + \
+            (np.int32(slot), np.int32(tok))
         if self.self_draft:
-            if self.paged:
-                (self._cache, self._draft_cache, self._history,
-                 self._mask) = self._assign_jit(
-                    self._cache, self._draft_cache, self._history,
-                    self._mask, primed, d_primed, hist_row,
-                    full_mask, table_row, np.int32(slot))
-            else:
-                (self._cache, self._draft_cache, self._history,
-                 self._mask) = self._assign_jit(
-                    self._cache, self._draft_cache, self._history,
-                    self._mask, primed, d_primed, hist_row,
-                    full_mask, np.int32(slot))
-        elif self.paged:
-            self._cache, self._history, self._mask = \
-                self._assign_jit(self._cache, self._history,
-                                 self._mask, primed, hist_row,
-                                 full_mask, table_row,
-                                 np.int32(slot))
+            (self._cache, self._draft_cache, self._history, self._mask,
+             self._last_tok) = self._assign_jit(
+                self._cache, self._draft_cache, self._history,
+                self._mask, self._last_tok, primed, d_primed, *rows)
         else:
-            self._cache, self._history, self._mask = \
-                self._assign_jit(self._cache, self._history,
-                                 self._mask, primed, hist_row,
-                                 full_mask, np.int32(slot))
+            self._cache, self._history, self._mask, self._last_tok = \
+                self._assign_jit(self._cache, self._history, self._mask,
+                                 self._last_tok, primed, *rows)
         req.state = RUNNING
         req.slot = slot
         self._slot_req[slot] = req
         self._active[slot] = True
-        self._last_tok[slot] = tok
+        self._ticks_left[slot] = req.max_new_tokens - len(req.tokens)
         # logical pos of last_tok: len(prompt) for a fresh lane
         # (tokens == [tok]); a resumed lane holds k committed
         # tokens, the same invariant pos = P + len(tokens) - 1
@@ -1544,12 +1643,14 @@ class ContinuousBatchingEngine:
     # ---- drivers ----------------------------------------------------
 
     def run_until_idle(self, max_ticks: int = 1_000_000) -> None:
-        """Offline driver: tick until queue and pool are empty."""
+        """Offline driver: tick one ahead, as the serve loop does, until
+        queue and pool are empty and nothing is in flight."""
         for _ in range(max_ticks):
             with self._cv:
-                if not self._queue and not self._active.any():
+                if not self._queue and not self._active.any() \
+                        and self._inflight is None:
                     return
-                self._step_locked()  # fslint: disable=blocking-under-lock; offline driver, same tick-owns-lock design as step()
+                self._tick_locked(ahead=True)  # fslint: disable=blocking-under-lock; offline driver, same tick-owns-lock design as step()
         raise RuntimeError(f"engine still busy after {max_ticks} ticks")
 
     def generate_all(self, prompts,
@@ -1572,7 +1673,7 @@ class ContinuousBatchingEngine:
     def _serve_loop(self) -> None:
         while not self._stop_flag:
             try:
-                n = self.step()
+                n = self._tick(ahead=True)
             except Exception as e:  # noqa: BLE001 — a dead serve
                 # thread would leave every waiter blocked for its full
                 # timeout and the server accepting traffic against a
@@ -1615,8 +1716,10 @@ class ContinuousBatchingEngine:
                             self._cv.wait(timeout=0.02)
 
     def _reset_pool_locked(self) -> None:
-        """Fail every queued/running request and rebuild the slot pool
-        (donated buffers may be invalid after a mid-tick error)."""
+        """Fail every queued/running request, drop the tick in flight
+        and rebuild the slot pool (donated buffers may be invalid after
+        a mid-tick error)."""
+        self._inflight = None
         for req in list(self._queue):
             self._queue.remove(req)
             self._finish(req, EXPIRED, "engine_error")
@@ -1635,18 +1738,24 @@ class ContinuousBatchingEngine:
                                self._zero_key.dtype)
         if self.self_draft:
             self._draft_cache = init_slot_cache(self._draft_model, S)
-        self._last_tok = np.zeros((S,), np.int32)
+        self._last_tok = self._zero_tokens()
         self._pos = np.zeros((S,), np.int32)
         self._phys = np.zeros((S,), np.int32)
         self._active = np.zeros((S,), bool)
+        self._ticks_left = np.zeros((S,), np.int32)
 
     def stop(self) -> None:
+        """Stop the serve thread. Running lanes stay where they are (a
+        later `start()` resumes them); the tick in flight is committed
+        first, so their cursors and tokens agree."""
         self._stop_flag = True
         with self._cv:
             self._cv.notify_all()
         if self._thread is not None:
             self._thread.join(timeout=5.0)
             self._thread = None
+        with self._cv:
+            self._drain_locked()  # fslint: disable=blocking-under-lock; the serve thread is gone, one last fetch under its lock
 
     # ---- drain (docs/fleet.md "Drain runbook") ----------------------
 
@@ -1668,6 +1777,8 @@ class ContinuousBatchingEngine:
             if self._draining:
                 return
             self._draining = True
+            # evacuation reads committed lanes next: nothing in flight
+            self._drain_locked()  # fslint: disable=blocking-under-lock; one tick's fetch, as a tick itself holds the lock
             flushed = list(self._queue)
             self._queue.clear()
             for req in flushed:
@@ -1686,7 +1797,8 @@ class ContinuousBatchingEngine:
         """True when nothing is queued or decoding (the drain handler's
         exit condition)."""
         with self._cv:
-            return not self._queue and not bool(self._active.any())
+            return not self._queue and not bool(self._active.any()) \
+                and self._inflight is None
 
     def live_lane_ids(self) -> list:
         """Request ids of every RUNNING lane — the drain handler's
@@ -1776,17 +1888,7 @@ class ContinuousBatchingEngine:
                     else:
                         self._prefill_jit.warm(self.params, ids, mask,
                                                self._zero_key)
-                if self.self_draft:
-                    self._decode_jit.warm(
-                        self.params, self._draft_params, self._cache,
-                        self._draft_cache, self._history, self._mask,
-                        self._last_tok, self._pos, self._phys,
-                        self._active, self._keys)
-                else:
-                    self._decode_jit.warm(
-                        self.params, self._cache, self._history,
-                        self._mask, self._last_tok, self._pos,
-                        self._phys, self._active, self._keys)
+                self._decode_jit.warm(*self._decode_args(self._active))
         else:
             with self._cv:
                 for bucket in self.ladder.buckets:
@@ -1809,21 +1911,7 @@ class ContinuousBatchingEngine:
                 # tick is a no-op on pool state (free lanes write at
                 # index 0 and are fully overwritten by the next
                 # assignment anyway) and on the zero key ring
-                if self.self_draft:
-                    out = self._decode_jit(  # fslint: disable=blocking-under-lock; warmup must exclude ticks
-                        self.params, self._draft_params, self._cache,
-                        self._draft_cache, self._history, self._mask,
-                        self._last_tok, self._pos, self._phys,
-                        self._active, self._keys)
-                    (self._cache, self._draft_cache, self._history,
-                     self._keys) = out[0], out[1], out[2], out[3]
-                else:
-                    out = self._decode_jit(  # fslint: disable=blocking-under-lock; warmup must exclude ticks
-                        self.params, self._cache, self._history,
-                        self._mask, self._last_tok, self._pos,
-                        self._phys, self._active, self._keys)
-                    self._cache, self._history, self._keys = \
-                        out[0], out[1], out[2]
+                self._run_decode(self._active)  # fslint: disable=blocking-under-lock; warmup must exclude ticks
                 jax.block_until_ready(self._cache)  # fslint: disable=blocking-under-lock; warmup must exclude ticks
         dt = time.perf_counter() - t0
         self.metrics.warmup_compile_s = round(dt, 3)

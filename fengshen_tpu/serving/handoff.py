@@ -23,6 +23,10 @@ Wire-format invariants (version 1):
   forward, so the pending token rides in the payload header
   (``last_tok``) and the receiver's first tick re-commits it — the
   cache never carries a position the scheduler hasn't.
+- The export is of COMMITTED state: the engine keeps one decode tick
+  in flight, so `export_lane` first fetches and commits it
+  (`_drain_locked`); the host cursors then stand at the committed
+  position and the pending token is the request's last one.
 - Everything here is EAGER jnp gather/scatter on the scheduler lock —
   no new jitted programs, so the engine's pinned compile counts
   (one decode program, one assign program, one prefill per bucket)
@@ -166,6 +170,7 @@ def export_lane(engine, request_id: str) -> dict:
         refusal = _undeclared_on_wire(engine)
         if refusal:
             raise HandoffError(refusal)
+        engine._drain_locked()
         req = None
         for r in engine._slot_req:
             if r is not None and r.request_id == request_id:
@@ -177,7 +182,7 @@ def export_lane(engine, request_id: str) -> dict:
         slot = req.slot
         phys = int(engine._phys[slot])
         pos = int(engine._pos[slot])
-        last_tok = int(engine._last_tok[slot])
+        last_tok = req.tokens[-1]
         bucket = phys - (len(req.tokens) - 1)
         blocks = engine._slot_blocks[slot] if engine.paged else None
         int8_src = engine.config.kv_dtype == "int8"
@@ -363,9 +368,11 @@ def adopt_lane(engine, payload: dict) -> Request:
         req.slot = slot
         engine._slot_req[slot] = req
         engine._active[slot] = True
-        engine._last_tok[slot] = int(payload["last_tok"])
+        engine._last_tok = engine._last_tok.at[slot].set(
+            int(payload["last_tok"]))
         engine._pos[slot] = pos
         engine._phys[slot] = phys
+        engine._ticks_left[slot] = remaining
         # the adopter journals the lane too: after a hard kill of the
         # source, this replica's `GET /partial/<id>` carries the
         # committed prefix the router resumes from

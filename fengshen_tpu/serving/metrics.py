@@ -65,13 +65,19 @@ class EngineMetrics:
             "prefills per bucket width", labelnames=("bucket",))
         self._decode_ticks = r.counter(
             "fstpu_serving_decode_ticks_total", "jitted decode ticks")
+        self._decode_ticks_ahead = r.counter(
+            "fstpu_serving_decode_ticks_ahead_total",
+            "decode ticks enqueued while the previous tick's tokens "
+            "were still unfetched (over decode_ticks_total: the share "
+            "of ticks whose host part ran under the device's)")
         self._decode_tokens = r.counter(
             "fstpu_serving_decode_tokens_total",
             "tokens generated by decode ticks")
         self._decode_time = r.counter(
             "fstpu_serving_decode_seconds_total",
-            "wall seconds of the decode ticks: dispatch, the device's "
-            "run and the fetch of the tokens")
+            "wall seconds of the decode ticks: from a tick's enqueue, "
+            "or from the fetch before it where it was enqueued behind "
+            "a running tick, to the fetch of its tokens")
         self._prefill_tokens = r.counter(
             "fstpu_serving_prefill_tokens_total",
             "real prompt tokens prefilled (a resumed request's "
@@ -179,13 +185,16 @@ class EngineMetrics:
 
     def record_tick(self, n_active: int, num_slots: int,
                     seconds: float, tokens: Optional[int] = None,
-                    kv_tokens: int = 0) -> None:
+                    kv_tokens: int = 0, ahead: bool = False) -> None:
         """`tokens` defaults to `n_active` (one committed token per
         active lane — the plain decode tick); the speculative tick
         passes the real committed count, which exceeds the lane count
         whenever draft proposals were accepted. `kv_tokens`: the real
-        cached tokens this tick's attention read, over all lanes."""
+        cached tokens this tick's attention read, over all lanes.
+        `ahead`: the tick was enqueued one ahead (engine `_Tick`)."""
         self._decode_ticks.inc()
+        if ahead:
+            self._decode_ticks_ahead.inc()
         self._kv_attended.inc(kv_tokens)
         self._decode_tokens.inc(n_active if tokens is None else tokens)
         self._decode_time.inc(seconds)

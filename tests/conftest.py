@@ -38,3 +38,18 @@ def mesh_seq4():
     set_mesh(mesh)
     yield mesh
     set_mesh(None)
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _no_mesh_left_behind():
+    """`Trainer.__init__` (and a test that forgets) installs the
+    process-global mesh and nothing takes it out. Under `--dist
+    loadfile` the next file on the same worker would then build its
+    serving engines under that mesh, where the first tick's fresh
+    arrays and the later ticks' mesh-sharded outputs are two decode
+    programs: the one-compile tests failed by file order."""
+    yield
+    import sys
+    mesh_module = sys.modules.get("fengshen_tpu.parallel.mesh")
+    if mesh_module is not None:
+        mesh_module.set_mesh(None)

@@ -128,10 +128,10 @@ def test_assign_scatters_a_prompt_into_the_pool_in_place(one_chip,
     primed, _ = jax.eval_shape(
         eng._prefill_jit, params, i32(1, 32), i32(1, 32),
         jax.ShapeDtypeStruct((2,), jnp.uint32))
-    args = _abstract((eng._cache, eng._history, eng._mask, primed),
-                     one_chip) + (i32(eng.seq_capacity),
-                                  i32(eng.seq_capacity),
-                                  i32(eng.max_blocks_per_slot), i32())
+    args = _abstract((eng._cache, eng._history, eng._mask, eng._last_tok,
+                      primed), one_chip) + (
+        i32(eng.seq_capacity), i32(eng.seq_capacity),
+        i32(eng.max_blocks_per_slot), i32(), i32())
     compiled = eng._assign_jit.lower(*args).compile()
     shapes = {p.shape for p in pools}
     found = []
