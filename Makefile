@@ -9,7 +9,7 @@ PY ?= python
 	serve-bench-parity serve-bench-spec serve-bench-fleet \
 	serve-bench-disagg serve-bench-evac serve-bench-multimodal \
 	serve-bench-stream \
-	serve-fleet aot-bench \
+	serve-fleet \
 	kernel-bench benchdiff
 
 # whole package, all rules (per-file + the cross-module concurrency
@@ -110,12 +110,6 @@ serve-fleet:
 	$(PY) -m fengshen_tpu.fleet \
 		--spawn $(or $(N),3) --config $(CONFIG) \
 		--port $(or $(PORT),8080)
-
-# AOT cold-start microbench (docs/aot_cache.md): cold-process vs
-# warm-process engine warmup through the persistent executable cache,
-# one BENCH-schema JSON line (aot_cold_s, aot_warm_s, speedup)
-aot-bench:
-	JAX_PLATFORMS=cpu $(PY) -m fengshen_tpu.aot.bench
 
 # kernel-layer microbench (docs/kernels.md): the Pallas dispatch seam
 # A/B'd against the stock XLA lowerings (paged decode read, fused CE

@@ -4,10 +4,9 @@ The project index (``analysis/project.py``) records *where* things
 happen — calls, writes, guard scopes. The rules added by the dataflow
 tier need to know *what happens next on each path*:
 
-- **donation tracking**: a ``jax.jit(fn, donate_argnums=...)`` /
-  ``cached_compile`` / ``CachedFunction`` binding makes specific
-  positional arguments of every later call through that binding
-  *donated* — the caller's buffer is invalidated by dispatch. The flow
+- **donation tracking**: a ``jax.jit(fn, donate_argnums=...)``
+  binding makes specific positional arguments of every later call
+  through that binding *donated* — the caller's buffer is invalidated by dispatch. The flow
   engine arms the variables passed in donated positions at each call
   site and reports any read on any later path; rebinding from the
   call's outputs (``state = step(state, ...)``) disarms, which is
@@ -174,8 +173,8 @@ def _find_donate_calls(value: ast.AST,
                        defs_by_name: Dict[str, ast.AST],
                        ) -> List[Tuple[ast.Call, Tuple[int, ...]]]:
     """Every call carrying a resolvable donate keyword anywhere inside
-    ``value`` — sees through ``self._maybe_aot_wrap(jax.jit(...))``
-    nesting and conditional-expression branches."""
+    ``value`` — sees through a wrapping call and conditional-expression
+    branches."""
     hits: List[Tuple[ast.Call, Tuple[int, ...]]] = []
     for n in ast.walk(value):
         if isinstance(n, ast.Call):

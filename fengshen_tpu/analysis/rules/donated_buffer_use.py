@@ -1,9 +1,8 @@
 """donated-buffer-use: a buffer read after being donated to a jitted
 call is reading freed device memory.
 
-``jax.jit(fn, donate_argnums=...)`` (and the AOT-cache wrappers
-``cached_compile`` / ``CachedFunction`` / ``aot.wrap``, which forward
-the keyword) hands the listed arguments' buffers to XLA — after the
+``jax.jit(fn, donate_argnums=...)`` hands the listed arguments'
+buffers to XLA — after the
 call dispatches, the caller's reference is invalid and reading it
 returns garbage or raises, depending on backend and timing. That makes
 this the classic silent-corruption bug: it passes on CPU test runs

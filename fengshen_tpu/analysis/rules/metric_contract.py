@@ -17,9 +17,8 @@ statically constant name):
   rot silently.
 
 Families whose registration is dynamic — the serving outcome counters
-built in a dict comprehension and the AOT cache counters whose name
-is a parameter — are invisible to static extraction; they are
-documented but live on ``DYNAMIC_REGISTRATIONS`` below so the rule
+built in a dict comprehension — are invisible to static extraction;
+they are documented but live on ``DYNAMIC_REGISTRATIONS`` below so the rule
 lands with a genuinely empty baseline instead of day-one
 suppressions. The docs diff only runs when the analyzed set includes
 package files and the docs file exists (fixture runs in tmp roots
@@ -51,11 +50,6 @@ DYNAMIC_REGISTRATIONS = frozenset({
     "fstpu_serving_rejected_duplicate_total",
     "fstpu_serving_rejected_prompt_too_long_total",
     "fstpu_serving_rejected_queue_full_total",
-    # aot/cache.py registers through a helper taking the name as a
-    # parameter
-    "fstpu_aot_cache_errors_total",
-    "fstpu_aot_cache_hits_total",
-    "fstpu_aot_cache_misses_total",
 })
 
 #: where documented-but-unregistered findings anchor (the registry

@@ -18,12 +18,9 @@ paged/int8 pool serves ≥2x the concurrent requests per KV byte, see
 docs/serving.md "Paged KV cache" — and the speculative-decode knobs
 `spec_mode: "off"|"prompt_lookup"`, `spec_gamma`, `spec_ngram` — the
 draft/verify tick commits >1 token per weight stream on repetitive
-text, docs/serving.md "Speculative decoding"), and the optional AOT
-block (`{"cache_dir": ...}`, docs/aot_cache.md) routes every engine
-compile through the persistent executable cache so a restarted replica
-deserializes instead of recompiling (the KV and spec knobs join the
-cache key). `GET /stats` includes the KV-pool utilization (blocks
-total/used/free, bytes, fragmentation, layout/dtype) alongside the
+text, docs/serving.md "Speculative decoding"). `GET /stats` includes
+the KV-pool utilization (blocks total/used/free, bytes, fragmentation,
+layout/dtype) alongside the
 engine metrics, plus — on a spec engine only, so the non-spec payload
 shape never churns — `spec_mode`/`spec_gamma`/`spec_drafted_total`/
 `spec_accepted_total`/`spec_acceptance_rate`.
@@ -103,7 +100,6 @@ class ServerConfig:
     # tick errors, SIGTERM) land here (docs/observability.md)
     dump_dir: str = "fstpu_dumps"
     engine_args: dict = dataclasses.field(default_factory=dict)
-    aot_args: dict = dataclasses.field(default_factory=dict)
 
     def __post_init__(self):
         if self.engine not in ("simple", "continuous", "batch_image",
@@ -130,8 +126,13 @@ def load_config(path: str) -> tuple[ServerConfig, PipelineConfig]:
     with open(path) as f:
         raw = json.load(f)
     server = ServerConfig(**raw.get("SERVER", {}))
+    if "AOT" in raw:
+        raise ValueError(
+            f"{path}: the \"AOT\" block was removed with the AOT "
+            "executable cache; the one compile cache is jax's own "
+            "persistent cache (fengshen_tpu/compile_cache.py): delete "
+            "the block and set JAX_COMPILATION_CACHE_DIR to place it")
     server.engine_args = dict(raw.get("ENGINE", {}))
-    server.aot_args = dict(raw.get("AOT", {}))
     pipeline = PipelineConfig(
         task=raw.get("PIPELINE", {}).get("task", "text_classification"),
         model=raw.get("PIPELINE", {}).get("model"),
@@ -292,15 +293,11 @@ def warmup_pipeline(pipeline, task: str) -> float:
     return dt
 
 
-def create_continuous_engine(pipeline, engine_args: dict,
-                             aot_args: Optional[dict] = None, log=None,
+def create_continuous_engine(pipeline, engine_args: dict, log=None,
                              recorder=None):
-    """Build (but do not warm or start) the continuous-batching engine;
-    `aot_args` is the AOT config block — when it names a cache_dir, the
-    engine's programs route through the persistent executable cache
-    (docs/aot_cache.md). `recorder` is an optional
-    `observability.FlightRecorder` the engine feeds its event stream
-    into and dumps through on tick errors."""
+    """Build (but do not warm or start) the continuous-batching engine.
+    `recorder` is an optional `observability.FlightRecorder` the engine
+    feeds its event stream into and dumps through on tick errors."""
     from fengshen_tpu.compile_cache import ensure_compile_cache
     from fengshen_tpu.serving import (ContinuousBatchingEngine,
                                       EngineConfig)
@@ -311,23 +308,17 @@ def create_continuous_engine(pipeline, engine_args: dict,
             "module/params/engine_config_kwargs (task "
             "'text_generation'), not a per-call classification "
             "pipeline")
-    aot = None
-    if aot_args and aot_args.get("cache_dir"):
-        from fengshen_tpu.aot import AotConfig, AotSetup
-        aot = AotSetup(AotConfig(**aot_args), log=log)
     kwargs = {**pipeline.engine_config_kwargs(), **engine_args}
     return ContinuousBatchingEngine(
         pipeline.module, pipeline.params, EngineConfig(**kwargs),
-        log=log, aot=aot, recorder=recorder)
+        log=log, recorder=recorder)
 
 
 def start_continuous_engine(pipeline, engine_args: dict, log=None,
-                            aot_args: Optional[dict] = None,
                             recorder=None):
     """Build, warm up (compile all prefill buckets + the decode step,
     logging the time), and start the continuous-batching engine."""
-    engine = create_continuous_engine(pipeline, engine_args,
-                                      aot_args=aot_args, log=log,
+    engine = create_continuous_engine(pipeline, engine_args, log=log,
                                       recorder=recorder)
     dt = engine.warmup()
     print(f"[serving] continuous engine warmup "
@@ -1193,11 +1184,10 @@ def install_drain_handler(server, draining, engine=None, recorder=None,
 def _start_warmup_thread(server_cfg: ServerConfig,
                          pipeline_cfg: PipelineConfig, pipeline,
                          engine) -> Readiness:
-    """Warm up in the background while the server is already listening
-    (docs/aot_cache.md "cold start"): /healthz answers 503 until the
-    returned gate is set, then 200 — the load-balancer readiness
-    contract. With a warm compile cache the warmup is mostly
-    deserialization and the 503 window shrinks to near zero.
+    """Warm up in the background while the server is already listening:
+    /healthz answers 503 until the returned gate is set, then 200 — the
+    load-balancer readiness contract. With a warm compile cache the
+    warmup is mostly deserialization and the 503 window shrinks.
 
     A warmup that raises leaves the gate shut for good: the engine's
     serve loop is not started, /healthz keeps answering 503 with the
@@ -1276,7 +1266,6 @@ def main(argv=None) -> None:
         # background thread below; construction itself is compile-free
         engine = create_continuous_engine(pipeline,
                                           server_cfg.engine_args,
-                                          aot_args=server_cfg.aot_args,
                                           recorder=recorder)
         # every continuous replica can play either side of a KV
         # handoff; the router's phase-aware placement decides which

@@ -10,8 +10,8 @@ Every bench round lands as `BENCH_r<NN>.json`:
     {"n": 3, "cmd": "...", "rc": 1, "tail": "<stderr tail>",
      "parsed": null | {...row...} | [{...}, ...]}
 
-where each parsed row is the one-line BENCH schema bench.py /
-serving/bench.py emit ({"metric", "value", "unit", "vs_baseline", and
+where each parsed row is the one-line BENCH schema
+serving/bench.py emits ({"metric", "value", "unit", "vs_baseline", and
 optionally "mfu", "degraded", ...}). The comparator:
 
 - classifies each round: ``ok`` (rc 0 + parsed rows) or ``failed``

@@ -7,13 +7,11 @@ could not answer "how long did this replica take to become ready" or
 
 - ``fstpu_build_info{jax_version,backend}`` is a constant ``1``
   info-gauge (the Prometheus idiom: the VALUE is meaningless, the
-  labels are the payload) set by the api server, the trainer, and the
-  AOT CLI at startup;
+  labels are the payload) set by the api server and the trainer at
+  startup;
 - ``fstpu_warmup_seconds{phase}`` records each warmup phase's wall
   seconds: ``engine`` (serving engine compile of all prefill buckets +
-  decode), ``pipeline`` (the legacy batch-1 warmup request), and
-  ``aot_replay`` (manifest-driven pre-compilation, see
-  docs/aot_cache.md).
+  decode) and ``pipeline`` (the legacy batch-1 warmup request).
 
 Pure-stdlib except for the lazy jax probe, which degrades to
 ``jax_version="none"`` so the exporter works on hosts without jax.

@@ -12,7 +12,6 @@ host memory, and writes it all out on:
 
 - an engine tick error (`serving.engine._serve_loop` wires it),
 - a step-guard rewind (`Trainer._rewind` wires it),
-- the bench watchdog's abort path (`bench.py` wires it),
 - SIGTERM (`install_sigterm`, chained — never replacing — the previous
   handler, the resilience convention),
 - demand (`POST /debug/dump` on both API paths).

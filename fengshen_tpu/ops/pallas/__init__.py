@@ -3,7 +3,7 @@
 Before this, the two Pallas kernels in this package (flash_attention,
 block_sparse_attention) were orphans — each caller re-derived "can the
 backend run Mosaic?" from ``jax.default_backend()`` inline, and the
-decision never reached logs, metrics, or the AOT cache key. Now every
+decision never reached logs or metrics. Now every
 fused kernel registers BOTH implementations here:
 
 - ``pallas`` — the Mosaic TPU kernel (fengshen_tpu.ops.pallas.*);
@@ -21,9 +21,6 @@ and callers route through one seam:
   retrace hazard.
 - :func:`kernel_choice` — the per-op decision (``"pallas"`` or
   ``"xla"``), and :func:`get_kernel` to fetch the callable.
-- :func:`kernel_fingerprint` — the dispatch table serialized for the
-  AOT cache key (docs/aot_cache.md): a pallas-compiled executable must
-  never be replayed on an xla-dispatch process and vice versa.
 - :func:`resolve_dispatch` — what each TRACED call site actually
   takes: the table says which implementation an op prefers on this
   backend, but a seam still routes a shape its kernel cannot tile to
@@ -152,15 +149,6 @@ def get_kernel(op: str, impl: Optional[str] = None) -> Callable:
 def dispatch_table() -> Dict[str, str]:
     """op -> chosen impl for every registered kernel."""
     return {op: kernel_choice(op) for op in sorted(_REGISTRY)}
-
-
-def kernel_fingerprint() -> str:
-    """The dispatch table as a stable string for the AOT cache key
-    (docs/aot_cache.md): two processes whose kernels dispatch
-    differently must never share a compiled executable."""
-    table = ",".join(f"{op}:{impl}" for op, impl in
-                     sorted(dispatch_table().items()))
-    return f"kernels={table};backend={probe().backend}"
 
 
 #: (op, impl, detail) of every dispatch decision a seam took while
@@ -294,7 +282,7 @@ register_kernel("fused_ce", "xla", xla_fused_ce)
 
 __all__ = [
     "KernelProbe", "probe", "register_kernel", "kernel_choice",
-    "get_kernel", "dispatch_table", "kernel_fingerprint", "log_dispatch",
+    "get_kernel", "dispatch_table", "log_dispatch",
     "resolve_dispatch", "traced_dispatch", "run_per_shard",
     "decode_attention", "xla_decode_attention", "pallas_decode_attention",
     "pallas_decode_eligible", "fused_ce_loss", "pallas_fused_ce",

@@ -78,7 +78,7 @@ import numpy as np
 
 from fengshen_tpu.observability import (RequestTimeline,
                                         record_warmup_seconds, span)
-from fengshen_tpu.ops.pallas import kernel_fingerprint, log_dispatch
+from fengshen_tpu.ops.pallas import log_dispatch
 from fengshen_tpu.serving.buckets import DEFAULT_BUCKETS, BucketLadder
 from fengshen_tpu.serving.cache import (abstract_init, assign_slot,
                                         init_slot_cache, reset_free_slots,
@@ -89,7 +89,6 @@ from fengshen_tpu.serving.paged_cache import (BlockAllocator,
                                               blocks_for_tokens,
                                               init_pool_cache)
 from fengshen_tpu.serving.metrics import EngineMetrics
-from fengshen_tpu.sharding import rules_fingerprint
 from fengshen_tpu.streaming import StreamBook
 from fengshen_tpu.utils.generate import (_controls_active,
                                          _ngram_propose_lanes,
@@ -342,12 +341,7 @@ class ContinuousBatchingEngine:
     (`cached_*` row leaves beside a `cache_index`: `cached_key` /
     `cached_value` in the LLaMA family, one `cached_latent` under
     latent attention; `paged_cache.row_leaves`). `clock`
-    is injectable for deterministic deadline tests. `aot` is an
-    optional `fengshen_tpu.aot.AotSetup`: when given, the prefill /
-    assign / decode programs route through the persistent executable
-    cache (`cached_compile`) instead of plain `jax.jit`, so a restarted
-    replica deserializes yesterday's executables rather than re-paying
-    XLA (docs/aot_cache.md).
+    is injectable for deterministic deadline tests.
     """
 
     #: dispatch discriminator for the API layer and /stats — the
@@ -357,7 +351,7 @@ class ContinuousBatchingEngine:
     def __init__(self, model: Any, params: Any, config: EngineConfig,
                  log: Optional[Callable[[dict], None]] = None,
                  clock: Callable[[], float] = time.monotonic,
-                 aot: Any = None, recorder: Any = None,
+                 recorder: Any = None,
                  wall: Callable[[], float] = time.time):
         self.model = model
         self.params = params
@@ -829,7 +823,6 @@ class ContinuousBatchingEngine:
         # as xs/ys, the paged pool was copied six times a tick;
         # PERF.md, PR 25). tests/test_serving_paged.py reads the
         # aliasing off the compiled program.
-        self._aot = aot
         # self-draft programs carry two extra donated buffers (the
         # draft pool in both, plus the draft params slot shifting the
         # argnums); the key ring is donated everywhere it is threaded
@@ -841,39 +834,9 @@ class ContinuousBatchingEngine:
         else:
             assign_donate = (0, 1, 2)
             decode_donate = (1, 2, 8)
-        if aot is not None:
-            # everything the closures bake into the traced programs
-            # beyond argument avals — gates trusted manifest replay
-            # (docs/aot_cache.md): config drift must demote replay to
-            # the verified lower-and-hash path. The kernel dispatch
-            # table is part of that identity: a pallas-compiled decode
-            # must never be replayed on an xla-dispatch process
-            # (docs/kernels.md)
-            # the active logical-axis rules table is part of that
-            # identity too: the same model under a different rules
-            # table lowers to differently-partitioned programs
-            fp = (f"{model.config!r}::{config!r}"
-                  f"::{kernel_fingerprint()}"
-                  f"::{rules_fingerprint()}")
-            if self.self_draft:
-                # the draft tower's shape is baked into the traced
-                # programs too — a manifest compiled at one draft depth
-                # must never replay at another
-                fp += f"::draft={self._draft_model.config!r}"
-            self._prefill_jit = aot.wrap(prefill_fn, "serving/prefill",
-                                         fingerprint_extra=fp)
-            self._assign_jit = aot.wrap(assign_fn, "serving/assign",
-                                        donate_argnums=assign_donate,
-                                        fingerprint_extra=fp)
-            self._decode_jit = aot.wrap(decode_fn, "serving/decode",
-                                        donate_argnums=decode_donate,
-                                        fingerprint_extra=fp)
-        else:
-            self._prefill_jit = jax.jit(prefill_fn)
-            self._assign_jit = jax.jit(assign_fn,
-                                       donate_argnums=assign_donate)
-            self._decode_jit = jax.jit(decode_fn,
-                                       donate_argnums=decode_donate)
+        self._prefill_jit = jax.jit(prefill_fn)
+        self._assign_jit = jax.jit(assign_fn, donate_argnums=assign_donate)
+        self._decode_jit = jax.jit(decode_fn, donate_argnums=decode_donate)
 
     def _init_pool(self):
         """Zeros KV pool in the configured (layout, dtype)."""
@@ -1853,74 +1816,38 @@ class ContinuousBatchingEngine:
 
     def warmup(self) -> float:
         """Compile every prefill bucket + the decode step before traffic
-        (the first user must not pay jit). Returns seconds.
-
-        With an AOT setup attached, the warmup manifest is replayed
-        first — thread-parallel, hitting the persistent executable
-        cache when warm (docs/aot_cache.md) — and covers `serving/
-        assign` too (which plain warmup only compiles at the first
-        admission); the loop below then finds every program already
-        built and is reduced to shape bookkeeping."""
+        (the first user must not pay jit). Returns seconds. The assign
+        program is left to the first admission."""
         t0 = time.perf_counter()
-        replay = None
-        if self._aot is not None:
-            replay = self._aot.replay({
-                "serving/prefill": self._prefill_jit,
-                "serving/assign": self._assign_jit,
-                "serving/decode": self._decode_jit})
-            if replay is not None:
-                record_warmup_seconds("aot_replay", replay["seconds"])
-        if self._aot is not None:
-            # AOT path: `warm()` builds (compiles or deserializes) each
-            # program WITHOUT executing it — after a manifest replay
-            # these are instant signature hits; on a cold/stale cache
-            # they compile exactly what the loop below would have
-            with self._cv:
-                for bucket in self.ladder.buckets:
-                    if bucket + 1 > self.seq_capacity:
-                        continue
-                    ids = np.ones((1, bucket), np.int32)
-                    mask = np.ones((1, bucket), np.int32)
-                    if self.self_draft:
-                        self._prefill_jit.warm(
-                            self.params, self._draft_params, ids, mask,
-                            self._zero_key)
-                    else:
-                        self._prefill_jit.warm(self.params, ids, mask,
-                                               self._zero_key)
-                self._decode_jit.warm(*self._decode_args(self._active))
-        else:
-            with self._cv:
-                for bucket in self.ladder.buckets:
-                    if bucket + 1 > self.seq_capacity:
-                        continue
-                    ids = np.ones((1, bucket), np.int32)
-                    mask = np.ones((1, bucket), np.int32)
-                    # warmup compiles under _cv on purpose: no request
-                    # may tick mid-warmup or it would pay (and double-
-                    # compile) the very programs being primed
-                    if self.self_draft:
-                        jax.block_until_ready(self._prefill_jit(  # fslint: disable=blocking-under-lock; warmup must exclude ticks
-                            self.params, self._draft_params, ids, mask,
-                            self._zero_key))
-                    else:
-                        jax.block_until_ready(self._prefill_jit(  # fslint: disable=blocking-under-lock; warmup must exclude ticks
-                            self.params, ids, mask, self._zero_key))
-                # cache/history/keys (and the draft pool) are donated,
-                # so reassign them; with every lane free the warmup
-                # tick is a no-op on pool state (free lanes write at
-                # index 0 and are fully overwritten by the next
-                # assignment anyway) and on the zero key ring
-                self._run_decode(self._active)  # fslint: disable=blocking-under-lock; warmup must exclude ticks
-                jax.block_until_ready(self._cache)  # fslint: disable=blocking-under-lock; warmup must exclude ticks
+        with self._cv:
+            for bucket in self.ladder.buckets:
+                if bucket + 1 > self.seq_capacity:
+                    continue
+                ids = np.ones((1, bucket), np.int32)
+                mask = np.ones((1, bucket), np.int32)
+                # warmup compiles under _cv on purpose: no request
+                # may tick mid-warmup or it would pay (and double-
+                # compile) the very programs being primed
+                if self.self_draft:
+                    jax.block_until_ready(self._prefill_jit(  # fslint: disable=blocking-under-lock; warmup must exclude ticks
+                        self.params, self._draft_params, ids, mask,
+                        self._zero_key))
+                else:
+                    jax.block_until_ready(self._prefill_jit(  # fslint: disable=blocking-under-lock; warmup must exclude ticks
+                        self.params, ids, mask, self._zero_key))
+            # cache/history/keys (and the draft pool) are donated,
+            # so reassign them; with every lane free the warmup
+            # tick is a no-op on pool state (free lanes write at
+            # index 0 and are fully overwritten by the next
+            # assignment anyway) and on the zero key ring
+            self._run_decode(self._active)  # fslint: disable=blocking-under-lock; warmup must exclude ticks
+            jax.block_until_ready(self._cache)  # fslint: disable=blocking-under-lock; warmup must exclude ticks
         dt = time.perf_counter() - t0
         self.metrics.warmup_compile_s = round(dt, 3)
         record_warmup_seconds("engine", dt)
         entry = {"event": "serving_warmup", "seconds": round(dt, 3),
                  "buckets": list(self.ladder.buckets),
                  "num_slots": self.config.num_slots}
-        if replay is not None:
-            entry["aot_replayed"] = replay["replayed"]
         self._log(entry)
         # every program is traced now: restate the dispatch with the
         # choice each decode/prefill call site actually took

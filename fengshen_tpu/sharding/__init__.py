@@ -7,15 +7,14 @@ declare ``PARAM_LOGICAL_AXES`` (regex → logical tuple);
 :func:`to_partition_rules` resolves them against the active table into
 the regex → PartitionSpec lists the existing partition/trainer/offload
 machinery consumes unchanged; :func:`with_logical_constraint`
-annotates activations; :func:`rules_fingerprint` puts the table into
-the AOT cache key.
+annotates activations.
 """
 
 from fengshen_tpu.sharding.axes import LOGICAL_AXES, LOGICAL_AXIS_SET
 from fengshen_tpu.sharding.rules import (DEFAULT_LOGICAL_AXIS_RULES,
                                          get_rules, resolve_spec,
-                                         rules_fingerprint, set_rules,
-                                         to_partition_rules, use_rules,
+                                         set_rules, to_partition_rules,
+                                         use_rules,
                                          validate_rules,
                                          with_logical_constraint)
 
@@ -30,5 +29,4 @@ __all__ = [
     "resolve_spec",
     "to_partition_rules",
     "with_logical_constraint",
-    "rules_fingerprint",
 ]

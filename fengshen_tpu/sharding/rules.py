@@ -11,17 +11,10 @@ param path → tuple of logical names, the same path-matching contract
 takes unchanged. Activations go through
 :func:`with_logical_constraint`, optimizer state inherits the param
 specs as before.
-
-The resolved sharding is part of a compiled program's identity:
-:func:`rules_fingerprint` serializes the active table into the AOT
-cache key (docs/aot_cache.md) so two deployments with different tables
-can never cross-hit one executable cache.
 """
 
 from __future__ import annotations
 
-import hashlib
-import json
 import threading
 from typing import Any, Optional, Sequence, Tuple
 
@@ -174,22 +167,3 @@ def with_logical_constraint(x, logical_axes: Sequence[Optional[str]],
     from fengshen_tpu.parallel.partition import with_sharding_constraint
     return with_sharding_constraint(x, resolve_spec(logical_axes, rules),
                                     mesh=mesh)
-
-
-def _canonical(rules: Sequence[Tuple[str, Any]]) -> list:
-    return sorted((k, list(v) if isinstance(v, (tuple, list)) else v)
-                  for k, v in rules)
-
-
-def rules_fingerprint(
-        rules: Optional[Sequence[Tuple[str, Any]]] = None) -> str:
-    """Deterministic digest of a table (default: the active one) for
-    the AOT cache key: programs compiled under different tables bake
-    different collectives into the executable, so the table is part of
-    the program identity exactly like the kernel dispatch table
-    (docs/aot_cache.md, docs/kernels.md). Order-insensitive — two
-    spellings of the same mapping hit the same cache."""
-    payload = json.dumps(
-        _canonical(rules if rules is not None else get_rules()),
-        separators=(",", ":"), sort_keys=True)
-    return "lar1:" + hashlib.sha256(payload.encode()).hexdigest()[:16]

@@ -37,10 +37,8 @@ Two pieces fix that for good (docs/offload.md):
 The resolved policy feeds the TrainState shardings
 (`create_sharded_state` / `offload_opt_state_shardings`), the
 offloaded two-program step (`Trainer._build_offloaded_train_step`),
-the streamed engine's `moments_dtype` knob (`StreamedAdamW`), the AOT
-cache key + trusted-replay fingerprint (`OffloadPolicy.fingerprint` —
-placement changes the compiled programs, so a stale cross-placement
-cache hit is structurally impossible), and the observability gauges
+the streamed engine's `moments_dtype` knob (`StreamedAdamW`), and the
+observability gauges
 (`fstpu_offload_level`, `fstpu_memory_kind_supported{kind}`,
 `fstpu_offload_host_bytes`).
 """
@@ -216,16 +214,6 @@ class OffloadPolicy:
     @property
     def level_index(self) -> int:
         return OFFLOAD_LEVELS.index(self.level)
-
-    def fingerprint(self) -> str:
-        """Stable identity of this placement for the AOT cache key and
-        the trusted-replay fingerprint: two placements must never share
-        a compiled-executable cache entry (docs/aot_cache.md)."""
-        kinds = ",".join(sorted(k for k, v in self.caps.supported.items()
-                                if v))
-        return (f"offload={self.level};opt={self.opt_state_kind};"
-                f"master={self.master_kind};moments={self.moments_dtype};"
-                f"kinds={kinds};dev={self.caps.device_memory_kind}")
 
     def describe(self) -> dict:
         return {

@@ -132,6 +132,17 @@ def _spanned_iter(it, name: str):
         yield item
 
 
+class _RefuseAotCacheDir(argparse.Action):
+    """Refuses `--aot_cache_dir`, whose cache is gone."""
+
+    def __call__(self, parser, namespace, values, option_string=None):
+        parser.error(
+            f"{option_string} was removed with the AOT executable cache; "
+            "the one compile cache is jax's own persistent cache "
+            "(fengshen_tpu/compile_cache.py): set "
+            "JAX_COMPILATION_CACHE_DIR to place it")
+
+
 def add_trainer_args(parent_parser: argparse.ArgumentParser):
     """Lightning-Trainer-compatible flag subset actually used by the
     reference examples (SURVEY.md §2.9 pattern)."""
@@ -194,14 +205,11 @@ def add_trainer_args(parent_parser: argparse.ArgumentParser):
              "exporter thread on this port during fit; 0 = off. Only "
              "process_index 0 of a multihost job binds the socket "
              "(docs/observability.md)")
-    parser.add_argument(
-        "--aot_cache_dir", default=None, type=str,
-        help="persistent AOT executable cache directory "
-             "(docs/aot_cache.md): the jitted train step is looked up "
-             "by content address (jax version, devices, mesh axes, "
-             "StableHLO) and deserialized instead of recompiled on "
-             "restart/rewind; any cache failure silently falls back "
-             "to a fresh compile")
+    # removed with the hand-written executable cache: refused by name,
+    # so a launch script that still passes it does not lose its cache
+    # in silence
+    parser.add_argument("--aot_cache_dir", action=_RefuseAotCacheDir,
+                        help=argparse.SUPPRESS)
     # resilience (docs/fault_tolerance.md)
     resil = parent_parser.add_argument_group("resilience")
     resil.add_argument(
@@ -479,54 +487,19 @@ class Trainer:
                 stacked_sh = jax.tree_util.tree_map(
                     lambda spec: NamedSharding(mesh, P(None, *spec)),
                     batch_spec, is_leaf=lambda x: isinstance(x, P))
-            return self._maybe_aot_wrap(jax.jit(
+            return jax.jit(
                 multi_step,
                 in_shardings=(state_sh, stacked_sh, None),
                 out_shardings=(state_sh, None),
                 donate_argnums=(0,),
-            ), "trainer/multi_step"), stacked_sh
+            ), stacked_sh
 
-        return self._maybe_aot_wrap(jax.jit(
+        return jax.jit(
             train_step,
             in_shardings=(state_sh, batch_shardings, None),
             out_shardings=(state_sh, None),
             donate_argnums=(0,),
-        ), "trainer/train_step"), batch_shardings
-
-    def _maybe_aot_wrap(self, jitted, name: str):
-        """Route a jitted step through the persistent executable cache
-        when --aot_cache_dir is set (docs/aot_cache.md): a restart or
-        rewind deserializes the train step instead of re-paying XLA.
-        The offloaded path keeps plain jit (its update program is
-        built lazily per optimizer; see _build_offloaded_train_step)."""
-        cache_dir = getattr(self.args, "aot_cache_dir", None)
-        if not cache_dir:
-            return jitted
-        if getattr(self, "_aot_setup", None) is None:
-            from fengshen_tpu.aot import AotConfig, AotSetup
-            self._aot_setup = AotSetup(AotConfig(cache_dir=cache_dir),
-                                       mesh=self.mesh, log=self._log)
-        # a non-"none" placement enters the cache key — and, through
-        # key_extra, the trusted-replay fingerprint (docs/offload.md):
-        # placement changes the programs' transfer choreography, so a
-        # stale cross-placement cache hit must be impossible. Level
-        # "none" keeps key_extra EMPTY on purpose: it runs the
-        # identical pre-placement program, and a non-empty extra would
-        # invalidate every existing cache entry and warmup manifest of
-        # users who never touch --offload
-        policy = getattr(self, "_offload_policy", None)
-        placement = policy.fingerprint() \
-            if policy is not None and policy.level != "none" else ""
-        # same bargain for the logical-axis rules table
-        # (docs/sharding.md): the DEFAULT table keeps the extra empty
-        # so pre-existing caches stay valid; a custom table changes how
-        # every program is partitioned and must change the key
-        from fengshen_tpu.sharding import (DEFAULT_LOGICAL_AXIS_RULES,
-                                           get_rules, rules_fingerprint)
-        if tuple(get_rules()) != tuple(DEFAULT_LOGICAL_AXIS_RULES):
-            placement = f"{placement}::{rules_fingerprint()}" \
-                if placement else rules_fingerprint()
-        return self._aot_setup.wrap(jitted, name, key_extra=placement)
+        ), batch_shardings
 
     def _build_offloaded_train_step(self, module, state_sh, batch_sh,
                                     policy=None):
@@ -846,8 +819,8 @@ class Trainer:
         # memory placement (docs/offload.md): probe the backend's
         # memory kinds, size the state from eval_shape (no buffers),
         # resolve the offload ladder level BEFORE anything compiles —
-        # the policy decides the state shardings, which step program is
-        # built, and the AOT cache key
+        # the policy decides the state shardings and which step program
+        # is built
         from fengshen_tpu.trainer.memory import (offload_request_from_args,
                                                  record_offload_metrics,
                                                  resolve_offload_policy)

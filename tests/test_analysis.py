@@ -415,25 +415,6 @@ def test_traced_context_spans_local_call_chains(tmp_path):
     assert [f.rule for f in findings] == ["host-divergence"]
 
 
-def test_aot_cache_internals_are_clean():
-    """Regression fixture for the AOT subsystem (docs/aot_cache.md):
-    the cached_compile idiom — metric bumps, pickle/file I/O, and host
-    syncs strictly OUTSIDE traced code — must not trip
-    `metrics-in-traced-code` or `blocking-transfer` (nor any other
-    rule), here or in the real modules. If this starts firing, either
-    the cache grew a traced-context side effect (a real bug) or a rule
-    lost precision."""
-    fixture = os.path.join(FIXTURES, "aot_cache_clean.py")
-    findings = check_file(fixture, make_rules(), REPO)
-    assert not findings, "\n".join(f.render() for f in findings)
-
-    aot_pkg = os.path.join(PKG, "aot")
-    findings = check_paths([aot_pkg], make_rules(), REPO)
-    hits = [f for f in findings
-            if f.rule in ("metrics-in-traced-code", "blocking-transfer")]
-    assert not hits, "\n".join(f.render() for f in hits)
-
-
 def test_offload_policy_internals_are_clean():
     """Regression fixture for the memory-placement subsystem (ISSUE 9,
     docs/offload.md): the capability probe runs OUTSIDE traced code by

@@ -340,13 +340,16 @@ def _start_replica(tiny, max_new, tick_delay_s=0.0):
                      max_queue=32, pad_token_id=0))
     engine.warmup()
     if tick_delay_s:
-        real = engine._decode_jit
+        real = engine._tick
 
-        def slow_decode(*a, **kw):
+        def slow_tick(ahead):
+            # BEFORE the tick takes `_cv`: a sleep inside the decode
+            # call held the lock for the whole delay, and the drill's
+            # poll, export and detach then waited many ticks for it
             time.sleep(tick_delay_s)
-            return real(*a, **kw)
+            return real(ahead)
 
-        engine._decode_jit = slow_decode
+        engine._tick = slow_tick
     engine.start()
     coord = DisaggCoordinator(engine, pipe)
     ready = threading.Event()

@@ -572,13 +572,17 @@ def test_trainer_loop_spans_name_log_callbacks_and_real_saves(
     seen = _record_annotations(monkeypatch)
     modules = []
 
-    def naming_wrap(self, jitted, name):
+    build = Trainer._build_train_step
+
+    def naming_build(self, *a, **kw):
+        jitted, batch_sh = build(self, *a, **kw)
+
         def call(*args):
             if not modules:
                 modules.append(jitted.lower(*args).as_text()[:80])
             return jitted(*args)
-        return call
-    monkeypatch.setattr(Trainer, "_maybe_aot_wrap", naming_wrap)
+        return call, batch_sh
+    monkeypatch.setattr(Trainer, "_build_train_step", naming_build)
 
     args = _parse(["--train_batchsize", "4", "--learning_rate", "1e-3",
                    "--warmup_steps", "1", "--log_every_n_steps", "1",

@@ -1,5 +1,5 @@
 """Memory-placement subsystem (docs/offload.md): capability probe +
-offload policy + AOT-key isolation + /metrics gauges.
+offload policy + /metrics gauges.
 
 Fast lane, model-free by design (ISSUE 9 satellite): everything here is
 probe plumbing and placement math — the multi-layer parity fits live in
@@ -241,18 +241,6 @@ def test_stream_moments_dtype_is_a_policy_knob():
     assert "bfloat16" not in p.reason
 
 
-def test_policy_fingerprints_distinct_per_placement():
-    caps = _fake_caps()
-    fps = {resolve_offload_policy(lvl, caps=caps).fingerprint()
-           for lvl in OFFLOAD_LEVELS}
-    assert len(fps) == len(OFFLOAD_LEVELS)
-    # the probed kind set enters the fingerprint too: the same level
-    # on a pinned-less backend is a different placement
-    assert resolve_offload_policy("opt", caps=caps).fingerprint() != \
-        resolve_offload_policy(
-            "opt", caps=_fake_caps(pinned=False)).fingerprint()
-
-
 def test_announce_logs_the_placement_and_why():
     entries = []
     p = resolve_offload_policy("opt", caps=_fake_caps(pinned=False),
@@ -317,48 +305,6 @@ def test_offload_request_from_args_flag_precedence():
     assert offload_request_from_args(ns) == "opt"
     ns.offload = "none"          # ...but an explicit --offload wins
     assert offload_request_from_args(ns) == "none"
-
-
-# ---- placement in the AOT cache key ---------------------------------
-
-
-def test_offload_placement_forces_distinct_aot_keys(tmp_path):
-    """Acceptance (ISSUE 9): changing the offload level forces a
-    distinct cache key, and both placements' payloads coexist in ONE
-    cache dir without cross-hits."""
-    from fengshen_tpu.aot import AotConfig, AotSetup, cache_key
-    from fengshen_tpu.observability import MetricsRegistry
-
-    fp_a = resolve_offload_policy("none", caps=_fake_caps()).fingerprint()
-    fp_b = resolve_offload_policy("opt", caps=_fake_caps()).fingerprint()
-    jitted = jax.jit(lambda x: x * 2)
-    lowered = jitted.lower(jax.ShapeDtypeStruct((4,), np.float32))
-    base = cache_key("t/step", lowered)
-    assert cache_key("t/step", lowered, extra=fp_a) != \
-        cache_key("t/step", lowered, extra=fp_b)
-    # empty extra keeps the pre-placement key derivation (no blanket
-    # cache invalidation for non-trainer users)
-    assert cache_key("t/step", lowered, extra="") == base
-
-    setup = AotSetup(AotConfig(cache_dir=str(tmp_path), record=False),
-                     registry=MetricsRegistry())
-    aval = jax.ShapeDtypeStruct((4,), np.float32)
-    setup.wrap(lambda x: x * 2, "t/step", key_extra=fp_a).warm(aval)
-    setup.wrap(lambda x: x * 2, "t/step", key_extra=fp_b).warm(aval)
-    blobs = setup.cache.entries()
-    assert len(blobs) == 2  # same fn, same aval, two placements
-    assert len({e.key for e in blobs}) == 2
-
-    # a fresh process at placement A hits ONLY its own entry
-    reg = MetricsRegistry()
-    setup2 = AotSetup(AotConfig(cache_dir=str(tmp_path), record=False),
-                      registry=reg)
-    setup2.wrap(lambda x: x * 2, "t/step", key_extra=fp_a).warm(aval)
-    from fengshen_tpu.aot import HITS_METRIC, MISSES_METRIC
-    assert reg.get(HITS_METRIC).labels("t/step").value == 1
-    assert reg.get(MISSES_METRIC) is None or \
-        reg.get(MISSES_METRIC).labels("t/step").value == 0
-    assert len(setup2.cache.entries()) == 2  # nothing clobbered
 
 
 # ---- /metrics gauges ------------------------------------------------

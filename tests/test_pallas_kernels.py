@@ -20,8 +20,7 @@ import pytest
 
 from fengshen_tpu.ops.pallas import (FORCE_ENV, dispatch_table,
                                      get_kernel, kernel_choice,
-                                     kernel_fingerprint, log_dispatch,
-                                     probe)
+                                     log_dispatch, probe)
 from fengshen_tpu.ops.pallas.decode_attention import (
     decode_attention, pallas_decode_attention, pallas_decode_eligible,
     xla_decode_attention)
@@ -54,19 +53,14 @@ def test_probe_cached_and_forceable(fresh_probe):
     assert not probe().pallas_tpu
 
 
-def test_dispatch_table_and_fingerprint(fresh_probe):
+def test_dispatch_table_follows_the_probe(fresh_probe):
     table = dispatch_table()
     for op in ("decode_attention", "fused_ce", "flash_attention",
                "block_sparse_attention"):
         assert table[op] == "xla"  # CPU backend: stock lowerings
-    fp = kernel_fingerprint()
-    assert fp.startswith("kernels=") and fp.endswith(";backend=cpu")
-    assert "decode_attention:xla" in fp
 
-    # the AOT-key contract: a forced-pallas process fingerprints
-    # differently, so it can never replay an xla-dispatch executable
     fresh_probe.setenv(FORCE_ENV, "pallas")
-    assert "decode_attention:pallas" in kernel_fingerprint()
+    assert dispatch_table()["decode_attention"] == "pallas"
 
 
 def test_get_kernel_resolution(fresh_probe):
@@ -518,15 +512,3 @@ def test_benchdiff_kernel_rows_incomparable():
     assert (2, "incomparable") in statuses  # xla -> pallas: new program
     assert (3, "regression") in statuses    # pallas -> pallas: honest
     assert report["verdict"] == "REGRESSED"
-
-
-def test_engine_aot_key_carries_kernel_fingerprint():
-    """serving/engine.py folds kernel_fingerprint() into the AOT cache
-    identity — source-level pin that a pallas-dispatch process can
-    never replay an xla-dispatch executable (docs/aot_cache.md)."""
-    import inspect
-
-    from fengshen_tpu.serving import engine
-
-    src = inspect.getsource(engine)
-    assert "kernel_fingerprint()" in src

@@ -358,38 +358,6 @@ def test_cancel_running_paged_request_frees_blocks(tiny):
     assert eng.stats()["kv_blocks_used"] == 0
 
 
-# ---- AOT integration ----------------------------------------------------
-
-def test_paged_engine_through_aot_cache(tiny, tmp_path):
-    """The KV knobs flow into the AOT path (docs/aot_cache.md): a
-    paged engine warms through the persistent executable cache, a
-    SECOND paged engine in the same dir replays/deserializes it with
-    token parity, and a different carving coexists as distinct
-    executables (different avals → different keys — no collision,
-    no wrong-executable reuse)."""
-    from fengshen_tpu.aot import AotConfig, AotSetup
-
-    model, params = tiny
-    prompts = _prompts((5, 11), seed=6)
-    refs = [_ref(model, params, p, 6) for p in prompts]
-    cfg = EngineConfig(num_slots=2, buckets=(8, 16), max_new_tokens=6,
-                       max_queue=8, **PAGED)
-
-    def build(config):
-        aot = AotSetup(AotConfig(cache_dir=str(tmp_path)))
-        eng = ContinuousBatchingEngine(model, params, config, aot=aot)
-        eng.warmup()
-        return eng
-    assert build(cfg).generate_all(prompts) == refs
-    assert build(cfg).generate_all(prompts) == refs     # warm replay
-    # a different carving must be a different executable, not a hit
-    # on the first one's blob
-    recarved = EngineConfig(num_slots=2, buckets=(8, 16),
-                            max_new_tokens=6, max_queue=8,
-                            kv_layout="paged", kv_block_size=8)
-    assert build(recarved).generate_all(prompts) == refs
-
-
 # ---- pool state & config surface ----------------------------------------
 
 def test_kv_stats_shape_on_stats(tiny):

@@ -376,36 +376,3 @@ def test_spec_metrics_count_only_delivered_tokens(tiny):
     assert st["spec_accepted_total"] <= st["decode_tokens"]
     # drafted = gamma per active lane per tick
     assert 0 < st["spec_drafted_total"] <= 4 * st["decode_ticks"] * 3
-
-
-# ---- AOT integration ----------------------------------------------------
-
-def test_spec_engine_through_aot_cache(tiny, tmp_path):
-    """The spec knobs flow into the AOT key (gamma via the verify
-    avals, spec_mode via the EngineConfig-repr fingerprint): a spec
-    engine warms through the persistent cache, a second engine replays
-    it with token parity, and a different gamma coexists as a distinct
-    executable."""
-    from fengshen_tpu.aot import AotConfig, AotSetup
-
-    model, params = tiny
-    prompts = _prompts((5, 11), seed=6)
-    refs = [_ref(model, params, p, 6) for p in prompts]
-
-    def build(gamma):
-        aot = AotSetup(AotConfig(cache_dir=str(tmp_path)))
-        return ContinuousBatchingEngine(
-            model, params,
-            EngineConfig(num_slots=2, buckets=(8, 16), max_new_tokens=6,
-                         max_queue=8, spec_mode="prompt_lookup",
-                         spec_gamma=gamma), aot=aot)
-
-    eng = build(4)
-    eng.warmup()
-    assert eng.generate_all(prompts) == refs
-    eng2 = build(4)
-    eng2.warmup()                        # warm replay
-    assert eng2.generate_all(prompts) == refs
-    eng3 = build(2)                      # different gamma, same dir
-    eng3.warmup()
-    assert eng3.generate_all(prompts) == refs

@@ -10,9 +10,7 @@ The load-bearing contracts:
   (the migration-equivalence pins below — regressing one silently
   changes how a fleet shards);
 - ``use_rules`` scopes an alternative table without leaking across the
-  default, and ``rules_fingerprint`` keys the AOT cache so programs
-  compiled under different tables can never cross-hit (the
-  coexistence test);
+  default;
 - the rule-driven parity matrix: llama, transfo_xl, sd_unet and clip
   run SHARDED on the virtual 8-device mesh numerically equal to
   replicated — including the two towers whose divergences this
@@ -33,8 +31,7 @@ from jax.sharding import PartitionSpec as P
 
 from fengshen_tpu.sharding import (DEFAULT_LOGICAL_AXIS_RULES,
                                    LOGICAL_AXES, LOGICAL_AXIS_SET,
-                                   get_rules, resolve_spec,
-                                   rules_fingerprint, set_rules,
+                                   get_rules, resolve_spec, set_rules,
                                    to_partition_rules, use_rules,
                                    validate_rules)
 
@@ -91,24 +88,6 @@ def test_use_rules_scoping_and_set_rules():
     with pytest.raises(ValueError, match="unknown logical axis"):
         set_rules((("head", "tensor"),))
     assert get_rules() == DEFAULT_LOGICAL_AXIS_RULES
-
-
-def test_rules_fingerprint_stable_and_order_insensitive():
-    fp = rules_fingerprint()
-    assert fp.startswith("lar1:") and len(fp) == len("lar1:") + 16
-    assert fp == rules_fingerprint(DEFAULT_LOGICAL_AXIS_RULES)
-    # order-insensitive: two spellings of the same mapping, one key
-    assert rules_fingerprint(tuple(reversed(
-        DEFAULT_LOGICAL_AXIS_RULES))) == fp
-    # tuple-vs-list spelling of a multi-axis mapping, one key
-    respelled = tuple((k, list(v)) if isinstance(v, tuple) else (k, v)
-                      for k, v in DEFAULT_LOGICAL_AXIS_RULES)
-    assert rules_fingerprint(respelled) == fp
-    custom = tuple((k, None) if k == "mlp" else (k, v)
-                   for k, v in DEFAULT_LOGICAL_AXIS_RULES)
-    assert rules_fingerprint(custom) != fp
-    with use_rules(custom):
-        assert rules_fingerprint() == rules_fingerprint(custom)
 
 
 # ---- migration-equivalence pins ----------------------------------------
@@ -334,84 +313,3 @@ def test_llama_greedy_decode_token_identity_sharded(mesh8):
     out = np.asarray(generate(model, sharded, ids, max_new_tokens=12,
                               eos_token_id=None, pad_token_id=0))
     np.testing.assert_array_equal(out, ref)
-
-
-# ---- AOT-key coexistence ------------------------------------------------
-
-class _FpCapture:
-    """Stands in for AotSetup: records the fingerprint_extra each wrap
-    site bakes into its cache key."""
-
-    def __init__(self):
-        self.fps = {}
-
-    def wrap(self, fn, name, fingerprint_extra=None, donate_argnums=()):
-        self.fps[name] = fingerprint_extra
-        return jax.jit(fn, donate_argnums=donate_argnums)
-
-
-def test_engine_aot_key_separates_rules_tables():
-    """Two deployments of the SAME model under different rules tables
-    must produce different AOT cache keys — the executables bake
-    different collectives, so a cross-hit would be wrong-program replay
-    (docs/aot_cache.md)."""
-    from fengshen_tpu.models.llama import LlamaConfig, LlamaForCausalLM
-    from fengshen_tpu.serving import (ContinuousBatchingEngine,
-                                      EngineConfig)
-    cfg = LlamaConfig(vocab_size=97, hidden_size=32,
-                      intermediate_size=64, num_hidden_layers=2,
-                      num_attention_heads=4,
-                      max_position_embeddings=64, dtype="float32")
-    model = LlamaForCausalLM(cfg)
-    params = model.init(jax.random.PRNGKey(0),
-                        jnp.zeros((1, 4), jnp.int32))["params"]
-    ecfg = dict(num_slots=2, buckets=(8, 16), max_new_tokens=8,
-                max_queue=4)
-
-    default_aot = _FpCapture()
-    ContinuousBatchingEngine(model, params, EngineConfig(**ecfg),
-                             aot=default_aot)
-    custom = tuple((k, None) if k == "mlp" else (k, v)
-                   for k, v in DEFAULT_LOGICAL_AXIS_RULES)
-    custom_aot = _FpCapture()
-    with use_rules(custom):
-        ContinuousBatchingEngine(model, params, EngineConfig(**ecfg),
-                                 aot=custom_aot)
-
-    assert set(default_aot.fps) == {"serving/prefill", "serving/assign",
-                                    "serving/decode"}
-    for name, fp in default_aot.fps.items():
-        assert rules_fingerprint(DEFAULT_LOGICAL_AXIS_RULES) in fp
-        assert rules_fingerprint(custom) in custom_aot.fps[name]
-        assert fp != custom_aot.fps[name]
-
-
-def test_trainer_key_extra_carries_non_default_rules(tmp_path):
-    """The trainer's AOT key gains the rules fingerprint ONLY for
-    non-default tables (the level-none precedent: existing caches keyed
-    without it must keep hitting)."""
-    from fengshen_tpu.trainer.trainer import Trainer
-
-    captured = []
-
-    class _Setup:
-        def wrap(self, fn, name, key_extra=None, **kw):
-            captured.append((name, key_extra))
-            return fn
-
-    class _Args:
-        aot_cache_dir = str(tmp_path)
-
-    tr = Trainer.__new__(Trainer)
-    tr.args = _Args()
-    tr._aot_setup = _Setup()
-    tr._offload_policy = None
-    tr._maybe_aot_wrap(lambda x: x, "t/step")
-    custom = tuple((k, None) if k == "mlp" else (k, v)
-                   for k, v in DEFAULT_LOGICAL_AXIS_RULES)
-    with use_rules(custom):
-        tr._maybe_aot_wrap(lambda x: x, "t/step")
-
-    (_, default_extra), (_, custom_extra) = captured
-    assert not default_extra
-    assert custom_extra and rules_fingerprint(custom) in custom_extra

@@ -109,7 +109,7 @@ def test_13b_shape_partition_compiles_without_spec_drops(mesh8, caplog):
     add_trainer_args(parser)
     args = parser.parse_args(["--precision", "bf16"])
 
-    # the BENCH_CONFIG=large ladder shape (bench.py): Ziya-LLaMA-13B dims
+    # Ziya-LLaMA-13B dims
     config = LlamaConfig(
         vocab_size=32000, hidden_size=5120, intermediate_size=13824,
         num_hidden_layers=40, num_attention_heads=40,

@@ -878,32 +878,3 @@ def test_grouped_prefetch_drops_ragged_group(capsys):
     out = list(_prefetch_grouped(iter(batches), sh, 2))
     assert len(out) == 1  # first group ok, ragged second group dropped
     assert "mismatched batch shapes" in capsys.readouterr().out
-
-
-def test_aot_cache_dir_reuses_train_step_across_fits(mesh8, tmp_path):
-    """--aot_cache_dir (docs/aot_cache.md): the first fit compiles the
-    train step and persists it; a second fit — the restart/rewind case
-    — deserializes it (cache hit) and trains identically: same losses,
-    same final params."""
-    from fengshen_tpu.observability import get_registry
-
-    def _hits():
-        m = get_registry().get("fstpu_aot_cache_hits_total")
-        return {k[0]: c.value for k, c in m.children()} if m else {}
-
-    cache_dir = tmp_path / "aot-cache"
-    state1, losses1 = _fit_tiny(
-        tmp_path / "a", ["--aot_cache_dir", str(cache_dir)])
-    blobs = [f for f in os.listdir(cache_dir) if f.endswith(".aotx")]
-    assert any(f.startswith("trainer-train_step") for f in blobs), blobs
-    base = _hits().get("trainer/train_step", 0)
-
-    state2, losses2 = _fit_tiny(
-        tmp_path / "b", ["--aot_cache_dir", str(cache_dir)])
-    assert _hits().get("trainer/train_step", 0) > base
-    assert int(state1.step) == int(state2.step) == 4
-    np.testing.assert_allclose(losses1, losses2, rtol=1e-6)
-    for a, b in zip(jax.tree_util.tree_leaves(state1.params),
-                    jax.tree_util.tree_leaves(state2.params)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-6, atol=1e-7)
