@@ -25,7 +25,9 @@ from flax import linen as nn
 from flax.traverse_util import flatten_dict
 from fengshen_tpu.models.llama.configuration_llama import LlamaConfig
 from fengshen_tpu.ops.attention import dot_product_attention
-from fengshen_tpu.ops.pallas.decode_attention import decode_attention
+from fengshen_tpu.ops.flash_attention import prefill_attention
+from fengshen_tpu.ops.pallas.decode_attention import (_MAX_QUERY_WINDOW,
+                                                      decode_attention)
 from fengshen_tpu.ops.embedding import VocabParallelEmbed
 from fengshen_tpu.ops.masks import causal_mask
 from fengshen_tpu.ops.norms import RMSNorm
@@ -136,7 +138,7 @@ class LlamaAttention(nn.Module):
     @nn.compact
     def __call__(self, hidden, attention_mask=None, position_ids=None,
                  init_cache: bool = False, deterministic: bool = True,
-                 layer=None):
+                 cache_empty: bool = False, layer=None):
         cfg = self.config
         n_heads, n_kv = cfg.num_attention_heads, cfg.num_key_value_heads
         head_dim = cfg.head_dim
@@ -169,11 +171,22 @@ class LlamaAttention(nn.Module):
             # → dense chain op-for-op, so CPU tier-1 pins decode
             # token-identical through the seam
             view = self._update_cache(k, v, attention_mask, layer)
-            out = decode_attention(
-                q, view.k, view.v, view.valid,
-                k_scale=view.k_scale, v_scale=view.v_scale,
-                block_table=view.block_table, layer=view.layer,
-                dequant_dtype=_dt(cfg))
+            if cache_empty and seq > _MAX_QUERY_WINDOW and \
+                    self._is_lockstep_cache():
+                # a whole prompt onto an empty cache: the K/V just
+                # projected ARE every key a query may see, so the read
+                # skips the cache's extent (rows of zeros the mask
+                # throws away) and the seam's dense lowering over it.
+                # The write above is the same; the tick and the verify
+                # windows (short, or onto a cache that holds a prefix)
+                # stay on the seam
+                out = prefill_attention(q, k, v, attention_mask)
+            else:
+                out = decode_attention(
+                    q, view.k, view.v, view.valid,
+                    k_scale=view.k_scale, v_scale=view.v_scale,
+                    block_table=view.block_table, layer=view.layer,
+                    dequant_dtype=_dt(cfg))
         else:
             mask = causal_mask(seq, k.shape[1])[None, None]
             if attention_mask is not None:
@@ -216,6 +229,14 @@ class LlamaAttention(nn.Module):
         out = out.reshape(batch, seq, n_heads * head_dim)
         return dense(cfg.hidden_size, "o_proj")(out)
 
+    def _is_lockstep_cache(self) -> bool:
+        """Whether the cache `_update_cache` has just written is the
+        contiguous one with a scalar `cache_index` (`utils.generate`),
+        not the slot pool or the paged pool. Static under jit: variable
+        names and ranks."""
+        return not self.has_variable("cache", "block_table") and \
+            self.get_variable("cache", "cache_index").ndim == 0
+
     def _update_cache(self, k, v, attention_mask, layer=None):
         """flax mutable-cache decode (same role as the reference's KV concat,
         reference: transformer.py:529-537, but with static shapes for XLA:
@@ -236,6 +257,16 @@ class LlamaAttention(nn.Module):
         decode_attention dispatch seam owns the read (gather/dequant on
         the xla lowering, table-indirect + in-register dequant in the
         Mosaic kernel).
+
+        One caller does not read what this returns: a whole-prompt
+        prefill onto an EMPTY lockstep cache (`__call__`'s
+        `cache_empty`) takes the write alone and attends over the K/V
+        it passed in, under the same law (causal, no pad keys) cut to
+        the prompt's own `seq` keys. The view's `valid` spans the
+        whole cache, `[B, seq, max_len]`, and its `k`/`v` ARE the whole
+        cache: the seam's dense lowering over them is `[H, seq,
+        max_len]` scores, which is why only short windows, and windows
+        that must see a cached prefix, go there.
         """
         cfg = self.config
         batch, seq, n_kv, head_dim = k.shape
@@ -447,12 +478,13 @@ class LlamaDecoderLayer(nn.Module):
 
     @nn.compact
     def __call__(self, hidden, attention_mask=None, position_ids=None,
-                 init_cache=False, deterministic=True, layer=None):
+                 init_cache=False, deterministic=True, cache_empty=False,
+                 layer=None):
         cfg = self.config
         h = RMSNorm(epsilon=cfg.rms_norm_eps, name="input_layernorm")(hidden)
         h = LlamaAttention(cfg, name="self_attn")(
             h, attention_mask, position_ids, init_cache, deterministic,
-            layer)
+            cache_empty, layer)
         hidden = hidden + h
         h = RMSNorm(epsilon=cfg.rms_norm_eps,
                     name="post_attention_layernorm")(hidden)
@@ -488,10 +520,10 @@ class _ScanDecoderLayer(nn.Module):
 
     @nn.compact
     def __call__(self, hidden, attention_mask, position_ids, init_cache,
-                 deterministic, layer=None):
+                 deterministic, cache_empty=False, layer=None):
         out = LlamaDecoderLayer(self.config, name="layer")(
             hidden, attention_mask, position_ids, init_cache, deterministic,
-            layer)
+            cache_empty, layer)
         return out, None
 
 
@@ -502,7 +534,7 @@ class LlamaModel(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, attention_mask=None, position_ids=None,
-                 init_cache=False, deterministic=True):
+                 init_cache=False, deterministic=True, cache_empty=False):
         cfg = self.config
         embed = VocabParallelEmbed(cfg.vocab_size, cfg.hidden_size,
                                    dtype=_dt(cfg),
@@ -524,16 +556,16 @@ class LlamaModel(nn.Module):
             body = _ScanDecoderLayer
             if cfg.gradient_checkpointing:
                 body = nn.remat(
-                    body, static_argnums=(4, 5),
+                    body, static_argnums=(4, 5, 6),
                     policy=remat_policy,
                     prevent_cse=False)
             scan_kw = dict(
                 variable_axes={"params": 0, "cache": 0, "losses": 0},
                 split_rngs={"params": True, "dropout": True},
-                in_axes=(nn.broadcast,) * 4,
+                in_axes=(nn.broadcast,) * 5,
                 length=cfg.num_hidden_layers)
             args = (hidden, attention_mask, position_ids, init_cache,
-                    deterministic)
+                    deterministic, cache_empty)
             if _holds_block_table(self.variables.get("cache", {})):
                 # a paged KV pool is loop STATE, not a scanned
                 # input/output: as xs/ys every iteration would slice a
@@ -546,19 +578,19 @@ class LlamaModel(nn.Module):
                 scan_kw.update(
                     variable_axes={"params": 0, "losses": 0},
                     variable_carry="cache",
-                    in_axes=(nn.broadcast,) * 4 + (0,))
+                    in_axes=(nn.broadcast,) * 5 + (0,))
                 args += (jnp.arange(cfg.num_hidden_layers),)
             hidden, _ = nn.scan(body, **scan_kw)(cfg, name="layers")(*args)
         else:
             layer_cls = LlamaDecoderLayer
             if cfg.gradient_checkpointing:
                 layer_cls = nn.remat(
-                    layer_cls, static_argnums=(4, 5),
+                    layer_cls, static_argnums=(4, 5, 6),
                     policy=remat_policy)
             for i in range(cfg.num_hidden_layers):
                 hidden = layer_cls(cfg, name=f"layers_{i}")(
                     hidden, attention_mask, position_ids, init_cache,
-                    deterministic)
+                    deterministic, cache_empty)
         return RMSNorm(epsilon=cfg.rms_norm_eps, name="norm")(hidden)
 
 
@@ -588,11 +620,15 @@ class LlamaForCausalLM(nn.Module):
     @nn.compact
     def __call__(self, input_ids, attention_mask=None, position_ids=None,
                  init_cache=False, deterministic=True,
-                 return_hidden=False):
+                 return_hidden=False, cache_empty=False):
+        """`cache_empty` (static) says the cache this call writes holds
+        nothing yet: a whole-prompt prefill, which then attends over the
+        prompt's own keys (`LlamaAttention`). Only the caller that made
+        the cache can know: `cache_index` is traced."""
         cfg = self.config
         hidden = LlamaModel(cfg, name="model")(
             input_ids, attention_mask, position_ids, init_cache,
-            deterministic)
+            deterministic, cache_empty)
         if return_hidden:
             # the fused chunked LM-head+CE path (ops/fused_ce.py)
             # applies the head itself from the param tree (init always

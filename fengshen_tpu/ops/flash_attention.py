@@ -166,22 +166,14 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if q_seg is not None:
         q_seg = q_seg.astype(jnp.int32)
         kv_seg = kv_seg.astype(jnp.int32)
-    from fengshen_tpu.ops.pallas import resolve_dispatch, run_per_shard
+    from fengshen_tpu.ops.pallas import resolve_dispatch
     impl = resolve_dispatch(
         "flash_attention",
         f"q={tuple(q.shape)} kv={tuple(k.shape)}:{q.dtype.name} "
         f"causal={causal} segments={q_seg is not None}",
         _pallas_ineligible_reason(q, k, bias))
     if impl == "pallas":
-        from fengshen_tpu.ops.pallas.flash_attention import (
-            pallas_flash_attention)
-        if q_seg is None:
-            return run_per_shard(
-                lambda q, k, v: pallas_flash_attention(
-                    q, k, v, None, None, causal), q, k, v)
-        return run_per_shard(
-            lambda q, k, v, q_seg, kv_seg: pallas_flash_attention(
-                q, k, v, q_seg, kv_seg, causal), q, k, v, q_seg, kv_seg)
+        return _pallas_attention(q, k, v, q_seg, kv_seg, causal)
     if k.shape[2] != q.shape[2]:  # GQA on blockwise: repeat the KV heads
         rep = q.shape[2] // k.shape[2]
         k = jnp.repeat(k, rep, axis=2)
@@ -189,6 +181,65 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return blockwise_attention(q, k, v, bias=bias, causal=causal,
                                block_size=block_size,
                                q_segment_ids=q_seg, kv_segment_ids=kv_seg)
+
+
+def _pallas_attention(q, k, v, q_seg, kv_seg, causal):
+    """The Mosaic kernel, per shard under a device mesh."""
+    from fengshen_tpu.ops.pallas import run_per_shard
+    from fengshen_tpu.ops.pallas.flash_attention import (
+        pallas_flash_attention)
+    if q_seg is None:
+        return run_per_shard(
+            lambda q, k, v: pallas_flash_attention(
+                q, k, v, None, None, causal), q, k, v)
+    return run_per_shard(
+        lambda q, k, v, q_seg, kv_seg: pallas_flash_attention(
+            q, k, v, q_seg, kv_seg, causal), q, k, v, q_seg, kv_seg)
+
+
+def prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                      attention_mask: Optional[jax.Array] = None
+                      ) -> jax.Array:
+    """Causal attention of a whole prompt over its OWN keys: what a
+    prefill onto an empty KV cache computes, without reading the cache
+    it fills (every cache row past the prompt has weight exactly 0).
+
+    q: ``[B, S, H, D]``; k/v: ``[B, S, KVH, D]``, the rows just
+    projected; ``attention_mask``: ``[B, S]``, 0 on the (left) pads, or
+    None. One algorithm, two lowerings, chosen per traced call site
+    through ``resolve_dispatch("flash_attention", ...)``: the Mosaic
+    flash kernel where its tiling takes the shape (causal, the mask as
+    segment ids, GQA read once per group), else the dense chain under
+    the causal mask without the pad keys — the sums the decode seam's
+    dense lowering computes over the cache's whole extent, without the
+    zeros.
+
+    Pad rows: under segment ids a pad query attends the pad keys at or
+    before it (segment 0); under the dense mask it has no key and the
+    softmax spreads it over all. Neither is read: no real query sees a
+    pad key on either lowering, and a pad row's logits and cache rows
+    are masked wherever they are used.
+    """
+    from fengshen_tpu.ops.attention import dot_product_attention
+    from fengshen_tpu.ops.masks import causal_mask
+    from fengshen_tpu.ops.pallas import resolve_dispatch
+    impl = resolve_dispatch(
+        "flash_attention",
+        f"prefill q={tuple(q.shape)} kv={tuple(k.shape)}:{q.dtype.name} "
+        f"causal=True segments={attention_mask is not None}",
+        _pallas_ineligible_reason(q, k, None))
+    if impl == "pallas":
+        seg = None if attention_mask is None else \
+            attention_mask.astype(bool).astype(jnp.int32)
+        return _pallas_attention(q, k, v, seg, seg, True)
+    mask = causal_mask(q.shape[1])[None, None]
+    if attention_mask is not None:
+        mask = mask & attention_mask[:, None, None, :].astype(bool)
+    if k.shape[2] != q.shape[2]:
+        rep = q.shape[2] // k.shape[2]
+        k = jnp.repeat(k, rep, axis=2)
+        v = jnp.repeat(v, rep, axis=2)
+    return dot_product_attention(q, k, v, mask=mask)
 
 
 def _pallas_ineligible_reason(q, k, bias) -> Optional[str]:

@@ -32,6 +32,35 @@ from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
+#: the name the forward kernel carries into HLO and the profiler's trace
+TRACE_NAME = "fstpu_flash_attention"
+
+#: tile edge (queries x keys) of the forward and of the two backward
+#: kernels where the caller names none. Forward: on a v5e at `[1, 2048,
+#: 32, 128]` bf16 over 8 KV heads, causal with segment ids, 256 x 256
+#: tiles took 1.45 ms a call, 512 x 512 0.88, 1024 x 1024 0.63 (PERF.md,
+#: PR 29): many small steps cost more than the part of a diagonal tile
+#: the causal skip saves, and the f32 score tile (4 MiB) still fits the
+#: scoped VMEM. The backward holds three such tiles: it stays at the
+#: size it has compiled and run at (`chip_smoke.py`); no cell times it.
+_FWD_TILE = 1024
+_BWD_TILE = 256
+
+
+def _tile(length: int, cap: int) -> int:
+    """The largest tile of at most `cap` that divides `length`, in
+    steps of 128 lanes: 1024 for 2048, but 768 for 1536 and 384 for
+    384 (any multiple of 128 is an eligible length)."""
+    if length <= cap:
+        return length
+    if length % cap == 0:
+        return cap
+    for tile in range(cap // 128 * 128, 0, -128):
+        if length % tile == 0:
+            return tile
+    raise ValueError(f"no tile of at most {cap} (or multiple of 128 "
+                     f"under it) divides the length {length}")
+
 
 def _mask_scores(scores, causal, q_start, k_start, blk_q, blk_k,
                  seg_q, seg_k):
@@ -119,9 +148,8 @@ def _fwd_impl(q, k, v, q_seg, kv_seg, causal, blk_q, blk_k, interpret):
     batch, num_heads, q_len, head_dim = q.shape
     k_len = k.shape[2]
     rep = num_heads // k.shape[1]  # q heads per kv head (1 = MHA)
-    blk_q = min(blk_q, q_len)
-    blk_k = min(blk_k, k_len)
-    assert q_len % blk_q == 0 and k_len % blk_k == 0
+    blk_q = _tile(q_len, blk_q or _FWD_TILE)
+    blk_k = _tile(k_len, blk_k or _FWD_TILE)
     scale = float(1.0 / (head_dim ** 0.5))
     n_kblocks = k_len // blk_k
     has_segments = q_seg is not None
@@ -165,7 +193,7 @@ def _fwd_impl(q, k, v, q_seg, kv_seg, causal, blk_q, blk_k, interpret):
             pltpu.VMEM((blk_q, 1), jnp.float32),         # running max
             pltpu.VMEM((blk_q, 1), jnp.float32),         # running sum
         ],
-        interpret=interpret,
+        interpret=interpret, name=TRACE_NAME,
     )(q, k, v, q_seg3, kv_seg3)
     return out, lse
 
@@ -300,8 +328,8 @@ def _bwd_impl(q, k, v, q_seg, kv_seg, out, lse, do,
                              head_dim).sum(2).astype(v.dtype)
         return dq, dk, dv
     k_len = k.shape[2]
-    blk_q = min(blk_q, q_len)
-    blk_k = min(blk_k, k_len)
+    blk_q = _tile(q_len, blk_q or _BWD_TILE)
+    blk_k = _tile(k_len, blk_k or _BWD_TILE)
     scale = float(1.0 / (head_dim ** 0.5))
     n_qblocks, n_kblocks = q_len // blk_q, k_len // blk_k
     has_segments = q_seg is not None
@@ -399,7 +427,8 @@ def pallas_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                            q_segment_ids: jax.Array | None = None,
                            kv_segment_ids: jax.Array | None = None,
                            causal: bool = False,
-                           blk_q: int = 256, blk_k: int = 256,
+                           blk_q: int | None = None,
+                           blk_k: int | None = None,
                            interpret: bool = False) -> jax.Array:
     """q: [B, Sq, H, D], k/v: [B, Sk, KVH, D] → [B, Sq, H, D].
 
@@ -408,9 +437,11 @@ def pallas_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     repeat); the backward computes per-query-head dk/dv and group-sums.
 
     segment ids: int32 [B, S]; tokens attend only within equal ids (pads are
-    segment 0 when derived from an attention_mask). Requires Sq % blk_q == 0,
-    Sk % blk_k == 0 (the eligibility rule in ops.flash_attention.flash_attention
-    guarantees tile-aligned shapes, in the spirit of the reference's
+    segment 0 when derived from an attention_mask). `blk_q` / `blk_k` cap
+    the tile; None takes the kernels' own (`_FWD_TILE`, `_BWD_TILE`), and
+    the tile is the largest under the cap that divides the length (the
+    eligibility rule in ops.flash_attention.flash_attention guarantees
+    lengths in multiples of 128, in the spirit of the reference's
     fused-kernel availability check, reference:
     fengshen/models/megatron/layers/fused_softmax.py:148-168).
     """

@@ -268,10 +268,16 @@ def _prefill_cache(model, params, input_ids, attention_mask,
                            init_cache=True))
     cache = jax.tree_util.tree_map(
         lambda s: jnp.zeros(s.shape, s.dtype), abstract["cache"])
+    # the one place that KNOWS the cache holds nothing (it was made a
+    # line ago; its index is traced): a model that can use the fact
+    # attends over the prompt's own keys, not the cache's extent
+    import inspect
+    empty = {"cache_empty": True} if "cache_empty" in inspect.signature(
+        type(model).__call__).parameters else {}
     logits, mutated = model.apply(
         {"params": params, "cache": cache}, input_ids,
         attention_mask=attention_mask, position_ids=position_ids,
-        init_cache=True, mutable=["cache"])
+        init_cache=True, mutable=["cache"], **empty)
     return logits, mutated["cache"]
 
 

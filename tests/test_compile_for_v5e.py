@@ -170,3 +170,49 @@ def test_routed_experts_compile_to_the_grouped_matmul_at_published_widths(
     assert compiled.cost_analysis()["flops"] < 2 * once
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 64 * 2 ** 20      # no [T, E, C]
+
+
+def test_whole_prompt_prefill_holds_the_flash_call_and_no_scores_tensor(
+        one_chip, no_compile_cache, monkeypatch):
+    """The engine's 2048-bucket `prefill_fn` at Mistral-7B's attention
+    geometry (32 heads of 128 over 8 KV heads, a 4,096-row scratch
+    cache): attention is the Mosaic flash kernel over the prompt's own
+    2,048 keys, and nothing in the program has the `[32, 2048, 4096]`
+    shape of the scores over the cache's extent. At the benchmark's
+    full configuration (16 layers, MLP 14336) the compiler's
+    temporaries were 1.125 GB with that tensor and are 0.135 GB
+    without (PERF.md, PR 29)."""
+    import fengshen_tpu.ops.pallas as kernels
+    from fengshen_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    from fengshen_tpu.serving import ContinuousBatchingEngine, EngineConfig
+    # the seam asks the backend, which is the CPU here: answer as the
+    # described chip would
+    monkeypatch.setitem(kernels._PROBE_CACHE, ("cpu", None),
+                        kernels.KernelProbe("tpu", True, None,
+                                            "described v5e"))
+    model = LlamaForCausalLM(LlamaConfig(
+        vocab_size=256, hidden_size=4096, intermediate_size=512,
+        num_hidden_layers=2, num_attention_heads=32, num_key_value_heads=8,
+        max_position_embeddings=4096, dtype="bfloat16",
+        param_dtype="bfloat16", scan_layers=True))
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    eng = ContinuousBatchingEngine(
+        model, params, EngineConfig(num_slots=2, buckets=(2048,),
+                                    max_new_tokens=16, kv_layout="paged",
+                                    kv_block_size=128, kv_num_blocks=36))
+    ids = jax.ShapeDtypeStruct((1, 2048), jnp.int32, sharding=one_chip)
+    compiled = eng._prefill_jit.lower(
+        _abstract(params, one_chip), ids, ids,
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    took = [d for d in kernels.traced_dispatch()
+            if d["detail"].startswith("prefill q=(1, 2048, 32, 128)")]
+    assert took and all(d["impl"] == "pallas" for d in took), took
+    scores = [line.strip()[:120] for line in text.splitlines()
+              if re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[32,2048,4096\]",
+                          line)]
+    assert not scores, scores
+    # one f32[32, 2048, 4096] alone is 1,024 MiB
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2 ** 20
