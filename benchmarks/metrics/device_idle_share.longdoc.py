@@ -1,0 +1,5 @@
+"""1 - the union of device operation intervals over the traced window.
+Sixteen of the deployment's thirty-two layers run here, so the host's
+part of a tick, and with it this share, is larger than in the
+deployment."""
+from benchmarks.lib.obsutil import idle_share as read  # noqa: F401

@@ -455,3 +455,106 @@ def xla_mla_decode_attention(q_latent, q_rope, kv, valid, *, scale,
     probs = jax.nn.softmax(scores, axis=-1).astype(rows.dtype)
     return jnp.einsum("bhst,btc->bshc", probs,
                       rows[..., :rank]).astype(q_latent.dtype)
+
+
+SPARSE_TRACE_NAME = "fstpu_sparse_decode_attention"
+
+#: why every sparse read takes the xla lowering today: the kernel above
+#: walks a lane's whole table in order; a chosen-block list is dynamic
+#: per KV head, and this model's 2 KV heads fail `KVH % 8` besides
+_NO_SPARSE_KERNEL = "no Mosaic kernel walks a dynamic block list yet"
+
+
+def sparse_decode_attention(q: jax.Array, pooled: jax.Array, k: jax.Array,
+                            v: jax.Array, block_table: jax.Array,
+                            t: jax.Array, spec, *,
+                            layer: Optional[jax.Array] = None,
+                            dense: bool = False) -> jax.Array:
+    """The seam's sparse entry (`ops/sparse_attention.py` has the
+    mathematics): one query a lane scores the lane's POOLED keys,
+    chooses `spec.topk` blocks a KV head, and attends over the chosen
+    entries of the lane's block table only.
+
+    q: ``[B, 1, H, D]``; k/v: the shared ``[num_blocks, block_size, 1,
+    KVH * D]`` pools (a token's KV heads folded into one row, see
+    ``sparse_attention.gather_blocks``) and pooled: ``[num_blocks,
+    block_size // stride, 1, KVH * D]`` behind ``block_table`` ``[B,
+    max_blocks]`` — one table reads all three, a pool block holding the
+    pooled keys that START in it; with ``layer`` the ``[L, ...]``
+    stacks, read in place as :func:`_layer_of_stack` reads K/V. ``t``: ``[B]`` int32, each
+    query's position (its context is ``t + 1`` tokens). A pool block is
+    a whole number of ``spec.block_size``-token selection blocks.
+    Without ``dense`` every lane is taken to be past ``spec.dense_len``
+    (a shorter one reads its ``topk`` highest blocks, which is
+    everything only while it has no more); with it the read has room
+    for ``dense_len`` tokens' blocks and a lane within ``dense_len``
+    reads all of its own — the caller asks for that, twice the gather,
+    only on a tick that holds such a lane. (The dense seam above is not
+    asked: its GQA repeat of a 25,600-token lane at 16 query heads a KV
+    head is 6.25 GB.) Returns ``[B, 1, H, D]``."""
+    from fengshen_tpu.ops.pallas import resolve_dispatch
+    # recorded, not decided: the xla lowering is the only one there is
+    resolve_dispatch(
+        "sparse_decode_attention",
+        f"q={tuple(q.shape)} kv={tuple(k.shape[-4:])}:{k.dtype.name} "
+        f"topk={spec.topk}x{spec.block_size}", _NO_SPARSE_KERNEL)
+    # the whole read — the pooled keys' gather and the choice (their
+    # own scope inside this one), the chosen slabs, the attention
+    with jax.named_scope(SPARSE_TRACE_NAME):
+        return xla_sparse_decode_attention(q, pooled, k, v, block_table, t,
+                                           spec, layer=layer, dense=dense)
+
+
+def xla_sparse_decode_attention(q, pooled, k, v, block_table, t, spec, *,
+                                layer=None, dense=False):
+    """The stock lowering and the CPU tier-1 truth: the lane's pooled
+    keys gathered a block at a time (a sixteenth of the lane's keys),
+    the choice, then ONE gather of the chosen blocks whole, each head
+    keeping its own half of a row (`sparse_attention.gather_blocks`) —
+    4,096 of up to 25,000 cached tokens — and dense attention over
+    them."""
+    from fengshen_tpu.ops.sparse_attention import (gather_blocks,
+                                                   select_blocks)
+    batch, _, heads, dim = q.shape
+    if layer is not None:
+        num_blocks = k.shape[1]
+        k, v, pooled = (x.reshape((-1,) + x.shape[2:])
+                        for x in (k, v, pooled))
+        block_table = block_table + layer * num_blocks
+    pool_block, groups = k.shape[1], k.shape[-1] // dim
+    sel = spec.block_size
+    if pool_block % sel:
+        raise ValueError(f"a pool block of {pool_block} tokens is not a "
+                         f"whole number of {sel}-token selection blocks")
+    per = pool_block // sel
+    n_sel = block_table.shape[-1] * per
+    lane = jnp.take(pooled, block_table, axis=0, mode="clip")
+    lane = lane.reshape(batch, -1, groups, dim)            # [B, J, G, D]
+    rank = select_blocks(q, lane, t[:, None], spec, n_sel)[:, 0]
+    room = max(spec.topk, -(-spec.dense_len // sel)) if dense \
+        else spec.topk
+    top = min(room, n_sel)
+    value, chosen = jax.lax.top_k(rank, top)               # [B, G, K]
+    taken = value > -jnp.inf
+    if dense:
+        # past dense_len a lane still reads its topk best only
+        taken = taken & ((t + 1 <= spec.dense_len)[:, None, None] |
+                         (jnp.arange(top) < spec.topk))
+    phys = jnp.take_along_axis(
+        block_table[:, None, :], chosen // per, axis=-1) * per + \
+        chosen % per
+    ks = gather_blocks(k.reshape((-1, sel) + k.shape[2:]), phys, dim)
+    vs = gather_blocks(v.reshape((-1, sel) + v.shape[2:]), phys, dim)
+    ks = ks.reshape(batch, groups, top * sel, dim)
+    vs = vs.reshape(batch, groups, top * sel, dim)
+    pos = (chosen[..., None] * sel + jnp.arange(sel)).reshape(
+        batch, groups, top * sel)
+    ok = jnp.repeat(taken, sel, axis=-1) & \
+        (pos <= t[:, None, None])
+    qg = q[:, 0].reshape(batch, groups, heads // groups, dim)
+    scores = jnp.einsum("bgrd,bgkd->bgrk", qg, ks,
+                        preferred_element_type=jnp.float32) * dim ** -0.5
+    scores = jnp.where(ok[:, :, None], scores, _NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1).astype(vs.dtype)
+    out = jnp.einsum("bgrk,bgkd->bgrd", probs, vs)
+    return out.reshape(batch, 1, heads, dim).astype(q.dtype)

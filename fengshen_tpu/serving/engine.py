@@ -10,6 +10,13 @@ onto a fixed pool of `num_slots` KV-cache lanes:
   (`buckets.BucketLadder`), prefilled batch-1 through the model's own
   cache machinery (`utils.generate._prefill_cache` — reused, not
   forked), and scattered into a free lane (`cache.assign_slot`);
+- long prompts: a prompt past the largest bucket is prefilled as
+  WINDOWS of that bucket's width from position 0, the last padded on
+  the right, each one the model's cached call onto the batch-1 cache
+  the windows before it filled (rows so far, state so far); no
+  program's temporaries grow with the prompt. A cache that declares
+  leaves defined on token positions (`paged_cache.positional_leaves`:
+  a recurrent state, pooled keys) takes every prompt that way;
 - decode: every tick runs ONE jitted step over all `num_slots` lanes —
   per-lane `cache_index` vectors (modeling_llama's vector-index path)
   let lanes sit at different write positions, so the step never
@@ -65,6 +72,7 @@ are untouched (the timeline parity test pins both).
 from __future__ import annotations
 
 import dataclasses
+import inspect
 import itertools
 import threading
 import time
@@ -87,12 +95,14 @@ from fengshen_tpu.serving.paged_cache import (BlockAllocator,
                                               assign_paged,
                                               assign_slot_quantized,
                                               blocks_for_tokens,
-                                              init_pool_cache)
+                                              init_pool_cache,
+                                              positional_leaves)
 from fengshen_tpu.serving.metrics import EngineMetrics
 from fengshen_tpu.streaming import StreamBook
 from fengshen_tpu.utils.generate import (_controls_active,
                                          _ngram_propose_lanes,
-                                         _prefill_cache, _select_token,
+                                         _prefill_cache, _rollback_cache,
+                                         _select_token,
                                          _spec_round_tokens,
                                          _spec_round_tokens_lanes,
                                          apply_logits_controls)
@@ -340,7 +350,11 @@ class ContinuousBatchingEngine:
     `model` must use the repo's preallocated flax cache contract
     (`cached_*` row leaves beside a `cache_index`: `cached_key` /
     `cached_value` in the LLaMA family, one `cached_latent` under
-    latent attention; `paged_cache.row_leaves`). `clock`
+    latent attention; `paged_cache.row_leaves`), beside or instead of
+    which it may declare a per-lane `state_*` leaf and row leaves of
+    another rate (`paged_cache.state_leaves`); a model whose
+    `__call__` takes `live` is handed the tick's live mask and keeps a
+    dead lane's state itself. `clock`
     is injectable for deterministic deadline tests.
     """
 
@@ -432,6 +446,19 @@ class ContinuousBatchingEngine:
                 (f" (speculative window needs gamma={self._gamma} "
                  "extra positions)" if self._gamma else ""))
 
+        #: the model's init_cache pass as shapes: the pool is built
+        #: from its "cache", the routing's shape read off its "moe_stats"
+        self._abstract_init = abstract_init(model, S)
+        #: leaves defined on token positions from 0 (a state, pooled
+        #: keys): a cache that declares any is filled by windows only
+        self._positional = positional_leaves(self._abstract_init["cache"])
+        if self._positional and self.spec:
+            raise ValueError(
+                f"spec_mode={config.spec_mode!r} cannot serve a cache "
+                f"that declares {self._positional}: a rejected draft "
+                "cannot be rolled back out of a recurrent state or a "
+                "pooled row; use spec_mode='off'")
+
         if self.self_draft:
             # the self-draft tower (docs/streaming.md "Draft tower"):
             # the target's own first spec_draft_layers decoder layers
@@ -452,9 +479,6 @@ class ContinuousBatchingEngine:
             self._draft_cache = init_slot_cache(self._draft_model, S)
 
         L = self.seq_capacity
-        #: the model's init_cache pass as shapes: the pool is built
-        #: from its "cache", the routing's shape read off its "moe_stats"
-        self._abstract_init = abstract_init(model, S)
         self._cache = self._init_pool()
         #: (expert layers, experts) where the model sows its routing
         #: ("moe_stats"); None for a model without routed experts
@@ -464,6 +488,15 @@ class ContinuousBatchingEngine:
             jax.tree_util.tree_flatten_with_path(self._cache)[0]
             if any(getattr(k, "key", "").startswith("cached_")
                    for k in path))
+        #: bytes of per-lane state (a recurrent layer's) beside the rows
+        self._state_bytes = sum(
+            leaf.nbytes for path, leaf in
+            jax.tree_util.tree_flatten_with_path(self._cache)[0]
+            if any(getattr(k, "key", "").startswith("state_")
+                   for k in path))
+        #: host arithmetic a sparse-attention model offers: tokens a
+        #: query with so many cached tokens reads (None: all of them)
+        self._attended_tokens = getattr(model, "attended_tokens", None)
         self._history = jnp.zeros((S, L), jnp.int32)
         self._mask = jnp.zeros((S, L), jnp.int32)
         # each lane's next input token stays on the device: the decode
@@ -540,6 +573,41 @@ class ContinuousBatchingEngine:
                                 cfg.temperature, cfg.top_k, cfg.top_p)
             return cache, tok.astype(jnp.int32)
 
+        max_len = self.max_len
+
+        def window_fn(params, cache, ids, prompt_row, start, n_valid, rng):
+            """One window of a prompt onto the batch-1 cache the
+            windows before it filled: tokens `start ..` of the prompt,
+            the first `n_valid` of `ids` real, the rest padding on the
+            right. The mask is over cache positions; the cursor ends
+            at the last real token. Returns the cache and the token
+            after the last real one (meant on the last window)."""
+            width = ids.shape[1]
+            end = start + n_valid
+            mask = (jnp.arange(max_len) < end)[None].astype(jnp.int32)
+            logits, mutated = model.apply(
+                {"params": params, "cache": cache}, ids,
+                attention_mask=mask,
+                position_ids=start + jnp.arange(width)[None],
+                init_cache=True, mutable=["cache"])
+            cache = _rollback_cache(mutated["cache"], width - n_valid)
+            step_logits = jax.lax.dynamic_index_in_dim(
+                logits, n_valid - 1, axis=1, keepdims=False)
+            if controls_on:
+                seen = (jnp.arange(prompt_row.shape[1]) < end)[None]
+                step_logits = apply_logits_controls(
+                    step_logits, prompt_row, end,
+                    history_mask=seen.astype(jnp.int32), **control_kw)
+            tok = _select_token(step_logits, rng, cfg.do_sample,
+                                cfg.temperature, cfg.top_k, cfg.top_p)
+            return cache, tok.astype(jnp.int32)
+
+        def fresh_fn():
+            # the batch-1 cache a prompt's first window writes onto
+            return jax.tree_util.tree_map(
+                lambda s: jnp.zeros(s.shape, s.dtype),
+                abstract_init(model, 1)["cache"])
+
         if self.self_draft:
             # the draft tower primes its OWN cache over the same
             # prompt in the same program — its cursor starts congruent
@@ -597,6 +665,10 @@ class ContinuousBatchingEngine:
 
         gamma, ngram = cfg.spec_gamma, cfg.spec_ngram
         moe_shape = self._moe_shape
+        # a model with per-lane state keeps a dead lane's itself: it
+        # has no null block to park the write on
+        takes_live = "live" in inspect.signature(
+            type(model).__call__).parameters
         if self.self_draft:
             draft_model = self._draft_model
 
@@ -785,7 +857,8 @@ class ContinuousBatchingEngine:
                     {"params": params, "cache": cache}, tokens[:, None],
                     attention_mask=mask, position_ids=pos[:, None],
                     init_cache=True, mutable=["cache"] + (
-                        ["moe_stats"] if moe_shape else []))
+                        ["moe_stats"] if moe_shape else []), **(
+                        {"live": active} if takes_live else {}))
                 cache = mutated["cache"] if paged else \
                     reset_free_slots(mutated["cache"], active)
                 step_logits = logits[:, -1]
@@ -835,6 +908,10 @@ class ContinuousBatchingEngine:
             assign_donate = (0, 1, 2)
             decode_donate = (1, 2, 8)
         self._prefill_jit = jax.jit(prefill_fn)
+        # one program a window width; the batch-1 cache is handed from
+        # window to window and updated in place
+        self._window_jit = jax.jit(window_fn, donate_argnums=(1,))
+        self._fresh_jit = jax.jit(fresh_fn)
         self._assign_jit = jax.jit(assign_fn, donate_argnums=assign_donate)
         self._decode_jit = jax.jit(decode_fn, donate_argnums=decode_donate)
 
@@ -897,6 +974,19 @@ class ContinuousBatchingEngine:
         with self._cv:
             self._record_rejection_locked(
                 req, reason, prompt_tokens=int(len(ids)), **attrs)
+
+    def _windows(self, prefill_len: int) -> Optional[list]:
+        """`[(start, width)]`: the windows a prompt of `prefill_len`
+        tokens is prefilled in from position 0, or None where it takes
+        one left-padded bucket. Windows serve a prompt past the largest
+        bucket, and every prompt of a cache defined on positions
+        (`_positional`). A speculative engine has none: its drafter
+        reads a history laid out by buckets."""
+        bucket = self.ladder.bucket_for(prefill_len)
+        if self.spec or (bucket is not None and not self._positional):
+            return None
+        width = bucket if bucket is not None else self.ladder.max_bucket
+        return [(start, width) for start in range(0, prefill_len, width)]
 
     def submit(self, input_ids, max_new_tokens: Optional[int] = None,
                request_id: Optional[str] = None,
@@ -968,7 +1058,16 @@ class ContinuousBatchingEngine:
         # committed token re-enters as the decode seed, exactly where
         # an undisturbed lane would hold it)
         prefill_len = len(ids) + max(len(resume) - 1, 0)
-        bucket = self.ladder.bucket_for(prefill_len)
+        # what the prompt occupies of the lane: its bucket where one
+        # left-padded bucket takes it, the prompt itself where it is
+        # filled from position 0 in windows — whose last must end
+        # inside the batch-1 cache the windows fill
+        windows = self._windows(prefill_len)
+        if windows is None:
+            bucket = self.ladder.bucket_for(prefill_len)
+        else:
+            bucket = prefill_len if sum(windows[-1]) <= self.max_len \
+                else None
         if bucket is None:
             self.metrics.count("rejected_prompt_too_long")
             self._log({"event": "serving_reject", "reason":
@@ -978,7 +1077,10 @@ class ContinuousBatchingEngine:
                                 parent_span_id=parent_span_id)
             raise PromptTooLong(
                 f"prompt of {len(ids)} tokens exceeds the largest "
-                f"bucket {self.ladder.max_bucket}")
+                f"bucket {self.ladder.max_bucket}" if windows is None
+                else f"prompt of {len(ids)} tokens in windows of "
+                f"{windows[-1][1]} overruns the lane's "
+                f"{self.max_len} positions")
         max_new = requested_new
         # the lane must hold bucket + generated tokens + the gamma-wide
         # speculative tail (seq_capacity is max_len for the slot
@@ -1247,6 +1349,12 @@ class ContinuousBatchingEngine:
         # real cached tokens this tick's attention reads: the logical
         # cursor, not `_phys`, which counts the bucket's padding
         kv_tokens = int(self._pos[lanes].sum()) + len(lanes)
+        if self._attended_tokens is not None:
+            # a sparse-attention model: what its queries read of what
+            # is cached, from the cursors alone
+            self.metrics.record_sparse(
+                int(self._attended_tokens(self._pos[lanes] + 1).sum()),
+                kv_tokens)
         t0 = time.perf_counter()
         # dispatch: the host cursors are uploaded and the program
         # enqueued behind whatever the device is still running
@@ -1406,7 +1514,9 @@ class ContinuousBatchingEngine:
             resume = req.resume
             prefill_ids = req.prompt if not resume else np.concatenate(
                 [req.prompt, np.asarray(resume[:-1], np.int32)])
-            bucket = self.ladder.bucket_for(len(prefill_ids))
+            windows = self._windows(len(prefill_ids))
+            bucket = self.ladder.bucket_for(len(prefill_ids)) \
+                if windows is None else len(prefill_ids)
             decode_span = req.max_new_tokens - len(resume) + 1 \
                 if resume else req.max_new_tokens
             blocks = None
@@ -1440,8 +1550,13 @@ class ContinuousBatchingEngine:
                     return prefills
                 self._deferred_req = None
             try:
-                row, mask_row = self.ladder.pad_prompt(
-                    prefill_ids, bucket, self.config.pad_token_id)
+                if windows is None:
+                    row, mask_row = self.ladder.pad_prompt(
+                        prefill_ids, bucket, self.config.pad_token_id)
+                else:
+                    # filled from position 0: the lane holds the prompt
+                    row = np.asarray(prefill_ids, np.int32)
+                    mask_row = np.ones_like(row)
                 if self.config.do_sample:
                     # per-request key derivation (docs/streaming.md
                     # "Seed semantics"): fold the request seed into the
@@ -1461,7 +1576,10 @@ class ContinuousBatchingEngine:
                 with span("serving/prefill", request_id=req.request_id,
                           bucket=int(bucket),
                           prompt_tokens=int(len(prefill_ids))):
-                    if self.self_draft:
+                    if windows is not None:
+                        primed, tok = self._prefill_windows(
+                            req, row, windows, key)
+                    elif self.self_draft:
                         primed, d_primed, tok = self._prefill_jit(
                             self.params, self._draft_params, row[None],
                             mask_row[None], key)
@@ -1470,7 +1588,11 @@ class ContinuousBatchingEngine:
                             self.params, row[None], mask_row[None], key)
                     tok = int(np.asarray(tok)[0])
                 prefills += 1
-                self.metrics.record_prefill(bucket, len(prefill_ids))
+                if windows is None:
+                    self.metrics.record_prefill(bucket, len(prefill_ids))
+                else:
+                    self.metrics.record_prefill_windows(
+                        windows[0][1], len(windows), len(prefill_ids))
                 t_first = self._clock()
                 req.ttft_s = t_first - req.submit_time
                 self.metrics.record_ttft(req.ttft_s)
@@ -1512,6 +1634,25 @@ class ContinuousBatchingEngine:
                              d_primed if self.self_draft else None, tok,
                              lane_key)
         return prefills
+
+    def _prefill_windows(self, req: Request, ids, windows, key):
+        """Prefill `ids` window by window onto one batch-1 cache, each
+        call the same program (one a width), the cache donated from
+        call to call. Returns the primed cache and the first token,
+        still on the device. No decode tick runs between windows."""
+        prompt_row = np.zeros((1, self.seq_capacity), np.int32)
+        prompt_row[0, :len(ids)] = ids
+        primed = self._fresh_jit()
+        for w, (start, width) in enumerate(windows):
+            n_valid = min(width, len(ids) - start)
+            chunk = np.full((1, width), self.config.pad_token_id, np.int32)
+            chunk[0, :n_valid] = ids[start:start + n_valid]
+            with span("window", request_id=req.request_id, window=w,
+                      tokens=int(n_valid)):
+                primed, tok = self._window_jit(
+                    self.params, primed, chunk, prompt_row,
+                    np.int32(start), np.int32(n_valid), key)
+        return primed, tok
 
     def _assign(self, req: Request, slot: int, bucket: int, row,
                 mask_row, primed, d_primed, tok: int, lane_key) -> None:
@@ -1825,6 +1966,17 @@ class ContinuousBatchingEngine:
                     continue
                 ids = np.ones((1, bucket), np.int32)
                 mask = np.ones((1, bucket), np.int32)
+                if self._positional:
+                    # every prompt of this cache goes by windows: warm
+                    # the window program of each width, not the
+                    # left-padded one. (Elsewhere a window program
+                    # compiles at the first prompt past the ladder.)
+                    fresh = self._fresh_jit()  # fslint: disable=blocking-under-lock; warmup must exclude ticks
+                    jax.block_until_ready(self._window_jit(  # fslint: disable=blocking-under-lock; warmup must exclude ticks
+                        self.params, fresh, ids,
+                        np.zeros((1, self.seq_capacity), np.int32),
+                        np.int32(0), np.int32(bucket), self._zero_key))
+                    continue
                 # warmup compiles under _cv on purpose: no request
                 # may tick mid-warmup or it would pay (and double-
                 # compile) the very programs being primed
@@ -1880,6 +2032,7 @@ class ContinuousBatchingEngine:
             "blocks_total": total, "blocks_used": used,
             "blocks_free": total - used, "block_tokens": block_tokens,
             "bytes": self._kv_bytes, "fragmentation": frag,
+            "state_bytes": self._state_bytes,
         }
 
     def stats(self) -> dict:

@@ -49,8 +49,9 @@ import numpy as np
 from fengshen_tpu.disagg import transfer
 from fengshen_tpu.ops.int8_matmul import dequantize_kv, quantize_kv
 from fengshen_tpu.serving.engine import RUNNING, Request
-from fengshen_tpu.serving.paged_cache import (_map_attn_dicts, row_leaves,
-                                              blocks_for_tokens)
+from fengshen_tpu.serving.paged_cache import (_map_attn_dicts,
+                                              blocks_for_tokens, row_leaves,
+                                              state_leaves)
 
 #: wire header constants — adopt declines any mismatch with "version"
 WIRE_KIND = "fstpu-kv-handoff"
@@ -140,10 +141,12 @@ def _undeclared_on_wire(engine) -> Optional[str]:
     """None when the engine's cache declares exactly the K/V pair the
     wire carries; else the one loud sentence both sides refuse with.
     A latent cache (one `cached_latent` row a token) has no wire
-    format yet (ROADMAP D4)."""
+    format yet, nor has a per-lane state (a `state_*` leaf: a snapshot
+    of it is what a wire and a preemption would both need) or a row
+    leaf of another rate (ROADMAP D4, M6)."""
     found: List[list] = []
-    _map_attn_dicts(engine._cache,
-                    lambda d: found.append(row_leaves(d)) or d)
+    _map_attn_dicts(engine._cache, lambda d: found.append(
+        row_leaves(d) + state_leaves(d)) or d)
     odd = [names for names in found if names != WIRE_LEAVES]
     if not odd:
         return None

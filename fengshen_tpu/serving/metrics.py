@@ -85,6 +85,20 @@ class EngineMetrics:
         self._prefill_padded = r.counter(
             "fstpu_serving_prefill_padded_tokens_total",
             "bucket widths prefilled: what the device paid for")
+        self._prefill_windows = r.counter(
+            "fstpu_serving_prefill_windows_total",
+            "windows prefilled onto a cache the windows before them "
+            "filled (a prompt past the largest bucket, or any prompt "
+            "of a cache defined on token positions)")
+        self._sparse_attended = r.counter(
+            "fstpu_sparse_tokens_attended_total",
+            "per-tick sum over live lanes of the tokens a sparse "
+            "layer's query reads (the chosen blocks; everything while "
+            "the context is dense)")
+        self._sparse_cached = r.counter(
+            "fstpu_sparse_tokens_cached_total",
+            "per-tick sum over live lanes of the tokens cached when a "
+            "sparse layer's query chose (cursor + 1)")
         self._kv_attended = r.counter(
             "fstpu_serving_kv_tokens_attended_total",
             "per-tick sum over active lanes of the real cached tokens "
@@ -144,6 +158,10 @@ class EngineMetrics:
             "fstpu_kv_blocks_used", "KV-pool blocks held by live lanes")
         self._g_kv_bytes = r.gauge(
             "fstpu_kv_cache_bytes", "device bytes of the KV pool")
+        self._g_state_bytes = r.gauge(
+            "fstpu_serving_state_bytes",
+            "device bytes of per-lane state (a recurrent layer's) the "
+            "pool holds beside its rows")
         self._g_kv_frag = r.gauge(
             "fstpu_kv_fragmentation",
             "unwritten fraction of allocated KV lane capacity")
@@ -179,6 +197,18 @@ class EngineMetrics:
         self._prefills.labels(bucket).inc()
         self._prefill_tokens.inc(prompt_tokens)
         self._prefill_padded.inc(bucket)
+
+    def record_prefill_windows(self, width: int, windows: int,
+                               prompt_tokens: int) -> None:
+        """A prompt prefilled as `windows` windows of `width`."""
+        self._prefills.labels(width).inc(windows)
+        self._prefill_windows.inc(windows)
+        self._prefill_tokens.inc(prompt_tokens)
+        self._prefill_padded.inc(width * windows)
+
+    def record_sparse(self, attended: int, cached: int) -> None:
+        self._sparse_attended.inc(attended)
+        self._sparse_cached.inc(cached)
 
     def record_submit_lock_wait(self, seconds: float) -> None:
         self._submit_lock_wait.observe(seconds)
@@ -303,6 +333,7 @@ class EngineMetrics:
         self._g_kv_total.set(kv["blocks_total"])
         self._g_kv_used.set(kv["blocks_used"])
         self._g_kv_bytes.set(kv["bytes"])
+        self._g_state_bytes.set(kv.get("state_bytes", 0))
         self._g_kv_frag.set(kv["fragmentation"])
         ttft = self._ttft.window_values()
         decode_tokens = int(self._decode_tokens.value())
@@ -363,6 +394,9 @@ class EngineMetrics:
                 "spec_accepted_total": accepted,
                 "spec_acceptance_rate": rate,
             })
+        if kv.get("state_bytes"):
+            # a pool without per-lane state keeps the payload as it was
+            out["state_bytes"] = int(kv["state_bytes"])
         if streams is not None:
             self._g_streams.set(streams["active"])
             out["streams_active"] = int(streams["active"])

@@ -26,7 +26,17 @@ byte budget into fixed-size blocks instead (the paged-attention idea):
   `tests/analysis_fixtures/paged_cache_clean.py` pins that split);
 - block 0 is the NULL block: never allocated, parked-on by every free
   lane's table row. Stray writes from inactive lanes land there and
-  are never read back unmasked.
+  are never read back unmasked;
+- a cache dict may declare more than rows a token (`row_leaves`): a row
+  leaf of another RATE (one row every `r` tokens, e.g. pooled keys:
+  its length is the longest row leaf's over `r`) pages behind the same
+  table, a block holding `block_size // r` of its rows; and a STATE
+  leaf (`state_leaves`: constant size a lane, e.g. a linear-attention
+  layer's `[heads, dim, dim]`) is not rows at all: the pool holds it
+  `[num_slots, ...]`, an assignment copies the primed one into its
+  lane, and a release zeroes nothing because the next assignment
+  overwrites it. A state has no null block: the decode program keeps a
+  dead lane's state by the tick's live mask (the model's `live`).
 
 The int8 mode stores the pools as int8 with fp32 per-(token, head)
 absmax scales (`cached_key_scale`/`cached_value_scale`, the
@@ -125,6 +135,37 @@ def row_leaves(d: dict) -> list:
                   and not k.endswith("_scale"))
 
 
+def state_leaves(d: dict) -> list:
+    """The per-LANE state an attention-cache dict declares beside (or
+    instead of) its rows: its `state_*` leaves, `[..., batch, ...]` of
+    constant size whatever the tokens cached."""
+    return sorted(k for k in d if k.startswith("state_"))
+
+
+def _row_rate(d: dict, name: str) -> int:
+    """Tokens a row of row leaf `name`: the longest row leaf holds one
+    a token, a leaf `r` times shorter one every `r` tokens."""
+    longest = max(d[n].shape[-3] for n in row_leaves(d))
+    return longest // d[name].shape[-3]
+
+
+def positional_leaves(cache) -> list:
+    """The leaves of a cache tree that are defined on a lane's token
+    POSITIONS counted from 0 — a state, a row leaf of another rate —
+    so that a lane holding them is filled from position 0 and padded on
+    the right (a left-padded lane would shift every pooled window by
+    its pad). Empty for a cache of plain rows."""
+    found: list = []
+
+    def look(d):
+        rows = row_leaves(d)
+        found.extend(state_leaves(d))
+        found.extend(n for n in rows if _row_rate(d, n) != 1)
+        return d
+    _map_attn_dicts(cache, look)
+    return found
+
+
 def _map_attn_dicts(tree, fn):
     """Rebuild a cache pytree, applying `fn` to every attention-cache
     dict (the one holding `cache_index` beside its row leaves). Works
@@ -179,7 +220,13 @@ def init_pool_cache(model, num_slots: int, *, layout: str = "slot",
             lead = leaf.shape[:-4]           # (layers,) under scan
             heads, dim = leaf.shape[-2:]
             if layout == "paged":
-                val_shape = lead + (num_blocks, block_size, heads, dim)
+                rate = _row_rate(d, name)
+                if block_size % rate:
+                    raise ValueError(
+                        f"{name} holds a row every {rate} tokens: "
+                        f"kv_block_size {block_size} must be a multiple")
+                val_shape = lead + (num_blocks, block_size // rate, heads,
+                                    dim)
             else:
                 val_shape = leaf.shape
             out[name] = jnp.zeros(
@@ -187,6 +234,9 @@ def init_pool_cache(model, num_slots: int, *, layout: str = "slot",
             if kv_dtype == "int8":
                 out[name + "_scale"] = jnp.zeros(val_shape[:-1],
                                                  jnp.float32)
+        for name in state_leaves(d):
+            # abstract over `num_slots` lanes: already one a lane
+            out[name] = jnp.zeros(d[name].shape, d[name].dtype)
         out["cache_index"] = jnp.zeros(lead + (num_slots,), jnp.int32)
         if layout == "paged":
             out["block_table"] = jnp.zeros(
@@ -237,7 +287,6 @@ def assign_paged(pool, primed, slot, table_row):
         lead = first.ndim - 4
         num_blocks, block_size = first.shape[-4:-2]
         max_blocks = pool_d["block_table"].shape[-1]
-        virt_len = max_blocks * block_size
 
         # every layer's blocks in ONE scatter of whole blocks into the
         # stack viewed as `layers * num_blocks` blocks (layer l's block
@@ -251,9 +300,11 @@ def assign_paged(pool, primed, slot, table_row):
                   table_row[None, :]).reshape(-1)
 
         def vals(pool_leaf, prim_leaf, pick):
-            rest = pool_leaf.shape[lead + 1:]    # (block_size, heads[, dim])
+            # (rows a block, heads[, dim]): `block_size`, or fewer for
+            # a leaf that holds a row every few tokens
+            rest = pool_leaf.shape[lead + 1:]
             src = prim_leaf.reshape((layers,) + prim_leaf.shape[lead:])[
-                :, 0, :virt_len]                 # [L, V, heads, dim] fp
+                :, 0, :max_blocks * rest[0]]     # [L, V, heads, dim] fp
             val = src.astype(pool_leaf.dtype) if pick is None else \
                 pick(quantize_kv(src))
             flat = pool_leaf.reshape((layers * num_blocks,) + rest)
@@ -271,6 +322,12 @@ def assign_paged(pool, primed, slot, table_row):
                     lambda qs: qs[1])
             else:
                 out[name] = vals(pool_d[name], prim_d[name], None)
+        for name in state_leaves(pool_d):
+            # [..., num_slots, ...] <- the primed [..., 1, ...], whole
+            at = (0,) * lead + (slot,) + (0,) * (
+                pool_d[name].ndim - lead - 1)
+            out[name] = jax.lax.dynamic_update_slice(
+                pool_d[name], prim_d[name].astype(pool_d[name].dtype), at)
         out["cache_index"] = pool_d["cache_index"].at[..., slot].set(
             prim_d["cache_index"].astype(pool_d["cache_index"].dtype))
         out["block_table"] = pool_d["block_table"].at[

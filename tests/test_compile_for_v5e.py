@@ -216,3 +216,85 @@ def test_whole_prompt_prefill_holds_the_flash_call_and_no_scores_tensor(
     assert not scores, scores
     # one f32[32, 2048, 4096] alone is 1,024 MiB
     assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2 ** 20
+
+
+def test_sala_decode_tick_keeps_pool_and_state_in_place(one_chip,
+                                                        no_compile_cache):
+    """The engine's decode program over a pool of rows at two rates and
+    a float32 state (`models/sala`, heads of 128 as published, a short
+    stack): the K/V pool and the state stack are aliased to the
+    returned ones and neither is copied whole; and the dense seam's
+    GQA repeat of a whole lane (6.25 GB at the published sizes, PR 30)
+    is nowhere: lanes within `dense_len` go through the sparse entry."""
+    from fengshen_tpu.models.sala import SalaConfig, SalaForCausalLM
+    from fengshen_tpu.serving.engine import (ContinuousBatchingEngine,
+                                             EngineConfig)
+    cfg = SalaConfig.small_test_config(
+        hidden_size=256, intermediate_size=512, num_attention_heads=32,
+        num_key_value_heads=2, head_dim=128, lightning_nh=4, lightning_nkv=4,
+        lightning_head_dim=128, max_position_embeddings=4096,
+        kernel_size=32, kernel_stride=16, block_size=64, topk=8,
+        window_size=256, dense_len=1024, dtype="bfloat16",
+        param_dtype="bfloat16")
+    model = SalaForCausalLM(cfg)
+    shapes = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    eng = ContinuousBatchingEngine(model, shapes, EngineConfig(
+        num_slots=8, buckets=(256,), max_new_tokens=16, kv_layout="paged",
+        kv_block_size=128, max_queue=8))
+    args = [_abstract(a, one_chip) for a in eng._decode_args(eng._active)]
+    compiled = eng._decode_jit.lower(*args).compile()
+    text = compiled.as_text()
+    pool = eng._cache["model"]["cached_key"]
+    state = eng._cache["model"]["state_lightning"]
+    # nothing as large as the pool or the state is copied, in whatever
+    # shape: a gather that took one KV head of a `[block, KVH, D]` block
+    # had XLA:TPU re-lay the pool out as `[blocks * 2, 64, 2, 128]`,
+    # eight times a tick (PERF.md, PR 30)
+    for dims in re.findall(r"= \w+\[([\d,]+)\][^ ]* copy\(", text):
+        size = 1
+        for d in dims.split(","):
+            size *= int(d)
+        assert size < min(pool.size, state.size), dims
+    stats = compiled.memory_analysis()
+    held = sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(eng._cache))
+    assert stats.alias_size_in_bytes >= held
+    # no [lanes, lane length, KVH, 16, D] repeat of a gathered lane
+    assert f"[8,{cfg.max_position_embeddings},2,16,128]" not in text
+
+
+def test_sala_window_program_compiles_without_cache_sized_scores(
+        one_chip, no_compile_cache):
+    """The window program (`jit_window_fn`) at heads of 128 over a
+    4,096-row cache: it compiles for the chip, its batch-1 cache is
+    donated and aliased, and no `[heads, window, cache]` score array
+    exists (the sparse read walks the cache in tiles)."""
+    from fengshen_tpu.models.sala import SalaConfig, SalaForCausalLM
+    from fengshen_tpu.serving.engine import (ContinuousBatchingEngine,
+                                             EngineConfig)
+    cfg = SalaConfig.small_test_config(
+        hidden_size=256, intermediate_size=512, num_attention_heads=32,
+        num_key_value_heads=2, head_dim=128, lightning_nh=4, lightning_nkv=4,
+        lightning_head_dim=128, max_position_embeddings=4096,
+        kernel_size=32, kernel_stride=16, block_size=64, topk=8,
+        window_size=256, dense_len=1024, lightning_chunk=256,
+        dtype="bfloat16", param_dtype="bfloat16")
+    model = SalaForCausalLM(cfg)
+    shapes = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    eng = ContinuousBatchingEngine(model, shapes, EngineConfig(
+        num_slots=2, buckets=(1024,), max_new_tokens=16, kv_layout="paged",
+        kv_block_size=128, max_queue=8))
+    scratch = jax.eval_shape(eng._fresh_jit)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32,  # noqa: E731
+                                          sharding=one_chip)
+    compiled = eng._window_jit.lower(
+        _abstract(shapes, one_chip), _abstract(scratch, one_chip),
+        i32(1, 1024), i32(1, eng.seq_capacity), i32(), i32(),
+        _abstract(eng._zero_key, one_chip)).compile()
+    stats = compiled.memory_analysis()
+    held = sum(s.size * s.dtype.itemsize
+               for s in jax.tree_util.tree_leaves(scratch))
+    assert stats.alias_size_in_bytes >= held
+    assert not re.search(r"\[(1,)?32,1024,4096\]|\[2,16,1024,4096\]",
+                         compiled.as_text())

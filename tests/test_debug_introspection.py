@@ -218,15 +218,15 @@ def test_debug_requests_ring_and_rejections(tiny):
     assert rej[0]["finish_reason"] == "queue_full"
     d = eng.debug_request("rejected-1")
     assert d["events"][-1]["event"] == "rejected"
-    # 413-class rejections (no bucket fits) join the ring too — a
+    # 413-class rejections (the lane cannot hold it) join the ring too — a
     # burst of 413s must be diagnosable, not invisible
     from fengshen_tpu.serving import PromptTooLong
     with pytest.raises(PromptTooLong):
-        eng.submit(_prompts((20,))[0], request_id="too-long-1")
+        eng.submit(_prompts((70,))[0], request_id="too-long-1")
     d413 = eng.debug_request("too-long-1")
     assert d413["state"] == "rejected"
     assert d413["finish_reason"] == "prompt_too_long"
-    assert d413["events"][-1]["prompt_tokens"] == 20
+    assert d413["events"][-1]["prompt_tokens"] == 70
     eng.run_until_idle()
     dbg = eng.debug_requests()
     assert not dbg["in_flight"]

@@ -197,7 +197,9 @@ def test_prompt_too_long_rejected(tiny):
         model, params, EngineConfig(num_slots=1, buckets=(8, 16),
                                     max_new_tokens=4, max_queue=2))
     with pytest.raises(PromptTooLong):
-        eng.submit(np.arange(1, 20, dtype=np.int32))  # > max bucket
+        # past the largest bucket a prompt goes by windows; what is
+        # refused is a prompt past the LANE (max_position_embeddings 64)
+        eng.submit(np.arange(1, 71, dtype=np.int32))
     assert eng.stats()["rejected_prompt_too_long"] == 1
 
 
@@ -400,8 +402,8 @@ def test_api_stdlib_server_continuous_engine(tiny):
             stats = json_mod.loads(r.read())
         assert stats["completed"] >= 1
         assert stats["num_slots"] == 2
-        # prompt longer than every bucket → 413
-        too_long = " ".join(["3"] * 12)
+        # prompt longer than the lane (64 positions) → 413
+        too_long = " ".join(["3"] * 70)
         bad = urllib.request.Request(
             f"http://127.0.0.1:{port}/api/text_generation",
             data=json_mod.dumps({"input_text": too_long}).encode(),

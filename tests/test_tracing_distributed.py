@@ -682,7 +682,7 @@ def test_engine_tracing_parity_one_compile(tiny):
     # 413-class rejections keep their trace correlation as well
     from fengshen_tpu.serving import PromptTooLong
     with pytest.raises(PromptTooLong):
-        eng.submit(rng.randint(3, 96, 40).astype(np.int32),
+        eng.submit(rng.randint(3, 96, 70).astype(np.int32),
                    request_id="rej-1", trace_id="e" * 32,
                    parent_span_id="f" * 16)
     assert eng.debug_request("rej-1")["trace_id"] == "e" * 32
