@@ -13,9 +13,11 @@ shape routes through (see fengshen_tpu/ops/pallas/__init__.py):
 
 - :func:`pallas_decode_attention` — Mosaic kernel that reads the pool
   **through the block table directly**: the block-table row rides in as
-  a scalar-prefetch operand, so each grid step's BlockSpec index map
-  picks the lane's physical block out of HBM — no gather copy, no
-  virtual-lane materialization, no head-major transpose. A block
+  a scalar-prefetch operand and the kernel fetches the lane's physical
+  blocks out of HBM itself, only those that hold a key (the count comes
+  from ``valid``: :func:`_live_blocks`) — no gather copy, no
+  virtual-lane materialization, no head-major transpose, no step for a
+  table entry past the lane's cursor. A block
   arrives with all its KV heads (the only shape Mosaic can DMA out of
   a ``[.., block_size, KVH, D]`` pool) and the kernel folds tokens and
   heads into one key axis, masking the columns of other heads
@@ -26,8 +28,8 @@ shape routes through (see fengshen_tpu/ops/pallas/__init__.py):
   ``[B, max_len]``) caches reuse the same kernel by reshaping into
   ``max_len // block_size`` blocks per lane with an arange block table.
   Serves both the ``[B, 1]`` decode tick and the ``[B, gamma+1]``
-  speculative verify window (one sequential grid axis over blocks,
-  online softmax across them).
+  speculative verify window (one grid step a lane, online softmax
+  across the lane's live blocks).
 - :func:`xla_decode_attention` — the stock lowering, op-for-op the
   sequence the model ran before this seam existed (take-gather →
   dequantize → GQA repeat → dense attention), so CPU tier-1 pins
@@ -49,6 +51,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -205,9 +208,31 @@ def xla_decode_attention(q, k, v, valid, *, k_scale=None, v_scale=None,
     return dot_product_attention(q, k, v, mask=valid[:, None])
 
 
-def _decode_kernel(tables_ref, *refs, scale, n_blocks, n_query, rep,
+def _live_blocks(valid, block_size: int):
+    """Per lane, how many leading blocks of its table row the kernel
+    has to walk: up to the block of the last valid column, over all
+    query positions (the verify window's last query reaches furthest),
+    and at least one, so a lane with no valid column still reads the
+    block its row starts on (a released lane: the null block). Holes at
+    the FRONT of ``valid`` (a left-padded prompt) are inside the walk;
+    only the tail past every lane's cursor is left out. ``[B]`` int32."""
+    upto = np.arange(valid.shape[-1], dtype=np.int32) // block_size + 1
+    return jnp.max(jnp.where(valid, upto, 1), axis=(1, 2))
+
+
+def _decode_kernel(tables_ref, live_ref, *refs, scale, n_query, rep,
                    quantized, dt):
-    """One (lane, block) grid step over ALL heads at once.
+    """One lane a grid step, over ALL heads at once; inside the step a
+    loop over the lane's LIVE blocks only (``live_ref[lane]`` of them,
+    :func:`_live_blocks`): a table entry past the lane's cursor costs
+    no step, no DMA and no matmul.
+
+    K and V stay in HBM (``pl.ANY``) and a block is fetched through the
+    table into one of two VMEM slots while the block before it is
+    multiplied; the lane's last block prefetches the NEXT lane's first,
+    so the walk never waits at a lane boundary (the grid axis is
+    sequential for that, and ``slot_ref`` carries across it which slot
+    the lane's first block was fetched into).
 
     The pool block arrives whole — ``[block_size, KVH, D]`` is one
     contiguous slab of HBM, and Mosaic cannot DMA a single head out of
@@ -223,63 +248,101 @@ def _decode_kernel(tables_ref, *refs, scale, n_blocks, n_query, rep,
     head-major transpose. The fold is a free reshape only in f32 tiles
     (hence ``KVH % 8 == 0`` in the eligibility rule), so K/V are
     widened in VMEM first. Online-softmax stats live in scratch across
-    the sequential block axis."""
+    the lane's blocks."""
     if quantized:
-        (q_ref, k_ref, v_ref, ks_ref, vs_ref, mask_ref, colhead_ref,
-         o_ref, acc_ref, m_ref, l_ref) = refs
-    else:
-        (q_ref, k_ref, v_ref, mask_ref, colhead_ref, o_ref,
+        (q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, mask_ref, colhead_ref,
+         o_ref, k_buf, v_buf, ks_buf, vs_buf, sems, slot_ref,
          acc_ref, m_ref, l_ref) = refs
-    j = pl.program_id(1)
+    else:
+        (q_ref, k_hbm, v_hbm, mask_ref, colhead_ref, o_ref,
+         k_buf, v_buf, sems, slot_ref, acc_ref, m_ref, l_ref) = refs
+    lane = pl.program_id(0)
+    n_lanes = pl.num_programs(0)
+    n_live = live_ref[lane]
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def copies(lane, j, slot):
+        """The DMAs that bring block ``j`` of ``lane`` into ``slot``."""
+        block = tables_ref[0, lane, j]
+        out = [pltpu.make_async_copy(k_hbm.at[block], k_buf.at[slot],
+                                     sems.at[0, slot]),
+               pltpu.make_async_copy(v_hbm.at[block], v_buf.at[slot],
+                                     sems.at[1, slot])]
+        if quantized:
+            block = tables_ref[1, lane, j]
+            out += [pltpu.make_async_copy(ks_hbm.at[block], ks_buf.at[slot],
+                                          sems.at[2, slot]),
+                    pltpu.make_async_copy(vs_hbm.at[block], vs_buf.at[slot],
+                                          sems.at[3, slot])]
+        return out
 
-    block_size, kv_heads, head_dim = k_ref.shape[1:]
+    @pl.when(lane == 0)
+    def _first_fetch():
+        slot_ref[0] = 0
+        for dma in copies(0, 0, 0):
+            dma.start()
+
+    first_slot = slot_ref[0]
+    slot_ref[0] = (first_slot + n_live) % 2
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    block_size, kv_heads, head_dim = k_buf.shape[1:]
     n_cols = block_size * kv_heads
     n_heads = q_ref.shape[2]
-    k = k_ref[0].astype(jnp.float32).reshape(n_cols, head_dim)
-    v = v_ref[0].astype(jnp.float32).reshape(n_cols, head_dim)
     row_kv = jax.lax.broadcasted_iota(jnp.int32, (n_heads, n_cols), 0)
     if rep > 1:
         row_kv = row_kv // rep
     own_head = colhead_ref[...] == row_kv                # [H, C]
 
-    for s in range(n_query):
-        q = q_ref[0, s].astype(jnp.float32) * scale      # [H, D]
-        scores = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [H, C]
-        if quantized:
-            # per-(token, head) dequant applied to the product: the
-            # pool stays int8 in HBM and in VMEM
-            scores = scores * ks_ref[0]
-        allowed = own_head & (mask_ref[0, s:s + 1, :] > 0)
-        scores = jnp.where(allowed, scores, _NEG_INF)
+    def walk(j, _):
+        slot = (first_slot + j) % 2
+        more = j + 1 < n_live
+        next_lane = jnp.where(more, lane, lane + 1)
 
-        m_prev, l_prev = m_ref[s], l_ref[s]              # [H, 1]
-        m_new = jnp.maximum(m_prev, scores.max(-1, keepdims=True))
-        correction = jnp.exp(m_prev - m_new)
-        probs = jnp.exp(scores - m_new)
-        l_ref[s] = l_prev * correction + probs.sum(-1, keepdims=True)
-        if quantized:
-            probs = probs * vs_ref[0]
-        # round the probabilities through the compute dtype like the
-        # xla lowering does before its PV matmul
-        pv = jax.lax.dot_general(
-            probs.astype(dt).astype(jnp.float32), v,
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [H, D]
-        acc_ref[s] = acc_ref[s] * correction + pv
-        m_ref[s] = m_new
+        @pl.when(next_lane < n_lanes)
+        def _prefetch():
+            for dma in copies(next_lane, jnp.where(more, j + 1, 0),
+                              1 - slot):
+                dma.start()
 
-    @pl.when(j == n_blocks - 1)
-    def _finalize():
-        o_ref[0] = (acc_ref[...] /
-                    jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        for dma in copies(lane, j, slot):
+            dma.wait()
+        k = k_buf[slot].astype(jnp.float32).reshape(n_cols, head_dim)
+        v = v_buf[slot].astype(jnp.float32).reshape(n_cols, head_dim)
+
+        for s in range(n_query):
+            q = q_ref[0, s].astype(jnp.float32) * scale      # [H, D]
+            scores = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)          # [H, C]
+            if quantized:
+                # per-(token, head) dequant applied to the product: the
+                # pool stays int8 in HBM and in VMEM
+                scores = scores * ks_buf[slot]
+            allowed = own_head & (mask_ref[0, s, pl.ds(j, 1), :] > 0)
+            scores = jnp.where(allowed, scores, _NEG_INF)
+
+            m_prev, l_prev = m_ref[s], l_ref[s]              # [H, 1]
+            m_new = jnp.maximum(m_prev, scores.max(-1, keepdims=True))
+            correction = jnp.exp(m_prev - m_new)
+            probs = jnp.exp(scores - m_new)
+            l_ref[s] = l_prev * correction + probs.sum(-1, keepdims=True)
+            if quantized:
+                probs = probs * vs_buf[slot]
+            # round the probabilities through the compute dtype like the
+            # xla lowering does before its PV matmul
+            pv = jax.lax.dot_general(
+                probs.astype(dt).astype(jnp.float32), v,
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)          # [H, D]
+            acc_ref[s] = acc_ref[s] * correction + pv
+            m_ref[s] = m_new
+
+    jax.lax.fori_loop(0, n_live, walk, None)
+    o_ref[0] = (acc_ref[...] /
+                jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def pallas_decode_attention(q, k, v, valid, *, k_scale=None,
@@ -290,7 +353,8 @@ def pallas_decode_attention(q, k, v, valid, *, k_scale=None,
     """Fused paged decode attention. Same contract as
     :func:`decode_attention`; slot caches (``block_table=None``) are
     viewed as ``max_len // block_size`` pool blocks per lane with an
-    arange table, so one kernel serves both layouts."""
+    arange table, so one kernel serves both layouts. How far a lane's
+    row is walked comes from ``valid`` (:func:`_live_blocks`)."""
     batch, s, n_heads, head_dim = q.shape
     kv_heads = k.shape[-2]
     rep = n_heads // kv_heads
@@ -325,44 +389,45 @@ def pallas_decode_attention(q, k, v, valid, *, k_scale=None,
     tables = jnp.stack([block_table, scale_table]).astype(jnp.int32)
     n_cols = block_size * kv_heads
 
-    # key column c of a block is (token c // KVH, kv head c % KVH):
-    # the validity mask and the int8 scales are laid out to match
-    mask = jnp.repeat(valid.astype(jnp.int32), kv_heads, axis=-1)
+    # key column c of a block is (token c // KVH, kv head c % KVH): the
+    # validity mask, a block a row, and the int8 scales are laid out
+    # to match
+    mask = jnp.repeat(valid.astype(jnp.int32), kv_heads, axis=-1).reshape(
+        batch, s, blocks_per_lane, n_cols)
     col_head = jnp.tile(jnp.arange(kv_heads, dtype=jnp.int32),
                         block_size)[None]                # [1, C]
 
-    def kv_map(b, j, tables):
-        # the whole point: the lane's j-th PHYSICAL block comes out of
-        # the pool directly — no gather into a virtual lane
-        return (tables[0, b, j], 0, 0, 0)
-
-    def scale_map(b, j, tables):
-        return (tables[1, b, j], 0, 0)
-
-    kv_spec = pl.BlockSpec((1, block_size, kv_heads, head_dim), kv_map)
+    # the whole point: K and V stay where they are and the kernel
+    # fetches a lane's j-th PHYSICAL block out of the pool itself — no
+    # gather into a virtual lane
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     qo_spec = pl.BlockSpec((1, s, n_heads, head_dim),
-                           lambda b, j, tables: (b, 0, 0, 0))
-    in_specs = [qo_spec, kv_spec, kv_spec]
+                           lambda b, *_: (b, 0, 0, 0))
+    in_specs = [qo_spec, in_hbm, in_hbm]
     operands = [q, k, v]
+    buffers = [pltpu.VMEM((2, block_size, kv_heads, head_dim), k.dtype),
+               pltpu.VMEM((2, block_size, kv_heads, head_dim), v.dtype)]
     if quantized:
-        in_specs += [pl.BlockSpec((1, 1, n_cols), scale_map)] * 2
+        in_specs += [in_hbm] * 2
         operands += [k_scale.reshape(-1, 1, n_cols),
                      v_scale.reshape(-1, 1, n_cols)]
-    in_specs += [pl.BlockSpec((1, s, n_cols),
-                              lambda b, j, tables: (b, 0, j)),
-                 pl.BlockSpec((1, n_cols), lambda b, j, tables: (0, 0))]
+        buffers += [pltpu.VMEM((2, 1, n_cols), jnp.float32)] * 2
+    in_specs += [pl.BlockSpec((1, s, blocks_per_lane, n_cols),
+                              lambda b, *_: (b, 0, 0, 0)),
+                 pl.BlockSpec((1, n_cols), lambda b, *_: (0, 0))]
     operands += [mask, col_head]
 
     kernel = functools.partial(
-        _decode_kernel, scale=1.0 / math.sqrt(head_dim),
-        n_blocks=blocks_per_lane, n_query=s, rep=rep,
-        quantized=quantized, dt=dt)
+        _decode_kernel, scale=1.0 / math.sqrt(head_dim), n_query=s,
+        rep=rep, quantized=quantized, dt=dt)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(batch, blocks_per_lane),
+        num_scalar_prefetch=2,
+        grid=(batch,),
         in_specs=in_specs,
         out_specs=qo_spec,
-        scratch_shapes=[
+        scratch_shapes=buffers + [
+            pltpu.SemaphoreType.DMA((4 if quantized else 2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
             pltpu.VMEM((s, n_heads, head_dim), jnp.float32),
             pltpu.VMEM((s, n_heads, 1), jnp.float32),
             pltpu.VMEM((s, n_heads, 1), jnp.float32),
@@ -373,10 +438,10 @@ def pallas_decode_attention(q, k, v, valid, *, k_scale=None,
             kernel, grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary"),
+                dimension_semantics=("arbitrary",),
                 vmem_limit_bytes=_VMEM_LIMIT_BYTES),
             interpret=interpret, name=TRACE_NAME,
-        )(tables, *operands)
+        )(tables, _live_blocks(valid, block_size), *operands)
 
 
 # -- the latent (MLA) read ----------------------------------------------

@@ -313,6 +313,7 @@ class _Tick:
     lanes: np.ndarray       # lane indices the tick ran
     reqs: list              # the request in each of them at enqueue
     kv_tokens: int          # real cached tokens its attention reads
+    kv_blocks: tuple        # (live, tabled) blocks of its lanes' table rows
     t0: float               # perf_counter at enqueue
     ahead: bool             # enqueued with the previous tick unfetched
     host: tuple = ()        # `out` on the host, once fetched
@@ -1355,6 +1356,17 @@ class ContinuousBatchingEngine:
             self.metrics.record_sparse(
                 int(self._attended_tokens(self._pos[lanes] + 1).sum()),
                 kv_tokens)
+        # of the table rows the tick's attention is handed, the blocks
+        # up to each lane's PHYSICAL cursor (bucket padding in, as the
+        # lane's blocks hold it; a released lane's one null block; a
+        # verify window reaches `spec_gamma` further): what the paged
+        # decode kernel walks, of what is tabled
+        kv_blocks = (0, 0)
+        if self.paged:
+            reach = self._phys + (self.config.spec_gamma if self.spec
+                                  else 0)
+            kv_blocks = (int((reach // self.block_size + 1).sum()),
+                         len(reach) * self.max_blocks_per_slot)
         t0 = time.perf_counter()
         # dispatch: the host cursors are uploaded and the program
         # enqueued behind whatever the device is still running
@@ -1369,7 +1381,7 @@ class ContinuousBatchingEngine:
             self._pos = self._pos + run
             self._phys = self._phys + run
         return _Tick(out, lanes, [self._slot_req[i] for i in lanes],
-                     kv_tokens, t0, ahead)
+                     kv_tokens, kv_blocks, t0, ahead)
 
     def _fetch_locked(self, tick: _Tick) -> None:
         """Block until `tick`'s outputs are on the host (copies — the
@@ -1457,7 +1469,8 @@ class ContinuousBatchingEngine:
         self.metrics.record_tick(len(tick.lanes),
                                  self.config.num_slots, tick.seconds,
                                  tokens=delivered,
-                                 kv_tokens=tick.kv_tokens)
+                                 kv_tokens=tick.kv_tokens,
+                                 kv_blocks=tick.kv_blocks)
         self.metrics.record_spec(
             self.config.spec_gamma * len(tick.lanes),
             accepted_delivered)
@@ -1471,6 +1484,7 @@ class ContinuousBatchingEngine:
         self.metrics.record_tick(len(tick.lanes), S, tick.seconds,
                                  tokens=len(live),
                                  kv_tokens=tick.kv_tokens,
+                                 kv_blocks=tick.kv_blocks,
                                  ahead=tick.ahead)
         t_commit = self._clock()
         for i, req in live:

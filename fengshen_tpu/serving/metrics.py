@@ -103,6 +103,17 @@ class EngineMetrics:
             "fstpu_serving_kv_tokens_attended_total",
             "per-tick sum over active lanes of the real cached tokens "
             "the tick's attention reads (bucket padding out)")
+        self._kv_blocks_live = r.counter(
+            "fstpu_serving_kv_blocks_live_total",
+            "per-tick sum over ALL lanes of the table-row blocks up to "
+            "the lane's physical cursor (bucket padding in; a released "
+            "lane counts its one null block): what the paged decode "
+            "kernel walks")
+        self._kv_blocks_tabled = r.counter(
+            "fstpu_serving_kv_blocks_tabled_total",
+            "per-tick lanes x table width: every block a table row "
+            "names (live over tabled: the share of the table the "
+            "kernel walks); paged layout only")
         # routed-expert models only (engine._commit_plain): what a
         # tick's live lanes asked of each expert layer
         self._moe_assignments = r.counter(
@@ -215,17 +226,22 @@ class EngineMetrics:
 
     def record_tick(self, n_active: int, num_slots: int,
                     seconds: float, tokens: Optional[int] = None,
-                    kv_tokens: int = 0, ahead: bool = False) -> None:
+                    kv_tokens: int = 0, kv_blocks: tuple = (0, 0),
+                    ahead: bool = False) -> None:
         """`tokens` defaults to `n_active` (one committed token per
         active lane — the plain decode tick); the speculative tick
         passes the real committed count, which exceeds the lane count
         whenever draft proposals were accepted. `kv_tokens`: the real
-        cached tokens this tick's attention read, over all lanes.
+        cached tokens this tick's attention read, over all lanes;
+        `kv_blocks`: (live, tabled) blocks of the table rows it was
+        handed.
         `ahead`: the tick was enqueued one ahead (engine `_Tick`)."""
         self._decode_ticks.inc()
         if ahead:
             self._decode_ticks_ahead.inc()
         self._kv_attended.inc(kv_tokens)
+        self._kv_blocks_live.inc(kv_blocks[0])
+        self._kv_blocks_tabled.inc(kv_blocks[1])
         self._decode_tokens.inc(n_active if tokens is None else tokens)
         self._decode_time.inc(seconds)
         self._occupied_ticks.inc(n_active)

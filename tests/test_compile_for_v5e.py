@@ -298,3 +298,39 @@ def test_sala_window_program_compiles_without_cache_sized_scores(
     assert stats.alias_size_in_bytes >= held
     assert not re.search(r"\[(1,)?32,1024,4096\]|\[2,16,1024,4096\]",
                          compiled.as_text())
+
+
+@pytest.mark.parametrize("table_width, window, int8", [
+    (10, 1, False), (17, 1, False), (10, 1, True), (10, 5, False)],
+    ids=["chat", "doc", "chat_int8", "verify_window"])
+def test_paged_decode_kernel_compiles_at_the_mistral_cells_shapes(
+        one_chip, no_compile_cache, table_width, window, int8):
+    """The paged decode kernel for the chip at the benchmark's Mistral
+    cells' geometry: 32 lanes, 32 heads of 128 over 8 KV heads, the
+    16-layer stack of 449 blocks of 128 tokens read in place through
+    `layer`. Interpret mode cannot refuse what Mosaic refuses — the
+    K/V operands left in HBM, the fetches through the table, the loop
+    whose trip count is a lane's live blocks. The program is the one
+    custom call and nothing the size of a layer's pool is copied."""
+    from fengshen_tpu.ops.pallas.decode_attention import (
+        TRACE_NAME, pallas_decode_attention)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    pool = shape((16, 449, 128, 8, 128), jnp.int8 if int8 else jnp.bfloat16)
+    args = [shape((32, window, 32, 128), jnp.bfloat16), pool, pool,
+            shape((32, window, table_width * 128), jnp.bool_),
+            shape((32, table_width), jnp.int32), shape((), jnp.int32)]
+    if int8:
+        args += [shape((16, 449, 128, 8), jnp.float32)] * 2
+
+    def call(q, k, v, valid, table, layer, k_scale=None, v_scale=None):
+        return pallas_decode_attention(
+            q, k, v, valid, block_table=table, layer=layer,
+            k_scale=k_scale, v_scale=v_scale, dequant_dtype=jnp.bfloat16)
+    text = jax.jit(call).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text and TRACE_NAME in text
+    copies = [line.strip()[:120] for line in text.splitlines()
+              if re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[(16,)?449,128,8,128\]"
+                          r"[^=]* (copy|dynamic-slice)\(", line)]
+    assert not copies, copies

@@ -394,13 +394,17 @@ def _start_phase_replica(tiny, phase, max_new, transport=None,
                      max_queue=32, pad_token_id=0))
     engine.warmup()
     if tick_delay_s:
-        real = engine._decode_jit
+        real = engine._tick
 
-        def slow_decode(*a, **kw):
+        def slow_tick(ahead):
+            # BEFORE the tick takes `_cv` (as `test_evac.py`): a sleep
+            # inside the decode call held the lock for the whole delay,
+            # and the handoff's export then waited for it until the
+            # lane had finished locally
             _time.sleep(tick_delay_s)
-            return real(*a, **kw)
+            return real(ahead)
 
-        engine._decode_jit = slow_decode
+        engine._tick = slow_tick
     engine.start()
     coord = DisaggCoordinator(engine, pipe, transport=transport)
     ready = threading.Event()

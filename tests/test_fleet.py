@@ -557,6 +557,13 @@ def test_replica_sigterm_drains_while_inflight_completes(tiny):
     base = f"http://127.0.0.1:{port}"
     prev = install_drain_handler(server, draining, engine=engine,
                                  drain_timeout_s=30.0)
+    # the drain's waiter shuts the server down as soon as it is idle,
+    # and the flush below makes it idle at once: hold it off until the
+    # drain assertions have been made over HTTP (they raced its 50 ms
+    # poll and timed out on a closed server, one run in four here)
+    asserted = threading.Event()
+    in_flight = server.in_flight
+    server.in_flight = lambda: in_flight() if asserted.is_set() else 1
     result = {}
 
     def worker():
@@ -621,6 +628,7 @@ def test_replica_sigterm_drains_while_inflight_completes(tiny):
         assert result["body"]["reason"] == "draining"
         # and the drained server shuts itself down (serve_forever
         # returns in the serving thread) once the engine runs idle
+        asserted.set()
         engine.start()
         thread.join(timeout=30)
         assert not thread.is_alive()

@@ -760,6 +760,53 @@ def test_prefill_and_attended_token_counters_equal_hand_sums(tiny, extra):
         assert eng.stats()["decode_tokens"] == 20
 
 
+@pytest.mark.parametrize("layout", ["paged", "slot", "speculative"])
+def test_live_and_tabled_block_counters_equal_hand_sums(tiny, layout):
+    """What the paged decode kernel walks of what its table rows name,
+    counted per tick from the host's PHYSICAL cursors over ALL lanes:
+    one lane inside its first block, one whose bucket ends ON a block
+    boundary, one that holds no request (its one null block). A verify
+    window reaches `spec_gamma` further; the slot layout has no
+    table."""
+    model, params = tiny
+    extra = {"slot": {}, "paged": {"kv_layout": "paged",
+                                   "kv_block_size": 16},
+             "speculative": {"kv_layout": "paged", "kv_block_size": 16,
+                             "spec_mode": "prompt_lookup",
+                             "spec_gamma": 4}}[layout]
+    eng = ContinuousBatchingEngine(
+        model, params, EngineConfig(num_slots=3, buckets=(8, 16),
+                                    max_new_tokens=6, max_queue=16,
+                                    **extra))
+    reqs = [eng.submit(p) for p in _prompts((5, 11))]
+    eng.run_until_idle()
+    reg = eng.metrics.registry
+    live = reg.get("fstpu_serving_kv_blocks_live_total").value()
+    tabled = reg.get("fstpu_serving_kv_blocks_tabled_total").value()
+    ticks = reg.get("fstpu_serving_decode_ticks_total").value()
+    if layout == "slot":
+        assert (live, tabled) == (0, 0) and ticks == 5
+        return
+    assert tabled == ticks * 3 * eng.max_blocks_per_slot
+    if layout == "paged":
+        # five ticks: the first lane's cursor runs 8..12 (one block of
+        # 16), the second's 16..20 (its bucket filled block 0 to the
+        # end: two), the free lane's stays 0 (the null block)
+        assert ticks == 5 and live == 5 * (1 + 2 + 1) == 20
+        return
+    # each tick's window reaches cursor + 4; the cursors from the
+    # committed tokens of the ticks before
+    want = 0
+    for t in range(int(ticks)):
+        for req, bucket in zip(reqs, (8, 16)):
+            commits = [e["n"] for e in eng.debug_request(
+                req.request_id)["events"] if e["event"] == "commit"]
+            phys = bucket + sum(commits[:t]) if t < len(commits) else 0
+            want += (phys + 4) // 16 + 1
+        want += 1
+    assert live == want
+
+
 def test_resumed_prefill_counts_its_committed_prefix(tiny):
     """A resumed request prefills prompt + resume[:-1] in one bucket:
     the real-token counter counts what was prefilled."""
