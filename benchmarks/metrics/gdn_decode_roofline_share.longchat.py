@@ -1,0 +1,23 @@
+"""The linear layers' decode step's share of its roofline. Bound:
+bytes. The least time a tick is `costs_qwen3next.gdn_decode_bytes` at
+the window's mean live lanes a tick (delta of the occupied slot ticks
+over delta of the ticks): every live lane's delta state and convolution
+state in and out, a linear layer, over the published HBM bytes/s; the
+time taken a tick is the device seconds under the scopes
+`fstpu_gated_delta_decode` and `fstpu_short_conv` inside the decode
+program's runs in the traced window, over those runs."""
+from benchmarks.lib import costs_qwen3next, obsutil, trace_qwen3next
+
+
+def read(obs):
+    ticks = obsutil.counter_delta(obs, "fstpu_serving_decode_ticks_total")
+    lanes = obsutil.counter_delta(
+        obs, "fstpu_serving_occupied_slot_ticks_total")
+    taken = trace_qwen3next.scope_seconds_in(
+        obs, ("fstpu_gated_delta_decode", "fstpu_short_conv"),
+        trace_qwen3next.DECODE)
+    if not ticks or lanes is None or not taken or not taken[0]:
+        return None
+    needed = costs_qwen3next.gdn_decode_bytes(lanes / ticks, obs["config"])
+    return 100.0 * needed / obs["peaks"]["hbm_bytes_per_s"] / \
+        (taken[0] / taken[1])

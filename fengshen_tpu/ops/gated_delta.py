@@ -1,0 +1,212 @@
+"""The gated delta rule (Gated DeltaNet, Yang et al. arXiv:2412.06464)
+and the short causal convolution that feeds it: a `[Dk, Dv]` state a
+head that forgets by a data-dependent gate and, before it writes a
+key's value, subtracts what it already predicts for that key.
+
+Per head, with `g_t <= 0` the token's log-decay and `beta_t` in (0, 1)
+its write strength (`S_0 = 0`):
+
+    S' = exp(g_t) S_{t-1}
+    d_t = beta_t (v_t - k_t S')
+    S_t = S' + k_t^T d_t
+    o_t = q_t S_t
+
+Two forms of that one recurrence, both plain `jax.numpy` (the CPU
+tier-1 truth; each under its own device scope so a trace finds it):
+
+- :func:`gated_delta_decode` — the one-token step over a pool of lanes,
+  gated by the tick's live mask: a state has no null block to park a
+  dead lane's write on, so a lane that is not live keeps its state bit
+  for bit. The two products with the state (`k S'`, `q S_t`) are a
+  multiply and a sum over the key axis in float32: exact, and fused
+  into the passes over the state that the update makes anyway;
+- :func:`gated_delta_prefill` — the same mathematics over chunks of `c`
+  tokens (the WY / UT transform). Inside a chunk with cumulative
+  log-decays `G`: `A = -strict_tril((beta K) K^T * exp(G_i - G_j))`,
+  `T = (I - A)^-1` (a unit lower triangular solve: forward
+  substitution), `W = T (beta K exp(G))`, `U = T (beta V)`; across
+  chunks, in order, `V_new = U - W S`, `O = (Q exp(G)) S + tril(Q K^T
+  exp(G_i - G_j)) V_new`, `S <- exp(G_last) S + (K exp(G_last - G))^T
+  V_new`. Everything that does not read the state is batched over the
+  chunks; the scan carries the `[H, Dk, Dv]` state and four products a
+  chunk. The result does not depend on `c`.
+
+A masked token (padding) has `beta = 0`, `g = 0`, `k = v = 0`: it
+neither writes nor decays, so padding on either side leaves the state
+as if the token were not there.
+
+The state is float32 and every matmul that touches it, or the
+triangular solve, runs at `HIGHEST` (on a TPU a float32 matmul is one
+bf16 pass unless asked; `ops/lightning_attention.py` argues the same):
+a delta rule feeds its own prediction error back into the state, so a
+rounded `k S` is written into every later token. They are ~13 MFLOP a
+chunk a head, a few percent of the layer's projections.
+
+:func:`short_conv_prefill` / :func:`short_conv_decode`: the depthwise
+causal convolution of kernel `K` over the channels of `[q | k | v]`,
+`y_t = sum_j c_j u_{t-K+1+j}` with zeros before the sequence, then
+SiLU. Its state is the last `K - 1` INPUTS `u` of a lane, `[B, K - 1,
+C]` (time before channels: the channels are the lanes of a TPU tile; a
+`[C, K - 1]` state, as the published code keeps it, pads 3 to 128).
+"""
+
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax.scipy.linalg import solve_triangular
+
+PREFILL_SCOPE = "fstpu_gated_delta_prefill"
+DECODE_SCOPE = "fstpu_gated_delta_decode"
+CONV_SCOPE = "fstpu_short_conv"
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+#: tokens a chunk of the prefill form (the result does not depend on it)
+DEFAULT_CHUNK = 64
+
+
+def l2norm(x, eps: float = 1e-6):
+    """`x / sqrt(sum x^2 + eps)` over the last axis, float32."""
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+
+
+def gated_delta_decode(q, k, v, g, beta, state,
+                       live: Optional[jax.Array] = None):
+    """One token a lane. q, k: `[B, H, Dk]`; v: `[B, H, Dv]`; g, beta:
+    `[B, H]` float32; state: `[B, H, Dk, Dv]` float32; `live`: `[B]`
+    bool or None. Returns (`[B, H, Dv]` in v's dtype, the new state);
+    where `live` is False the state is the one passed in, unchanged."""
+    with jax.named_scope(DECODE_SCOPE):
+        qf, kf, vf = (x.astype(jnp.float32) for x in (q, k, v))
+        decayed = jnp.exp(g.astype(jnp.float32))[..., None, None] * state
+        predicted = (kf[..., :, None] * decayed).sum(axis=-2)   # [B,H,Dv]
+        delta = beta.astype(jnp.float32)[..., None] * (vf - predicted)
+        new = decayed + kf[..., :, None] * delta[..., None, :]
+        if live is not None:
+            new = jnp.where(live[:, None, None, None], new, state)
+        out = (qf[..., :, None] * new).sum(axis=-2)
+        return out.astype(v.dtype), new
+
+
+def gated_delta_prefill(q, k, v, g, beta, state,
+                        mask: Optional[jax.Array] = None,
+                        chunk: int = DEFAULT_CHUNK):
+    """A window of tokens onto a state. q, k: `[B, S, H, Dk]`; v: `[B,
+    S, H, Dv]`; g, beta: `[B, S, H]`; state: `[B, H, Dk, Dv]` float32;
+    `mask`: `[B, S]`, 0 on padding (either side), or None. Returns
+    (`[B, S, H, Dv]` in v's dtype, the state after the window's valid
+    tokens). A padded query's output is unspecified."""
+    batch, seq, heads, dk = q.shape
+    dv = v.shape[-1]
+    c = min(chunk, seq)
+    pad = -seq % c
+    if mask is None:
+        mask = jnp.ones((batch, seq), bool)
+    mask = mask.astype(bool)
+    if pad:
+        q, k, v = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                   for x in (q, k, v))
+        g, beta = (jnp.pad(x, ((0, 0), (0, pad), (0, 0))) for x in (g, beta))
+        mask = jnp.pad(mask, ((0, 0), (0, pad)))
+    n = (seq + pad) // c
+    with jax.named_scope(PREFILL_SCOPE):
+        keep = mask[..., None]
+
+        def chunks(x):
+            # [B, S, H, ...] -> [B, n, H, c, ...], float32, padding zeroed
+            x = jnp.where(keep.reshape(keep.shape + (1,) * (x.ndim - 3)),
+                          x.astype(jnp.float32), 0.0)
+            x = x.reshape((batch, n, c) + x.shape[2:])
+            return jnp.moveaxis(x, 2, 3)
+
+        qc, kc, vc = chunks(q), chunks(k), chunks(v)       # [B,n,H,c,D]
+        bc, gc = chunks(beta), chunks(g)                   # [B,n,H,c]
+        G = jnp.cumsum(gc, axis=-1)
+        # exp(G_i - G_j) for i >= j: every exponent <= 0
+        lower = jnp.tril(jnp.ones((c, c), bool))
+        decay = jnp.where(lower, jnp.exp(jnp.where(
+            lower, G[..., :, None] - G[..., None, :], 0.0)), 0.0)
+        k_beta = kc * bc[..., None]
+        eye = jnp.eye(c, dtype=jnp.float32)
+        A = -jnp.einsum("bnhid,bnhjd->bnhij", k_beta, kc,
+                        precision=_HIGHEST) * (decay * (1.0 - eye))
+        # T (I - A) = I, forward substitution on a unit lower triangle
+        rhs = jnp.concatenate(
+            [k_beta * jnp.exp(G)[..., None], vc * bc[..., None]], axis=-1)
+        solved = solve_triangular(eye - A, rhs, lower=True,
+                                  unit_diagonal=True)
+        W, U = solved[..., :dk], solved[..., dk:]          # [B,n,H,c,D]
+        qk = jnp.einsum("bnhid,bnhjd->bnhij", qc, kc,
+                        precision=_HIGHEST) * decay
+        q_dec = qc * jnp.exp(G)[..., None]
+        G_last = G[..., -1:]                               # [B,n,H,1]
+        k_dec = kc * jnp.exp(G_last - G)[..., None]
+        chunk_decay = jnp.exp(G_last)[..., None]           # [B,n,H,1,1]
+
+        def carry(s, xs):
+            w, u, qd, a, kd, dec = xs
+            v_new = u - jnp.einsum("bhck,bhkv->bhcv", w, s,
+                                   precision=_HIGHEST)
+            out = jnp.einsum("bhck,bhkv->bhcv", qd, s, precision=_HIGHEST) \
+                + jnp.einsum("bhij,bhjv->bhiv", a, v_new,
+                             precision=_HIGHEST)
+            s = dec * s + jnp.einsum("bhck,bhcv->bhkv", kd, v_new,
+                                     precision=_HIGHEST)
+            return s, out
+
+        state, out = jax.lax.scan(
+            carry, state.astype(jnp.float32),
+            tuple(jnp.moveaxis(x, 1, 0)
+                  for x in (W, U, q_dec, qk, k_dec, chunk_decay)))
+        # [n, B, H, c, Dv] -> [B, S, H, Dv]
+        out = jnp.moveaxis(out, (0, 3), (1, 2)).reshape(
+            batch, n * c, heads, dv)[:, :seq]
+        return out.astype(v.dtype), state
+
+
+def _conv(window, weight):
+    """`y_t = sum_j c_j u_{t+j}` over a `[B, S + K - 1, C]` window of
+    inputs; weight `[K, C]`. Float32, then SiLU."""
+    taps = weight.shape[0]
+    seq = window.shape[1] - taps + 1
+    w = weight.astype(jnp.float32)
+    y = sum(w[j] * window[:, j:j + seq].astype(jnp.float32)
+            for j in range(taps))
+    return jax.nn.silu(y)
+
+
+def short_conv_prefill(u, weight, state, n_valid=None):
+    """A window of inputs `u` `[B, S, C]` after a lane's last `K - 1`
+    inputs `state` `[B, K - 1, C]` (zeros at the start of a sequence);
+    weight `[K, C]`. `n_valid` (`[B]` int, or None: all of them) counts
+    the real tokens of a window padded on the RIGHT. Returns (`[B, S,
+    C]` in u's dtype, the last `K - 1` real inputs)."""
+    with jax.named_scope(CONV_SCOPE):
+        taps = weight.shape[0]
+        window = jnp.concatenate([state.astype(u.dtype), u], axis=1)
+        y = _conv(window, weight).astype(u.dtype)
+        if n_valid is None:
+            new = window[:, window.shape[1] - (taps - 1):]
+        else:
+            # input t of the window is row t + K - 1: the last K - 1
+            # real ones start at row n_valid, earlier state included
+            new = jax.vmap(lambda w, at: jax.lax.dynamic_slice_in_dim(
+                w, at, taps - 1, axis=0))(window, n_valid)
+        return y, new.astype(state.dtype)
+
+
+def short_conv_decode(u, weight, state, live: Optional[jax.Array] = None):
+    """One input a lane. u: `[B, C]`; state: `[B, K - 1, C]`. Returns
+    (`[B, C]` in u's dtype, the state shifted by the input); where
+    `live` is False the state is the one passed in."""
+    with jax.named_scope(CONV_SCOPE):
+        window = jnp.concatenate([state.astype(u.dtype), u[:, None]], axis=1)
+        y = _conv(window, weight)[:, 0].astype(u.dtype)
+        new = window[:, 1:].astype(state.dtype)
+        if live is not None:
+            new = jnp.where(live[:, None, None], new, state)
+        return y, new

@@ -8,8 +8,9 @@ held here run as one grouped (ragged) matrix product over their stacked
 `[E_held, ...]` tables — every token reaches every expert it picked, no
 capacity, no `[T, E, C]` dispatch tensor. The published DeepSeek-V3
 router (sigmoid scores, a selection-only correction bias, normalised
-and scaled weights, a shared expert) and the Switch router (softmax,
-top-1) are settings of it.
+and scaled weights, a shared expert), the Switch router (softmax,
+top-1) and Qwen's (softmax, top-k renormalised, a sigmoid-gated shared expert:
+`shared_gate`) are settings of it.
 
 `experts_held = (first, count)` is a chip's share of an expert-parallel
 deployment (docs/sharding.md): the router keeps all `num_experts`
@@ -154,6 +155,9 @@ class RoutedExperts(nn.Module):
     experts_held: Optional[Tuple[int, int]] = None
     #: whether this share adds the shared experts (one share does)
     shared_here: bool = True
+    #: the shared experts' output times `sigmoid(x w_sg)`, one learned
+    #: `[hidden, 1]` gate a layer (Qwen's `shared_expert_gate`)
+    shared_gate: bool = False
     aux_loss: bool = False
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
@@ -211,10 +215,16 @@ class RoutedExperts(nn.Module):
                 w_down.astype(self.dtype), first)
         if self.n_shared_experts and self.shared_here:
             with jax.named_scope(SHARED_SCOPE):
-                out = out + SwiGLU(
+                shared = SwiGLU(
                     hidden, F * self.n_shared_experts, self.dtype,
                     self.param_dtype, self.initializer_range,
                     name="shared_experts")(xt).astype(jnp.float32)
+                if self.shared_gate:
+                    shared = shared * jax.nn.sigmoid(nn.Dense(
+                        1, use_bias=False, dtype=self.dtype,
+                        param_dtype=self.param_dtype, kernel_init=init,
+                        name="shared_expert_gate")(xt).astype(jnp.float32))
+                out = out + shared
         if tm is not None:
             out = out * tm[:, None]
         return out.reshape(batch, seq, hidden).astype(x.dtype)

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 from typing import Any, Optional
 
+import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
@@ -37,6 +38,22 @@ class RMSNorm(nn.Module):
                               jnp.float32)
             y = y + bias
         return y.astype(orig_dtype)
+
+
+class ZeroCentredRMSNorm(nn.Module):
+    """`x * rsqrt(mean(x^2) + eps) * (1 + weight)` in float32; `weight`
+    starts at zero (Qwen3-Next, Gemma). No reference equivalent."""
+
+    epsilon: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x):
+        x32 = x.astype(jnp.float32)
+        y = x32 * jax.lax.rsqrt(
+            jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.epsilon)
+        weight = self.param("weight", nn.initializers.zeros,
+                            (x.shape[-1],), jnp.float32)
+        return (y * (1.0 + weight)).astype(x.dtype)
 
 
 class LayerNorm(nn.Module):

@@ -623,3 +623,38 @@ def xla_sparse_decode_attention(q, pooled, k, v, block_table, t, spec, *,
     probs = jax.nn.softmax(scores, axis=-1).astype(vs.dtype)
     out = jnp.einsum("bgrk,bgkd->bgrd", probs, vs)
     return out.reshape(batch, 1, heads, dim).astype(q.dtype)
+
+
+#: why a read of folded rows takes the xla lowering today: the kernel
+#: above folds tokens and heads into one key axis, a free reshape only
+#: at `KVH % 8 == 0`; at 2 KV heads of 256 it would have to take the 8
+#: query heads of a KV head as the matmul's rows instead
+_NO_FOLDED_KERNEL = "no Mosaic kernel reads rows that fold 2 KV heads yet"
+
+
+def folded_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                            block_table: jax.Array, t: jax.Array, *,
+                            scale: float,
+                            layer: Optional[jax.Array] = None) -> jax.Array:
+    """The seam's entry for K/V rows that fold a token's few, wide KV
+    heads into one (`ops/gated_attention.py` has the mathematics): one
+    query a lane over the lane's live blocks, a few blocks a step,
+    grouped — K/V are neither repeated per query head (the dense seam
+    above would: 8 copies of an 18,432-token lane) nor is a head sliced
+    out of a gathered row.
+
+    q: ``[B, 1, H, D]``; k/v: the shared ``[num_blocks, block_size, 1,
+    KVH * D]`` pools behind ``block_table`` ``[B, max_blocks]``; with
+    ``layer`` the ``[L, ...]`` stacks, read in place as
+    :func:`_layer_of_stack` reads them. ``t``: ``[B]`` int32, each
+    query's position. Returns ``[B, 1, H, D]``."""
+    from fengshen_tpu.ops.gated_attention import folded_decode_walk
+    from fengshen_tpu.ops.pallas import resolve_dispatch
+    # recorded, not decided: the xla lowering is the only one there is
+    resolve_dispatch(
+        "folded_decode_attention",
+        f"q={tuple(q.shape)} kv={tuple(k.shape[-4:])}:{k.dtype.name}",
+        _NO_FOLDED_KERNEL)
+    if layer is not None:
+        k, v, block_table = _layer_of_stack(k, v, block_table, layer)
+    return folded_decode_walk(q, k, v, block_table, t, scale=scale)

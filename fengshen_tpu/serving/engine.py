@@ -484,6 +484,10 @@ class ContinuousBatchingEngine:
         #: (expert layers, experts) where the model sows its routing
         #: ("moe_stats"); None for a model without routed experts
         self._moe_shape = _moe_stats_shape(self._abstract_init, S)
+        #: (first, count) of the experts this chip holds where the
+        #: model's config states a share of them (`ops/moe.py`)
+        self._experts_held = getattr(getattr(model, "config", None),
+                                     "experts_held", None)
         self._kv_bytes = sum(
             leaf.nbytes for path, leaf in
             jax.tree_util.tree_flatten_with_path(self._cache)[0]
@@ -1478,7 +1482,8 @@ class ContinuousBatchingEngine:
     def _commit_plain(self, tick: _Tick, live) -> None:
         nxt, S = tick.host[0], self.config.num_slots
         if self._moe_shape:
-            self.metrics.record_moe(nxt[S:].reshape(self._moe_shape))
+            self.metrics.record_moe(nxt[S:].reshape(self._moe_shape),
+                                    self._experts_held)
         # credited at commit, and only what is delivered: the counter
         # keeps matching the clients' count
         self.metrics.record_tick(len(tick.lanes), S, tick.seconds,

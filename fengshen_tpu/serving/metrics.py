@@ -120,16 +120,20 @@ class EngineMetrics:
             "fstpu_moe_assignments_total",
             "token-to-expert assignments of live lanes, over the "
             "expert layers of every plain decode tick")
+        self._moe_held = r.counter(
+            "fstpu_moe_assignments_held_total",
+            "those of the assignments that landed on an expert held "
+            "here (all of them where the chip holds every expert)")
         self._moe_touched = r.counter(
             "fstpu_moe_experts_touched_total",
-            "experts with at least one assignment, summed over "
-            "layer-ticks")
+            "experts held here with at least one assignment, summed "
+            "over layer-ticks")
         self._moe_layer_ticks = r.counter(
             "fstpu_moe_layer_ticks_total",
             "expert layers run by plain decode ticks")
         self._moe_max_tokens = r.counter(
             "fstpu_moe_max_expert_tokens_total",
-            "assignments of the busiest expert, summed over "
+            "assignments of the busiest expert held here, summed over "
             "layer-ticks (the straggler)")
         self._submit_lock_wait = r.histogram(
             "fstpu_serving_submit_lock_wait_seconds",
@@ -250,10 +254,15 @@ class EngineMetrics:
             self._peak_active = n_active
             self._g_peak.set(n_active)
 
-    def record_moe(self, histogram) -> None:
+    def record_moe(self, histogram, held=None) -> None:
         """`histogram`: `[expert layers, experts]` assignments of one
-        tick's live lanes (numpy, already on the host)."""
+        tick's live lanes over ALL the router's experts (numpy, already
+        on the host); `held`: (first, count) of the experts this chip
+        computes, or None for all."""
         self._moe_assignments.inc(int(histogram.sum()))
+        if held is not None:
+            histogram = histogram[:, held[0]:held[0] + held[1]]
+        self._moe_held.inc(int(histogram.sum()))
         self._moe_touched.inc(int((histogram > 0).sum()))
         self._moe_layer_ticks.inc(histogram.shape[0])
         self._moe_max_tokens.inc(int(histogram.max(axis=1).sum()))

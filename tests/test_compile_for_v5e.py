@@ -334,3 +334,124 @@ def test_paged_decode_kernel_compiles_at_the_mistral_cells_shapes(
               if re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[(16,)?449,128,8,128\]"
                           r"[^=]* (copy|dynamic-slice)\(", line)]
     assert not copies, copies
+
+
+@pytest.fixture(scope="module")
+def qwen3next_engine():
+    """The benchmark's Qwen3-Next configuration at its full widths (one
+    period, 256 of 512 experts held, half the vocabulary, 18,432
+    positions) behind the engine, parameters as shapes, 8 lanes of 144
+    blocks instead of 64: the three programs of its serving path."""
+    import json
+    import os
+
+    from benchmarks.lib import manifest
+    from fengshen_tpu.serving import ContinuousBatchingEngine, EngineConfig
+    with open(os.path.join(manifest.ROOT, "benchmarks", "configs",
+                           "qwen3-next-80b-a3b.json")) as f:
+        config = json.load(f)
+    model, cfg = manifest.family(config).build(config)
+    assert (cfg.hidden_size, cfg.head_dim, cfg.num_key_value_heads,
+            cfg.num_experts, cfg.experts_held) == (2048, 256, 2, 512,
+                                                   (0, 256))
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    eng = ContinuousBatchingEngine(model, params, EngineConfig(
+        num_slots=8, buckets=(2048,), max_new_tokens=2048,
+        kv_layout="paged", kv_block_size=128, kv_num_blocks=8 * 144 + 1,
+        kv_max_blocks_per_slot=144))
+    return eng, params
+
+
+def _big_copies(compiled, shapes):
+    """Lines of the compiled program that copy, transpose or slice an
+    array of one of `shapes`."""
+    found = []
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* "
+                     r"(copy|transpose|dynamic-slice)\(", line)
+        if m and tuple(int(d) for d in m.group(1).split(",") if d) \
+                in shapes:
+            found.append(line.strip()[:120])
+    return found
+
+
+def _qwen3next_window_args(eng, params, one_chip):
+    i32 = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=one_chip)
+    fresh = jax.eval_shape(eng._fresh_jit)
+    return (_abstract(params, one_chip), _abstract(fresh, one_chip),
+            i32(1, 2048), i32(1, eng.seq_capacity), i32(), i32(),
+            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip))
+
+
+def test_qwen3next_window_program_walks_the_keys_in_blocks(
+        one_chip, no_compile_cache, qwen3next_engine):
+    """A 2,048-token window onto the carried batch-1 cache of 18,432
+    rows: no `[16, 2048, 18432]` score tensor (2.4 GB in float32), no
+    copy of the cache's rows, the donated cache (rows and both states)
+    aliased to the returned one."""
+    eng, params = qwen3next_engine
+    args = _qwen3next_window_args(eng, params, one_chip)
+    compiled = eng._window_jit.lower(*args).compile()
+    wide = [line.strip()[:120] for line in compiled.as_text().splitlines()
+            if re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[[\d,]*(2048[\d,]*"
+                        r"18432|18432[\d,]*2048)", line)]
+    assert not wide, wide
+    cache = args[1]["model"]
+    rows = cache["cached_key"].shape
+    assert rows == (1, 1, 18432, 1, 512)
+    assert not _big_copies(compiled, {rows, rows[1:], rows[2:]})
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.0e9              # 0.55 GB, PR 32
+    held = sum(leaf.size * leaf.dtype.itemsize
+               for leaf in jax.tree_util.tree_leaves(cache))
+    assert mem.alias_size_in_bytes >= held
+
+
+def test_qwen3next_assign_and_tick_keep_pool_and_both_states_in_place(
+        one_chip, no_compile_cache, qwen3next_engine, monkeypatch):
+    """The assign program (a primed prompt's rows and BOTH states into a
+    lane) and the decode tick over the paged pool: no copy, transpose or
+    slice of a pool- or state-shaped array, the donated leaves aliased
+    to the returned ones, and the tick's temporaries far under what K/V
+    repeated per query head would take (8 lanes x 18,432 tokens x 16
+    heads x 256 is 1.2 GB for K alone; the folded walk gathers 1,024
+    tokens of 512 values a lane a step)."""
+    import fengshen_tpu.ops.pallas as kernels
+    monkeypatch.setitem(kernels._PROBE_CACHE, ("cpu", None),
+                        kernels.KernelProbe("tpu", True, None,
+                                            "described v5e"))
+    eng, params = qwen3next_engine
+    tree = eng._cache["model"]
+    assert tree["cached_key"].shape == (1, 8 * 144 + 1, 128, 1, 512)
+    assert tree["state_delta"].shape == (3, 8, 32, 128, 128)
+    assert tree["state_conv"].shape == (3, 8, 3, 8192)
+    held = {name: tree[name] for name in
+            ("cached_key", "cached_value", "state_delta", "state_conv")}
+    shapes = {leaf.shape for leaf in held.values()}
+    shapes |= {s[1:] for s in shapes}
+    nbytes = sum(leaf.nbytes for leaf in held.values())
+    i32 = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=one_chip)
+    primed, _ = jax.eval_shape(
+        eng._window_jit, *_qwen3next_window_args(eng, params, one_chip))
+    assign = eng._assign_jit.lower(
+        *_abstract((eng._cache, eng._history, eng._mask, eng._last_tok,
+                    primed), one_chip),
+        i32(eng.seq_capacity), i32(eng.seq_capacity),
+        i32(eng.max_blocks_per_slot), i32(), i32()).compile()
+    assert not _big_copies(assign, shapes)
+    assert assign.memory_analysis().alias_size_in_bytes >= nbytes
+    tick = eng._decode_jit.lower(*_abstract(
+        (params, eng._cache, eng._history, eng._mask,
+         jnp.asarray(eng._last_tok), jnp.asarray(eng._pos),
+         jnp.asarray(eng._phys), jnp.asarray(eng._active), eng._keys),
+        one_chip)).compile()
+    assert not _big_copies(tick, shapes)
+    mem = tick.memory_analysis()
+    assert mem.alias_size_in_bytes >= nbytes
+    assert mem.temp_size_in_bytes < 0.3e9
+    took = [d for d in kernels.traced_dispatch()
+            if d["op"] == "folded_decode_attention"]
+    assert took and all(d["impl"] == "xla" for d in took), took
