@@ -7,7 +7,9 @@ process holds the chip for all three phases:
 
 1. kernels — every op in `ops.pallas.dispatch_table()`: the registered
    Mosaic implementation compiled for real (never interpreted) at the
-   shapes the next two phases use, against its registered xla twin;
+   shapes the next two phases use (the folded decode entry, which no
+   LLaMA shape reaches, at Qwen3-Next's), against its registered xla
+   twin;
 2. train — `Trainer(args).fit(CausalLMModule, UniversalDataModule)` as
    every example builds them: a few optimizer steps at seq 2048, mesh
    over all visible devices, one `UniversalCheckpoint` save and restore;
@@ -261,6 +263,41 @@ def _decode_cases(cfg, rows):
                        f"kv={list(shape)}", got, want)
 
 
+def _folded_decode_cases(cfg, rows):
+    """The seam's folded entry at Qwen3-Next's published geometry (16
+    query heads over 2 KV heads of 256 in one 512-value row, blocks of
+    128 tokens), whatever the smoke's own model is: the kernel walks
+    each lane to its own cursor, the xla twin to the longest lane's."""
+    del cfg
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from fengshen_tpu.ops.pallas import get_kernel
+    from fengshen_tpu.ops.pallas.decode_attention import (
+        _folded_ineligible_reason)
+    pallas = get_kernel("folded_decode_attention", "pallas")
+    xla = get_kernel("folded_decode_attention", "xla")
+    lanes, heads, hd, groups, block, per_lane = 8, 16, 256, 2, 128, 24
+    n_blocks = lanes * per_lane + 1
+    rng = np.random.RandomState(SEED + 4)
+    keys = jax.random.split(jax.random.PRNGKey(SEED + 4), 3)
+    q = jax.random.normal(keys[0], (lanes, 1, heads, hd), jnp.bfloat16)
+    shape = (n_blocks, block, 1, groups * hd)
+    k = jax.random.normal(keys[1], shape, jnp.bfloat16)
+    v = jax.random.normal(keys[2], shape, jnp.bfloat16)
+    table = jnp.asarray(1 + rng.permutation(n_blocks - 1).reshape(
+        lanes, per_lane), jnp.int32).at[0].set(0)      # a released lane
+    t = jnp.asarray([0, 127, 128, 1023, 1024, per_lane * block - 1] +
+                    list(rng.randint(200, per_lane * block, lanes - 6)),
+                    jnp.int32)
+    assert _folded_ineligible_reason(q, k) is None
+    got = jax.jit(lambda *a: pallas(*a, scale=hd ** -0.5))(q, k, v, table, t)
+    want = jax.jit(lambda *a: xla(*a, scale=hd ** -0.5))(q, k, v, table, t)
+    _check(rows, "folded_decode_attention",
+           f"paged bf16 kv={list(shape)} t={t.tolist()}", got, want)
+
+
 def _fused_ce_cases(cfg, rows):
     import jax
     import jax.numpy as jnp
@@ -326,6 +363,7 @@ def _block_sparse_cases(cfg, rows):
 KERNEL_CASES = {
     "flash_attention": _flash_cases,
     "decode_attention": _decode_cases,
+    "folded_decode_attention": _folded_decode_cases,
     "fused_ce": _fused_ce_cases,
     "block_sparse_attention": _block_sparse_cases,
 }

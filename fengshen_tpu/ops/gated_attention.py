@@ -11,12 +11,17 @@ own device scope).
 
 - :func:`folded_decode_walk` — one query a lane over the lane's LIVE
   pool blocks through its block-table row, `chunk_blocks` blocks a
-  step. The query is laid into the folded row's width with zeros in the
+  step. The xla lowering of the `decode_attention` seam's folded entry
+  (`ops/pallas/decode_attention.folded_decode_attention`), which takes
+  a Mosaic kernel where the rows' shape allows and this walk elsewhere.
+  The query is laid into the folded row's width with zeros in the
   other heads' places (`q_h . k_row = q_h . k_g` exactly), so the
   gathered blocks are multiplied as they lie: no head is sliced out of
   a gathered row (PERF.md, PR 30: such a slice re-laid the pool, and
-  once came back wrong) and nothing is transposed. The zeros double the
-  matmul work of a read that is bound by its bytes.
+  once came back wrong) and nothing is transposed. What it costs, and
+  the kernel does not pay: every lane walks to the LONGEST lane's
+  cursor, the gather moves every block twice more, the zeros double
+  the matmul work (PERF.md, PR 33).
 - :func:`folded_prefill_walk` — a window of queries at positions `start
   ...` over a lane's rows `0 .. start + S` (the carried cache with the
   window's own keys already written, or the rows just projected),
@@ -36,7 +41,8 @@ PREFILL_SCOPE = "fstpu_gated_attention_prefill"
 
 _NEG_INF = -1e30
 
-#: pool blocks a step of the decode walk gathers (1,024 tokens at 128)
+#: pool blocks a step of the decode walk gathers, and of the folded
+#: kernel fetches (1,024 tokens at 128)
 CHUNK_BLOCKS = 8
 #: keys a step of the prefill walk scores
 KEY_BLOCK = 1024

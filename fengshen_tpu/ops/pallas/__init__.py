@@ -257,6 +257,7 @@ def run_per_shard(kernel: Callable, q, k, v, *per_token):
 # are kept here so the whole table is visible in one place.
 
 from fengshen_tpu.ops.flash_attention import blockwise_attention  # noqa: E402
+from fengshen_tpu.ops.gated_attention import folded_decode_walk  # noqa: E402
 # aliased: binding the bare function name here would shadow the
 # `ops.pallas.block_sparse_attention` SUBMODULE attribute that
 # `import fengshen_tpu.ops.pallas.block_sparse_attention as bsa` resolves
@@ -264,7 +265,7 @@ from fengshen_tpu.ops.pallas.block_sparse_attention import (  # noqa: E402
     block_sparse_attention as _block_sparse_attention)
 from fengshen_tpu.ops.pallas.decode_attention import (  # noqa: E402
     decode_attention, pallas_decode_attention, pallas_decode_eligible,
-    xla_decode_attention)
+    pallas_folded_decode_attention, xla_decode_attention)
 from fengshen_tpu.ops.pallas.flash_attention import (  # noqa: E402
     pallas_flash_attention)
 from fengshen_tpu.ops.pallas.fused_ce import (  # noqa: E402
@@ -277,6 +278,11 @@ register_kernel("block_sparse_attention", "pallas", _block_sparse_attention)
 # layout to a dense mask) lives in ops.attention.dot_product_attention
 register_kernel("decode_attention", "pallas", pallas_decode_attention)
 register_kernel("decode_attention", "xla", xla_decode_attention)
+# the same seam's folded entry (rows that hold a token's few, wide KV
+# heads): its own kernel, its xla lowering the plain walk
+register_kernel("folded_decode_attention", "pallas",
+                pallas_folded_decode_attention)
+register_kernel("folded_decode_attention", "xla", folded_decode_walk)
 register_kernel("fused_ce", "pallas", pallas_fused_ce)
 register_kernel("fused_ce", "xla", xla_fused_ce)
 

@@ -36,11 +36,24 @@ shape routes through (see fengshen_tpu/ops/pallas/__init__.py):
   greedy decode through the dispatcher token-identical to the
   pre-kernel path.
 
+- :func:`folded_decode_attention` — the seam's entry for pools whose
+  rows hold a token's few, wide KV heads side by side (``[block, 1, G *
+  D]``: 2 heads of 256), which the fold above cannot tile. Its Mosaic
+  kernel (:func:`_folded_decode_kernel`) shares the walk — a lane a
+  grid step, the lane's own live blocks fetched through the table
+  behind the multiply — and nothing of the body: it slices each KV head
+  out of the block as it lies, in the pool's dtype. Its xla lowering is
+  ``ops/gated_attention.folded_decode_walk``.
+- :func:`mla_decode_attention`, :func:`sparse_decode_attention` — the
+  latent and the chosen-block entries, xla lowerings only.
+
 Tiling (docs/kernels.md): the pallas path requires
 ``head_dim % 128 == 0``, ``block_size % 128 == 0`` and
 ``KVH % 8 == 0`` (the token×head fold is a free reshape only on f32
-sublane tiles). Other shapes are the xla lowering's; which one a traced
-call site took is recorded through ``ops.pallas.resolve_dispatch``.
+sublane tiles); the folded entry's wants the same of ``head_dim`` and
+``block_size`` and one row a token. Other shapes are the xla
+lowering's; which one a traced call site took is recorded through
+``ops.pallas.resolve_dispatch``.
 """
 
 from __future__ import annotations
@@ -56,6 +69,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from fengshen_tpu.ops.attention import dot_product_attention
+from fengshen_tpu.ops.gated_attention import (CHUNK_BLOCKS, DECODE_SCOPE,
+                                              folded_decode_walk)
 from fengshen_tpu.ops.int8_matmul import dequantize_kv
 
 _NEG_INF = -1e30
@@ -625,36 +640,225 @@ def xla_sparse_decode_attention(q, pooled, k, v, block_table, t, spec, *,
     return out.reshape(batch, 1, heads, dim).astype(q.dtype)
 
 
-#: why a read of folded rows takes the xla lowering today: the kernel
-#: above folds tokens and heads into one key axis, a free reshape only
-#: at `KVH % 8 == 0`; at 2 KV heads of 256 it would have to take the 8
-#: query heads of a KV head as the matmul's rows instead
-_NO_FOLDED_KERNEL = "no Mosaic kernel reads rows that fold 2 KV heads yet"
+#: what of `_VMEM_LIMIT_BYTES` the two slots of a step's K and V blocks
+#: may take in the folded kernel (4 MiB at 8 blocks of 128 x 512 bf16)
+_FOLDED_BUFFER_BYTES = 16 * 1024 * 1024
 
 
 def folded_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                             block_table: jax.Array, t: jax.Array, *,
                             scale: float,
-                            layer: Optional[jax.Array] = None) -> jax.Array:
+                            layer: Optional[jax.Array] = None,
+                            impl: Optional[str] = None,
+                            interpret: bool = False) -> jax.Array:
     """The seam's entry for K/V rows that fold a token's few, wide KV
     heads into one (`ops/gated_attention.py` has the mathematics): one
-    query a lane over the lane's live blocks, a few blocks a step,
-    grouped — K/V are neither repeated per query head (the dense seam
-    above would: 8 copies of an 18,432-token lane) nor is a head sliced
-    out of a gathered row.
+    query a lane over the lane's live blocks, grouped — K/V are neither
+    repeated per query head (the dense seam above would: 8 copies of an
+    18,432-token lane) nor is a head sliced out of a gathered row.
 
     q: ``[B, 1, H, D]``; k/v: the shared ``[num_blocks, block_size, 1,
     KVH * D]`` pools behind ``block_table`` ``[B, max_blocks]``; with
     ``layer`` the ``[L, ...]`` stacks, read in place as
     :func:`_layer_of_stack` reads them. ``t``: ``[B]`` int32, each
-    query's position. Returns ``[B, 1, H, D]``."""
-    from fengshen_tpu.ops.gated_attention import folded_decode_walk
-    from fengshen_tpu.ops.pallas import resolve_dispatch
-    # recorded, not decided: the xla lowering is the only one there is
-    resolve_dispatch(
-        "folded_decode_attention",
-        f"q={tuple(q.shape)} kv={tuple(k.shape[-4:])}:{k.dtype.name}",
-        _NO_FOLDED_KERNEL)
+    query's position. Returns ``[B, 1, H, D]``. The rows' shape picks
+    the path (:func:`_folded_ineligible_reason`): the Mosaic kernel
+    :func:`pallas_folded_decode_attention` where it tiles, else
+    ``gated_attention.folded_decode_walk``, the xla lowering and the
+    CPU tier-1 truth. ``impl`` forces either, as in
+    :func:`decode_attention`."""
+    if impl is None:
+        from fengshen_tpu.ops.pallas import resolve_dispatch
+        impl = resolve_dispatch(
+            "folded_decode_attention",
+            f"q={tuple(q.shape)} kv={tuple(k.shape[-4:])}:{k.dtype.name}",
+            _folded_ineligible_reason(q, k))
     if layer is not None:
         k, v, block_table = _layer_of_stack(k, v, block_table, layer)
+    if impl == "pallas":
+        return pallas_folded_decode_attention(
+            q, k, v, block_table, t, scale=scale, interpret=interpret)
     return folded_decode_walk(q, k, v, block_table, t, scale=scale)
+
+
+def _folded_ineligible_reason(q, k) -> Optional[str]:
+    """Why rows of this shape cannot take the folded kernel, or None
+    when they can."""
+    _, s, n_heads, head_dim = q.shape
+    block_size, one, width = k.shape[-3:]
+    if s != 1:
+        return f"query window {s} != 1"
+    if one != 1 or width % head_dim != 0:
+        return f"rows {tuple(k.shape[-2:])} do not fold whole heads of " \
+               f"{head_dim} into one"
+    if n_heads % (width // head_dim) != 0:
+        return f"{width // head_dim} kv heads do not divide {n_heads}"
+    if head_dim % 128 != 0:
+        return f"head_dim {head_dim} % 128 != 0"
+    if block_size % 128 != 0:
+        return f"block_size {block_size} % 128 != 0"
+    if 4 * CHUNK_BLOCKS * block_size * width * k.dtype.itemsize > \
+            _FOLDED_BUFFER_BYTES:
+        return f"{CHUNK_BLOCKS} blocks of {block_size} tokens x {width} " \
+               f"{k.dtype.name} a step outgrow VMEM"
+    return None
+
+
+def _folded_decode_kernel(table_ref, t_ref, q_ref, k_hbm, v_hbm, o_ref,
+                          k_buf, v_buf, sems, slot_ref, acc_ref, m_ref,
+                          l_ref, *, scale, groups, block_size):
+    """One lane a grid step; inside it a loop over the lane's LIVE
+    blocks only (``t // block_size + 1`` of them), ``per`` blocks a
+    step, fetched through the table into one of two VMEM slots while
+    the step before is multiplied; the lane's last step prefetches the
+    NEXT lane's first (``slot_ref`` carries across the sequential grid
+    axis which slot that went into) — the walk of
+    :func:`_decode_kernel`, over another kind of row. A step's tail
+    past the lane's last live block is not fetched: a lane's walk ends
+    at ITS cursor, and a released lane (cursor 0, its row on the null
+    block) costs one block.
+
+    A block is ``[block_size, G * D]``: a token's ``G`` KV heads side
+    by side. Each KV head is a static slice of the buffer at a multiple
+    of 128 lanes, multiplied as it lies, in the pool's dtype with
+    float32 accumulation and the probabilities rounded to V's dtype
+    (the precision of ``folded_decode_walk``'s two einsums): the ``H //
+    G`` query heads of KV head ``g`` (rows ``g * H // G ...`` of the
+    ``[H, D]`` query) against columns ``[g * D, (g + 1) * D)``. No
+    zeros in the query, no mask of other heads' columns, no float32
+    copy of the block. The one mask is ``position <= t``, from an
+    iota. Online-softmax statistics and the ``[H, D]`` accumulator stay
+    in VMEM scratch across the lane's steps."""
+    lane = pl.program_id(0)
+    n_lanes = pl.num_programs(0)
+    span = k_buf.shape[1]
+    per = span // block_size
+    t = t_ref[lane]
+    n_steps = t // span + 1
+
+    def fetch(lane, j, slot, wait=False):
+        """Start (or wait for) the DMAs that bring step ``j`` of
+        ``lane`` into ``slot``: those of its ``per`` blocks that hold a
+        key."""
+        n_live = t_ref[lane] // block_size + 1
+        for i in range(per):
+            @pl.when(j * per + i < n_live)
+            def _one():
+                block = table_ref[lane, j * per + i]
+                at = pl.ds(i * block_size, block_size)
+                for x, (hbm, buf) in enumerate(((k_hbm, k_buf),
+                                                (v_hbm, v_buf))):
+                    dma = pltpu.make_async_copy(
+                        hbm.at[block], buf.at[slot, at], sems.at[x, slot, i])
+                    if wait:
+                        dma.wait()
+                    else:
+                        dma.start()
+
+    @pl.when(lane == 0)
+    def _first_fetch():
+        slot_ref[0] = 0
+        # a step's unfetched tail is weighed by exact zeros: whatever
+        # the buffer holds there must not be NaN
+        v_buf[...] = jnp.zeros_like(v_buf)
+        fetch(0, 0, 0)
+
+    first_slot = slot_ref[0]
+    slot_ref[0] = (first_slot + n_steps) % 2
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    n_heads, head_dim = q_ref.shape[2:]
+    rep = n_heads // groups
+    q = (q_ref[0, 0] * scale).astype(q_ref.dtype)            # [H, D]
+    column = jax.lax.broadcasted_iota(jnp.int32, (rep, span), 1)
+
+    def walk(j, _):
+        slot = (first_slot + j) % 2
+        more = j + 1 < n_steps
+        next_lane = jnp.where(more, lane, lane + 1)
+
+        @pl.when(next_lane < n_lanes)
+        def _prefetch():
+            fetch(next_lane, jnp.where(more, j + 1, 0), 1 - slot)
+
+        fetch(lane, j, slot, wait=True)
+        seen = column + j * span <= t
+        for g in range(groups):
+            rows = pl.ds(g * rep, rep)
+            cols = pl.ds(g * head_dim, head_dim)
+            scores = jax.lax.dot_general(
+                q[g * rep:(g + 1) * rep], k_buf[slot, :, cols],
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)      # [rep, span]
+            scores = jnp.where(seen, scores, _NEG_INF)
+            m_prev = m_ref[rows]
+            m_new = jnp.maximum(m_prev, scores.max(-1, keepdims=True))
+            correction = jnp.exp(m_prev - m_new)
+            probs = jnp.exp(scores - m_new)
+            l_ref[rows] = l_ref[rows] * correction + \
+                probs.sum(-1, keepdims=True)
+            pv = jax.lax.dot_general(
+                probs.astype(v_buf.dtype), v_buf[slot, :, cols],
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)      # [rep, D]
+            acc_ref[rows] = acc_ref[rows] * correction + pv
+            m_ref[rows] = m_new
+
+    jax.lax.fori_loop(0, n_steps, walk, None)
+    o_ref[0, 0] = (acc_ref[...] /
+                   jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+
+
+def pallas_folded_decode_attention(q, k, v, block_table, t, *, scale,
+                                   blocks_per_step: Optional[int] = None,
+                                   interpret: bool = False):
+    """The folded read as a Mosaic kernel. q ``[B, 1, H, D]``; k/v flat
+    pools ``[num_blocks, block_size, 1, G * D]`` (a stack already
+    flattened by :func:`_layer_of_stack`), left in HBM; ``block_table``
+    ``[B, max_blocks]``; ``t`` ``[B]``, clamped to the table row's
+    reach. A step takes ``blocks_per_step`` blocks, by default the
+    walk's ``gated_attention.CHUNK_BLOCKS`` (1,024 keys at 128: the
+    same partition of the online softmax, and on a v5e the step at
+    which the fetches hide everything else; PERF.md, PR 33). Named and
+    scoped ``gated_attention.DECODE_SCOPE``, so the trace finds the
+    read by that text whichever path ran."""
+    batch, _, n_heads, head_dim = q.shape
+    block_size, _, width = k.shape[-3:]
+    max_blocks = block_table.shape[-1]
+    per = min(blocks_per_step or CHUNK_BLOCKS, max_blocks)
+    t = jnp.clip(t.astype(jnp.int32), 0, max_blocks * block_size - 1)
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    qo_spec = pl.BlockSpec((1, 1, n_heads, head_dim),
+                           lambda b, *_: (b, 0, 0, 0))
+    kernel = functools.partial(_folded_decode_kernel, scale=scale,
+                               groups=width // head_dim,
+                               block_size=block_size)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(batch,),
+        in_specs=[qo_spec, in_hbm, in_hbm],
+        out_specs=qo_spec,
+        scratch_shapes=[
+            pltpu.VMEM((2, per * block_size, width), k.dtype),
+            pltpu.VMEM((2, per * block_size, width), v.dtype),
+            pltpu.SemaphoreType.DMA((2, 2, per)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((n_heads, head_dim), jnp.float32),
+            pltpu.VMEM((n_heads, 1), jnp.float32),
+            pltpu.VMEM((n_heads, 1), jnp.float32),
+        ],
+    )
+    with jax.named_scope(DECODE_SCOPE):
+        # a row's unit axis goes: a free reshape, the blocks stay put
+        return pl.pallas_call(
+            kernel, grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+            interpret=interpret, name=DECODE_SCOPE,
+        )(block_table.astype(jnp.int32), t, q,
+          k.reshape(-1, block_size, width), v.reshape(-1, block_size, width))

@@ -336,6 +336,42 @@ def test_paged_decode_kernel_compiles_at_the_mistral_cells_shapes(
     assert not copies, copies
 
 
+@pytest.mark.parametrize("stacked", [True, False], ids=["stack", "pool"])
+def test_folded_decode_kernel_compiles_at_the_qwen3next_cells_shapes(
+        one_chip, no_compile_cache, stacked):
+    """The folded kernel for the chip at `qwen3next_longchat_saturated`'s
+    geometry: 64 lanes, 16 heads of 256 over 2 KV heads in one
+    512-value row, 9,217 blocks of 128 tokens behind a 144-wide table,
+    read in place through `layer` (and as a lone pool). Interpret mode
+    cannot refuse what Mosaic refuses: pools left in HBM, fetches
+    through the table, a trip count from a lane's cursor, half-row
+    slices of the block. The program is the one custom call, found in
+    the trace by the scope's name; nothing pool-sized is copied."""
+    from fengshen_tpu.ops.gated_attention import DECODE_SCOPE
+    from fengshen_tpu.ops.pallas.decode_attention import (
+        folded_decode_attention)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    rows = (9217, 128, 1, 512)
+    pool = shape(((1,) if stacked else ()) + rows, jnp.bfloat16)
+
+    def call(q, k, v, table, t, layer):
+        return folded_decode_attention(
+            q, k, v, table, t, scale=256 ** -0.5,
+            layer=layer if stacked else None, impl="pallas")
+    text = jax.jit(call).lower(
+        shape((64, 1, 16, 256), jnp.bfloat16), pool, pool,
+        shape((64, 144), jnp.int32), shape((64,), jnp.int32),
+        shape((), jnp.int32)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1 and DECODE_SCOPE in text
+    copies = [line.strip()[:120] for line in text.splitlines()
+              if re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[(1,)?9217,128,"
+                          r"(1,)?512\][^=]* (copy|dynamic-slice|transpose)\(",
+                          line)]
+    assert not copies, copies
+
+
 @pytest.fixture(scope="module")
 def qwen3next_engine():
     """The benchmark's Qwen3-Next configuration at its full widths (one
@@ -416,8 +452,10 @@ def test_qwen3next_assign_and_tick_keep_pool_and_both_states_in_place(
     slice of a pool- or state-shaped array, the donated leaves aliased
     to the returned ones, and the tick's temporaries far under what K/V
     repeated per query head would take (8 lanes x 18,432 tokens x 16
-    heads x 256 is 1.2 GB for K alone; the folded walk gathers 1,024
-    tokens of 512 values a lane a step)."""
+    heads x 256 is 1.2 GB for K alone). The full layer's read is the
+    folded kernel, chosen from the rows' shape: no gather of the live
+    blocks (`[8 lanes, 1024 tokens, 512]` a step of the xla walk) is
+    left in the tick."""
     import fengshen_tpu.ops.pallas as kernels
     monkeypatch.setitem(kernels._PROBE_CACHE, ("cpu", None),
                         kernels.KernelProbe("tpu", True, None,
@@ -454,4 +492,9 @@ def test_qwen3next_assign_and_tick_keep_pool_and_both_states_in_place(
     assert mem.temp_size_in_bytes < 0.3e9
     took = [d for d in kernels.traced_dispatch()
             if d["op"] == "folded_decode_attention"]
-    assert took and all(d["impl"] == "xla" for d in took), took
+    assert took and all(d["impl"] == "pallas" for d in took), took
+    text = tick.as_text()
+    kernel = [line for line in text.splitlines()
+              if re.match(r"\s*%?fstpu_gated_attention_decode[\w.]* = ", line)]
+    assert len(kernel) == 1 and "tpu_custom_call" in kernel[0], kernel
+    assert not re.search(r"\[8,1024,512\]|\[64,128,512\]", text)
