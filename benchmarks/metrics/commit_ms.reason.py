@@ -1,0 +1,4 @@
+"""As `commit_ms.chat`, in the JoyAI cell (64 lanes, 96 callers, unrolled layers)."""
+from benchmarks.lib import manifest
+
+read = manifest.reader("commit_ms.chat")

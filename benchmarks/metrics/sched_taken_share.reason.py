@@ -1,0 +1,4 @@
+"""As `sched_taken_share.chat`, in the JoyAI cell (64 lanes, 96 callers, unrolled layers)."""
+from benchmarks.lib import manifest
+
+read = manifest.reader("sched_taken_share.chat")

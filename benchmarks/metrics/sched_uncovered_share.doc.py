@@ -1,0 +1,4 @@
+"""As `sched_uncovered_share.chat`, in the document cell (32 lanes, 64 callers, scanned layers)."""
+from benchmarks.lib import manifest
+
+read = manifest.reader("sched_uncovered_share.chat")

@@ -33,7 +33,8 @@ from fengshen_tpu.observability.tracectx import (SpanLedger,
                                                  TraceContext, TraceIds,
                                                  assemble_trace,
                                                  parse_traceparent)
-from fengshen_tpu.observability.tracing import (current_span_stack, span)
+from fengshen_tpu.observability.tracing import (current_span_stack, span,
+                                                thread_times)
 
 __all__ = [
     "BUILD_INFO_METRIC", "CONTENT_TYPE_LATEST", "Counter",
@@ -45,5 +46,5 @@ __all__ = [
     "get_registry", "parse_traceparent",
     "peak_flops_per_chip", "percentile", "record_build_info",
     "record_warmup_seconds", "render_prometheus", "span",
-    "start_metrics_server",
+    "start_metrics_server", "thread_times",
 ]

@@ -257,6 +257,9 @@ class MetricsRegistry:
     def __init__(self):
         self._lock = threading.Lock()
         self._metrics: Dict[str, Metric] = {}
+        #: `tracing.span`'s resolved children by span label, so that a
+        #: span's exit looks nothing up by name (tracing._children)
+        self.span_children: Dict[str, tuple] = {}
 
     def _get_or_create(self, cls, name: str, help: str, **kw) -> Metric:
         candidate = cls(name, help, **kw)

@@ -85,7 +85,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from fengshen_tpu.observability import (RequestTimeline,
-                                        record_warmup_seconds, span)
+                                        record_warmup_seconds, span,
+                                        thread_times)
 from fengshen_tpu.ops.pallas import log_dispatch
 from fengshen_tpu.serving.buckets import DEFAULT_BUCKETS, BucketLadder
 from fengshen_tpu.serving.cache import (abstract_init, assign_slot,
@@ -527,6 +528,14 @@ class ContinuousBatchingEngine:
         #: the one decode tick enqueued and not yet fetched, or None
         self._inflight: Optional[_Tick] = None
         self._fetched_at = 0.0      # perf_counter of the last fetch
+        #: the scheduler thread's account since the serve loop's last
+        #: flush to the `fstpu_serving_scheduler_*` counters: off-CPU
+        #: seconds inside the spans that wait by design, and the wall
+        #: seconds of `serving/lock_wait` among them
+        self._waited = 0.0
+        self._lock_waited = 0.0
+        #: the serve thread's open `serving/tail` span, or None
+        self._tail: Optional[span] = None
 
         self._queue: deque[Request] = deque()
         # commit journal: request_id -> the live Request object, a
@@ -1260,8 +1269,9 @@ class ContinuousBatchingEngine:
     def _tick(self, ahead: bool) -> int:
         # the wait for the lock has a span of its own, so a trace tells
         # a scheduler starved by submitters from one at work
-        with span("serving/lock_wait"):
+        with span("serving/lock_wait") as s:
             self._cv.acquire()
+        self._declared_wait(s, lock=True)
         try:
             # the tick IS the critical section: the scheduler owns all
             # device state under _cv by design; admission threads wait
@@ -1280,23 +1290,28 @@ class ContinuousBatchingEngine:
         ahead = ahead and not self.spec
         if not ahead:
             self._drain_locked()
-        now = self._clock()
-        # a queued request whose deadline already passed will never be
-        # worth prefilling — drop it while it waits, not just at pop
-        expired = [r for r in self._queue
-                   if r.deadline is not None and now > r.deadline]
-        for req in expired:
-            self._queue.remove(req)
-            self._finish(req, EXPIRED, "deadline")
-        # a lane released here may have a token in flight: its record
-        # no longer names the lane's request, so the commit drops it
-        for i, req in enumerate(self._slot_req):
-            if req is None:
-                continue
-            if req._cancel:
-                self._release(i, CANCELLED, "cancelled")
-            elif req.deadline is not None and now > req.deadline:
-                self._release(i, EXPIRED, "deadline")
+        with span("serving/reclaim"):
+            now = self._clock()
+            # a queued request whose deadline already passed will never
+            # be worth prefilling — drop it while it waits, not just at
+            # pop
+            expired = [r for r in self._queue
+                       if r.deadline is not None and now > r.deadline]
+            for req in expired:
+                self._queue.remove(req)
+                self._finish(req, EXPIRED, "deadline")
+            # a lane released here may have a token in flight: its
+            # record no longer names the lane's request, so the commit
+            # drops it
+            for i, req in enumerate(self._slot_req):
+                if req is None:
+                    continue
+                if req._cancel:
+                    self._release(i, CANCELLED, "cancelled")
+                elif req.deadline is not None and now > req.deadline:
+                    self._release(i, EXPIRED, "deadline")
+        # no span of its own: it would become the parent of
+        # `serving/prefill` and `serving/assign` and rename them
         admitted = self._admit()
         prev = self._inflight
         run = self._active & (self._ticks_left > 0)
@@ -1316,7 +1331,39 @@ class ContinuousBatchingEngine:
                 self._fetch_locked(prev)
         if prev is not None:
             self._commit_locked(prev)
+            self._open_tail()
+            if self._inflight is not None and not self._active.any():
+                # every lane of the tick in flight was just released:
+                # nobody waits for its tokens
+                self._inflight = None
         return int(self._active.sum())
+
+    def _declared_wait(self, s, lock: bool = False) -> None:
+        """On the serve thread, credit a closed span that waits by
+        design (the lock, the device, the idle condition): its off-CPU
+        seconds are declared wait, flushed to the scheduler's counters
+        once a serve-loop iteration. A tick driven by `step()`,
+        `run_until_idle` or a drain from another thread is not the
+        scheduler thread's time."""
+        if threading.current_thread() is self._thread:
+            # never negative: the two clocks are not read at one instant
+            self._waited += max(0.0, s.seconds - s.cpu_seconds)
+            if lock:
+                self._lock_waited += s.seconds
+
+    def _open_tail(self) -> None:
+        """On the serve thread, open `serving/tail`: from the end of a
+        tick's commit until the serve loop is back at `_tick`. The serve
+        loop closes it; no other thread opens one (its span stack would
+        keep it)."""
+        if threading.current_thread() is self._thread:
+            self._tail = span("serving/tail")
+            self._tail.__enter__()
+
+    def _close_tail(self) -> None:
+        tail, self._tail = self._tail, None
+        if tail is not None:
+            tail.__exit__(None, None, None)
 
     def _zero_tokens(self):
         """The device token array before any tick: the shape of the
@@ -1374,12 +1421,16 @@ class ContinuousBatchingEngine:
         t0 = time.perf_counter()
         # dispatch: the host cursors are uploaded and the program
         # enqueued behind whatever the device is still running
-        with span("dispatch", lanes=len(lanes)):
-            out = self._run_decode(run)
-            for x in out:
-                # the copy back starts when the device is done, not
-                # when the host comes to ask
-                x.copy_to_host_async()
+        with span("dispatch", lanes=len(lanes)) as s:
+            # argument handling, the three uploads, the enqueue
+            with span("call"):
+                out = self._run_decode(run)
+            with span("copy_back"):
+                for x in out:
+                    # the copy back starts when the device is done, not
+                    # when the host comes to ask
+                    x.copy_to_host_async()
+        self.metrics.record_dispatch(s)
         self._ticks_left = self._ticks_left - run
         if not self.spec:
             self._pos = self._pos + run
@@ -1390,8 +1441,9 @@ class ContinuousBatchingEngine:
     def _fetch_locked(self, tick: _Tick) -> None:
         """Block until `tick`'s outputs are on the host (copies — the
         device views are read-only)."""
-        with span("fetch"):
+        with span("fetch") as s:
             tick.host = tuple(np.array(x) for x in tick.out)
+        self._declared_wait(s)
         now = time.perf_counter()
         # its share of the wall: from its enqueue, or from the fetch
         # before it where it was enqueued behind a running tick
@@ -1418,15 +1470,13 @@ class ContinuousBatchingEngine:
         # a speculative tick's first output is its accept counts
         tokens = len(live) if not self.spec else \
             int(tick.host[0][tick.lanes].sum()) + len(tick.lanes)
-        with span("serving/commit", lanes=len(tick.lanes), tokens=tokens):
+        with span("serving/commit", lanes=len(tick.lanes),
+                  tokens=tokens) as s:
             if self.spec:
                 self._commit_spec(tick, live)
             else:
                 self._commit_plain(tick, live)
-        if self._inflight is not None and not self._active.any():
-            # every lane of the tick in flight was just released:
-            # nobody waits for its tokens
-            self._inflight = None
+        self.metrics.record_commit(s)
 
     def _commit_spec(self, tick: _Tick, live) -> None:
         n_r, win = tick.host
@@ -1471,7 +1521,7 @@ class ContinuousBatchingEngine:
             # drafts; the one at offset n_r is the correction
             accepted_delivered += min(int(n_r[i]), k)
         self.metrics.record_tick(len(tick.lanes),
-                                 self.config.num_slots, tick.seconds,
+                                 self.config.num_slots,
                                  tokens=delivered,
                                  kv_tokens=tick.kv_tokens,
                                  kv_blocks=tick.kv_blocks)
@@ -1486,7 +1536,7 @@ class ContinuousBatchingEngine:
                                     self._experts_held)
         # credited at commit, and only what is delivered: the counter
         # keeps matching the clients' count
-        self.metrics.record_tick(len(tick.lanes), S, tick.seconds,
+        self.metrics.record_tick(len(tick.lanes), S,
                                  tokens=len(live),
                                  kv_tokens=tick.kv_tokens,
                                  kv_blocks=tick.kv_blocks,
@@ -1549,25 +1599,13 @@ class ContinuousBatchingEngine:
                 need = blocks_for_tokens(
                     bucket + decode_span + self._gamma,
                     self.block_size)
-                blocks = self._allocator.alloc(need)
-                if blocks is None:
-                    self._queue.appendleft(req)
-                    if self._deferred_req != req.request_id:
-                        # count the deferral EVENT once, not once per
-                        # tick the head keeps waiting
-                        self._deferred_req = req.request_id
-                        self.metrics.count("deferred_admissions")
-                        req.timeline.add(
-                            now, "deferred", blocks_needed=int(need),
-                            blocks_free=int(self._allocator.free_blocks))
-                        self._log({"event": "serving_defer",
-                                   "reason": "kv_blocks_exhausted",
-                                   "request_id": req.request_id,
-                                   "blocks_needed": need,
-                                   "blocks_free":
-                                       self._allocator.free_blocks})
-                    return prefills
-                self._deferred_req = None
+                with span("serving/alloc", request_id=req.request_id,
+                          blocks=int(need)):
+                    blocks = self._allocator.alloc(need)
+                    if blocks is None:
+                        self._defer(req, need, now)
+                        return prefills
+                    self._deferred_req = None
             try:
                 if windows is None:
                     row, mask_row = self.ladder.pad_prompt(
@@ -1594,7 +1632,7 @@ class ContinuousBatchingEngine:
                                  bucket=int(bucket))
                 with span("serving/prefill", request_id=req.request_id,
                           bucket=int(bucket),
-                          prompt_tokens=int(len(prefill_ids))):
+                          prompt_tokens=int(len(prefill_ids))) as s:
                     if windows is not None:
                         primed, tok = self._prefill_windows(
                             req, row, windows, key)
@@ -1606,6 +1644,8 @@ class ContinuousBatchingEngine:
                         primed, tok = self._prefill_jit(
                             self.params, row[None], mask_row[None], key)
                     tok = int(np.asarray(tok)[0])
+                # its first-token fetch waits for the device
+                self._declared_wait(s)
                 prefills += 1
                 if windows is None:
                     self.metrics.record_prefill(bucket, len(prefill_ids))
@@ -1653,6 +1693,23 @@ class ContinuousBatchingEngine:
                              d_primed if self.self_draft else None, tok,
                              lane_key)
         return prefills
+
+    def _defer(self, req: Request, need: int, now: float) -> None:
+        """The pool cannot serve `req`: back to the head of the queue."""
+        self._queue.appendleft(req)
+        if self._deferred_req != req.request_id:
+            # count the deferral EVENT once, not once per tick the head
+            # keeps waiting
+            self._deferred_req = req.request_id
+            self.metrics.count("deferred_admissions")
+            req.timeline.add(
+                now, "deferred", blocks_needed=int(need),
+                blocks_free=int(self._allocator.free_blocks))
+            self._log({"event": "serving_defer",
+                       "reason": "kv_blocks_exhausted",
+                       "request_id": req.request_id,
+                       "blocks_needed": need,
+                       "blocks_free": self._allocator.free_blocks})
 
     def _prefill_windows(self, req: Request, ids, windows, key):
         """Prefill `ids` window by window onto one batch-1 cache, each
@@ -1794,6 +1851,7 @@ class ContinuousBatchingEngine:
         self._thread.start()
 
     def _serve_loop(self) -> None:
+        wall, cpu = thread_times()
         while not self._stop_flag:
             try:
                 n = self._tick(ahead=True)
@@ -1803,6 +1861,7 @@ class ContinuousBatchingEngine:
                 # wedged engine; fail the in-flight work loudly and
                 # keep serving (the tick may have died mid-donation,
                 # so the pool is rebuilt from scratch)
+                self._close_tail()
                 self._log({"event": "serving_tick_error",
                            "error": str(e)[:500]})
                 with self._cv:
@@ -1833,10 +1892,20 @@ class ContinuousBatchingEngine:
                 # not just the final values
                 self._recorder.snapshot_metrics((self.metrics.registry,))
             if n == 0:
+                self._close_tail()
                 with self._cv:
                     if not self._queue and not self._stop_flag:
-                        with span("serving/idle_wait"):
+                        with span("serving/idle_wait") as s:
                             self._cv.wait(timeout=0.02)
+                        self._declared_wait(s)
+            # the iteration's account: wall = cpu + declared wait + what
+            # was taken from the thread (docs/serving.md "Threading")
+            now, cpu_now = thread_times()
+            self.metrics.record_scheduler(now - wall, cpu_now - cpu,
+                                          self._waited, self._lock_waited)
+            wall, cpu = now, cpu_now
+            self._waited = self._lock_waited = 0.0
+            self._close_tail()
 
     def _reset_pool_locked(self) -> None:
         """Fail every queued/running request, drop the tick in flight

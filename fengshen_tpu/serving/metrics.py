@@ -73,11 +73,6 @@ class EngineMetrics:
         self._decode_tokens = r.counter(
             "fstpu_serving_decode_tokens_total",
             "tokens generated by decode ticks")
-        self._decode_time = r.counter(
-            "fstpu_serving_decode_seconds_total",
-            "wall seconds of the decode ticks: from a tick's enqueue, "
-            "or from the fetch before it where it was enqueued behind "
-            "a running tick, to the fetch of its tokens")
         self._prefill_tokens = r.counter(
             "fstpu_serving_prefill_tokens_total",
             "real prompt tokens prefilled (a resumed request's "
@@ -135,6 +130,33 @@ class EngineMetrics:
             "fstpu_moe_max_expert_tokens_total",
             "assignments of the busiest expert held here, summed over "
             "layer-ticks (the straggler)")
+        # the scheduler thread's own time (docs/serving.md "Threading"):
+        # wall = cpu + wait + what was taken from it
+        self._sched_wall = r.counter(
+            "fstpu_serving_scheduler_wall_seconds_total",
+            "wall seconds of serve-loop iterations")
+        self._sched_cpu = r.counter(
+            "fstpu_serving_scheduler_cpu_seconds_total",
+            "the scheduler thread's CPU seconds over the same "
+            "iterations")
+        self._sched_wait = r.counter(
+            "fstpu_serving_scheduler_wait_seconds_total",
+            "off-CPU seconds (wall - CPU) inside the spans that wait "
+            "by design: serving/lock_wait, serving/idle_wait, "
+            "serving/decode/fetch, serving/prefill")
+        self._sched_lock_wait = r.counter(
+            "fstpu_serving_lock_wait_seconds_total",
+            "wall seconds of the scheduler's serving/lock_wait spans")
+        self._dispatch = (
+            r.counter("fstpu_serving_dispatch_seconds_total",
+                      "wall seconds of serving/decode/dispatch"),
+            r.counter("fstpu_serving_dispatch_cpu_seconds_total",
+                      "the thread's CPU seconds inside them"))
+        self._commit = (
+            r.counter("fstpu_serving_commit_seconds_total",
+                      "wall seconds of serving/commit"),
+            r.counter("fstpu_serving_commit_cpu_seconds_total",
+                      "the thread's CPU seconds inside them"))
         self._submit_lock_wait = r.histogram(
             "fstpu_serving_submit_lock_wait_seconds",
             "submit()'s wait for the scheduler's lock", window=window)
@@ -228,8 +250,29 @@ class EngineMetrics:
     def record_submit_lock_wait(self, seconds: float) -> None:
         self._submit_lock_wait.observe(seconds)
 
+    def record_scheduler(self, wall: float, cpu: float, wait: float,
+                         lock_wait: float) -> None:
+        """One serve-loop iteration of the scheduler thread. What is
+        left of `wall` after `cpu` and `wait` was taken from the
+        thread: the GIL, preemption, or a call that blocks where no
+        wait was declared."""
+        self._sched_wall.inc(wall)
+        self._sched_cpu.inc(cpu)
+        self._sched_wait.inc(wait)
+        self._sched_lock_wait.inc(lock_wait)
+
+    def record_dispatch(self, s) -> None:
+        """A closed `serving/decode/dispatch` span."""
+        self._dispatch[0].inc(s.seconds)
+        self._dispatch[1].inc(s.cpu_seconds)
+
+    def record_commit(self, s) -> None:
+        """A closed `serving/commit` span."""
+        self._commit[0].inc(s.seconds)
+        self._commit[1].inc(s.cpu_seconds)
+
     def record_tick(self, n_active: int, num_slots: int,
-                    seconds: float, tokens: Optional[int] = None,
+                    tokens: Optional[int] = None,
                     kv_tokens: int = 0, kv_blocks: tuple = (0, 0),
                     ahead: bool = False) -> None:
         """`tokens` defaults to `n_active` (one committed token per
@@ -247,7 +290,6 @@ class EngineMetrics:
         self._kv_blocks_live.inc(kv_blocks[0])
         self._kv_blocks_tabled.inc(kv_blocks[1])
         self._decode_tokens.inc(n_active if tokens is None else tokens)
-        self._decode_time.inc(seconds)
         self._occupied_ticks.inc(n_active)
         self._total_ticks.inc(num_slots)
         if n_active > self._peak_active:
@@ -362,10 +404,7 @@ class EngineMetrics:
         self._g_kv_frag.set(kv["fragmentation"])
         ttft = self._ttft.window_values()
         decode_tokens = int(self._decode_tokens.value())
-        decode_time_s = self._decode_time.value()
         total_ticks = int(self._total_ticks.value())
-        decode_tps = (decode_tokens / decode_time_s
-                      if decode_time_s > 0 else 0.0)
         occupancy = (int(self._occupied_ticks.value()) / total_ticks
                      if total_ticks > 0 else 0.0)
         out = {
@@ -396,7 +435,6 @@ class EngineMetrics:
                 for values, child in self._prefills.children()},
             "decode_ticks": int(self._decode_ticks.value()),
             "decode_tokens": decode_tokens,
-            "decode_tokens_per_sec": round(decode_tps, 2),
             "slot_occupancy": round(occupancy, 4),
             "ttft_avg_s": round(sum(ttft) / len(ttft), 4) if ttft
                           else 0.0,

@@ -13,6 +13,7 @@ import subprocess
 import sys
 import textwrap
 import threading
+import time
 import urllib.request
 
 import numpy as np
@@ -172,6 +173,142 @@ def test_span_records_on_exception():
             raise RuntimeError("x")
     assert r.get("fstpu_span_seconds").labels("boom").count == 1
     assert current_span_stack() == ()
+
+
+def _sleep_50ms():
+    time.sleep(0.05)
+
+
+def _burn_20ms_of_cpu():
+    end = time.thread_time() + 0.02
+    while time.thread_time() < end:
+        pass
+
+
+@pytest.mark.parametrize("body, low, high", [
+    # a wait: the thread is off the CPU for nearly all of the wall
+    (_sleep_50ms, 0.0, 0.2),
+    # work: what the wall holds beyond the CPU is what a loaded test
+    # host took from the thread, never the other way round
+    (_burn_20ms_of_cpu, 0.02, None)],
+    ids=["a_sleep_is_a_wait", "a_busy_loop_is_work"])
+def test_span_measures_the_threads_cpu_beside_the_wall(body, low, high):
+    r = MetricsRegistry()
+    with span("cpu/probe", registry=r) as s:
+        body()
+    # the two clocks are not read at one instant
+    assert low <= s.cpu_seconds <= s.seconds + 1e-4
+    if high is not None:
+        assert s.cpu_seconds < high * s.seconds
+    assert r.get("fstpu_span_seconds").labels("cpu/probe").sum == \
+        pytest.approx(s.seconds)
+    assert r.get("fstpu_span_cpu_seconds_total").labels(
+        "cpu/probe").value == pytest.approx(s.cpu_seconds)
+
+
+@pytest.mark.parametrize("raises", [False, True],
+                         ids=["at_exit", "on_an_exception"])
+def test_span_yields_a_record_filled_when_the_section_ends(raises):
+    r = MetricsRegistry()
+    try:
+        with span("record/probe", registry=r, lanes=2) as s:
+            assert s.seconds == 0.0 and s.cpu_seconds == 0.0
+            time.sleep(0.002)
+            if raises:
+                raise RuntimeError("x")
+    except RuntimeError:
+        assert raises
+    assert s.seconds >= 0.002 and 0.0 <= s.cpu_seconds <= s.seconds + 1e-4
+    assert current_span_stack() == ()
+    # a caller that ignores the record is unchanged
+    with span("record/ignored", registry=r):
+        pass
+    assert r.get("fstpu_span_seconds").labels("record/ignored").count == 1
+
+
+def test_span_cpu_counter_renders_labelled_beside_the_histogram():
+    r = MetricsRegistry()
+    with span("outer", registry=r):
+        with span("inner", registry=r):
+            _burn_20ms_of_cpu()
+    text = render_prometheus(r)
+    assert "# TYPE fstpu_span_cpu_seconds_total counter" in text
+    cpu = {line.split(" ")[0]: float(line.split(" ")[1])
+           for line in text.splitlines()
+           if line.startswith("fstpu_span_cpu_seconds_total{")}
+    assert set(cpu) == {
+        'fstpu_span_cpu_seconds_total{span="outer"}',
+        'fstpu_span_cpu_seconds_total{span="outer/inner"}'}
+    # the parent's CPU holds its child's
+    assert cpu['fstpu_span_cpu_seconds_total{span="outer"}'] >= \
+        cpu['fstpu_span_cpu_seconds_total{span="outer/inner"}'] >= 0.02
+    assert 'fstpu_span_seconds_count{span="outer/inner"} 1' in text
+
+
+def test_span_resolves_its_children_once_per_registry_and_label(
+        monkeypatch):
+    """An exit looks nothing up by name after the first, and two
+    registries never share a child."""
+    a, b = MetricsRegistry(), MetricsRegistry()
+    lookups = []
+    for reg in (a, b):
+        real = reg.histogram
+        monkeypatch.setattr(
+            reg, "histogram",
+            lambda *args, _real=real, _reg=reg, **kw:
+            (lookups.append(_reg), _real(*args, **kw))[1])
+    for _ in range(5):
+        with span("cached", registry=a):
+            pass
+    with span("cached", registry=b):
+        pass
+    with span("other", registry=a):
+        pass
+    assert lookups == [a, b, a]
+    assert a.get("fstpu_span_seconds").labels("cached").count == 5
+    assert b.get("fstpu_span_seconds").labels("cached").count == 1
+    assert a.span_children["cached"][0] is not b.span_children["cached"][0]
+    assert a.span_children["cached"][1] is \
+        a.get("fstpu_span_cpu_seconds_total").labels("cached")
+    assert set(a.span_children) == {"cached", "other"}
+    assert set(b.span_children) == {"cached"}
+
+
+def test_nested_and_adjacent_spans_share_one_read_of_the_cpu_clock(
+        monkeypatch):
+    """The CPU clock is a system call: a reading within 100 us of the
+    thread's last is extrapolated from it, a later one is read anew and
+    never lies behind an earlier reading."""
+    import fengshen_tpu.observability.tracing as tracing
+    wall, cpu, reads = [100.0], [7.0], []
+    fake = type("T", (), {
+        "perf_counter": staticmethod(lambda: wall[0]),
+        "thread_time": staticmethod(
+            lambda: (reads.append(wall[0]), cpu[0])[1])})
+    monkeypatch.setattr(tracing, "time", fake)
+    monkeypatch.setattr(tracing._local, "cpu_anchor", None, raising=False)
+    assert tracing.thread_times() == (100.0, 7.0)
+    wall[0] += 5e-6
+    cpu[0] += 1.0                   # not looked at: 5 us after the last
+    t, c = tracing.thread_times()
+    assert t == wall[0] and c == pytest.approx(7.0 + 5e-6)
+    wall[0] += 96e-6                # 101 us after the anchor: read anew
+    assert tracing.thread_times() == (wall[0], 8.0)
+    assert reads == [100.0, wall[0]]
+    # a coarse clock that has not moved since: the extrapolated reading
+    # stands, the next one is not behind it
+    wall[0] += 60e-6
+    assert tracing.thread_times()[1] == pytest.approx(8.0 + 60e-6)
+    wall[0] += 60e-6
+    assert tracing.thread_times()[1] == pytest.approx(8.0 + 60e-6)
+    assert len(reads) == 3
+    # a sleeping section is measured, not extrapolated
+    r = MetricsRegistry()
+    with span("reuse/probe", registry=r) as s:
+        wall[0] += 0.5
+        cpu[0] += 0.001
+    assert s.seconds == pytest.approx(0.5)
+    assert s.cpu_seconds == pytest.approx(0.001, abs=125e-6)
 
 
 def _record_annotations(monkeypatch) -> list:
@@ -376,9 +513,16 @@ def test_metrics_endpoint_stdlib_server_simple_pipeline():
             headers={"Content-Type": "application/json"})
         with urllib.request.urlopen(req, timeout=10) as r:
             assert r.status == 200
+        with span("probe/metrics_endpoint"):
+            pass
         code, ctype, body = _get(f"http://127.0.0.1:{port}/metrics")
         assert code == 200
         assert ctype.startswith("text/plain; version=0.0.4")
+        # a span's wall histogram and its CPU counter, both labelled
+        assert ('fstpu_span_seconds_count{span="probe/metrics_endpoint"}'
+                in body)
+        assert ('fstpu_span_cpu_seconds_total{span='
+                '"probe/metrics_endpoint"}') in body
         assert ('fstpu_http_requests_total{route='
                 '"/api/text_classification",code="200"} 1') in body
         # every sample line parses as `name{labels} value`
@@ -421,7 +565,7 @@ def test_engine_metrics_snapshot_shape_pinned():
     m.count("completed")
     m.record_prefill(64, 50)
     m.record_prefill(64, 50)
-    m.record_tick(3, 8, 0.5)
+    m.record_tick(3, 8)
     m.record_ttft(0.2)
     m.record_ttft(0.4)
     m.warmup_compile_s = 1.5
@@ -443,8 +587,7 @@ def test_engine_metrics_snapshot_shape_pinned():
         "kv_blocks_free": 11, "kv_block_tokens": 64,
         "kv_cache_bytes": 4096, "kv_fragmentation": 0.25,
         "prefills_per_bucket": {64: 2},
-        "decode_ticks": 1, "decode_tokens": 3,
-        "decode_tokens_per_sec": 6.0, "slot_occupancy": 0.375,
+        "decode_ticks": 1, "decode_tokens": 3, "slot_occupancy": 0.375,
         "ttft_avg_s": 0.3, "ttft_p50_s": 0.4, "ttft_p95_s": 0.4,
         "warmup_compile_s": 1.5,
         # ISSUE 8: the payload only EXTENDS (uptime + last error type/
@@ -459,7 +602,7 @@ def test_engine_metrics_snapshot_shape_pinned():
     # non-spec payload above stays byte-identical
     assert not any(k.startswith("spec_") for k in snap)
     m.record_spec(8, 5)
-    m.record_tick(3, 8, 0.5, tokens=8)   # spec tick: 8 committed
+    m.record_tick(3, 8, tokens=8)        # spec tick: 8 committed
     snap2 = m.snapshot(queue_depth=1, slots_active=3, num_slots=8,
                        kv={"layout": "paged", "dtype": "int8",
                            "blocks_total": 16, "blocks_used": 5,
@@ -467,11 +610,13 @@ def test_engine_metrics_snapshot_shape_pinned():
                            "bytes": 4096, "fragmentation": 0.25},
                        spec={"mode": "prompt_lookup", "gamma": 4})
     assert snap2 == dict(snap, decode_ticks=2, decode_tokens=11,
-                         decode_tokens_per_sec=11.0,
                          spec_mode="prompt_lookup", spec_gamma=4,
                          spec_drafted_total=8, spec_accepted_total=5,
                          spec_acceptance_rate=0.625)
     text = render_prometheus(m.registry)
+    # wall time of dispatch + run + fetch was neither device nor host
+    # time and had no reader
+    assert "decode_seconds" not in text
     assert "fstpu_serving_admitted_total 2" in text
     assert 'fstpu_serving_prefills_total{bucket="64"} 2' in text
     assert "fstpu_serving_queue_depth 1" in text

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from fengshen_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+from fengshen_tpu.observability import current_span_stack
 from fengshen_tpu.serving import (ContinuousBatchingEngine, EngineConfig,
                                   BucketLadder, PromptTooLong, QueueFull,
                                   rollback_slots)
@@ -638,6 +639,11 @@ def test_scheduler_spans_cover_the_tick_and_keep_the_old_names(tiny):
     before = _span_counts()
     reqs = [eng.submit(p) for p in _prompts((5, 11, 16))]
     eng.run_until_idle()
+    offline_ticks = eng.stats()["decode_ticks"]
+    # no serve thread, no `serving/tail`: its span stack would keep it
+    assert eng._tail is None and current_span_stack() == ()
+    assert _span_counts().get("serving/tail", 0) == \
+        before.get("serving/tail", 0)
     eng.start()                  # the serve loop: lock and idle waits
     try:
         late = eng.submit(_prompts((7,))[0])
@@ -655,11 +661,29 @@ def test_scheduler_spans_cover_the_tick_and_keep_the_old_names(tiny):
     assert {"serving/decode/dispatch", "serving/decode/fetch",
             "serving/commit", "serving/assign", "serving/lock_wait",
             "serving/idle_wait", "serving/admit/lock_wait"} <= grew
+    # the cycle's cover (ISSUE 34): siblings and children again
+    assert {"serving/reclaim", "serving/tail",
+            "serving/decode/dispatch/call",
+            "serving/decode/dispatch/copy_back"} <= grew
+    # nothing was renamed by a span opened around it
+    assert not {k for k in grew if k.startswith("serving/")} - {
+        "serving/admit", "serving/admit/lock_wait", "serving/lock_wait",
+        "serving/reclaim", "serving/prefill", "serving/assign",
+        "serving/decode", "serving/decode/dispatch",
+        "serving/decode/dispatch/call",
+        "serving/decode/dispatch/copy_back", "serving/decode/fetch",
+        "serving/commit", "serving/tail", "serving/idle_wait"}
     after = _span_counts()
     ticks = eng.stats()["decode_ticks"]
     for child in ("serving/decode/dispatch", "serving/decode/fetch",
-                  "serving/commit"):
+                  "serving/commit", "serving/decode/dispatch/call",
+                  "serving/decode/dispatch/copy_back"):
         assert after[child] - before.get(child, 0) == ticks
+    # one tail a tick the serve thread committed, one reclaim a cycle
+    assert after["serving/tail"] - before.get("serving/tail", 0) == \
+        ticks - offline_ticks
+    assert after["serving/reclaim"] - before.get("serving/reclaim", 0) \
+        >= after["serving/decode"] - before.get("serving/decode", 0)
     # one `serving/decode` a tick, holding the next tick's dispatch and
     # this tick's fetch; each of the three busy stretches (two lanes
     # that end together, the third prompt, the late one) opens with a
@@ -669,6 +693,146 @@ def test_scheduler_spans_cover_the_tick_and_keep_the_old_names(tiny):
     assert after["serving/assign"] - before.get("serving/assign", 0) == 4
     if hasattr(eng._decode_jit, "_cache_size"):
         assert eng._decode_jit._cache_size() == 1
+
+
+_SCHED = {k: f"fstpu_serving_{k}_total" for k in (
+    "scheduler_wall_seconds", "scheduler_cpu_seconds",
+    "scheduler_wait_seconds", "lock_wait_seconds", "dispatch_seconds",
+    "dispatch_cpu_seconds", "commit_seconds", "commit_cpu_seconds")}
+
+
+def _sched(eng) -> dict:
+    return {k: eng.metrics.registry.get(name).value()
+            for k, name in _SCHED.items()}
+
+
+def test_scheduler_thread_accounts_its_wall_cpu_and_declared_wait(tiny):
+    """wall = cpu + declared wait + what was taken from the thread:
+    the serve loop credits the first three once an iteration; `step()`
+    and `run_until_idle` are nobody's scheduler thread and credit
+    nothing but the dispatch and commit pairs."""
+    import time
+    model, params = tiny
+    eng = ContinuousBatchingEngine(
+        model, params, EngineConfig(num_slots=2, buckets=(8, 16),
+                                    max_new_tokens=6, max_queue=16))
+    assert set(_sched(eng).values()) == {0.0}
+    first = eng.submit(_prompts((5,))[0])
+    while not first.done:
+        eng.step()
+    eng.generate_all(_prompts((7, 9), seed=1))
+    offline = _sched(eng)
+    for k in ("scheduler_wall_seconds", "scheduler_cpu_seconds",
+              "scheduler_wait_seconds", "lock_wait_seconds"):
+        assert offline[k] == 0.0
+    slack = 1e-4                     # the two clocks are read in turn
+    assert 0.0 < offline["dispatch_cpu_seconds"] <= \
+        offline["dispatch_seconds"] + slack
+    assert 0.0 < offline["commit_cpu_seconds"] <= \
+        offline["commit_seconds"] + slack
+
+    def sound(c):
+        assert c["scheduler_cpu_seconds"] > 0.0
+        assert c["scheduler_wait_seconds"] > 0.0
+        assert c["scheduler_wall_seconds"] + slack >= \
+            c["scheduler_cpu_seconds"] + c["scheduler_wait_seconds"]
+        assert 0.0 < c["lock_wait_seconds"] <= c["scheduler_wait_seconds"]
+        assert c["dispatch_cpu_seconds"] <= c["dispatch_seconds"] + slack
+        assert c["commit_cpu_seconds"] <= c["commit_seconds"] + slack
+
+    eng.start()
+    try:
+        for r in [eng.submit(p) for p in _prompts((5, 11, 16), seed=2)]:
+            assert r.wait(timeout=60)
+        time.sleep(0.05)             # an idle wait or two
+        mid = _sched(eng)
+        sound(mid)
+        for r in [eng.submit(p) for p in _prompts((6, 12), seed=3)]:
+            assert r.wait(timeout=60)
+    finally:
+        eng.stop()
+    end = _sched(eng)
+    sound(end)
+    for k in _SCHED:
+        assert offline[k] <= mid[k] <= end[k]
+    assert mid["scheduler_wall_seconds"] < end["scheduler_wall_seconds"]
+    # the thread lived about as long as its iterations say
+    assert end["scheduler_wall_seconds"] >= 0.05
+    if hasattr(eng._decode_jit, "_cache_size"):
+        assert eng._decode_jit._cache_size() == 1
+
+
+def test_a_cpu_clock_that_runs_ahead_never_stops_the_serve_loop(
+        tiny, monkeypatch):
+    """On a sandboxed host the thread's CPU clock is coarse and may
+    read ahead of the wall clock over a short span: the account clamps,
+    the serve thread lives and its counters only go up."""
+    import fengshen_tpu.observability.tracing as tracing
+    real = tracing.time.thread_time
+    fake = type("T", (), {
+        "perf_counter": staticmethod(tracing.time.perf_counter),
+        # 4 ms steps, rounded up and then some
+        "thread_time": staticmethod(
+            lambda: (int(real() / 0.004) + 1) * 0.004 + 10 * real())})
+    monkeypatch.setattr(tracing, "time", fake)
+    model, params = tiny
+    eng = ContinuousBatchingEngine(
+        model, params, EngineConfig(num_slots=2, buckets=(8, 16),
+                                    max_new_tokens=12, max_queue=16))
+    eng.start()
+    try:
+        seen = [_sched(eng)]
+        for seed in (4, 5):
+            for r in [eng.submit(p) for p in _prompts((5, 11), seed=seed)]:
+                assert r.wait(timeout=60) and r.state == "finished"
+            seen.append(_sched(eng))
+        assert eng._thread.is_alive()
+    finally:
+        eng.stop()
+    for before, after in zip(seen, seen[1:]):
+        assert all(before[k] <= after[k] for k in _SCHED)
+    assert seen[-1]["scheduler_wall_seconds"] > 0.0
+    assert seen[-1]["scheduler_wait_seconds"] >= 0.0
+
+
+def test_block_allocation_has_a_span_a_request_also_when_deferred(tiny):
+    model, params = tiny
+    eng = ContinuousBatchingEngine(
+        model, params, EngineConfig(num_slots=4, buckets=(8,),
+                                    max_new_tokens=8, max_queue=16,
+                                    kv_layout="paged", kv_block_size=16,
+                                    kv_num_blocks=3))
+    before = _span_counts().get("serving/alloc", 0)
+    reqs = [eng.submit(p) for p in _prompts((6, 6, 6), seed=2)]
+    eng.step()
+    # two admitted, the third deferred inside its own span
+    assert _span_counts()["serving/alloc"] - before == 3
+    assert eng.stats()["deferred_admissions"] == 1
+    assert [r.request_id for r in eng._queue] == [reqs[2].request_id]
+    eng.run_until_idle()
+    assert all(r.state == "finished" for r in reqs)
+    assert eng.stats()["kv_blocks_used"] == 0
+    assert "serving/alloc/serving/prefill" not in _span_counts()
+
+
+def test_the_unread_decode_seconds_are_gone_from_stats_and_metrics(tiny):
+    from fengshen_tpu.observability import render_prometheus
+    model, params = tiny
+    eng = ContinuousBatchingEngine(
+        model, params, EngineConfig(num_slots=1, buckets=(8,),
+                                    max_new_tokens=3, max_queue=4))
+    req = eng.submit(_prompts((5,))[0])
+    eng.run_until_idle()
+    stats = eng.stats()
+    assert "decode_tokens_per_sec" not in stats
+    assert stats["decode_ticks"] == 2 and stats["decode_tokens"] == 2
+    text = render_prometheus(eng.metrics.registry)
+    assert "decode_seconds" not in text
+    assert "fstpu_serving_decode_ticks_total 2" in text
+    # the timelines keep `tick_s`, documented for operators
+    commits = [e for e in eng.debug_request(req.request_id)["events"]
+               if e["event"] == "commit"]
+    assert commits and all(e["tick_s"] >= 0 for e in commits)
 
 
 def test_submit_lock_wait_is_measured_and_enqueued_is_stamped_after_it(
