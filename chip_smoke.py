@@ -7,9 +7,9 @@ process holds the chip for all three phases:
 
 1. kernels — every op in `ops.pallas.dispatch_table()`: the registered
    Mosaic implementation compiled for real (never interpreted) at the
-   shapes the next two phases use (the folded decode entry, which no
-   LLaMA shape reaches, at Qwen3-Next's), against its registered xla
-   twin;
+   shapes the next two phases use (the folded decode entry and the
+   chunked gated delta rule, which no LLaMA shape reaches, at
+   Qwen3-Next's), against its registered xla twin;
 2. train — `Trainer(args).fit(CausalLMModule, UniversalDataModule)` as
    every example builds them: a few optimizer steps at seq 2048, mesh
    over all visible devices, one `UniversalCheckpoint` save and restore;
@@ -298,6 +298,41 @@ def _folded_decode_cases(cfg, rows):
            f"paged bf16 kv={list(shape)} t={t.tolist()}", got, want)
 
 
+def _gated_delta_cases(cfg, rows):
+    """The chunked gated delta rule at Qwen3-Next's published head
+    geometry (value heads of 128 two to a key head, l2-normalised
+    float32 q and k, bfloat16 v), a window padded on the right, onto a
+    state that is not zero: the chunk kernel against the `jax.numpy`
+    form. The float32 state is held to 1e-4, not to a bf16 tolerance."""
+    del cfg
+    import jax
+    import jax.numpy as jnp
+
+    from fengshen_tpu.ops.gated_delta import l2norm
+    from fengshen_tpu.ops.pallas import get_kernel
+    from fengshen_tpu.ops.pallas.gated_delta import _ineligible_reason
+    pallas = get_kernel("gated_delta_prefill", "pallas")
+    xla = get_kernel("gated_delta_prefill", "xla")
+    seq, key_heads, heads, dim, real = 1024, 4, 8, 128, 900
+    ks = jax.random.split(jax.random.PRNGKey(SEED + 5), 6)
+    q = l2norm(jax.random.normal(ks[0], (1, seq, key_heads, dim))) * \
+        dim ** -0.5
+    k = l2norm(jax.random.normal(ks[1], (1, seq, key_heads, dim)))
+    v = jax.random.normal(ks[2], (1, seq, heads, dim), jnp.bfloat16)
+    g = -jax.nn.softplus(jax.random.normal(ks[3], (1, seq, heads)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (1, seq, heads)))
+    state = jax.random.normal(ks[5], (1, heads, dim, dim))
+    mask = jnp.arange(seq)[None] < real
+    assert _ineligible_reason(q, v) is None
+    got, got_state = jax.jit(pallas)(q, k, v, g, beta, state, mask)
+    want, want_state = jax.jit(xla)(q, k, v, g, beta, state, mask)
+    case = f"q={list(q.shape)} v={list(v.shape)} bf16, {real} real"
+    _check(rows, "gated_delta_prefill", case + ", out", got[:, :real],
+           want[:, :real])
+    _check(rows, "gated_delta_prefill", case + ", state", got_state,
+           want_state, tol=1e-4)
+
+
 def _fused_ce_cases(cfg, rows):
     import jax
     import jax.numpy as jnp
@@ -364,6 +399,7 @@ KERNEL_CASES = {
     "flash_attention": _flash_cases,
     "decode_attention": _decode_cases,
     "folded_decode_attention": _folded_decode_cases,
+    "gated_delta_prefill": _gated_delta_cases,
     "fused_ce": _fused_ce_cases,
     "block_sparse_attention": _block_sparse_cases,
 }

@@ -194,13 +194,15 @@ class GatedDeltaNet(nn.Module):
         q = y[..., :cfg.key_dim].reshape(batch, seq, Hk, Dk)
         k = y[..., cfg.key_dim:2 * cfg.key_dim].reshape(batch, seq, Hk, Dk)
         v = y[..., 2 * cfg.key_dim:].reshape(batch, seq, Hv, Dv)
-        q = jnp.repeat(l2norm(q) * Dk ** -0.5, rep, axis=2)
-        k = jnp.repeat(l2norm(k), rep, axis=2)
+        q, k = l2norm(q) * Dk ** -0.5, l2norm(k)
         if tick:
-            out, state = gated_delta_decode(q[:, 0], k[:, 0], v[:, 0],
-                                            g[:, 0], beta[:, 0], state, live)
+            q, k = (jnp.repeat(x[:, 0], rep, axis=1) for x in (q, k))
+            out, state = gated_delta_decode(q, k, v[:, 0], g[:, 0],
+                                            beta[:, 0], state, live)
             out = out[:, None]
         else:
+            # a key head's rows as they are: the prefill form reads them
+            # for the value heads that share it
             out, state = gated_delta_prefill(q, k, v, g, beta, state, mask,
                                              chunk=cfg.delta_chunk)
         if cache is not None:

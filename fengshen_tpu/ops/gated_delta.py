@@ -11,7 +11,7 @@ its write strength (`S_0 = 0`):
     S_t = S' + k_t^T d_t
     o_t = q_t S_t
 
-Two forms of that one recurrence, both plain `jax.numpy` (the CPU
+Two forms of that one recurrence, in plain `jax.numpy` (the CPU
 tier-1 truth; each under its own device scope so a trace finds it):
 
 - :func:`gated_delta_decode` — the one-token step over a pool of lanes,
@@ -27,9 +27,15 @@ tier-1 truth; each under its own device scope so a trace finds it):
   substitution), `W = T (beta K exp(G))`, `U = T (beta V)`; across
   chunks, in order, `V_new = U - W S`, `O = (Q exp(G)) S + tril(Q K^T
   exp(G_i - G_j)) V_new`, `S <- exp(G_last) S + (K exp(G_last - G))^T
-  V_new`. Everything that does not read the state is batched over the
-  chunks; the scan carries the `[H, Dk, Dv]` state and four products a
-  chunk. The result does not depend on `c`.
+  V_new`. The result does not depend on `c`. It is a seam of
+  `ops/pallas`: a window whose shape the Mosaic chunk kernel tiles
+  (`ops/pallas/gated_delta.py`: the state in VMEM over a head's chunks,
+  the inverse by products) takes it on a TPU, chosen at trace time from
+  the operands' shape with the reason on record; any other shape, and
+  every backend that is not a TPU, takes
+  :func:`xla_gated_delta_prefill`, the `jax.numpy` form: everything
+  that does not read the state batched over the chunks, a scan that
+  carries the `[H, Dk, Dv]` state and four products a chunk.
 
 A masked token (padding) has `beta = 0`, `g = 0`, `k = v = 0`: it
 neither writes nor decays, so padding on either side leaves the state
@@ -95,13 +101,39 @@ def gated_delta_decode(q, k, v, g, beta, state,
 def gated_delta_prefill(q, k, v, g, beta, state,
                         mask: Optional[jax.Array] = None,
                         chunk: int = DEFAULT_CHUNK):
-    """A window of tokens onto a state. q, k: `[B, S, H, Dk]`; v: `[B,
-    S, H, Dv]`; g, beta: `[B, S, H]`; state: `[B, H, Dk, Dv]` float32;
-    `mask`: `[B, S]`, 0 on padding (either side), or None. Returns
-    (`[B, S, H, Dv]` in v's dtype, the state after the window's valid
-    tokens). A padded query's output is unspecified."""
-    batch, seq, heads, dk = q.shape
-    dv = v.shape[-1]
+    """A window of tokens onto a state. q, k: `[B, S, Hk, Dk]`; v: `[B,
+    S, H, Dv]`, `Hk` dividing `H` (value head `h` reads key head `h //
+    (H // Hk)`, as `jnp.repeat` lays them); g, beta: `[B, S, H]`; state:
+    `[B, H, Dk, Dv]` float32; `mask`: `[B, S]`, 0 on padding (either
+    side), or None. Returns (`[B, S, H, Dv]` in v's dtype, the state
+    after the window's valid tokens). A padded query's output is
+    unspecified. The window's shape picks the path
+    (`ops.pallas.gated_delta._ineligible_reason`): the Mosaic chunk
+    kernel where it tiles, else :func:`xla_gated_delta_prefill` in
+    chunks of `chunk` (the kernel's chunk is its module's constant; the
+    result depends on neither)."""
+    # here, not at the top: the registry imports this module's xla form
+    from fengshen_tpu.ops.pallas import gated_delta as kernel
+    from fengshen_tpu.ops.pallas import resolve_dispatch
+    impl = resolve_dispatch(
+        "gated_delta_prefill",
+        f"q={tuple(q.shape)}:{q.dtype.name} v={tuple(v.shape)}:"
+        f"{v.dtype.name}", kernel._ineligible_reason(q, v))
+    if impl == "pallas":
+        return kernel.pallas_gated_delta_prefill(q, k, v, g, beta, state,
+                                                 mask)
+    return xla_gated_delta_prefill(q, k, v, g, beta, state, mask, chunk)
+
+
+def xla_gated_delta_prefill(q, k, v, g, beta, state,
+                            mask: Optional[jax.Array] = None,
+                            chunk: int = DEFAULT_CHUNK):
+    """:func:`gated_delta_prefill` in `jax.numpy`: the CPU tier-1 truth
+    and the kernel's xla twin, the same arguments and results."""
+    batch, seq, heads, dv = v.shape
+    dk = q.shape[-1]
+    if q.shape[2] != heads:
+        q, k = (jnp.repeat(x, heads // x.shape[2], axis=2) for x in (q, k))
     c = min(chunk, seq)
     pad = -seq % c
     if mask is None:

@@ -258,6 +258,7 @@ def run_per_shard(kernel: Callable, q, k, v, *per_token):
 
 from fengshen_tpu.ops.flash_attention import blockwise_attention  # noqa: E402
 from fengshen_tpu.ops.gated_attention import folded_decode_walk  # noqa: E402
+from fengshen_tpu.ops.gated_delta import xla_gated_delta_prefill  # noqa: E402
 # aliased: binding the bare function name here would shadow the
 # `ops.pallas.block_sparse_attention` SUBMODULE attribute that
 # `import fengshen_tpu.ops.pallas.block_sparse_attention as bsa` resolves
@@ -270,6 +271,8 @@ from fengshen_tpu.ops.pallas.flash_attention import (  # noqa: E402
     pallas_flash_attention)
 from fengshen_tpu.ops.pallas.fused_ce import (  # noqa: E402
     fused_ce_loss, pallas_fused_ce, xla_fused_ce)
+from fengshen_tpu.ops.pallas.gated_delta import (  # noqa: E402
+    pallas_gated_delta_prefill)
 
 register_kernel("flash_attention", "pallas", pallas_flash_attention)
 register_kernel("flash_attention", "xla", blockwise_attention)
@@ -283,6 +286,11 @@ register_kernel("decode_attention", "xla", xla_decode_attention)
 register_kernel("folded_decode_attention", "pallas",
                 pallas_folded_decode_attention)
 register_kernel("folded_decode_attention", "xla", folded_decode_walk)
+# the chunked gated delta rule of a prefill window (the seam is
+# `ops.gated_delta.gated_delta_prefill`); its xla lowering the
+# `jax.numpy` chunked form
+register_kernel("gated_delta_prefill", "pallas", pallas_gated_delta_prefill)
+register_kernel("gated_delta_prefill", "xla", xla_gated_delta_prefill)
 register_kernel("fused_ce", "pallas", pallas_fused_ce)
 register_kernel("fused_ce", "xla", xla_fused_ce)
 
