@@ -85,6 +85,8 @@ MODEL_REGISTRY: dict[str, tuple[str, str, dict[str, str]]] = {
     "transfo-xl-reasoning": ("fengshen_tpu.models.transfo_xl_reasoning",
                              "TransfoXLReasoningConfig",
                              {"base": "TransfoXLReasoningModel"}),
+    "KeyeVL2": ("fengshen_tpu.models.keye", "KeyeConfig",
+                {"causal_lm": "KeyeForCausalLM", "base": "KeyeModel"}),
 }
 
 

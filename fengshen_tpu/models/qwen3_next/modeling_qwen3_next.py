@@ -254,11 +254,11 @@ class GatedAttention(nn.Module):
                 q, k.reshape(batch, seq, G * D), v.reshape(batch, seq, G * D),
                 jnp.int32(0), scale=scale)
         elif seq == 1:
-            cache = _write_rows(cache, k, v, layer)
+            cache = _write_rows(cache, layer, k=k, v=v)
             out = self._tick(q, cache, layer, scale)
         else:
             _no_window_on_a_pool(cache, seq)
-            cache = _write_rows(cache, k, v, layer)
+            cache = _write_rows(cache, layer, k=k, v=v)
             lane = lambda x: x[layer].reshape(  # noqa: E731
                 batch, -1, G * D)
             out = folded_prefill_walk(q, lane(cache.k), lane(cache.v),

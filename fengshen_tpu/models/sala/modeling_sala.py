@@ -202,32 +202,34 @@ class SalaLinearAttention(_Projections):
         return self.gated_out(out, hidden), cache
 
 
-def _write_rows(cache: SalaCache, k, v, layer: int):
-    """This step's K/V rows `[B, S, G, D]`, each token's heads folded
-    into one row, into layer `layer` of the stacks at each lane's
-    cursor, in place: one slice update on a
-    contiguous cache with a scalar cursor, else one scatter into the
-    stack addressed flat (PERF.md, PR 25); paged lanes go through their
-    `block_table` row, free lanes park on the null block."""
-    batch, seq = k.shape[:2]
-    k, v = (x.reshape(batch, seq, 1, -1) for x in (k, v))
+def _write_rows(cache, layer: int, **rows):
+    """This step's rows — each `[B, S, ...]` under the name of the stack
+    it goes into (`k=`, `v=`), a token's heads folded into one row —
+    into layer `layer` of those stacks at each lane's cursor, in place:
+    one slice update a stack on a contiguous cache with a scalar cursor,
+    else one scatter into the stack addressed flat (PERF.md, PR 25);
+    paged lanes go through their `block_table` row, free lanes park on
+    the null block."""
+    batch, seq = next(iter(rows.values())).shape[:2]
+    rows = {name: x.reshape(batch, seq, 1, -1) for name, x in rows.items()}
     if cache.start.ndim == 0:
         at = (layer, 0, cache.start, 0, 0)
-        return cache._replace(
-            k=jax.lax.dynamic_update_slice(
-                cache.k, k[None].astype(cache.k.dtype), at),
-            v=jax.lax.dynamic_update_slice(
-                cache.v, v[None].astype(cache.v.dtype), at))
+        return cache._replace(**{
+            name: jax.lax.dynamic_update_slice(
+                getattr(cache, name),
+                x[None].astype(getattr(cache, name).dtype), at)
+            for name, x in rows.items()})
     pos = _flat_rows(cache, layer,
                      cache.start[:, None] + jnp.arange(seq)[None]
                      ).reshape(-1)
 
-    def put(pool, rows):
+    def put(pool, x):
         flat = pool.reshape((-1,) + pool.shape[3:])
         return flat.at[pos].set(
-            rows.reshape((batch * seq,) + rows.shape[2:]).astype(pool.dtype)
+            x.reshape((batch * seq,) + x.shape[2:]).astype(pool.dtype)
         ).reshape(pool.shape)
-    return cache._replace(k=put(cache.k, k), v=put(cache.v, v))
+    return cache._replace(**{name: put(getattr(cache, name), x)
+                             for name, x in rows.items()})
 
 
 def _flat_rows(cache: SalaCache, layer: int, p, stride: int = 1):
@@ -310,7 +312,7 @@ class SalaSparseAttention(_Projections):
         if cache is None:
             out = _uncached_sparse(q, k, v, spec)
         elif seq == 1:
-            cache = _append_pooled(_write_rows(cache, k, v, layer), layer,
+            cache = _append_pooled(_write_rows(cache, layer, k=k, v=v), layer,
                                    spec)
             out = self._tick(q, cache, layer, live)
         else:
@@ -319,7 +321,7 @@ class SalaSparseAttention(_Projections):
                     f"a window of {seq} tokens onto a pool of lanes: the "
                     "pooled keys take one token a lane a tick; prefill "
                     "runs on a contiguous batch-1 cache")
-            cache = _pool_window(_write_rows(cache, k, v, layer), layer,
+            cache = _pool_window(_write_rows(cache, layer, k=k, v=v), layer,
                                  seq, spec)
             lane = lambda x: x[layer].reshape(  # noqa: E731
                 batch, -1, G, D)

@@ -44,8 +44,9 @@ shape routes through (see fengshen_tpu/ops/pallas/__init__.py):
   behind the multiply — and nothing of the body: it slices each KV head
   out of the block as it lies, in the pool's dtype. Its xla lowering is
   ``ops/gated_attention.folded_decode_walk``.
-- :func:`mla_decode_attention`, :func:`sparse_decode_attention` — the
-  latent and the chosen-block entries, xla lowerings only.
+- :func:`mla_decode_attention`, :func:`sparse_decode_attention`,
+  :func:`indexed_decode_attention` — the latent, the chosen-block and
+  the chosen-token entries, xla lowerings only.
 
 Tiling (docs/kernels.md): the pallas path requires
 ``head_dim % 128 == 0``, ``block_size % 128 == 0`` and
@@ -637,6 +638,94 @@ def xla_sparse_decode_attention(q, pooled, k, v, block_table, t, spec, *,
     scores = jnp.where(ok[:, :, None], scores, _NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(vs.dtype)
     out = jnp.einsum("bgrk,bgkd->bgrd", probs, vs)
+    return out.reshape(batch, 1, heads, dim).astype(q.dtype)
+
+
+INDEXED_TRACE_NAME = "fstpu_indexed_decode_attention"
+
+#: why the indexed read takes the xla lowering today: the kernels above
+#: walk a lane's blocks in order; a chosen-TOKEN list is 2,048 rows
+#: scattered over them, a DMA a row
+_NO_INDEXED_KERNEL = "no Mosaic kernel reads a dynamic token list yet"
+
+
+def indexed_decode_attention(q: jax.Array, qi: jax.Array, w: jax.Array,
+                             index_keys: jax.Array, k: jax.Array,
+                             v: jax.Array, block_table: jax.Array,
+                             t: jax.Array, *, topk: int, index_scale: float,
+                             layer: Optional[jax.Array] = None
+                             ) -> jax.Array:
+    """The seam's entry for a learned indexer (`ops/sparse_attention.py`
+    has the mathematics): one query a lane scores EVERY cached token of
+    the lane by the indexer's keys, chooses `topk` single tokens, and
+    attends over those rows only.
+
+    q: ``[B, 1, H, D]``; qi: ``[B, J, Di]`` and w: ``[B, J]``, the
+    indexer's queries and head weights; index_keys: ``[num_blocks,
+    block_size, 1, Di]`` and k/v: ``[num_blocks, block_size, 1, KVH *
+    D]`` (a token's KV heads folded into one row) behind ``block_table``
+    ``[B, max_blocks]`` — one table reads all three; with ``layer`` the
+    ``[L, ...]`` stacks, read in place as :func:`_layer_of_stack` reads
+    K/V. ``t``: ``[B]`` int32, each query's position (its context is
+    ``t + 1`` tokens, all of them read while that is within ``topk``).
+    Returns ``[B, 1, H, D]``."""
+    from fengshen_tpu.ops.pallas import resolve_dispatch
+    # recorded, not decided: the xla lowering is the only one there is
+    resolve_dispatch(
+        "indexed_decode_attention",
+        f"q={tuple(q.shape)} kv={tuple(k.shape[-4:])}:{k.dtype.name} "
+        f"topk={topk}", _NO_INDEXED_KERNEL)
+    return xla_indexed_decode_attention(
+        q, qi, w, index_keys, k, v, block_table, t, topk=topk,
+        index_scale=index_scale, layer=layer)
+
+
+def xla_indexed_decode_attention(q, qi, w, index_keys, k, v, block_table,
+                                 t, *, topk, index_scale, layer=None):
+    """The stock lowering and the CPU tier-1 truth: the lane's indexer
+    keys gathered a block at a time (128 B a token), the scores and the
+    choice under their own scopes, then ONE gather of the chosen rows —
+    a row is a token's whole K (or V), 1 KB — and dense attention over
+    them. A context within ``topk`` chooses every token it holds."""
+    from fengshen_tpu.ops.sparse_attention import (INDEX_SCORE_SCOPE,
+                                                   INDEX_TOPK_SCOPE,
+                                                   topk_tokens,
+                                                   weigh_heads)
+    batch, _, heads, dim = q.shape
+    if layer is not None:
+        # the stacks as one pool of `L * num_blocks` blocks; nothing
+        # else of a pool is reshaped: merging a token axis or splitting
+        # a row into heads re-lays the whole pool out on the chip
+        # (2.2 GB of copies a layer at this cell's size; PERF.md, PR 36)
+        num_blocks = k.shape[1]
+        k, v, index_keys = (x.reshape((-1,) + x.shape[2:])
+                            for x in (k, v, index_keys))
+        block_table = block_table + layer * num_blocks
+    block, groups = k.shape[1], k.shape[-1] // dim
+    lane_len = block_table.shape[-1] * block
+    top = min(topk, lane_len)
+    with jax.named_scope(INDEX_SCORE_SCOPE):
+        lane = jnp.take(index_keys, block_table, axis=0, mode="clip")
+        products = jnp.einsum("bjd,bmtd->bjmt", qi,
+                              lane[:, :, :, 0].astype(qi.dtype),
+                              preferred_element_type=jnp.float32)
+        scores = weigh_heads(products[:, None], w[:, None],
+                             index_scale).reshape(batch, lane_len)
+    with jax.named_scope(INDEX_TOPK_SCOPE):
+        cached = jnp.arange(lane_len)[None, :] <= t[:, None]
+        chosen, taken = topk_tokens(scores, cached, top)     # [B, K]
+    with jax.named_scope(INDEXED_TRACE_NAME):
+        rows = jnp.take_along_axis(block_table, chosen // block,
+                                   axis=-1) * block + chosen % block
+        ks, vs = (jnp.take(x.reshape(-1, x.shape[-1]), rows, axis=0,
+                           mode="clip").reshape(batch, top, groups, dim)
+                  for x in (k, v))
+        qg = q[:, 0].reshape(batch, groups, heads // groups, dim)
+        sc = jnp.einsum("bgrd,bkgd->bgrk", qg, ks,
+                        preferred_element_type=jnp.float32) * dim ** -0.5
+        sc = jnp.where(taken[:, None, None, :], sc, _NEG_INF)
+        probs = jax.nn.softmax(sc, axis=-1).astype(vs.dtype)
+        out = jnp.einsum("bgrk,bkgd->bgrd", probs, vs)
     return out.reshape(batch, 1, heads, dim).astype(q.dtype)
 
 

@@ -16,7 +16,8 @@ onto a fixed pool of `num_slots` KV-cache lanes:
   the windows before it filled (rows so far, state so far); no
   program's temporaries grow with the prompt. A cache that declares
   leaves defined on token positions (`paged_cache.positional_leaves`:
-  a recurrent state, pooled keys) takes every prompt that way;
+  a recurrent state, pooled keys, a learned indexer's keys) takes
+  every prompt that way;
 - decode: every tick runs ONE jitted step over all `num_slots` lanes —
   per-lane `cache_index` vectors (modeling_llama's vector-index path)
   let lanes sit at different write positions, so the step never
@@ -92,7 +93,8 @@ from fengshen_tpu.serving.buckets import DEFAULT_BUCKETS, BucketLadder
 from fengshen_tpu.serving.cache import (abstract_init, assign_slot,
                                         init_slot_cache, reset_free_slots,
                                         rollback_slots)
-from fengshen_tpu.serving.paged_cache import (BlockAllocator,
+from fengshen_tpu.serving.paged_cache import (INDEX_PREFIX,
+                                              BlockAllocator,
                                               assign_paged,
                                               assign_slot_quantized,
                                               blocks_for_tokens,
@@ -489,20 +491,23 @@ class ContinuousBatchingEngine:
         #: model's config states a share of them (`ops/moe.py`)
         self._experts_held = getattr(getattr(model, "config", None),
                                      "experts_held", None)
-        self._kv_bytes = sum(
-            leaf.nbytes for path, leaf in
-            jax.tree_util.tree_flatten_with_path(self._cache)[0]
-            if any(getattr(k, "key", "").startswith("cached_")
-                   for k in path))
-        #: bytes of per-lane state (a recurrent layer's) beside the rows
-        self._state_bytes = sum(
-            leaf.nbytes for path, leaf in
-            jax.tree_util.tree_flatten_with_path(self._cache)[0]
-            if any(getattr(k, "key", "").startswith("state_")
-                   for k in path))
+        def pool_bytes(*prefixes, but=()):
+            return sum(
+                leaf.nbytes for path, leaf in
+                jax.tree_util.tree_flatten_with_path(self._cache)[0]
+                if any(getattr(k, "key", "").startswith(prefixes) and
+                       not getattr(k, "key", "").startswith(but)
+                       for k in path))
+        self._kv_bytes = pool_bytes("cached_", but=INDEX_PREFIX)
+        #: bytes the pool holds beside the rows attention reads: per-lane
+        #: state (a recurrent layer's) and the rows a selection scores
+        self._state_bytes = pool_bytes("state_", INDEX_PREFIX)
         #: host arithmetic a sparse-attention model offers: tokens a
-        #: query with so many cached tokens reads (None: all of them)
+        #: query with so many cached tokens reads (None: all of them),
+        #: `attended_tokens` where it chooses pooled blocks,
+        #: `indexed_tokens` where a learned indexer chooses single tokens
         self._attended_tokens = getattr(model, "attended_tokens", None)
+        self._indexed_tokens = getattr(model, "indexed_tokens", None)
         self._history = jnp.zeros((S, L), jnp.int32)
         self._mask = jnp.zeros((S, L), jnp.int32)
         # each lane's next input token stays on the device: the decode
@@ -1406,6 +1411,10 @@ class ContinuousBatchingEngine:
             # is cached, from the cursors alone
             self.metrics.record_sparse(
                 int(self._attended_tokens(self._pos[lanes] + 1).sum()),
+                kv_tokens)
+        if self._indexed_tokens is not None:
+            self.metrics.record_index(
+                int(self._indexed_tokens(self._pos[lanes] + 1).sum()),
                 kv_tokens)
         # of the table rows the tick's attention is handed, the blocks
         # up to each lane's PHYSICAL cursor (bucket padding in, as the

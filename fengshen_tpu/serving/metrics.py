@@ -94,6 +94,15 @@ class EngineMetrics:
             "fstpu_sparse_tokens_cached_total",
             "per-tick sum over live lanes of the tokens cached when a "
             "sparse layer's query chose (cursor + 1)")
+        self._index_selected = r.counter(
+            "fstpu_index_tokens_selected_total",
+            "per-tick sum over live lanes of the tokens a learned "
+            "indexer leaves a layer's query to read (its topk; "
+            "everything while the context is within it)")
+        self._index_scored = r.counter(
+            "fstpu_index_tokens_scored_total",
+            "per-tick sum over live lanes of the cached tokens a "
+            "layer's indexer scored to choose them (cursor + 1)")
         self._kv_attended = r.counter(
             "fstpu_serving_kv_tokens_attended_total",
             "per-tick sum over active lanes of the real cached tokens "
@@ -197,8 +206,9 @@ class EngineMetrics:
             "fstpu_kv_cache_bytes", "device bytes of the KV pool")
         self._g_state_bytes = r.gauge(
             "fstpu_serving_state_bytes",
-            "device bytes of per-lane state (a recurrent layer's) the "
-            "pool holds beside its rows")
+            "device bytes the pool holds beside the rows attention "
+            "reads: per-lane state (a recurrent layer's) and the rows "
+            "a selection scores (a learned indexer's keys)")
         self._g_kv_frag = r.gauge(
             "fstpu_kv_fragmentation",
             "unwritten fraction of allocated KV lane capacity")
@@ -246,6 +256,10 @@ class EngineMetrics:
     def record_sparse(self, attended: int, cached: int) -> None:
         self._sparse_attended.inc(attended)
         self._sparse_cached.inc(cached)
+
+    def record_index(self, selected: int, scored: int) -> None:
+        self._index_selected.inc(selected)
+        self._index_scored.inc(scored)
 
     def record_submit_lock_wait(self, seconds: float) -> None:
         self._submit_lock_wait.observe(seconds)

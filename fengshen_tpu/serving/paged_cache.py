@@ -36,7 +36,14 @@ byte budget into fixed-size blocks instead (the paged-attention idea):
   `[num_slots, ...]`, an assignment copies the primed one into its
   lane, and a release zeroes nothing because the next assignment
   overwrites it. A state has no null block: the decode program keeps a
-  dead lane's state by the tick's live mask (the model's `live`).
+  dead lane's state by the tick's live mask (the model's `live`);
+- a THIRD kind of row (`INDEX_PREFIX`): a `cached_index_*` leaf is not
+  attended over but SCORED to choose which K/V rows a query reads (a
+  learned indexer's key, 64 values a token beside the K/V row's 1,024):
+  one row a token behind the same table, written, assigned and released
+  as the K/V rows are. What it chooses is a list of the lane's token
+  positions, so a cache that declares one is positional
+  (`positional_leaves`).
 
 The int8 mode stores the pools as int8 with fp32 per-(token, head)
 absmax scales (`cached_key_scale`/`cached_value_scale`, the
@@ -142,6 +149,11 @@ def state_leaves(d: dict) -> list:
     return sorted(k for k in d if k.startswith("state_"))
 
 
+#: the row leaves a selection scores and attention does not read
+#: (module docstring)
+INDEX_PREFIX = "cached_index_"
+
+
 def _row_rate(d: dict, name: str) -> int:
     """Tokens a row of row leaf `name`: the longest row leaf holds one
     a token, a leaf `r` times shorter one every `r` tokens."""
@@ -151,16 +163,18 @@ def _row_rate(d: dict, name: str) -> int:
 
 def positional_leaves(cache) -> list:
     """The leaves of a cache tree that are defined on a lane's token
-    POSITIONS counted from 0 — a state, a row leaf of another rate —
-    so that a lane holding them is filled from position 0 and padded on
-    the right (a left-padded lane would shift every pooled window by
-    its pad). Empty for a cache of plain rows."""
+    POSITIONS counted from 0 — a state, a row leaf of another rate, a
+    row leaf a selection scores — so that a lane holding them is filled
+    from position 0 and padded on the right (a left-padded lane would
+    shift every pooled window, and every chosen position, by its pad).
+    Empty for a cache of plain rows."""
     found: list = []
 
     def look(d):
         rows = row_leaves(d)
         found.extend(state_leaves(d))
-        found.extend(n for n in rows if _row_rate(d, n) != 1)
+        found.extend(n for n in rows if _row_rate(d, n) != 1
+                     or n.startswith(INDEX_PREFIX))
         return d
     _map_attn_dicts(cache, look)
     return found

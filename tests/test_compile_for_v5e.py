@@ -498,3 +498,87 @@ def test_qwen3next_assign_and_tick_keep_pool_and_both_states_in_place(
               if re.match(r"\s*%?fstpu_gated_attention_decode[\w.]* = ", line)]
     assert len(kernel) == 1 and "tpu_custom_call" in kernel[0], kernel
     assert not re.search(r"\[8,1024,512\]|\[64,128,512\]", text)
+
+
+@pytest.fixture(scope="module")
+def keye_engine():
+    """The benchmark's Keye configuration at its full widths (4 layers,
+    all 128 experts, the whole vocabulary, 33,280 positions) behind the
+    engine, parameters as shapes, 4 lanes of 260 blocks instead of 16."""
+    import json
+    import os
+
+    from benchmarks.lib import manifest
+    from fengshen_tpu.serving import ContinuousBatchingEngine, EngineConfig
+    with open(os.path.join(manifest.ROOT, "benchmarks", "configs",
+                           "keye-vl-2.0-30b-a3b.json")) as f:
+        config = json.load(f)
+    model, cfg = manifest.family(config).build(config)
+    assert (cfg.hidden_size, cfg.head_dim, cfg.num_key_value_heads,
+            cfg.num_experts, cfg.index_heads, cfg.index_head_dim,
+            cfg.index_topk) == (2048, 128, 4, 128, 16, 64, 2048)
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    eng = ContinuousBatchingEngine(model, params, EngineConfig(
+        num_slots=4, buckets=(2048,), max_new_tokens=512,
+        kv_layout="paged", kv_block_size=128, kv_num_blocks=4 * 260 + 1,
+        kv_max_blocks_per_slot=260))
+    return eng, params
+
+
+def test_keye_tick_reads_three_kinds_of_row_in_place(one_chip,
+                                                     no_compile_cache,
+                                                     keye_engine):
+    """The decode tick over the paged pool of K, V and indexer-key
+    rows: no copy, transpose or slice of a K- or V-pool-shaped array (a
+    pool is addressed by its own axes: merging its token axis or
+    splitting a row into heads before the gather re-laid both K/V pools
+    out, 2.3 GB of temporaries at 16 lanes; PERF.md, PR 36), the
+    donated pool aliased to the returned one. Of the indexer keys' pool
+    exactly TWO copies a tick are known and left (the 64-wide leaf is
+    kept tokens-minor and re-laid out and back around the layers'
+    writes, once for all four; a scatter into it viewed flat paid that
+    every layer): a third would be a regression."""
+    eng, params = keye_engine
+    tree = eng._cache["model"]
+    assert tree["cached_key"].shape == (4, 1041, 128, 1, 512)
+    assert tree["cached_index_key"].shape == (4, 1041, 128, 1, 64)
+    held = {name: tree[name] for name in
+            ("cached_key", "cached_value", "cached_index_key")}
+    shapes = {leaf.shape for leaf in held.values()}
+    shapes |= {s[1:] for s in shapes}
+    nbytes = sum(leaf.nbytes for leaf in held.values())
+    tick = eng._decode_jit.lower(*_abstract(
+        (params, eng._cache, eng._history, eng._mask,
+         jnp.asarray(eng._last_tok), jnp.asarray(eng._pos),
+         jnp.asarray(eng._phys), jnp.asarray(eng._active), eng._keys),
+        one_chip)).compile()
+    copies = _big_copies(tick, shapes)
+    assert len(copies) == 2 and all("[4,1041,128,1,64]" in c
+                                    for c in copies), copies
+    mem = tick.memory_analysis()
+    assert mem.alias_size_in_bytes >= nbytes
+    # re-laid copies of the indexer keys' pool, 68 MB each at 4 lanes
+    assert mem.temp_size_in_bytes < 0.2e9              # 0.15 GB, PR 36
+
+
+def test_keye_window_program_scores_and_selects_in_tiles(
+        one_chip, no_compile_cache, keye_engine):
+    """A 2,048-token window onto the carried batch-1 cache of 33,280
+    rows: no `[2048, 33280]` plane of scores or of the mask (273 MB in
+    float32; a tile of 256 queries is the unit), the donated cache
+    aliased to the returned one."""
+    eng, params = keye_engine
+    args = _qwen3next_window_args(eng, params, one_chip)
+    compiled = eng._window_jit.lower(*args).compile()
+    wide = [line.strip()[:120] for line in compiled.as_text().splitlines()
+            if re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[[\d,]*(2048[\d,]*"
+                        r"33280|33280[\d,]*2048)", line)]
+    assert not wide, wide
+    cache = args[1]["model"]
+    assert cache["cached_index_key"].shape == (4, 1, 33280, 1, 64)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.0e9              # 0.65 GB, PR 36
+    held = sum(leaf.size * leaf.dtype.itemsize
+               for leaf in jax.tree_util.tree_leaves(cache))
+    assert mem.alias_size_in_bytes >= held
