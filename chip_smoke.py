@@ -9,7 +9,8 @@ process holds the chip for all three phases:
    Mosaic implementation compiled for real (never interpreted) at the
    shapes the next two phases use (the folded decode entry and the
    chunked gated delta rule, which no LLaMA shape reaches, at
-   Qwen3-Next's), against its registered xla twin;
+   Qwen3-Next's; the experts' grouped matmul at Keye's), against its
+   registered xla twin;
 2. train — `Trainer(args).fit(CausalLMModule, UniversalDataModule)` as
    every example builds them: a few optimizer steps at seq 2048, mesh
    over all visible devices, one `UniversalCheckpoint` save and restore;
@@ -333,6 +334,44 @@ def _gated_delta_cases(cfg, rows):
            want_state, tol=1e-4)
 
 
+def _grouped_matmul_cases(cfg, rows):
+    """The routed experts' products at Keye's published widths (tables
+    of 2048 x 768) over rows sorted by expert: groups of uneven size,
+    some empty, a tile that straddles several, and a last quarter of
+    the rows past the last group (a share's experts not held): the
+    Mosaic grouped matmul against three `ragged_dot`. The rows past the
+    last group are the kernel's zeros; `ragged_dot` leaves them
+    undefined, so they are not compared."""
+    del cfg
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from fengshen_tpu.ops.pallas import get_kernel
+    from fengshen_tpu.ops.pallas.grouped_matmul import _ineligible_reason
+    pallas = get_kernel("grouped_matmul", "pallas")
+    xla = get_kernel("grouped_matmul", "xla")
+    total, count, hidden, width = 4096, 32, 2048, 768
+    rng = np.random.RandomState(SEED + 6)
+    share = rng.dirichlet(np.full(count, 0.5)) * (rng.rand(count) > 0.2)
+    sizes = np.floor(share / share.sum() * total * 0.75).astype(np.int32)
+    held = int(sizes.sum())
+    ks = jax.random.split(jax.random.PRNGKey(SEED + 6), 4)
+    r = jax.random.normal(ks[0], (total, hidden), jnp.bfloat16)
+    g, u = (0.02 * jax.random.normal(k, (count, hidden, width),
+                                     jnp.bfloat16) for k in ks[1:3])
+    d = 0.02 * jax.random.normal(ks[3], (count, width, hidden),
+                                 jnp.bfloat16)
+    assert _ineligible_reason(r, g) is None
+    got = jax.jit(pallas)(r, g, u, d, jnp.asarray(sizes))
+    want = jax.jit(xla)(r, g, u, d, jnp.asarray(sizes))
+    assert not np.asarray(got[held:], np.float32).any()
+    _check(rows, "grouped_matmul",
+           f"rows={list(r.shape)} tables={list(g.shape)} bf16, {held} held "
+           f"in groups of {sizes.min()}..{sizes.max()}", got[:held],
+           want[:held])
+
+
 def _fused_ce_cases(cfg, rows):
     import jax
     import jax.numpy as jnp
@@ -400,6 +439,7 @@ KERNEL_CASES = {
     "decode_attention": _decode_cases,
     "folded_decode_attention": _folded_decode_cases,
     "gated_delta_prefill": _gated_delta_cases,
+    "grouped_matmul": _grouped_matmul_cases,
     "fused_ce": _fused_ce_cases,
     "block_sparse_attention": _block_sparse_cases,
 }

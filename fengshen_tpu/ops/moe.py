@@ -85,6 +85,20 @@ def route(scores: jax.Array, bias: Optional[jax.Array], top_k: int,
     return index.astype(jnp.int32), weight * scaling
 
 
+def xla_grouped_swiglu(rows: jax.Array, w_gate: jax.Array,
+                       w_up: jax.Array, w_down: jax.Array,
+                       sizes: jax.Array) -> jax.Array:
+    """`down(silu(gate(r)) * up(r))` of each row through its group's
+    tables: `rows` `[assignments, hidden]` sorted by group, `sizes`
+    `[count]` int32 rows a group, the tables `[count, ...]`. Three
+    `jax.lax.ragged_dot` (on a TPU one native grouped matmul each);
+    rows past the last group are not to be trusted (zeros on the CPU,
+    undefined on the chip)."""
+    gate = jax.lax.ragged_dot(rows, w_gate, sizes)
+    up = jax.lax.ragged_dot(rows, w_up, sizes)
+    return jax.lax.ragged_dot(nn.silu(gate) * up, w_down, sizes)
+
+
 def grouped_swiglu(x: jax.Array, index: jax.Array, weight: jax.Array,
                    w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
                    first: int = 0) -> jax.Array:
@@ -93,10 +107,17 @@ def grouped_swiglu(x: jax.Array, index: jax.Array, weight: jax.Array,
 
     The `T * top_k` assignments are sorted by expert (stable, so each
     expert sees its tokens in token order), the rows gathered once, and
-    the three SwiGLU products run as `jax.lax.ragged_dot` over the
-    group sizes — on a TPU one native grouped matmul each. Assignments
-    to experts not held sort past the last group; their rows are zeroed
+    the three SwiGLU products run over the group sizes. The call's
+    shape picks how (`ops.pallas.grouped_matmul._ineligible_reason`):
+    with many rows an expert (a prefill window) the Mosaic grouped
+    matmul that reads each touched table once, else
+    :func:`xla_grouped_swiglu` (a decode tick's row or two an expert,
+    any call under a device mesh or off the TPU). Assignments to
+    experts not held sort past the last group; their rows are zeroed
     rather than trusted."""
+    # here, not at the top: the registry imports this module's xla form
+    from fengshen_tpu.ops.pallas import grouped_matmul as kernel
+    from fengshen_tpu.ops.pallas import resolve_dispatch
     tokens, top_k = index.shape
     count = w_gate.shape[0]
     local = index.reshape(-1) - first
@@ -105,15 +126,22 @@ def grouped_swiglu(x: jax.Array, index: jax.Array, weight: jax.Array,
     order = jnp.argsort(local, stable=True)
     sizes = jnp.bincount(local, length=count + 1)[:count].astype(jnp.int32)
     rows = x[order // top_k]
-    gate = jax.lax.ragged_dot(rows, w_gate, sizes)
-    up = jax.lax.ragged_dot(rows, w_up, sizes)
-    out = jax.lax.ragged_dot(nn.silu(gate) * up, w_down, sizes)
-    scale = weight.reshape(-1)[order][:, None]
-    out = jnp.where(held[order][:, None],
-                    out.astype(jnp.float32) * scale, 0.0)
-    # back to assignment order, then the top_k of a token are adjacent
-    out = out[jnp.argsort(order)]
-    return out.reshape(tokens, top_k, -1).sum(axis=1)
+    impl = resolve_dispatch(
+        "grouped_matmul",
+        f"rows={tuple(rows.shape)}:{rows.dtype.name} "
+        f"tables={tuple(w_gate.shape)}:{w_gate.dtype.name}",
+        kernel._ineligible_reason(rows, w_gate))
+    products = kernel.pallas_grouped_swiglu if impl == "pallas" \
+        else xla_grouped_swiglu
+    out = products(rows, w_gate, w_up, w_down, sizes)
+    # back to the tokens, pick-major: ONE gather of the rows in the
+    # products' own dtype into `[top_k, T, H]` (a pick a slab: no
+    # `[T, top_k]` tile to re-lay where top_k is not whole sublanes),
+    # then weight, mask and sum over the picks in one pass
+    place = jnp.argsort(order).reshape(tokens, top_k).T
+    picked, held = out[place], held.reshape(tokens, top_k)
+    return sum(jnp.where(held[:, k, None], picked[k].astype(jnp.float32) *
+                         weight[:, k, None], 0.0) for k in range(top_k))
 
 
 class SwiGLU(nn.Module):

@@ -259,6 +259,7 @@ def run_per_shard(kernel: Callable, q, k, v, *per_token):
 from fengshen_tpu.ops.flash_attention import blockwise_attention  # noqa: E402
 from fengshen_tpu.ops.gated_attention import folded_decode_walk  # noqa: E402
 from fengshen_tpu.ops.gated_delta import xla_gated_delta_prefill  # noqa: E402
+from fengshen_tpu.ops.moe import xla_grouped_swiglu  # noqa: E402
 # aliased: binding the bare function name here would shadow the
 # `ops.pallas.block_sparse_attention` SUBMODULE attribute that
 # `import fengshen_tpu.ops.pallas.block_sparse_attention as bsa` resolves
@@ -273,6 +274,8 @@ from fengshen_tpu.ops.pallas.fused_ce import (  # noqa: E402
     fused_ce_loss, pallas_fused_ce, xla_fused_ce)
 from fengshen_tpu.ops.pallas.gated_delta import (  # noqa: E402
     pallas_gated_delta_prefill)
+from fengshen_tpu.ops.pallas.grouped_matmul import (  # noqa: E402
+    pallas_grouped_swiglu)
 
 register_kernel("flash_attention", "pallas", pallas_flash_attention)
 register_kernel("flash_attention", "xla", blockwise_attention)
@@ -291,6 +294,11 @@ register_kernel("folded_decode_attention", "xla", folded_decode_walk)
 # `jax.numpy` chunked form
 register_kernel("gated_delta_prefill", "pallas", pallas_gated_delta_prefill)
 register_kernel("gated_delta_prefill", "xla", xla_gated_delta_prefill)
+# the routed experts' three products over sorted rows (the seam is
+# `ops.moe.grouped_swiglu`, by rows an expert); its xla lowering three
+# `jax.lax.ragged_dot`
+register_kernel("grouped_matmul", "pallas", pallas_grouped_swiglu)
+register_kernel("grouped_matmul", "xla", xla_grouped_swiglu)
 register_kernel("fused_ce", "pallas", pallas_fused_ce)
 register_kernel("fused_ce", "xla", xla_fused_ce)
 

@@ -172,6 +172,54 @@ def test_routed_experts_compile_to_the_grouped_matmul_at_published_widths(
     assert mem.temp_size_in_bytes < 64 * 2 ** 20      # no [T, E, C]
 
 
+@pytest.mark.parametrize("tokens,top_k,count,width", [
+    (2048, 8, 128, 768), (2048, 10, 256, 512), (2048, 8, 256, 768)],
+    ids=["keye_window", "qwen3next_window", "joyai_2048"])
+def test_routed_experts_over_a_window_compile_to_the_mosaic_grouped_matmul(
+        one_chip, no_compile_cache, monkeypatch, tokens, top_k, count,
+        width):
+    """A prefill window's 2,048 tokens x top-k picks over the experts
+    held, at the published widths (Keye 128 tables of 2048 x 768,
+    Qwen3-Next 256 of 512 held at 2048 x 512, JoyAI's 2,048 bucket over
+    256 of 2048 x 768): through the seam the three products are two
+    Mosaic calls named `fstpu_moe_experts...` and no `ragged-dot`; two
+    slots of gate and up fit the kernel's VMEM and every tile is
+    aligned, or the compiler refuses here; the program's operations are
+    those of each row through ONE expert, and it holds no `[tokens,
+    experts, ...]` tensor."""
+    import fengshen_tpu.ops.pallas as kernels
+    from fengshen_tpu.ops.moe import EXPERTS_SCOPE, grouped_swiglu
+    monkeypatch.setitem(kernels._PROBE_CACHE, ("cpu", None),
+                        kernels.KernelProbe("tpu", True, None,
+                                            "described v5e"))
+    hidden, bf16, sd = 2048, jnp.bfloat16, jax.ShapeDtypeStruct
+    args = (sd((tokens, hidden), bf16, sharding=one_chip),
+            sd((tokens, top_k), jnp.int32, sharding=one_chip),
+            sd((tokens, top_k), jnp.float32, sharding=one_chip),
+            sd((count, hidden, width), bf16, sharding=one_chip),
+            sd((count, hidden, width), bf16, sharding=one_chip),
+            sd((count, width, hidden), bf16, sharding=one_chip))
+    compiled = jax.jit(lambda *a: grouped_swiglu(*a)).lower(*args).compile()
+    took = kernels.traced_dispatch()[-1]
+    assert took["impl"] == "pallas" and \
+        f"rows=({tokens * top_k}, 2048)" in took["detail"], took
+    text = compiled.as_text()
+    calls = re.findall(r"%?(" + EXPERTS_SCOPE + r"[\w.\-]*) = [^\n]*"
+                       r"custom_call_target=\"tpu_custom_call\"", text)
+    assert len(calls) == 2, calls
+    assert "ragged-dot" not in text
+    once = 2 * tokens * top_k * hidden * width * 3
+    assert once <= compiled.cost_analysis()["flops"] < 3 * once
+    dispatch = [line.strip()[:120] for line in text.splitlines()
+                if re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[" +
+                            f"{tokens},{count}[,\\]]", line)]
+    assert not dispatch, dispatch
+    # the sorted rows, their products and the picks gathered back, all
+    # bf16 and rows-shaped; none of them experts wide
+    rows = tokens * top_k * hidden * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * rows
+
+
 def test_whole_prompt_prefill_holds_the_flash_call_and_no_scores_tensor(
         one_chip, no_compile_cache, monkeypatch):
     """The engine's 2048-bucket `prefill_fn` at Mistral-7B's attention
