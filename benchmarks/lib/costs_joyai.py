@@ -8,6 +8,13 @@ from __future__ import annotations
 
 #: bytes a value of a configuration's `program` dtype takes
 DTYPE_BYTES = {"bfloat16": 2, "float32": 4}
+#: the routed experts' own work on the trace: the scope (the sort, the
+#: rows' gather, the unsort and weighted sum) and the grouped matmuls by
+#: their own name: XLA:TPU lowers `jax.lax.ragged_dot` to a custom call
+#: `ragged-dot-none*` whose `op_name` it drops (my chip run, PR 26)
+EXPERT_SCOPES = ("fstpu_moe_experts", "%ragged-dot-none")
+#: the latent read's scope (xla lowering; no Mosaic kernel yet)
+MLA_DECODE_SCOPE = "fstpu_mla_decode_attention"
 
 
 def moe_expert_bytes(hidden: int, width: int, weight_bytes: int) -> int:

@@ -13,6 +13,17 @@ from __future__ import annotations
 # whatever the program's dtype, as the linear layers' of `costs_sala`
 from benchmarks.lib.costs_sala import DTYPE_BYTES, STATE_BYTES
 
+#: the device scopes of the two mixers (fengshen_tpu/ops)
+MIXER_SCOPES = ("fstpu_gated_delta_prefill", "fstpu_gated_delta_decode",
+                "fstpu_short_conv", "fstpu_gated_attention_decode",
+                "fstpu_gated_attention_prefill")
+#: the experts' scopes, and the grouped matmuls by their own name
+#: (XLA:TPU drops the `op_name` of a `ragged-dot` call)
+MOE_SCOPES = ("fstpu_moe_route", "fstpu_moe_experts", "fstpu_moe_shared",
+              "%ragged-dot")
+#: what of them is the routed experts' own work
+EXPERT_SCOPES = ("fstpu_moe_experts", "%ragged-dot")
+
 
 def layer_counts(cfg: dict) -> tuple:
     """(full layers, linear layers) of a configuration."""

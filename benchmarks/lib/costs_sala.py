@@ -12,6 +12,11 @@ from __future__ import annotations
 DTYPE_BYTES = {"bfloat16": 2, "float32": 4}
 #: the linear layers' state is float32 whatever the program's dtype
 STATE_BYTES = 4
+#: the device scopes of the two mixers (fengshen_tpu/ops)
+MIXER_SCOPES = ("fstpu_lightning_prefill", "fstpu_lightning_decode",
+                "fstpu_sparse_pool", "fstpu_sparse_select",
+                "fstpu_sparse_decode_attention",
+                "fstpu_sparse_prefill_attention")
 
 
 def layer_counts(cfg: dict) -> tuple:

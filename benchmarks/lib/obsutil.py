@@ -3,8 +3,6 @@ to read returns None and the harness leaves the metric out."""
 
 from __future__ import annotations
 
-import statistics
-
 from benchmarks.lib import reduce as R
 from benchmarks.lib import xplane
 
@@ -23,15 +21,6 @@ def idle_share(obs):
         return None
     trace, lo, hi = t
     return 100.0 * (1.0 - xplane.busy_seconds(trace, lo, hi) / (hi - lo))
-
-
-def device_ms_under(obs, span: str):
-    t = traced(obs)
-    if t is None:
-        return None
-    trace, lo, hi = t
-    per = xplane.device_seconds_under(trace, span, lo, hi)
-    return 1e3 * statistics.median(per) if per else None
 
 
 def counter_delta(obs, name: str):

@@ -10,16 +10,16 @@ keeps, for device 0's operations, every string it finds:
 
     [[text, start_s, dur_s], ...]
 
-on the trace's own clock, as in `lib.xplane`. A reader asks for the
-busy seconds, inside a host span, of the operations whose text holds a
-scope's name. The harness keeps no path, so the file is found where it
-wrote it: `.bench_run/<cell>/trace` (as `lib.xplane_attrs` does).
+on the trace's own clock, as in `lib.xplane`. `lib.trace_lines` sums the
+seconds of the operations whose text holds a scope's name, over the
+traced window or inside one program's runs. The harness keeps no path,
+so the file is found where it wrote it: `.bench_run/<cell>/trace` (as
+`lib.xplane_attrs` does).
 """
 
 from __future__ import annotations
 
 import os
-import statistics
 
 from benchmarks.lib import manifest, obsutil, xplane, xplane_meta
 
@@ -61,24 +61,3 @@ def of(obs: dict):
                 pass
     return obs["scope_ops"]
 
-
-def seconds_per_span(obs: dict, scope, span: str):
-    """Mean, over the traced host spans `span` that hold any, of the
-    device seconds of the operations under `scope` inside the span (the
-    union of their intervals: a `while` and the operations of its body
-    lie on one line and may both carry the scope). `scope` is one
-    string or several: an operation counts if its text holds any. None
-    where the run has no trace, no such span or no such operation."""
-    t, ops = obsutil.traced(obs), of(obs)
-    if t is None or not ops:
-        return None
-    trace, lo, hi = t
-    marks = (scope,) if isinstance(scope, str) else tuple(scope)
-    under = sorted(([n, s, d] for n, s, d in ops
-                    if any(m in n for m in marks)), key=lambda e: e[1])
-    per = []
-    for a, b in xplane.spans(trace, span, lo, hi):
-        inside = [e for e in under if a <= e[1] and e[1] + e[2] <= b]
-        if inside:
-            per.append(sum(y - x for x, y in xplane.merged(inside, a, b)))
-    return statistics.mean(per) if per else None

@@ -1,5 +1,5 @@
-"""From the profiler's trace to device busy and idle time, time under a
-host span, time by operation and idle gaps by what the host was doing.
+"""From the profiler's trace to device busy and idle time, host spans,
+time by operation and idle gaps by what the host was doing.
 
 `load` turns an `.xplane.pb` into plain lists (seconds on the trace's
 own clock); everything else works on those lists, so the reduction can
@@ -106,15 +106,6 @@ def first_device(trace: dict) -> list:
 def spans(trace: dict, name: str, lo: float, hi: float) -> list:
     return [(s, s + d) for n, s, d in trace["host"]
             if n == name and s >= lo and s + d <= hi]
-
-
-def device_seconds_under(trace: dict, name: str, lo: float,
-                         hi: float) -> list:
-    """Device-busy seconds inside each host span `name` (the spans end
-    on a fetch of the result, so their device work lies inside)."""
-    ops = first_device(trace)
-    return [sum(b - a for a, b in merged(ops, s, e))
-            for s, e in spans(trace, name, lo, hi)]
 
 
 def op_seconds(trace: dict, pattern, lo: float, hi: float) -> float:

@@ -6,13 +6,13 @@ window index and real tokens; query `i` of a window at `start` reads
 the device seconds under the scope `fstpu_gated_attention_prefill`
 inside the window program's runs in the traced window, scaled to the
 windows whose spans were seen."""
-from benchmarks.lib import costs_qwen3next, trace_qwen3next, trace_sala
+from benchmarks.lib import costs_qwen3next, trace_lines
 
 
 def read(obs):
-    spans = trace_sala.window_spans(obs)
-    taken = trace_qwen3next.scope_seconds_in(
-        obs, "fstpu_gated_attention_prefill", trace_qwen3next.WINDOW)
+    spans = trace_lines.window_spans(obs)
+    taken = trace_lines.scope_seconds_in(
+        obs, "fstpu_gated_attention_prefill", trace_lines.WINDOW)
     if not spans or not taken or not taken[0]:
         return None
     cfg = obs["config"]

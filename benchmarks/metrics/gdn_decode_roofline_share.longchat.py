@@ -6,18 +6,17 @@ state in and out, a linear layer, over the published HBM bytes/s; the
 time taken a tick is the device seconds under the scopes
 `fstpu_gated_delta_decode` and `fstpu_short_conv` inside the decode
 program's runs in the traced window, over those runs."""
-from benchmarks.lib import costs_qwen3next, obsutil, trace_qwen3next
+from benchmarks.lib import costs_qwen3next, obsutil, trace_lines
 
 
 def read(obs):
     ticks = obsutil.counter_delta(obs, "fstpu_serving_decode_ticks_total")
     lanes = obsutil.counter_delta(
         obs, "fstpu_serving_occupied_slot_ticks_total")
-    taken = trace_qwen3next.scope_seconds_in(
+    taken = trace_lines.seconds_a_run(trace_lines.scope_seconds_in(
         obs, ("fstpu_gated_delta_decode", "fstpu_short_conv"),
-        trace_qwen3next.DECODE)
-    if not ticks or lanes is None or not taken or not taken[0]:
+        trace_lines.DECODE))
+    if not ticks or lanes is None or not taken:
         return None
     needed = costs_qwen3next.gdn_decode_bytes(lanes / ticks, obs["config"])
-    return 100.0 * needed / obs["peaks"]["hbm_bytes_per_s"] / \
-        (taken[0] / taken[1])
+    return 100.0 * needed / obs["peaks"]["hbm_bytes_per_s"] / taken

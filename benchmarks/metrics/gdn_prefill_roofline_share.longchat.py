@@ -7,14 +7,14 @@ the BYTES bind (40 ns a token a layer against 16 ns); the time taken is
 the device seconds under the scopes `fstpu_gated_delta_prefill` and
 `fstpu_short_conv` inside the window program's runs in the traced
 window, scaled to the windows whose spans were seen."""
-from benchmarks.lib import costs_qwen3next, trace_qwen3next, trace_sala
+from benchmarks.lib import costs_qwen3next, trace_lines
 
 
 def read(obs):
-    spans = trace_sala.window_spans(obs)
-    taken = trace_qwen3next.scope_seconds_in(
+    spans = trace_lines.window_spans(obs)
+    taken = trace_lines.scope_seconds_in(
         obs, ("fstpu_gated_delta_prefill", "fstpu_short_conv"),
-        trace_qwen3next.WINDOW)
+        trace_lines.WINDOW)
     if not spans or not taken or not taken[0]:
         return None
     needed, _ = costs_qwen3next.gdn_prefill_floor_s(

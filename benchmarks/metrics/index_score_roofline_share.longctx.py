@@ -7,13 +7,13 @@ FLOP a pair a layer) over the published bf16 peak; the time taken is the
 device seconds under the scope `fstpu_index_score` inside the window
 program's runs in the traced window, scaled to the windows whose spans
 were seen."""
-from benchmarks.lib import costs_keye, trace_qwen3next, trace_sala
+from benchmarks.lib import costs_keye, trace_lines
 
 
 def read(obs):
-    spans = trace_sala.window_spans(obs)
-    taken = trace_qwen3next.scope_seconds_in(
-        obs, "fstpu_index_score", trace_qwen3next.WINDOW)
+    spans = trace_lines.window_spans(obs)
+    taken = trace_lines.scope_seconds_in(
+        obs, "fstpu_index_score", trace_lines.WINDOW)
     if not spans or not taken or not taken[0]:
         return None
     cfg = obs["config"]

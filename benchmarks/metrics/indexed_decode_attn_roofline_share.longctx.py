@@ -8,7 +8,7 @@ bytes/s; the time taken a tick is the device seconds under the scopes
 `fstpu_indexed_decode_attention` (the lane's indexer keys gathered and
 scored, the choice, the chosen rows' gather, the attention) inside the
 decode program's runs in the traced window, over those runs."""
-from benchmarks.lib import costs_keye, obsutil, trace_qwen3next
+from benchmarks.lib import costs_keye, obsutil, trace_lines
 
 SCOPES = ("fstpu_index_score", "fstpu_index_topk",
           "fstpu_indexed_decode_attention")
@@ -19,12 +19,10 @@ def read(obs):
     selected = obsutil.counter_delta(
         obs, "fstpu_index_tokens_selected_total")
     scored = obsutil.counter_delta(obs, "fstpu_index_tokens_scored_total")
-    taken = trace_qwen3next.scope_seconds_in(obs, SCOPES,
-                                             trace_qwen3next.DECODE)
-    if not ticks or selected is None or scored is None or not taken \
-            or not taken[0]:
+    taken = trace_lines.seconds_a_run(trace_lines.scope_seconds_in(
+        obs, SCOPES, trace_lines.DECODE))
+    if not ticks or selected is None or scored is None or not taken:
         return None
     needed = costs_keye.indexed_decode_bytes(
         selected / ticks, scored / ticks, obs["config"])
-    return 100.0 * needed / obs["peaks"]["hbm_bytes_per_s"] / \
-        (taken[0] / taken[1])
+    return 100.0 * needed / obs["peaks"]["hbm_bytes_per_s"] / taken

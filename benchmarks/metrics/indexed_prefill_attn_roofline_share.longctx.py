@@ -6,13 +6,13 @@ tokens, 4 x 32 x 128 FLOP a token a layer) over the published bf16
 peak; the time taken is the device seconds under the scope
 `fstpu_indexed_prefill_attention` inside the window program's runs in
 the traced window, scaled to the windows whose spans were seen."""
-from benchmarks.lib import costs_keye, trace_qwen3next, trace_sala
+from benchmarks.lib import costs_keye, trace_lines
 
 
 def read(obs):
-    spans = trace_sala.window_spans(obs)
-    taken = trace_qwen3next.scope_seconds_in(
-        obs, "fstpu_indexed_prefill_attention", trace_qwen3next.WINDOW)
+    spans = trace_lines.window_spans(obs)
+    taken = trace_lines.scope_seconds_in(
+        obs, "fstpu_indexed_prefill_attention", trace_lines.WINDOW)
     if not spans or not taken or not taken[0]:
         return None
     cfg = obs["config"]

@@ -3,8 +3,8 @@ prefill and decode forms, the short convolution, the full layer's
 decode and windowed reads) over the device's busy seconds, in the
 traced window: how much of the chip the new mixers are. The rest is
 projections, experts, the head."""
-from benchmarks.lib import trace_qwen3next, trace_sala
+from benchmarks.lib import costs_qwen3next, trace_lines
 
 
 def read(obs):
-    return trace_sala.share_of_busy(obs, trace_qwen3next.MIXER_SCOPES)
+    return trace_lines.share_of_busy(obs, costs_qwen3next.MIXER_SCOPES)

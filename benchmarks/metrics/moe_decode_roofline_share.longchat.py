@@ -9,16 +9,15 @@ unsort and weighted sum) and of the grouped matmuls themselves
 (`ragged-dot*` custom calls, matched by name: XLA:TPU drops their
 `op_name`) inside the decode program's runs in the traced window, over
 those runs."""
-from benchmarks.lib import costs_qwen3next, obsutil, trace_qwen3next
+from benchmarks.lib import costs_qwen3next, obsutil, trace_lines
 
 
 def read(obs):
     ticks = obsutil.counter_delta(obs, "fstpu_serving_decode_ticks_total")
     touched = obsutil.counter_delta(obs, "fstpu_moe_experts_touched_total")
-    taken = trace_qwen3next.scope_seconds_in(
-        obs, trace_qwen3next.EXPERT_SCOPES, trace_qwen3next.DECODE)
-    if not ticks or touched is None or not taken or not taken[0]:
+    taken = trace_lines.seconds_a_run(trace_lines.scope_seconds_in(
+        obs, costs_qwen3next.EXPERT_SCOPES, trace_lines.DECODE))
+    if not ticks or touched is None or not taken:
         return None
     needed = costs_qwen3next.moe_decode_bytes(touched / ticks, obs["config"])
-    return 100.0 * needed / obs["peaks"]["hbm_bytes_per_s"] / \
-        (taken[0] / taken[1])
+    return 100.0 * needed / obs["peaks"]["hbm_bytes_per_s"] / taken

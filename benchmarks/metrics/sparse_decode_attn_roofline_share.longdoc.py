@@ -6,7 +6,7 @@ time taken a tick is the device seconds under the scope
 `fstpu_sparse_decode_attention` (the pooled keys' gather and the choice
 inside it, the chosen slabs' gather, the attention) over the traced
 window, over the decode program's runs in it."""
-from benchmarks.lib import costs_sala, obsutil, trace_sala
+from benchmarks.lib import costs_sala, obsutil, trace_lines
 
 
 def read(obs):
@@ -14,8 +14,8 @@ def read(obs):
     attended = obsutil.counter_delta(
         obs, "fstpu_sparse_tokens_attended_total")
     cached = obsutil.counter_delta(obs, "fstpu_sparse_tokens_cached_total")
-    taken = trace_sala.scope_seconds(obs, "fstpu_sparse_decode_attention")
-    runs = trace_sala.module_runs(obs, trace_sala.DECODE)
+    taken = trace_lines.scope_seconds(obs, "fstpu_sparse_decode_attention")
+    runs = trace_lines.module_runs(obs, trace_lines.DECODE)
     if not ticks or attended is None or cached is None or not taken \
             or not runs:
         return None

@@ -7,13 +7,13 @@ bf16 peak; the time taken is the device seconds under the scope
 `fstpu_sparse_prefill_attention` over the traced window, scaled to the
 windows whose spans were seen (window program runs over spans: a span
 ends before its program does)."""
-from benchmarks.lib import costs_sala, trace_sala
+from benchmarks.lib import costs_sala, trace_lines
 
 
 def read(obs):
-    spans = trace_sala.window_spans(obs)
-    runs = trace_sala.module_runs(obs, trace_sala.WINDOW)
-    taken = trace_sala.scope_seconds(obs, "fstpu_sparse_prefill_attention")
+    spans = trace_lines.window_spans(obs)
+    runs = trace_lines.module_runs(obs, trace_lines.WINDOW)
+    taken = trace_lines.scope_seconds(obs, "fstpu_sparse_prefill_attention")
     if not spans or not runs or not taken:
         return None
     cfg = obs["config"]

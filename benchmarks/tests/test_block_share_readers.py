@@ -7,7 +7,7 @@ from benchmarks.lib import manifest
 
 LIVE = "fstpu_serving_kv_blocks_live_total"
 TABLED = "fstpu_serving_kv_blocks_tabled_total"
-NAMES = ["decode_live_block_share.chat", "decode_live_block_share.doc"]
+NAMES = ["decode_live_block_share.chat", "decode_live_block_share.serve"]
 
 
 def read(name, obs):
@@ -26,6 +26,12 @@ def test_live_over_tabled_blocks_from_counter_deltas(name):
     # every lane full to its row's end
     obs["stats_close"][LIVE] = 33000.0
     assert read(name, obs) == pytest.approx(100.0)
+    # 100 ticks of 64 lanes on a table of 144; 63 lanes of 60 blocks
+    # and one released lane's null block a tick (the folded kernel)
+    obs = {"stats_open": {LIVE: 500.0, TABLED: 1000.0},
+           "stats_close": {LIVE: 500.0 + 100 * (63 * 60 + 1),
+                           TABLED: 1000.0 + 100 * 64 * 144}}
+    assert read(name, obs) == pytest.approx(100.0 * 3781 / 9216)
 
 
 @pytest.mark.parametrize("name", NAMES)
@@ -42,12 +48,14 @@ def test_nothing_to_read_returns_none_and_does_not_raise(name):
                        "stats_close": {LIVE: 0.0, TABLED: 0.0}}) is None
 
 
-def test_both_are_declared_for_their_own_cell_only():
+def test_both_are_declared_for_the_cells_whose_kernel_walks_live_blocks():
     man = manifest.load()
     got = {m["name"]: m for m in man["per_layer"] if m["name"] in NAMES}
     assert sorted(got) == NAMES
     assert got[NAMES[0]]["workloads"] == ["mistral_chat_steady"]
-    assert got[NAMES[1]]["workloads"] == ["mistral_doc_saturated"]
+    # the paged decode kernel and the folded kernel (PR 31, PR 33)
+    assert got[NAMES[1]]["workloads"] == ["mistral_doc_saturated",
+                                          "qwen3next_longchat_saturated"]
     assert got[NAMES[0]]["moves"] == "gap_p50_ms"
     assert got[NAMES[1]]["moves"] == "serve_tokens_per_s"
     for m in got.values():

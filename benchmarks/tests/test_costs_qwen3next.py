@@ -1,12 +1,12 @@
 """`lib/costs_qwen3next.py` by hand arithmetic at the published widths,
-and `lib/trace_qwen3next.scope_seconds_in` on a small made-up trace."""
+and `lib/trace_lines.scope_seconds_in` on a small made-up trace."""
 
 import json
 import os
 import re
 
 from benchmarks.lib import costs_qwen3next as costs
-from benchmarks.lib import manifest, trace_qwen3next
+from benchmarks.lib import manifest, trace_lines
 
 with open(os.path.join(manifest.ROOT, "benchmarks", "configs",
                        "qwen3-next-80b-a3b.json")) as f:
@@ -69,14 +69,14 @@ def test_scope_seconds_inside_one_programs_runs():
                                         ["jit_window_fn(2)", 2.9, 1.0],
                                         ["jit_decode_fn(1)", 9.5, 1.0]]}}
     conv = ("fstpu_short_conv",)
-    got = trace_qwen3next.scope_seconds_in(obs, conv, trace_qwen3next.DECODE)
+    got = trace_lines.scope_seconds_in(obs, conv, trace_lines.DECODE)
     assert got[1] == 1 and abs(got[0] - 0.5) < 1e-9        # 1.0 .. 1.5
-    got = trace_qwen3next.scope_seconds_in(
-        obs, conv + ("%ragged-dot",), trace_qwen3next.DECODE)
+    got = trace_lines.scope_seconds_in(
+        obs, conv + ("%ragged-dot",), trace_lines.DECODE)
     assert abs(got[0] - 0.6) < 1e-9                        # 1.0 .. 1.6
-    got = trace_qwen3next.scope_seconds_in(obs, conv, trace_qwen3next.WINDOW)
+    got = trace_lines.scope_seconds_in(obs, conv, trace_lines.WINDOW)
     assert got[1] == 1 and abs(got[0] - 0.5) < 1e-9
-    assert trace_qwen3next.scope_seconds_in(
-        obs, "fstpu_nothing", trace_qwen3next.DECODE) is None
-    assert trace_qwen3next.scope_seconds_in(
+    assert trace_lines.scope_seconds_in(
+        obs, "fstpu_nothing", trace_lines.DECODE) is None
+    assert trace_lines.scope_seconds_in(
         obs, conv, re.compile("jit_assign_fn")) is None

@@ -66,7 +66,10 @@ def test_every_cells_files_exist_and_every_metric_has_a_reader(cell):
     assert entry["source"] == config["source"]
     assert sorted(entry["reduced"]) == sorted(config["reduced"])
     for key in config["reduced"]:
-        assert not key.endswith(("_dim", "_rank", "_size"))
+        # no width is cut; `vocab_size` is no width: a share of an
+        # expert- or tensor-parallel group holds a slice of the table
+        assert not key.endswith(("_dim", "_rank"))
+        assert not key.endswith("_size") or key == "vocab_size"
         assert config["published"][key] != config[key]
     assert manifest.job(config).run and manifest.family(config).build
     mix = traffic.load_mix(w["traffic"])
@@ -77,9 +80,60 @@ def test_every_cells_files_exist_and_every_metric_has_a_reader(cell):
     assert "setup_s" in names and len(names) >= 2 and per
     for m in e2e + per:
         assert callable(manifest.reader(m["name"]))
-    assert config["job"] in ("train_fit", "serve_http")
+    # a job kind is a file of its own under `lib/jobs`
+    assert os.path.exists(os.path.join(
+        manifest.BENCH, "lib", "jobs", config["job"] + ".py"))
     for m in per:       # a per-layer metric moves one metric of THIS cell
         assert m["moves"] in names
+
+
+def _reader_files() -> dict:
+    folder = os.path.join(manifest.BENCH, "metrics")
+    return {f[:-3]: os.path.join(folder, f) for f in os.listdir(folder)
+            if f.endswith(".py")}
+
+
+def test_every_entry_has_its_reader_and_every_reader_its_entry():
+    """One entry a metric, one file an entry: a retired entry takes its
+    file with it and a file without an entry measures nothing."""
+    entries = [m["name"] for m in MAN["end_to_end"] + MAN["per_layer"]]
+    assert sorted(entries) == sorted(_reader_files())
+
+
+def test_every_entry_lists_cells_that_exist_or_follows_every_cell():
+    """A per-layer entry names the cells in which its reader finds
+    something to read; only the set-up entries follow every cell,
+    those a later PR adds too."""
+    cells = {w["name"] for w in MAN["workloads"]}
+    for m in MAN["per_layer"]:
+        if "workloads" not in m:
+            assert m["moves"] == "setup_s", m["name"]
+            continue
+        assert m["workloads"] and set(m["workloads"]) <= cells, m["name"]
+        assert len(set(m["workloads"])) == len(m["workloads"]), m["name"]
+
+
+def test_no_reader_is_a_delegation_to_another_entrys_file():
+    """What two entries share is a function under `lib/`; a reader that
+    loads another entry's file makes that entry one nobody may retire."""
+    for name, path in _reader_files().items():
+        with open(path) as f:
+            text = f.read()
+        assert "manifest.reader" not in text, name
+        assert "benchmarks.metrics" not in text, name
+        assert "def read(" in text or re.search(
+            r"^(read = |from benchmarks\.lib\.\w+ import \w+ as read\b)",
+            text, re.M), name
+
+
+def test_the_per_layer_list_has_room(capsys):
+    """The contract's limit; the free slots are printed for whoever
+    sizes the next cell (`pytest -s`)."""
+    limit = 128
+    assert len(MAN["per_layer"]) <= limit
+    with capsys.disabled():
+        print(f"\nper_layer: {len(MAN['per_layer'])} of {limit} entries, "
+              f"{limit - len(MAN['per_layer'])} free")
 
 
 def test_every_file_under_paths_is_named_from_the_allowed_characters():

@@ -13,7 +13,7 @@ import pytest
 
 from benchmarks import run
 from benchmarks.lib import check, device, manifest
-from benchmarks.tests import tiny
+from benchmarks.tests import expected, tiny
 
 MAN = manifest.load()
 CELLS = {
@@ -54,7 +54,8 @@ def test_cell_runs_end_to_end_and_is_correct(cell, trace):
     else:
         # readers of device time find no device plane on a CPU and
         # return nothing; the counters and host clocks are all there
-        assert set(result["metrics"]) <= {m["name"] for m in per}
+        assert expected.counters(MAN, cell) <= set(result["metrics"]) <= \
+            {m["name"] for m in per}
         assert {"compile_s", "compiles_in_window"} <= set(result["metrics"])
         assert result["metrics"]["compiles_in_window"]["value"] == 0
         assert {"busy_s", "window_s"} <= set(result["device"])

@@ -9,12 +9,15 @@ import pytest
 
 from benchmarks import run
 from benchmarks.lib import device, manifest
-from benchmarks.tests import tiny_joyai
+from benchmarks.tests import expected, tiny_joyai
 
 MAN = manifest.load()
 CELL = "joyai_reason_saturated"
-COUNTERS = {"lane_occupancy.reason", "moe_experts_touched_share.reason",
-            "moe_expert_load_max_over_mean.reason"}
+COUNTERS = expected.counters(MAN, CELL)
+OWN = {"moe_experts_touched_share.reason",
+       "moe_expert_load_max_over_mean.reason",
+       "moe_decode_roofline_share.reason",
+       "mla_decode_attn_roofline_share.reason"}
 
 
 @pytest.fixture(autouse=True)
@@ -42,18 +45,22 @@ def test_cell_runs_end_to_end_and_is_correct(trace):
     else:
         # readers of device time find no device plane on a CPU and
         # return nothing; the counters are all there
-        assert COUNTERS | {"compile_s", "compiles_in_window"} <= \
-            set(result["metrics"]) <= {m["name"] for m in per}
+        assert COUNTERS <= set(result["metrics"]) <= \
+            {m["name"] for m in per}
         got = {k: v["value"] for k, v in result["metrics"].items()}
         assert 0 < got["moe_experts_touched_share.reason"] <= 100
         assert 1 <= got["moe_expert_load_max_over_mean.reason"] <= 8
         assert got["compiles_in_window"] == 0
 
 
-def test_the_cell_reports_its_fifteen_metrics_and_the_accepted_ones():
+def test_the_cell_reports_the_common_entries_and_its_own():
     _, per = manifest.metrics_of(MAN, CELL)
     names = {m["name"] for m in per}
-    assert len([n for n in names if n.endswith(".reason")]) == 15
+    assert {n for n in names if n.endswith(".reason")} == OWN
+    # no Mosaic kernel walks live blocks here (the latent read is xla)
+    assert expected.common(MAN) - names == {"decode_live_block_share.serve"}
+    assert {"moe_experts_touched_share.reason", "lane_occupancy.serve",
+            "sched_taken_share.serve"} <= COUNTERS
     assert {"compile_s", "compiles_in_window", "runtime_start_s"} <= names
     for n in names:
         assert callable(manifest.reader(n))

@@ -9,7 +9,7 @@ import pytest
 
 from benchmarks import run
 from benchmarks.lib import device, manifest
-from benchmarks.tests import tiny_keye
+from benchmarks.tests import expected, tiny_keye
 
 MAN = manifest.load()
 CELL = "keye_longctx_saturated"
@@ -18,11 +18,7 @@ NEW = {"index_selected_share.longctx", "index_select_device_share.longctx",
        "indexed_prefill_attn_roofline_share.longctx",
        "indexed_decode_attn_roofline_share.longctx",
        "mixer_device_share.longctx"}
-COUNTERS = {"index_selected_share.longctx", "lane_occupancy.longchat",
-            "prefill_padding_share.longchat", "decode_ahead_share.longchat",
-            "deferred_admissions.longchat", "kv_blocks_peak_share.longchat",
-            "moe_experts_touched_share.longchat",
-            "moe_expert_load_max_over_mean.longchat"}
+COUNTERS = expected.counters(MAN, CELL)
 
 
 @pytest.fixture(autouse=True)
@@ -50,23 +46,27 @@ def test_cell_runs_end_to_end_and_is_correct(trace):
     else:
         # readers of device time find no device plane on a CPU and
         # return nothing; the counters are all there
-        assert COUNTERS | {"compile_s", "compiles_in_window"} <= \
-            set(result["metrics"]) <= {m["name"] for m in per}
+        assert COUNTERS <= set(result["metrics"]) <= \
+            {m["name"] for m in per}
         got = {k: v["value"] for k, v in result["metrics"].items()}
         # every prompt is past the tiny topk: 16 of 100-208 cached tokens
         assert 7 < got["index_selected_share.longctx"] < 17
         assert got["compiles_in_window"] == 0
-        assert got["deferred_admissions.longchat"] == 0
+        assert got["deferred_admissions.serve"] == 0
 
 
 def test_the_cell_reports_its_six_metrics_and_the_accepted_ones():
     _, per = manifest.metrics_of(MAN, CELL)
     names = {m["name"] for m in per}
     assert {n for n in names if n.endswith(".longctx")} == NEW
+    # the experts' entries are the `.longchat` ones (the same `ops/moe`)
     assert {"compile_s", "compiles_in_window", "runtime_start_s",
-            "device_idle_share.longchat", "hbm_peak_gb.longchat",
             "moe_decode_roofline_share.longchat",
-            "sched_cycle_ms.longchat"} <= names
+            "moe_experts_touched_share.longchat"} <= names
+    # no Mosaic kernel walks live blocks here (the indexed read is xla)
+    assert expected.common(MAN) - names == {"decode_live_block_share.serve"}
+    assert {"index_selected_share.longctx", "lane_occupancy.serve",
+            "sched_taken_share.serve"} <= COUNTERS
     for n in names:
         assert callable(manifest.reader(n))
     assert len(MAN["per_layer"]) <= 128

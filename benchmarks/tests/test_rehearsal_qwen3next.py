@@ -9,16 +9,11 @@ import pytest
 
 from benchmarks import run
 from benchmarks.lib import device, manifest
-from benchmarks.tests import tiny_qwen3next
+from benchmarks.tests import expected, tiny_qwen3next
 
 MAN = manifest.load()
 CELL = "qwen3next_longchat_saturated"
-COUNTERS = {"lane_occupancy.longchat", "prefill_padding_share.longchat",
-            "decode_ahead_share.longchat", "deferred_admissions.longchat",
-            "kv_blocks_peak_share.longchat",
-            "moe_held_assignment_share.longchat",
-            "moe_experts_touched_share.longchat",
-            "moe_expert_load_max_over_mean.longchat"}
+COUNTERS = expected.counters(MAN, CELL)
 
 
 @pytest.fixture(autouse=True)
@@ -47,20 +42,26 @@ def test_cell_runs_end_to_end_and_is_correct(trace):
     else:
         # readers of device time find no device plane on a CPU and
         # return nothing; the counters are all there
-        assert COUNTERS | {"compile_s", "compiles_in_window"} <= \
-            set(result["metrics"]) <= {m["name"] for m in per}
+        assert COUNTERS <= set(result["metrics"]) <= \
+            {m["name"] for m in per}
         got = {k: v["value"] for k, v in result["metrics"].items()}
         # the router routes over all 8 outputs, the chip holds 4
         assert 20 < got["moe_held_assignment_share.longchat"] < 80
         assert 0 < got["moe_experts_touched_share.longchat"] <= 100
         assert got["compiles_in_window"] == 0
-        assert got["deferred_admissions.longchat"] == 0
+        assert got["deferred_admissions.serve"] == 0
 
 
-def test_the_cell_reports_its_nineteen_metrics_and_the_accepted_ones():
+def test_the_cell_reports_the_common_entries_and_its_own():
     _, per = manifest.metrics_of(MAN, CELL)
     names = {m["name"] for m in per}
-    assert len([n for n in names if n.endswith(".longchat")]) == 19
+    # every `.longchat` entry is this cell's (Keye's cell shares the
+    # experts' five) and every common entry lists it
+    assert {m["name"] for m in MAN["per_layer"]
+            if m["name"].endswith(".longchat")} <= names
+    assert expected.common(MAN) <= names
+    assert {"moe_held_assignment_share.longchat", "lane_occupancy.serve",
+            "decode_live_block_share.serve"} <= COUNTERS
     assert {"compile_s", "compiles_in_window", "runtime_start_s"} <= names
     for n in names:
         assert callable(manifest.reader(n))
@@ -74,9 +75,8 @@ def test_readers_find_nothing_without_the_programs_spans_and_counters():
            "mix": tiny_qwen3next.longchat(), "peaks": {}, "trace": None,
            "window": (0.0, 1.0), "stats_open": {}, "stats_close": {},
            "polls": [], "memory_peak_bytes": None}
-    for m in manifest.metrics_of(MAN, CELL)[1]:
-        if m["name"].endswith(".longchat"):
-            assert manifest.reader(m["name"])(obs) is None, m["name"]
+    for m in expected.by_cell(MAN, CELL):
+        assert manifest.reader(m["name"])(obs) is None, m["name"]
 
 
 def test_the_job_hands_serve_http_its_own_check_back():

@@ -9,13 +9,11 @@ import pytest
 
 from benchmarks import run
 from benchmarks.lib import device, manifest
-from benchmarks.tests import tiny_sala
+from benchmarks.tests import expected, tiny_sala
 
 MAN = manifest.load()
 CELL = "sala_longdoc_saturated"
-COUNTERS = {"lane_occupancy.longdoc", "sparse_attended_share.longdoc",
-            "prefill_padding_share.longdoc", "decode_ahead_share.longdoc",
-            "deferred_admissions.longdoc", "kv_blocks_peak_share.longdoc"}
+COUNTERS = expected.counters(MAN, CELL)
 
 
 @pytest.fixture(autouse=True)
@@ -43,20 +41,25 @@ def test_cell_runs_end_to_end_and_is_correct(trace):
     else:
         # readers of device time find no device plane on a CPU and
         # return nothing; the counters are all there
-        assert COUNTERS | {"compile_s", "compiles_in_window"} <= \
-            set(result["metrics"]) <= {m["name"] for m in per}
+        assert COUNTERS <= set(result["metrics"]) <= \
+            {m["name"] for m in per}
         got = {k: v["value"] for k, v in result["metrics"].items()}
         # every prompt is past the tiny dense_len: 6 blocks of 16 of
         # 100-208 cached tokens
         assert 40 < got["sparse_attended_share.longdoc"] < 100
         assert got["compiles_in_window"] == 0
-        assert got["deferred_admissions.longdoc"] == 0
+        assert got["deferred_admissions.serve"] == 0
 
 
-def test_the_cell_reports_its_sixteen_metrics_and_the_accepted_ones():
+def test_the_cell_reports_the_common_entries_and_its_own():
     _, per = manifest.metrics_of(MAN, CELL)
     names = {m["name"] for m in per}
-    assert len([n for n in names if n.endswith(".longdoc")]) == 16
+    assert {m["name"] for m in MAN["per_layer"]
+            if m["name"].endswith(".longdoc")} <= names
+    # no Mosaic kernel walks live blocks here (the sparse read is xla)
+    assert expected.common(MAN) - names == {"decode_live_block_share.serve"}
+    assert {"sparse_attended_share.longdoc", "lane_occupancy.serve",
+            "sched_taken_share.serve"} <= COUNTERS
     assert {"compile_s", "compiles_in_window", "runtime_start_s"} <= names
     for n in names:
         assert callable(manifest.reader(n))
@@ -69,9 +72,8 @@ def test_readers_find_nothing_without_the_programs_spans_and_counters():
            "mix": tiny_sala.longdoc(), "peaks": {}, "trace": None,
            "window": (0.0, 1.0), "stats_open": {}, "stats_close": {},
            "polls": [], "memory_peak_bytes": None}
-    for m in manifest.metrics_of(MAN, CELL)[1]:
-        if m["name"].endswith(".longdoc"):
-            assert manifest.reader(m["name"])(obs) is None, m["name"]
+    for m in expected.by_cell(MAN, CELL):
+        assert manifest.reader(m["name"])(obs) is None, m["name"]
 
 
 def test_a_served_token_altered_where_it_is_produced_is_not_correct(

@@ -11,14 +11,12 @@ import pytest
 
 from benchmarks.lib import manifest
 
-CELLS = ("chat", "doc", "reason", "longchat")
+CELLS = ("chat", "serve")
 COUNTER_METRICS = ("sched_cpu_ms_per_tick", "sched_taken_share",
                    "sched_lock_wait_ms_per_tick",
                    "decode_dispatch_cpu_share", "commit_cpu_share")
 SPAN_METRICS = ("sched_cycle_ms", "sched_uncovered_share")
-ALIASES = ("decode_dispatch_ms.doc", "decode_dispatch_ms.reason",
-           "decode_dispatch_ms.longchat", "commit_ms.reason",
-           "commit_ms.longchat")
+ALIASES = ("decode_dispatch_ms.serve", "commit_ms.serve")
 NEW = [f"{m}.{c}" for m in SPAN_METRICS + COUNTER_METRICS
        for c in CELLS] + list(ALIASES)
 ADDED_SPANS = ("serving/reclaim", "serving/tail", "serving/alloc",
@@ -107,11 +105,11 @@ def test_the_parents_trace_reads_the_same_cycle_and_a_larger_hole():
     reclaim and tail stretches are uncovered too."""
     parent = [e for e in HOST if e[0] not in ADDED_SPANS]
     obs = _obs(host=parent)
-    assert read("sched_cycle_ms.longchat", obs) == pytest.approx(22.0)
-    assert read("sched_uncovered_share.longchat", obs) == \
+    assert read("sched_cycle_ms.serve", obs) == pytest.approx(22.0)
+    assert read("sched_uncovered_share.serve", obs) == \
         pytest.approx(100 * (6 + 2 * 2.1) / 44)
-    assert read("decode_dispatch_ms.longchat", obs) == pytest.approx(5.9)
-    assert read("commit_ms.reason", obs) == pytest.approx(2.0)
+    assert read("decode_dispatch_ms.serve", obs) == pytest.approx(5.9)
+    assert read("commit_ms.serve", obs) == pytest.approx(2.0)
 
 
 OPEN = {"fstpu_serving_decode_ticks_total": 1000.0,
@@ -178,16 +176,18 @@ def test_every_new_reader_returns_nothing_where_there_is_nothing_to_read(
     assert read(name, dict(PARENT_SHAPED[shape])) is None
 
 
-def test_every_new_metric_has_its_file_and_its_entry_for_one_cell():
+def test_every_metric_has_its_file_and_one_entry_an_end_to_end_metric():
+    """`.chat` moves `gap_p50_ms` in the open loop; `.serve` moves
+    `serve_tokens_per_s` in every cell that reports it."""
     man = manifest.load()
     entries = {m["name"]: m for m in man["per_layer"]}
-    cell_of = {"chat": "mistral_chat_steady", "doc": "mistral_doc_saturated",
-               "reason": "joyai_reason_saturated",
-               "longchat": "qwen3next_longchat_saturated"}
+    saturated = next(m["workloads"] for m in man["end_to_end"]
+                     if m["name"] == "serve_tokens_per_s")
     for name in NEW:
         entry = entries[name]
         cell = name.rsplit(".", 1)[1]
-        assert entry["workloads"] == [cell_of[cell]]
+        assert entry["workloads"] == (["mistral_chat_steady"]
+                                      if cell == "chat" else saturated)
         assert entry["layer"] == "scheduler"
         assert entry["moves"] == ("gap_p50_ms" if cell == "chat"
                                   else "serve_tokens_per_s")

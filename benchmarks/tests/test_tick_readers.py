@@ -12,11 +12,12 @@ import pytest
 from benchmarks.lib import costs, manifest, xplane_attrs
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-NEW = ["tick_host_ms.chat", "decode_dispatch_ms.chat",
-       "decode_fetch_tail_ms.chat", "commit_ms.chat",
+NEW = ["decode_dispatch_ms.chat", "decode_dispatch_ms.serve",
+       "commit_ms.chat", "commit_ms.serve",
        "submit_lock_wait_p50_ms.chat", "log_stall_ms.train",
-       "prefill_padding_share.doc", "prefill_device_us_per_token.doc",
-       "assign_device_ms.doc", "decode_attn_roofline_share.doc"]
+       "prefill_padding_share.serve", "prefill_device_us_per_token.serve",
+       "assign_device_ms.serve", "decode_attn_roofline_share.doc",
+       "decode_step_device_ms.serve", "decode_step_device_ms.chat"]
 
 
 @pytest.fixture(scope="module")
@@ -36,27 +37,28 @@ def read(name, obs):
     return manifest.reader(name)(obs)
 
 
-def test_the_tick_is_split_into_dispatch_device_tail_and_commit(made):
+@pytest.mark.parametrize("cell", ["chat", "serve"])
+def test_the_tick_on_the_module_line_its_dispatch_and_its_commit(made,
+                                                                cell):
     obs = _obs(made["serve"], (0.0, 1.0))
-    # ticks start at 0.110, 0.175, 0.250 and, after a prefill, 0.410;
-    # the device is busy 50 and 52 ms inside the first two: the host's
-    # share of those two gaps is 65 - 50 and 75 - 52 ms. The pair with
-    # the prefill between it is left out
-    assert read("tick_host_ms.chat", obs) == pytest.approx(19.0)
-    assert read("decode_dispatch_ms.chat", obs) == pytest.approx(3.0)
-    # the last operation ends 6, 8, 8 and 6 ms before the fetch returns
-    assert read("decode_fetch_tail_ms.chat", obs) == pytest.approx(7.0)
-    assert read("commit_ms.chat", obs) == pytest.approx(2.5)
+    # four runs of `jit_decode_fn`: 50, 52, 50 and 50 ms
+    assert read(f"decode_step_device_ms.{cell}", obs) == pytest.approx(50.0)
+    assert read(f"decode_dispatch_ms.{cell}", obs) == pytest.approx(3.0)
+    assert read(f"commit_ms.{cell}", obs) == pytest.approx(2.5)
+    # a window that cuts the second run leaves it out
+    assert read(f"decode_step_device_ms.{cell}",
+                _obs(made["serve"], (0.0, 0.2))) == pytest.approx(50.0)
 
 
 def test_prefill_cost_a_padded_token_and_the_assign_program(made):
     obs = _obs(made["serve"], (0.0, 1.0))
-    # 80 ms under the 512 bucket, 70 ms under the 1024 one
-    assert read("prefill_device_us_per_token.doc", obs) == \
+    # `jit_prefill_fn` ran 80 ms for the span of the 512 bucket and
+    # 70 ms for the span of the 1024 one
+    assert read("prefill_device_us_per_token.serve", obs) == \
         pytest.approx(1e6 * 0.150 / 1536)
-    assert read("assign_device_ms.doc", obs) == pytest.approx(5.0)
+    assert read("assign_device_ms.serve", obs) == pytest.approx(5.0)
     # a window that holds only the first prefill
-    assert read("prefill_device_us_per_token.doc",
+    assert read("prefill_device_us_per_token.serve",
                 _obs(made["serve"], (0.0, 0.3))) == \
         pytest.approx(1e6 * 0.080 / 512)
 
@@ -73,17 +75,17 @@ def test_decode_attention_roofline_share_from_counters_and_kernel_time(
                stats_open={ticks: 1000.0, attended: 5e6},
                stats_close={ticks: 1100.0, attended: 9e6})
     # 40,000 real cached tokens a tick, 65,536 B each over 16 layers:
-    # 2.62 GB, 3.2 ms at 819 GB/s; the kernel took 10 and 12 ms in the
-    # two ticks that ran it
+    # 2.62 GB, 3.2 ms at 819 GB/s; the kernel took 10 and 12 ms inside
+    # two of the four runs of `jit_decode_fn`: 5.5 ms a tick
     least = costs.decode_attention_bytes(40000, 8, 128, 2, 16) / 819e9
     assert least == pytest.approx(3.2008e-3, rel=1e-4)
     share = read("decode_attn_roofline_share.doc", obs)
-    assert share == pytest.approx(100 * least / 0.011)
+    assert share == pytest.approx(100 * least / 0.0055)
     assert 0 < share < 100
     # an int8 pool halves the bytes; the parent's engine has no counter
     obs["config"] = dict(config, engine_args={"kv_dtype": "int8"})
     assert read("decode_attn_roofline_share.doc", obs) == \
-        pytest.approx(50 * least / 0.011)
+        pytest.approx(50 * least / 0.0055)
     del obs["stats_open"][attended], obs["stats_close"][attended]
     assert read("decode_attn_roofline_share.doc", obs) is None
 
@@ -93,7 +95,7 @@ def test_padding_share_and_lock_wait_read_counters_and_timelines():
         "fstpu_serving_prefill_padded_tokens_total"
     obs = {"stats_open": {real: 1000.0, padded: 2000.0},
            "stats_close": {real: 31000.0, padded: 42000.0}}
-    assert read("prefill_padding_share.doc", obs) == pytest.approx(25.0)
+    assert read("prefill_padding_share.serve", obs) == pytest.approx(25.0)
     obs = {"timelines": {
         "a": {"phases": {"queue_wait_s": 1.0, "lock_wait_s": 0.9}},
         "b": {"phases": {"queue_wait_s": 0.3, "lock_wait_s": 0.1}},
@@ -137,7 +139,10 @@ def test_every_new_reader_returns_nothing_without_a_device_plane(
         "stats_open": {"fstpu_serving_decode_ticks_total": 1.0},
         "stats_close": {"fstpu_serving_decode_ticks_total": 9.0},
         "config": {}, "peaks": {"hbm_bytes_per_s": 819e9}}
-    expected = {"tick_host_ms.chat": 19.0, "assign_device_ms.doc": 5.0}
+    # what the module line alone gives is read all the same
+    expected = {"assign_device_ms.serve": 5.0,
+                "decode_step_device_ms.serve": 50.0,
+                "decode_step_device_ms.chat": 50.0}
     assert read(name, parent) == (pytest.approx(expected[name])
                                   if name in expected else None)
 

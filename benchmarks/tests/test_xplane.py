@@ -6,7 +6,7 @@ import os
 
 import pytest
 
-from benchmarks.lib import xplane
+from benchmarks.lib import xplane, xplane_attrs
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -22,10 +22,15 @@ def test_recorded_trace_busy_idle_and_time_under_spans(recorded):
     assert (lo, hi) == (0.0, 0.4)
     busy = xplane.busy_seconds(recorded, lo, hi)
     assert busy == pytest.approx(0.345047427, rel=1e-6)
-    decode = xplane.device_seconds_under(recorded, "serving/decode", lo, hi)
+    index = xplane_attrs.Busy(recorded, lo, hi)
+
+    def under(span):
+        return [index.seconds(a, b)
+                for a, b in xplane.spans(recorded, span, lo, hi)]
+    decode = under("serving/decode")
     assert len(decode) == 5
     assert all(d == pytest.approx(0.0521, abs=4e-4) for d in decode)
-    prefill = xplane.device_seconds_under(recorded, "serving/prefill", lo, hi)
+    prefill = under("serving/prefill")
     assert prefill == [pytest.approx(0.069535389, rel=1e-6)]
     # device work under the engine thread's spans is nearly all of it
     assert sum(decode) + sum(prefill) == pytest.approx(busy, rel=0.05)
@@ -60,8 +65,8 @@ def test_hand_made_intervals():
     assert xplane.busy_seconds(trace, 0, 4) == pytest.approx(3.25)
     assert xplane.busy_seconds(trace, 1, 3.5) == pytest.approx(
         (0.5 + 0.5 + 2.5) / 2)
-    assert xplane.device_seconds_under(trace, "x/step", 0, 4) == \
-        [pytest.approx(1.5)]
+    assert xplane_attrs.Busy(trace, 0, 4).seconds(0.0, 2.0) == \
+        pytest.approx(1.5)
     # the one gap, 1.5..3.0, lies mostly under x/wait
     assert xplane.idle_gaps(trace, 0, 4) == [("x/wait", pytest.approx(1.5))]
 

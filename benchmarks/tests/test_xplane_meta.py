@@ -1,9 +1,11 @@
 """`lib.xplane_meta` on an XSpace written by hand in the wire format:
 the per-kind attributes come out under the event's name, string values
-and references alike, and `lib.scopes` sums the device time of the
-operations under a scope inside a host span."""
+and references alike, and `lib.trace_lines` sums the device time of
+the operations under a scope inside a program's runs."""
 
-from benchmarks.lib import scopes, xplane_meta
+import pytest
+
+from benchmarks.lib import trace_lines, xplane_meta
 
 
 def varint(n: int) -> bytes:
@@ -74,21 +76,28 @@ def test_a_varint_longer_than_a_byte_and_unknown_fields_are_walked():
     assert got == [(3, 0, 300), (9, 2, b"x" * 200), (2, 2, b"name")]
 
 
-def test_seconds_under_a_scope_inside_each_span():
+def test_seconds_under_a_scope_inside_each_run_of_the_program():
     # two ticks; the scope's operations are a while (1.0-1.4) holding
-    # two of its body's (1.1-1.2, 1.25-1.35), and one in the second tick
+    # two of its body's (1.1-1.2, 1.25-1.35), and one in the second
+    # tick; a third run is cut by the window's end
     obs = {"trace": {"devices": {"/device:TPU:0": [["op", 1.0, 0.1]]},
-                     "host": [["serving/decode", 0.9, 0.6],
-                              ["serving/decode", 2.0, 0.5]]},
+                     "host": []},
            "trace_window": (0.0, 3.0),
+           "trace_attrs": {"spans": [], "modules": [
+               ["jit_decode_fn", 0.9, 0.6], ["jit_decode_fn", 2.0, 0.5],
+               ["jit_decode_fn", 2.8, 0.5]]},
            "scope_ops": [["%while.1 ... fstpu_moe_experts/while", 1.0, 0.4],
                          ["%a ... fstpu_moe_experts/ragged_dot", 1.1, 0.1],
                          ["%b ... fstpu_moe_experts/gather", 1.25, 0.1],
                          ["%c ... fstpu_moe_route/top_k", 1.45, 0.02],
                          ["%d ... fstpu_moe_experts/ragged_dot", 2.1, 0.2],
                          ["%e ... fstpu_moe_experts/ragged_dot", 2.9, 0.2]]}
-    got = scopes.seconds_per_span(obs, "fstpu_moe_experts", "serving/decode")
-    assert abs(got - (0.4 + 0.2) / 2) < 1e-9
-    assert scopes.seconds_per_span(obs, "fstpu_absent", "serving/decode") \
-        is None
-    assert scopes.seconds_per_span({"trace": None}, "x", "y") is None
+    got = trace_lines.scope_seconds_in(obs, "fstpu_moe_experts",
+                                       trace_lines.DECODE)
+    assert got[1] == 2 and abs(got[0] - (0.4 + 0.2)) < 1e-9
+    assert trace_lines.seconds_a_run(got) == pytest.approx(0.3)
+    assert trace_lines.scope_seconds_in(obs, "fstpu_absent",
+                                        trace_lines.DECODE) is None
+    assert trace_lines.scope_seconds_in({"trace": None}, "x",
+                                        trace_lines.DECODE) is None
+    assert trace_lines.seconds_a_run(None) is None
