@@ -1,0 +1,26 @@
+"""The window layers' windowed read's share of its roofline. Bound:
+operations. The least time is `costs_trinity.attn_flops` over the
+visible pairs of the real queries of the traced windows
+(`serving/prefill/window` spans: window index and real tokens; a query
+reads min(context, 4,096) keys, 4 x 48 x 128 FLOP a pair a layer) over
+the published bf16 peak; the time taken is the device seconds under
+the scope `fstpu_window_prefill_attention` inside the window program's
+runs in the traced window, scaled to the windows whose spans were
+seen."""
+from benchmarks.lib import costs_trinity, trace_lines
+
+
+def read(obs):
+    spans = trace_lines.window_spans(obs)
+    taken = trace_lines.scope_seconds_in(
+        obs, costs_trinity.WINDOW_PREFILL_SCOPE, trace_lines.WINDOW)
+    if not spans or not taken or not taken[0]:
+        return None
+    cfg = obs["config"]
+    width = max(obs["mix"]["engine_args"]["buckets"])
+    pairs = sum(costs_trinity.window_prefill_pairs(w * width, n, cfg)
+                for w, n in spans)
+    needed = costs_trinity.attn_flops(
+        pairs, costs_trinity.layers(cfg, costs_trinity.SLIDING), cfg) / \
+        obs["peaks"]["bf16_flops_per_s"]
+    return 100.0 * needed / (taken[0] * len(spans) / taken[1])

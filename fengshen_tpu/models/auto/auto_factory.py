@@ -87,6 +87,8 @@ MODEL_REGISTRY: dict[str, tuple[str, str, dict[str, str]]] = {
                              {"base": "TransfoXLReasoningModel"}),
     "KeyeVL2": ("fengshen_tpu.models.keye", "KeyeConfig",
                 {"causal_lm": "KeyeForCausalLM", "base": "KeyeModel"}),
+    "afmoe": ("fengshen_tpu.models.trinity", "TrinityConfig",
+              {"causal_lm": "TrinityForCausalLM", "base": "TrinityModel"}),
 }
 
 

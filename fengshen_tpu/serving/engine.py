@@ -93,13 +93,14 @@ from fengshen_tpu.serving.buckets import DEFAULT_BUCKETS, BucketLadder
 from fengshen_tpu.serving.cache import (abstract_init, assign_slot,
                                         init_slot_cache, reset_free_slots,
                                         rollback_slots)
-from fengshen_tpu.serving.paged_cache import (INDEX_PREFIX,
+from fengshen_tpu.serving.paged_cache import (INDEX_PREFIX, RING_PREFIX,
                                               BlockAllocator,
                                               assign_paged,
                                               assign_slot_quantized,
                                               blocks_for_tokens,
                                               init_pool_cache,
-                                              positional_leaves)
+                                              positional_leaves,
+                                              ring_leaves)
 from fengshen_tpu.serving.metrics import EngineMetrics
 from fengshen_tpu.streaming import StreamBook
 from fengshen_tpu.utils.generate import (_controls_active,
@@ -165,6 +166,13 @@ class EngineConfig:
     kv_block_size: int = 64                  # tokens per paged block
     kv_num_blocks: Optional[int] = None      # default: slot-parity + null
     kv_max_blocks_per_slot: Optional[int] = None  # default: max_len/bs
+    # a model with window layers keeps their K/V as a RING (docs/
+    # serving.md "Paged KV cache"; `paged_cache.ring_leaves`): a second
+    # table a lane of `kv_ring_blocks_per_slot` blocks drawn from a
+    # second free list of `kv_ring_num_blocks`. Ignored by a model that
+    # declares no ring
+    kv_ring_blocks_per_slot: Optional[int] = None  # default: window+bucket
+    kv_ring_num_blocks: Optional[int] = None  # default: slot-parity + null
     # speculative decode (docs/serving.md "Speculative decoding"):
     # "prompt_lookup" makes every tick draft spec_gamma tokens per lane
     # by n-gram match against the lane's on-device committed history
@@ -439,6 +447,9 @@ class ContinuousBatchingEngine:
             self.seq_capacity = mb * bs
             self._allocator = BlockAllocator(nb)
             self._slot_blocks: list[list[int]] = [[] for _ in range(S)]
+            #: the ring blocks a lane holds beside them (a model whose
+            #: cache declares a ring; else always empty)
+            self._slot_ring: list[list[int]] = [[] for _ in range(S)]
             self._deferred_req: Optional[str] = None
         else:
             self.seq_capacity = self.max_len
@@ -461,7 +472,41 @@ class ContinuousBatchingEngine:
                 f"spec_mode={config.spec_mode!r} cannot serve a cache "
                 f"that declares {self._positional}: a rejected draft "
                 "cannot be rolled back out of a recurrent state or a "
-                "pooled row; use spec_mode='off'")
+                "pooled row, nor rewound in a ring; use spec_mode='off'")
+        #: the leaves a lane holds only the last tokens of
+        #: (`paged_cache.ring_leaves`): under the paged layout they page
+        #: behind a second table of `ring_blocks` blocks a lane, from a
+        #: second free list
+        self._ring = ring_leaves(self._abstract_init["cache"])
+        #: host arithmetic a model with window layers offers: keys such
+        #: a layer's query with so many cached tokens reads
+        self._window_tokens = getattr(model, "window_tokens", None)
+        self.ring_blocks = 0
+        if self._ring and config.kv_dtype == "int8":
+            raise ValueError(
+                f"kv_dtype='int8' cannot serve a cache that declares "
+                f"{self._ring}: a ring is read through a table of its "
+                "live blocks as the rows lie and has no scales; use "
+                "kv_dtype='fp32'")
+        if self._ring and self.paged:
+            window = int(self._window_tokens(self.max_len))
+            rb = int(min(mb, blocks_for_tokens(
+                window + self.ladder.max_bucket, bs))
+                if config.kv_ring_blocks_per_slot is None
+                else config.kv_ring_blocks_per_slot)
+            if rb * bs < window or rb > mb:
+                raise ValueError(
+                    f"kv_ring_blocks_per_slot={rb} x kv_block_size={bs} "
+                    f"must hold the {window} tokens a window layer reads "
+                    f"and no more than the lane's {mb} blocks")
+            rnb = int(S * rb + 1 if config.kv_ring_num_blocks is None
+                      else config.kv_ring_num_blocks)
+            if rnb - 1 < rb:
+                raise ValueError(
+                    f"kv_ring_num_blocks={rnb} cannot hold one lane's "
+                    f"ring of {rb} blocks beside the null block")
+            self.ring_blocks, self.ring_num_blocks = rb, rnb
+            self._ring_allocator = BlockAllocator(rnb)
 
         if self.self_draft:
             # the self-draft tower (docs/streaming.md "Draft tower"):
@@ -502,6 +547,8 @@ class ContinuousBatchingEngine:
         #: bytes the pool holds beside the rows attention reads: per-lane
         #: state (a recurrent layer's) and the rows a selection scores
         self._state_bytes = pool_bytes("state_", INDEX_PREFIX)
+        #: of `_kv_bytes`, the rings' part
+        self._ring_bytes = pool_bytes(RING_PREFIX)
         #: host arithmetic a sparse-attention model offers: tokens a
         #: query with so many cached tokens reads (None: all of them),
         #: `attended_tokens` where it chooses pooled blocks,
@@ -648,8 +695,11 @@ class ContinuousBatchingEngine:
         # record still fetches the array it derives from)
         if paged:
             def assign_fn(cache, history, mask, tokens, primed,
-                          prompt_row, mask_row, table_row, slot, tok):
-                cache = assign_paged(cache, primed, slot, table_row)
+                          prompt_row, mask_row, table_row, *rest):
+                # a cache with a ring brings its table row too
+                *ring_row, slot, tok = rest
+                cache = assign_paged(cache, primed, slot, table_row,
+                                     *ring_row)
                 history = history.at[slot].set(prompt_row)
                 mask = mask.at[slot].set(mask_row)
                 return cache, history, mask, tokens.at[slot].set(tok)
@@ -946,6 +996,9 @@ class ContinuousBatchingEngine:
                 kv_dtype=cfg.kv_dtype, num_blocks=self.num_blocks,
                 block_size=self.block_size,
                 max_blocks_per_slot=self.max_blocks_per_slot,
+                **(dict(ring_num_blocks=self.ring_num_blocks,
+                        ring_blocks_per_slot=self.ring_blocks)
+                   if self.ring_blocks else {}),
                 abstract=self._abstract_init)
         return init_pool_cache(self.model, cfg.num_slots, layout="slot",
                                kv_dtype=cfg.kv_dtype,
@@ -1416,6 +1469,14 @@ class ContinuousBatchingEngine:
             self.metrics.record_index(
                 int(self._indexed_tokens(self._pos[lanes] + 1).sum()),
                 kv_tokens)
+        if self._window_tokens is not None:
+            # a model with window layers: what such a layer's queries
+            # read, and the blocks of each kind the lanes hold
+            self.metrics.record_window(
+                int(self._window_tokens(self._pos[lanes] + 1).sum()),
+                *((sum(map(len, self._slot_blocks)),
+                   sum(map(len, self._slot_ring))) if self.paged
+                  else (0, 0)))
         # of the table rows the tick's attention is handed, the blocks
         # up to each lane's PHYSICAL cursor (bucket padding in, as the
         # lane's blocks hold it; a released lane's one null block; a
@@ -1609,8 +1670,10 @@ class ContinuousBatchingEngine:
                     bucket + decode_span + self._gamma,
                     self.block_size)
                 with span("serving/alloc", request_id=req.request_id,
-                          blocks=int(need)):
-                    blocks = self._allocator.alloc(need)
+                          blocks=int(need), **(
+                              {"ring_blocks": min(need, self.ring_blocks)}
+                              if self.ring_blocks else {})):
+                    blocks = self._alloc_blocks(need)
                     if blocks is None:
                         self._defer(req, need, now)
                         return prefills
@@ -1678,13 +1741,13 @@ class ContinuousBatchingEngine:
                 if self.config.eos_token_id is not None and \
                         tok == self.config.eos_token_id:
                     if blocks is not None:
-                        self._allocator.free(blocks)
+                        self._free_blocks(*blocks)
                         blocks = None
                     self._finish(req, FINISHED, "eos")
                     continue
                 if len(req.tokens) >= req.max_new_tokens:
                     if blocks is not None:
-                        self._allocator.free(blocks)
+                        self._free_blocks(*blocks)
                         blocks = None
                     self._finish(req, FINISHED, "length")
                     continue
@@ -1692,16 +1755,38 @@ class ContinuousBatchingEngine:
                 # a failed prefill must not strand the request's KV
                 # blocks: return them to the pool before propagating
                 if blocks is not None:
-                    self._allocator.free(blocks)
+                    self._free_blocks(*blocks)
                 raise
             if self.paged:
-                self._slot_blocks[slot] = blocks    # the lane owns them
+                # the lane owns them
+                self._slot_blocks[slot], self._slot_ring[slot] = blocks
             with span("serving/assign", request_id=req.request_id,
                       slot=slot):
                 self._assign(req, slot, bucket, row, mask_row, primed,
                              d_primed if self.self_draft else None, tok,
                              lane_key)
         return prefills
+
+    def _alloc_blocks(self, need: int) -> Optional[tuple]:
+        """(`need` blocks of the lane-long kind, the ring's blocks) for
+        one request, or None when either free list cannot serve it. A
+        lane shorter than the ring takes only the ring blocks it will
+        reach; a model without a ring takes none."""
+        ring_need = min(need, self.ring_blocks)
+        ring = self._ring_allocator.alloc(ring_need) if ring_need else []
+        if ring is None:
+            return None
+        blocks = self._allocator.alloc(need)
+        if blocks is None:
+            if ring:
+                self._ring_allocator.free(ring)
+            return None
+        return blocks, ring
+
+    def _free_blocks(self, blocks: list, ring: list) -> None:
+        self._allocator.free(blocks)
+        if ring:
+            self._ring_allocator.free(ring)
 
     def _defer(self, req: Request, need: int, now: float) -> None:
         """The pool cannot serve `req`: back to the head of the queue."""
@@ -1711,14 +1796,17 @@ class ContinuousBatchingEngine:
             # keeps waiting
             self._deferred_req = req.request_id
             self.metrics.count("deferred_admissions")
+            ring = {"ring_blocks_free":
+                    int(self._ring_allocator.free_blocks)} \
+                if self.ring_blocks else {}
             req.timeline.add(
                 now, "deferred", blocks_needed=int(need),
-                blocks_free=int(self._allocator.free_blocks))
+                blocks_free=int(self._allocator.free_blocks), **ring)
             self._log({"event": "serving_defer",
                        "reason": "kv_blocks_exhausted",
                        "request_id": req.request_id,
                        "blocks_needed": need,
-                       "blocks_free": self._allocator.free_blocks})
+                       "blocks_free": self._allocator.free_blocks, **ring})
 
     def _prefill_windows(self, req: Request, ids, windows, key):
         """Prefill `ids` window by window onto one batch-1 cache, each
@@ -1756,8 +1844,13 @@ class ContinuousBatchingEngine:
             blocks = self._slot_blocks[slot]
             table_row = np.zeros((self.max_blocks_per_slot,), np.int32)
             table_row[:len(blocks)] = blocks
-        rows = (hist_row, full_mask) + \
-            ((table_row,) if self.paged else ()) + \
+        tables = (table_row,) if self.paged else ()
+        if self.ring_blocks:
+            ring = self._slot_ring[slot]
+            ring_row = np.zeros((self.ring_blocks,), np.int32)
+            ring_row[:len(ring)] = ring
+            tables += (ring_row,)
+        rows = (hist_row, full_mask) + tables + \
             (np.int32(slot), np.int32(tok))
         if self.self_draft:
             (self._cache, self._draft_cache, self._history, self._mask,
@@ -1793,8 +1886,9 @@ class ContinuousBatchingEngine:
             # blocks return to the free list NOW; the lane's stale
             # block-table row is parked on the null block by the next
             # decode's entry clamp before any write can land
-            self._allocator.free(self._slot_blocks[slot])
-            self._slot_blocks[slot] = []
+            self._free_blocks(self._slot_blocks[slot],
+                              self._slot_ring[slot])
+            self._slot_blocks[slot], self._slot_ring[slot] = [], []
         self._finish(req, state, reason)
 
     def _finish(self, req: Request, state: str, reason: str) -> None:
@@ -1931,6 +2025,9 @@ class ContinuousBatchingEngine:
         if self.paged:
             self._allocator = BlockAllocator(self.num_blocks)
             self._slot_blocks = [[] for _ in range(S)]
+            self._slot_ring = [[] for _ in range(S)]
+            if self.ring_blocks:
+                self._ring_allocator = BlockAllocator(self.ring_num_blocks)
             self._deferred_req = None
         self._cache = self._init_pool()
         self._history = jnp.zeros((S, L), jnp.int32)
@@ -2124,12 +2221,17 @@ class ContinuousBatchingEngine:
             alloc_tokens = used * block_tokens
         frag = round(1.0 - used_tokens / alloc_tokens, 4) \
             if alloc_tokens else 0.0
+        # a ring's blocks stand beside, not among, the lane-long kind's:
+        # `blocks_*` is the pool that grows with a lane's context
+        ring = {"ring_blocks_total": self._ring_allocator.total_blocks,
+                "ring_blocks_used": self._ring_allocator.used_blocks,
+                "ring_bytes": self._ring_bytes} if self.ring_blocks else {}
         return {
             "layout": cfg.kv_layout, "dtype": cfg.kv_dtype,
             "blocks_total": total, "blocks_used": used,
             "blocks_free": total - used, "block_tokens": block_tokens,
             "bytes": self._kv_bytes, "fragmentation": frag,
-            "state_bytes": self._state_bytes,
+            "state_bytes": self._state_bytes, **ring,
         }
 
     def stats(self) -> dict:

@@ -103,6 +103,18 @@ class EngineMetrics:
             "fstpu_index_tokens_scored_total",
             "per-tick sum over live lanes of the cached tokens a "
             "layer's indexer scored to choose them (cursor + 1)")
+        self._window_attended = r.counter(
+            "fstpu_serving_kv_window_tokens_attended_total",
+            "per-tick sum over live lanes of the keys a WINDOW layer's "
+            "query reads (min(cursor + 1, window); a layer's count): "
+            "beside kv_tokens_attended, which a full layer reads")
+        self._kv_blocks_held = r.counter(
+            "fstpu_serving_kv_blocks_held_total",
+            "per-tick sum over lanes of the lane-long table's blocks "
+            "they hold (a model with window layers only)")
+        self._kv_ring_blocks_held = r.counter(
+            "fstpu_serving_kv_ring_blocks_held_total",
+            "per-tick sum over lanes of the ring blocks they hold")
         self._kv_attended = r.counter(
             "fstpu_serving_kv_tokens_attended_total",
             "per-tick sum over active lanes of the real cached tokens "
@@ -204,6 +216,12 @@ class EngineMetrics:
             "fstpu_kv_blocks_used", "KV-pool blocks held by live lanes")
         self._g_kv_bytes = r.gauge(
             "fstpu_kv_cache_bytes", "device bytes of the KV pool")
+        self._g_ring_total = r.gauge(
+            "fstpu_kv_ring_blocks_total",
+            "allocatable blocks of the window layers' ring pool")
+        self._g_ring_used = r.gauge(
+            "fstpu_kv_ring_blocks_used",
+            "ring-pool blocks held by live lanes")
         self._g_state_bytes = r.gauge(
             "fstpu_serving_state_bytes",
             "device bytes the pool holds beside the rows attention "
@@ -256,6 +274,14 @@ class EngineMetrics:
     def record_sparse(self, attended: int, cached: int) -> None:
         self._sparse_attended.inc(attended)
         self._sparse_cached.inc(cached)
+
+    def record_window(self, attended: int, blocks: int,
+                      ring_blocks: int) -> None:
+        """One enqueued tick of a model with window layers: keys such
+        a layer reads over the live lanes; blocks of each kind held."""
+        self._window_attended.inc(attended)
+        self._kv_blocks_held.inc(blocks)
+        self._kv_ring_blocks_held.inc(ring_blocks)
 
     def record_index(self, selected: int, scored: int) -> None:
         self._index_selected.inc(selected)
@@ -414,6 +440,8 @@ class EngineMetrics:
         self._g_kv_total.set(kv["blocks_total"])
         self._g_kv_used.set(kv["blocks_used"])
         self._g_kv_bytes.set(kv["bytes"])
+        self._g_ring_total.set(kv.get("ring_blocks_total", 0))
+        self._g_ring_used.set(kv.get("ring_blocks_used", 0))
         self._g_state_bytes.set(kv.get("state_bytes", 0))
         self._g_kv_frag.set(kv["fragmentation"])
         ttft = self._ttft.window_values()

@@ -43,7 +43,18 @@ byte budget into fixed-size blocks instead (the paged-attention idea):
   one row a token behind the same table, written, assigned and released
   as the K/V rows are. What it chooses is a list of the lane's token
   positions, so a cache that declares one is positional
-  (`positional_leaves`).
+  (`positional_leaves`);
+- a FOURTH kind of row (`RING_PREFIX`): a dict of `cached_window_*`
+  leaves declares that only a lane's last `W` tokens are ever read (a
+  sliding-window layer's K/V). Such a dict pages behind a SECOND table
+  a lane, of fixed width (`ring_blocks_per_slot`), drawn from a second
+  free list (`ring_num_blocks`): token `p` lies in block `table[lane,
+  (p // block_size) % width]`, so the lane holds and reads the same few
+  blocks at any context, and a dict of plain rows in the same model
+  keeps the table that grows with the lane. A ring is addressed by
+  position, so it too is positional. The model masks its read by
+  absolute position; nothing here knows `W` (the engine checks the
+  ring is long enough).
 
 The int8 mode stores the pools as int8 with fp32 per-(token, head)
 absmax scales (`cached_key_scale`/`cached_value_scale`, the
@@ -154,6 +165,28 @@ def state_leaves(d: dict) -> list:
 INDEX_PREFIX = "cached_index_"
 
 
+#: the row leaves a lane holds only the last tokens of, as a ring
+#: (module docstring)
+RING_PREFIX = "cached_window_"
+
+
+def ring_leaves(tree) -> list:
+    """The ring leaves of an attention-cache dict, or of every such
+    dict of a cache tree. A dict is a ring whole or not at all: its
+    rows share one table."""
+    if isinstance(tree, dict) and "cache_index" in tree:
+        rows = row_leaves(tree)
+        ring = [n for n in rows if n.startswith(RING_PREFIX)]
+        if ring and ring != rows:
+            raise ValueError(
+                f"{ring} are ring leaves and {sorted(set(rows) - set(ring))}"
+                " are not: the rows of one dict share one block table")
+        return ring
+    found: list = []
+    _map_attn_dicts(tree, lambda d: found.extend(ring_leaves(d)) or d)
+    return found
+
+
 def _row_rate(d: dict, name: str) -> int:
     """Tokens a row of row leaf `name`: the longest row leaf holds one
     a token, a leaf `r` times shorter one every `r` tokens."""
@@ -164,8 +197,8 @@ def _row_rate(d: dict, name: str) -> int:
 def positional_leaves(cache) -> list:
     """The leaves of a cache tree that are defined on a lane's token
     POSITIONS counted from 0 — a state, a row leaf of another rate, a
-    row leaf a selection scores — so that a lane holding them is filled
-    from position 0 and padded on the right (a left-padded lane would
+    row leaf a selection scores, a ring — so that a lane holding them is
+    filled from position 0 and padded on the right (a left-padded lane would
     shift every pooled window, and every chosen position, by its pad).
     Empty for a cache of plain rows."""
     found: list = []
@@ -174,7 +207,7 @@ def positional_leaves(cache) -> list:
         rows = row_leaves(d)
         found.extend(state_leaves(d))
         found.extend(n for n in rows if _row_rate(d, n) != 1
-                     or n.startswith(INDEX_PREFIX))
+                     or n.startswith((INDEX_PREFIX, RING_PREFIX)))
         return d
     _map_attn_dicts(cache, look)
     return found
@@ -214,12 +247,14 @@ def _vmap_layers(fn, lead: int):
 def init_pool_cache(model, num_slots: int, *, layout: str = "slot",
                     kv_dtype: str = "fp32", num_blocks: int = 0,
                     block_size: int = 0, max_blocks_per_slot: int = 0,
+                    ring_num_blocks: int = 0, ring_blocks_per_slot: int = 0,
                     abstract=None):
     """Zeros KV pool for the engine — the one constructor for all four
     (layout, dtype) combinations. Abstract-init only, like
     `cache.init_slot_cache` (which this generalizes; the fp32 slot
     result is structurally identical to it); `abstract` is a
-    `cache.abstract_init` the caller already made."""
+    `cache.abstract_init` the caller already made. A ring dict's pool
+    and table take `ring_num_blocks` and `ring_blocks_per_slot`."""
     if layout not in ("slot", "paged"):
         raise ValueError(f"unknown kv layout {layout!r}")
     if kv_dtype not in ("fp32", "int8"):
@@ -229,6 +264,8 @@ def init_pool_cache(model, num_slots: int, *, layout: str = "slot",
 
     def build(d):
         out = {}
+        blocks, width = (ring_num_blocks, ring_blocks_per_slot) \
+            if ring_leaves(d) else (num_blocks, max_blocks_per_slot)
         for name in row_leaves(d):
             leaf = d[name]
             lead = leaf.shape[:-4]           # (layers,) under scan
@@ -239,8 +276,7 @@ def init_pool_cache(model, num_slots: int, *, layout: str = "slot",
                     raise ValueError(
                         f"{name} holds a row every {rate} tokens: "
                         f"kv_block_size {block_size} must be a multiple")
-                val_shape = lead + (num_blocks, block_size // rate, heads,
-                                    dim)
+                val_shape = lead + (blocks, block_size // rate, heads, dim)
             else:
                 val_shape = leaf.shape
             out[name] = jnp.zeros(
@@ -253,8 +289,8 @@ def init_pool_cache(model, num_slots: int, *, layout: str = "slot",
             out[name] = jnp.zeros(d[name].shape, d[name].dtype)
         out["cache_index"] = jnp.zeros(lead + (num_slots,), jnp.int32)
         if layout == "paged":
-            out["block_table"] = jnp.zeros(
-                lead + (num_slots, max_blocks_per_slot), jnp.int32)
+            out["block_table"] = jnp.zeros(lead + (num_slots, width),
+                                           jnp.int32)
         return out
     return _map_attn_dicts(abstract["cache"], build)
 
@@ -284,10 +320,14 @@ def assign_slot_quantized(pool, primed, slot):
     return _zip_attn_dicts(pool, primed, put)
 
 
-def assign_paged(pool, primed, slot, table_row):
-    """Scatter a primed batch-1 cache into the blocks of `table_row`
+def assign_paged(pool, primed, slot, lane_row, ring_row=None):
+    """Scatter a primed batch-1 cache into the blocks of `lane_row`
     (a `[max_blocks_per_slot]` int32 vector from the host allocator,
-    padded with the null block) and point lane `slot` at them.
+    padded with the null block) and point lane `slot` at them. A ring
+    dict takes `ring_row` (`[ring_blocks_per_slot]`) instead, and into
+    its entry `r` the LAST logical block `j <= cursor // block_size`
+    with `j % width == r` of the primed lane: what the ring would hold
+    had the lane been written token by token.
 
     The first `max_blocks * block_size` tokens of the primed lane are
     copied wholesale — unpadded-row entries land in the lane's real
@@ -301,6 +341,17 @@ def assign_paged(pool, primed, slot, table_row):
         lead = first.ndim - 4
         num_blocks, block_size = first.shape[-4:-2]
         max_blocks = pool_d["block_table"].shape[-1]
+        ring = bool(ring_leaves(pool_d))
+        table_row = ring_row if ring else lane_row
+        if ring:
+            # entry r <- logical block j_r; past the lane's last block
+            # the entry's own number (rows no query may read yet)
+            last = jnp.maximum(prim_d["cache_index"].reshape(-1)[0] - 1,
+                               0) // block_size
+            r = jnp.arange(max_blocks)
+            logical = jnp.where(r <= last, last - (last - r) % max_blocks, r)
+            tokens = (logical[:, None] * block_size +
+                      jnp.arange(block_size)[None]).reshape(-1)
 
         # every layer's blocks in ONE scatter of whole blocks into the
         # stack viewed as `layers * num_blocks` blocks (layer l's block
@@ -317,7 +368,9 @@ def assign_paged(pool, primed, slot, table_row):
             # (rows a block, heads[, dim]): `block_size`, or fewer for
             # a leaf that holds a row every few tokens
             rest = pool_leaf.shape[lead + 1:]
-            src = prim_leaf.reshape((layers,) + prim_leaf.shape[lead:])[
+            lanes = prim_leaf.reshape((layers,) + prim_leaf.shape[lead:])
+            src = jnp.take(lanes[:, 0], tokens, axis=1, mode="clip") \
+                if ring else lanes[
                 :, 0, :max_blocks * rest[0]]     # [L, V, heads, dim] fp
             val = src.astype(pool_leaf.dtype) if pick is None else \
                 pick(quantize_kv(src))

@@ -630,3 +630,103 @@ def test_keye_window_program_scores_and_selects_in_tiles(
     held = sum(leaf.size * leaf.dtype.itemsize
                for leaf in jax.tree_util.tree_leaves(cache))
     assert mem.alias_size_in_bytes >= held
+
+
+@pytest.fixture(scope="module")
+def trinity_engine():
+    """The benchmark's Trinity configuration at its full widths (the
+    published layers 5-9, 32 of 256 experts held, an eighth of the
+    vocabulary, 33,792 positions) behind the engine, parameters as
+    shapes, 4 lanes of 264 + 48 blocks instead of 16."""
+    import json
+    import os
+
+    from benchmarks.lib import manifest
+    from fengshen_tpu.serving import ContinuousBatchingEngine, EngineConfig
+    with open(os.path.join(manifest.ROOT, "benchmarks", "configs",
+                           "trinity-large-preview.json")) as f:
+        config = json.load(f)
+    model, cfg = manifest.family(config).build(config)
+    assert (cfg.hidden_size, cfg.head_dim, cfg.num_key_value_heads,
+            cfg.num_experts, cfg.experts_held, cfg.sliding_window) == (
+        3072, 128, 8, 256, (0, 32), 4096)
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    eng = ContinuousBatchingEngine(model, params, EngineConfig(
+        num_slots=4, buckets=(2048,), max_new_tokens=1024,
+        kv_layout="paged", kv_block_size=128, kv_num_blocks=4 * 264 + 1,
+        kv_max_blocks_per_slot=264, kv_ring_num_blocks=4 * 48 + 1,
+        kv_ring_blocks_per_slot=48))
+    return eng, params
+
+
+def test_trinity_assign_and_tick_keep_both_pools_in_place(
+        one_chip, no_compile_cache, trinity_engine, monkeypatch):
+    """The assign program and the decode tick over TWO pools (the full
+    layer's rows behind the lane-long table, the four window layers' a
+    ring behind a second one): no copy, transpose or slice of an array
+    of either pool's shape, both donated pools aliased to the returned
+    ones; with the backend's kernels on, both kinds of layer read
+    through the paged Mosaic kernel (8 KV heads of 128: the Mistral
+    cells' shape) and the experts stay on `ragged_dot`."""
+    from fengshen_tpu.ops import pallas as kernels
+    monkeypatch.setitem(kernels._PROBE_CACHE, ("cpu", None),
+                        kernels.KernelProbe("tpu", True, None, "aot"))
+    eng, params = trinity_engine
+    tree = eng._cache["model"]
+    full, ring = tree["full_rows"], tree["window_rows"]
+    assert full["cached_key"].shape == (1, 1057, 128, 8, 128)
+    assert ring["cached_window_key"].shape == (4, 193, 128, 8, 128)
+    assert full["block_table"].shape == (1, 4, 264)
+    assert ring["block_table"].shape == (4, 4, 48)
+    pools = [full["cached_key"], full["cached_value"],
+             ring["cached_window_key"], ring["cached_window_value"]]
+    shapes = {p.shape for p in pools} | {p.shape[1:] for p in pools}
+    nbytes = sum(p.nbytes for p in pools)
+    tick = eng._decode_jit.lower(*_abstract(
+        (params, eng._cache, eng._history, eng._mask,
+         jnp.asarray(eng._last_tok), jnp.asarray(eng._pos),
+         jnp.asarray(eng._phys), jnp.asarray(eng._active), eng._keys),
+        one_chip)).compile()
+    assert not _big_copies(tick, shapes)
+    assert tick.memory_analysis().alias_size_in_bytes >= nbytes
+    text = tick.as_text()
+    assert text.count("fstpu_decode_attention") >= 5
+    assert "ragged-dot" in text or "ragged_dot" in text
+    i32 = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=one_chip)
+    window_args = _qwen3next_window_args(eng, params, one_chip)
+    primed, _ = jax.eval_shape(eng._window_jit, *window_args)
+    assign = eng._assign_jit.lower(*(_abstract(
+        (eng._cache, eng._history, eng._mask, eng._last_tok, primed),
+        one_chip) + (i32(eng.seq_capacity), i32(eng.seq_capacity),
+                     i32(264), i32(48), i32(), i32()))).compile()
+    assert not _big_copies(assign, shapes)
+    assert assign.memory_analysis().alias_size_in_bytes >= nbytes
+
+
+def test_trinity_window_program_reads_a_band_and_walks_in_blocks(
+        one_chip, no_compile_cache, trinity_engine):
+    """A 2,048-token window onto the carried batch-1 cache of 33,792
+    rows: no `[.., 2048, 33792]` score tensor in the full layer, no
+    scores wider than a tile of 512 queries against 1,024 keys in the
+    window layers, no copy of either kind's rows, the donated cache
+    aliased to the returned one."""
+    eng, params = trinity_engine
+    args = _qwen3next_window_args(eng, params, one_chip)
+    compiled = eng._window_jit.lower(*args).compile()
+    wide = [line.strip()[:120] for line in compiled.as_text().splitlines()
+            if re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[[\d,]*(2048[\d,]*"
+                        r"33792|33792[\d,]*2048)", line)]
+    assert not wide, wide
+    cache = args[1]["model"]
+    assert cache["window_rows"]["cached_window_key"].shape == \
+        (4, 1, 33792, 8, 128)
+    assert not _big_copies(compiled, {
+        leaf.shape for leaf in jax.tree_util.tree_leaves(cache)
+        if len(leaf.shape) == 5})
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.0e9              # 0.57 GB, PR 41
+    held = sum(leaf.size * leaf.dtype.itemsize
+               for leaf in jax.tree_util.tree_leaves(cache))
+    assert mem.alias_size_in_bytes >= held

@@ -1,0 +1,99 @@
+"""The lowered decode, window and assign programs of the families whose
+cache declares NO ring are, byte for byte, what they were before the
+pool learned a ring (PR 41): `serving/paged_cache.py` and the engine
+took a second table and a second free list, and a model without
+`cached_window_*` leaves must not see either. The hashes are of
+`jit(...).lower(...).as_text()` at tiny sizes on the CPU, taken on the
+commit PR 41 started from and equal on its own tree; a PR that changes
+one of these programs on purpose replaces its hash here and says so."""
+
+import hashlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from fengshen_tpu.serving.engine import (ContinuousBatchingEngine,
+                                         EngineConfig)
+
+SHA = {
+    "llama.decode": "5a1add90e836d82a",
+    "llama.window": "053f4a282aafb0ae",
+    "llama.assign": "13aa0c5a11ba3006",
+    "joyai.decode": "3f1780b6a2d39053",
+    "joyai.window": "b209a149c84224bd",
+    "joyai.assign": "96810c12d7c41873",
+    "sala.decode": "8aba89589fbc0375",
+    "sala.window": "91b546b9efe1d108",
+    "sala.assign": "7805764a91b2a79d",
+    "qwen3_next.decode": "c7d5fcadf09da984",
+    "qwen3_next.window": "64d62a3b809176e1",
+    "qwen3_next.assign": "b290aa7ede5bb08c",
+    "keye.decode": "ebf5fa33299f43a1",
+    "keye.window": "28a1b92dd8b45c7d",
+    "keye.assign": "2162ac0abf6da2ca",
+}
+
+
+def _model(family):
+    if family == "llama":
+        from fengshen_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+        return LlamaForCausalLM(LlamaConfig(
+            vocab_size=64, hidden_size=32, intermediate_size=64,
+            num_hidden_layers=2, num_attention_heads=4,
+            num_key_value_heads=2, max_position_embeddings=64,
+            scan_layers=True))
+    if family == "joyai":
+        from fengshen_tpu.models.joyai import JoyAIConfig, JoyAIForCausalLM
+        return JoyAIForCausalLM(JoyAIConfig.small_test_config())
+    if family == "sala":
+        from fengshen_tpu.models.sala import SalaConfig, SalaForCausalLM
+        return SalaForCausalLM(SalaConfig.small_test_config())
+    if family == "qwen3_next":
+        from fengshen_tpu.models.qwen3_next import (Qwen3NextConfig,
+                                                    Qwen3NextForCausalLM)
+        return Qwen3NextForCausalLM(Qwen3NextConfig.small_test_config())
+    from fengshen_tpu.models.keye import KeyeConfig, KeyeForCausalLM
+    return KeyeForCausalLM(KeyeConfig.small_test_config())
+
+
+@pytest.fixture(scope="module")
+def lowered():
+    """{family: {program: text}}, each family's engine built once."""
+    made = {}
+
+    def of(family):
+        if family in made:
+            return made[family]
+        model = _model(family)
+        params = jax.eval_shape(lambda: model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+        eng = ContinuousBatchingEngine(model, params, EngineConfig(
+            num_slots=2, buckets=(16,), max_new_tokens=8,
+            kv_layout="paged", kv_block_size=16))
+        assert not eng._ring and eng.ring_blocks == 0
+        sds = lambda t: jax.tree_util.tree_map(  # noqa: E731
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), t)
+        i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+        window_args = (params, jax.eval_shape(eng._fresh_jit), i32(1, 16),
+                       i32(1, eng.seq_capacity), i32(), i32(),
+                       jax.ShapeDtypeStruct((2,), jnp.uint32))
+        primed, _ = jax.eval_shape(eng._window_jit, *window_args)
+        assign_args = sds((eng._cache, eng._history, eng._mask,
+                           eng._last_tok, primed)) + (
+            i32(eng.seq_capacity), i32(eng.seq_capacity),
+            i32(eng.max_blocks_per_slot), i32(), i32())
+        made[family] = {
+            "decode": eng._decode_jit.lower(
+                *sds(eng._decode_args(eng._active))).as_text(),
+            "window": eng._window_jit.lower(*window_args).as_text(),
+            "assign": eng._assign_jit.lower(*assign_args).as_text()}
+        return made[family]
+    return of
+
+
+@pytest.mark.parametrize("name", sorted(SHA))
+def test_a_ring_less_models_program_is_unchanged(lowered, name):
+    family, program = name.split(".")
+    text = lowered(family)[program]
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == SHA[name]
