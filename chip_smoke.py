@@ -335,13 +335,15 @@ def _gated_delta_cases(cfg, rows):
 
 
 def _grouped_matmul_cases(cfg, rows):
-    """The routed experts' products at Keye's published widths (tables
-    of 2048 x 768) over rows sorted by expert: groups of uneven size,
-    some empty, a tile that straddles several, and a last quarter of
-    the rows past the last group (a share's experts not held): the
-    Mosaic grouped matmul against three `ragged_dot`. The rows past the
-    last group are the kernel's zeros; `ragged_dot` leaves them
-    undefined, so they are not compared."""
+    """The routed experts' products at Keye's and JoyAI's published
+    widths (tables of 2048 x 768) over rows sorted by expert, the
+    Mosaic grouped matmul against three `ragged_dot`. A window: groups
+    of uneven size, some empty, a tile that straddles several, and a
+    last quarter of the rows past the last group (a share's experts not
+    held). A decode tick: 2 rows an expert, a third of the groups
+    empty, one tile visited by dozens of groups. The rows past the last
+    group are the kernel's zeros; `ragged_dot` leaves them undefined, so
+    they are not compared."""
     del cfg
     import jax
     import jax.numpy as jnp
@@ -351,25 +353,30 @@ def _grouped_matmul_cases(cfg, rows):
     from fengshen_tpu.ops.pallas.grouped_matmul import _ineligible_reason
     pallas = get_kernel("grouped_matmul", "pallas")
     xla = get_kernel("grouped_matmul", "xla")
-    total, count, hidden, width = 4096, 32, 2048, 768
+    hidden, width = 2048, 768
     rng = np.random.RandomState(SEED + 6)
-    share = rng.dirichlet(np.full(count, 0.5)) * (rng.rand(count) > 0.2)
-    sizes = np.floor(share / share.sum() * total * 0.75).astype(np.int32)
-    held = int(sizes.sum())
-    ks = jax.random.split(jax.random.PRNGKey(SEED + 6), 4)
-    r = jax.random.normal(ks[0], (total, hidden), jnp.bfloat16)
-    g, u = (0.02 * jax.random.normal(k, (count, hidden, width),
-                                     jnp.bfloat16) for k in ks[1:3])
-    d = 0.02 * jax.random.normal(ks[3], (count, width, hidden),
-                                 jnp.bfloat16)
-    assert _ineligible_reason(r, g) is None
-    got = jax.jit(pallas)(r, g, u, d, jnp.asarray(sizes))
-    want = jax.jit(xla)(r, g, u, d, jnp.asarray(sizes))
-    assert not np.asarray(got[held:], np.float32).any()
-    _check(rows, "grouped_matmul",
-           f"rows={list(r.shape)} tables={list(g.shape)} bf16, {held} held "
-           f"in groups of {sizes.min()}..{sizes.max()}", got[:held],
-           want[:held])
+    share = rng.dirichlet(np.full(32, 0.5)) * (rng.rand(32) > 0.2)
+    window = np.floor(share / share.sum() * 4096 * 0.75).astype(np.int32)
+    tick = rng.multinomial(256, rng.dirichlet(np.full(128, 1.0))
+                           ).astype(np.int32)
+    assert (tick == 0).sum() >= 16
+    for what, total, sizes in (("window", 4096, window),
+                               ("tick", 256, tick)):
+        count, held = len(sizes), int(sizes.sum())
+        ks = jax.random.split(jax.random.PRNGKey(SEED + 6), 4)
+        r = jax.random.normal(ks[0], (total, hidden), jnp.bfloat16)
+        g, u = (0.02 * jax.random.normal(k, (count, hidden, width),
+                                         jnp.bfloat16) for k in ks[1:3])
+        d = 0.02 * jax.random.normal(ks[3], (count, width, hidden),
+                                     jnp.bfloat16)
+        assert _ineligible_reason(r, g) is None
+        got = jax.jit(pallas)(r, g, u, d, jnp.asarray(sizes))
+        want = jax.jit(xla)(r, g, u, d, jnp.asarray(sizes))
+        assert not np.asarray(got[held:], np.float32).any()
+        _check(rows, "grouped_matmul",
+               f"{what}: rows={list(r.shape)} tables={list(g.shape)} bf16, "
+               f"{held} held in groups of {sizes.min()}..{sizes.max()}",
+               got[:held], want[:held])
 
 
 def _fused_ce_cases(cfg, rows):

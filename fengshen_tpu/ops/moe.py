@@ -109,12 +109,12 @@ def grouped_swiglu(x: jax.Array, index: jax.Array, weight: jax.Array,
     expert sees its tokens in token order), the rows gathered once, and
     the three SwiGLU products run over the group sizes. The call's
     shape picks how (`ops.pallas.grouped_matmul._ineligible_reason`):
-    with many rows an expert (a prefill window) the Mosaic grouped
-    matmul that reads each touched table once, else
-    :func:`xla_grouped_swiglu` (a decode tick's row or two an expert,
-    any call under a device mesh or off the TPU). Assignments to
-    experts not held sort past the last group; their rows are zeroed
-    rather than trusted."""
+    the Mosaic grouped matmul that reads each touched table once (a
+    prefill window's many rows an expert and a decode tick's row or
+    two alike), else :func:`xla_grouped_swiglu` (any call under a
+    device mesh or off the TPU, rows that are not whole tiles, tables
+    that outgrow VMEM). Assignments to experts not held sort past the
+    last group; their rows are zeroed rather than trusted."""
     # here, not at the top: the registry imports this module's xla form
     from fengshen_tpu.ops.pallas import grouped_matmul as kernel
     from fengshen_tpu.ops.pallas import resolve_dispatch

@@ -295,7 +295,7 @@ register_kernel("folded_decode_attention", "xla", folded_decode_walk)
 register_kernel("gated_delta_prefill", "pallas", pallas_gated_delta_prefill)
 register_kernel("gated_delta_prefill", "xla", xla_gated_delta_prefill)
 # the routed experts' three products over sorted rows (the seam is
-# `ops.moe.grouped_swiglu`, by rows an expert); its xla lowering three
+# `ops.moe.grouped_swiglu`, by the call's shape); its xla lowering three
 # `jax.lax.ragged_dot`
 register_kernel("grouped_matmul", "pallas", pallas_grouped_swiglu)
 register_kernel("grouped_matmul", "xla", xla_grouped_swiglu)

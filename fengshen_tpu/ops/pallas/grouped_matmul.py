@@ -1,12 +1,15 @@
-"""The routed experts' products over many rows an expert, as a Mosaic
-grouped matmul that reads each touched expert's table once.
+"""The routed experts' products as a Mosaic grouped matmul that reads
+each touched expert's table once, whatever the rows an expert: a
+prefill window's hundred and a decode tick's one or two.
 
 `ops/moe.py:grouped_swiglu` sorts a call's `tokens x top_k` assignments
 by expert and has the group sizes; its xla lowering
 (:func:`fengshen_tpu.ops.moe.xla_grouped_swiglu`, three
 `jax.lax.ragged_dot`) is this kernel's twin and its backward. XLA:TPU's
 ragged dot runs a prefill window's products at a third to a fifth of
-the rate at which the touched tables can be read (PERF.md, PR 38).
+the rate at which the touched tables can be read (PERF.md, PR 38) and a
+tick's at 31-73 % of it where this kernel stands at 89-92 % (PERF.md,
+PR 42): its cost is the touched tables' bytes at any row count.
 Here, for one product `out[r] = rows[r] @ tables[group of r]`:
 
 - the grid is a list of VISITS, one a (row tile, group) pair that
@@ -19,11 +22,14 @@ Here, for one product `out[r] = rows[r] @ tables[group of r]`:
   row of the output is defined and the grid is exactly as long as the
   call's work (a dynamic grid bound);
 - a visit's weights are the WHOLE `[in, out]` table of its group, in
-  one of two VMEM slots. The tables stay in HBM (`pl.ANY`); the first
-  visit of a group waits for its table and starts the copy of the NEXT
-  touched group's into the other slot, so the read of a table overlaps
-  all of the previous group's visits, not only its last, and every
-  touched table crosses HBM once a call;
+  one of `_SLOTS` VMEM slots that go round by the group's rank among
+  the touched. The tables stay in HBM (`pl.ANY`); the first visit of a
+  group waits for its table and starts the copy of the touched group
+  TWO after it into the slot of the group before, so two copies are in
+  flight: a visit is as long as its table's copy (7.7 us for 6.3 MB
+  against ~4 us of MXU, at 128 rows as at 2), and with one copy in
+  flight each copy's issue showed (6-9 % a call). Every touched table
+  crosses HBM once a call;
 - the row and output tiles ride the ordinary block pipeline: a tile
   visited by consecutive groups is fetched once and written back once;
 - float32 accumulation, one rounding to the output's dtype.
@@ -47,17 +53,18 @@ from fengshen_tpu.ops.moe import EXPERTS_SCOPE, xla_grouped_swiglu
 
 #: rows a visit: an MXU pass's worth. A smaller tile makes more visits
 #: that each push a whole table through the MXU, a larger one streams
-#: more rows a straddling visit throws away (PERF.md, PR 38)
+#: more rows a straddling visit throws away (PERF.md, PR 38). With a row
+#: or two an expert a visit is its table's copy and tiles of 8 to 128
+#: rows time alike (PERF.md, PR 42)
 TILE = 128
 
-#: the seam takes the kernel from this many assignments an expert held
-#: (a mean; static at trace time). Under it a call is a read of touched
-#: tables with a row or two each: `ragged_dot`'s regime, another kernel
-MIN_ROWS_AN_EXPERT = 8
+#: VMEM slots a table: the visited group's and the next two in flight
+#: (two slots: 6-9 % slower in windows and ticks alike; four: no faster)
+_SLOTS = 3
 
 #: scoped-VMEM ceiling asked of Mosaic (the default is 16 MiB; a v5e
-#: core has 128 MiB), and what of it two slots of a visit's tables may
-#: take (Keye's gate and up: 12.6 MB), beside the row and output tiles
+#: core has 128 MiB), and what of it the slots of a visit's tables may
+#: take (Keye's gate and up: 18.9 MB), beside the row and output tiles
 _VMEM_LIMIT_BYTES = 64 * 2 ** 20
 _TABLE_BYTES = 32 * 2 ** 20
 
@@ -73,9 +80,6 @@ def _ineligible_reason(rows, w_gate) -> Optional[str]:
     if mesh is not None and mesh.size > 1:
         return f"{mesh.size}-device mesh: GSPMD cannot partition a " \
                "Mosaic call"
-    if assignments < MIN_ROWS_AN_EXPERT * count:
-        return f"{assignments / count:.1f} rows an expert under " \
-               f"{MIN_ROWS_AN_EXPERT}: a read of touched tables"
     if assignments % TILE:
         return f"{assignments} rows % {TILE} != 0"
     if hidden % 128 or width % 128:
@@ -84,9 +88,9 @@ def _ineligible_reason(rows, w_gate) -> Optional[str]:
             rows.dtype not in (jnp.bfloat16, jnp.float32):
         return f"rows {rows.dtype.name} against tables " \
                f"{w_gate.dtype.name}"
-    tables = 2 * 2 * hidden * width * w_gate.dtype.itemsize
+    tables = _SLOTS * 2 * hidden * width * w_gate.dtype.itemsize
     if tables > _TABLE_BYTES:
-        return f"two slots of gate and up ({tables} B) outgrow VMEM"
+        return f"{_SLOTS} slots of gate and up ({tables} B) outgrow VMEM"
     return None
 
 
@@ -97,8 +101,8 @@ def _visits(sizes, tiles: int):
     group order and then one a tile that lies wholly past the last
     group; `offsets` `[count + 1]` (group g's rows are `offsets[g] ...
     offsets[g + 1]`), `group` and `tile` `[tiles + count - 1]` a visit,
-    and a group `slot` (the parity of its rank among the touched
-    groups) and `nxt` (the next touched group, -1 after the last)
+    and a group `slot` (its rank among the touched groups modulo
+    `_SLOTS`) and `nxt` (the next touched group, -1 after the last)
     `[count]`."""
     count = sizes.shape[0]
     ends = jnp.cumsum(sizes)
@@ -123,7 +127,7 @@ def _visits(sizes, tiles: int):
     after = jax.lax.cummin(jnp.where(touched, ids, count), reverse=True)
     nxt = jnp.concatenate([after[1:], jnp.full((1,), count, jnp.int32)])
     nxt = jnp.where(nxt == count, -1, nxt).astype(jnp.int32)
-    slot = ((jnp.cumsum(touched) - 1) % 2).astype(jnp.int32)
+    slot = ((jnp.cumsum(touched) - 1) % _SLOTS).astype(jnp.int32)
     offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32),
                                ends.astype(jnp.int32)])
     steps = (made + tiles - held_tiles).astype(jnp.int32)
@@ -146,20 +150,29 @@ def _visit_kernel(offsets, group, tile, slot, nxt, rows_ref, *refs,
                                       sems.at[i, into])
                 for i, (t, b) in enumerate(zip(tables, bufs))]
 
+    def start(hops):
+        """Start the copy of the touched group `hops` after `g`, if
+        there is one, into its slot."""
+        which = g
+        for _ in range(hops):
+            which = jnp.where(which >= 0, nxt[jnp.maximum(which, 0)], -1)
+
+        @pl.when(which >= 0)
+        def _():
+            for c in copies(which, (s + hops) % _SLOTS):
+                c.start()
+
     @pl.when(v == 0)
     def _():
-        for c in copies(g, s):
-            c.start()
+        for hops in range(_SLOTS - 1):
+            start(hops)
 
     @pl.when((v == 0) | (g != group[before]))
     def _():
         for c in copies(g, s):
             c.wait()
-
-        @pl.when(nxt[g] >= 0)
-        def _():
-            for c in copies(nxt[g], 1 - s):
-                c.start()
+        # into the slot of the group before, whose visits are over
+        start(_SLOTS - 1)
 
     @pl.when((v == 0) | (tile[v] != tile[before]))
     def _():
@@ -203,9 +216,9 @@ def grouped_matmul(rows, tables, sizes, *, name: str,
             + [pl.BlockSpec(memory_space=pl.ANY)] * len(tables),
             out_specs=pl.BlockSpec((TILE, width_out),
                                    lambda v, o, g, tile, *_: (tile[v], 0)),
-            scratch_shapes=[pltpu.VMEM((2, width_in, width_out), t.dtype)
+            scratch_shapes=[pltpu.VMEM((_SLOTS, width_in, width_out), t.dtype)
                             for t in tables]
-            + [pltpu.SemaphoreType.DMA((len(tables), 2))]),
+            + [pltpu.SemaphoreType.DMA((len(tables), _SLOTS))]),
         out_shape=jax.ShapeDtypeStruct((total, width_out), rows.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),

@@ -173,20 +173,23 @@ def test_routed_experts_compile_to_the_grouped_matmul_at_published_widths(
 
 
 @pytest.mark.parametrize("tokens,top_k,count,width", [
-    (2048, 8, 128, 768), (2048, 10, 256, 512), (2048, 8, 256, 768)],
-    ids=["keye_window", "qwen3next_window", "joyai_2048"])
-def test_routed_experts_over_a_window_compile_to_the_mosaic_grouped_matmul(
+    (2048, 8, 128, 768), (2048, 10, 256, 512), (2048, 8, 256, 768),
+    (16, 8, 128, 768), (64, 10, 256, 512), (64, 8, 256, 768)],
+    ids=["keye_window", "qwen3next_window", "joyai_2048",
+         "keye_tick", "qwen3next_tick", "joyai_tick"])
+def test_routed_experts_compile_to_the_mosaic_grouped_matmul(
         one_chip, no_compile_cache, monkeypatch, tokens, top_k, count,
         width):
-    """A prefill window's 2,048 tokens x top-k picks over the experts
-    held, at the published widths (Keye 128 tables of 2048 x 768,
-    Qwen3-Next 256 of 512 held at 2048 x 512, JoyAI's 2,048 bucket over
-    256 of 2048 x 768): through the seam the three products are two
-    Mosaic calls named `fstpu_moe_experts...` and no `ragged-dot`; two
-    slots of gate and up fit the kernel's VMEM and every tile is
-    aligned, or the compiler refuses here; the program's operations are
-    those of each row through ONE expert, and it holds no `[tokens,
-    experts, ...]` tensor."""
+    """A prefill window's 2,048 tokens and a decode tick's 16 or 64 x
+    top-k picks over the experts held, at the published widths (Keye
+    128 tables of 2048 x 768, Qwen3-Next 256 of 512 held at 2048 x 512,
+    JoyAI's 2,048 bucket and its tick over 256 of 2048 x 768): through
+    the seam the three products are two Mosaic calls named
+    `fstpu_moe_experts...` and no `ragged-dot`; the slots of gate and
+    up fit the kernel's VMEM and every tile is aligned, or the compiler
+    refuses here; the program's operations are those of each row
+    through ONE expert, and it holds no `[tokens, experts, ...]`
+    tensor."""
     import fengshen_tpu.ops.pallas as kernels
     from fengshen_tpu.ops.moe import EXPERTS_SCOPE, grouped_swiglu
     monkeypatch.setitem(kernels._PROBE_CACHE, ("cpu", None),
@@ -218,6 +221,54 @@ def test_routed_experts_over_a_window_compile_to_the_mosaic_grouped_matmul(
     # bf16 and rows-shaped; none of them experts wide
     rows = tokens * top_k * hidden * 2
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * rows
+
+
+@pytest.mark.parametrize("cell,lanes", [
+    ("joyai-llm-flash", 64), ("keye-vl-2.0-30b-a3b", 16)],
+    ids=["joyai", "keye"])
+def test_a_routed_models_decode_tick_reads_its_experts_through_the_kernel(
+        one_chip, no_compile_cache, monkeypatch, cell, lanes):
+    """The engine's decode program at the benchmark's widths and LANES
+    (JoyAI 64 x top-8 over 256 tables, Keye 16 x top-8 over 128; a pool
+    of 4 blocks a lane): each of the four expert layers' three products
+    are the two Mosaic calls `fstpu_moe_experts_gate_up` / `_down`, and
+    the program holds no `ragged-dot` (PERF.md, PR 42)."""
+    import json
+    import os
+
+    import fengshen_tpu.ops.pallas as kernels
+    from benchmarks.lib import manifest
+    from fengshen_tpu.ops.moe import EXPERTS_SCOPE
+    from fengshen_tpu.serving import ContinuousBatchingEngine, EngineConfig
+    monkeypatch.setitem(kernels._PROBE_CACHE, ("cpu", None),
+                        kernels.KernelProbe("tpu", True, None,
+                                            "described v5e"))
+    with open(os.path.join(manifest.ROOT, "benchmarks", "configs",
+                           cell + ".json")) as f:
+        config = json.load(f)
+    model, _ = manifest.family(config).build(config)
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    eng = ContinuousBatchingEngine(model, params, EngineConfig(
+        num_slots=lanes, buckets=(256,), max_new_tokens=256,
+        kv_layout="paged", kv_block_size=128,
+        kv_num_blocks=lanes * 4 + 1, kv_max_blocks_per_slot=4))
+    # the decisions of the tick alone, not of `model.init`'s 8 tokens
+    monkeypatch.setattr(kernels, "_TRACED", {})
+    tick = eng._decode_jit.lower(*_abstract(
+        (params, eng._cache, eng._history, eng._mask,
+         jnp.asarray(eng._last_tok), jnp.asarray(eng._pos),
+         jnp.asarray(eng._phys), jnp.asarray(eng._active), eng._keys),
+        one_chip)).compile()
+    took = [d for d in kernels.traced_dispatch()
+            if d["op"] == "grouped_matmul"]
+    assert took and all(d["impl"] == "pallas" for d in took), took
+    text = tick.as_text()
+    calls = re.findall(r"%?(" + EXPERTS_SCOPE + r"_(?:gate_up|down)[\w.\-]*)"
+                       r" = [^\n]*custom_call_target=\"tpu_custom_call\"",
+                       text)
+    assert len(calls) == 8, calls
+    assert "ragged-dot" not in text
 
 
 def test_whole_prompt_prefill_holds_the_flash_call_and_no_scores_tensor(
@@ -692,7 +743,11 @@ def test_trinity_assign_and_tick_keep_both_pools_in_place(
     assert tick.memory_analysis().alias_size_in_bytes >= nbytes
     text = tick.as_text()
     assert text.count("fstpu_decode_attention") >= 5
-    assert "ragged-dot" in text or "ragged_dot" in text
+    # four expert layers x gate, up and down: tables of 3,072 x 3,072
+    # outgrow the kernel's VMEM and 64 rows are not whole tiles
+    assert len(re.findall(r"ragged-dot-none[\w.\-]* = [^\n]*custom-call\(",
+                          text)) == 12
+    assert "fstpu_moe_experts_gate_up" not in text
     i32 = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
         shape, jnp.int32, sharding=one_chip)
     window_args = _qwen3next_window_args(eng, params, one_chip)

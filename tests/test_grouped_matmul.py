@@ -1,5 +1,5 @@
-"""`ops/pallas/grouped_matmul.py`: the routed experts' products over
-many rows an expert.
+"""`ops/pallas/grouped_matmul.py`: the routed experts' products, a
+prefill window's many rows an expert and a decode tick's row or two.
 
 The Mosaic kernel in interpret mode (the arithmetic; the compile for
 the chip is `tests/test_compile_for_v5e.py`'s) against
@@ -24,6 +24,18 @@ from fengshen_tpu.ops.pallas import grouped_matmul as gm
 
 TILE = gm.TILE
 
+
+def _tick(lanes, top_k, experts, held, skew, seed):
+    """(rows, rows a held group) of one decode tick: `lanes` tokens pick
+    `top_k` distinct experts of `experts` (Gumbel top-k over weights
+    `exp(skew z)`), the first `held` of them have tables here."""
+    rng = np.random.RandomState(seed)
+    keys = skew * rng.randn(experts) + rng.gumbel(size=(lanes, experts))
+    picks = np.argsort(-keys, axis=1)[:, :top_k].reshape(-1)
+    return lanes * top_k, np.bincount(picks[picks < held],
+                                      minlength=held).tolist()
+
+
 #: name -> (rows of the call, rows a group): each in whole tiles
 LAYOUTS = {
     "balanced": (4 * TILE, [TILE] * 4),
@@ -36,6 +48,17 @@ LAYOUTS = {
     "rows_past_the_last_group": (4 * TILE, [70, 0, 90, 32]),
     "sizes_sum_to_less_than_a_tile": (2 * TILE, [5, 0, 7]),
     "no_row_held": (2 * TILE, [0, 0, 0]),
+    # the three cells' decode ticks, a row or two an expert: 512 rows
+    # over 256 groups of which ~40 are empty;
+    "joyai_tick": _tick(64, 8, 256, 256, 0.38, 1),
+    # 128 rows, ONE tile, over 128 groups of which ~50 are empty;
+    "keye_tick": _tick(16, 8, 128, 128, 0.3, 2),
+    # half of the 640 rows past the last of the 256 groups held
+    "qwen3next_tick": _tick(64, 10, 512, 256, 0.0, 3),
+    # groups of ONE row on both sides of a tile's edge, then a gap of
+    # empty groups, one more row, and a tile that one group fills
+    "one_row_at_a_tiles_edge": (4 * TILE, [TILE - 1, 1, 1, TILE - 2, 0, 0,
+                                           1, TILE] + [0] * 8),
 }
 
 
@@ -97,8 +120,8 @@ def test_visits_touch_each_held_table_once_and_every_tile(layout):
     # a fill visit keeps the last touched table: no fetch
     assert set(group[made:steps]) <= {touched[-1] if touched else 0}
     assert np.asarray(offsets).tolist() == [0] + ends.tolist()
-    # the slots alternate over the touched groups, each names the next
-    assert [int(slot[g]) for g in touched] == [i % 2 for i in
+    # the slots go round over the touched groups, each names the next
+    assert [int(slot[g]) for g in touched] == [i % gm._SLOTS for i in
                                                range(len(touched))]
     assert [int(nxt[g]) for g in touched] == (touched + [-1])[1:]
 
@@ -206,22 +229,26 @@ def _decide(tokens, top_k, count, hidden=2048, width=768,
     (2048, 8, 128, 768, None),              # Keye's window, 16384 / 128
     (2048, 10, 256, 512, None),             # Qwen3-Next's, 20480 / 256
     (2048, 8, 256, 768, None),              # JoyAI's largest bucket
-    (256, 8, 256, 768, None),               # its smallest: on the line
-    (16, 8, 128, 768, "1.0 rows an expert under 8"),     # Keye's tick
-    (64, 10, 256, 512, "2.5 rows an expert under 8"),    # Qwen3-Next's
-    (64, 8, 256, 768, "2.0 rows an expert under 8"),     # JoyAI's
+    (256, 8, 256, 768, None),               # its smallest
+    (16, 8, 128, 768, None),                # Keye's tick, 1 row an expert
+    (64, 10, 256, 512, None),               # Qwen3-Next's, 2.5 (1.25 held)
+    (64, 8, 256, 768, None),                # JoyAI's, 2
+    (16, 4, 32, 768, "64 rows % 128"),      # a tick of Trinity's lanes
     (100, 3, 8, 768, "300 rows % 128"),
     (128, 8, 8, 704, "width 704"),
     (256, 8, 8, 8192, "outgrow VMEM"),
 ], ids=["keye_window", "qwen3next_window", "joyai_2048", "joyai_256",
-        "keye_tick", "qwen3next_tick", "joyai_tick", "ragged_rows",
-        "narrow_lanes", "wide_tables"])
+        "keye_tick", "qwen3next_tick", "joyai_tick", "few_lanes",
+        "ragged_rows", "narrow_lanes", "wide_tables"])
 def test_seam_follows_the_calls_shape(forced, tokens, top_k, count, width,
                                       why):
-    """`grouped_swiglu` chooses its path from rows an expert (static at
-    trace time) through `resolve_dispatch`: the prefill windows of the
-    three routed models take the kernel, their decode ticks
-    `ragged_dot` with the reason on record, and the choice shows on the
+    """`grouped_swiglu` chooses its path from the call's shape (static
+    at trace time) through `resolve_dispatch`: the prefill windows AND
+    the decode ticks of the three routed models take the kernel (at a
+    row or two an expert it reads the touched tables 1.2-2.9x faster
+    than `ragged_dot`: PERF.md, PR 42), rows that are not whole tiles,
+    narrow lanes and tables that outgrow VMEM `ragged_dot` with the
+    reason on record, and the choice shows on the
     `fstpu_kernel_dispatch{op,impl}` gauge and the dispatch line."""
     from fengshen_tpu.observability.registry import MetricsRegistry
     took = _decide(tokens, top_k, count, width=width)

@@ -1,11 +1,19 @@
-"""The lowered decode, window and assign programs of the families whose
-cache declares NO ring are, byte for byte, what they were before the
-pool learned a ring (PR 41): `serving/paged_cache.py` and the engine
+"""The lowered decode, window and assign programs of the serving
+families. Those whose cache declares NO ring are, byte for byte, what
+they were before the pool learned a ring (PR 41): `serving/paged_cache.py` and the engine
 took a second table and a second free list, and a model without
 `cached_window_*` leaves must not see either. The hashes are of
 `jit(...).lower(...).as_text()` at tiny sizes on the CPU, taken on the
 commit PR 41 started from and equal on its own tree; a PR that changes
-one of these programs on purpose replaces its hash here and says so."""
+one of these programs on purpose replaces its hash here and says so.
+
+PR 42 replaced none: the seam that sends the routed experts' decode
+ticks to the Mosaic grouped matmul decides by the call's shape ON A TPU
+(`tests/test_compile_for_v5e.py` pins those programs: eight Mosaic
+calls in JoyAI's and Keye's tick, twelve `ragged-dot` in Trinity's);
+off it every call stays `ragged_dot`, and nothing else moved. It added
+Trinity's three programs (a ring and its table beside the lane-long
+one), taken on the commit PR 42 started from and equal on its tree."""
 
 import hashlib
 
@@ -32,6 +40,9 @@ SHA = {
     "keye.decode": "ebf5fa33299f43a1",
     "keye.window": "28a1b92dd8b45c7d",
     "keye.assign": "2162ac0abf6da2ca",
+    "trinity.decode": "f500c48b548b710b",
+    "trinity.window": "ae7830ae150a1616",
+    "trinity.assign": "c63004b277bfeb20",
 }
 
 
@@ -53,6 +64,10 @@ def _model(family):
         from fengshen_tpu.models.qwen3_next import (Qwen3NextConfig,
                                                     Qwen3NextForCausalLM)
         return Qwen3NextForCausalLM(Qwen3NextConfig.small_test_config())
+    if family == "trinity":
+        from fengshen_tpu.models.trinity import (TrinityConfig,
+                                                 TrinityForCausalLM)
+        return TrinityForCausalLM(TrinityConfig.small_test_config())
     from fengshen_tpu.models.keye import KeyeConfig, KeyeForCausalLM
     return KeyeForCausalLM(KeyeConfig.small_test_config())
 
@@ -71,7 +86,7 @@ def lowered():
         eng = ContinuousBatchingEngine(model, params, EngineConfig(
             num_slots=2, buckets=(16,), max_new_tokens=8,
             kv_layout="paged", kv_block_size=16))
-        assert not eng._ring and eng.ring_blocks == 0
+        assert bool(eng.ring_blocks) == (family == "trinity")
         sds = lambda t: jax.tree_util.tree_map(  # noqa: E731
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), t)
         i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
@@ -82,7 +97,9 @@ def lowered():
         assign_args = sds((eng._cache, eng._history, eng._mask,
                            eng._last_tok, primed)) + (
             i32(eng.seq_capacity), i32(eng.seq_capacity),
-            i32(eng.max_blocks_per_slot), i32(), i32())
+            i32(eng.max_blocks_per_slot)) + (
+            (i32(eng.ring_blocks),) if eng.ring_blocks else ()) + (
+            i32(), i32())
         made[family] = {
             "decode": eng._decode_jit.lower(
                 *sds(eng._decode_args(eng._active))).as_text(),
@@ -93,7 +110,7 @@ def lowered():
 
 
 @pytest.mark.parametrize("name", sorted(SHA))
-def test_a_ring_less_models_program_is_unchanged(lowered, name):
+def test_a_serving_program_is_unchanged(lowered, name):
     family, program = name.split(".")
     text = lowered(family)[program]
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == SHA[name]

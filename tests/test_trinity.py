@@ -450,8 +450,8 @@ def test_moe_counters_count_the_held_experts():
 # ---- (e) the seam's decision for the published expert tables ------------
 
 @pytest.mark.parametrize("rows,reason", [
-    (8192, "two slots of gate and up (75497472 B) outgrow VMEM"),
-    (64, "2.0 rows an expert under 8: a read of touched tables")])
+    (8192, "3 slots of gate and up (113246208 B) outgrow VMEM"),
+    (64, "64 rows % 128 != 0")])
 def test_the_seam_sends_the_published_tables_to_ragged_dot(rows, reason):
     """A 2,048-token window's 8,192 assignments and a 16-lane tick's 64
     against this chip's `[32, 3072, 3072]` tables: both stay on
