@@ -299,6 +299,55 @@ def _folded_decode_cases(cfg, rows):
            f"paged bf16 kv={list(shape)} t={t.tolist()}", got, want)
 
 
+def _mla_decode_cases(cfg, rows):
+    """The seam's latent entry at JoyAI-LLM-Flash's published geometry
+    (32 heads over one shared row of rank 512 + rope 64, padded to 640;
+    blocks of 128 tokens in a `[2, ...]` stack read at layer 1),
+    whatever the smoke's own model is: ragged cursors, left-padded
+    lanes, a released lane; the kernel walks each lane's live blocks,
+    the xla twin gathers every table entry."""
+    del cfg
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from fengshen_tpu.ops.pallas import get_kernel
+    from fengshen_tpu.ops.pallas.decode_attention import (
+        _mla_ineligible_reason)
+    pallas = get_kernel("mla_decode_attention", "pallas")
+    xla = get_kernel("mla_decode_attention", "xla")
+    lanes, heads, rank, rope, width, block, per_lane = 8, 32, 512, 64, 640, \
+        128, 24
+    n_blocks = lanes * per_lane + 1
+    rng = np.random.RandomState(SEED + 5)
+    keys = jax.random.split(jax.random.PRNGKey(SEED + 5), 3)
+    shape = (2, n_blocks, block, 1, width)
+    kv = jax.random.normal(keys[2], shape, jnp.bfloat16)
+    table = jnp.asarray(1 + rng.permutation(n_blocks - 1).reshape(
+        lanes, per_lane), jnp.int32).at[0].set(0)      # a released lane
+    hi = np.array([0, 127, 128, 1023, 1024, per_lane * block - 2] +
+                  list(rng.randint(200, per_lane * block - 2, lanes - 6)))
+    lo = np.minimum(rng.randint(0, 200, lanes), hi)
+    lo[0] = 1                                          # no valid key
+    pos = np.arange(per_lane * block)[None, None, :]
+    for s in (1, 2):              # decode tick, a verify window
+        q = jax.random.normal(keys[0], (lanes, s, heads, rank),
+                              jnp.bfloat16)
+        q_rope = jax.random.normal(keys[1], (lanes, s, heads, rope),
+                                   jnp.bfloat16)
+        valid = jnp.asarray((pos >= lo[:, None, None]) &
+                            (pos <= (hi[:, None] +
+                                     np.arange(s)[None])[:, :, None]))
+        assert _mla_ineligible_reason(q, kv, table) is None
+        kw = dict(scale=192 ** -0.5, block_table=table, layer=jnp.int32(1))
+        got = jax.jit(lambda *a: pallas(*a, **kw))(q, q_rope, kv, valid)
+        want = jax.jit(lambda *a: xla(*a, **kw))(q, q_rope, kv, valid)
+        # the released lane attends nothing: each lowering averages
+        # what it walked
+        _check(rows, "mla_decode_attention",
+               f"paged bf16 S={s} kv={list(shape)}", got[1:], want[1:])
+
+
 def _gated_delta_cases(cfg, rows):
     """The chunked gated delta rule at Qwen3-Next's published head
     geometry (value heads of 128 two to a key head, l2-normalised
@@ -445,6 +494,7 @@ KERNEL_CASES = {
     "flash_attention": _flash_cases,
     "decode_attention": _decode_cases,
     "folded_decode_attention": _folded_decode_cases,
+    "mla_decode_attention": _mla_decode_cases,
     "gated_delta_prefill": _gated_delta_cases,
     "grouped_matmul": _grouped_matmul_cases,
     "fused_ce": _fused_ce_cases,

@@ -267,7 +267,8 @@ from fengshen_tpu.ops.pallas.block_sparse_attention import (  # noqa: E402
     block_sparse_attention as _block_sparse_attention)
 from fengshen_tpu.ops.pallas.decode_attention import (  # noqa: E402
     decode_attention, pallas_decode_attention, pallas_decode_eligible,
-    pallas_folded_decode_attention, xla_decode_attention)
+    pallas_folded_decode_attention, pallas_mla_decode_attention,
+    xla_decode_attention, xla_mla_decode_attention)
 from fengshen_tpu.ops.pallas.flash_attention import (  # noqa: E402
     pallas_flash_attention)
 from fengshen_tpu.ops.pallas.fused_ce import (  # noqa: E402
@@ -289,6 +290,11 @@ register_kernel("decode_attention", "xla", xla_decode_attention)
 register_kernel("folded_decode_attention", "pallas",
                 pallas_folded_decode_attention)
 register_kernel("folded_decode_attention", "xla", folded_decode_walk)
+# the same seam's latent entry (one shared row a token, key and value
+# at once): its own kernel over a paged pool, its xla lowering the
+# gather of the lane
+register_kernel("mla_decode_attention", "pallas", pallas_mla_decode_attention)
+register_kernel("mla_decode_attention", "xla", xla_mla_decode_attention)
 # the chunked gated delta rule of a prefill window (the seam is
 # `ops.gated_delta.gated_delta_prefill`); its xla lowering the
 # `jax.numpy` chunked form

@@ -44,15 +44,23 @@ shape routes through (see fengshen_tpu/ops/pallas/__init__.py):
   behind the multiply — and nothing of the body: it slices each KV head
   out of the block as it lies, in the pool's dtype. Its xla lowering is
   ``ops/gated_attention.folded_decode_walk``.
-- :func:`mla_decode_attention`, :func:`sparse_decode_attention`,
-  :func:`indexed_decode_attention` — the latent, the chosen-block and
-  the chosen-token entries, xla lowerings only.
+- :func:`mla_decode_attention` — the seam's entry for a latent cache:
+  ONE row ``[c_kv | k_rope | zeros]`` a token, key and value at once,
+  shared by every query head. Over a paged pool of whole-lane rows its
+  Mosaic kernel (:func:`_mla_decode_kernel`) shares the folded kernel's
+  walk (:func:`_fetch_step`) with one buffer a slot; slot and lockstep
+  caches and every other shape take :func:`xla_mla_decode_attention`,
+  which gathers the lane.
+- :func:`sparse_decode_attention`, :func:`indexed_decode_attention` —
+  the chosen-block and the chosen-token entries, xla lowerings only.
 
 Tiling (docs/kernels.md): the pallas path requires
 ``head_dim % 128 == 0``, ``block_size % 128 == 0`` and
 ``KVH % 8 == 0`` (the token×head fold is a free reshape only on f32
 sublane tiles); the folded entry's wants the same of ``head_dim`` and
-``block_size`` and one row a token. Other shapes are the xla
+``block_size`` and one row a token; the latent entry's wants whole
+lanes of the row's width, of ``rank`` and of ``block_size``, behind a
+block table. Other shapes are the xla
 lowering's; which one a traced call site took is recorded through
 ``ops.pallas.resolve_dispatch``.
 """
@@ -465,16 +473,17 @@ def pallas_decode_attention(q, k, v, valid, *, k_scale=None,
 #: the name the latent read carries into HLO and the trace
 MLA_TRACE_NAME = "fstpu_mla_decode_attention"
 
-#: why every latent read takes the xla lowering today: the kernel above
-#: folds tokens x KV heads into one key axis (`KVH % 8`, `head_dim %
-#: 128`); a latent row is ONE shared head of `rank + rope` values
-_NO_LATENT_KERNEL = "no Mosaic kernel walks one shared latent head yet"
+#: latent blocks a step of the kernel's walk (1,024 keys at 128; the
+#: step sizes measured alone on a v5e are in PERF.md, PR 43)
+_MLA_BLOCKS_PER_STEP = 8
 
 
 def mla_decode_attention(q_latent: jax.Array, q_rope: jax.Array,
                          kv: jax.Array, valid: jax.Array, *, scale: float,
                          block_table: Optional[jax.Array] = None,
-                         layer: Optional[jax.Array] = None) -> jax.Array:
+                         layer: Optional[jax.Array] = None,
+                         impl: Optional[str] = None,
+                         interpret: bool = False) -> jax.Array:
     """The seam's latent entry: absorbed multi-head latent attention
     over a cache of one row `[c_kv (rank) | k_rope | zeros]` per token
     (the zeros pad the row to whole lanes; the row is as wide as the
@@ -489,19 +498,55 @@ def mla_decode_attention(q_latent: jax.Array, q_rope: jax.Array,
     ``[B, S, L]`` bool. All ``H`` query heads share the row: its first
     ``rank`` values are the key's no-position part AND the value.
     Returns ``[B, S, H, rank]``, still latent: the caller's
-    up-projection turns it into heads of values."""
-    from fengshen_tpu.ops.pallas import resolve_dispatch
-    # recorded, not decided: the xla lowering is the only one there is
-    resolve_dispatch(
-        "mla_decode_attention",
-        f"q={tuple(q_latent.shape)}+{q_rope.shape[-1]} "
-        f"kv={tuple(kv.shape[-4:])}:{kv.dtype.name} "
-        f"{'paged' if block_table is not None else 'slot'}",
-        _NO_LATENT_KERNEL)
+    up-projection turns it into heads of values. The cache's shape
+    picks the path (:func:`_mla_ineligible_reason`): the Mosaic kernel
+    :func:`pallas_mla_decode_attention` for a paged pool of whole-lane
+    rows, else :func:`xla_mla_decode_attention`, the CPU tier-1 truth.
+    ``impl`` forces either, as in :func:`decode_attention`."""
+    if impl is None:
+        from fengshen_tpu.ops.pallas import resolve_dispatch
+        impl = resolve_dispatch(
+            "mla_decode_attention",
+            f"q={tuple(q_latent.shape)}+{q_rope.shape[-1]} "
+            f"kv={tuple(kv.shape[-4:])}:{kv.dtype.name} "
+            f"{'paged' if block_table is not None else 'slot'}",
+            _mla_ineligible_reason(q_latent, kv, block_table))
+    if impl == "pallas":
+        return pallas_mla_decode_attention(
+            q_latent, q_rope, kv, valid, scale=scale,
+            block_table=block_table, layer=layer, interpret=interpret)
     with jax.named_scope(MLA_TRACE_NAME):
         return xla_mla_decode_attention(
             q_latent, q_rope, kv, valid, scale=scale,
             block_table=block_table, layer=layer)
+
+
+def _mla_ineligible_reason(q_latent, kv, block_table) -> Optional[str]:
+    """Why a latent cache of this shape cannot take the Mosaic kernel,
+    or None when it can."""
+    s, rank = q_latent.shape[1], q_latent.shape[-1]
+    block_size, one, width = kv.shape[-3:]
+    if block_table is None:
+        return "a slot cache has no block table to walk"
+    if s > _MAX_QUERY_WINDOW:
+        return f"query window {s} > {_MAX_QUERY_WINDOW}"
+    if one != 1:
+        return f"rows {tuple(kv.shape[-2:])} are not one shared head"
+    if width % 128 != 0:
+        return f"row width {width} % 128 != 0"
+    if rank % 128 != 0:
+        return f"rank {rank} % 128 != 0"
+    if block_size % 128 != 0:
+        return f"block_size {block_size} % 128 != 0"
+    return None
+
+
+def _latent_query(q_latent, q_rope, width, dtype):
+    """``[q_latent | q_rope | 0]``: the query against a whole cache row
+    `[c_kv | k_rope | zeros]`, in the row's dtype."""
+    pad = jnp.zeros(q_rope.shape[:-1] + (
+        width - q_latent.shape[-1] - q_rope.shape[-1],), q_rope.dtype)
+    return jnp.concatenate([q_latent, q_rope, pad], axis=-1).astype(dtype)
 
 
 def xla_mla_decode_attention(q_latent, q_rope, kv, valid, *, scale,
@@ -527,15 +572,192 @@ def xla_mla_decode_attention(q_latent, q_rope, kv, valid, *, scale,
         if layer is not None:
             kv = kv[layer]
         rows = kv[:, :, 0, :]
-    pad = jnp.zeros(q_rope.shape[:-1] + (
-        rows.shape[-1] - rank - q_rope.shape[-1],), q_rope.dtype)
-    q = jnp.concatenate([q_latent, q_rope, pad], axis=-1).astype(rows.dtype)
+    q = _latent_query(q_latent, q_rope, rows.shape[-1], rows.dtype)
     scores = jnp.einsum("bshd,btd->bhst", q, rows,
                         preferred_element_type=jnp.float32) * scale
     scores = jnp.where(valid[:, None], scores, _NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(rows.dtype)
     return jnp.einsum("bhst,btc->bshc", probs,
                       rows[..., :rank]).astype(q_latent.dtype)
+
+
+def _fetch_step(table_ref, pools, sems, n_live, lane, j, slot, block_size,
+                wait=False):
+    """Start (or wait for) the DMAs that bring step ``j`` of ``lane``
+    into ``slot``: of its blocks (as many as a slot holds) those among
+    the lane's ``n_live`` — a step's tail past the lane's last live
+    block is not fetched. ``pools``: ``(pool in HBM, its ``[2, span,
+    width]`` buffer)`` pairs that one table reads; ``sems``: ``[pool,
+    slot, block of the step]``."""
+    per = pools[0][1].shape[1] // block_size
+    for i in range(per):
+        @pl.when(j * per + i < n_live)
+        def _one():
+            block = table_ref[lane, j * per + i]
+            at = pl.ds(i * block_size, block_size)
+            for x, (hbm, buf) in enumerate(pools):
+                dma = pltpu.make_async_copy(
+                    hbm.at[block], buf.at[slot, at], sems.at[x, slot, i])
+                if wait:
+                    dma.wait()
+                else:
+                    dma.start()
+
+
+def _mla_decode_kernel(table_ref, live_ref, q_ref, kv_hbm, mask_ref, o_ref,
+                       buf, sems, slot_ref, acc_ref, m_ref, l_ref, *,
+                       scale, rank, block_size):
+    """One lane a grid step; inside it a loop over the lane's LIVE
+    blocks only (``live_ref[lane]`` of them, :func:`_live_blocks`),
+    as many a step as a slot of ``buf`` holds, fetched through the
+    table from the pool left in HBM into one of two VMEM slots while
+    the step before is multiplied; the lane's last step prefetches the
+    NEXT lane's first (``slot_ref`` carries across the sequential grid
+    axis which slot that went into) — the walk of
+    :func:`_folded_decode_kernel`, over a latent row. A step's tail
+    past the lane's last live block is not fetched, a released lane
+    (no valid column, its row on the null block) costs one block, an
+    entry past the cursor nothing.
+
+    ONE buffer a slot: a block ``[block_size, width]`` of rows ``[c_kv
+    (rank) | k_rope | zeros]`` is key and value at once, shared by all
+    ``H`` heads. Scores are ``[H, width] x [width, span]`` of the
+    concatenated query ``[q_latent | q_rope | 0]`` against the block as
+    it lies; the probabilities weigh the buffer's first ``rank``
+    columns (a static slice at a multiple of 128 lanes). No head fold,
+    no mask of other heads' columns, no float32 copy of the block.
+
+    Precision, that of :func:`xla_mla_decode_attention`: operands in
+    the pool's dtype, float32 accumulation, the scale applied to the
+    float32 scores, probabilities rounded to the row's dtype before
+    the second product; online-softmax statistics and the ``[H, rank]``
+    accumulator in float32 scratch across the lane's steps. Every live
+    row is read whole. The mask is the lane's tile of ``valid``, a row
+    a step (holes at the FRONT of a lane, a left-padded prompt, are
+    inside the walk); a query position of a verify window is one more
+    pass of the same loop body over the fetched step."""
+    lane = pl.program_id(0)
+    n_lanes = pl.num_programs(0)
+    per = buf.shape[1] // block_size
+    n_steps = (live_ref[lane] + per - 1) // per
+    pools = ((kv_hbm, buf),)
+
+    def fetch(lane, j, slot, wait=False):
+        _fetch_step(table_ref, pools, sems, live_ref[lane], lane, j, slot,
+                    block_size, wait)
+
+    @pl.when(lane == 0)
+    def _first_fetch():
+        slot_ref[0] = 0
+        # a step's unfetched tail is weighed by exact zeros: whatever
+        # the buffer holds there must not be NaN
+        buf[...] = jnp.zeros_like(buf)
+        fetch(0, 0, 0)
+
+    first_slot = slot_ref[0]
+    slot_ref[0] = (first_slot + n_steps) % 2
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    def walk(j, _):
+        slot = (first_slot + j) % 2
+        more = j + 1 < n_steps
+        next_lane = jnp.where(more, lane, lane + 1)
+
+        @pl.when(next_lane < n_lanes)
+        def _prefetch():
+            fetch(next_lane, jnp.where(more, j + 1, 0), 1 - slot)
+
+        fetch(lane, j, slot, wait=True)
+        for s in range(q_ref.shape[1]):
+            scores = jax.lax.dot_general(
+                q_ref[0, s], buf[slot], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # [H, span]
+            scores = jnp.where(mask_ref[0, s, pl.ds(j, 1), :] > 0, scores,
+                               _NEG_INF)
+            m_prev = m_ref[s]
+            m_new = jnp.maximum(m_prev, scores.max(-1, keepdims=True))
+            correction = jnp.exp(m_prev - m_new)
+            probs = jnp.exp(scores - m_new)
+            l_ref[s] = l_ref[s] * correction + probs.sum(-1, keepdims=True)
+            pv = jax.lax.dot_general(
+                probs.astype(buf.dtype), buf[slot, :, pl.ds(0, rank)],
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)          # [H, rank]
+            acc_ref[s] = acc_ref[s] * correction + pv
+            m_ref[s] = m_new
+
+    jax.lax.fori_loop(0, n_steps, walk, None)
+    o_ref[0] = (acc_ref[...] /
+                jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+
+
+def pallas_mla_decode_attention(q_latent, q_rope, kv, valid, *, scale,
+                                block_table, layer=None,
+                                blocks_per_step: Optional[int] = None,
+                                interpret: bool = False):
+    """The latent read as a Mosaic kernel (:func:`_mla_decode_kernel`).
+    Same contract as :func:`mla_decode_attention` over a paged pool:
+    the pool stays in HBM and is read THROUGH ``block_table``, each
+    live block of a lane once and no gathered copy written; a stack is
+    read in place as one pool of ``L x num_blocks`` blocks. A step
+    takes ``blocks_per_step`` blocks. How far a lane's row is walked
+    comes from ``valid`` (:func:`_live_blocks`). Named and scoped
+    ``MLA_TRACE_NAME`` with the query's concatenation and the mask's
+    tiles inside the scope, so the trace finds the same work by that
+    text whichever lowering ran."""
+    if block_table is None:
+        raise ValueError("the latent kernel walks a block table; a slot "
+                         "cache is xla_mla_decode_attention's")
+    batch, s, n_heads, rank = q_latent.shape
+    block_size, _, width = kv.shape[-3:]
+    max_blocks = block_table.shape[-1]
+    per = min(blocks_per_step or _MLA_BLOCKS_PER_STEP, max_blocks)
+    span, n_steps = per * block_size, -(-max_blocks // per)
+    with jax.named_scope(MLA_TRACE_NAME):
+        if layer is not None:
+            block_table = block_table + layer * kv.shape[1]
+        q = _latent_query(q_latent, q_rope, width, kv.dtype)
+        # the lane's mask, a row a step of the walk
+        mask = jnp.pad(valid.astype(jnp.int32), (
+            (0, 0), (0, 0), (0, n_steps * span - valid.shape[-1]))
+        ).reshape(batch, s, n_steps, span)
+        kernel = functools.partial(_mla_decode_kernel, scale=scale,
+                                   rank=rank, block_size=block_size)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(batch,),
+            in_specs=[
+                pl.BlockSpec((1, s, n_heads, width),
+                             lambda b, *_: (b, 0, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec((1, s, n_steps, span),
+                             lambda b, *_: (b, 0, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, s, n_heads, rank),
+                                   lambda b, *_: (b, 0, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, span, width), kv.dtype),
+                pltpu.SemaphoreType.DMA((1, 2, per)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((s, n_heads, rank), jnp.float32),
+                pltpu.VMEM((s, n_heads, 1), jnp.float32),
+                pltpu.VMEM((s, n_heads, 1), jnp.float32),
+            ],
+        )
+        # a row's unit axis and a stack's layer axis go: a free reshape,
+        # the blocks stay put
+        return pl.pallas_call(
+            kernel, grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(q_latent.shape, q_latent.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+            interpret=interpret, name=MLA_TRACE_NAME,
+        )(block_table.astype(jnp.int32), _live_blocks(valid, block_size), q,
+          kv.reshape(-1, block_size, width), mask)
 
 
 SPARSE_TRACE_NAME = "fstpu_sparse_decode_attention"
@@ -797,8 +1019,8 @@ def _folded_decode_kernel(table_ref, t_ref, q_ref, k_hbm, v_hbm, o_ref,
                           k_buf, v_buf, sems, slot_ref, acc_ref, m_ref,
                           l_ref, *, scale, groups, block_size):
     """One lane a grid step; inside it a loop over the lane's LIVE
-    blocks only (``t // block_size + 1`` of them), ``per`` blocks a
-    step, fetched through the table into one of two VMEM slots while
+    blocks only (``t // block_size + 1`` of them), as many a step as a
+    slot holds, fetched through the table into one of two VMEM slots while
     the step before is multiplied; the lane's last step prefetches the
     NEXT lane's first (``slot_ref`` carries across the sequential grid
     axis which slot that went into) — the walk of
@@ -821,28 +1043,13 @@ def _folded_decode_kernel(table_ref, t_ref, q_ref, k_hbm, v_hbm, o_ref,
     lane = pl.program_id(0)
     n_lanes = pl.num_programs(0)
     span = k_buf.shape[1]
-    per = span // block_size
     t = t_ref[lane]
     n_steps = t // span + 1
+    pools = ((k_hbm, k_buf), (v_hbm, v_buf))
 
     def fetch(lane, j, slot, wait=False):
-        """Start (or wait for) the DMAs that bring step ``j`` of
-        ``lane`` into ``slot``: those of its ``per`` blocks that hold a
-        key."""
-        n_live = t_ref[lane] // block_size + 1
-        for i in range(per):
-            @pl.when(j * per + i < n_live)
-            def _one():
-                block = table_ref[lane, j * per + i]
-                at = pl.ds(i * block_size, block_size)
-                for x, (hbm, buf) in enumerate(((k_hbm, k_buf),
-                                                (v_hbm, v_buf))):
-                    dma = pltpu.make_async_copy(
-                        hbm.at[block], buf.at[slot, at], sems.at[x, slot, i])
-                    if wait:
-                        dma.wait()
-                    else:
-                        dma.start()
+        _fetch_step(table_ref, pools, sems, t_ref[lane] // block_size + 1,
+                    lane, j, slot, block_size, wait)
 
     @pl.when(lane == 0)
     def _first_fetch():

@@ -471,6 +471,95 @@ def test_folded_decode_kernel_compiles_at_the_qwen3next_cells_shapes(
     assert not copies, copies
 
 
+@pytest.mark.parametrize("window", [1, 4], ids=["tick", "verify_window"])
+def test_latent_decode_kernel_compiles_at_the_joyai_cells_shapes(
+        one_chip, no_compile_cache, window):
+    """The latent kernel for the chip at `joyai_reason_saturated`'s
+    geometry: 64 lanes, 32 heads over one shared row of 640 (rank 512 +
+    rope 64 + zeros), a `[5, 1537, ...]` stack of 128-token blocks
+    behind a 24-wide table, read in place through a traced `layer`; one
+    query a lane and a verify window of four. The program is the one
+    custom call, found in the trace by the scope's name; nothing
+    pool-sized is copied and no lane's 24 blocks are gathered."""
+    from fengshen_tpu.ops.pallas.decode_attention import (
+        MLA_TRACE_NAME, mla_decode_attention)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def call(q_latent, q_rope, kv, valid, table, layer):
+        return mla_decode_attention(
+            q_latent, q_rope, kv, valid, scale=192 ** -0.5,
+            block_table=table, layer=layer, impl="pallas")
+    text = jax.jit(call).lower(
+        shape((64, window, 32, 512), jnp.bfloat16),
+        shape((64, window, 32, 64), jnp.bfloat16),
+        shape((5, 1537, 128, 1, 640), jnp.bfloat16),
+        shape((64, window, 3072), jnp.bool_), shape((64, 24), jnp.int32),
+        shape((), jnp.int32)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1 and MLA_TRACE_NAME in text
+    copies = [line.strip()[:120] for line in text.splitlines()
+              if re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[(5,)?1537,128,"
+                          r"(1,)?640\][^=]* (copy|dynamic-slice|transpose)\(",
+                          line)]
+    assert not copies, copies
+    assert "[1536,128,640]" not in text
+
+
+def test_joyai_decode_tick_reads_the_latent_pool_through_the_kernel(
+        one_chip, no_compile_cache, monkeypatch):
+    """The engine's decode program at the benchmark's widths, lanes and
+    pool (64 lanes of 24 blocks, a `[5, 1537, 128, 1, 640]` stack): the
+    seam sends each of the five layers' latent read to the Mosaic
+    kernel by the pool's shape, the program holds five such calls by
+    the scope's name and no `[1536, 128, 640]` gather of every lane's
+    table row (252 MB a layer with the xla lowering, fifteen arrays of
+    that shape in the parent's program; PERF.md, PR 43), and the stack
+    is neither copied nor sliced."""
+    import json
+    import os
+
+    import fengshen_tpu.ops.pallas as kernels
+    from benchmarks.lib import manifest
+    from fengshen_tpu.ops.pallas.decode_attention import MLA_TRACE_NAME
+    from fengshen_tpu.serving import ContinuousBatchingEngine, EngineConfig
+    monkeypatch.setitem(kernels._PROBE_CACHE, ("cpu", None),
+                        kernels.KernelProbe("tpu", True, None,
+                                            "described v5e"))
+    with open(os.path.join(manifest.ROOT, "benchmarks", "configs",
+                           "joyai-llm-flash.json")) as f:
+        config = json.load(f)
+    model, _ = manifest.family(config).build(config)
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    eng = ContinuousBatchingEngine(model, params, EngineConfig(
+        num_slots=64, buckets=(256,), max_new_tokens=1024,
+        kv_layout="paged", kv_block_size=128, kv_num_blocks=64 * 24 + 1,
+        kv_max_blocks_per_slot=24))
+    pool = eng._cache["model"]["cached_latent"]
+    assert pool.shape == (5, 1537, 128, 1, 640)
+    # the decisions of the tick alone, not of `model.init`'s 8 tokens
+    monkeypatch.setattr(kernels, "_TRACED", {})
+    tick = eng._decode_jit.lower(*_abstract(
+        (params, eng._cache, eng._history, eng._mask,
+         jnp.asarray(eng._last_tok), jnp.asarray(eng._pos),
+         jnp.asarray(eng._phys), jnp.asarray(eng._active), eng._keys),
+        one_chip)).compile()
+    took = [d for d in kernels.traced_dispatch()
+            if d["op"] == "mla_decode_attention"]
+    assert took and all(d["impl"] == "pallas" for d in took), took
+    text = tick.as_text()
+    calls = re.findall(r"%?(" + MLA_TRACE_NAME + r"[\w.\-]*) = [^\n]*"
+                       r"custom_call_target=\"tpu_custom_call\"", text)
+    assert len(calls) == 5, calls
+    assert "[1536,128,640]" not in text
+    assert not _big_copies(tick, {pool.shape, pool.shape[1:],
+                                  (5 * 1537, 128, 640), (1537, 128, 640)})
+    mem = tick.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool.nbytes
+    assert mem.temp_size_in_bytes < 0.1e9      # 21 MB; 269 with the gather
+
+
 @pytest.fixture(scope="module")
 def qwen3next_engine():
     """The benchmark's Qwen3-Next configuration at its full widths (one

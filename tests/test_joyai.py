@@ -250,6 +250,46 @@ def test_engine_serves_the_references_greedy_tokens(made):
         got["fstpu_moe_layer_ticks_total"]
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_engine_decodes_the_same_tokens_through_the_latent_kernel(
+        monkeypatch, dtype):
+    """Greedy decode through the continuous-batching engine's paged
+    pool — staggered admissions over two buckets (left-padded prompts),
+    a reclaimed lane, a lane parked on the null block — once with the
+    seam's latent read forced to the Mosaic kernel (interpret mode: its
+    arithmetic on the CPU) and once to the xla lowering: the same
+    tokens, request by request."""
+    import functools
+    seam = modeling_joyai.mla_decode_attention
+    model, params, _, _ = _make(dtype)
+    prompts = [_ids(n, seed=n) for n in (5, 11, 16)]
+
+    def served(**forced):
+        calls = []
+
+        def read(*args, block_table=None, **kw):
+            if block_table is None:     # a contiguous cache: xla's
+                return seam(*args, **kw)
+            calls.append(args[2].shape)
+            return seam(*args, block_table=block_table, **kw, **forced)
+        monkeypatch.setattr(modeling_joyai, "mla_decode_attention", read)
+        eng = ContinuousBatchingEngine(
+            model, params, EngineConfig(num_slots=2, buckets=(8, 16),
+                                        max_new_tokens=6, max_queue=8,
+                                        kv_num_blocks=9, **PAGED))
+        reqs = [eng.submit(p) for p in prompts[:2]]
+        for _ in range(3):
+            eng.step()
+        reqs.append(eng.submit(prompts[2]))
+        eng.run_until_idle()
+        assert all(r.state == "finished" for r in reqs)
+        assert (3, 9, 16, 1, 128) in calls     # the paged stack was read
+        return [list(r.tokens) for r in reqs]
+    kernel = served(impl="pallas", interpret=True)
+    assert all(len(t) == 6 for t in kernel)
+    assert kernel == served(impl="xla")
+
+
 def test_lockstep_generate_runs_on_the_contiguous_latent_cache():
     model, params, rcfg, rparams = _make()
     prompt = _ids(9, seed=3)
