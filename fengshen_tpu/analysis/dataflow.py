@@ -17,10 +17,9 @@ tier need to know *what happens next on each path*:
   handles). It flags a release that can be skipped by an exception
   (acquire .. raising-call .. release with no ``finally`` and no broad
   ``except`` that releases) and double-release along a single path.
-- **contract extraction**: the fastapi-decorator and stdlib
-  ``do_GET``-dispatch route surfaces, and every ``fstpu_*`` metric
-  get-or-create site (name, kind, label set) — cheap facts the
-  contract rules diff across files and against docs.
+- **contract extraction**: every ``fstpu_*`` metric get-or-create
+  site (name, kind, label set) — cheap facts the contract rule diffs
+  across files and against docs.
 
 Everything here is pure stdlib ``ast``, runs per file with no project
 state, and returns sorted tuples of primitives, so results are cached
@@ -104,38 +103,6 @@ def _expr_text(node: ast.AST) -> str:
         base = _expr_text(node.value)
         return f"{base}.{node.attr}" if base else ""
     return ""
-
-
-def _as_route_str(node: ast.AST) -> Optional[str]:
-    """A string constant, with f-strings collapsed to their literal
-    prefix + ``*`` (``f"/api/{task}"`` -> ``/api/*``)."""
-    if isinstance(node, ast.Constant) and isinstance(node.value, str):
-        return node.value
-    if isinstance(node, ast.JoinedStr):
-        parts: List[str] = []
-        for v in node.values:
-            if isinstance(v, ast.Constant) and \
-                    isinstance(v.value, str):
-                parts.append(v.value)
-            else:
-                parts.append("*")
-                break
-        return "".join(parts)
-    return None
-
-
-def _str_const_map(tree: ast.Module) -> Dict[str, str]:
-    """name -> string value for every simple ``NAME = "..."`` /
-    ``NAME = f"..."`` assignment anywhere in the file (module
-    constants like route prefixes and metric-name constants)."""
-    out: Dict[str, str] = {}
-    for node in ast.walk(tree):
-        if isinstance(node, ast.Assign) and len(node.targets) == 1 \
-                and isinstance(node.targets[0], ast.Name):
-            s = _as_route_str(node.value)
-            if s is not None:
-                out[node.targets[0].id] = s
-    return out
 
 
 # --------------------------------------------------------------------
@@ -908,95 +875,6 @@ def analyze_lifecycle(tree: ast.Module,
         if isinstance(n, (ast.FunctionDef, ast.AsyncFunctionDef)):
             _LifecycleFlow(n, findings).run()
     return sorted(findings, key=lambda f: (f[3], f[4], f[0], f[2]))
-
-
-# --------------------------------------------------------------------
-# API route surfaces
-# --------------------------------------------------------------------
-
-_HTTP_VERBS = ("delete", "get", "patch", "post", "put")
-_STDLIB_DISPATCH = {"do_DELETE": "DELETE", "do_GET": "GET",
-                    "do_PATCH": "PATCH", "do_POST": "POST",
-                    "do_PUT": "PUT"}
-
-
-def _is_self_path(node: ast.AST) -> bool:
-    return isinstance(node, ast.Attribute) and node.attr == "path" \
-        and isinstance(node.value, ast.Name) and node.value.id == "self"
-
-
-def extract_routes(tree: ast.Module,
-                   ) -> List[Tuple[str, str, str, int, int]]:
-    """Sorted ``(surface, METHOD, raw_path, line, col)`` for both API
-    surfaces of a file: fastapi ``@app.<verb>(path)`` decorators and
-    stdlib ``do_<METHOD>`` dispatchers comparing ``self.path`` (``==``
-    / ``!=`` / ``.startswith``, prefix matches recorded as
-    ``prefix*``). Paths resolve through same-file string constants and
-    f-string prefixes."""
-    consts = _str_const_map(tree)
-    app_names = {
-        t.id
-        for node in ast.walk(tree) if isinstance(node, ast.Assign)
-        for t in node.targets if isinstance(t, ast.Name)
-        if isinstance(node.value, ast.Call) and
-        _expr_text(node.value.func).rsplit(".", 1)[-1] == "FastAPI"}
-
-    def resolve(expr: ast.AST) -> Optional[str]:
-        s = _as_route_str(expr)
-        if s is not None:
-            return s
-        if isinstance(expr, ast.Name):
-            return consts.get(expr.id)
-        return None
-
-    out: List[Tuple[str, str, str, int, int]] = []
-    for node in ast.walk(tree):
-        if not isinstance(node, (ast.FunctionDef,
-                                 ast.AsyncFunctionDef)):
-            continue
-        for dec in node.decorator_list:
-            if isinstance(dec, ast.Call) and \
-                    isinstance(dec.func, ast.Attribute) and \
-                    dec.func.attr in _HTTP_VERBS and \
-                    isinstance(dec.func.value, ast.Name) and \
-                    dec.func.value.id in app_names and dec.args:
-                path = resolve(dec.args[0])
-                if path:
-                    out.append(("fastapi", dec.func.attr.upper(),
-                                path, dec.lineno, dec.col_offset))
-        method = _STDLIB_DISPATCH.get(node.name)
-        if method is None:
-            continue
-        for n in _scan(node):
-            if isinstance(n, ast.Compare) and \
-                    all(isinstance(op, (ast.Eq, ast.NotEq))
-                        for op in n.ops):
-                sides = [n.left] + list(n.comparators)
-                if any(_is_self_path(s) for s in sides):
-                    for s in sides:
-                        p = resolve(s)
-                        if p:
-                            out.append(("stdlib", method, p,
-                                        n.lineno, n.col_offset))
-            elif isinstance(n, ast.Call) and \
-                    isinstance(n.func, ast.Attribute) and \
-                    n.func.attr == "startswith" and \
-                    _is_self_path(n.func.value) and n.args:
-                p = resolve(n.args[0])
-                if p:
-                    out.append(("stdlib", method, p + "*",
-                                n.lineno, n.col_offset))
-    return sorted(set(out))
-
-
-def normalize_route(path: str) -> str:
-    """Comparable form of a route: path params and f-string/prefix
-    wildcards both become ``*``; trailing slashes are insignificant."""
-    p = re.sub(r"\{[^}]*\}", "*", path)
-    p = re.sub(r"\*+", "*", p)
-    if len(p) > 1 and p.endswith("/"):
-        p = p[:-1]
-    return p
 
 
 # --------------------------------------------------------------------

@@ -44,7 +44,7 @@ from typing import Dict, Iterable, Iterator, List, Optional, Set, Tuple
 
 from fengshen_tpu.analysis import dataflow
 
-INDEX_CACHE_VERSION = 4
+INDEX_CACHE_VERSION = 5
 
 #: filled by every build_index() call — files seen, cache hit/miss
 #: split, and whether the in-process memo short-circuited the build.
@@ -265,8 +265,6 @@ class FileSummary:
     # (kind, protocol, var, line, col, other_line, detail)
     lifecycle_findings: List[Tuple] = dataclasses.field(
         default_factory=list)
-    # (surface, METHOD, raw_path, line, col)
-    routes: List[Tuple] = dataclasses.field(default_factory=list)
     # (name, kind, labelnames, line, col)
     metrics: List[Tuple] = dataclasses.field(default_factory=list)
 
@@ -291,7 +289,6 @@ class FileSummary:
                                   self.donation_findings],
             "lifecycle_findings": [list(t) for t in
                                    self.lifecycle_findings],
-            "routes": [list(t) for t in self.routes],
             "metrics": [[t[0], t[1], list(t[2]), t[3], t[4]]
                         for t in self.metrics],
         }
@@ -316,7 +313,6 @@ class FileSummary:
                                d["donation_findings"]],
             lifecycle_findings=[tuple(t) for t in
                                 d["lifecycle_findings"]],
-            routes=[tuple(t) for t in d["routes"]],
             metrics=[(t[0], t[1], tuple(t[2]), t[3], t[4])
                      for t in d["metrics"]])
 
@@ -819,7 +815,6 @@ def summarize_file(path: str, relpath: str) -> FileSummary:
         suppressions=s.suppressions,
         donation_findings=dataflow.analyze_donation_use(tree),
         lifecycle_findings=dataflow.analyze_lifecycle(tree),
-        routes=dataflow.extract_routes(tree),
         metrics=dataflow.extract_metrics(tree))
 
 

@@ -5,7 +5,6 @@ To add rule 7: drop a module here with a ``@register``-decorated
 """
 
 from fengshen_tpu.analysis.rules import (  # noqa: F401
-    api_surface_parity,
     blanket_except,
     blocking_transfer,
     blocking_under_lock,

@@ -1,9 +1,9 @@
-"""REST serving: JSON config → pipeline → FastAPI POST endpoint.
+"""REST serving: JSON config → pipeline → `POST /api/<task>`.
 
 Port of reference: fengshen/API/main.py:12-75 + API/utils.py — a config
 file names the task/model/server options; the server instantiates the
-matching pipeline and exposes `POST /api/<task>`; CORS enabled; run with
-uvicorn. FastAPI/uvicorn are optional deps — gated at call time.
+matching pipeline and exposes `POST /api/<task>`; CORS enabled; served
+by one dependency-free `http.server` (`build_stdlib_server`).
 
     python -m fengshen_tpu.api.main --config text_classification.json
 
@@ -34,11 +34,10 @@ after. `GET /stats` exposes the engine metrics as JSON (now incl.
 `GET /metrics` renders the same registry (plus the process-global one —
 HTTP counters, `fstpu_http_request_seconds{route}` latency histograms,
 span timings, `fstpu_warmup_seconds{phase}`, `fstpu_build_info`) as
-Prometheus text exposition, on BOTH the fastapi and the stdlib server
-paths (docs/observability.md).
+Prometheus text exposition (docs/observability.md).
 
-Debug introspection (docs/serving.md "Debug endpoints"), again on both
-paths: `GET /debug/requests` lists in-flight + recently finished
+Debug introspection (docs/serving.md "Debug endpoints"):
+`GET /debug/requests` lists in-flight + recently finished
 request summaries, `GET /debug/requests/<id>` returns one request's
 full lifecycle timeline and latency waterfall (queue wait / prefill /
 decode phases), and `POST /debug/dump` writes the flight recorder's
@@ -66,7 +65,7 @@ import importlib
 import json
 import threading
 import time
-from typing import Any, Optional
+from typing import Optional
 
 
 @dataclasses.dataclass
@@ -79,7 +78,6 @@ class ServerConfig:
 
     host: str = "0.0.0.0"
     port: int = 8000
-    log_level: str = "info"
     engine: str = "simple"
     warmup: bool = True
     request_timeout_s: float = 120.0
@@ -156,7 +154,7 @@ class Readiness(threading.Event):
 
 
 def _healthz_payload(task: str, ready, draining) -> tuple[int, dict]:
-    """The readiness contract BOTH server paths answer (pinned by
+    """The readiness contract `/healthz` answers (pinned by
     tests): 503 with `{"ready": false, "reason": "warmup"|"draining"|
     "warmup_failed"}` while the replica must not receive traffic, 200
     with `{"ready": true}` otherwise. The legacy `status` key stays for
@@ -203,7 +201,7 @@ def _count_http(route: str, code: int) -> None:
 
 def _observe_http(route: str, seconds: float) -> None:
     """`fstpu_http_request_seconds{route}` beside the counter: the
-    request-latency histogram both API paths feed (docs/observability.md)."""
+    request-latency histogram (docs/observability.md)."""
     from fengshen_tpu.observability.httpmetrics import http_request_seconds
     http_request_seconds().labels(route).observe(seconds)
 
@@ -482,9 +480,8 @@ def _engine_stream(engine, pipeline, req: dict, timeout_s: float):
         for kind, idx, payload in stream.events(start,
                                                 timeout=timeout_s):
             if first:
-                # delivery-layer TTFB: received-to-first-byte, the
-                # headline `serve-bench-stream` reads (the engine's
-                # ttft_seconds keeps its commit-time meaning)
+                # delivery-layer TTFB: received-to-first-byte (the
+                # engine's ttft_seconds keeps its commit-time meaning)
                 engine.metrics.record_stream_ttfb(
                     time.perf_counter() - t0)
                 first = False
@@ -554,262 +551,6 @@ def _multimodal_generate(engine, pipeline, req: dict,
                  "engine_type": engine.engine_type}
 
 
-def build_app(pipeline_cfg: PipelineConfig, pipeline=None,
-              server_cfg: Optional[ServerConfig] = None, engine=None,
-              ready=None, recorder=None, draining=None, disagg=None):
-    """Create the FastAPI app around a pipeline instance. `ready` is an
-    optional `threading.Event`: until set, `GET /healthz` answers 503
-    ("warming") so load balancers keep routing around a replica that is
-    still compiling; None means always ready. `draining` is the mirror
-    event for the way OUT: once set, `/healthz` answers 503 with reason
-    "draining" and new generate requests get 503 while in-flight ones
-    finish (docs/fleet.md). `recorder` enables `POST /debug/dump`.
-    `disagg` is an optional `DisaggCoordinator` enabling the KV-handoff
-    surface (`PUT/GET/DELETE /kv/<id>`, docs/disaggregation.md)."""
-    from fastapi import FastAPI, Header
-    from fastapi.middleware.cors import CORSMiddleware
-    from fastapi.responses import JSONResponse, Response
-    from pydantic import BaseModel
-
-    server_cfg = server_cfg or ServerConfig()
-    if pipeline is None:
-        pipeline = _resolve_pipeline(pipeline_cfg)
-
-    app = FastAPI()
-    app.add_middleware(CORSMiddleware, allow_origins=["*"],
-                       allow_methods=["*"], allow_headers=["*"])
-
-    class Request(BaseModel):
-        input_text: str
-        max_new_tokens: Optional[int] = None
-        # the fleet router's idempotent-safe retry hook: without this
-        # field pydantic silently DROPS the router-assigned id and the
-        # engine dedupe (409 contract) never sees it
-        request_id: Optional[str] = None
-        # distributed-trace context (docs/observability.md): the
-        # router sends it BOTH as this body field and as the
-        # `traceparent` HTTP header; the body form survives proxies
-        # that strip unknown headers
-        traceparent: Optional[str] = None
-        # phase-aware placement directive (docs/disaggregation.md):
-        # the router names the decode replica this prefill replica
-        # should push the primed lane to; pydantic must not drop it
-        disagg_push_to: Optional[str] = None
-        # resume-from-token-k failover (docs/fault_tolerance.md
-        # "Preemption runbook"): the router replays a dead replica's
-        # journaled prefix so the retry prefills prompt+prefix and
-        # decodes only the remainder; pydantic must not drop these
-        resume_tokens: Optional[list] = None
-        resume_source: Optional[str] = None
-        # streaming tier (docs/streaming.md): the per-request sampling
-        # seed, and the reconnect cursor (body form of the SSE
-        # `Last-Event-ID` header — the body wins when both arrive);
-        # pydantic must not drop them
-        seed: Optional[int] = None
-        last_event_id: Optional[int] = None
-
-    api_route = f"/api/{pipeline_cfg.task}"
-    stream_route = f"{api_route}/stream"
-
-    @app.middleware("http")
-    async def _time_request(request, call_next):
-        # the `fstpu_http_request_seconds{route}` histogram beside the
-        # per-route counter (the stdlib path times in _send_bytes)
-        t0 = time.perf_counter()
-        response = await call_next(request)
-        _observe_http(_classify_route(request.url.path, api_route),
-                      time.perf_counter() - t0)
-        return response
-
-    @app.post(api_route)
-    def run(req: Request,
-            traceparent: Optional[str] = Header(None)) -> Any:
-        if draining is not None and draining.is_set():
-            # the engine path would answer the same via Draining; this
-            # ALSO covers the simple path, and spares encode work
-            _count_http(api_route, 503)
-            return JSONResponse(
-                status_code=503,
-                content={"error": "replica draining",
-                         "reason": "draining"})
-        if engine is not None:
-            payload = req.model_dump()
-            if traceparent and not payload.get("traceparent"):
-                # header form of the trace context (the body field
-                # wins when both are present — they are identical
-                # when the fleet router sent them)
-                payload["traceparent"] = traceparent
-            if getattr(engine, "engine_type",
-                       "continuous") == "continuous":
-                code, body = _engine_generate(
-                    engine, pipeline, payload,
-                    server_cfg.request_timeout_s, disagg=disagg)
-            else:
-                code, body = _multimodal_generate(
-                    engine, pipeline, payload,
-                    server_cfg.request_timeout_s)
-            _count_http(api_route, code)
-            return JSONResponse(status_code=code, content=body)
-        if req.max_new_tokens is not None and \
-                _accepts_max_new_tokens(pipeline):
-            result = pipeline(req.input_text,
-                              max_new_tokens=req.max_new_tokens)
-        else:
-            result = pipeline(req.input_text)
-        _count_http(api_route, 200)
-        return {"result": result}
-
-    class StreamRequest(Request):
-        # a reconnect body carries only request_id + last_event_id —
-        # no prompt — so input_text relaxes to optional HERE ONLY (the
-        # handler 422s a fresh submission without it)
-        input_text: Optional[str] = None
-
-    @app.post(stream_route)
-    def run_stream(req: StreamRequest,
-                   traceparent: Optional[str] = Header(None),
-                   last_event_id: Optional[str] = Header(None)) -> Any:
-        from fastapi.responses import StreamingResponse
-        payload = req.model_dump()
-        if traceparent and not payload.get("traceparent"):
-            payload["traceparent"] = traceparent
-        if last_event_id is not None and \
-                payload.get("last_event_id") is None:
-            # the SSE-standard reconnect header; EventSource clients
-            # send it automatically on reconnection
-            try:
-                payload["last_event_id"] = int(last_event_id)
-            except ValueError:
-                pass
-        reconnect = payload.get("request_id") is not None and \
-            payload.get("last_event_id") is not None
-        if not reconnect and payload.get("input_text") is None:
-            _count_http(stream_route, 422)
-            return JSONResponse(status_code=422,
-                                content={"error": "input_text required"})
-        if draining is not None and draining.is_set() and not reconnect:
-            # reconnects pass through the drain edge: a live lane's
-            # reader must still receive its `evacuated` terminal event
-            _count_http(stream_route, 503)
-            return JSONResponse(
-                status_code=503,
-                content={"error": "replica draining",
-                         "reason": "draining"})
-        code, body, frames = _engine_stream(
-            engine, pipeline, payload, server_cfg.request_timeout_s)
-        _count_http(stream_route, code)
-        if frames is None:
-            return JSONResponse(status_code=code, content=body)
-        return StreamingResponse(frames, media_type="text/event-stream",
-                                 headers={"Cache-Control": "no-cache"})
-
-    @app.get("/healthz")
-    def healthz():
-        code, body = _healthz_payload(pipeline_cfg.task, ready,
-                                      draining)
-        _count_http("/healthz", code)
-        if code != 200:
-            return JSONResponse(status_code=code, content=body)
-        return body
-
-    @app.get("/stats")
-    def stats():
-        _count_http("/stats", 200)
-        if engine is not None:
-            # the replica's disaggregation role EXTENDS the pinned
-            # engine payload (same precedent as uptime_s/draining) —
-            # the fleet router's poll keys phase-aware placement on it
-            return dict(engine.stats(), phase=server_cfg.phase)
-        return {"engine": "simple", "task": pipeline_cfg.task,
-                "phase": server_cfg.phase}
-
-    @app.get("/metrics")
-    def metrics():
-        from fengshen_tpu.observability import CONTENT_TYPE_LATEST
-        _count_http("/metrics", 200)
-        return Response(content=_render_metrics(engine, disagg=disagg),
-                        media_type=CONTENT_TYPE_LATEST)
-
-    @app.put("/kv/{request_id}")
-    def kv_put(request_id: str, payload: dict):
-        if disagg is None:
-            _count_http("/kv/<id>", 409)
-            return JSONResponse(
-                status_code=409,
-                content={"adopted": False, "reason": "no_engine"})
-        code, body = disagg.handle_put(request_id, payload)
-        _count_http("/kv/<id>", code)
-        return JSONResponse(status_code=code, content=body)
-
-    @app.get("/kv/{request_id}")
-    def kv_get(request_id: str):
-        if disagg is None:
-            _count_http("/kv/<id>", 404)
-            return JSONResponse(
-                status_code=404,
-                content={"error": "no disagg coordinator"})
-        code, body = disagg.handle_get(request_id,
-                                       server_cfg.request_timeout_s)
-        _count_http("/kv/<id>", code)
-        return JSONResponse(status_code=code, content=body)
-
-    @app.delete("/kv/{request_id}")
-    def kv_delete(request_id: str):
-        if disagg is None:
-            _count_http("/kv/<id>", 404)
-            return JSONResponse(
-                status_code=404,
-                content={"error": "no disagg coordinator"})
-        code, body = disagg.handle_delete(request_id)
-        _count_http("/kv/<id>", code)
-        return JSONResponse(status_code=code, content=body)
-
-    @app.get("/partial/{request_id}")
-    def partial(request_id: str):
-        code, body = _partial_payload(engine, pipeline, request_id)
-        _count_http("/partial/<id>", code)
-        if code != 200:
-            return JSONResponse(status_code=code, content=body)
-        return body
-
-    @app.get("/debug/requests")
-    def debug_requests():
-        _count_http("/debug/requests", 200)
-        return _debug_requests_payload(engine)
-
-    @app.get("/debug/requests/{request_id}")
-    def debug_request(request_id: str):
-        d = engine.debug_request(request_id) \
-            if engine is not None and hasattr(engine, "debug_request") \
-            else None
-        code = 200 if d is not None else 404
-        _count_http("/debug/requests/<id>", code)
-        if d is None:
-            return JSONResponse(
-                status_code=404,
-                content={"error": f"unknown request_id {request_id!r}"})
-        return d
-
-    @app.post("/debug/dump")
-    def debug_dump():
-        if recorder is None:
-            _count_http("/debug/dump", 404)
-            return JSONResponse(
-                status_code=404,
-                content={"error": "no flight recorder configured"})
-        try:
-            bundle = _dump_recorder(recorder, engine)
-        except Exception as e:  # noqa: BLE001 — an unwritable dump_dir
-            # (the sick-host case) must answer, not drop the socket
-            _count_http("/debug/dump", 500)
-            return JSONResponse(status_code=500,
-                                content={"error": str(e)[:500]})
-        _count_http("/debug/dump", 200)
-        return {"bundle": bundle}
-
-    return app
-
-
 def _resolve_pipeline(pipeline_cfg: PipelineConfig):
     module = importlib.import_module(
         f"fengshen_tpu.pipelines.{pipeline_cfg.task}")
@@ -821,18 +562,18 @@ def build_stdlib_server(server_cfg: ServerConfig,
                         pipeline_cfg: PipelineConfig, pipeline=None,
                         engine=None, ready=None, recorder=None,
                         draining=None, disagg=None):
-    """Dependency-free fallback server (http.server) exposing the SAME
-    surface as the FastAPI app: `POST /api/<task>` with
+    """The server (http.server, no dependency): `POST /api/<task>` with
     `{"input_text": ...}`, `GET /healthz` (503 `{"ready": false,
-    "reason": "warmup"}` until the `ready` event is set, 503 with
-    reason "draining" once the `draining` event is set — both mirrored
-    by build_app), `GET /stats`, `GET /metrics`, and the debug
-    introspection routes (`GET /debug/requests[/<id>]`,
-    `POST /debug/dump` when a `recorder` is wired). FastAPI/uvicorn
-    stay the production path; this keeps the REST surface runnable (and
-    testable) where they are not installed. The returned server tracks
-    its in-flight generate requests (`server.in_flight()`) so the
-    SIGTERM drain handler can wait them out (docs/fleet.md)."""
+    "reason": "warmup"}` until the `ready` event is set — None means
+    always ready — and 503 with reason "draining" once the `draining`
+    event is set, while in-flight requests finish), `GET /stats`,
+    `GET /metrics`, the debug introspection routes
+    (`GET /debug/requests[/<id>]`, `POST /debug/dump` when a `recorder`
+    is wired) and, with a `disagg` coordinator, the KV-handoff surface
+    (`PUT/GET/DELETE /kv/<id>`, docs/disaggregation.md). The returned
+    server tracks its in-flight generate requests
+    (`server.in_flight()`) so the SIGTERM drain handler can wait them
+    out (docs/fleet.md)."""
     import http.server
     import threading
 
@@ -1291,60 +1032,23 @@ def main(argv=None) -> None:
         server_cfg.peers = tuple(
             p.strip().rstrip("/") for p in peers_env.split(",")
             if p.strip())
-    # FSTPU_API_SERVER=stdlib forces the stdlib path even where
-    # uvicorn is installed — the fleet launcher sets it because only
-    # this path has the SIGTERM graceful drain (uvicorn installs its
-    # own signal handlers; its shutdown drops in-flight engine waits)
-    use_stdlib = os.environ.get("FSTPU_API_SERVER",
-                                "").lower() == "stdlib"
-    app = None
-    if not use_stdlib:
-        try:
-            app = build_app(pipeline_cfg, pipeline=pipeline,
-                            server_cfg=server_cfg, engine=engine,
-                            ready=ready, recorder=recorder,
-                            draining=draining, disagg=disagg)
-            import uvicorn
-        except ModuleNotFoundError:
-            app = None
-    if app is None:
-        server = build_stdlib_server(server_cfg, pipeline_cfg,
-                                     pipeline=pipeline, engine=engine,
-                                     ready=ready, recorder=recorder,
-                                     draining=draining, disagg=disagg)
-        # graceful drain replaces the recorder's dump-then-die SIGTERM
-        # chain installed above (the dump still happens, post-drain)
-        install_drain_handler(server, draining, engine=engine,
-                              recorder=recorder,
-                              drain_timeout_s=server_cfg.drain_timeout_s,
-                              disagg=disagg, peers=server_cfg.peers)
-        why = "FSTPU_API_SERVER=stdlib" if use_stdlib else \
-            "fastapi/uvicorn not installed"
-        print(f"{why} — stdlib server on "
-              f"{server_cfg.host}:{server_cfg.port}", flush=True)
-        _stop_on_failed_warmup(ready, server.shutdown)
-        server.serve_forever()
-        server.server_close()
-        if engine is not None:
-            engine.stop()
-        if ready.error is not None:
-            raise SystemExit(f"warmup failed: {ready.error}")
-        return
-    uv_server = uvicorn.Server(uvicorn.Config(
-        app, host=server_cfg.host, port=server_cfg.port,
-        log_level=server_cfg.log_level))
-    _stop_on_failed_warmup(
-        ready, lambda: setattr(uv_server, "should_exit", True))
-    uv_server.run()
-    # uvicorn installs its OWN signal handlers (replacing the chained
-    # SIGTERM dump above) and returns here after its graceful
-    # shutdown — dump on the way out so a drained uvicorn replica
-    # still leaves a bundle; the stdlib path keeps the chained handler
-    try:
-        recorder.dump(reason="shutdown")
-    except Exception:  # noqa: BLE001 — never fail process exit on
-        # telemetry
-        pass
+    server = build_stdlib_server(server_cfg, pipeline_cfg,
+                                 pipeline=pipeline, engine=engine,
+                                 ready=ready, recorder=recorder,
+                                 draining=draining, disagg=disagg)
+    # graceful drain replaces the recorder's dump-then-die SIGTERM
+    # chain installed above (the dump still happens, post-drain)
+    install_drain_handler(server, draining, engine=engine,
+                          recorder=recorder,
+                          drain_timeout_s=server_cfg.drain_timeout_s,
+                          disagg=disagg, peers=server_cfg.peers)
+    print(f"stdlib server on {server_cfg.host}:{server_cfg.port}",
+          flush=True)
+    _stop_on_failed_warmup(ready, server.shutdown)
+    server.serve_forever()
+    server.server_close()
+    if engine is not None:
+        engine.stop()
     if ready.error is not None:
         raise SystemExit(f"warmup failed: {ready.error}")
 

@@ -11,8 +11,6 @@ imports this package in its NO-JAX process:
   detach, adopt → collect). Imports the serving engine, so it is NOT
   imported here — the api layer imports
   `fengshen_tpu.disagg.coordinator` explicitly.
-- `bench`: the serve-bench-disagg harness (same split: imported by
-  name only).
 """
 
 from fengshen_tpu.disagg import policy, transfer

@@ -107,11 +107,9 @@ def plan_evacuation(peers: Sequence[dict]) -> List[str]:
 
 
 def topology(phases: Sequence[str]) -> str:
-    """Canonical topology label for BENCH rows and `/fleet`:
-    ``"homogeneous"`` when no replica declares a dedicated phase, else
+    """Canonical topology label `/fleet` shows: ``"homogeneous"``
+    when no replica declares a dedicated phase, else
     ``"prefill=P,decode=D"`` (with ``,both=B`` appended when mixed).
-    `benchdiff._identity` folds this into the comparison key so
-    disaggregated runs never diff against homogeneous ones.
     """
     counts = {p: 0 for p in PHASES}
     for p in phases:

@@ -3,7 +3,7 @@
     # front replicas that are already running
     python -m fengshen_tpu.fleet --replicas 10.0.0.1:8000,10.0.0.2:8000
 
-    # or spawn N local stdlib api replicas from one config, then front
+    # or spawn N local api replicas from one config, then front
     # them (the `make serve-fleet` path)
     python -m fengshen_tpu.fleet --spawn 3 --config api.json
 
@@ -26,7 +26,7 @@ def build_parser() -> argparse.ArgumentParser:
                    help="comma list of replica targets (host:port or "
                         "http://... base URLs)")
     p.add_argument("--spawn", type=int, default=None, metavar="N",
-                   help="spawn N local stdlib api replicas from "
+                   help="spawn N local api replicas from "
                         "--config instead of fronting existing ones")
     p.add_argument("--config", type=str, default=None,
                    help="api/main.py config json for --spawn")

@@ -1,4 +1,4 @@
-"""Local fleet launcher: spawn N stdlib api replicas from one config.
+"""Local fleet launcher: spawn N api replicas from one config.
 
 `python -m fengshen_tpu.fleet --spawn N --config api.json` (the
 `make serve-fleet` path) takes the SAME config file a single replica
@@ -21,12 +21,10 @@ from __future__ import annotations
 
 import json
 import os
-import re
 import signal
 import subprocess
 import sys
 import tempfile
-import urllib.request
 from typing import List, Sequence, Tuple
 
 from fengshen_tpu.disagg.policy import validate_phase
@@ -46,21 +44,6 @@ def replica_env(index: int) -> dict:
             "TPU_PROCESS_BOUNDS": "1,1,1"}
 
 
-def replica_backend(target: str, timeout_s: float = 10.0) -> str:
-    """The jax backend a running replica reports (the `backend` label
-    of `fstpu_build_info` on its `/metrics`) — how a bench parent names
-    the backend its rows came from without importing jax, which would
-    take the chip its replicas need."""
-    with urllib.request.urlopen(f"http://{target}/metrics",
-                                timeout=timeout_s) as r:
-        text = r.read().decode()
-    found = re.search(r'fstpu_build_info\{[^}]*backend="([^"]+)"', text)
-    if found is None:
-        raise RuntimeError(f"replica {target} exposes no "
-                           "fstpu_build_info on /metrics")
-    return found.group(1)
-
-
 def spawn_replicas(config_path: str, n: int, base_port: int,
                    host: str = "127.0.0.1",
                    workdir: str = None,
@@ -70,10 +53,8 @@ def spawn_replicas(config_path: str, n: int, base_port: int,
     (targets, processes) where targets are "host:port" strings for
     `FleetConfig.replicas`. Replica i starts in `replica_env(i)` — this
     process's env (so `JAX_PLATFORMS` etc. flow through) narrowed to
-    chip i — plus `FSTPU_API_SERVER=stdlib`: only the stdlib server
-    path has the SIGTERM graceful drain the fleet's rolling restarts
-    depend on — a uvicorn replica would die with its in-flight
-    requests instead of draining.
+    chip i. A replica drains gracefully on SIGTERM (`api/main.py`),
+    which the fleet's rolling restarts depend on.
 
     `phases` assigns replica i the serving phase `phases[i]`
     (`prefill` | `decode` | `both`, docs/disaggregation.md) via its
@@ -106,7 +87,7 @@ def spawn_replicas(config_path: str, n: int, base_port: int,
         procs.append(subprocess.Popen(
             [sys.executable, "-m", "fengshen_tpu.api.main",
              "--config", path],
-            env={**replica_env(i), "FSTPU_API_SERVER": "stdlib"}))
+            env=replica_env(i)))
         targets.append(f"{host}:{port}")
     return targets, procs
 

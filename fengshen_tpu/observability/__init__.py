@@ -4,7 +4,7 @@ jsonl event sink, MFU/goodput step stats, and trace spans
 
 Every subsystem plugs into this one core instead of inventing its own
 telemetry dialect: the Trainer's step log, the serving engine's
-`EngineMetrics`, the resilience events, and bench's JSON rows all write
+`EngineMetrics` and the resilience events all write
 through here; ``GET /metrics`` (api server routes + the standalone
 exporter thread) and `/stats` read from it.
 """

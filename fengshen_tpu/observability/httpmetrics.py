@@ -1,7 +1,7 @@
 """The shared HTTP request telemetry families.
 
-Both server surfaces — the replica api servers (`api/main.py`, fastapi
-AND stdlib paths) and the fleet router's own endpoints
+Both server surfaces — the replica api server (`api/main.py`) and the
+fleet router's own endpoints
 (`fleet/server.py`) — count and time their requests into the SAME
 global-registry families, so router-side and replica-side latency read
 on one dashboard. The family definitions (name, help, labelnames) live

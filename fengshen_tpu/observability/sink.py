@@ -3,14 +3,12 @@
 Subsumes the event dialects that grew per-subsystem — the Trainer's
 ``metrics.jsonl`` step/val/rewind entries, the resilience loader's
 ``loader_retry``/``loader_skip_batch`` events, the serving engine's
-``serving_admit``/``serving_finish`` events, and bench's one-line JSON
-rows — behind a single callable ``sink(entry: dict)``. Event NAMES are
-unchanged (compatibility layer: anything already parsing metrics.jsonl
-or bench stdout keeps working); what unifies is the writer: one
+``serving_admit``/``serving_finish`` events — behind a single callable
+``sink(entry: dict)``. Event NAMES are unchanged (compatibility layer:
+anything already parsing metrics.jsonl keeps working); what unifies is the writer: one
 process-gating rule, one echo format, one logger bridge.
 
-A sink writes to a jsonl ``path``, a ``stream`` (bench writes stdout),
-or both; ``echo`` mirrors the Trainer's human-readable console line;
+A sink writes to a jsonl ``path``, a ``stream``, or both; ``echo`` mirrors the Trainer's human-readable console line;
 ``logger`` bridges numeric fields to a Lightning-style
 ``log_metrics``. Multihost gating: only ``process_index == 0`` writes
 (``only_process_zero=False`` opts out — bench children are already

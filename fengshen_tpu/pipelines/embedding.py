@@ -10,9 +10,9 @@ is the one-request path; the `EmbeddingEngine`
 co-arriving requests ride ONE jitted text-tower forward.
 
 `small_test=True` builds a compact random-init tower with a built-in
-byte tokenizer — serving tests and `make serve-bench-multimodal` run
-on it without checkpoints. Real weights: convert the Taiyi-CLIP
-checkpoint with `models.clip.convert` and inject `module=`/`params=`.
+byte tokenizer — the serving tests run on it without checkpoints. Real
+weights: convert the Taiyi-CLIP checkpoint with `models.clip.convert`
+and inject `module=`/`params=`.
 """
 
 from __future__ import annotations

@@ -13,8 +13,7 @@ Released Taiyi-SD weights are three towers (text encoder + diffusers
 unet/vae) — convert them with `models.stable_diffusion.convert` and
 inject `module=`/`params=`. `small_test=True` builds the compact
 random-init towers with a built-in byte tokenizer — the serving tests
-and `make serve-bench-multimodal` run on it without any checkpoint or
-tokenizer dependency.
+run on it without any checkpoint or tokenizer dependency.
 """
 
 from __future__ import annotations

@@ -1560,8 +1560,7 @@ class ContinuousBatchingEngine:
         # metrics count DELIVERED tokens, not the raw window: a
         # lane finishing mid-window (eos, or the max_new cap)
         # discards the tail, and counting it would inflate
-        # decode_tokens and the acceptance rate the bench's
-        # committed-per-forward headline is derived from
+        # decode_tokens and the acceptance rate `/stats` reports
         delivered = 0
         accepted_delivered = 0
         t_commit = self._clock()
@@ -2243,8 +2242,8 @@ class ContinuousBatchingEngine:
                     "type": self._last_error["type"],
                     "age_s": round(now - self._last_error["at"], 3)}
             # engine_type EXTENDS the pinned payload (same precedent
-            # as uptime_s/draining): the fleet router and benchdiff
-            # key multimodal-vs-text comparisons on it
+            # as uptime_s/draining): the fleet router's heterogeneous
+            # placement keys multimodal-vs-text on it
             return dict(self.metrics.snapshot(
                 queue_depth=len(self._queue),
                 slots_active=int(self._active.sum()),

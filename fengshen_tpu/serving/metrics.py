@@ -374,8 +374,8 @@ class EngineMetrics:
 
     def record_stream_ttfb(self, seconds: float) -> None:
         """Request-received to first SSE byte on the wire — the
-        delivery-layer TTFT the bench's first-byte headline reads
-        (ttft_seconds keeps its commit-time meaning)."""
+        delivery-layer TTFT (ttft_seconds keeps its commit-time
+        meaning)."""
         self._stream_ttfb.observe(seconds)
 
     def record_phases(self, phases: dict) -> None:

@@ -40,6 +40,17 @@ def mesh_seq4():
     set_mesh(None)
 
 
+@pytest.fixture
+def fresh_probe(monkeypatch):
+    """The kernel tests' probe (tests/test_pallas_*.py): each force-env
+    scenario re-probes; the cache key includes the env var so leaving it
+    unset afterwards restores the real answer."""
+    from fengshen_tpu.ops.pallas import FORCE_ENV, probe
+    monkeypatch.delenv(FORCE_ENV, raising=False)
+    yield monkeypatch
+    probe(refresh=True)
+
+
 @pytest.fixture(autouse=True, scope="module")
 def _no_mesh_left_behind():
     """`Trainer.__init__` (and a test that forgets) installs the

@@ -28,7 +28,7 @@ PKG = os.path.join(REPO, "fengshen_tpu")
 FIXTURES = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                         "analysis_fixtures")
 
-RULE_IDS = ("api-surface-parity", "blanket-except", "blocking-transfer",
+RULE_IDS = ("blanket-except", "blocking-transfer",
             "blocking-under-lock", "donated-buffer-use",
             "host-divergence", "lock-order", "metric-contract",
             "metrics-in-traced-code", "nondet-iteration",
@@ -38,8 +38,8 @@ RULE_IDS = ("api-surface-parity", "blanket-except", "blocking-transfer",
 CONCURRENCY_RULE_IDS = ("blocking-under-lock", "lock-order",
                         "unguarded-shared-state")
 
-DATAFLOW_RULE_IDS = ("api-surface-parity", "donated-buffer-use",
-                     "metric-contract", "resource-lifecycle")
+DATAFLOW_RULE_IDS = ("donated-buffer-use", "metric-contract",
+                     "resource-lifecycle")
 
 
 def _fixture(rule_id: str, kind: str) -> str:
@@ -578,14 +578,7 @@ def test_streaming_internals_are_clean():
     frame and proxy the wire). A hit means a publish, a socket write,
     or a counter leaked into a traced program (a real hazard:
     streaming must add ZERO per-token compiled work) or a rule lost
-    precision.
-
-    The same gate pins api-surface parity for the new wire: the
-    `/stream` route must be visible to `extract_routes` on BOTH
-    surfaces of api/main.py — fastapi decorator and stdlib dispatcher
-    — so `api-surface-parity` keeps diffing it (a BinOp-concatenated
-    path would silently drop out of the extractor and the rule would
-    stop guarding the route)."""
+    precision."""
     fixture = os.path.join(FIXTURES, "streaming_clean.py")
     findings = check_file(fixture, make_rules(), REPO)
     assert not findings, "\n".join(f.render() for f in findings)
@@ -599,22 +592,6 @@ def test_streaming_internals_are_clean():
             if f.rule in ("metrics-in-traced-code", "blocking-transfer",
                           "host-divergence")]
     assert not hits, "\n".join(f.render() for f in hits)
-
-    # the SSE route is on both surfaces of the dual-stack api module,
-    # in extractor-visible form, and the parity rule stays green
-    import ast as _ast
-    from fengshen_tpu.analysis.dataflow import extract_routes
-    api_main = os.path.join(PKG, "api", "main.py")
-    with open(api_main, encoding="utf-8") as fp:
-        tree = _ast.parse(fp.read())
-    routes = extract_routes(tree)
-    stream_surfaces = {s for (s, method, path, _l, _c) in routes
-                       if method == "POST" and path.endswith("*")}
-    assert stream_surfaces == {"fastapi", "stdlib"}, routes
-    parity = check_paths([os.path.join(PKG, "api")],
-                         make_rules(select=["api-surface-parity"]),
-                         REPO)
-    assert not parity, "\n".join(f.render() for f in parity)
 
 
 def test_trace_context_internals_are_clean():
@@ -719,8 +696,8 @@ def test_concurrency_rules_clean_on_package():
 
 def test_dataflow_rules_clean_on_package():
     """The dataflow gate, same policy as the concurrency gate: the
-    four PR-17 rules (`donated-buffer-use`, `resource-lifecycle`,
-    `api-surface-parity`, `metric-contract`) report ZERO findings
+    three PR-17 rules (`donated-buffer-use`, `resource-lifecycle`,
+    `metric-contract`) report ZERO findings
     over the merged tree with an EMPTY baseline. Every real leak the
     sweep found was fixed at the site (serving/engine.py `_admit`,
     serving/handoff.py `adopt_lane`, the bert_dataloader shard

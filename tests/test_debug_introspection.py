@@ -17,8 +17,7 @@ The load-bearing contracts:
   API path; `fstpu_http_request_seconds{route}` and
   `fstpu_request_phase_seconds{phase}` land in /metrics;
 - /stats only EXTENDS (uptime_s, last_error as type+age — no
-  traceback); benchdiff classifies the repo's BENCH trajectory
-  deterministically and flags synthetic regressions.
+  traceback).
 """
 
 import json
@@ -702,181 +701,3 @@ def test_trainer_rewind_dumps_postmortem(tmp_path):
     trainer_dump = json.loads((bundle / "trainer.json").read_text())
     assert trainer_dump["step"] == 2
     assert trainer_dump["args"]["max_consecutive_bad_steps"] == 2
-
-
-# ---- benchdiff ----------------------------------------------------------
-
-def _write_round(directory, n, rows, rc=0, tail=""):
-    payload = {"n": n, "cmd": "bench", "rc": rc, "tail": tail,
-               "parsed": rows}
-    with open(os.path.join(directory, f"BENCH_r{n:02d}.json"),
-              "w") as f:
-        json.dump(payload, f)
-
-
-def test_benchdiff_flags_regressions(tmp_path):
-    from fengshen_tpu.observability import benchdiff
-
-    d = str(tmp_path)
-    _write_round(d, 1, [{"metric": "tps", "value": 100.0,
-                         "unit": "tok/s", "vs_baseline": 1.0}])
-    _write_round(d, 2, None, rc=1, tail="ValueError: broke\n")
-    _write_round(d, 3, [{"metric": "tps", "value": 50.0,
-                         "unit": "tok/s", "vs_baseline": 0.5},
-                        {"metric": "mfu_row", "value": 0.5,
-                         "unit": "mfu", "vs_baseline": 1.0}])
-    _write_round(d, 4, [{"metric": "tps", "value": 49.0,
-                         "unit": "tok/s", "vs_baseline": 0.5},
-                        {"metric": "mfu_row", "value": 0.8,
-                         "unit": "mfu", "vs_baseline": 1.6},
-                        {"metric": "cpu_row", "value": 10.0,
-                         "degraded": True, "unit": "tok/s",
-                         "vs_baseline": 0.1}])
-    _write_round(d, 5, [{"metric": "cpu_row", "value": 9.0,
-                         "unit": "tok/s", "vs_baseline": 0.1},
-                        {"metric": "zero_row", "value": 0.0,
-                         "unit": "rate", "vs_baseline": 0.0}])
-    _write_round(d, 6, [{"metric": "zero_row", "value": 0.4,
-                         "unit": "rate", "vs_baseline": 1.0}])
-    report = benchdiff.diff_rounds(benchdiff.load_rounds(d),
-                                   threshold=0.15)
-    assert report["verdict"] == "REGRESSED"
-    by_key = {(c["metric"], c["round"]): c
-              for c in report["comparisons"]}
-    # r03 tps regressed vs r01 (the failed r02 is skipped over)
-    assert by_key[("tps", 3)]["status"] == "regression"
-    assert by_key[("tps", 3)]["prev_round"] == 1
-    assert by_key[("tps", 4)]["status"] == "flat"
-    assert by_key[("mfu_row", 4)]["status"] == "improvement"
-    # degraded vs non-degraded must never read as a regression
-    assert by_key[("cpu_row", 5)]["status"] == "incomparable"
-    # a move off a zero-valued metric is a change, never "flat +0%"
-    assert by_key[("zero_row", 6)]["status"] == "improvement"
-    assert by_key[("zero_row", 6)]["delta_pct"] is None
-    assert report["counts"] == {"ok": 5, "failed": 1}
-    # --strict exits 3 on REGRESSED
-    assert benchdiff.main(["--dir", d, "--strict"]) == 3
-    assert benchdiff.main(["--dir", d]) == 0
-    # empty dir exits 2
-    empty = tmp_path / "empty"
-    empty.mkdir()
-    assert benchdiff.main(["--dir", str(empty)]) == 2
-
-
-def test_benchdiff_never_compares_across_placements(tmp_path):
-    """ISSUE 9 satellite: offload rows carry their resolved placement
-    ({"offload", "memory_kind"}, docs/offload.md) and rows at
-    different placements are INCOMPARABLE — an offloaded-update rung
-    slowing down relative to a device-resident rung is a placement
-    change, not a perf regression."""
-    from fengshen_tpu.observability import benchdiff
-
-    d = str(tmp_path)
-    _write_round(d, 1, [{"metric": "off_tps", "value": 100.0,
-                         "unit": "tok/s", "vs_baseline": 1.0}])
-    # same metric, now measured at an offload placement: incomparable
-    _write_round(d, 2, [{"metric": "off_tps", "value": 40.0,
-                         "unit": "tok/s", "vs_baseline": 0.4,
-                         "offload": "opt",
-                         "memory_kind": "unpinned_host"}])
-    # same placement again: comparable, and this IS a regression
-    _write_round(d, 3, [{"metric": "off_tps", "value": 30.0,
-                         "unit": "tok/s", "vs_baseline": 0.3,
-                         "offload": "opt",
-                         "memory_kind": "unpinned_host"}])
-    # same level on a DIFFERENT memory kind: incomparable again
-    _write_round(d, 4, [{"metric": "off_tps", "value": 60.0,
-                         "unit": "tok/s", "vs_baseline": 0.6,
-                         "offload": "opt",
-                         "memory_kind": "pinned_host"}])
-    report = benchdiff.diff_rounds(benchdiff.load_rounds(d),
-                                   threshold=0.15)
-    by_round = {c["round"]: c for c in report["comparisons"]}
-    assert by_round[2]["status"] == "incomparable"
-    assert by_round[2]["delta_pct"] is None
-    assert by_round[3]["status"] == "regression"
-    assert by_round[4]["status"] == "incomparable"
-
-
-def test_benchdiff_never_compares_across_replica_counts(tmp_path):
-    """ISSUE 10 satellite: fleet rows carry their replica count
-    (docs/fleet.md) and rows at different N are INCOMPARABLE — a
-    2-replica aggregate dropping below a 3-replica one is a deployment
-    change, not a perf regression. Same N still diffs normally."""
-    from fengshen_tpu.observability import benchdiff
-
-    d = str(tmp_path)
-    base = {"metric": "fleet_router_tokens_per_sec", "unit": "tok/s"}
-    _write_round(d, 1, [dict(base, value=300.0, vs_baseline=2.3,
-                             replicas=3)])
-    # fewer replicas: lower aggregate is a different deployment
-    _write_round(d, 2, [dict(base, value=210.0, vs_baseline=1.6,
-                             replicas=2)])
-    # back at N=3: still incomparable (prev round carried N=2)
-    _write_round(d, 3, [dict(base, value=290.0, vs_baseline=2.2,
-                             replicas=3)])
-    # same N as the previous round: compares normally — a regression
-    _write_round(d, 4, [dict(base, value=150.0, vs_baseline=1.1,
-                             replicas=3)])
-    report = benchdiff.diff_rounds(benchdiff.load_rounds(d),
-                                   threshold=0.15)
-    by_round = {c["round"]: c for c in report["comparisons"]}
-    assert by_round[2]["status"] == "incomparable"
-    assert by_round[2]["delta_pct"] is None
-    assert by_round[3]["status"] == "incomparable"  # vs round 2 (N=2)
-    assert by_round[4]["status"] == "regression"
-    assert report["verdict"] == "REGRESSED"
-
-
-def test_benchdiff_never_compares_across_phase_topologies(tmp_path):
-    """ISSUE 13 satellite: disaggregated rows carry their phase
-    topology (docs/disaggregation.md) and rows at different topologies
-    are INCOMPARABLE even at equal replica counts — a
-    prefill=1,decode=2 split measuring below a homogeneous 3-replica
-    fleet is a deployment change, not a perf regression. The same
-    topology still diffs normally."""
-    from fengshen_tpu.observability import benchdiff
-
-    d = str(tmp_path)
-    base = {"metric": "disagg_tokens_per_sec", "unit": "tok/s",
-            "replicas": 3}
-    _write_round(d, 1, [dict(base, value=300.0, vs_baseline=1.4,
-                             topology="prefill=1,decode=2")])
-    # same N, homogeneous topology: a different deployment
-    _write_round(d, 2, [dict(base, value=220.0, vs_baseline=1.0,
-                             topology="homogeneous")])
-    # back at the split: still incomparable (prev was homogeneous)
-    _write_round(d, 3, [dict(base, value=290.0, vs_baseline=1.35,
-                             topology="prefill=1,decode=2")])
-    # same topology as the previous round: a real regression
-    _write_round(d, 4, [dict(base, value=150.0, vs_baseline=0.7,
-                             topology="prefill=1,decode=2")])
-    report = benchdiff.diff_rounds(benchdiff.load_rounds(d),
-                                   threshold=0.15)
-    by_round = {c["round"]: c for c in report["comparisons"]}
-    assert by_round[2]["status"] == "incomparable"
-    assert by_round[2]["delta_pct"] is None
-    assert by_round[3]["status"] == "incomparable"
-    assert by_round[4]["status"] == "regression"
-
-
-def test_benchdiff_report_deterministic_across_hashseed(tmp_path):
-    d = str(tmp_path)
-    _write_round(d, 1, [{"metric": f"m{i}", "value": float(i + 1),
-                         "unit": "u", "vs_baseline": 1.0}
-                        for i in range(8)])
-    _write_round(d, 2, [{"metric": f"m{i}", "value": float(i + 2),
-                         "unit": "u", "vs_baseline": 1.0}
-                        for i in range(8)])
-    outs = []
-    for seed in ("0", "1"):
-        out = subprocess.run(
-            [sys.executable, "-m",
-             "fengshen_tpu.observability.benchdiff", "--dir", d,
-             "--json"],
-            env={**os.environ, "PYTHONHASHSEED": seed,
-                 "JAX_PLATFORMS": "cpu"},
-            capture_output=True, text=True, cwd=REPO)
-        assert out.returncode == 0, out.stderr
-        outs.append(out.stdout)
-    assert outs[0] == outs[1]

@@ -9,27 +9,21 @@ The contracts pinned here:
   so the fleet router's retry contract holds across engine types;
 - `_multimodal_generate` maps those to the same HTTP codes the text
   path uses (429/503/409/422) and the 200 body carries `engine_type`;
-- both server paths (stdlib + fastapi, when installed) dispatch on
-  `engine.engine_type` — a batch_image/embedding engine behind
-  `POST /api/<task>` answers through the micro-batch path, and `/stats`
-  exposes the micro-batch block;
-- the `make serve-bench-multimodal` harness emits one BENCH-schema row
-  per engine type, each carrying `engine_type` (benchdiff folds it
-  into the row identity).
+- the server dispatches on `engine.engine_type` — a
+  batch_image/embedding engine behind `POST /api/<task>` answers
+  through the micro-batch path, and `/stats` exposes the micro-batch
+  block.
 
 The engine/dispatch unit tests run on a fake pipeline so the machinery
 is pinned fast and deterministically; the real towers (small-test
 Taiyi-SD denoise loop + VAE decode, Taiyi-CLIP text embeddings) are
-exercised end-to-end — pipeline → engine → stdlib HTTP server — by the
-tests at the bottom, and through the bench harness smoke.
+exercised end-to-end — pipeline → engine → HTTP server — by the
+tests at the bottom.
 """
 
-import io
 import json
-import os
 import threading
 import time
-from contextlib import redirect_stdout
 
 import pytest
 
@@ -37,8 +31,8 @@ from fengshen_tpu.serving import (Draining, DuplicateRequest, QueueFull,
                                   BatchImageEngine, EmbeddingEngine,
                                   MULTIMODAL_ENGINE_TYPES,
                                   create_multimodal_engine)
-from fengshen_tpu.serving.multimodal import (MM_CANCELLED, MM_FAILED,
-                                             MM_FINISHED, MM_QUEUED)
+from fengshen_tpu.serving.multimodal import (
+    MM_CANCELLED, MM_FAILED, MM_FINISHED)
 
 
 class FakePipeline:
@@ -254,7 +248,7 @@ def test_multimodal_generate_failed_batch_maps_503():
         eng.stop()
 
 
-# ---- server dispatch (stdlib always; fastapi when installed) ------------
+# ---- server dispatch ---------------------------------------------------
 
 def _stdlib_server(engine, task):
     from fengshen_tpu.api.main import (PipelineConfig, ServerConfig,
@@ -268,29 +262,33 @@ def _stdlib_server(engine, task):
     return server, server.server_address[1]
 
 
-def test_stdlib_server_dispatches_multimodal_engine():
+@pytest.mark.parametrize("cls,task", [
+    (EmbeddingEngine, "embedding"),
+    (BatchImageEngine, "image_generation")],
+    ids=["embedding", "batch_image"])
+def test_stdlib_server_dispatches_multimodal_engine(cls, task):
     import urllib.error
     import urllib.request
 
-    eng = _engine(cls=EmbeddingEngine)
+    eng = _engine(cls=cls)
     eng.start()
-    server, port = _stdlib_server(eng, "embedding")
+    server, port = _stdlib_server(eng, task)
     try:
         req = urllib.request.Request(
-            f"http://127.0.0.1:{port}/api/embedding",
+            f"http://127.0.0.1:{port}/api/{task}",
             data=json.dumps({"input_text": "测试"}).encode(),
             headers={"Content-Type": "application/json"})
         with urllib.request.urlopen(req, timeout=30) as r:
             out = json.loads(r.read())
-        assert out["engine_type"] == "embedding"
+        assert out["engine_type"] == cls.engine_type
         assert out["result"] == {"result_for": "测试"}
         with urllib.request.urlopen(
                 f"http://127.0.0.1:{port}/stats", timeout=10) as r:
             stats = json.loads(r.read())
-        assert stats["engine_type"] == "embedding"
+        assert stats["engine_type"] == cls.engine_type
         assert stats["requests_total"] >= 1
         bad = urllib.request.Request(
-            f"http://127.0.0.1:{port}/api/embedding",
+            f"http://127.0.0.1:{port}/api/{task}",
             data=json.dumps({"input_text": "  "}).encode(),
             headers={"Content-Type": "application/json"})
         with pytest.raises(urllib.error.HTTPError) as exc:
@@ -298,31 +296,6 @@ def test_stdlib_server_dispatches_multimodal_engine():
         assert exc.value.code == 422
     finally:
         server.shutdown()
-        eng.stop()
-
-
-def test_fastapi_app_dispatches_multimodal_engine():
-    pytest.importorskip("fastapi")
-    from fastapi.testclient import TestClient
-
-    from fengshen_tpu.api.main import (PipelineConfig, ServerConfig,
-                                       build_app)
-
-    eng = _engine(cls=BatchImageEngine)
-    eng.start()
-    app = build_app(PipelineConfig(task="image_generation"),
-                    pipeline=eng.pipeline,
-                    server_cfg=ServerConfig(engine="batch_image"),
-                    engine=eng)
-    try:
-        client = TestClient(app)
-        r = client.post("/api/image_generation",
-                        json={"input_text": "一只猫"})
-        assert r.status_code == 200
-        assert r.json()["engine_type"] == "batch_image"
-        stats = client.get("/stats").json()
-        assert stats["engine_type"] == "batch_image"
-    finally:
         eng.stop()
 
 
@@ -390,65 +363,3 @@ def test_batch_image_tower_serves_end_to_end():
     finally:
         server.shutdown()
         eng.stop()
-
-
-# ---- benchdiff row identity ---------------------------------------------
-
-def test_benchdiff_engine_type_rows_incomparable():
-    """`engine_type` is part of BENCH row identity: a batch_image round
-    never diffs against an embedding round of the same metric name
-    (same contract as offload placement / kernel dispatch / drills);
-    same-engine rounds still diff honestly."""
-    from fengshen_tpu.observability.benchdiff import diff_rounds
-
-    rounds = [
-        (1, "BENCH_r01.json", {"rc": 0, "parsed": [
-            {"metric": "serving_mm_requests_per_sec", "value": 70.0,
-             "unit": "requests/s", "vs_baseline": 1.3,
-             "engine_type": "batch_image"}]}),
-        (2, "BENCH_r02.json", {"rc": 0, "parsed": [
-            {"metric": "serving_mm_requests_per_sec", "value": 2200.0,
-             "unit": "requests/s", "vs_baseline": 2.9,
-             "engine_type": "embedding"}]}),
-        (3, "BENCH_r03.json", {"rc": 0, "parsed": [
-            {"metric": "serving_mm_requests_per_sec", "value": 1100.0,
-             "unit": "requests/s", "vs_baseline": 1.5,
-             "engine_type": "embedding"}]}),
-    ]
-    report = diff_rounds(rounds)
-    statuses = {(c["round"], c["status"])
-                for c in report["comparisons"]}
-    assert (2, "incomparable") in statuses   # engine type changed
-    assert (3, "regression") in statuses     # embedding vs embedding
-
-
-# ---- `make serve-bench-multimodal` harness smoke ------------------------
-
-def test_serve_bench_multimodal_emits_engine_rows(monkeypatch):
-    """The real towers (small-test Taiyi-SD + Taiyi-CLIP) through the
-    real engines: one BENCH-schema row per engine type, each carrying
-    the `engine_type` benchdiff folds into the row identity."""
-    from fengshen_tpu.serving import bench
-
-    for key in list(os.environ):
-        if key.startswith(("SERVE_BENCH_", "BENCH_DEGRADED")):
-            monkeypatch.delenv(key)
-    monkeypatch.setenv("SERVE_BENCH_MODE", "multimodal")
-    monkeypatch.setenv("SERVE_BENCH_REQUESTS", "2")
-    monkeypatch.setenv("SERVE_BENCH_MAX_BATCH", "2")
-    out = io.StringIO()
-    with redirect_stdout(out):
-        bench.main()
-    rows = [json.loads(l) for l in out.getvalue().splitlines()
-            if l.startswith("{")]
-    by_type = {row["engine_type"]: row for row in rows}
-    assert set(by_type) == {"batch_image", "embedding"}
-    for engine_type, row in by_type.items():
-        assert set(row) >= {"metric", "value", "unit", "vs_baseline",
-                            "mode", "engine_type"}
-        assert row["metric"] == \
-            f"serving_{engine_type}_requests_per_sec"
-        assert row["unit"] == "requests/s"
-        assert row["mode"] == "multimodal"
-        assert row["value"] > 0
-        assert row["vs_baseline"] > 0

@@ -1,7 +1,6 @@
 """Pipeline / CLI / metrics / CRF tests."""
 
 import argparse
-import json
 
 import jax
 import jax.numpy as jnp
@@ -196,35 +195,8 @@ def test_cli_usage_and_unknown_task(capsys):
 
 # -- API ------------------------------------------------------------------
 
-def test_api_build_app(tmp_path):
-    fastapi = pytest.importorskip("fastapi")
-    from fastapi.testclient import TestClient
-    from fengshen_tpu.api.main import build_app, load_config
-
-    cfg = tmp_path / "cfg.json"
-    cfg.write_text(json.dumps({
-        "SERVER": {"port": 8123},
-        "PIPELINE": {"task": "text_classification"}}))
-    server_cfg, pipeline_cfg = load_config(str(cfg))
-    assert server_cfg.port == 8123
-
-    class FakePipeline:
-        def __call__(self, text):
-            return {"label": 1, "score": 0.9}
-
-    app = build_app(pipeline_cfg, pipeline=FakePipeline())
-    client = TestClient(app)
-    r = client.post("/api/text_classification",
-                    json={"input_text": "你好"})
-    assert r.status_code == 200
-    assert r.json()["result"]["label"] == 1
-    assert client.get("/healthz").json()["status"] == "ok"
-
-
 def test_api_stdlib_server_roundtrip():
-    """The dependency-free REST fallback serves the same surface as the
-    FastAPI app: POST /api/<task> + GET /healthz (fastapi is not in
-    this image, so this path IS the runnable serving surface here)."""
+    """The server's surface: POST /api/<task> + GET /healthz."""
     import json as json_mod
     import threading
     import urllib.request
@@ -249,7 +221,8 @@ def test_api_stdlib_server_roundtrip():
         with urllib.request.urlopen(
                 f"http://127.0.0.1:{port}/healthz", timeout=10) as r:
             health = json_mod.loads(r.read())
-        assert health == {"status": "ok", "task": "text_classification"}
+        assert health == {"status": "ok", "task": "text_classification",
+                          "ready": True}
 
         req = urllib.request.Request(
             f"http://127.0.0.1:{port}/api/text_classification",

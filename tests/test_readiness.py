@@ -90,24 +90,6 @@ def test_healthz_defaults_to_ready_stdlib():
         server.shutdown()
 
 
-def test_healthz_503_until_ready_fastapi():
-    fastapi = pytest.importorskip("fastapi")  # noqa: F841
-    from fastapi.testclient import TestClient
-
-    from fengshen_tpu.api.main import PipelineConfig, build_app
-    ready = threading.Event()
-    app = build_app(PipelineConfig(task="text_classification"),
-                    pipeline=_DummyPipeline(), ready=ready)
-    client = TestClient(app)
-    r = client.get("/healthz")
-    assert r.status_code == 503 and r.json()["status"] == "warming"
-    # the fastapi path mirrors the stdlib ready/reason body (ISSUE 10)
-    assert r.json()["ready"] is False and r.json()["reason"] == "warmup"
-    ready.set()
-    r = client.get("/healthz")
-    assert r.status_code == 200 and r.json()["ready"] is True
-
-
 # ---- warmup + build-info gauges ----------------------------------------
 
 def test_build_info_and_warmup_gauges():

@@ -775,6 +775,12 @@ def test_a_cpu_clock_that_runs_ahead_never_stops_the_serve_loop(
         "thread_time": staticmethod(
             lambda: (int(real() / 0.004) + 1) * 0.004 + 10 * real())})
     monkeypatch.setattr(tracing, "time", fake)
+    # this thread's spans read the fake clock too, and `thread_times()`
+    # never goes behind an earlier reading: left behind, a reading at
+    # eleven times the truth held every later span of this process's
+    # main thread at 0.0 CPU seconds (tests/test_observability.py, two
+    # files on the same worker). The undo puts the real one back.
+    monkeypatch.setattr(tracing._local, "cpu_anchor", None, raising=False)
     model, params = tiny
     eng = ContinuousBatchingEngine(
         model, params, EngineConfig(num_slots=2, buckets=(8, 16),

@@ -295,6 +295,41 @@ def test_block_exhaustion_defers_then_serves(tiny):
     assert st["deferred_admissions"] == 1
 
 
+@pytest.mark.parametrize("kv_dtype", ["fp32", "int8"])
+def test_paged_pool_admits_twice_the_slot_pool_at_equal_kv_bytes(
+        tiny, kv_dtype):
+    """What the paged pool is for. A slot pool pays every lane its
+    worst case (the largest bucket + max_new_tokens); blocks are paid
+    as a request needs them, so when the traffic sits in the small
+    bucket the same KV bytes hold at least twice the concurrent
+    requests. Admission capacity is allocator arithmetic, not timing."""
+    model, params = tiny
+    common = dict(buckets=(8, 32), max_new_tokens=8, max_queue=32)
+    slot = ContinuousBatchingEngine(
+        model, params, EngineConfig(num_slots=2, **common))
+    budget = slot.stats()["kv_cache_bytes"]
+    # [K and V] x 4 kv heads x 8 head_dim x 2 layers a token; int8
+    # adds a float32 scale a (token, head)
+    per_token = 2 * 4 * 8 * 2 * (1 if kv_dtype == "int8" else 4) + \
+        (2 * 4 * 2 * 4 if kv_dtype == "int8" else 0)
+    block = 8
+    blocks = budget // (block * per_token)
+    lanes = (blocks - 1) // 2       # (bucket 8 + 8 new) / 8 = 2 blocks
+    paged = ContinuousBatchingEngine(
+        model, params, EngineConfig(
+            num_slots=lanes, kv_layout="paged", kv_dtype=kv_dtype,
+            kv_block_size=block, kv_num_blocks=blocks, **common))
+    assert paged.stats()["kv_cache_bytes"] <= budget
+    prompts = _prompts((4,) * (lanes + 2), seed=5)
+    outs = paged.generate_all(prompts)
+    slot_outs = slot.generate_all(prompts)
+    assert slot.stats()["slots_active_peak"] == 2
+    assert paged.stats()["slots_active_peak"] >= 4
+    assert paged.stats()["slots_active_peak"] == lanes
+    if kv_dtype == "fp32":
+        assert outs == slot_outs
+
+
 def test_block_exhaustion_backpressures_submit_as_queue_full(tiny):
     """OOM-of-blocks maps onto the existing QueueFull path: with no
     engine thread draining, a full pool leaves requests queued and the

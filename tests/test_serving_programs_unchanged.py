@@ -13,7 +13,17 @@ ticks to the Mosaic grouped matmul decides by the call's shape ON A TPU
 calls in JoyAI's and Keye's tick, twelve `ragged-dot` in Trinity's);
 off it every call stays `ragged_dot`, and nothing else moved. It added
 Trinity's three programs (a ring and its table beside the lane-long
-one), taken on the commit PR 42 started from and equal on its tree."""
+one), taken on the commit PR 42 started from and equal on its tree.
+
+PR 44 added `<family>.prefill`, the whole-prompt program of a bucket
+(`_prefill_jit`: every admission of a prompt the ladder holds), for the
+families whose cache admits one: llama and joyai. The caches of sala,
+qwen3_next, keye and trinity declare positional leaves (a state, a
+ring, an indexer's keys: `engine._positional`), so every prompt of
+theirs goes by windows and they have no such program. Taken on the
+commit PR 44 started from and equal on its tree: the two prefill
+bodies and the six decoder skeletons (ROADMAP D13, D14) are merged
+against these."""
 
 import hashlib
 
@@ -28,9 +38,11 @@ SHA = {
     "llama.decode": "5a1add90e836d82a",
     "llama.window": "053f4a282aafb0ae",
     "llama.assign": "13aa0c5a11ba3006",
+    "llama.prefill": "90be13956a587e73",
     "joyai.decode": "3f1780b6a2d39053",
     "joyai.window": "b209a149c84224bd",
     "joyai.assign": "96810c12d7c41873",
+    "joyai.prefill": "44951615ee5359e5",
     "sala.decode": "8aba89589fbc0375",
     "sala.window": "91b546b9efe1d108",
     "sala.assign": "7805764a91b2a79d",
@@ -105,6 +117,12 @@ def lowered():
                 *sds(eng._decode_args(eng._active))).as_text(),
             "window": eng._window_jit.lower(*window_args).as_text(),
             "assign": eng._assign_jit.lower(*assign_args).as_text()}
+        # a cache with positional leaves takes every prompt by windows
+        assert bool(eng._positional) == (f"{family}.prefill" not in SHA)
+        if not eng._positional:
+            made[family]["prefill"] = eng._prefill_jit.lower(
+                params, i32(1, 16), i32(1, 16),
+                jax.ShapeDtypeStruct((2,), jnp.uint32)).as_text()
         return made[family]
     return of
 

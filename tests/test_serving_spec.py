@@ -360,8 +360,9 @@ def test_spec_metrics_count_only_delivered_tokens(tiny):
     """A lane finishing mid-window (length cap / eos) discards the
     window tail — decode_tokens must equal the tokens requests
     actually received (minus the prefill token), not the raw committed
-    windows, else tokens/s and the bench's committed-per-forward
-    headline inflate by up to gamma per request."""
+    windows, else tokens/s and the committed tokens a target forward
+    (`decode_tokens / decode_ticks`) inflate by up to gamma per
+    request."""
     model, params = tiny
     prompts = _rep_prompts(3, 14, seed=2)   # high-acceptance workload
     eng = ContinuousBatchingEngine(

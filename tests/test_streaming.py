@@ -27,7 +27,6 @@ The load-bearing contracts:
 """
 
 import json
-import os
 import threading
 import urllib.error
 import urllib.request
@@ -43,8 +42,6 @@ from fengshen_tpu.serving import ContinuousBatchingEngine, EngineConfig
 from fengshen_tpu.streaming import (StreamBook, TokenStream,
                                     format_event, iter_sse)
 from fengshen_tpu.utils.generate import generate
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 PAGED = dict(kv_layout="paged", kv_block_size=16)
 
@@ -297,7 +294,9 @@ def _sse_post(base, payload, headers=None, timeout=60):
         return list(iter_sse(r))
 
 
-def test_stdlib_sse_route_and_reconnect(tiny):
+@pytest.fixture(scope="module")
+def sse_replica(tiny):
+    """The base url of one api server over a continuous engine."""
     from fengshen_tpu.api.main import (PipelineConfig, ServerConfig,
                                        build_stdlib_server,
                                        start_continuous_engine)
@@ -315,62 +314,67 @@ def test_stdlib_sse_route_and_reconnect(tiny):
         engine=engine)
     port = server.server_address[1]
     threading.Thread(target=server.serve_forever, daemon=True).start()
-    base = f"http://127.0.0.1:{port}"
     try:
-        # the non-streamed answer is the reference
-        req = urllib.request.Request(
-            f"{base}/api/text_generation",
-            data=json.dumps({"input_text": "5 7 9",
-                             "request_id": "batch-1"}).encode(),
-            headers={"Content-Type": "application/json"})
-        with urllib.request.urlopen(req, timeout=60) as r:
-            ref = json.loads(r.read())["result"]
-
-        evs = _sse_post(base, {"input_text": "5 7 9",
-                               "request_id": "sse-1"})
-        toks = [e["data"]["token"] for e in evs
-                if e["event"] == "token"]
-        ids = [e["id"] for e in evs if e["event"] == "token"]
-        assert ids == list(range(6))
-        assert evs[-1]["event"] == "done"
-        assert evs[-1]["data"]["result"] == ref
-        assert " ".join(str(t) for t in toks) == ref
-
-        # Last-Event-ID reconnect (header path): replay from k+1
-        evs2 = _sse_post(base, {"request_id": "sse-1"},
-                         headers={"Last-Event-ID": "2"})
-        assert [e["id"] for e in evs2 if e["event"] == "token"] == \
-            [3, 4, 5]
-        assert [e["data"]["token"] for e in evs2
-                if e["event"] == "token"] == toks[3:]
-        assert evs2[-1]["event"] == "done"
-
-        # body-field reconnect is the same contract
-        evs3 = _sse_post(base, {"request_id": "sse-1",
-                                "last_event_id": 4})
-        assert [e["id"] for e in evs3 if e["event"] == "token"] == [5]
-
-        # unknown id reconnect: 404 before any stream byte
-        with pytest.raises(urllib.error.HTTPError) as exc:
-            _sse_post(base, {"request_id": "nope",
-                             "last_event_id": 0})
-        assert exc.value.code == 404
-
-        # fresh submission without input_text: 422
-        with pytest.raises(urllib.error.HTTPError) as exc:
-            _sse_post(base, {"max_new_tokens": 3})
-        assert exc.value.code == 422
-
-        # reproducibility across the wire: same explicit seed twice
-        s1 = _sse_post(base, {"input_text": "5 7 9", "seed": 13,
-                              "request_id": "sse-s1"})
-        s2 = _sse_post(base, {"input_text": "5 7 9", "seed": 13,
-                              "request_id": "sse-s2"})
-        assert ([e["data"] for e in s1 if e["event"] == "token"] ==
-                [e["data"] for e in s2 if e["event"] == "token"])
+        yield f"http://127.0.0.1:{port}"
     finally:
         server.shutdown()
+        server.server_close()
         engine.stop()
+
+
+def test_stdlib_sse_route_and_reconnect(sse_replica):
+    base = sse_replica
+    # the non-streamed answer is the reference
+    req = urllib.request.Request(
+        f"{base}/api/text_generation",
+        data=json.dumps({"input_text": "5 7 9",
+                         "request_id": "batch-1"}).encode(),
+        headers={"Content-Type": "application/json"})
+    with urllib.request.urlopen(req, timeout=60) as r:
+        ref = json.loads(r.read())["result"]
+
+    evs = _sse_post(base, {"input_text": "5 7 9",
+                           "request_id": "sse-1"})
+    toks = [e["data"]["token"] for e in evs
+            if e["event"] == "token"]
+    ids = [e["id"] for e in evs if e["event"] == "token"]
+    assert ids == list(range(6))
+    assert evs[-1]["event"] == "done"
+    assert evs[-1]["data"]["result"] == ref
+    assert " ".join(str(t) for t in toks) == ref
+
+    # Last-Event-ID reconnect (header path): replay from k+1
+    evs2 = _sse_post(base, {"request_id": "sse-1"},
+                     headers={"Last-Event-ID": "2"})
+    assert [e["id"] for e in evs2 if e["event"] == "token"] == \
+        [3, 4, 5]
+    assert [e["data"]["token"] for e in evs2
+            if e["event"] == "token"] == toks[3:]
+    assert evs2[-1]["event"] == "done"
+
+    # body-field reconnect is the same contract
+    evs3 = _sse_post(base, {"request_id": "sse-1",
+                            "last_event_id": 4})
+    assert [e["id"] for e in evs3 if e["event"] == "token"] == [5]
+
+    # unknown id reconnect: 404 before any stream byte
+    with pytest.raises(urllib.error.HTTPError) as exc:
+        _sse_post(base, {"request_id": "nope",
+                         "last_event_id": 0})
+    assert exc.value.code == 404
+
+    # fresh submission without input_text: 422
+    with pytest.raises(urllib.error.HTTPError) as exc:
+        _sse_post(base, {"max_new_tokens": 3})
+    assert exc.value.code == 422
+
+    # reproducibility across the wire: same explicit seed twice
+    s1 = _sse_post(base, {"input_text": "5 7 9", "seed": 13,
+                          "request_id": "sse-s1"})
+    s2 = _sse_post(base, {"input_text": "5 7 9", "seed": 13,
+                          "request_id": "sse-s2"})
+    assert ([e["data"] for e in s1 if e["event"] == "token"] ==
+            [e["data"] for e in s2 if e["event"] == "token"])
 
 
 # ---- fleet router: kill mid-stream, gapless resume ----------------------
@@ -458,6 +462,41 @@ def test_router_stream_kill_gapless_resume():
     assert "resume_tokens" not in t.bodies[0][1]
 
 
+def test_router_streams_a_replicas_sse_over_the_real_transport(
+        sse_replica):
+    """The transport the fleet runs with (`UrllibTransport.stream`)
+    against a real replica: the router's frames are the replica's own
+    events, and a refusal before the first stream byte (an HTTP status,
+    not an exception) reaches the client as one `error` event."""
+    from fengshen_tpu.fleet import FleetConfig, FleetRouter
+
+    direct = _sse_post(sse_replica, {"input_text": "5 7 9",
+                                     "request_id": "direct-1"})
+    router = FleetRouter(FleetConfig(
+        replicas=(sse_replica.split("://", 1)[1],), recovery_probes=1))
+    try:
+        router.poll_once()
+        code, body, frames = router.route_generate_stream(
+            {"input_text": "5 7 9", "request_id": "routed-1"})
+        assert code == 200 and body is None
+        evs = list(iter_sse(b"".join(frames).decode().splitlines()))
+        tokens = lambda events: [  # noqa: E731
+            (e["id"], e["data"]["token"]) for e in events
+            if e["event"] == "token"]
+        assert tokens(evs) == tokens(direct) and len(tokens(evs)) == 6
+        assert evs[-1]["event"] == "done"
+        assert evs[-1]["data"]["result"] == direct[-1]["data"]["result"]
+
+        code, body, frames = router.route_generate_stream(
+            {"request_id": "routed-2", "max_new_tokens": 3})
+        (refusal,) = iter_sse(b"".join(frames).decode().splitlines())
+        assert refusal["event"] == "error"
+        assert refusal["data"]["status"] == 422
+        assert refusal["data"]["error"] == "input_text required"
+    finally:
+        router.stop()
+
+
 def test_router_stream_follows_evacuation():
     from fengshen_tpu.fleet import FleetConfig, FleetRouter
 
@@ -509,32 +548,3 @@ def test_router_stream_draining_refusal():
         {"input_text": "x"})
     assert code == 503 and frames is None
     assert body["reason"] == "draining"
-
-
-# ---- bench harness (the fast no-jax slice) ------------------------------
-
-def test_stream_bench_kill_rung_real_http():
-    """The serve-bench-stream kill rung over REAL stdlib SSE servers:
-    abrupt replica death mid-stream, zero client-visible gaps."""
-    from fengshen_tpu.streaming.bench import _kill_rung
-    out = _kill_rung(new_tokens=12, kill_after=4)
-    assert out["gapless"] is True
-    assert out["token_identical"] is True
-    assert out["terminal"] == "done"
-    assert out["delivered"] == 12
-
-
-def test_make_target_wired():
-    mk = open(os.path.join(REPO, "Makefile")).read()
-    assert "serve-bench-stream:" in mk
-    assert "fengshen_tpu.streaming.bench" in mk
-
-
-def test_benchdiff_identity_grows_stream_keys():
-    from fengshen_tpu.observability.benchdiff import _identity
-    row = {"metric": "m", "value": 1.0}
-    assert _identity(row) == "none"       # old rows unchanged
-    srow = dict(row, stream=True, spec_mode="self_draft")
-    ident = _identity(srow)
-    assert "stream=True" in ident and "spec_mode=self_draft" in ident
-    assert _identity(dict(srow, spec_mode="prompt_lookup")) != ident
