@@ -89,6 +89,9 @@ MODEL_REGISTRY: dict[str, tuple[str, str, dict[str, str]]] = {
                 {"causal_lm": "KeyeForCausalLM", "base": "KeyeModel"}),
     "afmoe": ("fengshen_tpu.models.trinity", "TrinityConfig",
               {"causal_lm": "TrinityForCausalLM", "base": "TrinityModel"}),
+    "kimi_linear": ("fengshen_tpu.models.kimi_linear", "KimiLinearConfig",
+                    {"causal_lm": "KimiLinearForCausalLM",
+                     "base": "KimiLinearModel"}),
 }
 
 

@@ -41,8 +41,7 @@ and not built: it adds nothing to the main model's logits.
 
 from __future__ import annotations
 
-import dataclasses
-from typing import NamedTuple, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -50,6 +49,8 @@ from flax import linen as nn
 
 from fengshen_tpu.models.joyai.configuration_joyai import JoyAIConfig
 from fengshen_tpu.models.llama.modeling_llama import LlamaMLP
+from fengshen_tpu.models.model_utils import (LatentCache,  # noqa: F401
+                                             expert_share, write_latent)
 from fengshen_tpu.ops.embedding import VocabParallelEmbed
 from fengshen_tpu.ops.masks import causal_mask
 from fengshen_tpu.ops.moe import RoutedExperts
@@ -87,63 +88,11 @@ def _dt(config: JoyAIConfig):
     return jnp.dtype(config.dtype)
 
 
-class LatentCache(NamedTuple):
-    """The cache stacks the layer loop carries (module docstring)."""
-
-    kv: jax.Array
-    index: jax.Array
-    table: Optional[jax.Array]
-
-
 def _deinterleave(x):
     """`rope_interleave`: the published code views the rope dims as
     adjacent pairs and moves them to the rotate-half layout
     (`[..., d/2, 2]` -> `[..., 2, d/2]`) before rotating."""
     return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
-
-
-def write_latent(cache: LatentCache, rows, layer, attention_mask):
-    """Scatter this step's latent `rows` `[B, S, R]` into layer
-    `layer` of the stack at each lane's cursor. Returns the cache and
-    the `[B, S, T]` validity of the layer's lane positions (query `t`
-    of lane `b`, at `index + t`, sees positions up to its own; a
-    `attention_mask` over cache positions masks a left-padded prompt).
-
-    The stack is addressed flat — layer `l`'s row `r` is row `l *
-    rows_per_layer + r` — so the write is one scatter into the carried
-    buffer, in place (PERF.md, PR 25). Paged lanes go through their
-    `block_table` row; free lanes are parked on the null block."""
-    batch, seq, width = rows.shape
-    kv = cache.kv
-    index = jnp.broadcast_to(cache.index[layer], (batch,))
-    p = index[:, None] + jnp.arange(seq)[None, :]              # [B, S]
-    if cache.table is not None:
-        num_blocks, block_size = kv.shape[1:3]
-        lane_table = cache.table[layer]
-        lane_len = lane_table.shape[-1] * block_size
-        if seq > lane_len:
-            raise ValueError(
-                f"paged cache updates take at most the virtual lane "
-                f"length {lane_len} tokens per step; got seq={seq}. "
-                "Prefill runs on a contiguous batch-1 cache.")
-        blk = jnp.take_along_axis(lane_table, p // block_size, axis=-1)
-        pos = (layer * num_blocks + blk) * block_size + p % block_size
-    else:
-        lane_len = kv.shape[2]
-        pos = (layer * batch + jnp.arange(batch)[:, None]) * lane_len + p
-    flat = kv.reshape((-1,) + kv.shape[3:])
-    kv = flat.at[pos.reshape(-1)].set(
-        rows.reshape(batch * seq, 1, width).astype(kv.dtype)
-    ).reshape(kv.shape)
-    valid = jnp.arange(lane_len)[None, None, :] <= p[:, :, None]
-    if attention_mask is not None:
-        m = attention_mask[:, :lane_len]
-        if m.shape[1] < lane_len:
-            m = jnp.concatenate(
-                [m, jnp.ones((batch, lane_len - m.shape[1]), m.dtype)], 1)
-        valid = valid & m[:, None, :].astype(bool)
-    return LatentCache(kv, cache.index.at[layer].add(seq),
-                       cache.table), valid
 
 
 class _Kernel(nn.Module):
@@ -352,18 +301,3 @@ class JoyAIForCausalLM(nn.Module):
 
     def partition_rules(self):
         return to_partition_rules(PARAM_LOGICAL_AXES)
-
-
-def expert_share(config: JoyAIConfig, params: dict, first: int,
-                 count: int):
-    """(config, params) of the share that holds experts `first ...
-    first + count` of every expert layer: the `[E, ...]` tables sliced,
-    everything else aliased. What one chip of an expert-parallel
-    deployment is given (docs/sharding.md)."""
-    def cut(path, leaf):
-        name = str(getattr(path[-1], "key", ""))
-        if not name.startswith("experts_"):
-            return leaf
-        return leaf[first:first + count]
-    return (dataclasses.replace(config, experts_held=(first, count)),
-            jax.tree_util.tree_map_with_path(cut, params))

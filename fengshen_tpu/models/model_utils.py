@@ -12,14 +12,21 @@ Port of the reference's shared model utilities
 TPU-native differences: `optax.adamw` replaces FusedAdam/DeepSpeedCPUAdam
 (XLA already fuses the update), and "CPU offload" of optimizer state is a
 sharding/placement decision (see trainer), not a different optimizer.
+
+Below them, what more than two decoders need of each other's cache
+plumbing (ROADMAP D14: a third model takes it from here, not from a
+sibling's private names): `expert_share`, `token_mask`, `LatentCache` /
+`write_latent`.
 """
 
 from __future__ import annotations
 
 import argparse
-from typing import Any, Optional
+import dataclasses
+from typing import Any, NamedTuple, Optional
 
 import jax
+import jax.numpy as jnp
 import optax
 
 
@@ -156,3 +163,81 @@ def get_total_steps(args, dataset_len: int, world_batch: int) -> int:
         return args.max_steps
     epochs = getattr(args, "max_epochs", 1) or 1
     return max(1, epochs * dataset_len // max(world_batch, 1))
+
+
+def expert_share(config, params: dict, first: int, count: int):
+    """(config, params) of the share that holds experts `first ...
+    first + count` of every expert layer: the `[E, ...]` tables sliced,
+    everything else aliased. What one chip of an expert-parallel
+    deployment is given (docs/sharding.md)."""
+    def cut(path, leaf):
+        name = str(getattr(path[-1], "key", ""))
+        if not name.startswith("experts_"):
+            return leaf
+        return leaf[first:first + count]
+    return (dataclasses.replace(config, experts_held=(first, count)),
+            jax.tree_util.tree_map_with_path(cut, params))
+
+
+def token_mask(attention_mask, start, seq: int, max_len: int):
+    """`[B, seq]` bool: which of the window's tokens are real, from a
+    mask over cache positions (shorter than the cache: ones after it)."""
+    if attention_mask is None:
+        return None
+    m = attention_mask.astype(bool)
+    if m.shape[1] < max_len:
+        m = jnp.pad(m, ((0, 0), (0, max_len - m.shape[1])),
+                    constant_values=True)
+    return jax.lax.dynamic_slice_in_dim(m, start, seq, axis=1)
+
+
+class LatentCache(NamedTuple):
+    """The cache stacks the layer loop carries (module docstring)."""
+
+    kv: jax.Array
+    index: jax.Array
+    table: Optional[jax.Array]
+
+
+def write_latent(cache: LatentCache, rows, layer, attention_mask):
+    """Scatter this step's latent `rows` `[B, S, R]` into layer
+    `layer` of the stack at each lane's cursor. Returns the cache and
+    the `[B, S, T]` validity of the layer's lane positions (query `t`
+    of lane `b`, at `index + t`, sees positions up to its own; a
+    `attention_mask` over cache positions masks a left-padded prompt).
+
+    The stack is addressed flat — layer `l`'s row `r` is row `l *
+    rows_per_layer + r` — so the write is one scatter into the carried
+    buffer, in place (PERF.md, PR 25). Paged lanes go through their
+    `block_table` row; free lanes are parked on the null block."""
+    batch, seq, width = rows.shape
+    kv = cache.kv
+    index = jnp.broadcast_to(cache.index[layer], (batch,))
+    p = index[:, None] + jnp.arange(seq)[None, :]              # [B, S]
+    if cache.table is not None:
+        num_blocks, block_size = kv.shape[1:3]
+        lane_table = cache.table[layer]
+        lane_len = lane_table.shape[-1] * block_size
+        if seq > lane_len:
+            raise ValueError(
+                f"paged cache updates take at most the virtual lane "
+                f"length {lane_len} tokens per step; got seq={seq}. "
+                "Prefill runs on a contiguous batch-1 cache.")
+        blk = jnp.take_along_axis(lane_table, p // block_size, axis=-1)
+        pos = (layer * num_blocks + blk) * block_size + p % block_size
+    else:
+        lane_len = kv.shape[2]
+        pos = (layer * batch + jnp.arange(batch)[:, None]) * lane_len + p
+    flat = kv.reshape((-1,) + kv.shape[3:])
+    kv = flat.at[pos.reshape(-1)].set(
+        rows.reshape(batch * seq, 1, width).astype(kv.dtype)
+    ).reshape(kv.shape)
+    valid = jnp.arange(lane_len)[None, None, :] <= p[:, :, None]
+    if attention_mask is not None:
+        m = attention_mask[:, :lane_len]
+        if m.shape[1] < lane_len:
+            m = jnp.concatenate(
+                [m, jnp.ones((batch, lane_len - m.shape[1]), m.dtype)], 1)
+        valid = valid & m[:, None, :].astype(bool)
+    return LatentCache(kv, cache.index.at[layer].add(seq),
+                       cache.table), valid

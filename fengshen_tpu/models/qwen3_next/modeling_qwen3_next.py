@@ -70,13 +70,13 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from fengshen_tpu.models.joyai.modeling_joyai import expert_share
+from fengshen_tpu.models.model_utils import expert_share, token_mask
 from fengshen_tpu.models.qwen3_next.configuration_qwen3_next import (
     FULL, LINEAR, Qwen3NextConfig)
-from fengshen_tpu.models.sala.modeling_sala import _token_mask, _write_rows
+from fengshen_tpu.models.sala.modeling_sala import _write_rows
 from fengshen_tpu.ops.embedding import VocabParallelEmbed
 from fengshen_tpu.ops.gated_attention import folded_prefill_walk
-from fengshen_tpu.ops.gated_delta import (gated_delta_decode,
+from fengshen_tpu.ops.gated_delta import (a_log_init, gated_delta_decode,
                                           gated_delta_prefill, l2norm,
                                           short_conv_decode,
                                           short_conv_prefill)
@@ -137,11 +137,6 @@ def _no_window_on_a_pool(cache: NextCache, seq: int):
             "batch-1 cache")
 
 
-def _a_log_init(key, shape, dtype):
-    # the published initialisation: A uniform in (0, 16)
-    return jnp.log(jax.random.uniform(key, shape, dtype, 1e-3, 16.0))
-
-
 class GatedDeltaNet(nn.Module):
     """`linear_attention`. Returns (output, cache)."""
 
@@ -162,7 +157,7 @@ class GatedDeltaNet(nn.Module):
         w_conv = self.param(
             "conv1d", nn.initializers.normal(cfg.initializer_range),
             (taps, cfg.conv_dim), jnp.dtype(cfg.param_dtype))
-        a_log = self.param("A_log", _a_log_init, (Hv,), jnp.float32)
+        a_log = self.param("A_log", a_log_init, (Hv,), jnp.float32)
         dt_bias = self.param("dt_bias", nn.initializers.ones, (Hv,),
                              jnp.float32)
         beta = jax.nn.sigmoid(ba[..., :Hv])
@@ -179,7 +174,7 @@ class GatedDeltaNet(nn.Module):
             conv_state, state = cache.conv[layer], cache.delta[layer]
             if not tick:
                 _no_window_on_a_pool(cache, seq)
-                mask = _token_mask(attention_mask, cache.start, seq,
+                mask = token_mask(attention_mask, cache.start, seq,
                                    cfg.max_position_embeddings)
         if tick:
             y, conv_state = short_conv_decode(u[:, 0], w_conv, conv_state,

@@ -62,6 +62,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from fengshen_tpu.models.llama.modeling_llama import LlamaMLP
+from fengshen_tpu.models.model_utils import token_mask as _token_mask
 from fengshen_tpu.models.sala.configuration_sala import (LINEAR, SPARSE,
                                                          SalaConfig)
 from fengshen_tpu.ops.embedding import VocabParallelEmbed
@@ -108,18 +109,6 @@ class SalaCache(NamedTuple):
     table: Optional[jax.Array]
     state: jax.Array
     start: jax.Array
-
-
-def _token_mask(attention_mask, start, seq: int, max_len: int):
-    """`[B, seq]` bool: which of the window's tokens are real, from a
-    mask over cache positions (shorter than the cache: ones after it)."""
-    if attention_mask is None:
-        return None
-    m = attention_mask.astype(bool)
-    if m.shape[1] < max_len:
-        m = jnp.pad(m, ((0, 0), (0, max_len - m.shape[1])),
-                    constant_values=True)
-    return jax.lax.dynamic_slice_in_dim(m, start, seq, axis=1)
 
 
 class _Projections(nn.Module):

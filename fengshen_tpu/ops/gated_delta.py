@@ -11,6 +11,13 @@ its write strength (`S_0 = 0`):
     S_t = S' + k_t^T d_t
     o_t = q_t S_t
 
+One recurrence, two gate shapes, told apart by `g`'s rank: ONE scalar a
+head a token (Gated DeltaNet: `g` `[..., H]`), or one value a KEY
+CHANNEL (Kimi Delta Attention, arXiv:2510.26692: `g` `[..., H, Dk]`,
+`S' = Diag(exp(g_t)) S_{t-1}`, row `d` of the state decays by its own
+`exp(g_t[d])`). A per-channel gate that is constant over a head's
+channels is the scalar one.
+
 Two forms of that one recurrence, in plain `jax.numpy` (the CPU
 tier-1 truth; each under its own device scope so a trace finds it):
 
@@ -22,7 +29,8 @@ tier-1 truth; each under its own device scope so a trace finds it):
   into the passes over the state that the update makes anyway;
 - :func:`gated_delta_prefill` — the same mathematics over chunks of `c`
   tokens (the WY / UT transform). Inside a chunk with cumulative
-  log-decays `G`: `A = -strict_tril((beta K) K^T * exp(G_i - G_j))`,
+  log-decays `G` of a scalar gate: `A = -strict_tril((beta K) K^T *
+  exp(G_i - G_j))`,
   `T = (I - A)^-1` (a unit lower triangular solve: forward
   substitution), `W = T (beta K exp(G))`, `U = T (beta V)`; across
   chunks, in order, `V_new = U - W S`, `O = (Q exp(G)) S + tril(Q K^T
@@ -36,6 +44,23 @@ tier-1 truth; each under its own device scope so a trace finds it):
   :func:`xla_gated_delta_prefill`, the `jax.numpy` form: everything
   that does not read the state batched over the chunks, a scan that
   carries the `[H, Dk, Dv]` state and four products a chunk.
+
+  Under a per-channel gate the decay between tokens `i >= j` of a chunk
+  is `exp(G_i - G_j)` a channel, no longer a `[c, c]` mask on `K K^T`:
+  `A = -strict_tril((beta K exp(G)) (K exp(-G))^T)`, `W = T (beta K
+  exp(G))`, `U = T (beta V)`, `O = (Q exp(G)) S + tril((Q exp(G)) (K
+  exp(-G))^T) V_new`, `S <- Diag(exp(G_last)) S + (K exp(G_last -
+  G))^T V_new`. `exp(-G_j)` overflows float32 once a channel has
+  decayed by e^88 inside a chunk, so the two `[c, c]` products are
+  never formed that way (:func:`_channel_decayed_products`): the chunk
+  is cut into sub-blocks of `SUB_BLOCK` rows; a sub-block below the
+  diagonal refers both factors to ITS first row `r` (`exp(G_i - G_r)`
+  and `exp(G_r - G_j)`, `j < r <= i`: both exponents <= 0), a diagonal
+  sub-block takes the explicit `[16, 16, Dk]` differences (`i >= j`:
+  <= 0 again). No exponent is ever positive; a factor that underflows
+  bounds a product that is itself under 1e-38. The Mosaic chunk kernel
+  takes `g` as one scalar a token and cannot take this gate: the seam
+  says so on the dispatch record and runs the `jax.numpy` form.
 
 A masked token (padding) has `beta = 0`, `g = 0`, `k = v = 0`: it
 neither writes nor decays, so padding on either side leaves the state
@@ -58,6 +83,7 @@ C]` (time before channels: the channels are the lanes of a TPU tile; a
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -72,6 +98,13 @@ _HIGHEST = jax.lax.Precision.HIGHEST
 
 #: tokens a chunk of the prefill form (the result does not depend on it)
 DEFAULT_CHUNK = 64
+#: rows a sub-block of a chunk under a per-channel gate (module docstring)
+SUB_BLOCK = 16
+
+
+def a_log_init(key, shape, dtype):
+    """`A_log`'s published initialisation: `log(A)`, A uniform in (0, 16)."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1e-3, 16.0))
 
 
 def l2norm(x, eps: float = 1e-6):
@@ -82,13 +115,17 @@ def l2norm(x, eps: float = 1e-6):
 
 def gated_delta_decode(q, k, v, g, beta, state,
                        live: Optional[jax.Array] = None):
-    """One token a lane. q, k: `[B, H, Dk]`; v: `[B, H, Dv]`; g, beta:
-    `[B, H]` float32; state: `[B, H, Dk, Dv]` float32; `live`: `[B]`
-    bool or None. Returns (`[B, H, Dv]` in v's dtype, the new state);
-    where `live` is False the state is the one passed in, unchanged."""
+    """One token a lane. q, k: `[B, H, Dk]`; v: `[B, H, Dv]`; beta:
+    `[B, H]` float32; g: `[B, H]` (one scalar a head) or `[B, H, Dk]`
+    (one value a key channel: a broadcast along the state's rows);
+    state: `[B, H, Dk, Dv]` float32; `live`: `[B]` bool or None. Returns
+    (`[B, H, Dv]` in v's dtype, the new state); where `live` is False
+    the state is the one passed in, unchanged."""
     with jax.named_scope(DECODE_SCOPE):
         qf, kf, vf = (x.astype(jnp.float32) for x in (q, k, v))
-        decayed = jnp.exp(g.astype(jnp.float32))[..., None, None] * state
+        decay = jnp.exp(g.astype(jnp.float32))
+        decayed = (decay[..., None, None] if g.ndim == 2
+                   else decay[..., None]) * state
         predicted = (kf[..., :, None] * decayed).sum(axis=-2)   # [B,H,Dv]
         delta = beta.astype(jnp.float32)[..., None] * (vf - predicted)
         new = decayed + kf[..., :, None] * delta[..., None, :]
@@ -103,9 +140,10 @@ def gated_delta_prefill(q, k, v, g, beta, state,
                         chunk: int = DEFAULT_CHUNK):
     """A window of tokens onto a state. q, k: `[B, S, Hk, Dk]`; v: `[B,
     S, H, Dv]`, `Hk` dividing `H` (value head `h` reads key head `h //
-    (H // Hk)`, as `jnp.repeat` lays them); g, beta: `[B, S, H]`; state:
-    `[B, H, Dk, Dv]` float32; `mask`: `[B, S]`, 0 on padding (either
-    side), or None. Returns (`[B, S, H, Dv]` in v's dtype, the state
+    (H // Hk)`, as `jnp.repeat` lays them); beta: `[B, S, H]`; g: `[B, S,
+    H]` or, gated per key channel, `[B, S, H, Dk]`; state: `[B, H, Dk,
+    Dv]` float32; `mask`: `[B, S]`, 0 on padding (either side), or None.
+    Returns (`[B, S, H, Dv]` in v's dtype, the state
     after the window's valid tokens). A padded query's output is
     unspecified. The window's shape picks the path
     (`ops.pallas.gated_delta._ineligible_reason`): the Mosaic chunk
@@ -118,7 +156,8 @@ def gated_delta_prefill(q, k, v, g, beta, state,
     impl = resolve_dispatch(
         "gated_delta_prefill",
         f"q={tuple(q.shape)}:{q.dtype.name} v={tuple(v.shape)}:"
-        f"{v.dtype.name}", kernel._ineligible_reason(q, v))
+        f"{v.dtype.name}" + (f" g={tuple(g.shape)}" if g.ndim == 4 else ""),
+        kernel._ineligible_reason(q, v, g))
     if impl == "pallas":
         return kernel.pallas_gated_delta_prefill(q, k, v, g, beta, state,
                                                  mask)
@@ -142,7 +181,8 @@ def xla_gated_delta_prefill(q, k, v, g, beta, state,
     if pad:
         q, k, v = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
                    for x in (q, k, v))
-        g, beta = (jnp.pad(x, ((0, 0), (0, pad), (0, 0))) for x in (g, beta))
+        g, beta = (jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+                   for x in (g, beta))
         mask = jnp.pad(mask, ((0, 0), (0, pad)))
     n = (seq + pad) // c
     with jax.named_scope(PREFILL_SCOPE):
@@ -156,28 +196,33 @@ def xla_gated_delta_prefill(q, k, v, g, beta, state,
             return jnp.moveaxis(x, 2, 3)
 
         qc, kc, vc = chunks(q), chunks(k), chunks(v)       # [B,n,H,c,D]
-        bc, gc = chunks(beta), chunks(g)                   # [B,n,H,c]
-        G = jnp.cumsum(gc, axis=-1)
-        # exp(G_i - G_j) for i >= j: every exponent <= 0
-        lower = jnp.tril(jnp.ones((c, c), bool))
-        decay = jnp.where(lower, jnp.exp(jnp.where(
-            lower, G[..., :, None] - G[..., None, :], 0.0)), 0.0)
-        k_beta = kc * bc[..., None]
-        eye = jnp.eye(c, dtype=jnp.float32)
-        A = -jnp.einsum("bnhid,bnhjd->bnhij", k_beta, kc,
-                        precision=_HIGHEST) * (decay * (1.0 - eye))
-        # T (I - A) = I, forward substitution on a unit lower triangle
-        rhs = jnp.concatenate(
-            [k_beta * jnp.exp(G)[..., None], vc * bc[..., None]], axis=-1)
-        solved = solve_triangular(eye - A, rhs, lower=True,
-                                  unit_diagonal=True)
-        W, U = solved[..., :dk], solved[..., dk:]          # [B,n,H,c,D]
-        qk = jnp.einsum("bnhid,bnhjd->bnhij", qc, kc,
-                        precision=_HIGHEST) * decay
-        q_dec = qc * jnp.exp(G)[..., None]
-        G_last = G[..., -1:]                               # [B,n,H,1]
-        k_dec = kc * jnp.exp(G_last - G)[..., None]
-        chunk_decay = jnp.exp(G_last)[..., None]           # [B,n,H,1,1]
+        bc, gc = chunks(beta), chunks(g)       # [B,n,H,c]; gc [.., c(, Dk)]
+        if g.ndim == 4:
+            W, U, q_dec, qk, k_dec, chunk_decay = _channel_gated_chunks(
+                qc, kc, vc, bc, gc)
+        else:
+            G = jnp.cumsum(gc, axis=-1)
+            # exp(G_i - G_j) for i >= j: every exponent <= 0
+            lower = jnp.tril(jnp.ones((c, c), bool))
+            decay = jnp.where(lower, jnp.exp(jnp.where(
+                lower, G[..., :, None] - G[..., None, :], 0.0)), 0.0)
+            k_beta = kc * bc[..., None]
+            eye = jnp.eye(c, dtype=jnp.float32)
+            A = -jnp.einsum("bnhid,bnhjd->bnhij", k_beta, kc,
+                            precision=_HIGHEST) * (decay * (1.0 - eye))
+            # T (I - A) = I, forward substitution on a unit lower triangle
+            rhs = jnp.concatenate(
+                [k_beta * jnp.exp(G)[..., None], vc * bc[..., None]],
+                axis=-1)
+            solved = solve_triangular(eye - A, rhs, lower=True,
+                                      unit_diagonal=True)
+            W, U = solved[..., :dk], solved[..., dk:]      # [B,n,H,c,D]
+            qk = jnp.einsum("bnhid,bnhjd->bnhij", qc, kc,
+                            precision=_HIGHEST) * decay
+            q_dec = qc * jnp.exp(G)[..., None]
+            G_last = G[..., -1:]                           # [B,n,H,1]
+            k_dec = kc * jnp.exp(G_last - G)[..., None]
+            chunk_decay = jnp.exp(G_last)[..., None]       # [B,n,H,1,1]
 
         def carry(s, xs):
             w, u, qd, a, kd, dec = xs
@@ -198,6 +243,66 @@ def xla_gated_delta_prefill(q, k, v, g, beta, state,
         out = jnp.moveaxis(out, (0, 3), (1, 2)).reshape(
             batch, n * c, heads, dv)[:, :seq]
         return out.astype(v.dtype), state
+
+
+def _channel_decayed_products(rows, keys, G, sub: int = SUB_BLOCK):
+    """`M[i, j] = sum_d r_i[d] k_j[d] exp(G_i[d] - G_j[d])` for `i >= j`
+    (0 above the diagonal), for each `r` of `rows`: the two `[c, c]`
+    products of a chunk under a per-channel gate, with no exponent ever
+    positive (module docstring). rows: arrays `[..., c, Dk]`; keys, G:
+    `[..., c, Dk]`, `G` the chunk's cumulative log-decays (never
+    rising along `c`). Returns a tuple of `[..., c, c]` float32."""
+    c, dk = G.shape[-2:]
+    sub = math.gcd(c, sub)
+    nb = c // sub
+    lead = G.shape[:-2]
+    Gs = G.reshape(lead + (nb, sub, dk))
+    anchor = Gs[..., 0, :]                                  # [..., nb, Dk]
+    # below the diagonal: sub-block I's rows and every earlier key, both
+    # referred to I's first row (the clamp only meets keys the mask drops)
+    to_anchor = jnp.exp(Gs - anchor[..., None, :])          # [..,nb,sub,Dk]
+    keys_at = keys[..., None, :, :] * jnp.exp(jnp.minimum(
+        anchor[..., :, None, :] - G[..., None, :, :], 0.0))  # [..,nb,c,Dk]
+    # the diagonal sub-blocks: explicit differences, i >= j
+    tri = jnp.tril(jnp.ones((sub, sub), bool))
+    within = jnp.exp(jnp.where(
+        tri[..., None], Gs[..., :, None, :] - Gs[..., None, :, :], 0.0))
+    ks = keys.reshape(lead + (nb, sub, dk))
+    blocks = jnp.arange(nb)
+    below = (blocks[:, None] > blocks[None, :])[:, None, :, None]
+    on = (blocks[:, None] == blocks[None, :])[:, None, :, None]
+    out = []
+    for r in rows:
+        rs = r.reshape(lead + (nb, sub, dk))
+        off = jnp.einsum("...id,...jd->...ij", rs * to_anchor, keys_at,
+                         precision=_HIGHEST)                # [..,nb,sub,c]
+        off = off.reshape(lead + (nb, sub, nb, sub))
+        diag = jnp.where(tri, (rs[..., :, None, :] * ks[..., None, :, :] *
+                               within).sum(-1), 0.0)        # [..,nb,sub,sub]
+        full = jnp.where(on, diag[..., :, :, None, :],
+                         jnp.where(below, off, 0.0))
+        out.append(full.reshape(lead + (c, c)))
+    return tuple(out)
+
+
+def _channel_gated_chunks(qc, kc, vc, bc, gc):
+    """What the scan over chunks reads, under a per-channel gate: (W, U,
+    Q exp(G), the `[c, c]` read of a chunk's own tokens, K exp(G_last -
+    G), a chunk's decay a state row `[.., Dk, 1]`). qc, kc, gc: `[B, n,
+    H, c, Dk]`; vc: `[B, n, H, c, Dv]`; bc: `[B, n, H, c]`."""
+    c, dk = gc.shape[-2:]
+    G = jnp.cumsum(gc, axis=-2)
+    k_beta = kc * bc[..., None]
+    eye = jnp.eye(c, dtype=jnp.float32)
+    A, qk = _channel_decayed_products((k_beta, qc), kc, G)
+    rhs = jnp.concatenate([k_beta * jnp.exp(G), vc * bc[..., None]],
+                          axis=-1)
+    solved = solve_triangular(eye + A * (1.0 - eye), rhs, lower=True,
+                              unit_diagonal=True)
+    G_last = G[..., -1:, :]                                # [B,n,H,1,Dk]
+    return (solved[..., :dk], solved[..., dk:], qc * jnp.exp(G), qk,
+            kc * jnp.exp(G_last - G),
+            jnp.swapaxes(jnp.exp(G_last), -1, -2))
 
 
 def _conv(window, weight):

@@ -289,14 +289,19 @@ def _chunks_a_tile(n_chunks: int, rep: int) -> int:
     return max(d for d in range(1, most + 1) if n_chunks % d == 0)
 
 
-def _ineligible_reason(q, v) -> Optional[str]:
+def _ineligible_reason(q, v, g=None) -> Optional[str]:
     """Why a window of this shape cannot take the chunk kernel, or None
-    when it can. q: `[B, S, Hk, Dk]`; v: `[B, S, Hv, Dv]`. Under a
-    multi-device mesh the answer is the xla lowering (GSPMD cannot
-    partition a Mosaic call; the serving window runs on one chip)."""
+    when it can. q: `[B, S, Hk, Dk]`; v: `[B, S, Hv, Dv]`; g: the gate,
+    `[B, S, Hv]` or (per key channel) `[B, S, Hv, Dk]`, where the caller
+    has it. Under a multi-device mesh the answer is the xla lowering
+    (GSPMD cannot partition a Mosaic call; the serving window runs on
+    one chip)."""
     from fengshen_tpu.parallel.mesh import get_mesh
     _, seq, key_heads, dk = q.shape
     heads, dv = v.shape[2:]
+    if g is not None and g.ndim == 4:
+        return "gate per channel: the kernel takes g as [rep, n, c], " \
+               "one scalar a token a head"
     mesh = get_mesh()
     if mesh is not None and mesh.size > 1:
         return f"{mesh.size}-device mesh: GSPMD cannot partition a " \

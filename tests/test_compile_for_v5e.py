@@ -874,3 +874,107 @@ def test_trinity_window_program_reads_a_band_and_walks_in_blocks(
     held = sum(leaf.size * leaf.dtype.itemsize
                for leaf in jax.tree_util.tree_leaves(cache))
     assert mem.alias_size_in_bytes >= held
+
+
+@pytest.fixture(scope="module")
+def kimi_engine():
+    """The benchmark's Kimi-Linear configuration at its full widths (the
+    published layers 1-5, 128 of 256 experts held, half the vocabulary,
+    36,864 positions) behind the engine, parameters as shapes, 8 lanes
+    of 288 blocks instead of 64."""
+    import json
+    import os
+
+    from benchmarks.lib import manifest
+    from fengshen_tpu.serving import ContinuousBatchingEngine, EngineConfig
+    with open(os.path.join(manifest.ROOT, "benchmarks", "configs",
+                           "kimi-linear-48b-a3b.json")) as f:
+        config = json.load(f)
+    model, cfg = manifest.family(config).build(config)
+    assert (cfg.hidden_size, cfg.kda_heads, cfg.kda_head_dim,
+            cfg.latent_width, cfg.num_experts, cfg.experts_held) == (
+        2304, 32, 128, 640, 256, (0, 128))
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    eng = ContinuousBatchingEngine(model, params, EngineConfig(
+        num_slots=8, buckets=(2048,), max_new_tokens=4096,
+        kv_layout="paged", kv_block_size=128, kv_num_blocks=8 * 288 + 1,
+        kv_max_blocks_per_slot=288))
+    return eng, params
+
+
+def test_kimi_tick_reads_the_latent_pool_through_the_kernel_in_place(
+        one_chip, no_compile_cache, kimi_engine, monkeypatch):
+    """The decode tick over ONE latent layer's paged rows beside four
+    layers' two states a lane: no copy, transpose or slice of the pool
+    or of a state stack, the donated cache aliased to the returned one;
+    with the backend's kernels on, the latent read is PR 43's Mosaic
+    kernel (JoyAI's row, 640 wide) and, at 8 lanes, 64 rows are not
+    whole tiles for the grouped matmul (the cell's 64 lanes give 512)."""
+    from fengshen_tpu.ops import pallas as kernels
+    monkeypatch.setitem(kernels._PROBE_CACHE, ("cpu", None),
+                        kernels.KernelProbe("tpu", True, None, "aot"))
+    monkeypatch.setattr(kernels, "_TRACED", {})
+    eng, params = kimi_engine
+    tree = eng._cache["model"]
+    assert tree["cached_latent"].shape == (1, 2305, 128, 1, 640)
+    assert tree["state_delta"].shape == (4, 8, 32, 128, 128)
+    assert tree["state_conv"].shape == (4, 8, 3, 12288)
+    assert tree["block_table"].shape == (1, 8, 288)
+    held = [tree["cached_latent"], tree["state_delta"], tree["state_conv"]]
+    tick = eng._decode_jit.lower(*_abstract(
+        (params, eng._cache, eng._history, eng._mask,
+         jnp.asarray(eng._last_tok), jnp.asarray(eng._pos),
+         jnp.asarray(eng._phys), jnp.asarray(eng._active), eng._keys),
+        one_chip)).compile()
+    assert not _big_copies(tick, {x.shape for x in held} |
+                           {x.shape[1:] for x in held})
+    mem = tick.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(x.nbytes for x in held)
+    assert mem.temp_size_in_bytes < 0.1e9
+    took = {(d["op"], d["impl"]) for d in kernels.traced_dispatch()}
+    assert ("mla_decode_attention", "pallas") in took
+    reasons = [d["detail"] for d in kernels.traced_dispatch()
+               if d["op"] == "gated_delta_prefill"]
+    assert all("gate per channel" in r for r in reasons)
+
+
+def test_kimi_window_program_walks_the_latent_lane_in_blocks(
+        one_chip, no_compile_cache, kimi_engine, monkeypatch):
+    """A 2,048-token window onto the carried batch-1 cache of 36,864
+    latent rows: no `[.., 2048, 36864]` score tensor (9.7 GB in float32
+    over 32 heads), no `[36864, 32, 256]` expansion of the whole lane,
+    no copy of the lane, the donated cache (rows and both states)
+    aliased to the returned one; the per-channel delta rule stays in
+    `jax.numpy` with the reason on record, the experts' products are
+    the Mosaic grouped matmul (2 slots of 2,304 x 1,024 under its
+    VMEM budget)."""
+    from fengshen_tpu.ops import pallas as kernels
+    monkeypatch.setitem(kernels._PROBE_CACHE, ("cpu", None),
+                        kernels.KernelProbe("tpu", True, None, "aot"))
+    monkeypatch.setattr(kernels, "_TRACED", {})
+    eng, params = kimi_engine
+    args = _qwen3next_window_args(eng, params, one_chip)
+    compiled = eng._window_jit.lower(*args).compile()
+    text = compiled.as_text()
+    wide = [line.strip()[:120] for line in text.splitlines()
+            if re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[[\d,]*(2048[\d,]*"
+                        r"36864|36864[\d,]*2048|36864,32,256)", line)]
+    assert not wide, wide
+    cache = args[1]["model"]
+    rows = cache["cached_latent"].shape
+    assert rows == (1, 1, 36864, 1, 640)
+    assert not _big_copies(compiled, {rows, rows[1:], rows[2:]})
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.0e9              # 0.55 GB, PR 45
+    held = sum(leaf.size * leaf.dtype.itemsize
+               for leaf in jax.tree_util.tree_leaves(cache))
+    assert mem.alias_size_in_bytes >= held
+    assert "fstpu_mla_prefill_attention" in text
+    assert text.count("fstpu_moe_experts_gate_up") >= 4
+    sites = kernels.traced_dispatch()
+    assert any(d["op"] == "gated_delta_prefill" and d["impl"] == "xla" and
+               "gate per channel" in d["detail"] and
+               "q=(1, 2048, 32, 128)" in d["detail"] for d in sites)
+    assert any(d["op"] == "grouped_matmul" and d["impl"] == "pallas" and
+               "rows=(16384, 2304)" in d["detail"] for d in sites)

@@ -23,7 +23,16 @@ ring, an indexer's keys: `engine._positional`), so every prompt of
 theirs goes by windows and they have no such program. Taken on the
 commit PR 44 started from and equal on its tree: the two prefill
 bodies and the six decoder skeletons (ROADMAP D13, D14) are merged
-against these."""
+against these.
+
+PR 45 replaced none: `ops/gated_delta.py` took a second gate shape (one
+log-decay a key channel) beside the scalar one, and a scalar-gated call
+lowers to the program it lowered to (Qwen3-Next's three); what
+`models/joyai`, `models/sala` and `models/qwen3_next` shared moved to
+`models/model_utils.py` under the same bodies. It added Kimi-Linear's
+three programs (a latent row a token in one layer of five beside two
+states a lane in the others; a positional cache, so no whole-prompt
+program), taken on its own tree."""
 
 import hashlib
 
@@ -55,6 +64,9 @@ SHA = {
     "trinity.decode": "f500c48b548b710b",
     "trinity.window": "ae7830ae150a1616",
     "trinity.assign": "c63004b277bfeb20",
+    "kimi_linear.decode": "9d90cc775e211cad",
+    "kimi_linear.window": "c0b3c5ac8949a2a4",
+    "kimi_linear.assign": "6b9219d02e589a96",
 }
 
 
@@ -76,6 +88,10 @@ def _model(family):
         from fengshen_tpu.models.qwen3_next import (Qwen3NextConfig,
                                                     Qwen3NextForCausalLM)
         return Qwen3NextForCausalLM(Qwen3NextConfig.small_test_config())
+    if family == "kimi_linear":
+        from fengshen_tpu.models.kimi_linear import (KimiLinearConfig,
+                                                     KimiLinearForCausalLM)
+        return KimiLinearForCausalLM(KimiLinearConfig.small_test_config())
     if family == "trinity":
         from fengshen_tpu.models.trinity import (TrinityConfig,
                                                  TrinityForCausalLM)
