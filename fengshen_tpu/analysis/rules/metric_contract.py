@@ -46,6 +46,7 @@ DYNAMIC_REGISTRATIONS = frozenset({
     "fstpu_serving_completed_total",
     "fstpu_serving_deferred_admissions_total",
     "fstpu_serving_expired_total",
+    "fstpu_serving_prefill_head_rows_total",
     "fstpu_serving_rejected_draining_total",
     "fstpu_serving_rejected_duplicate_total",
     "fstpu_serving_rejected_prompt_too_long_total",

@@ -50,7 +50,8 @@ from flax import linen as nn
 from fengshen_tpu.models.joyai.configuration_joyai import JoyAIConfig
 from fengshen_tpu.models.llama.modeling_llama import LlamaMLP
 from fengshen_tpu.models.model_utils import (LatentCache,  # noqa: F401
-                                             expert_share, write_latent)
+                                             expert_share, head_rows,
+                                             write_latent)
 from fengshen_tpu.ops.embedding import VocabParallelEmbed
 from fengshen_tpu.ops.masks import causal_mask
 from fengshen_tpu.ops.moe import RoutedExperts
@@ -285,7 +286,9 @@ class JoyAIForCausalLM(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, attention_mask=None, position_ids=None,
-                 init_cache=False, deterministic=True):
+                 init_cache=False, deterministic=True, logits_row=None):
+        """`logits_row`: the one row whose logits the caller keeps
+        (`[B, 1, V]`), or None for every row's (`head_rows`)."""
         cfg = self.config
         hidden = JoyAIModel(cfg, name="model")(
             input_ids, attention_mask, position_ids, init_cache,
@@ -294,7 +297,7 @@ class JoyAIForCausalLM(nn.Module):
             cfg.vocab_size, use_bias=False, dtype=_dt(cfg),
             param_dtype=jnp.dtype(cfg.param_dtype),
             kernel_init=nn.initializers.normal(cfg.initializer_range),
-            name="lm_head")(hidden)
+            name="lm_head")(head_rows(hidden, logits_row))
 
     def init_params(self, rng, seq_len: int = 8):
         return self.init(rng, jnp.zeros((1, seq_len), jnp.int32))["params"]

@@ -69,7 +69,8 @@ from flax import linen as nn
 from fengshen_tpu.models.kimi_linear.configuration_kimi_linear import (
     FULL, KDA, KimiLinearConfig)
 from fengshen_tpu.models.model_utils import (LatentCache, expert_share,
-                                             token_mask, write_latent)
+                                             head_rows, token_mask,
+                                             write_latent)
 from fengshen_tpu.ops.embedding import VocabParallelEmbed
 from fengshen_tpu.ops.gated_delta import (a_log_init, gated_delta_decode,
                                           gated_delta_prefill, l2norm,
@@ -381,12 +382,16 @@ class KimiLinearForCausalLM(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, attention_mask=None, position_ids=None,
-                 init_cache=False, deterministic=True, live=None):
+                 init_cache=False, deterministic=True, live=None,
+                 logits_row=None):
+        """`logits_row`: the one row whose logits the caller keeps
+        (`[B, 1, V]`), or None for every row's (`head_rows`)."""
         cfg = self.config
         hidden = KimiLinearModel(cfg, name="model")(
             input_ids, attention_mask, position_ids, init_cache,
             deterministic, live)
-        return _dense(cfg, cfg.vocab_size, "lm_head")(hidden)
+        return _dense(cfg, cfg.vocab_size, "lm_head")(
+            head_rows(hidden, logits_row))
 
     def init_params(self, rng, seq_len: int = 8):
         return self.init(rng, jnp.zeros((1, seq_len), jnp.int32))["params"]

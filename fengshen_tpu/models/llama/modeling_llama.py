@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 from flax.traverse_util import flatten_dict
 from fengshen_tpu.models.llama.configuration_llama import LlamaConfig
+from fengshen_tpu.models.model_utils import head_rows
 from fengshen_tpu.ops.attention import dot_product_attention
 from fengshen_tpu.ops.flash_attention import prefill_attention
 from fengshen_tpu.ops.pallas.decode_attention import (_MAX_QUERY_WINDOW,
@@ -620,11 +621,16 @@ class LlamaForCausalLM(nn.Module):
     @nn.compact
     def __call__(self, input_ids, attention_mask=None, position_ids=None,
                  init_cache=False, deterministic=True,
-                 return_hidden=False, cache_empty=False):
+                 return_hidden=False, cache_empty=False, logits_row=None):
         """`cache_empty` (static) says the cache this call writes holds
         nothing yet: a whole-prompt prefill, which then attends over the
         prompt's own keys (`LlamaAttention`). Only the caller that made
-        the cache can know: `cache_index` is traced."""
+        the cache can know: `cache_index` is traced.
+
+        `logits_row` (a traced int32 scalar, or None for every row):
+        the one row whose logits the caller keeps, sliced out of the
+        hidden states BEFORE the head, whichever form the head has; the
+        logits are then `[B, 1, V]` (`model_utils.head_rows`)."""
         cfg = self.config
         hidden = LlamaModel(cfg, name="model")(
             input_ids, attention_mask, position_ids, init_cache,
@@ -634,6 +640,7 @@ class LlamaForCausalLM(nn.Module):
             # applies the head itself from the param tree (init always
             # runs the normal path, so lm_head params exist either way)
             return hidden
+        hidden = head_rows(hidden, logits_row)
         if cfg.tie_word_embeddings:
             embedding = self.variables["params"]["model"]["embed_tokens"][
                 "embedding"]

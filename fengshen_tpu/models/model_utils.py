@@ -179,6 +179,18 @@ def expert_share(config, params: dict, first: int, count: int):
             jax.tree_util.tree_map_with_path(cut, params))
 
 
+def head_rows(hidden, logits_row):
+    """The rows of `hidden` `[B, T, H]` a causal LM's head projects:
+    all of them when `logits_row` is None, else the ONE at `logits_row`
+    (a traced int32 scalar), `[B, 1, H]`. A prefill keeps one row's
+    logits a prompt, and the caller knows which; sliced after the
+    product, the head ran over every row of every window (PERF.md,
+    PR 46)."""
+    if logits_row is None:
+        return hidden
+    return jax.lax.dynamic_slice_in_dim(hidden, logits_row, 1, axis=1)
+
+
 def token_mask(attention_mask, start, seq: int, max_len: int):
     """`[B, seq]` bool: which of the window's tokens are real, from a
     mask over cache positions (shorter than the cache: ones after it)."""

@@ -70,7 +70,8 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from fengshen_tpu.models.model_utils import expert_share, token_mask
+from fengshen_tpu.models.model_utils import (expert_share, head_rows,
+                                             token_mask)
 from fengshen_tpu.models.qwen3_next.configuration_qwen3_next import (
     FULL, LINEAR, Qwen3NextConfig)
 from fengshen_tpu.models.sala.modeling_sala import _write_rows
@@ -395,12 +396,16 @@ class Qwen3NextForCausalLM(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, attention_mask=None, position_ids=None,
-                 init_cache=False, deterministic=True, live=None):
+                 init_cache=False, deterministic=True, live=None,
+                 logits_row=None):
+        """`logits_row`: the one row whose logits the caller keeps
+        (`[B, 1, V]`), or None for every row's (`head_rows`)."""
         cfg = self.config
         hidden = Qwen3NextModel(cfg, name="model")(
             input_ids, attention_mask, position_ids, init_cache,
             deterministic, live)
-        return _dense(cfg, cfg.vocab_size, "lm_head")(hidden)
+        return _dense(cfg, cfg.vocab_size, "lm_head")(
+            head_rows(hidden, logits_row))
 
     def init_params(self, rng, seq_len: int = 8):
         return self.init(rng, jnp.zeros((1, seq_len), jnp.int32))["params"]

@@ -62,6 +62,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from fengshen_tpu.models.llama.modeling_llama import LlamaMLP
+from fengshen_tpu.models.model_utils import head_rows
 from fengshen_tpu.models.model_utils import token_mask as _token_mask
 from fengshen_tpu.models.sala.configuration_sala import (LINEAR, SPARSE,
                                                          SalaConfig)
@@ -469,11 +470,15 @@ class SalaForCausalLM(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, attention_mask=None, position_ids=None,
-                 init_cache=False, deterministic=True, live=None):
+                 init_cache=False, deterministic=True, live=None,
+                 logits_row=None):
+        """`logits_row`: the one row whose logits the caller keeps
+        (`[B, 1, V]`), or None for every row's (`head_rows`)."""
         cfg = self.config
         hidden = SalaModel(cfg, name="model")(
             input_ids, attention_mask, position_ids, init_cache,
             deterministic, live)
+        hidden = head_rows(hidden, logits_row)
         hidden = hidden / (cfg.hidden_size / cfg.dim_model_base)
         return nn.Dense(
             cfg.vocab_size, use_bias=False, dtype=_dt(cfg),

@@ -61,6 +61,7 @@ from flax import linen as nn
 
 from fengshen_tpu.models.trinity.configuration_trinity import (
     FULL, SLIDING, TrinityConfig)
+from fengshen_tpu.models.model_utils import head_rows
 from fengshen_tpu.ops.embedding import VocabParallelEmbed
 from fengshen_tpu.ops.moe import RoutedExperts, SwiGLU
 from fengshen_tpu.ops.norms import RMSNorm
@@ -362,12 +363,15 @@ class TrinityForCausalLM(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, attention_mask=None, position_ids=None,
-                 init_cache=False, deterministic=True):
+                 init_cache=False, deterministic=True, logits_row=None):
+        """`logits_row`: the one row whose logits the caller keeps
+        (`[B, 1, V]`), or None for every row's (`head_rows`)."""
         cfg = self.config
         hidden = TrinityModel(cfg, name="model")(
             input_ids, attention_mask, position_ids, init_cache,
             deterministic)
-        return _dense(cfg, cfg.vocab_size, "lm_head")(hidden)
+        return _dense(cfg, cfg.vocab_size, "lm_head")(
+            head_rows(hidden, logits_row))
 
     def init_params(self, rng, seq_len: int = 8):
         return self.init(rng, jnp.zeros((1, seq_len), jnp.int32))["params"]

@@ -73,7 +73,6 @@ are untouched (the timeline parity test pins both).
 from __future__ import annotations
 
 import dataclasses
-import inspect
 import itertools
 import threading
 import time
@@ -109,7 +108,8 @@ from fengshen_tpu.utils.generate import (_controls_active,
                                          _select_token,
                                          _spec_round_tokens,
                                          _spec_round_tokens_lanes,
-                                         apply_logits_controls)
+                                         apply_logits_controls,
+                                         model_takes)
 
 
 class QueueFull(Exception):
@@ -628,8 +628,11 @@ class ContinuousBatchingEngine:
             # identical math to generate()'s prompt phase: mask-cumsum
             # positions, _prefill_cache, controls on the last position
             position_ids = jnp.clip(mask.cumsum(-1) - 1, 0, None)
-            logits, cache = _prefill_cache(model, params, ids, mask,
-                                           position_ids)
+            # prompts are left-padded: the last row is the last token,
+            # and the only one whose logits are kept
+            logits, cache = _prefill_cache(
+                model, params, ids, mask, position_ids,
+                logits_row=jnp.int32(ids.shape[1] - 1))
             step_logits = logits[:, -1]
             if controls_on:
                 step_logits = apply_logits_controls(
@@ -640,6 +643,10 @@ class ContinuousBatchingEngine:
             return cache, tok.astype(jnp.int32)
 
         max_len = self.max_len
+
+        # a model that takes `logits_row` projects the one row asked
+        # for; one that does not projects every row of the window
+        row_only = self._row_only = model_takes(model, "logits_row")
 
         def window_fn(params, cache, ids, prompt_row, start, n_valid, rng):
             """One window of a prompt onto the batch-1 cache the
@@ -655,10 +662,12 @@ class ContinuousBatchingEngine:
                 {"params": params, "cache": cache}, ids,
                 attention_mask=mask,
                 position_ids=start + jnp.arange(width)[None],
-                init_cache=True, mutable=["cache"])
+                init_cache=True, mutable=["cache"],
+                **({"logits_row": n_valid - 1} if row_only else {}))
             cache = _rollback_cache(mutated["cache"], width - n_valid)
-            step_logits = jax.lax.dynamic_index_in_dim(
-                logits, n_valid - 1, axis=1, keepdims=False)
+            step_logits = logits[:, 0] if row_only else \
+                jax.lax.dynamic_index_in_dim(
+                    logits, n_valid - 1, axis=1, keepdims=False)
             if controls_on:
                 seen = (jnp.arange(prompt_row.shape[1]) < end)[None]
                 step_logits = apply_logits_controls(
@@ -736,8 +745,7 @@ class ContinuousBatchingEngine:
         moe_shape = self._moe_shape
         # a model with per-lane state keeps a dead lane's itself: it
         # has no null block to park the write on
-        takes_live = "live" in inspect.signature(
-            type(model).__call__).parameters
+        takes_live = model_takes(model, "live")
         if self.self_draft:
             draft_model = self._draft_model
 
@@ -1720,9 +1728,18 @@ class ContinuousBatchingEngine:
                 prefills += 1
                 if windows is None:
                     self.metrics.record_prefill(bucket, len(prefill_ids))
+                    programs, width = 1, bucket
                 else:
+                    width = windows[0][1]
+                    programs = len(windows)
                     self.metrics.record_prefill_windows(
-                        windows[0][1], len(windows), len(prefill_ids))
+                        width, programs, len(prefill_ids))
+                # what the head projected: the row each program was
+                # asked for, or every padded row of a model that
+                # cannot be asked
+                self.metrics.count(
+                    "prefill_head_rows",
+                    programs * (1 if self._row_only else width))
                 t_first = self._clock()
                 req.ttft_s = t_first - req.submit_time
                 self.metrics.record_ttft(req.ttft_s)

@@ -48,6 +48,11 @@ _COUNTERS = {
     "deferred_admissions": (
         "fstpu_serving_deferred_admissions_total",
         "admissions deferred waiting for free KV blocks (paged pool)"),
+    "prefill_head_rows": (
+        "fstpu_serving_prefill_head_rows_total",
+        "rows the LM head projected in prefill programs (over "
+        "prefill_padded_tokens_total: one a program where the model "
+        "takes `logits_row`, every padded row where it does not)"),
 }
 
 

@@ -15,6 +15,7 @@ shapes, preallocated cache), instead of a per-token Python loop.
 
 from __future__ import annotations
 
+import inspect
 from functools import partial
 from typing import Any, Optional
 
@@ -253,10 +254,19 @@ def _rollback_cache(cache, delta):
     return jax.tree_util.tree_map_with_path(fix, cache)
 
 
+def model_takes(model, name: str) -> bool:
+    """Whether `model`'s `__call__` has the optional argument `name`:
+    how a caller finds out what a model family can be told
+    (`cache_empty`, `live`, `logits_row`; docs/serving.md)."""
+    return name in inspect.signature(type(model).__call__).parameters
+
+
 def _prefill_cache(model, params, input_ids, attention_mask,
-                   position_ids):
+                   position_ids, logits_row=None):
     """Abstract-init a decode cache and run the prompt through it.
-    Returns (prompt logits, primed cache).
+    Returns (prompt logits, primed cache). With `logits_row` (a traced
+    scalar) and a model that takes it, the logits are that one row's,
+    `[B, 1, V]`; a model that does not take it returns every row's.
 
     The cache is built from abstract shapes only — a real init would
     materialize a full-precision param tree (fatal for the int8 serving
@@ -271,13 +281,14 @@ def _prefill_cache(model, params, input_ids, attention_mask,
     # the one place that KNOWS the cache holds nothing (it was made a
     # line ago; its index is traced): a model that can use the fact
     # attends over the prompt's own keys, not the cache's extent
-    import inspect
-    empty = {"cache_empty": True} if "cache_empty" in inspect.signature(
-        type(model).__call__).parameters else {}
+    told = {"cache_empty": True} if model_takes(model, "cache_empty") \
+        else {}
+    if logits_row is not None and model_takes(model, "logits_row"):
+        told["logits_row"] = logits_row
     logits, mutated = model.apply(
         {"params": params, "cache": cache}, input_ids,
         attention_mask=attention_mask, position_ids=position_ids,
-        init_cache=True, mutable=["cache"], **empty)
+        init_cache=True, mutable=["cache"], **told)
     return logits, mutated["cache"]
 
 
@@ -718,7 +729,6 @@ def _make_seq2seq_logits_fn(model, params, input_ids, attention_mask,
 
 def _seq2seq_supports_cache(model) -> bool:
     """True when `decode_logits` takes `init_cache` (T5-style KV cache)."""
-    import inspect
     return (hasattr(model, "encode") and hasattr(model, "decode_logits")
             and "init_cache" in
             inspect.signature(model.decode_logits).parameters)
@@ -834,7 +844,6 @@ def _cross_cache_kwargs(model) -> dict:
     """{'cross_from_cache': True} when decode_logits supports reading the
     cross-attention K/V from the cache — the priming call projects the
     encoder K/V once and scan steps skip those matmuls entirely."""
-    import inspect
     if "cross_from_cache" in \
             inspect.signature(model.decode_logits).parameters:
         return {"cross_from_cache": True}
@@ -844,7 +853,6 @@ def _cross_cache_kwargs(model) -> dict:
 def _takes_position_offset(model) -> bool:
     """Absolute-position decoders (BART family) need the decode step's
     position explicitly; T5's relative bias derives it from the cache."""
-    import inspect
     return "position_offset" in \
         inspect.signature(model.decode_logits).parameters
 

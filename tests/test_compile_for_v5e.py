@@ -48,6 +48,20 @@ def _abstract(tree, sharding):
         tree)
 
 
+def _rows_by_vocab(compiled, rows, vocab):
+    """Instructions of the compiled program's ENTRY computation, its
+    parameters aside, whose result is `[..., rows, vocab]`: the head's
+    product over every row of a prefill where one row's logits are
+    kept (PERF.md, PR 46). A fusion's inner lines are never
+    materialised and do not count; a re-laid copy of the head's table
+    (hidden = rows = 2,048 in three families) does."""
+    entry = compiled.as_text().split("\nENTRY ", 1)[1].split("\n}", 1)[0]
+    return [line.strip()[:120] for line in entry.splitlines()
+            if re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[(?:\d+,)*" +
+                        f"{rows},{vocab}\\]", line)
+            and " parameter(" not in line]
+
+
 def test_latent_decode_tick_updates_its_pool_in_place(one_chip,
                                                       no_compile_cache):
     """The continuous-batching engine's decode program over a paged
@@ -313,6 +327,8 @@ def test_whole_prompt_prefill_holds_the_flash_call_and_no_scores_tensor(
               if re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[32,2048,4096\]",
                           line)]
     assert not scores, scores
+    # the head projects the last row alone, not the bucket's 2,048
+    assert not _rows_by_vocab(compiled, 2048, 256)
     # one f32[32, 2048, 4096] alone is 1,024 MiB
     assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2 ** 20
 
@@ -560,6 +576,41 @@ def test_joyai_decode_tick_reads_the_latent_pool_through_the_kernel(
     assert mem.temp_size_in_bytes < 0.1e9      # 21 MB; 269 with the gather
 
 
+def test_joyai_2048_bucket_projects_one_row_onto_the_vocabulary(
+        one_chip, no_compile_cache, monkeypatch):
+    """The whole-prompt program of JoyAI's 2,048 bucket at the
+    benchmark's widths (4 lanes of 24 blocks instead of 64): the head's
+    product is the last row's, so the program holds no `[2048, 129280]`
+    logits (529 MB in bf16; 5.9 ms of the bucket, PERF.md PR 43) and no
+    re-laid copy of the head's `[2048, 129280]` table."""
+    import json
+    import os
+
+    import fengshen_tpu.ops.pallas as kernels
+    from benchmarks.lib import manifest
+    from fengshen_tpu.serving import ContinuousBatchingEngine, EngineConfig
+    monkeypatch.setitem(kernels._PROBE_CACHE, ("cpu", None),
+                        kernels.KernelProbe("tpu", True, None,
+                                            "described v5e"))
+    with open(os.path.join(manifest.ROOT, "benchmarks", "configs",
+                           "joyai-llm-flash.json")) as f:
+        config = json.load(f)
+    model, cfg = manifest.family(config).build(config)
+    assert (cfg.hidden_size, cfg.vocab_size) == (2048, 129280)
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    eng = ContinuousBatchingEngine(model, params, EngineConfig(
+        num_slots=4, buckets=(2048,), max_new_tokens=1024,
+        kv_layout="paged", kv_block_size=128, kv_num_blocks=4 * 24 + 1,
+        kv_max_blocks_per_slot=24))
+    ids = jax.ShapeDtypeStruct((1, 2048), jnp.int32, sharding=one_chip)
+    compiled = eng._prefill_jit.lower(
+        _abstract(params, one_chip), ids, ids,
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)).compile()
+    assert not _rows_by_vocab(compiled, 2048, 129280)
+    assert "[1,2048,129280]" not in compiled.as_text()
+
+
 @pytest.fixture(scope="module")
 def qwen3next_engine():
     """The benchmark's Qwen3-Next configuration at its full widths (one
@@ -626,6 +677,9 @@ def test_qwen3next_window_program_walks_the_keys_in_blocks(
     rows = cache["cached_key"].shape
     assert rows == (1, 1, 18432, 1, 512)
     assert not _big_copies(compiled, {rows, rows[1:], rows[2:]})
+    # the head projects the one row asked for: no `[2048, 75968]`
+    # logits (311 MB in bf16), nor a re-laid copy of its table
+    assert not _rows_by_vocab(compiled, 2048, eng.model.config.vocab_size)
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 1.0e9              # 0.55 GB, PR 32
     held = sum(leaf.size * leaf.dtype.itemsize
@@ -765,8 +819,12 @@ def test_keye_window_program_scores_and_selects_in_tiles(
     assert not wide, wide
     cache = args[1]["model"]
     assert cache["cached_index_key"].shape == (4, 1, 33280, 1, 64)
+    # the head projects the one row asked for: no `[2048, 151936]`
+    # logits (622 MB in bf16), nor a re-laid copy of its table
+    assert eng.model.config.vocab_size == 151936
+    assert not _rows_by_vocab(compiled, 2048, 151936)
     mem = compiled.memory_analysis()
-    assert mem.temp_size_in_bytes < 1.0e9              # 0.65 GB, PR 36
+    assert mem.temp_size_in_bytes < 0.4e9     # 0.29 GB; 0.65 GB, PR 36
     held = sum(leaf.size * leaf.dtype.itemsize
                for leaf in jax.tree_util.tree_leaves(cache))
     assert mem.alias_size_in_bytes >= held
@@ -869,6 +927,7 @@ def test_trinity_window_program_reads_a_band_and_walks_in_blocks(
     assert not _big_copies(compiled, {
         leaf.shape for leaf in jax.tree_util.tree_leaves(cache)
         if len(leaf.shape) == 5})
+    assert not _rows_by_vocab(compiled, 2048, eng.model.config.vocab_size)
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 1.0e9              # 0.57 GB, PR 41
     held = sum(leaf.size * leaf.dtype.itemsize
@@ -965,6 +1024,7 @@ def test_kimi_window_program_walks_the_latent_lane_in_blocks(
     rows = cache["cached_latent"].shape
     assert rows == (1, 1, 36864, 1, 640)
     assert not _big_copies(compiled, {rows, rows[1:], rows[2:]})
+    assert not _rows_by_vocab(compiled, 2048, eng.model.config.vocab_size)
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 1.0e9              # 0.55 GB, PR 45
     held = sum(leaf.size * leaf.dtype.itemsize

@@ -32,7 +32,17 @@ lowers to the program it lowered to (Qwen3-Next's three); what
 `models/model_utils.py` under the same bodies. It added Kimi-Linear's
 three programs (a latent row a token in one layer of five beside two
 states a lane in the others; a positional cache, so no whole-prompt
-program), taken on its own tree."""
+program), taken on its own tree.
+
+PR 46 replaced the seven `*.window` and the two `*.prefill` hashes ON
+PURPOSE: a prefill program now tells the model which row's logits it
+keeps (`logits_row`; `models/model_utils.head_rows`), and the model
+slices its hidden states to that row BEFORE the head, so the head's
+product is `[1, 1, vocab]` where it was `[1, width, vocab]` with one
+row kept (`tests/test_logits_row.py` pins the equality of that row and
+the shape). The seven `*.decode` and seven `*.assign` hashes are the
+ones PR 45 left: no tick and no assignment changed, which is the proof
+that `logits_row=None` is the program it was."""
 
 import hashlib
 
@@ -45,27 +55,27 @@ from fengshen_tpu.serving.engine import (ContinuousBatchingEngine,
 
 SHA = {
     "llama.decode": "5a1add90e836d82a",
-    "llama.window": "053f4a282aafb0ae",
+    "llama.window": "1ce1b0090b6e086c",
     "llama.assign": "13aa0c5a11ba3006",
-    "llama.prefill": "90be13956a587e73",
+    "llama.prefill": "647bd6cb7a07bc79",
     "joyai.decode": "3f1780b6a2d39053",
-    "joyai.window": "b209a149c84224bd",
+    "joyai.window": "5d34ef2b13be9c66",
     "joyai.assign": "96810c12d7c41873",
-    "joyai.prefill": "44951615ee5359e5",
+    "joyai.prefill": "5e491c6bad84d1b8",
     "sala.decode": "8aba89589fbc0375",
-    "sala.window": "91b546b9efe1d108",
+    "sala.window": "61d348bea48383be",
     "sala.assign": "7805764a91b2a79d",
     "qwen3_next.decode": "c7d5fcadf09da984",
-    "qwen3_next.window": "64d62a3b809176e1",
+    "qwen3_next.window": "18f7cbcf1d4a3705",
     "qwen3_next.assign": "b290aa7ede5bb08c",
     "keye.decode": "ebf5fa33299f43a1",
-    "keye.window": "28a1b92dd8b45c7d",
+    "keye.window": "c215eb2d7712672e",
     "keye.assign": "2162ac0abf6da2ca",
     "trinity.decode": "f500c48b548b710b",
-    "trinity.window": "ae7830ae150a1616",
+    "trinity.window": "0ea2aa37da139612",
     "trinity.assign": "c63004b277bfeb20",
     "kimi_linear.decode": "9d90cc775e211cad",
-    "kimi_linear.window": "c0b3c5ac8949a2a4",
+    "kimi_linear.window": "5805f53721c6ad91",
     "kimi_linear.assign": "6b9219d02e589a96",
 }
 
