@@ -348,6 +348,44 @@ def _mla_decode_cases(cfg, rows):
                f"paged bf16 S={s} kv={list(shape)}", got[1:], want[1:])
 
 
+def _mla_prefill_cases(cfg, rows):
+    """Latent attention's full form at the published geometry of both
+    models that have it (32 heads of 128 + 64 and 128 over rows of rank
+    512 in 640): a window of 1,024 queries at position 3,072 of a lane
+    of 8,192 rows, Kimi-Linear's call, and a prompt of 1,024 left-padded
+    by 300 onto a lane of 4,096, JoyAI's; the kernel walks the rows to
+    the window's last query, its xla twin is the `jax.numpy` walk."""
+    del cfg
+    import jax
+    import jax.numpy as jnp
+
+    from fengshen_tpu.ops.pallas import get_kernel
+    from fengshen_tpu.ops.pallas.latent_attention import _ineligible_reason
+    pallas = get_kernel("mla_prefill_attention", "pallas")
+    xla = get_kernel("mla_prefill_attention", "xla")
+    seq, heads, rank, width = 1024, 32, 512, 640
+    ks = jax.random.split(jax.random.PRNGKey(SEED + 6), 4)
+    q_nope = jax.random.normal(ks[0], (1, seq, heads, 128), jnp.bfloat16)
+    q_shared = jax.random.normal(ks[1], (1, seq, heads, 64), jnp.bfloat16)
+    w_kvb = (jax.random.normal(ks[2], (rank, heads, 256)) *
+             rank ** -0.5).astype(jnp.bfloat16)
+    for total, start, pad in ((8192, 3072, None), (4096, 0, 300)):
+        lane = jax.random.normal(ks[3], (1, total, width), jnp.bfloat16)
+        lane = lane.at[..., rank + 64:].set(0)
+        valid = None if pad is None else (jnp.arange(total) >= pad)[None]
+        assert _ineligible_reason(q_nope, q_shared, lane, w_kvb) is None
+        kw = dict(key_valid=valid, scale=192 ** -0.5)
+        args = (q_nope, q_shared, lane, w_kvb, jnp.int32(start))
+        got = jax.jit(lambda *a: pallas(*a, **kw))(*args)
+        want = jax.jit(lambda *a: xla(*a, **kw))(*args)
+        # a pad query has no valid key: each lowering averages what it
+        # walked, and no one reads the row
+        _check(rows, "mla_prefill_attention",
+               f"bf16 q=[1,{seq},{heads},128+64] rows=[1,{total},{width}] "
+               f"start={start} pad={pad}", got[:, pad or 0:],
+               want[:, pad or 0:])
+
+
 def _gated_delta_cases(cfg, rows):
     """The chunked gated delta rule at Qwen3-Next's published head
     geometry (value heads of 128 two to a key head, l2-normalised
@@ -495,6 +533,7 @@ KERNEL_CASES = {
     "decode_attention": _decode_cases,
     "folded_decode_attention": _folded_decode_cases,
     "mla_decode_attention": _mla_decode_cases,
+    "mla_prefill_attention": _mla_prefill_cases,
     "gated_delta_prefill": _gated_delta_cases,
     "grouped_matmul": _grouped_matmul_cases,
     "fused_ce": _fused_ce_cases,

@@ -31,8 +31,11 @@ queries (the decode tick, a speculative verify) runs ABSORBED — the
 no-position query is multiplied into the latent space and the heads
 attend over the cached rows themselves through the
 `decode_attention` seam's latent entry; everything else (no cache, the
-prompt on its contiguous batch-1 cache) runs the FULL form, expanding
-the rows into per-head keys and values.
+prompt on its contiguous batch-1 cache) runs the FULL form through
+`ops/latent_attention.latent_prefill_attention`: the rows from 0 to the
+window's last query expanded into per-head keys and values a block at
+a time (a Mosaic kernel where the shapes tile, else the `jax.numpy`
+walk), a left-padded prompt's padding masked as keys.
 
 Parameter names follow HF's `DeepseekV3ForCausalLM` (see convert.py).
 The multi-token-prediction module (`num_nextn_predict_layers`) is read
@@ -43,7 +46,6 @@ from __future__ import annotations
 
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
@@ -51,9 +53,9 @@ from fengshen_tpu.models.joyai.configuration_joyai import JoyAIConfig
 from fengshen_tpu.models.llama.modeling_llama import LlamaMLP
 from fengshen_tpu.models.model_utils import (LatentCache,  # noqa: F401
                                              expert_share, head_rows,
-                                             write_latent)
+                                             key_mask, write_latent)
 from fengshen_tpu.ops.embedding import VocabParallelEmbed
-from fengshen_tpu.ops.masks import causal_mask
+from fengshen_tpu.ops.latent_attention import latent_prefill_attention
 from fengshen_tpu.ops.moe import RoutedExperts
 from fengshen_tpu.ops.norms import RMSNorm
 from fengshen_tpu.ops.pallas.decode_attention import mla_decode_attention
@@ -63,11 +65,6 @@ from fengshen_tpu.sharding import to_partition_rules, with_logical_constraint
 #: longest query window that reads the cache absorbed (the decode tick
 #: and any speculative gamma; `decode_attention._MAX_QUERY_WINDOW`)
 ABSORBED_WINDOW = 8
-
-#: the full form's device scope (the absorbed one is the seam's)
-PREFILL_SCOPE = "fstpu_mla_prefill_attention"
-
-_NEG_INF = -1e30
 
 #: logical axes of the parameters. The `[E, ...]` expert tables shard
 #: over 'expert' (docs/sharding.md)
@@ -149,15 +146,12 @@ class JoyAIAttention(nn.Module):
             .reshape(rank, H, dn + dv)
         scale = (dn + dr) ** -0.5
 
-        if cache is None:
-            mask = jnp.broadcast_to(causal_mask(seq, seq)[None],
-                                    (batch, seq, seq))
-            if attention_mask is not None:
-                mask = mask & attention_mask[:, None, :].astype(bool)
-        else:
-            pad = jnp.zeros((batch, seq, cfg.latent_width - rank - dr),
-                            c_kv.dtype)
-            rows = jnp.concatenate([c_kv, k_rope, pad], axis=-1)
+        pad = jnp.zeros((batch, seq, cfg.latent_width - rank - dr),
+                        c_kv.dtype)
+        rows = jnp.concatenate([c_kv, k_rope, pad], axis=-1)
+        start = jnp.int32(0)
+        if cache is not None:
+            start = cache.index[layer]       # before the write moves it
             cache, mask = write_latent(cache, rows, layer, attention_mask)
 
         if cache is not None and seq <= ABSORBED_WINDOW:
@@ -176,18 +170,10 @@ class JoyAIAttention(nn.Module):
                         "reads the cache in the full form, which "
                         "expands every cached row: prefill runs on a "
                         "contiguous batch-1 cache, not the paged pool")
-                lane = cache.kv[layer][:, :, 0]            # [B, T, R]
-                c_kv, k_rope = lane[..., :rank], lane[..., rank:rank + dr]
-            with jax.named_scope(PREFILL_SCOPE):
-                kvb = jnp.einsum("btc,chd->bthd", c_kv, w_kvb)
-                scores = (
-                    jnp.einsum("bshd,bthd->bhst", q_nope, kvb[..., :dn],
-                               preferred_element_type=jnp.float32) +
-                    jnp.einsum("bshr,btr->bhst", q_rope, k_rope,
-                               preferred_element_type=jnp.float32)) * scale
-                scores = jnp.where(mask[:, None], scores, _NEG_INF)
-                probs = jax.nn.softmax(scores, axis=-1).astype(_dt(cfg))
-                out = jnp.einsum("bhst,bthd->bshd", probs, kvb[..., dn:])
+                rows = cache.kv[layer][:, :, 0]            # [B, T, R]
+            out = latent_prefill_attention(
+                q_nope, q_rope, rows, w_kvb, start, scale=scale,
+                key_valid=key_mask(attention_mask, rows.shape[1]))
         out = with_logical_constraint(out, ("batch", "seq", "heads", None))
         out = out.reshape(batch, seq, H * dv)
         return dense(cfg.hidden_size, "o_proj")(out), cache

@@ -22,8 +22,9 @@ RMSNorm with a learned gain, eps `rms_norm_eps`.
   `KimiLinearConfig.latent_width` values a token, JoyAI's row: a tick
   reads it ABSORBED through the `decode_attention` seam's latent entry,
   a window of a prompt in the FULL form through
-  `ops/latent_attention.latent_prefill_walk` (the lane's rows expanded
-  a block of keys at a time, onto the carried batch-1 cache).
+  `ops/latent_attention.latent_prefill_attention` (the lane's rows
+  expanded a block of keys at a time, onto the carried batch-1 cache:
+  a Mosaic kernel where the window's shape tiles, else the walk).
 - MLP: layer 0 a dense SwiGLU; after it `ops/moe.py RoutedExperts`:
   sigmoid scores over ALL `num_experts` router outputs in float32, the
   `num_experts_per_token` largest of `scores + bias` picked, their
@@ -77,7 +78,7 @@ from fengshen_tpu.ops.gated_delta import (a_log_init, gated_delta_decode,
                                           short_conv_decode,
                                           short_conv_prefill)
 from fengshen_tpu.ops.latent_attention import (RawKernel,
-                                               latent_prefill_walk)
+                                               latent_prefill_attention)
 from fengshen_tpu.ops.moe import RoutedExperts, SwiGLU
 from fengshen_tpu.ops.norms import RMSNorm
 from fengshen_tpu.ops.pallas.decode_attention import mla_decode_attention
@@ -265,8 +266,8 @@ class KimiLatentAttention(nn.Module):
                     cache.kv, rows[None, :, :, None].astype(cache.kv.dtype),
                     (layer, 0, start, 0, 0)))
                 lane = cache.kv[layer][:, :, 0]            # [B, T, R]
-            out = latent_prefill_walk(q_nope, q_shared, lane, w_kvb, start,
-                                      scale=scale)
+            out = latent_prefill_attention(q_nope, q_shared, lane, w_kvb,
+                                           start, scale=scale)
         out = with_logical_constraint(out, ("batch", "seq", "heads", None))
         out = out.reshape(batch, seq, H * dv)
         return _dense(cfg, cfg.hidden_size, "o_proj")(out), cache

@@ -15,8 +15,8 @@ sharding/placement decision (see trainer), not a different optimizer.
 
 Below them, what more than two decoders need of each other's cache
 plumbing (ROADMAP D14: a third model takes it from here, not from a
-sibling's private names): `expert_share`, `token_mask`, `LatentCache` /
-`write_latent`.
+sibling's private names): `expert_share`, `key_mask`, `token_mask`,
+`LatentCache` / `write_latent`.
 """
 
 from __future__ import annotations
@@ -191,6 +191,19 @@ def head_rows(hidden, logits_row):
     return jax.lax.dynamic_slice_in_dim(hidden, logits_row, 1, axis=1)
 
 
+def key_mask(attention_mask, max_len: int):
+    """`[B, max_len]`, nonzero where a cache position may be read, from
+    a mask over them (shorter than the cache: ones after it; longer:
+    cut), in the mask's dtype; None stays None."""
+    if attention_mask is None:
+        return None
+    m = attention_mask[:, :max_len]
+    if m.shape[1] < max_len:
+        m = jnp.concatenate(
+            [m, jnp.ones((m.shape[0], max_len - m.shape[1]), m.dtype)], 1)
+    return m
+
+
 def token_mask(attention_mask, start, seq: int, max_len: int):
     """`[B, seq]` bool: which of the window's tokens are real, from a
     mask over cache positions (shorter than the cache: ones after it)."""
@@ -246,10 +259,7 @@ def write_latent(cache: LatentCache, rows, layer, attention_mask):
     ).reshape(kv.shape)
     valid = jnp.arange(lane_len)[None, None, :] <= p[:, :, None]
     if attention_mask is not None:
-        m = attention_mask[:, :lane_len]
-        if m.shape[1] < lane_len:
-            m = jnp.concatenate(
-                [m, jnp.ones((batch, lane_len - m.shape[1]), m.dtype)], 1)
-        valid = valid & m[:, None, :].astype(bool)
+        valid = valid & key_mask(attention_mask,
+                                 lane_len)[:, None, :].astype(bool)
     return LatentCache(kv, cache.index.at[layer].add(seq),
                        cache.table), valid

@@ -259,6 +259,7 @@ def run_per_shard(kernel: Callable, q, k, v, *per_token):
 from fengshen_tpu.ops.flash_attention import blockwise_attention  # noqa: E402
 from fengshen_tpu.ops.gated_attention import folded_decode_walk  # noqa: E402
 from fengshen_tpu.ops.gated_delta import xla_gated_delta_prefill  # noqa: E402
+from fengshen_tpu.ops.latent_attention import latent_prefill_walk  # noqa: E402
 from fengshen_tpu.ops.moe import xla_grouped_swiglu  # noqa: E402
 # aliased: binding the bare function name here would shadow the
 # `ops.pallas.block_sparse_attention` SUBMODULE attribute that
@@ -277,6 +278,8 @@ from fengshen_tpu.ops.pallas.gated_delta import (  # noqa: E402
     pallas_gated_delta_prefill)
 from fengshen_tpu.ops.pallas.grouped_matmul import (  # noqa: E402
     pallas_grouped_swiglu)
+from fengshen_tpu.ops.pallas.latent_attention import (  # noqa: E402
+    pallas_latent_prefill_attention)
 
 register_kernel("flash_attention", "pallas", pallas_flash_attention)
 register_kernel("flash_attention", "xla", blockwise_attention)
@@ -295,6 +298,12 @@ register_kernel("folded_decode_attention", "xla", folded_decode_walk)
 # gather of the lane
 register_kernel("mla_decode_attention", "pallas", pallas_mla_decode_attention)
 register_kernel("mla_decode_attention", "xla", xla_mla_decode_attention)
+# latent attention's FULL form, a window of queries onto a lane of
+# latent rows (the seam is `ops.latent_attention.latent_prefill_attention`);
+# its xla lowering the `jax.numpy` walk
+register_kernel("mla_prefill_attention", "pallas",
+                pallas_latent_prefill_attention)
+register_kernel("mla_prefill_attention", "xla", latent_prefill_walk)
 # the chunked gated delta rule of a prefill window (the seam is
 # `ops.gated_delta.gated_delta_prefill`); its xla lowering the
 # `jax.numpy` chunked form

@@ -51,6 +51,40 @@ def fresh_probe(monkeypatch):
     probe(refresh=True)
 
 
+@pytest.fixture
+def latent_kernel_interpreted():
+    """`with latent_kernel_interpreted() as took:` runs a model's
+    full-form latent attention through the seam's Mosaic kernel in
+    interpret mode, on the CPU: the seam's own decision with the
+    probe's "no Mosaic here" taken out for this one op (a shape the
+    kernel cannot tile still takes the walk; every other op keeps its
+    xla lowering). `took`: the details of the call sites that took the
+    kernel."""
+    import contextlib
+    import functools
+
+    from fengshen_tpu.ops import pallas as kernels
+    from fengshen_tpu.ops.pallas import latent_attention as kernel
+
+    @contextlib.contextmanager
+    def on():
+        real, took = kernels.resolve_dispatch, []
+
+        def resolve(op, detail, ineligible):
+            if op == "mla_prefill_attention" and ineligible is None:
+                took.append(detail)
+                return "pallas"
+            return real(op, detail, ineligible)
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(kernels, "resolve_dispatch", resolve)
+            patch.setattr(
+                kernel, "pallas_latent_prefill_attention",
+                functools.partial(kernel.pallas_latent_prefill_attention,
+                                  interpret=True))
+            yield took
+    return on
+
+
 @pytest.fixture(autouse=True, scope="module")
 def _no_mesh_left_behind():
     """`Trainer.__init__` (and a test that forgets) installs the

@@ -611,6 +611,107 @@ def test_joyai_2048_bucket_projects_one_row_onto_the_vocabulary(
     assert "[1,2048,129280]" not in compiled.as_text()
 
 
+@pytest.mark.parametrize("seq, total, padded", [
+    (2048, 36864, False), (2048, 4096, True), (256, 4096, True)],
+    ids=["kimi_window", "joyai_2048", "joyai_256"])
+def test_latent_prefill_kernel_compiles_at_the_cells_shapes(
+        one_chip, no_compile_cache, seq, total, padded):
+    """The full form's kernel for the chip at the two cells' geometry:
+    32 heads of 128 + 64 and 128 over rows of 640 (rank 512), a window
+    of 2,048 queries at a traced offset onto Kimi-Linear's lane of
+    36,864 rows, and JoyAI's largest and smallest bucket onto its lane
+    of 4,096 with the left padding as `key_valid`. The program is the
+    one custom call under the scope's name; the lane is an operand as
+    it lies (no copy, slice or transpose of it), nothing `[32, seq,
+    total]` exists and nothing `[total, 32, 256]` either."""
+    from fengshen_tpu.ops.latent_attention import PREFILL_SCOPE
+    from fengshen_tpu.ops.pallas.latent_attention import (
+        _ineligible_reason, pallas_latent_prefill_attention)
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    args = [shape((1, seq, 32, 128)), shape((1, seq, 32, 64)),
+            shape((1, total, 640)), shape((512, 32, 256)),
+            shape((), jnp.int32)]
+    assert _ineligible_reason(*args[:4]) is None
+    if padded:
+        args.append(shape((1, total), jnp.bool_))
+
+    def call(q_nope, q_shared, rows, w_kvb, start, key_valid=None):
+        return pallas_latent_prefill_attention(
+            q_nope, q_shared, rows, w_kvb, start, key_valid=key_valid,
+            scale=192 ** -0.5)
+    compiled = jax.jit(call).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and PREFILL_SCOPE in text
+    assert not _big_copies(compiled, {(1, total, 640), (total, 640)})
+    wide = [line.strip()[:120] for line in text.splitlines()
+            if re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[[\d,]*(" +
+                        f"32,{seq},{total}|{total},32,256)", line)]
+    assert not wide, wide
+    # the padded query and the output, nothing the lane's size
+    assert compiled.memory_analysis().temp_size_in_bytes < 40e6
+
+
+def test_joyai_prefill_buckets_read_the_prompt_through_the_latent_kernel(
+        one_chip, no_compile_cache, monkeypatch):
+    """The whole-prompt programs of the JoyAI cell's four buckets at the
+    benchmark's widths (4 lanes of 24 blocks instead of 64): every
+    layer's full form takes the Mosaic kernel by the call's shape, with
+    the bucket's left padding as `key_valid` and the batch-1 cache's
+    4,096 rows as the lane; the 2,048 bucket's compiled program holds
+    five such calls by the scope's name and no `[32, 2048, 4096]` score
+    tensor (1 GB in float32: the dense chain's, PERF.md PR 43) and no
+    `[4096, 32, 256]` expansion of the whole lane."""
+    import json
+    import os
+
+    import fengshen_tpu.ops.pallas as kernels
+    from benchmarks.lib import manifest
+    from fengshen_tpu.ops.latent_attention import PREFILL_SCOPE
+    from fengshen_tpu.serving import ContinuousBatchingEngine, EngineConfig
+    monkeypatch.setitem(kernels._PROBE_CACHE, ("cpu", None),
+                        kernels.KernelProbe("tpu", True, None,
+                                            "described v5e"))
+    with open(os.path.join(manifest.ROOT, "benchmarks", "configs",
+                           "joyai-llm-flash.json")) as f:
+        config = json.load(f)
+    model, _ = manifest.family(config).build(config)
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    buckets = (256, 512, 1024, 2048)
+    eng = ContinuousBatchingEngine(model, params, EngineConfig(
+        num_slots=4, buckets=buckets, max_new_tokens=1024,
+        kv_layout="paged", kv_block_size=128, kv_num_blocks=4 * 24 + 1,
+        kv_max_blocks_per_slot=24))
+    monkeypatch.setattr(kernels, "_TRACED", {})
+    for bucket in buckets:
+        ids = jax.ShapeDtypeStruct((1, bucket), jnp.int32, sharding=one_chip)
+        lowered = eng._prefill_jit.lower(
+            _abstract(params, one_chip), ids, ids,
+            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip))
+    # the prompts' own call sites (the abstract `init` of each
+    # program's cache is one token wide and takes the walk, on record)
+    took = [d for d in kernels.traced_dispatch()
+            if d["op"] == "mla_prefill_attention" and
+            "rows=(1, 4096, 640)" in d["detail"]]
+    assert [(d["impl"], d["detail"]) for d in took] == [
+        ("pallas", f"q=(1, {bucket}, 32, 128)+64:bfloat16 "
+                   "rows=(1, 4096, 640):bfloat16 key_valid")
+        for bucket in buckets]
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    calls = re.findall(r"%?(" + PREFILL_SCOPE + r"[\w.\-]*) = [^\n]*"
+                       r"custom_call_target=\"tpu_custom_call\"", text)
+    assert len(calls) == 5, calls
+    wide = [line.strip()[:120] for line in text.splitlines()
+            if re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[[\d,]*"
+                        r"(32,2048,4096|4096,32,256)", line)]
+    assert not wide, wide
+    # 1.4 GB with the dense chain's scores and probabilities
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
 @pytest.fixture(scope="module")
 def qwen3next_engine():
     """The benchmark's Qwen3-Next configuration at its full widths (one
@@ -1004,7 +1105,9 @@ def test_kimi_window_program_walks_the_latent_lane_in_blocks(
     latent rows: no `[.., 2048, 36864]` score tensor (9.7 GB in float32
     over 32 heads), no `[36864, 32, 256]` expansion of the whole lane,
     no copy of the lane, the donated cache (rows and both states)
-    aliased to the returned one; the per-channel delta rule stays in
+    aliased to the returned one; the full form is ONE Mosaic call (the
+    seam's kernel, by the window's shape: the lane an operand as it
+    lies in the cache), the per-channel delta rule stays in
     `jax.numpy` with the reason on record, the experts' products are
     the Mosaic grouped matmul (2 slots of 2,304 x 1,024 under its
     VMEM budget)."""
@@ -1030,9 +1133,14 @@ def test_kimi_window_program_walks_the_latent_lane_in_blocks(
     held = sum(leaf.size * leaf.dtype.itemsize
                for leaf in jax.tree_util.tree_leaves(cache))
     assert mem.alias_size_in_bytes >= held
-    assert "fstpu_mla_prefill_attention" in text
+    assert len(re.findall(
+        r"%?fstpu_mla_prefill_attention[\w.\-]* = [^\n]*"
+        r"custom_call_target=\"tpu_custom_call\"", text)) == 1
     assert text.count("fstpu_moe_experts_gate_up") >= 4
     sites = kernels.traced_dispatch()
+    assert {"op": "mla_prefill_attention", "impl": "pallas",
+            "detail": "q=(1, 2048, 32, 128)+64:bfloat16 "
+                      "rows=(1, 36864, 640):bfloat16"} in sites
     assert any(d["op"] == "gated_delta_prefill" and d["impl"] == "xla" and
                "gate per channel" in d["detail"] and
                "q=(1, 2048, 32, 128)" in d["detail"] for d in sites)

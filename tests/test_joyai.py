@@ -303,6 +303,112 @@ def test_lockstep_generate_runs_on_the_contiguous_latent_cache():
 
 # ---- the two forms of the attention -----------------------------------------
 
+def _dense_full_form(q_nope, q_rope, rows, w_kvb, start, *, key_valid=None,
+                     scale):
+    """The full form as the model had it inline before the seam
+    (`latent_prefill_attention`'s arguments): EVERY row of the lane
+    expanded, dense float32 scores over the lane's whole length, one
+    softmax, the probabilities normalised and then rounded."""
+    rank, dn, dr = w_kvb.shape[0], q_nope.shape[-1], q_rope.shape[-1]
+    seq, total = q_nope.shape[1], rows.shape[1]
+    mask = jnp.arange(total)[None, None, :] <= \
+        (start + jnp.arange(seq))[None, :, None]
+    if key_valid is not None:
+        mask = mask & key_valid[:, None, :].astype(bool)
+    kvb = jnp.einsum("btc,chd->bthd", rows[..., :rank], w_kvb)
+    scores = (
+        jnp.einsum("bshd,bthd->bhst", q_nope, kvb[..., :dn],
+                   preferred_element_type=jnp.float32) +
+        jnp.einsum("bshr,btr->bhst", q_rope, rows[..., rank:rank + dr],
+                   preferred_element_type=jnp.float32)) * scale
+    scores = jnp.where(mask[:, None], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q_nope.dtype)
+    return jnp.einsum("bhst,bthd->bshd", probs, kvb[..., dn:])
+
+
+def _left_padded(model, params, n_real, bucket, cached):
+    """Logits of a prompt left-padded to `bucket` the way the engine's
+    prefill program runs it (mask-cumsum positions; `cached`: onto a
+    fresh batch-1 cache of `max_position_embeddings` rows, else no
+    cache at all), the real rows only, and the cache it left."""
+    ids = np.zeros((1, bucket), np.int32)
+    ids[0, bucket - n_real:] = _ids(n_real, seed=6)
+    mask = jnp.asarray(ids > 0, jnp.int32)
+    position_ids = jnp.clip(mask.cumsum(-1) - 1, 0, None)
+    if cached:
+        logits, cache = _prefill_cache(model, params, jnp.asarray(ids),
+                                       mask, position_ids)
+    else:
+        logits, cache = model.apply({"params": params}, jnp.asarray(ids),
+                                    mask, position_ids), None
+    return np.asarray(logits, np.float32)[0, bucket - n_real:], cache
+
+
+@pytest.mark.parametrize("cached", [False, True],
+                         ids=["no_cache", "batch1_cache"])
+def test_a_left_padded_prompt_through_the_seam_equals_the_dense_chain(
+        made, monkeypatch, cached):
+    """The whole-prompt prefill through `latent_prefill_attention` (on
+    the CPU the walk: rows 0 .. S of the lane in blocks, the padding
+    masked as keys) against the dense chain the model had inline, in
+    float32: 17 real tokens behind 7 of padding, with no cache and onto
+    the batch-1 cache of 128 rows; and the rows left in that cache."""
+    model, params, _, _ = made
+    got, cache = _left_padded(model, params, 17, 24, cached)
+    monkeypatch.setattr(modeling_joyai, "latent_prefill_attention",
+                        _dense_full_form)
+    want, cache_d = _left_padded(model, params, 17, 24, cached)
+    assert np.abs(want).max() > 0.1
+    assert np.abs(got - want).max() < TOLERANCE
+    if cached:
+        np.testing.assert_allclose(
+            np.asarray(cache["model"]["cached_latent"]),
+            np.asarray(cache_d["model"]["cached_latent"]), atol=1e-6)
+        assert np.asarray(cache["model"]["cache_index"]).tolist() == [24] * 3
+
+
+def test_a_left_padded_prompt_through_the_kernel_equals_the_walk(
+        latent_kernel_interpreted, monkeypatch):
+    """At widths the full form's kernel tiles (rank 128, heads of 128 +
+    64 and 128, bfloat16, a 128-token bucket onto a cache of 256 rows)
+    every layer's prefill takes the Mosaic kernel (interpret mode) with
+    the padding as `key_valid`: the real rows' logits against the walk's
+    and against the dense chain's, and the rows in the cache. The
+    three round the same bfloat16 operands; the dense chain rounds the
+    NORMALISED probabilities, the online forms the unnormalised ones."""
+    model, params = _make_tiling()
+    want, cache_w = _left_padded(model, params, 91, 128, True)
+    with latent_kernel_interpreted() as took:
+        got, cache_k = _left_padded(model, params, 91, 128, True)
+    assert took == ["q=(1, 128, 2, 128)+64:bfloat16 "
+                    "rows=(1, 256, 256):bfloat16 key_valid"] * 3
+    monkeypatch.setattr(modeling_joyai, "latent_prefill_attention",
+                        _dense_full_form)
+    dense, _ = _left_padded(model, params, 91, 128, True)
+    top = np.abs(want).max()
+    assert np.isfinite(got).all() and top > 0.1
+    np.testing.assert_allclose(got, want, atol=0.02 * top)
+    np.testing.assert_allclose(got, dense, atol=0.03 * top)
+    rows_k, rows_w = (np.asarray(c["model"]["cached_latent"], np.float32)
+                      for c in (cache_k, cache_w))
+    # layer 0's rows are written before any attention ran; the later
+    # layers' follow the layers before them to bfloat16's last place
+    np.testing.assert_array_equal(rows_k[0], rows_w[0])
+    np.testing.assert_allclose(rows_k, rows_w, atol=0.02 * np.abs(rows_w).max())
+
+
+def _make_tiling():
+    """The tiny model at latent widths the full form's kernel tiles."""
+    cfg = JoyAIConfig.small_test_config(
+        dtype="bfloat16", kv_lora_rank=128, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, num_attention_heads=2,
+        max_position_embeddings=256)
+    model = JoyAIForCausalLM(cfg)
+    shapes = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    return model, weights.fill_like(weights.base_key(SEED), shapes)
+
+
 def test_absorbed_decode_equals_the_full_form_on_the_same_cache(
         monkeypatch):
     """One more token on a primed contiguous cache, once absorbed (the

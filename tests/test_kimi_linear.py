@@ -242,6 +242,53 @@ def test_the_latent_walk_equals_dense_attention(q_tile, key_block):
         atol=1e-5)
 
 
+def test_windows_through_the_kernel_equal_windows_through_the_walk(
+        latent_kernel_interpreted):
+    """Three windows of 128 tokens onto a carried batch-1 cache of 512
+    rows, at widths the full form's kernel tiles (rank 128, heads of
+    128 + 64 and 128, bfloat16): the model's logits with the seam on
+    the Mosaic kernel (interpret mode) against the same windows on
+    `latent_prefill_walk`, the path before the seam, and the rows both
+    leave in the cache. Kernel and walk round the same operands and
+    partition the keys differently (blocks of 512 against the walk's
+    own): bfloat16's last place in one layer's output."""
+    from fengshen_tpu.serving.cache import abstract_init
+    cfg = KimiLinearConfig.small_test_config(
+        dtype="bfloat16", kv_lora_rank=128, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, num_attention_heads=2,
+        max_position_embeddings=512)
+    model = KimiLinearForCausalLM(cfg)
+    params = weights.fill_like(weights.base_key(5), jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 8), jnp.int32))["params"]))
+    ids = _prompt(384, seed=2)
+
+    def windows():
+        cache = jax.tree_util.tree_map(
+            lambda s: jnp.zeros(s.shape, s.dtype),
+            abstract_init(model, 1)["cache"])
+        out = []
+        for start in range(0, 384, 128):
+            logits, mut = model.apply(
+                {"params": params, "cache": cache},
+                ids[None, start:start + 128],
+                attention_mask=(jnp.arange(512) < start + 128)[None],
+                init_cache=True, mutable=["cache"])
+            cache = mut["cache"]
+            out.append(np.asarray(logits[0], np.float32))
+        return np.concatenate(out), cache
+    want, cache_w = windows()
+    with latent_kernel_interpreted() as took:
+        got, cache_k = windows()
+    assert took == ["q=(1, 128, 2, 128)+64:bfloat16 "
+                    "rows=(1, 512, 256):bfloat16"] * 3
+    assert np.isfinite(got).all() and np.abs(want).max() > 0.1
+    np.testing.assert_allclose(got, want, atol=0.02 * np.abs(want).max())
+    np.testing.assert_array_equal(
+        np.asarray(cache_k["model"]["cached_latent"], np.float32),
+        np.asarray(cache_w["model"]["cached_latent"], np.float32))
+
+
 def test_the_absorbed_tick_equals_the_full_form():
     """One query a lane read through the seam's latent entry (the query
     multiplied into the latent space, the heads attending over the rows

@@ -39,7 +39,7 @@ def test_probe_cached_and_forceable(fresh_probe):
 def test_dispatch_table_follows_the_probe(fresh_probe):
     table = dispatch_table()
     for op in ("decode_attention", "folded_decode_attention",
-               "mla_decode_attention", "fused_ce",
+               "mla_decode_attention", "mla_prefill_attention", "fused_ce",
                "flash_attention", "block_sparse_attention",
                "gated_delta_prefill", "grouped_matmul"):
         assert table[op] == "xla"  # CPU backend: stock lowerings

@@ -42,7 +42,20 @@ product is `[1, 1, vocab]` where it was `[1, width, vocab]` with one
 row kept (`tests/test_logits_row.py` pins the equality of that row and
 the shape). The seven `*.decode` and seven `*.assign` hashes are the
 ones PR 45 left: no tick and no assignment changed, which is the proof
-that `logits_row=None` is the program it was."""
+that `logits_row=None` is the program it was.
+
+PR 47 replaced `joyai.prefill`, `joyai.window` and `kimi_linear.window`
+ON PURPOSE: the full form of latent attention is one seam
+(`ops/latent_attention.latent_prefill_attention`), and JoyAI's
+prefill reads rows `0 .. start + S` of its lane in blocks under an
+online softmax (off a TPU: `latent_prefill_walk`, with the left
+padding as `key_valid`) where it expanded all `max_position_embeddings`
+rows into dense scores; Kimi-Linear's window calls the same walk
+through the seam (the mask gained a batch axis for `key_valid`, nothing
+else). `tests/test_joyai.py` and `tests/test_kimi_linear.py` pin the
+logits against the old chain and the old call. Both models' `*.decode`
+and `*.assign` hashes are the ones PR 45 left: the absorbed tick and
+`write_latent` did not change."""
 
 import hashlib
 
@@ -59,9 +72,9 @@ SHA = {
     "llama.assign": "13aa0c5a11ba3006",
     "llama.prefill": "647bd6cb7a07bc79",
     "joyai.decode": "3f1780b6a2d39053",
-    "joyai.window": "5d34ef2b13be9c66",
+    "joyai.window": "c7e8f7acd2adacda",
     "joyai.assign": "96810c12d7c41873",
-    "joyai.prefill": "5e491c6bad84d1b8",
+    "joyai.prefill": "2f56076b0be4f486",
     "sala.decode": "8aba89589fbc0375",
     "sala.window": "61d348bea48383be",
     "sala.assign": "7805764a91b2a79d",
@@ -75,7 +88,7 @@ SHA = {
     "trinity.window": "0ea2aa37da139612",
     "trinity.assign": "c63004b277bfeb20",
     "kimi_linear.decode": "9d90cc775e211cad",
-    "kimi_linear.window": "5805f53721c6ad91",
+    "kimi_linear.window": "f774c9a50075f2f8",
     "kimi_linear.assign": "6b9219d02e589a96",
 }
 
