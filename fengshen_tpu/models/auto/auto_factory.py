@@ -92,6 +92,8 @@ MODEL_REGISTRY: dict[str, tuple[str, str, dict[str, str]]] = {
     "kimi_linear": ("fengshen_tpu.models.kimi_linear", "KimiLinearConfig",
                     {"causal_lm": "KimiLinearForCausalLM",
                      "base": "KimiLinearModel"}),
+    "sdar_moe": ("fengshen_tpu.models.sdar", "SdarConfig",
+                 {"causal_lm": "SdarForCausalLM", "base": "SdarModel"}),
 }
 
 

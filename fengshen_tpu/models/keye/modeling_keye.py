@@ -61,7 +61,7 @@ from flax import linen as nn
 
 from fengshen_tpu.models.keye.configuration_keye import KeyeConfig
 from fengshen_tpu.models.model_utils import head_rows
-from fengshen_tpu.models.sala.modeling_sala import _write_rows
+from fengshen_tpu.models.model_utils import write_rows as _write_rows
 from fengshen_tpu.ops.embedding import VocabParallelEmbed
 from fengshen_tpu.ops.moe import RoutedExperts
 from fengshen_tpu.ops.norms import LayerNorm, RMSNorm

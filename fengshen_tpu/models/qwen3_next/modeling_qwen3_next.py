@@ -72,9 +72,9 @@ from flax import linen as nn
 
 from fengshen_tpu.models.model_utils import (expert_share, head_rows,
                                              token_mask)
+from fengshen_tpu.models.model_utils import write_rows as _write_rows
 from fengshen_tpu.models.qwen3_next.configuration_qwen3_next import (
     FULL, LINEAR, Qwen3NextConfig)
-from fengshen_tpu.models.sala.modeling_sala import _write_rows
 from fengshen_tpu.ops.embedding import VocabParallelEmbed
 from fengshen_tpu.ops.gated_attention import folded_prefill_walk
 from fengshen_tpu.ops.gated_delta import (a_log_init, gated_delta_decode,

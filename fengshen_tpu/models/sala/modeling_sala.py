@@ -62,8 +62,10 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from fengshen_tpu.models.llama.modeling_llama import LlamaMLP
+from fengshen_tpu.models.model_utils import flat_rows as _flat_rows
 from fengshen_tpu.models.model_utils import head_rows
 from fengshen_tpu.models.model_utils import token_mask as _token_mask
+from fengshen_tpu.models.model_utils import write_rows as _write_rows
 from fengshen_tpu.models.sala.configuration_sala import (LINEAR, SPARSE,
                                                          SalaConfig)
 from fengshen_tpu.ops.embedding import VocabParallelEmbed
@@ -190,52 +192,6 @@ class SalaLinearAttention(_Projections):
         out = RMSNorm(epsilon=cfg.rms_norm_eps, name="o_norm")(
             out.reshape(batch, seq, H * D))
         return self.gated_out(out, hidden), cache
-
-
-def _write_rows(cache, layer: int, **rows):
-    """This step's rows — each `[B, S, ...]` under the name of the stack
-    it goes into (`k=`, `v=`), a token's heads folded into one row —
-    into layer `layer` of those stacks at each lane's cursor, in place:
-    one slice update a stack on a contiguous cache with a scalar cursor,
-    else one scatter into the stack addressed flat (PERF.md, PR 25);
-    paged lanes go through their `block_table` row, free lanes park on
-    the null block."""
-    batch, seq = next(iter(rows.values())).shape[:2]
-    rows = {name: x.reshape(batch, seq, 1, -1) for name, x in rows.items()}
-    if cache.start.ndim == 0:
-        at = (layer, 0, cache.start, 0, 0)
-        return cache._replace(**{
-            name: jax.lax.dynamic_update_slice(
-                getattr(cache, name),
-                x[None].astype(getattr(cache, name).dtype), at)
-            for name, x in rows.items()})
-    pos = _flat_rows(cache, layer,
-                     cache.start[:, None] + jnp.arange(seq)[None]
-                     ).reshape(-1)
-
-    def put(pool, x):
-        flat = pool.reshape((-1,) + pool.shape[3:])
-        return flat.at[pos].set(
-            x.reshape((batch * seq,) + x.shape[2:]).astype(pool.dtype)
-        ).reshape(pool.shape)
-    return cache._replace(**{name: put(getattr(cache, name), x)
-                             for name, x in rows.items()})
-
-
-def _flat_rows(cache: SalaCache, layer: int, p, stride: int = 1):
-    """Where positions `p` `[B, n]` (of tokens; `stride` > 1: of the
-    pooled keys that start at them) of layer `layer` lie in the K/V
-    (pooled) stack viewed as rows."""
-    pool = cache.k
-    if cache.table is not None:
-        num_blocks, block_size = pool.shape[1:3]
-        blk = jnp.take_along_axis(cache.table[layer], p // block_size,
-                                  axis=-1)
-        return ((layer * num_blocks + blk) * block_size +
-                p % block_size) // stride
-    batch, lane_len = pool.shape[1:3]
-    lane = layer * batch + jnp.arange(batch)[:, None]
-    return (lane * lane_len + p) // stride
 
 
 def _append_pooled(cache: SalaCache, layer: int, spec):

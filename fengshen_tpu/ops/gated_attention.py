@@ -9,9 +9,10 @@ under an online softmax, only as far as the keys that exist (a dynamic
 trip count), plain `jax.numpy` (the CPU tier-1 truth; each under its
 own device scope).
 
-- :func:`folded_decode_walk` — one query a lane over the lane's LIVE
-  pool blocks through its block-table row, `chunk_blocks` blocks a
-  step. The xla lowering of the `decode_attention` seam's folded entry
+- :func:`folded_decode_walk` — one query a lane (or a short window of
+  them that share ONE extent: a generation block's, `fold_queries`)
+  over the lane's LIVE pool blocks through its block-table row,
+  `chunk_blocks` blocks a step. The xla lowering of the `decode_attention` seam's folded entry
   (`ops/pallas/decode_attention.folded_decode_attention`), which takes
   a Mosaic kernel where the rows' shape allows and this walk elsewhere.
   The query is laid into the folded row's width with zeros in the
@@ -27,6 +28,10 @@ own device scope).
   window's own keys already written, or the rows just projected),
   `key_block` keys a step: scores are `[H, S, key_block]`, never `[H,
   S, max_len]`.
+- :func:`block_prefill_walk` — the same walk in tiles of queries under
+  a BLOCK-causal mask (a query reads the whole of its own block of
+  positions): the prefill of a model generated by diffusion over
+  blocks (`models/sdar`).
 """
 
 from __future__ import annotations
@@ -46,6 +51,8 @@ _NEG_INF = -1e30
 CHUNK_BLOCKS = 8
 #: keys a step of the prefill walk scores
 KEY_BLOCK = 1024
+#: queries a tile of the block-causal prefill walk scores at once
+Q_TILE = 512
 
 
 def _online(carry, scores, values, contract):
@@ -62,11 +69,37 @@ def _online(carry, scores, values, contract):
     return m_new, l, acc
 
 
+def fold_queries(q, groups: int):
+    """`[B, S, H, D]` -> `[B, 1, S * H, D]` with the `S * H // G` rows
+    that read KV head `g` adjacent (query-major inside a KV head): a
+    window of `S` queries that all read the SAME keys is `S` times the
+    query heads of one. `S = 1` is returned as it is."""
+    batch, s, heads, dim = q.shape
+    if s == 1:
+        return q
+    return q.reshape(batch, s, groups, heads // groups, dim).swapaxes(
+        1, 2).reshape(batch, 1, s * heads, dim)
+
+
+def unfold_queries(out, s: int, groups: int):
+    """The inverse of :func:`fold_queries` on an output `[B, 1, S * H,
+    D]`."""
+    if s == 1:
+        return out
+    batch, _, rows, dim = out.shape
+    return out.reshape(batch, groups, s, rows // (s * groups), dim).swapaxes(
+        1, 2).reshape(batch, s, rows // s, dim)
+
+
 def folded_decode_walk(q, k, v, block_table, t, *, scale: float,
                        chunk_blocks: int = CHUNK_BLOCKS):
-    """q: `[B, 1, H, D]`; k, v: pools `[num_blocks, block_size, 1, G *
-    D]`; block_table: `[B, max_blocks]`; t: `[B]` int32, each query's
-    position (it reads positions `0 .. t`). Returns `[B, 1, H, D]`."""
+    """q: `[B, S, H, D]`; k, v: pools `[num_blocks, block_size, 1, G *
+    D]`; block_table: `[B, max_blocks]`; t: `[B]` int32, the LAST
+    position every query of the lane reads (each reads positions `0 ..
+    t`: one query at its own position, or a block's queries at the
+    block's end). Returns `[B, S, H, D]`."""
+    window = q.shape[1]
+    q = fold_queries(q, k.shape[-1] // q.shape[-1])
     batch, _, heads, dim = q.shape
     block, width = k.shape[1], k.shape[-1]
     groups = width // dim
@@ -101,7 +134,7 @@ def folded_decode_walk(q, k, v, block_table, t, *, scale: float,
         out = (acc / jnp.maximum(l, 1e-30)[..., None]).reshape(
             batch, heads, groups, dim)
         out = (out * own[None, :, :, None]).sum(axis=2)
-        return out[:, None].astype(q.dtype)
+        return unfold_queries(out[:, None].astype(q.dtype), window, groups)
 
 
 def folded_prefill_walk(q, k_rows, v_rows, start, *, scale: float,
@@ -138,3 +171,51 @@ def folded_prefill_walk(q, k_rows, v_rows, start, *, scale: float,
         out = acc / jnp.maximum(l, 1e-30)[..., None]       # [B,G,R,S,D]
         return jnp.moveaxis(out, 3, 1).reshape(
             batch, seq, heads, dim).astype(q.dtype)
+
+
+def block_prefill_walk(q, k_rows, v_rows, start, *, scale: float,
+                       block: int, q_tile: int = Q_TILE,
+                       key_block: int = KEY_BLOCK):
+    """:func:`folded_prefill_walk` under a BLOCK-causal mask, in tiles of
+    queries: query `i`, at position `p = start + i`, reads rows `0 ..
+    (p // block + 1) * block - 1` — every earlier block of `block`
+    positions and the WHOLE of its own, the diagonal moved to the end of
+    each query's block (`block = 1` is causal). A tile of `q_tile`
+    queries walks the keys `key_block` at a time only as far as its own
+    last block: scores are `[H, q_tile, key_block]`. No scope of its
+    own: the caller names the read. Same arguments and result as
+    :func:`folded_prefill_walk`."""
+    batch, seq, heads, dim = q.shape
+    total, width = k_rows.shape[1:]
+    groups = width // dim
+    rep = heads // groups
+    qt = math.gcd(seq, q_tile)
+    kb = math.gcd(total, key_block)
+    qg = (q * scale).reshape(batch, seq // qt, qt, groups, rep, dim)
+
+    def tile(i):
+        at = start + i * qt + jnp.arange(qt)
+        end = (at // block + 1) * block               # first row unread
+        steps = jnp.minimum((end[-1] + kb - 1) // kb, total // kb)
+        q_i = jax.lax.dynamic_index_in_dim(qg, i, axis=1, keepdims=False)
+
+        def step(j, carry):
+            ks = jax.lax.dynamic_slice_in_dim(k_rows, j * kb, kb, axis=1) \
+                .reshape(batch, kb, groups, dim)
+            vs = jax.lax.dynamic_slice_in_dim(v_rows, j * kb, kb, axis=1) \
+                .reshape(batch, kb, groups, dim)
+            s = jnp.einsum("bsgrd,btgd->bgrst", q_i, ks,
+                           preferred_element_type=jnp.float32)
+            ok = (j * kb + jnp.arange(kb))[None, :] < end[:, None]
+            s = jnp.where(ok[None, None, None], s, _NEG_INF)
+            return _online(carry, s, vs, "bgrst,btgd->bgrsd")
+
+        _, l, acc = jax.lax.fori_loop(0, steps, step, (
+            jnp.full((batch, groups, rep, qt), _NEG_INF, jnp.float32),
+            jnp.zeros((batch, groups, rep, qt), jnp.float32),
+            jnp.zeros((batch, groups, rep, qt, dim), jnp.float32)))
+        return acc / jnp.maximum(l, 1e-30)[..., None]      # [B,G,R,qt,D]
+
+    out = jax.lax.map(tile, jnp.arange(seq // qt))         # [N,B,G,R,qt,D]
+    out = jnp.moveaxis(out, (0, 4), (1, 2))                # [B,N,qt,G,R,D]
+    return out.reshape(batch, seq, heads, dim).astype(q.dtype)

@@ -79,7 +79,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from fengshen_tpu.ops.attention import dot_product_attention
 from fengshen_tpu.ops.gated_attention import (CHUNK_BLOCKS, DECODE_SCOPE,
-                                              folded_decode_walk)
+                                              fold_queries,
+                                              folded_decode_walk,
+                                              unfold_queries)
 from fengshen_tpu.ops.int8_matmul import dequantize_kv
 
 _NEG_INF = -1e30
@@ -964,15 +966,18 @@ def folded_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                             interpret: bool = False) -> jax.Array:
     """The seam's entry for K/V rows that fold a token's few, wide KV
     heads into one (`ops/gated_attention.py` has the mathematics): one
-    query a lane over the lane's live blocks, grouped — K/V are neither
+    query a lane, or a window of up to 8 that share ONE extent (a
+    generation block: every query reads keys ``<= t[lane]``), over the
+    lane's live blocks, grouped — K/V are neither
     repeated per query head (the dense seam above would: 8 copies of an
     18,432-token lane) nor is a head sliced out of a gathered row.
 
-    q: ``[B, 1, H, D]``; k/v: the shared ``[num_blocks, block_size, 1,
-    KVH * D]`` pools behind ``block_table`` ``[B, max_blocks]``; with
-    ``layer`` the ``[L, ...]`` stacks, read in place as
-    :func:`_layer_of_stack` reads them. ``t``: ``[B]`` int32, each
-    query's position. Returns ``[B, 1, H, D]``. The rows' shape picks
+    q: ``[B, S, H, D]``, ``1 <= S <= 8``; k/v: the shared ``[num_blocks,
+    block_size, 1, KVH * D]`` pools behind ``block_table`` ``[B,
+    max_blocks]``; with ``layer`` the ``[L, ...]`` stacks, read in
+    place as :func:`_layer_of_stack` reads them. ``t``: ``[B]`` int32,
+    the last position the lane's queries read. Returns ``[B, S, H,
+    D]``. The rows' shape picks
     the path (:func:`_folded_ineligible_reason`): the Mosaic kernel
     :func:`pallas_folded_decode_attention` where it tiles, else
     ``gated_attention.folded_decode_walk``, the xla lowering and the
@@ -997,8 +1002,8 @@ def _folded_ineligible_reason(q, k) -> Optional[str]:
     when they can."""
     _, s, n_heads, head_dim = q.shape
     block_size, one, width = k.shape[-3:]
-    if s != 1:
-        return f"query window {s} != 1"
+    if s > _MAX_QUERY_WINDOW:
+        return f"query window {s} > {_MAX_QUERY_WINDOW}"
     if one != 1 or width % head_dim != 0:
         return f"rows {tuple(k.shape[-2:])} do not fold whole heads of " \
                f"{head_dim} into one"
@@ -1111,7 +1116,9 @@ def _folded_decode_kernel(table_ref, t_ref, q_ref, k_hbm, v_hbm, o_ref,
 def pallas_folded_decode_attention(q, k, v, block_table, t, *, scale,
                                    blocks_per_step: Optional[int] = None,
                                    interpret: bool = False):
-    """The folded read as a Mosaic kernel. q ``[B, 1, H, D]``; k/v flat
+    """The folded read as a Mosaic kernel. q ``[B, S, H, D]`` (a window
+    is folded into ``S`` times the query rows of each KV head against
+    the same fetched block: ``gated_attention.fold_queries``); k/v flat
     pools ``[num_blocks, block_size, 1, G * D]`` (a stack already
     flattened by :func:`_layer_of_stack`), left in HBM; ``block_table``
     ``[B, max_blocks]``; ``t`` ``[B]``, clamped to the table row's
@@ -1121,6 +1128,8 @@ def pallas_folded_decode_attention(q, k, v, block_table, t, *, scale,
     which the fetches hide everything else; PERF.md, PR 33). Named and
     scoped ``gated_attention.DECODE_SCOPE``, so the trace finds the
     read by that text whichever path ran."""
+    window, groups = q.shape[1], k.shape[-1] // q.shape[-1]
+    q = fold_queries(q, groups)
     batch, _, n_heads, head_dim = q.shape
     block_size, _, width = k.shape[-3:]
     max_blocks = block_table.shape[-1]
@@ -1149,7 +1158,7 @@ def pallas_folded_decode_attention(q, k, v, block_table, t, *, scale,
     )
     with jax.named_scope(DECODE_SCOPE):
         # a row's unit axis goes: a free reshape, the blocks stay put
-        return pl.pallas_call(
+        return unfold_queries(pl.pallas_call(
             kernel, grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
             compiler_params=pltpu.CompilerParams(
@@ -1157,4 +1166,5 @@ def pallas_folded_decode_attention(q, k, v, block_table, t, *, scale,
                 vmem_limit_bytes=_VMEM_LIMIT_BYTES),
             interpret=interpret, name=DECODE_SCOPE,
         )(block_table.astype(jnp.int32), t, q,
-          k.reshape(-1, block_size, width), v.reshape(-1, block_size, width))
+          k.reshape(-1, block_size, width), v.reshape(-1, block_size, width)),
+            window, groups)

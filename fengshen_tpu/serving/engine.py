@@ -48,6 +48,15 @@ onto a fixed pool of `num_slots` KV-cache lanes:
   so committing >1 token per weight stream is the per-request latency
   lever the pool alone cannot pull. Works over both layouts and both
   kv dtypes; still exactly one decode program per engine.
+- block tick: a model that declares a generation block
+  (`generation_block()`: diffusion over blocks of `L` positions,
+  `models/sdar`) gets a decode program that forwards each lane's whole
+  block, reveals several of its masked positions a forward and keeps
+  the block's K/V only on the forward after the last reveal; admission
+  prefills the prompt's whole blocks and hands its tail to the lane's
+  first block; a commit delivers a block's tokens together
+  (docs/serving.md "Block generation"). Still exactly one decode
+  program per engine, still one tick ahead.
 
 Greedy decode is TOKEN-IDENTICAL to sequential
 `utils.generate.generate` on the bucket-padded prompt (the parity test
@@ -190,6 +199,14 @@ class EngineConfig:
     spec_gamma: int = 4                      # drafted tokens per tick
     spec_ngram: int = 2                      # suffix length to match
     spec_draft_layers: int = 2               # self-draft tower depth
+    # block generation (docs/serving.md "Block generation"), read only
+    # where the model declares a generation block: the reveal forwards
+    # a block of L positions takes (default L, one position a forward;
+    # must divide L) and which masked positions a forward reveals —
+    # "low_confidence": those whose largest softmax probability is
+    # highest; "sequential": the leftmost
+    denoise_steps: Optional[int] = None
+    remasking: str = "low_confidence"
     # debug introspection (docs/serving.md "Debug endpoints"): how many
     # finished-request timelines the engine retains for
     # `GET /debug/requests` and the flight-recorder bundle
@@ -228,6 +245,11 @@ class EngineConfig:
                 "the continuous engine supports no_repeat_ngram_size of "
                 "0 or 1 only (per-slot cursors cannot drive the n>1 "
                 "window processor)")
+        if self.remasking not in ("low_confidence", "sequential"):
+            raise ValueError(f"unknown remasking {self.remasking!r}; "
+                             "expected 'low_confidence' or 'sequential'")
+        if self.denoise_steps is not None and self.denoise_steps < 1:
+            raise ValueError("denoise_steps must be >= 1")
         if self.spec_mode not in ("off", "prompt_lookup", "self_draft"):
             raise ValueError(
                 f"unknown spec_mode {self.spec_mode!r}; expected 'off', "
@@ -327,6 +349,8 @@ class _Tick:
     kv_blocks: tuple        # (live, tabled) blocks of its lanes' table rows
     t0: float               # perf_counter at enqueue
     ahead: bool             # enqueued with the previous tick unfetched
+    commits: Any = None     # block tick: bool [slots], the lanes whose
+    #                         forward this was kept their block's K/V
     host: tuple = ()        # `out` on the host, once fetched
     seconds: float = 0.0    # the tick's share of the wall, once fetched
 
@@ -419,6 +443,14 @@ class ContinuousBatchingEngine:
         # cursor (masked, later overwritten) but must stay inside the
         # lane (the engine analog of _check_spec_cache_headroom)
         self._gamma = config.spec_gamma if self.spec else 0
+        #: (L, mask token) where the model is generated by diffusion
+        #: over blocks of L positions: the decode program is the block
+        #: tick, `_block_len` its L (0: one token a lane a tick)
+        declared = getattr(model, "generation_block", None)
+        self._block_len, self._mask_id = \
+            (declared() if declared is not None else None) or (0, 0)
+        if self._block_len:
+            self._check_block_config()
         S = config.num_slots
         if self.paged:
             bs = int(config.kv_block_size)
@@ -467,12 +499,24 @@ class ContinuousBatchingEngine:
         #: leaves defined on token positions from 0 (a state, pooled
         #: keys): a cache that declares any is filled by windows only
         self._positional = positional_leaves(self._abstract_init["cache"])
+        #: every prompt is prefilled by windows from position 0: the
+        #: cache declares a positional leaf, or the model a block grid,
+        #: which is a grid of positions whatever the block's length (the
+        #: model reads positions as they lie and takes no mask, so a
+        #: lane is filled from 0 and padded on the right)
+        self._from_zero = bool(self._positional) or declared is not None
         if self._positional and self.spec:
             raise ValueError(
                 f"spec_mode={config.spec_mode!r} cannot serve a cache "
                 f"that declares {self._positional}: a rejected draft "
                 "cannot be rolled back out of a recurrent state or a "
                 "pooled row, nor rewound in a ring; use spec_mode='off'")
+        if declared is not None and self.spec:
+            raise ValueError(
+                f"spec_mode={config.spec_mode!r} cannot serve a model "
+                "that declares a block grid: its lanes are filled from "
+                "position 0 and a drafter reads a history laid out by "
+                "left-padded buckets; use spec_mode='off'")
         #: the leaves a lane holds only the last tokens of
         #: (`paged_cache.ring_leaves`): under the paged layout they page
         #: behind a second table of `ring_blocks` blocks a lane, from a
@@ -563,6 +607,14 @@ class ContinuousBatchingEngine:
         # the assign program writes an admitted lane's first token
         # into it — the host's fetched copy is for the commit only
         self._last_tok = self._zero_tokens()
+        # block tick: each lane's current block on the device, donated
+        # from tick to tick as the history is — its tokens and which of
+        # them are still masked — and, on the host, the forwards the
+        # block still takes, its commit forward included
+        self._block_tokens = jnp.full((S, self._block_len), self._mask_id,
+                                      jnp.int32)
+        self._block_masked = jnp.ones((S, self._block_len), bool)
+        self._fwd_left = np.zeros((S,), np.int32)
         # host-side per-slot state (authoritative for scheduling): the
         # cursors of the next tick to ENQUEUE. A plain tick advances
         # them by one per lane as it is enqueued, a speculative tick by
@@ -683,6 +735,20 @@ class ContinuousBatchingEngine:
                 lambda s: jnp.zeros(s.shape, s.dtype),
                 abstract_init(model, 1)["cache"])
 
+        block_len, mask_id = self._block_len, self._mask_id
+        if block_len:
+            def window_fn(params, cache, ids, start, n_valid):  # noqa: F811
+                """One window of a prompt's WHOLE blocks onto the
+                batch-1 cache (`n_valid` a multiple of the block): it
+                writes their K/V and reads no logits, so the model runs
+                no head. The prompt's tail is the lane's first block."""
+                width = ids.shape[1]
+                _, mutated = model.apply(
+                    {"params": params, "cache": cache}, ids,
+                    position_ids=start + jnp.arange(width)[None],
+                    init_cache=True, mutable=["cache"], head=False)
+                return _rollback_cache(mutated["cache"], width - n_valid)
+
         if self.self_draft:
             # the draft tower primes its OWN cache over the same
             # prompt in the same program — its cursor starts congruent
@@ -726,6 +792,17 @@ class ContinuousBatchingEngine:
                 history = history.at[slot].set(prompt_row)
                 mask = mask.at[slot].set(mask_row)
                 return cache, history, mask, tokens.at[slot].set(tok)
+
+        if block_len:
+            def assign_fn(cache, block_tokens, block_masked,  # noqa: F811
+                          primed, first, flags, *rest):
+                # rest: [table_row,] slot. The lane's first block is the
+                # prompt's tail and masks after it
+                *table_row, slot = rest
+                cache = assign_paged(cache, primed, slot, *table_row) \
+                    if paged else assign_slot(cache, primed, slot)
+                return (cache, block_tokens.at[slot].set(first),
+                        block_masked.at[slot].set(flags))
 
         if self.self_draft:
             # the draft pool is a plain slot pool regardless of the
@@ -903,6 +980,72 @@ class ContinuousBatchingEngine:
                 # next tick's input: it stays on the device
                 return (cache, history, keys, win[jnp.arange(n), n_r],
                         n_r, win)
+        elif block_len:
+            per_step = block_len // self._steps
+            by_confidence = cfg.remasking == "low_confidence"
+
+            def decode_fn(params, cache, block_tokens, block_masked, phys,
+                          active):
+                """Block tick: every lane's whole block of L positions
+                in ONE forward at `phys .. phys + L - 1`, the model
+                writing the block's rows at the lane's cursor and every
+                query reading the lane to the block's end. A lane with
+                masked positions left has `per_step` of them revealed
+                (the argmax of their own logits) and its cursor left
+                where it was: the next forward overwrites the rows. A
+                lane with none left made its COMMIT forward: the rows
+                stay, the cursor moves a block on, the block's tokens
+                are the tick's output and its next block starts all
+                masked. A position is masked by the lane's flag, never
+                by its id. The host knows each lane's phase without a
+                fetch (`_fwd_left`)."""
+                if paged:
+                    cache = reset_free_slots(cache, active)
+                logits, mutated = model.apply(
+                    {"params": params, "cache": cache}, block_tokens,
+                    position_ids=phys[:, None] + jnp.arange(block_len)[None],
+                    masked=block_masked, init_cache=True,
+                    mutable=["cache"] + (["moe_stats"] if moe_shape else []))
+                with jax.named_scope("fstpu_block_reveal"):
+                    z = logits.astype(jnp.float32)
+                    # greedy: the selection every tick shares
+                    best = _select_token(z, None, False, cfg.temperature,
+                                         cfg.top_k, cfg.top_p
+                                         ).astype(jnp.int32)
+                    at = jnp.arange(block_len)
+                    # what a masked position is picked by: its largest
+                    # softmax probability, or how far left it lies
+                    score = jnp.exp(z.max(-1) - jax.nn.logsumexp(z, -1)) \
+                        if by_confidence else jnp.broadcast_to(
+                            -at.astype(jnp.float32), block_masked.shape)
+                    score = jnp.where(block_masked, score, -jnp.inf)
+                    # positions that go before it: a higher score, or
+                    # the same further left
+                    ahead = (score[:, None, :] > score[:, :, None]) | (
+                        (score[:, None, :] == score[:, :, None]) &
+                        (at[None, None, :] < at[None, :, None]))
+                    pick = block_masked & (ahead.sum(-1) < per_step)
+                    commit = active & ~block_masked.any(-1)
+                    out = jnp.where(commit[:, None], block_tokens,
+                                    cfg.pad_token_id)
+                    # a lane that committed, or holds no request, starts
+                    # its next block all masked
+                    fresh = (commit | ~active)[:, None]
+                    block_tokens = jnp.where(
+                        fresh, mask_id, jnp.where(pick, best, block_tokens))
+                    block_masked = fresh | (block_masked & ~pick)
+                # the forward moved every cursor a block on: only a
+                # commit keeps that (rows past a cursor are never read
+                # by another block: `rollback_slots`' invariant)
+                cache = rollback_slots(mutated["cache"],
+                                       jnp.where(commit, 0, block_len))
+                if not paged:
+                    cache = reset_free_slots(cache, active)
+                out = out.reshape(-1).astype(jnp.int32)
+                if moe_shape:
+                    out = jnp.concatenate([out, _live_assignments(
+                        mutated["moe_stats"], active).reshape(-1)])
+                return cache, block_tokens, block_masked, out
         else:
             def decode_fn(params, cache, history, mask, tokens, pos,
                           phys, active, keys):
@@ -981,6 +1124,10 @@ class ContinuousBatchingEngine:
         if self.self_draft:
             assign_donate = (0, 1, 2, 3)
             decode_donate = (2, 3, 4, 10)
+        elif block_len:
+            # the pool and the lanes' blocks (tokens, flags)
+            assign_donate = (0, 1, 2)
+            decode_donate = (1, 2, 3)
         else:
             assign_donate = (0, 1, 2)
             decode_donate = (1, 2, 8)
@@ -991,6 +1138,47 @@ class ContinuousBatchingEngine:
         self._fresh_jit = jax.jit(fresh_fn)
         self._assign_jit = jax.jit(assign_fn, donate_argnums=assign_donate)
         self._decode_jit = jax.jit(decode_fn, donate_argnums=decode_donate)
+
+    def _check_block_config(self) -> None:
+        """What a block engine cannot serve, each with its reason, and
+        the reveal forwards a block takes."""
+        cfg, L = self.config, self._block_len
+        if cfg.spec_mode != "off":
+            raise ValueError(
+                f"spec_mode={cfg.spec_mode!r} cannot serve a model "
+                f"generated by diffusion over blocks of {L}: a block's "
+                "forward already yields several tokens, and a draft "
+                "window is causal where a block is not; use "
+                "spec_mode='off'")
+        if cfg.do_sample:
+            raise ValueError(
+                "do_sample cannot serve a model generated by diffusion "
+                "over blocks: a revealed token is the argmax of its own "
+                "logits (sampled reveal is not built)")
+        if _controls_active(cfg.repetition_penalty,
+                            cfg.no_repeat_ngram_size, cfg.min_length):
+            raise ValueError(
+                "logits controls (repetition_penalty / "
+                "no_repeat_ngram_size / min_length) act at one committed "
+                f"cursor; a block's forward scores {L} positions of which "
+                "any may be revealed next")
+        if cfg.kv_dtype == "int8":
+            raise ValueError(
+                "kv_dtype='int8' cannot serve a model generated by "
+                "diffusion over blocks: a block's rows are rewritten "
+                "every forward and read as they lie; use kv_dtype='fp32'")
+        self._steps = L if cfg.denoise_steps is None else cfg.denoise_steps
+        if L % self._steps:
+            raise ValueError(
+                f"denoise_steps={self._steps} must divide the model's "
+                f"block_length {L}: every reveal forward sets the same "
+                "number of positions")
+        odd = [b for b in self.ladder.buckets if b % L]
+        if odd:
+            raise ValueError(
+                f"buckets {odd} are not whole blocks of {L}: a prompt is "
+                "prefilled in windows of a bucket from position 0, and a "
+                "window holds whole blocks")
 
     def _init_pool(self):
         """Zeros KV pool in the configured (layout, dtype)."""
@@ -1060,13 +1248,32 @@ class ContinuousBatchingEngine:
         tokens is prefilled in from position 0, or None where it takes
         one left-padded bucket. Windows serve a prompt past the largest
         bucket, and every prompt of a cache defined on positions
-        (`_positional`). A speculative engine has none: its drafter
-        reads a history laid out by buckets."""
+        or of a block grid (`_from_zero`). A speculative engine has none:
+        its drafter reads a history laid out by buckets."""
+        if self._block_len and not prefill_len:
+            return []       # a prompt shorter than a block is all tail
         bucket = self.ladder.bucket_for(prefill_len)
-        if self.spec or (bucket is not None and not self._positional):
+        if self.spec or (bucket is not None and not self._from_zero):
             return None
         width = bucket if bucket is not None else self.ladder.max_bucket
         return [(start, width) for start in range(0, prefill_len, width)]
+
+    def _whole_blocks(self, n: int) -> int:
+        """The positions of `n` that are whole generation blocks."""
+        return n // self._block_len * self._block_len
+
+    def _decode_span(self, prompt_len: int, max_new: int,
+                     resumed: int) -> int:
+        """Positions a lane writes past the ones admission prefilled:
+        what the paged footprint is charged for beside the bucket. A
+        resumed request's committed prefix lives inside the bucket; a
+        block engine writes whole blocks, the prompt's tail among
+        them, to the end of the last one."""
+        if self._block_len:
+            return self._whole_blocks(
+                prompt_len + max_new + self._block_len - 1) - \
+                self._whole_blocks(prompt_len)
+        return max_new - resumed + 1 if resumed else max_new
 
     def submit(self, input_ids, max_new_tokens: Optional[int] = None,
                request_id: Optional[str] = None,
@@ -1134,10 +1341,17 @@ class ContinuousBatchingEngine:
                 f"max_new_tokens={requested_new} leaves nothing to "
                 "decode")
         ids = np.asarray(input_ids, np.int32).reshape(-1)
+        if resume and self._block_len:
+            raise ValueError(
+                "resume_tokens cannot be served by a block engine: a "
+                "lane re-enters at a committed cursor of one token, and "
+                "this engine's cursors move a block at a time")
         # a resumed request prefills prompt + resume[:-1] (the last
         # committed token re-enters as the decode seed, exactly where
-        # an undisturbed lane would hold it)
-        prefill_len = len(ids) + max(len(resume) - 1, 0)
+        # an undisturbed lane would hold it); a block engine prefills
+        # the prompt's whole blocks (its tail is the first block's)
+        prefill_len = len(ids) + max(len(resume) - 1, 0) \
+            if not self._block_len else self._whole_blocks(len(ids))
         # what the prompt occupies of the lane: its bucket where one
         # left-padded bucket takes it, the prompt itself where it is
         # filled from position 0 in windows — whose last must end
@@ -1146,8 +1360,8 @@ class ContinuousBatchingEngine:
         if windows is None:
             bucket = self.ladder.bucket_for(prefill_len)
         else:
-            bucket = prefill_len if sum(windows[-1]) <= self.max_len \
-                else None
+            bucket = prefill_len if not windows or \
+                sum(windows[-1]) <= self.max_len else None
         if bucket is None:
             self.metrics.count("rejected_prompt_too_long")
             self._log({"event": "serving_reject", "reason":
@@ -1171,7 +1385,9 @@ class ContinuousBatchingEngine:
         # k-1 committed tokens ride inside the prefill bucket, so they
         # restore that much headroom to the clamp
         max_new = min(max_new, self.seq_capacity - bucket - self._gamma
-                      + max(len(resume) - 1, 0))
+                      + max(len(resume) - 1, 0)) if not self._block_len \
+            else min(max_new,
+                     self._whole_blocks(self.seq_capacity) - len(ids))
         if max_new < (len(resume) + 1 if resume else 1):
             self.metrics.count("rejected_prompt_too_long")
             self._log({"event": "serving_reject", "reason":
@@ -1188,7 +1404,7 @@ class ContinuousBatchingEngine:
         # tokens the lane actually DECODES past the prefill bucket —
         # what the paged footprint is charged for (a resumed request's
         # committed prefix lives inside the bucket)
-        decode_span = max_new - len(resume) + 1 if resume else max_new
+        decode_span = self._decode_span(len(ids), max_new, len(resume))
         if self.paged:
             # a footprint the whole pool cannot hold would sit at the
             # queue head forever (nothing can free enough blocks) —
@@ -1434,7 +1650,7 @@ class ContinuousBatchingEngine:
     def _zero_tokens(self):
         """The device token array before any tick: the shape of the
         decode program's token output."""
-        n = self.config.num_slots
+        n = self.config.num_slots * max(self._block_len, 1)
         if self._moe_shape and not self.spec:
             n += self._moe_shape[0] * self._moe_shape[1]
         return jnp.zeros((n,), jnp.int32)
@@ -1442,6 +1658,9 @@ class ContinuousBatchingEngine:
     def _decode_args(self, active) -> tuple:
         """The decode program's arguments with `active` as its live
         mask (warmup lowers with the same)."""
+        if self._block_len:
+            return (self.params, self._cache, self._block_tokens,
+                    self._block_masked, self._phys, active)
         head = (self.params, self._draft_params, self._cache,
                 self._draft_cache) if self.self_draft else \
             (self.params, self._cache)
@@ -1453,6 +1672,10 @@ class ContinuousBatchingEngine:
         token array are rebound from its outputs; returns the device
         arrays the commit needs on the host."""
         out = self._decode_jit(*self._decode_args(active))
+        if self._block_len:
+            (self._cache, self._block_tokens, self._block_masked,
+             self._last_tok) = out
+            return (self._last_tok,)
         if self.self_draft:
             self._cache, self._draft_cache, *out = out
         else:
@@ -1466,7 +1689,9 @@ class ContinuousBatchingEngine:
         tokens: a plain tick moves each of its lanes one position on."""
         # real cached tokens this tick's attention reads: the logical
         # cursor, not `_phys`, which counts the bucket's padding
-        kv_tokens = int(self._pos[lanes].sum()) + len(lanes)
+        # (a block's queries all read to the block's end)
+        kv_tokens = int(self._pos[lanes].sum()) + \
+            max(self._block_len, 1) * len(lanes)
         if self._attended_tokens is not None:
             # a sparse-attention model: what its queries read of what
             # is cached, from the cursors alone
@@ -1493,7 +1718,7 @@ class ContinuousBatchingEngine:
         kv_blocks = (0, 0)
         if self.paged:
             reach = self._phys + (self.config.spec_gamma if self.spec
-                                  else 0)
+                                  else max(self._block_len - 1, 0))
             kv_blocks = (int((reach // self.block_size + 1).sum()),
                          len(reach) * self.max_blocks_per_slot)
         t0 = time.perf_counter()
@@ -1510,11 +1735,21 @@ class ContinuousBatchingEngine:
                     x.copy_to_host_async()
         self.metrics.record_dispatch(s)
         self._ticks_left = self._ticks_left - run
-        if not self.spec:
+        commits = None
+        if self._block_len:
+            # the lanes whose block had one forward left made their
+            # commit forward: the cursor moves a block on and the next
+            # block takes all its forwards
+            commits = run & (self._fwd_left == 1)
+            self._fwd_left = np.where(commits, self._steps + 1,
+                                      self._fwd_left - run)
+            self._pos = self._pos + self._block_len * commits
+            self._phys = self._phys + self._block_len * commits
+        elif not self.spec:
             self._pos = self._pos + run
             self._phys = self._phys + run
         return _Tick(out, lanes, [self._slot_req[i] for i in lanes],
-                     kv_tokens, kv_blocks, t0, ahead)
+                     kv_tokens, kv_blocks, t0, ahead, commits)
 
     def _fetch_locked(self, tick: _Tick) -> None:
         """Block until `tick`'s outputs are on the host (copies — the
@@ -1546,11 +1781,18 @@ class ContinuousBatchingEngine:
         live = [(i, req) for i, req in zip(tick.lanes, tick.reqs)
                 if self._slot_req[i] is req]
         # a speculative tick's first output is its accept counts
-        tokens = len(live) if not self.spec else \
-            int(tick.host[0][tick.lanes].sum()) + len(tick.lanes)
+        if self._block_len:
+            # what each committing lane is delivered of its block
+            deliver = self._block_deliveries(tick, live)
+            tokens = sum(len(toks) for _, _, toks, _ in deliver)
+        else:
+            tokens = len(live) if not self.spec else \
+                int(tick.host[0][tick.lanes].sum()) + len(tick.lanes)
         with span("serving/commit", lanes=len(tick.lanes),
                   tokens=tokens) as s:
-            if self.spec:
+            if self._block_len:
+                self._commit_blocks(tick, deliver)
+            elif self.spec:
                 self._commit_spec(tick, live)
             else:
                 self._commit_plain(tick, live)
@@ -1606,6 +1848,61 @@ class ContinuousBatchingEngine:
             self.config.spec_gamma * len(tick.lanes),
             accepted_delivered)
 
+    def _block_deliveries(self, tick: _Tick, live) -> list:
+        """`[(lane, request, tokens, finish)]` for the live lanes whose
+        forward in `tick` was a commit forward: the block's tokens less
+        the prompt's tail (a request's first block), cut at
+        `max_new_tokens` and after an EOS."""
+        S, L = self.config.num_slots, self._block_len
+        blocks = tick.host[0][:S * L].reshape(S, L)
+        eos = self.config.eos_token_id
+        out = []
+        for i, req in live:
+            if not tick.commits[i]:
+                continue
+            skip = len(req.prompt) % L if not req.tokens else 0
+            toks = [int(t) for t in blocks[i, skip:]]
+            toks = toks[:req.max_new_tokens - len(req.tokens)]
+            fin = "length" if len(req.tokens) + len(toks) >= \
+                req.max_new_tokens else None
+            if eos is not None and eos in toks:
+                toks = toks[:toks.index(eos) + 1]
+                fin = "eos"
+            out.append((i, req, toks, fin))
+        return out
+
+    def _commit_blocks(self, tick: _Tick, deliver: list) -> None:
+        """A block tick's commit: every live lane made a forward; the
+        lanes in `deliver` also finished a block, whose tokens arrive
+        together — one `commit` event, one stream publish — and are the
+        request's first where it had none (`ttft` is the first block's
+        commit)."""
+        S = self.config.num_slots
+        if self._moe_shape:
+            self.metrics.record_moe(
+                tick.host[0][S * self._block_len:].reshape(self._moe_shape),
+                self._experts_held)
+        self.metrics.record_tick(
+            len(tick.lanes), S,
+            tokens=sum(len(toks) for _, _, toks, _ in deliver),
+            kv_tokens=tick.kv_tokens, kv_blocks=tick.kv_blocks,
+            ahead=tick.ahead)
+        self.metrics.record_block_forwards(
+            len(tick.lanes), int(tick.commits[tick.lanes].sum()))
+        t_commit = self._clock()
+        for i, req, toks, fin in deliver:
+            if req.ttft_s is None:
+                req.ttft_s = t_commit - req.submit_time
+                self.metrics.record_ttft(req.ttft_s)
+                req.timeline.add(t_commit, "first_token")
+            req.tokens.extend(toks)
+            req.timeline.add(t_commit, "commit", n=len(toks),
+                             tick_s=round(tick.seconds, 6))
+            self._sync_stream(req)
+            if fin is not None:
+                # an EOS is learnt a tick late, as in `_commit_plain`
+                self._release(i, FINISHED, fin)
+
     def _commit_plain(self, tick: _Tick, live) -> None:
         nxt, S = tick.host[0], self.config.num_slots
         if self._moe_shape:
@@ -1660,11 +1957,14 @@ class ContinuousBatchingEngine:
             resume = req.resume
             prefill_ids = req.prompt if not resume else np.concatenate(
                 [req.prompt, np.asarray(resume[:-1], np.int32)])
+            if self._block_len:
+                prefill_ids = req.prompt[:self._whole_blocks(
+                    len(req.prompt))]
             windows = self._windows(len(prefill_ids))
             bucket = self.ladder.bucket_for(len(prefill_ids)) \
                 if windows is None else len(prefill_ids)
-            decode_span = req.max_new_tokens - len(resume) + 1 \
-                if resume else req.max_new_tokens
+            decode_span = self._decode_span(
+                len(req.prompt), req.max_new_tokens, len(resume))
             blocks = None
             if self.paged:
                 # admission switches from "free slot" to "enough free
@@ -1712,7 +2012,13 @@ class ContinuousBatchingEngine:
                 with span("serving/prefill", request_id=req.request_id,
                           bucket=int(bucket),
                           prompt_tokens=int(len(prefill_ids))) as s:
-                    if windows is not None:
+                    if self._block_len:
+                        # nothing is fetched: no logits are read, and
+                        # the lane's first tokens come with its first
+                        # block's commit
+                        primed = self._prefill_windows(req, row, windows,
+                                                       key)
+                    elif windows is not None:
                         primed, tok = self._prefill_windows(
                             req, row, windows, key)
                     elif self.self_draft:
@@ -1722,7 +2028,19 @@ class ContinuousBatchingEngine:
                     else:
                         primed, tok = self._prefill_jit(
                             self.params, row[None], mask_row[None], key)
-                    tok = int(np.asarray(tok)[0])
+                    if not self._block_len:
+                        tok = int(np.asarray(tok)[0])
+                if self._block_len:
+                    if windows:
+                        self.metrics.record_prefill_windows(
+                            windows[0][1], len(windows), len(prefill_ids))
+                    if self.paged:      # the lane owns them
+                        self._slot_blocks[slot], self._slot_ring[slot] = \
+                            blocks
+                    with span("serving/assign", request_id=req.request_id,
+                              slot=slot):
+                        self._assign_block(req, slot, primed)
+                    continue
                 # its first-token fetch waits for the device
                 self._declared_wait(s)
                 prefills += 1
@@ -1829,8 +2147,9 @@ class ContinuousBatchingEngine:
         call the same program (one a width), the cache donated from
         call to call. Returns the primed cache and the first token,
         still on the device. No decode tick runs between windows."""
-        prompt_row = np.zeros((1, self.seq_capacity), np.int32)
-        prompt_row[0, :len(ids)] = ids
+        if not self._block_len:
+            prompt_row = np.zeros((1, self.seq_capacity), np.int32)
+            prompt_row[0, :len(ids)] = ids
         primed = self._fresh_jit()
         for w, (start, width) in enumerate(windows):
             n_valid = min(width, len(ids) - start)
@@ -1838,10 +2157,16 @@ class ContinuousBatchingEngine:
             chunk[0, :n_valid] = ids[start:start + n_valid]
             with span("window", request_id=req.request_id, window=w,
                       tokens=int(n_valid)):
+                if self._block_len:
+                    # whole blocks, no head, no token (`window_fn`)
+                    primed = self._window_jit(
+                        self.params, primed, chunk, np.int32(start),
+                        np.int32(n_valid))
+                    continue
                 primed, tok = self._window_jit(
                     self.params, primed, chunk, prompt_row,
                     np.int32(start), np.int32(n_valid), key)
-        return primed, tok
+        return primed if self._block_len else (primed, tok)
 
     def _assign(self, req: Request, slot: int, bucket: int, row,
                 mask_row, primed, d_primed, tok: int, lane_key) -> None:
@@ -1891,6 +2216,38 @@ class ContinuousBatchingEngine:
             # install the lane's ring entry; greedy engines keep
             # the zero ring and never consume it
             self._keys = self._keys.at[slot].set(lane_key)
+
+    def _assign_block(self, req: Request, slot: int, primed) -> None:
+        """`_assign` for a block engine: the primed whole blocks into
+        lane `slot`, the prompt's tail and masks after it as the lane's
+        first block, and what the host knows of the lane's forwards: a
+        block with `m` positions to generate takes `ceil(m / per
+        forward)` reveal forwards and its commit forward."""
+        L, steps = self._block_len, self._steps
+        whole = self._whole_blocks(len(req.prompt))
+        tail = len(req.prompt) - whole
+        first = np.full((L,), self._mask_id, np.int32)
+        first[:tail] = req.prompt[whole:]
+        tables = ()
+        if self.paged:
+            blocks = self._slot_blocks[slot]
+            table_row = np.zeros((self.max_blocks_per_slot,), np.int32)
+            table_row[:len(blocks)] = blocks
+            tables = (table_row,)
+        self._cache, self._block_tokens, self._block_masked = \
+            self._assign_jit(self._cache, self._block_tokens,
+                             self._block_masked, primed, first,
+                             np.arange(L) >= tail, *tables, np.int32(slot))
+        req.state = RUNNING
+        req.slot = slot
+        self._slot_req[slot] = req
+        self._active[slot] = True
+        self._pos[slot] = self._phys[slot] = whole
+        per = L // steps
+        self._fwd_left[slot] = -(-(L - tail) // per) + 1
+        blocks_to_go = -(-(tail + req.max_new_tokens) // L)
+        self._ticks_left[slot] = self._fwd_left[slot] + \
+            (blocks_to_go - 1) * (steps + 1)
 
     def _release(self, slot: int, state: str, reason: str) -> None:
         req = self._slot_req[slot]
@@ -2053,6 +2410,10 @@ class ContinuousBatchingEngine:
         if self.self_draft:
             self._draft_cache = init_slot_cache(self._draft_model, S)
         self._last_tok = self._zero_tokens()
+        self._block_tokens = jnp.full((S, self._block_len), self._mask_id,
+                                      jnp.int32)
+        self._block_masked = jnp.ones((S, self._block_len), bool)
+        self._fwd_left = np.zeros((S,), np.int32)
         self._pos = np.zeros((S,), np.int32)
         self._phys = np.zeros((S,), np.int32)
         self._active = np.zeros((S,), bool)
@@ -2070,6 +2431,12 @@ class ContinuousBatchingEngine:
             self._thread = None
         with self._cv:
             self._drain_locked()  # fslint: disable=blocking-under-lock; the serve thread is gone, one last fetch under its lock
+
+    @property
+    def block_length(self) -> int:
+        """Positions of the generation block a tick forwards a lane; 0
+        where a tick yields one token a lane."""
+        return self._block_len
 
     # ---- drain (docs/fleet.md "Drain runbook") ----------------------
 
@@ -2176,16 +2543,20 @@ class ContinuousBatchingEngine:
                     continue
                 ids = np.ones((1, bucket), np.int32)
                 mask = np.ones((1, bucket), np.int32)
-                if self._positional:
+                if self._from_zero:
                     # every prompt of this cache goes by windows: warm
                     # the window program of each width, not the
                     # left-padded one. (Elsewhere a window program
                     # compiles at the first prompt past the ladder.)
                     fresh = self._fresh_jit()  # fslint: disable=blocking-under-lock; warmup must exclude ticks
+                    rest = (np.int32(0), np.int32(bucket))
+                    if not self._block_len:
+                        # the plain window program also takes the
+                        # prompt's row (logits controls) and a key
+                        rest = (np.zeros((1, self.seq_capacity), np.int32),
+                                *rest, self._zero_key)
                     jax.block_until_ready(self._window_jit(  # fslint: disable=blocking-under-lock; warmup must exclude ticks
-                        self.params, fresh, ids,
-                        np.zeros((1, self.seq_capacity), np.int32),
-                        np.int32(0), np.int32(bucket), self._zero_key))
+                        self.params, fresh, ids, *rest))
                     continue
                 # warmup compiles under _cv on purpose: no request
                 # may tick mid-warmup or it would pay (and double-

@@ -144,6 +144,12 @@ def _undeclared_on_wire(engine) -> Optional[str]:
     format yet, nor has a per-lane state (a `state_*` leaf: a snapshot
     of it is what a wire and a preemption would both need) or a row
     leaf of another rate (ROADMAP D4, M6)."""
+    if engine.block_length:
+        return ("the handoff wire carries a lane at a committed cursor of "
+                "one token a tick; this engine's lanes are inside a "
+                f"generation block ({engine.block_length} tokens and their "
+                "masked flags on the device, a cursor that moves a block "
+                "at a time), which has no wire format yet")
     found: List[list] = []
     _map_attn_dicts(engine._cache, lambda d: found.append(
         row_leaves(d) + state_leaves(d)) or d)

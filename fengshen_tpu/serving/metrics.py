@@ -77,7 +77,19 @@ class EngineMetrics:
             "of ticks whose host part ran under the device's)")
         self._decode_tokens = r.counter(
             "fstpu_serving_decode_tokens_total",
-            "tokens generated by decode ticks")
+            "tokens DELIVERED by decode ticks, credited at commit (one a "
+            "live lane of a plain tick, the first token being the "
+            "prefill's; a block engine's ticks deliver every output "
+            "token, the first included, a block at a time)")
+        # a block engine only (engine._commit_blocks)
+        self._block_forwards = r.counter(
+            "fstpu_serving_block_forwards_total",
+            "block forwards: live lanes summed over block ticks (over "
+            "decode_tokens_total: tokens a forward)")
+        self._block_commit_forwards = r.counter(
+            "fstpu_serving_block_commit_forwards_total",
+            "of the block forwards, the commit forwards: a finished "
+            "block's K/V written, no position revealed")
         self._prefill_tokens = r.counter(
             "fstpu_serving_prefill_tokens_total",
             "real prompt tokens prefilled (a resumed request's "
@@ -340,6 +352,10 @@ class EngineMetrics:
         if n_active > self._peak_active:
             self._peak_active = n_active
             self._g_peak.set(n_active)
+
+    def record_block_forwards(self, forwards: int, commits: int) -> None:
+        self._block_forwards.inc(forwards)
+        self._block_commit_forwards.inc(commits)
 
     def record_moe(self, histogram, held=None) -> None:
         """`histogram`: `[expert layers, experts]` assignments of one

@@ -10,6 +10,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -1146,3 +1147,53 @@ def test_kimi_window_program_walks_the_latent_lane_in_blocks(
                "q=(1, 2048, 32, 128)" in d["detail"] for d in sites)
     assert any(d["op"] == "grouped_matmul" and d["impl"] == "pallas" and
                "rows=(16384, 2304)" in d["detail"] for d in sites)
+
+
+def test_sdar_block_tick_reads_a_blocks_queries_through_the_folded_kernel(
+        one_chip, no_compile_cache, monkeypatch):
+    """The engine's block tick at the benchmark's widths and lanes
+    (`sdar-30b-a3b-chat`, two layers of it, a pool of 4 blocks a lane):
+    the block's four queries a lane are ONE read of the folded Mosaic
+    kernel a layer (`q=(64, 4, 32, 128)`: 32 query rows a KV head against
+    the same fetched block), the 2,048 assignment rows go through the
+    Mosaic grouped matmul, the pool is aliased to the returned one, and
+    no `[lanes, kv heads x 8, ...]` repeat of a lane exists (the dense
+    seam's lowering for 4 KV heads)."""
+    import json
+    import os
+
+    import fengshen_tpu.ops.pallas as kernels
+    from benchmarks.lib import manifest
+    from fengshen_tpu.ops.gated_attention import DECODE_SCOPE
+    from fengshen_tpu.serving import ContinuousBatchingEngine, EngineConfig
+    monkeypatch.setitem(kernels._PROBE_CACHE, ("cpu", None),
+                        kernels.KernelProbe("tpu", True, None,
+                                            "described v5e"))
+    with open(os.path.join(manifest.ROOT, "benchmarks", "configs",
+                           "sdar-30b-a3b-chat.json")) as f:
+        config = json.load(f)
+    config["num_hidden_layers"] = 2
+    model, _ = manifest.family(config).build(config)
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    eng = ContinuousBatchingEngine(model, params, EngineConfig(
+        num_slots=64, buckets=(256,), max_new_tokens=256,
+        kv_layout="paged", kv_block_size=128, kv_num_blocks=64 * 4 + 1,
+        kv_max_blocks_per_slot=4, denoise_steps=2, remasking="sequential"))
+    assert eng.block_length == 4
+    monkeypatch.setattr(kernels, "_TRACED", {})
+    tick = eng._decode_jit.lower(*_abstract(
+        tuple(jnp.asarray(a) if isinstance(a, np.ndarray) else a
+              for a in eng._decode_args(eng._active)), one_chip)).compile()
+    took = {d["op"]: d for d in kernels.traced_dispatch()}
+    assert took["folded_decode_attention"]["impl"] == "pallas"
+    assert "q=(64, 4, 32, 128)" in took["folded_decode_attention"]["detail"]
+    assert took["grouped_matmul"]["impl"] == "pallas"
+    assert "rows=(2048, 2048)" in took["grouped_matmul"]["detail"]
+    text = tick.as_text()
+    reads = re.findall(r"%?(" + DECODE_SCOPE + r"[\w.\-]*) = [^\n]*"
+                       r"custom_call_target=\"tpu_custom_call\"", text)
+    assert len(reads) == 2, reads
+    assert "ragged-dot" not in text
+    pool = eng._cache["model"]["cached_key"]
+    assert tick.memory_analysis().alias_size_in_bytes >= 2 * pool.nbytes

@@ -116,6 +116,40 @@ def test_folded_decode_kernel_interpret_parity(per, groups, stacked, dtype):
                                want[held], rtol=tol, atol=tol)
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("groups", [2, 4])
+@pytest.mark.parametrize("window", [1, 4, 8])
+def test_folded_window_shares_one_extent(window, groups, dtype):
+    """`S` queries a lane that all read keys `<= t[lane]` (a generation
+    block's: `t` is the block's last position): the kernel in interpret
+    mode and the walk against the DENSE lowering, plain grouped softmax
+    attention of every query over the lane's rows in order; 8 query
+    heads over 2 and over 4 KV heads (SDAR's row: four heads of 128),
+    the pools as a stack read through `layer`."""
+    rng = np.random.RandomState(350 + window + groups)
+    q1, k, v, table, t, layer, rows_k, rows_v = _folded_case(
+        rng, groups, True, jnp.dtype(dtype), n_heads=8)
+    q = jnp.asarray(rng.randn(q1.shape[0], window, 8, 128), q1.dtype)
+    assert _folded_ineligible_reason(q, k) is None
+    scale = q.shape[-1] ** -0.5
+    got = folded_decode_attention(q, k, v, table, t, scale=scale,
+                                  layer=layer, impl="pallas", interpret=True)
+    walk = folded_decode_attention(q, k, v, table, t, scale=scale,
+                                   layer=layer, impl="xla")
+    assert got.shape == walk.shape == q.shape and got.dtype == q.dtype
+    if dtype == "bfloat16":
+        rows_k, rows_v = (np.asarray(jnp.asarray(x, jnp.bfloat16),
+                                     np.float32) for x in (rows_k, rows_v))
+    want = np.concatenate([
+        _grouped_softmax(q[:, i:i + 1], rows_k, rows_v, np.asarray(t),
+                         groups) for i in range(window)], axis=1)
+    tol = 2e-5 if dtype == "float32" else 2e-2
+    held = np.array([at is not None for at in _FOLDED_LANES])
+    for out in (got, walk):
+        np.testing.assert_allclose(np.asarray(out, np.float32)[held],
+                                   want[held], rtol=tol, atol=tol)
+
+
 @pytest.mark.parametrize("groups", [1, 2])
 @pytest.mark.parametrize("per", [None, 1, 3], ids=["row", "1", "3"])
 def test_folded_decode_kernel_never_reads_past_a_lanes_cursor(per, groups):
@@ -146,14 +180,16 @@ def test_folded_decode_kernel_never_reads_past_a_lanes_cursor(per, groups):
 @pytest.mark.parametrize("q_shape, kv_shape, why", [
     ((2, 1, 4, 16), (9, 8, 1, 32), "head_dim 16 % 128"),
     ((2, 1, 4, 128), (9, 8, 1, 256), "block_size 8 % 128"),
-    ((2, 2, 4, 128), (9, 128, 1, 256), "query window 2"),
+    ((2, 9, 4, 128), (9, 128, 1, 256), "query window 9 > 8"),
+    ((2, 4, 4, 128), (9, 128, 1, 256), None),
     ((2, 1, 4, 128), (9, 128, 2, 128), "do not fold"),
     ((2, 1, 4, 128), (9, 128, 1, 320), "do not fold"),
     ((2, 1, 4, 128), (9, 128, 1, 384), "3 kv heads do not divide 4"),
     ((2, 1, 16, 256), (3, 9, 128, 1, 512), None),
     ((2, 1, 4, 128), (9, 256, 1, 128), None),
-], ids=["narrow_head", "tiny_block", "window", "unfolded_heads",
-        "ragged_width", "heads_not_grouped", "cell_stack", "one_kv_head"])
+], ids=["narrow_head", "tiny_block", "long_window", "block_window",
+        "unfolded_heads", "ragged_width", "heads_not_grouped", "cell_stack",
+        "one_kv_head"])
 def test_folded_dispatch_follows_the_rows_shape(fresh_probe, monkeypatch,
                                                 q_shape, kv_shape, why):
     """The folded entry chooses its path from the rows' shape through
@@ -177,7 +213,7 @@ def test_folded_dispatch_follows_the_rows_shape(fresh_probe, monkeypatch,
             q, k, k, table, t, scale=1.0, layer=layer),
         q, k, jax.ShapeDtypeStruct((2, 4), jnp.int32),
         jax.ShapeDtypeStruct((2,), jnp.int32))
-    assert out.shape == (2, 1) + q_shape[2:]    # one query a lane
+    assert out.shape == q_shape
     took, = kernels.traced_dispatch()
     assert took["op"] == "folded_decode_attention"
     assert took["impl"] == ("pallas" if why is None else "xla")

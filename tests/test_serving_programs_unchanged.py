@@ -55,7 +55,18 @@ through the seam (the mask gained a batch axis for `key_valid`, nothing
 else). `tests/test_joyai.py` and `tests/test_kimi_linear.py` pin the
 logits against the old chain and the old call. Both models' `*.decode`
 and `*.assign` hashes are the ones PR 45 left: the absorbed tick and
-`write_latent` did not change."""
+`write_latent` did not change.
+
+PR 48 replaced none: the engine took a FOURTH decode program, the block
+tick, for a model that declares a generation block
+(`generation_block()`: `models/sdar`), with its own window and assign
+programs (no head, no first token; a lane's first block) — and a model
+that declares none is handed the programs it was handed. The folded
+entry of the decode seam took `S <= 8` queries a lane that share one
+extent by folding them into the query heads before the `S = 1` body
+(Qwen3-Next's three stand); `write_rows` moved from `models/sala` to
+`models/model_utils.py` under the same body (sala, qwen3_next, keye). It
+added SDAR's three programs, taken on its own tree."""
 
 import hashlib
 
@@ -90,6 +101,9 @@ SHA = {
     "kimi_linear.decode": "9d90cc775e211cad",
     "kimi_linear.window": "f774c9a50075f2f8",
     "kimi_linear.assign": "6b9219d02e589a96",
+    "sdar.decode": "600c739fd19fe820",
+    "sdar.window": "53e244f9d49b9f57",
+    "sdar.assign": "e7b099ef2620a96f",
 }
 
 
@@ -115,6 +129,9 @@ def _model(family):
         from fengshen_tpu.models.kimi_linear import (KimiLinearConfig,
                                                      KimiLinearForCausalLM)
         return KimiLinearForCausalLM(KimiLinearConfig.small_test_config())
+    if family == "sdar":
+        from fengshen_tpu.models.sdar import SdarConfig, SdarForCausalLM
+        return SdarForCausalLM(SdarConfig.small_test_config())
     if family == "trinity":
         from fengshen_tpu.models.trinity import (TrinityConfig,
                                                  TrinityForCausalLM)
@@ -141,24 +158,38 @@ def lowered():
         sds = lambda t: jax.tree_util.tree_map(  # noqa: E731
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), t)
         i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
-        window_args = (params, jax.eval_shape(eng._fresh_jit), i32(1, 16),
-                       i32(1, eng.seq_capacity), i32(), i32(),
-                       jax.ShapeDtypeStruct((2,), jnp.uint32))
-        primed, _ = jax.eval_shape(eng._window_jit, *window_args)
-        assign_args = sds((eng._cache, eng._history, eng._mask,
-                           eng._last_tok, primed)) + (
-            i32(eng.seq_capacity), i32(eng.seq_capacity),
-            i32(eng.max_blocks_per_slot)) + (
-            (i32(eng.ring_blocks),) if eng.ring_blocks else ()) + (
-            i32(), i32())
+        if family == "sdar":
+            # the block tick's programs: whole blocks, no head, no
+            # first token; a lane's first block and its flags
+            window_args = (params, jax.eval_shape(eng._fresh_jit),
+                           i32(1, 16), i32(), i32())
+            primed = jax.eval_shape(eng._window_jit, *window_args)
+            block = eng.block_length
+            assign_args = sds((eng._cache, eng._block_tokens,
+                               eng._block_masked, primed)) + (
+                i32(block), jax.ShapeDtypeStruct((block,), jnp.bool_),
+                i32(eng.max_blocks_per_slot), i32())
+        else:
+            window_args = (
+                params, jax.eval_shape(eng._fresh_jit), i32(1, 16),
+                i32(1, eng.seq_capacity), i32(), i32(),
+                jax.ShapeDtypeStruct((2,), jnp.uint32))
+            primed, _ = jax.eval_shape(eng._window_jit, *window_args)
+            assign_args = sds((eng._cache, eng._history, eng._mask,
+                               eng._last_tok, primed)) + (
+                i32(eng.seq_capacity), i32(eng.seq_capacity),
+                i32(eng.max_blocks_per_slot)) + (
+                (i32(eng.ring_blocks),) if eng.ring_blocks else ()) + (
+                i32(), i32())
         made[family] = {
             "decode": eng._decode_jit.lower(
                 *sds(eng._decode_args(eng._active))).as_text(),
             "window": eng._window_jit.lower(*window_args).as_text(),
             "assign": eng._assign_jit.lower(*assign_args).as_text()}
-        # a cache with positional leaves takes every prompt by windows
-        assert bool(eng._positional) == (f"{family}.prefill" not in SHA)
-        if not eng._positional:
+        # a cache with positional leaves, and a block grid, take every
+        # prompt by windows
+        assert eng._from_zero == (f"{family}.prefill" not in SHA)
+        if not eng._from_zero:
             made[family]["prefill"] = eng._prefill_jit.lower(
                 params, i32(1, 16), i32(1, 16),
                 jax.ShapeDtypeStruct((2,), jnp.uint32)).as_text()
