@@ -387,11 +387,13 @@ def _mla_prefill_cases(cfg, rows):
 
 
 def _gated_delta_cases(cfg, rows):
-    """The chunked gated delta rule at Qwen3-Next's published head
-    geometry (value heads of 128 two to a key head, l2-normalised
-    float32 q and k, bfloat16 v), a window padded on the right, onto a
-    state that is not zero: the chunk kernel against the `jax.numpy`
-    form. The float32 state is held to 1e-4, not to a bf16 tolerance."""
+    """The chunked gated delta rule, a case a caller: at Qwen3-Next's
+    published head geometry (value heads of 128 two to a key head, one
+    scalar gate a token a head) and at Kimi-Linear's (one value head a
+    key head, a gate per key channel); l2-normalised float32 q and k,
+    bfloat16 v, a window padded on the right, onto a state that is not
+    zero: the chunk kernel's two bodies against the `jax.numpy` form.
+    The float32 state is held to 1e-4, not to a bf16 tolerance."""
     del cfg
     import jax
     import jax.numpy as jnp
@@ -401,24 +403,26 @@ def _gated_delta_cases(cfg, rows):
     from fengshen_tpu.ops.pallas.gated_delta import _ineligible_reason
     pallas = get_kernel("gated_delta_prefill", "pallas")
     xla = get_kernel("gated_delta_prefill", "xla")
-    seq, key_heads, heads, dim, real = 1024, 4, 8, 128, 900
-    ks = jax.random.split(jax.random.PRNGKey(SEED + 5), 6)
-    q = l2norm(jax.random.normal(ks[0], (1, seq, key_heads, dim))) * \
-        dim ** -0.5
-    k = l2norm(jax.random.normal(ks[1], (1, seq, key_heads, dim)))
-    v = jax.random.normal(ks[2], (1, seq, heads, dim), jnp.bfloat16)
-    g = -jax.nn.softplus(jax.random.normal(ks[3], (1, seq, heads)))
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (1, seq, heads)))
-    state = jax.random.normal(ks[5], (1, heads, dim, dim))
-    mask = jnp.arange(seq)[None] < real
-    assert _ineligible_reason(q, v) is None
-    got, got_state = jax.jit(pallas)(q, k, v, g, beta, state, mask)
-    want, want_state = jax.jit(xla)(q, k, v, g, beta, state, mask)
-    case = f"q={list(q.shape)} v={list(v.shape)} bf16, {real} real"
-    _check(rows, "gated_delta_prefill", case + ", out", got[:, :real],
-           want[:, :real])
-    _check(rows, "gated_delta_prefill", case + ", state", got_state,
-           want_state, tol=1e-4)
+    seq, heads, dim, real = 1024, 8, 128, 900
+    for key_heads, per_channel in ((4, False), (8, True)):
+        ks = jax.random.split(jax.random.PRNGKey(SEED + 5), 6)
+        q = l2norm(jax.random.normal(ks[0], (1, seq, key_heads, dim))) * \
+            dim ** -0.5
+        k = l2norm(jax.random.normal(ks[1], (1, seq, key_heads, dim)))
+        v = jax.random.normal(ks[2], (1, seq, heads, dim), jnp.bfloat16)
+        g = -jax.nn.softplus(jax.random.normal(
+            ks[3], (1, seq, heads) + (dim,) * per_channel))
+        beta = jax.nn.sigmoid(jax.random.normal(ks[4], (1, seq, heads)))
+        state = jax.random.normal(ks[5], (1, heads, dim, dim))
+        mask = jnp.arange(seq)[None] < real
+        assert _ineligible_reason(q, v, g) is None
+        got, got_state = jax.jit(pallas)(q, k, v, g, beta, state, mask)
+        want, want_state = jax.jit(xla)(q, k, v, g, beta, state, mask)
+        case = f"q={list(q.shape)} g={list(g.shape)} bf16, {real} real"
+        _check(rows, "gated_delta_prefill", case + ", out", got[:, :real],
+               want[:, :real])
+        _check(rows, "gated_delta_prefill", case + ", state", got_state,
+               want_state, tol=1e-4)
 
 
 def _grouped_matmul_cases(cfg, rows):

@@ -59,8 +59,11 @@ tier-1 truth; each under its own device scope so a trace finds it):
   sub-block takes the explicit `[16, 16, Dk]` differences (`i >= j`:
   <= 0 again). No exponent is ever positive; a factor that underflows
   bounds a product that is itself under 1e-38. The Mosaic chunk kernel
-  takes `g` as one scalar a token and cannot take this gate: the seam
-  says so on the dispatch record and runs the `jax.numpy` form.
+  has a second body for this gate, taken by the gate's rank where the
+  window tiles (one value head a key head): the same anchoring along
+  its merge tree, the diagonal sub-blocks along their diagonals, `T` by
+  the same block products, no triangular solve and no scan left in the
+  window program.
 
 A masked token (padding) has `beta = 0`, `g = 0`, `k = v = 0`: it
 neither writes nor decays, so padding on either side leaves the state
@@ -147,7 +150,8 @@ def gated_delta_prefill(q, k, v, g, beta, state,
     after the window's valid tokens). A padded query's output is
     unspecified. The window's shape picks the path
     (`ops.pallas.gated_delta._ineligible_reason`): the Mosaic chunk
-    kernel where it tiles, else :func:`xla_gated_delta_prefill` in
+    kernel where it tiles (the scalar gate's body or the per-channel
+    gate's, by `g`'s rank), else :func:`xla_gated_delta_prefill` in
     chunks of `chunk` (the kernel's chunk is its module's constant; the
     result depends on neither)."""
     # here, not at the top: the registry imports this module's xla form

@@ -1095,9 +1095,9 @@ def test_kimi_tick_reads_the_latent_pool_through_the_kernel_in_place(
     assert mem.temp_size_in_bytes < 0.1e9
     took = {(d["op"], d["impl"]) for d in kernels.traced_dispatch()}
     assert ("mla_decode_attention", "pallas") in took
-    reasons = [d["detail"] for d in kernels.traced_dispatch()
-               if d["op"] == "gated_delta_prefill"]
-    assert all("gate per channel" in r for r in reasons)
+    # a tick takes the delta rule's one-token step: the window's seam
+    # is not asked
+    assert "gated_delta_prefill" not in {op for op, _ in took}
 
 
 def test_kimi_window_program_walks_the_latent_lane_in_blocks(
@@ -1108,10 +1108,12 @@ def test_kimi_window_program_walks_the_latent_lane_in_blocks(
     no copy of the lane, the donated cache (rows and both states)
     aliased to the returned one; the full form is ONE Mosaic call (the
     seam's kernel, by the window's shape: the lane an operand as it
-    lies in the cache), the per-channel delta rule stays in
-    `jax.numpy` with the reason on record, the experts' products are
-    the Mosaic grouped matmul (2 slots of 2,304 x 1,024 under its
-    VMEM budget)."""
+    lies in the cache), the four KDA layers' per-channel delta rule is
+    four Mosaic calls of one lowering (the seam's kernel, by the gate's
+    rank: no `solve_triangular` custom call and none of the `jax.numpy`
+    form's `[1, 32, 32, 1, 64, 64]` systems is left in the program),
+    the experts' products are the Mosaic grouped matmul (2 slots of
+    2,304 x 1,024 under its VMEM budget)."""
     from fengshen_tpu.ops import pallas as kernels
     monkeypatch.setitem(kernels._PROBE_CACHE, ("cpu", None),
                         kernels.KernelProbe("tpu", True, None, "aot"))
@@ -1142,9 +1144,19 @@ def test_kimi_window_program_walks_the_latent_lane_in_blocks(
     assert {"op": "mla_prefill_attention", "impl": "pallas",
             "detail": "q=(1, 2048, 32, 128)+64:bfloat16 "
                       "rows=(1, 36864, 640):bfloat16"} in sites
-    assert any(d["op"] == "gated_delta_prefill" and d["impl"] == "xla" and
-               "gate per channel" in d["detail"] and
-               "q=(1, 2048, 32, 128)" in d["detail"] for d in sites)
+    assert len(re.findall(
+        r"%?fstpu_gated_delta_prefill[\w.\-]* = [^\n]*"
+        r"custom_call_target=\"tpu_custom_call\"", text)) == 4
+    assert "solve_triangular" not in text.lower()
+    assert "TriangularSolve" not in text and "triangular-solve" not in text
+    assert "f32[1,32,32,1,64,64]" not in text
+    assert {"op": "gated_delta_prefill", "impl": "pallas",
+            "detail": "q=(1, 2048, 32, 128):float32 v=(1, 2048, 32, 128):"
+                      "bfloat16 g=(1, 2048, 32, 128)"} in sites
+    # what else the record holds of this seam is the one-token pass
+    # that shapes the cache
+    assert all("shorter than a chunk" in d["detail"] for d in sites
+               if d["op"] == "gated_delta_prefill" and d["impl"] == "xla")
     assert any(d["op"] == "grouped_matmul" and d["impl"] == "pallas" and
                "rows=(16384, 2304)" in d["detail"] for d in sites)
 

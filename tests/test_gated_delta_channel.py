@@ -2,7 +2,8 @@
 Delta Attention): the one-token step and the chunked form against a
 token-by-token loop, the anchored exponents at decays that would
 overflow a naive `exp(-G)`, the scalar gate as the special case it is,
-and what the seam puts on the dispatch record."""
+and what the seam puts on the dispatch record (the Mosaic body for this
+gate has tests/test_pallas_gated_delta_channel.py)."""
 
 import jax
 import jax.numpy as jnp
@@ -194,33 +195,65 @@ def test_a_gate_averaged_over_a_heads_channels_is_another_rule():
     assert np.abs(np.asarray(got) - np.asarray(want)).max() > 500 * ATOL
 
 
-def test_the_seam_records_why_the_kernel_cannot_take_this_gate(
+def _channel_window(seq, heads, dk):
+    sds = jax.ShapeDtypeStruct
+    q = sds((1, seq, heads, dk), jnp.float32)
+    return (q, q, sds((1, seq, heads, 128), jnp.bfloat16), q,
+            sds((1, seq, heads), jnp.float32),
+            sds((1, heads, dk, 128), jnp.float32))
+
+
+def test_the_seam_takes_the_kernel_at_the_cells_shape_and_says_why_elsewhere(
         fresh_probe, monkeypatch):
-    """On a backend that runs Mosaic a window of the cell's shape with a
-    scalar gate takes the chunk kernel; with a per-channel gate the
-    seam runs the `jax.numpy` form and says why on the dispatch
-    record."""
+    """On a backend that runs Mosaic a window of the cell's shape takes
+    the chunk kernel under either gate, the per-channel one with the
+    gate's shape on the dispatch record; a shape that does not tile (a
+    key head of 64, a window shorter than a chunk) runs the `jax.numpy`
+    form and says why."""
     import fengshen_tpu.ops.pallas as kernels
     from fengshen_tpu.ops.pallas.gated_delta import _ineligible_reason
     monkeypatch.setitem(kernels._PROBE_CACHE, ("cpu", None),
                         kernels.KernelProbe("tpu", True, None, "test"))
     monkeypatch.setattr(kernels, "_TRACED", {})
-    sds = jax.ShapeDtypeStruct
-    q = sds((1, 2048, 32, 128), jnp.float32)
-    v = sds((1, 2048, 32, 128), jnp.bfloat16)
-    beta = sds((1, 2048, 32), jnp.float32)
-    g = sds((1, 2048, 32, 128), jnp.float32)
-    state = sds((1, 32, 128, 128), jnp.float32)
+    q, _, v, g, beta, state = cell = _channel_window(2048, 32, 128)
     assert _ineligible_reason(q, v, beta) is None
-    assert "gate per channel" in _ineligible_reason(q, v, g)
-    out, new = jax.eval_shape(gated_delta_prefill, q, q, v, g, beta, state)
-    assert out.shape == v.shape and new.shape == state.shape
+    assert _ineligible_reason(q, v, g) is None
+    out, new = jax.eval_shape(gated_delta_prefill, *cell)
+    assert out.shape == v.shape and out.dtype == v.dtype
+    assert new.shape == state.shape
     took, = kernels.traced_dispatch()
-    assert took["op"] == "gated_delta_prefill" and took["impl"] == "xla"
-    assert "gate per channel" in took["detail"]
+    assert took == {"op": "gated_delta_prefill", "impl": "pallas",
+                    "detail": "q=(1, 2048, 32, 128):float32 v=(1, 2048, 32, "
+                              "128):bfloat16 g=(1, 2048, 32, 128)"}
+    for case, why in [(_channel_window(2048, 32, 64), "Dk 64 % 128"),
+                      (_channel_window(100, 32, 128),
+                       "window 100 shorter than a chunk")]:
+        assert why in _ineligible_reason(case[0], case[2], case[3])
+        jax.eval_shape(gated_delta_prefill, *case)
+        last = kernels.traced_dispatch()[-1]
+        assert last["impl"] == "xla" and why in last["detail"]
+        assert f"g={case[3].shape}" in last["detail"]
+    assert not any("gate per channel" in t["detail"]
+                   for t in kernels.traced_dispatch())
+    # several value heads a key head under this gate: no model has them
+    narrow = jax.ShapeDtypeStruct((1, 2048, 16, 128), jnp.float32)
+    assert "value heads a key head" in _ineligible_reason(narrow, v, g)
+
+
+def test_the_seam_stays_on_xla_under_a_mesh_with_this_gate(
+        mesh8, fresh_probe, monkeypatch):
+    """GSPMD cannot partition a Mosaic call: under a multi-device mesh
+    the cell's shape runs the `jax.numpy` form and says why."""
+    import fengshen_tpu.ops.pallas as kernels
+    monkeypatch.setitem(kernels._PROBE_CACHE, ("cpu", None),
+                        kernels.KernelProbe("tpu", True, None, "test"))
+    monkeypatch.setattr(kernels, "_TRACED", {})
+    # a function of its own: the test above has traced this shape
+    jax.eval_shape(lambda *window: gated_delta_prefill(*window),
+                   *_channel_window(2048, 32, 128))
+    took, = kernels.traced_dispatch()
+    assert took["impl"] == "xla" and "8-device mesh" in took["detail"]
     assert "g=(1, 2048, 32, 128)" in took["detail"]
-    jax.eval_shape(gated_delta_prefill, q, q, v, beta, beta, state)
-    assert [t["impl"] for t in kernels.traced_dispatch()] == ["xla", "pallas"]
 
 
 def test_both_gates_run_under_the_scopes_a_trace_reads():
