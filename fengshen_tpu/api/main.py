@@ -328,7 +328,8 @@ def start_continuous_engine(pipeline, engine_args: dict, log=None,
 
 
 def _engine_generate(engine, pipeline, req: dict, timeout_s: float,
-                     disagg=None) -> tuple[int, dict]:
+                     disagg=None,
+                     cpu_start: Optional[float] = None) -> tuple[int, dict]:
     """Submit one HTTP request to the engine; returns (status, body).
     Backpressure maps to HTTP: queue full → 429, prompt too long → 413,
     engine timeout/eviction → 503, draining replica → 503 with reason,
@@ -344,12 +345,19 @@ def _engine_generate(engine, pipeline, req: dict, timeout_s: float,
     handed to that decode replica and the 200 body is a
     `disagg_redirect` marker the router collects from the peer
     (docs/disaggregation.md). A failed handoff falls through to the
-    plain local wait below — never a client-visible error."""
-    from fengshen_tpu.observability import parse_traceparent
+    plain local wait below — never a client-visible error.
+
+    `cpu_start`: the handler thread's CPU seconds (`thread_times()`)
+    at the POST's entry, this call's own entry where none is given;
+    from there to the return of `submit()` is credited to
+    `fstpu_serving_handler_admit_cpu_seconds_total`."""
+    from fengshen_tpu.observability import parse_traceparent, thread_times
     from fengshen_tpu.serving import (FINISHED, Draining,
                                       DuplicateRequest, PromptTooLong,
                                       QueueFull)
     from fengshen_tpu.serving.handoff import EVACUATED
+    if cpu_start is None:
+        cpu_start = thread_times()[1]
     rid = req.get("request_id")
     ctx = parse_traceparent(req.get("traceparent"))
 
@@ -380,6 +388,9 @@ def _engine_generate(engine, pipeline, req: dict, timeout_s: float,
     except (ValueError, TypeError) as e:
         # bad request payload (unencodable input, max_new_tokens < 1)
         return 422, _body({"error": str(e)})
+    finally:
+        engine.metrics.record_handler_admit_cpu(
+            thread_times()[1] - cpu_start)
     if disagg is not None and req.get("disagg_push_to"):
         redirect = disagg.handoff(request, str(req["disagg_push_to"]))
         if redirect is not None:
@@ -417,7 +428,20 @@ def _engine_generate(engine, pipeline, req: dict, timeout_s: float,
                        "finish_reason": request.finish_reason})
 
 
-def _engine_stream(engine, pipeline, req: dict, timeout_s: float):
+#: delivered tokens between two credits of a stream's account: a
+#: window over streams that live for a minute then reads steady state,
+#: not only the streams that ended inside it
+_CREDIT_EVERY = 64
+#: and between two readings of the thread's CPU clock, whose seconds
+#: ride on the next credit: a system call that holds the GIL, 15 us on
+#: a sandboxed host after a pause as short as 150 us and more after a
+#: longer one, so one in 512 tokens and not one in 64 (with 64 streams
+#: that was a call a tick)
+_CLOCK_EVERY = 512
+
+
+def _engine_stream(engine, pipeline, req: dict, timeout_s: float,
+                   cpu_start: Optional[float] = None):
     """`POST /api/<task>/stream` (docs/streaming.md): submit (or
     reattach to) a request and return its live SSE frame iterator.
 
@@ -432,15 +456,30 @@ def _engine_stream(engine, pipeline, req: dict, timeout_s: float):
     path (`Last-Event-ID`, lifted into the body by the server layer):
     no new submission — the journaled request's stream replays from
     token `last_event_id + 1` and continues live. On `evacuated`, the
-    client re-POSTs the same body to the named adopter."""
-    from fengshen_tpu.observability import parse_traceparent
+    client re-POSTs the same body to the named adopter.
+
+    The calling thread's account (docs/streaming.md "Observability"),
+    all on `engine.metrics`: its CPU seconds from `cpu_start` (the
+    POST's entry; this call's own where none is given) to the return
+    of `submit()` as admission, from there to the terminal event as
+    delivery; the batches it woke for, the tokens it flushed and
+    their lag behind the commit that brought them. Kept in locals and
+    credited every `_CREDIT_EVERY` tokens and at the stream's end (the
+    CPU seconds every `_CLOCK_EVERY`): no span, no per-token metric
+    call. `frames` resumes after each
+    `yield` only once the caller has written and flushed the frame,
+    which is where a token counts as delivered; a caller that stops
+    early closes `frames`, and what was sent is credited then."""
+    from fengshen_tpu.observability import parse_traceparent, thread_times
     from fengshen_tpu.serving import (Draining, DuplicateRequest,
                                       PromptTooLong, QueueFull)
     from fengshen_tpu.streaming import format_event
     if engine is None or not hasattr(engine, "attach_stream"):
         return 501, {"error": "streaming requires the continuous "
                               "batching engine"}, None
-    t0 = time.perf_counter()
+    t0, cpu_entry = thread_times()
+    if cpu_start is None:
+        cpu_start = cpu_entry
     rid = req.get("request_id")
     if rid is not None and req.get("last_event_id") is not None:
         stream = engine.attach_stream(str(rid))
@@ -449,6 +488,8 @@ def _engine_stream(engine, pipeline, req: dict, timeout_s: float):
         engine.metrics.record_stream_reconnect()
         start = int(req["last_event_id"]) + 1
         request_id = str(rid)
+        cpu_admitted = thread_times()[1]
+        engine.metrics.record_handler_admit_cpu(cpu_admitted - cpu_start)
     else:
         ctx = parse_traceparent(req.get("traceparent"))
         try:
@@ -471,43 +512,75 @@ def _engine_stream(engine, pipeline, req: dict, timeout_s: float):
             return 413, {"error": str(e)}, None
         except (ValueError, TypeError) as e:
             return 422, {"error": str(e)}, None
+        finally:
+            cpu_admitted = thread_times()[1]
+            engine.metrics.record_handler_admit_cpu(
+                cpu_admitted - cpu_start)
         stream = engine.streams.get(request.request_id)
         start = 0
         request_id = request.request_id
 
     def frames():
+        clock, metrics = time.perf_counter, engine.metrics
+        cpu, wakeups, tokens, lag = cpu_admitted, 0, 0, 0.0
+        unclocked = 0       # tokens credited since `cpu` was read
         first = True
-        for kind, idx, payload in stream.events(start,
-                                                timeout=timeout_s):
-            if first:
-                # delivery-layer TTFB: received-to-first-byte (the
-                # engine's ttft_seconds keeps its commit-time meaning)
-                engine.metrics.record_stream_ttfb(
-                    time.perf_counter() - t0)
-                first = False
-            if kind == "token":
-                yield format_event("token", {"token": payload},
-                                   event_id=idx)
-            elif kind == "evacuated":
-                # the lane moved mid-generation: the terminal event
-                # names the adopter; re-POST the same body there with
-                # last_event_id to continue gaplessly
-                yield format_event(
-                    "evacuated",
-                    {"request_id": request_id, "target": payload},
-                    event_id=idx)
-            elif kind == "timeout":
-                yield format_event(
-                    "timeout",
-                    {"request_id": request_id,
-                     "error": f"no stream event within {timeout_s}s"},
-                    event_id=idx)
-            else:   # done
-                data = {"request_id": request_id,
-                        "finish_reason": payload}
-                if payload in ("eos", "length"):
-                    data["result"] = pipeline.decode(stream.tokens())
-                yield format_event("done", data, event_id=idx)
+        try:
+            for kind, idx, payload in stream.batches(start,
+                                                     timeout=timeout_s):
+                if first:
+                    # delivery-layer TTFB: received-to-first-byte (the
+                    # engine's ttft_seconds keeps its commit-time
+                    # meaning)
+                    metrics.record_stream_ttfb(clock() - t0)
+                    first = False
+                if kind == "tokens":
+                    batch, stamps = payload
+                    wakeups += 1
+                    i = 0
+                    for tok in batch:
+                        yield format_event("token", {"token": tok},
+                                           event_id=idx + i)
+                        # a token committed before this reader came (a
+                        # reconnect's replay, a resumed prefix) lags
+                        # behind nothing the server did
+                        stamp = stamps[i]
+                        if stamp >= t0:
+                            lag += clock() - stamp
+                        i += 1
+                    tokens += i
+                    if tokens >= _CREDIT_EVERY:
+                        unclocked += tokens
+                        spent = 0.0
+                        if unclocked >= _CLOCK_EVERY:
+                            now = thread_times()[1]
+                            spent, cpu, unclocked = now - cpu, now, 0
+                        metrics.record_delivery(spent, wakeups, tokens,
+                                                lag)
+                        wakeups, tokens, lag = 0, 0, 0.0
+                elif kind == "evacuated":
+                    # the lane moved mid-generation: the terminal event
+                    # names the adopter; re-POST the same body there
+                    # with last_event_id to continue gaplessly
+                    yield format_event(
+                        "evacuated",
+                        {"request_id": request_id, "target": payload},
+                        event_id=idx)
+                elif kind == "timeout":
+                    yield format_event(
+                        "timeout",
+                        {"request_id": request_id,
+                         "error": f"no stream event within {timeout_s}s"},
+                        event_id=idx)
+                else:   # done
+                    data = {"request_id": request_id,
+                            "finish_reason": payload}
+                    if payload in ("eos", "length"):
+                        data["result"] = pipeline.decode(stream.tokens())
+                    yield format_event("done", data, event_id=idx)
+        finally:
+            metrics.record_delivery(thread_times()[1] - cpu, wakeups,
+                                    tokens, lag)
 
     return 200, None, frames()
 
@@ -577,6 +650,8 @@ def build_stdlib_server(server_cfg: ServerConfig,
     import http.server
     import threading
 
+    from fengshen_tpu.observability import thread_times
+
     if pipeline is None:
         pipeline = _resolve_pipeline(pipeline_cfg)
     route = f"/api/{pipeline_cfg.task}"
@@ -630,6 +705,9 @@ def build_stdlib_server(server_cfg: ServerConfig,
                 # the journal + stream buffer for a Last-Event-ID
                 # reconnect — nothing to clean up here
                 pass
+            finally:
+                # on this thread, now: what was sent is credited
+                frames.close()
             t0 = getattr(self, "_t_start", None)
             if t0 is not None:
                 _observe_http(label, time.perf_counter() - t0)
@@ -686,7 +764,7 @@ def build_stdlib_server(server_cfg: ServerConfig,
                 self._send(404, {"error": "not found"})
 
         def do_POST(self):
-            self._t_start = time.perf_counter()
+            self._t_start, self._cpu_start = thread_times()
             if self.path == "/debug/dump":
                 if recorder is None:
                     self._send(404, {"error":
@@ -737,7 +815,8 @@ def build_stdlib_server(server_cfg: ServerConfig,
                                 "continuous") == "continuous":
                     code, body = _engine_generate(
                         engine, pipeline, req,
-                        server_cfg.request_timeout_s, disagg=disagg)
+                        server_cfg.request_timeout_s, disagg=disagg,
+                        cpu_start=self._cpu_start)
                     self._send(code, body)
                 elif engine is not None:
                     code, body = _multimodal_generate(
@@ -797,7 +876,8 @@ def build_stdlib_server(server_cfg: ServerConfig,
             try:
                 code, body, frames = _engine_stream(
                     engine, pipeline, req,
-                    server_cfg.request_timeout_s)
+                    server_cfg.request_timeout_s,
+                    cpu_start=self._cpu_start)
                 if frames is None:
                     self._send(code, body)
                 else:

@@ -31,7 +31,8 @@ import math
 import re
 import threading
 from collections import deque
-from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import (Callable, Dict, Iterable, List, Optional, Sequence,
+                    Tuple)
 
 _NAME_RE = re.compile(r"^[a-zA-Z_:][a-zA-Z0-9_:]*$")
 _LABEL_RE = re.compile(r"^[a-zA-Z_][a-zA-Z0-9_]*$")
@@ -260,6 +261,11 @@ class MetricsRegistry:
         #: `tracing.span`'s resolved children by span label, so that a
         #: span's exit looks nothing up by name (tracing._children)
         self.span_children: Dict[str, tuple] = {}
+        #: callables run at the head of `metrics()`, outside the lock: a
+        #: family whose value is read off the OS is brought up to date
+        #: when something READS the registry (a scrape, the flight
+        #: recorder's snapshot), not on a writer's hot path
+        self.collectors: List[Callable[[], None]] = []
 
     def _get_or_create(self, cls, name: str, help: str, **kw) -> Metric:
         candidate = cls(name, help, **kw)
@@ -299,6 +305,8 @@ class MetricsRegistry:
 
     def metrics(self) -> List[Metric]:
         """All metrics, sorted by name (deterministic exposition)."""
+        for collect in self.collectors:
+            collect()
         with self._lock:
             return [self._metrics[k] for k in sorted(self._metrics)]
 

@@ -1813,7 +1813,7 @@ class ContinuousBatchingEngine:
         # decode_tokens and the acceptance rate `/stats` reports
         delivered = 0
         accepted_delivered = 0
-        t_commit = self._clock()
+        t_commit, stamp = self._clock(), time.perf_counter()
         for i, req in live:
             k = 0
             fin = None
@@ -1832,7 +1832,7 @@ class ContinuousBatchingEngine:
             req.timeline.add(t_commit, "commit", n=k,
                              accepted=min(int(n_r[i]), k),
                              tick_s=round(tick.seconds, 6))
-            self._sync_stream(req)
+            self._sync_stream(req, stamp)
             if fin is not None:
                 self._release(i, FINISHED, fin)
             delivered += k
@@ -1889,7 +1889,7 @@ class ContinuousBatchingEngine:
             ahead=tick.ahead)
         self.metrics.record_block_forwards(
             len(tick.lanes), int(tick.commits[tick.lanes].sum()))
-        t_commit = self._clock()
+        t_commit, stamp = self._clock(), time.perf_counter()
         for i, req, toks, fin in deliver:
             if req.ttft_s is None:
                 req.ttft_s = t_commit - req.submit_time
@@ -1898,7 +1898,7 @@ class ContinuousBatchingEngine:
             req.tokens.extend(toks)
             req.timeline.add(t_commit, "commit", n=len(toks),
                              tick_s=round(tick.seconds, 6))
-            self._sync_stream(req)
+            self._sync_stream(req, stamp)
             if fin is not None:
                 # an EOS is learnt a tick late, as in `_commit_plain`
                 self._release(i, FINISHED, fin)
@@ -1915,13 +1915,13 @@ class ContinuousBatchingEngine:
                                  kv_tokens=tick.kv_tokens,
                                  kv_blocks=tick.kv_blocks,
                                  ahead=tick.ahead)
-        t_commit = self._clock()
+        t_commit, stamp = self._clock(), time.perf_counter()
         for i, req in live:
             tok = int(nxt[i])
             req.tokens.append(tok)
             req.timeline.add(t_commit, "commit", n=1,
                              tick_s=round(tick.seconds, 6))
-            self._sync_stream(req)
+            self._sync_stream(req, stamp)
             if self.config.eos_token_id is not None and \
                     tok == self.config.eos_token_id:
                 # the host learns an EOS a tick late: the lane's extra
@@ -1986,29 +1986,31 @@ class ContinuousBatchingEngine:
                         return prefills
                     self._deferred_req = None
             try:
-                if windows is None:
-                    row, mask_row = self.ladder.pad_prompt(
-                        prefill_ids, bucket, self.config.pad_token_id)
-                else:
-                    # filled from position 0: the lane holds the prompt
-                    row = np.asarray(prefill_ids, np.int32)
-                    mask_row = np.ones_like(row)
-                if self.config.do_sample:
-                    # per-request key derivation (docs/streaming.md
-                    # "Seed semantics"): fold the request seed into the
-                    # engine base key, then split once — one half seeds
-                    # the prefill draw, the other becomes this lane's
-                    # ring entry. No global RNG is consumed, so a
-                    # request's stream is independent of admission
-                    # order and pool co-tenancy.
-                    base = jax.random.fold_in(self._base_key, req.seed)
-                    key, lane_key = jax.random.split(base)
-                else:
-                    key = lane_key = self._zero_key
-                req.timeline.add(self._clock(), "admitted", slot=slot,
-                                 bucket=int(bucket))
-                req.timeline.add(self._clock(), "prefill_start",
-                                 bucket=int(bucket))
+                with span("serving/prepare", request_id=req.request_id,
+                          bucket=int(bucket)):
+                    if windows is None:
+                        row, mask_row = self.ladder.pad_prompt(
+                            prefill_ids, bucket, self.config.pad_token_id)
+                    else:
+                        # filled from position 0: the lane holds the prompt
+                        row = np.asarray(prefill_ids, np.int32)
+                        mask_row = np.ones_like(row)
+                    if self.config.do_sample:
+                        # per-request key derivation (docs/streaming.md
+                        # "Seed semantics"): fold the request seed into the
+                        # engine base key, then split once — one half seeds
+                        # the prefill draw, the other becomes this lane's
+                        # ring entry. No global RNG is consumed, so a
+                        # request's stream is independent of admission
+                        # order and pool co-tenancy.
+                        base = jax.random.fold_in(self._base_key, req.seed)
+                        key, lane_key = jax.random.split(base)
+                    else:
+                        key = lane_key = self._zero_key
+                    req.timeline.add(self._clock(), "admitted", slot=slot,
+                                     bucket=int(bucket))
+                    req.timeline.add(self._clock(), "prefill_start",
+                                     bucket=int(bucket))
                 with span("serving/prefill", request_id=req.request_id,
                           bucket=int(bucket),
                           prompt_tokens=int(len(prefill_ids))) as s:
@@ -2071,7 +2073,7 @@ class ContinuousBatchingEngine:
                     tok = resume[-1]
                 else:
                     req.tokens.append(tok)
-                self._sync_stream(req)
+                self._sync_stream(req, time.perf_counter())
                 if self.config.eos_token_id is not None and \
                         tok == self.config.eos_token_id:
                     if blocks is not None:
@@ -2288,11 +2290,13 @@ class ContinuousBatchingEngine:
         self._sync_stream(req)
         req._done.set()
 
-    def _sync_stream(self, req: Request) -> None:
+    def _sync_stream(self, req: Request, stamp: float = 0.0) -> None:
         """Push `req`'s committed tokens to its live stream, if one is
         open. O(1) dict probe when it is not — the cost a non-streaming
-        engine pays per commit. Host-side only, never traced."""
-        n = self.streams.sync(req)
+        engine pays per commit. Host-side only, never traced. `stamp`:
+        the commit's one `time.perf_counter()` reading, shared by every
+        lane it syncs (a terminal sync brings no token and none)."""
+        n = self.streams.sync(req, stamp)
         if n:
             self.metrics.record_stream_tokens(n)
 

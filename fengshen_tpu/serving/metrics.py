@@ -19,6 +19,8 @@ rendering `GET /metrics`.
 
 from __future__ import annotations
 
+import threading
+import time
 from typing import Optional
 
 from fengshen_tpu.observability import MetricsRegistry
@@ -268,6 +270,43 @@ class EngineMetrics:
             window=window)
         self._g_streams = r.gauge(
             "fstpu_streams_active", "open (unclosed) SSE token streams")
+        # the handler threads' account (docs/serving.md "Threading"):
+        # sums over many streams mean something, one stream's reading
+        # does not (the CPU clock steps by 10 ms on some hosts)
+        self._handler_admit_cpu = r.counter(
+            "fstpu_serving_handler_admit_cpu_seconds_total",
+            "handler threads' CPU seconds from a POST's entry to the "
+            "return of submit(): body read, JSON, encode, the submit "
+            "itself (a refused request's too)")
+        self._handler_stream_cpu = r.counter(
+            "fstpu_serving_handler_stream_cpu_seconds_total",
+            "handler threads' CPU seconds from there to a stream's "
+            "terminal event: wake-ups, framing, socket writes")
+        self._stream_wakeups = r.counter(
+            "fstpu_stream_wakeups_total",
+            "batches stream readers took from a return of their "
+            "condition wait with something to deliver")
+        self._stream_delivered = r.counter(
+            "fstpu_stream_tokens_delivered_total",
+            "tokens whose SSE frame's flush() returned: the socket's "
+            "side of stream_tokens_total (over stream_wakeups_total: "
+            "tokens a wake-up)")
+        self._stream_lag = r.counter(
+            "fstpu_stream_delivery_lag_seconds_total",
+            "summed over delivered tokens, flush() returned less the "
+            "commit that brought the token (over "
+            "stream_tokens_delivered_total: the mean lag; a replayed "
+            "token adds none)")
+        self._process_cpu = r.counter(
+            "fstpu_serving_process_cpu_seconds_total",
+            "the whole process's CPU seconds (time.process_time()) "
+            "since the engine's metrics were made, brought up to date "
+            "at every read of the registry: the scheduler's, the "
+            "handlers' and the rest (the runtime's threads; clients "
+            "that share the process)")
+        self._process_cpu_at = time.process_time()
+        self._process_cpu_lock = threading.Lock()
+        r.collectors.append(self._collect_process_cpu)
         self._peak_active = 0
         self._warmup_compile_s: Optional[float] = None
 
@@ -398,6 +437,31 @@ class EngineMetrics:
         delivery-layer TTFT (ttft_seconds keeps its commit-time
         meaning)."""
         self._stream_ttfb.observe(seconds)
+
+    def record_handler_admit_cpu(self, seconds: float) -> None:
+        self._handler_admit_cpu.inc(seconds)
+
+    def record_delivery(self, cpu: float, wakeups: int, tokens: int,
+                        lag: float) -> None:
+        """What one stream's handler thread did since its last credit
+        (every 64 delivered tokens and at the stream's end): the
+        batches it woke for, the tokens it flushed, their summed
+        delivery lag, and its CPU seconds since it last read that
+        clock (every 512 tokens and at the end; 0.0 in between)."""
+        self._handler_stream_cpu.inc(cpu)
+        self._stream_wakeups.inc(wakeups)
+        self._stream_delivered.inc(tokens)
+        self._stream_lag.inc(lag)
+
+    def _collect_process_cpu(self) -> None:
+        """The registry's collector: `time.process_time()` sums over
+        every thread of the process (microseconds with hundreds of
+        them), so it is read when the registry is, never by the
+        scheduler or a handler."""
+        with self._process_cpu_lock:
+            total = time.process_time()
+            self._process_cpu.inc(total - self._process_cpu_at)
+            self._process_cpu_at = total
 
     def record_phases(self, phases: dict) -> None:
         """One finished request's derived waterfall (timeline.phases())

@@ -43,20 +43,29 @@ class TokenStream:
     def __init__(self) -> None:
         self._cond = threading.Condition()
         self._tokens: list = []
+        #: beside each token, the `time.perf_counter()` of the commit
+        #: that brought it (0.0: no commit did, `StreamBook.open`
+        #: seeded it); bounded like the tokens
+        self._stamps: list = []
         self.finish_reason: Optional[str] = None
         self.evac_target: Optional[str] = None
         self.closed = False
 
     def publish(self, tokens, finish_reason: Optional[str] = None,
-                evac_target: Optional[str] = None) -> int:
+                evac_target: Optional[str] = None,
+                stamp: float = 0.0) -> int:
         """Append any tokens past the current length, record terminal
         state, wake readers. Returns the number of NEW tokens (0 when
-        the snapshot brings nothing — the common non-commit sync)."""
+        the snapshot brings nothing — the common non-commit sync).
+        `stamp` is the committing tick's ONE `time.perf_counter()`
+        reading, the same for every stream it publishes to: a reader
+        measures its delivery lag from it."""
         with self._cond:
             new = len(tokens) - len(self._tokens)
             if new > 0:
                 self._tokens.extend(
                     int(t) for t in tokens[len(self._tokens):])
+                self._stamps.extend([stamp] * new)
             if evac_target is not None:
                 self.evac_target = evac_target
             if finish_reason is not None and not self.closed:
@@ -71,10 +80,14 @@ class TokenStream:
         with self._cond:
             return list(self._tokens)
 
-    def events(self, start: int = 0,
-               timeout: Optional[float] = None) -> Iterator[tuple]:
-        """Yield `("token", index, token_id)` for every token at
-        index >= start, then exactly one terminal event:
+    def batches(self, start: int = 0,
+                timeout: Optional[float] = None) -> Iterator[tuple]:
+        """The reader's side, one item a wake-up: `("tokens", index,
+        (token_ids, stamps))` for everything a return from the
+        condition wait found committed from `index` on (one token where
+        every tick wakes the reader in time, a block where a commit
+        delivers one, more where the reader fell behind), then exactly
+        one terminal event:
 
         - `("evacuated", next_index, target)` — the lane moved to
           another replica mid-generation; reconnect THERE with
@@ -84,7 +97,7 @@ class TokenStream:
           seconds (the reader's keep-alive/deadline surface; the
           stream itself stays open).
 
-        Tokens are yielded OUTSIDE the condition so a stalled socket
+        Items are yielded OUTSIDE the condition so a stalled socket
         write never holds the lock against the scheduler's publish.
         """
         pos = max(int(start), 0)
@@ -95,12 +108,13 @@ class TokenStream:
                         yield ("timeout", pos, None)
                         return
                 batch = self._tokens[pos:]
+                stamps = self._stamps[pos:]
                 closed = self.closed
                 reason = self.finish_reason
                 target = self.evac_target
-            for tok in batch:
-                yield ("token", pos, tok)
-                pos += 1
+            if batch:
+                yield ("tokens", pos, (batch, stamps))
+                pos += len(batch)
             if closed:
                 if target is not None and reason in (
                         "evacuated", "handed_off"):
@@ -108,6 +122,18 @@ class TokenStream:
                 else:
                     yield ("done", pos, reason)
                 return
+
+    def events(self, start: int = 0,
+               timeout: Optional[float] = None) -> Iterator[tuple]:
+        """`batches`, a token an item: `("token", index, token_id)` for
+        every token at index >= start, then the terminal event."""
+        for kind, pos, payload in self.batches(start, timeout):
+            if kind != "tokens":
+                yield (kind, pos, payload)
+                continue
+            for tok in payload[0]:
+                yield ("token", pos, tok)
+                pos += 1
 
 
 class StreamBook:
@@ -138,9 +164,10 @@ class StreamBook:
         self._publish(stream, req)
         return stream
 
-    def sync(self, req) -> int:
+    def sync(self, req, stamp: float = 0.0) -> int:
         """Scheduler-side push: publish `req`'s committed snapshot to
-        its stream if one is open. Returns new-token count (0 on the
+        its stream if one is open, under the commit's `stamp`
+        (`TokenStream.publish`). Returns new-token count (0 on the
         no-stream fast path)."""
         if not self.ever_opened:
             return 0
@@ -148,17 +175,17 @@ class StreamBook:
             stream = self._streams.get(req.request_id)
         if stream is None:
             return 0
-        return self._publish(stream, req)
+        return self._publish(stream, req, stamp)
 
     @staticmethod
-    def _publish(stream: TokenStream, req) -> int:
+    def _publish(stream: TokenStream, req, stamp: float = 0.0) -> int:
         # finish_reason doubles as the terminal marker: the engine sets
         # it exactly once per request (finish/reject/detach), and
         # detach_lane stamps evac_target first, so the terminal event
         # can point the reader at the adopter
         return stream.publish(req.tokens,
                               finish_reason=req.finish_reason,
-                              evac_target=req.evac_target)
+                              evac_target=req.evac_target, stamp=stamp)
 
     def get(self, request_id: str) -> Optional[TokenStream]:
         with self._lock:

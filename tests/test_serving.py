@@ -668,7 +668,8 @@ def test_scheduler_spans_cover_the_tick_and_keep_the_old_names(tiny):
     # nothing was renamed by a span opened around it
     assert not {k for k in grew if k.startswith("serving/")} - {
         "serving/admit", "serving/admit/lock_wait", "serving/lock_wait",
-        "serving/reclaim", "serving/prefill", "serving/assign",
+        "serving/reclaim", "serving/prepare", "serving/prefill",
+        "serving/assign",
         "serving/decode", "serving/decode/dispatch",
         "serving/decode/dispatch/call",
         "serving/decode/dispatch/copy_back", "serving/decode/fetch",
@@ -819,6 +820,39 @@ def test_block_allocation_has_a_span_a_request_also_when_deferred(tiny):
     assert all(r.state == "finished" for r in reqs)
     assert eng.stats()["kv_blocks_used"] == 0
     assert "serving/alloc/serving/prefill" not in _span_counts()
+
+
+@pytest.mark.parametrize("kw, prompts, windows", [
+    ({"buckets": (8, 16)}, (5, 11, 16), 0),
+    ({"buckets": (8,), "kv_layout": "paged", "kv_block_size": 16},
+     (5, 13), 2),
+], ids=["whole_prompt", "windowed"])
+def test_the_stretch_between_alloc_and_prefill_has_a_span_an_admission(
+        tiny, kw, prompts, windows):
+    """`serving/prepare` (ISSUE 50): the padded row or the window's, the
+    key and the two timeline events, once a request admitted: a sibling
+    of `serving/alloc` and `serving/prefill`, so neither is renamed; a
+    request past the ladder (windowed prefill) has one too."""
+    model, params = tiny
+    eng = ContinuousBatchingEngine(
+        model, params, EngineConfig(num_slots=2, max_new_tokens=4,
+                                    max_queue=16, **kw))
+    before = _span_counts()
+    reqs = [eng.submit(p) for p in _prompts(prompts, seed=5)]
+    eng.run_until_idle()
+    assert all(r.state == "finished" for r in reqs)
+    after = _span_counts()
+    grew = {k: n - before.get(k, 0) for k, n in after.items()
+            if n > before.get(k, 0)}
+    assert grew["serving/prepare"] == grew["serving/prefill"] == len(reqs)
+    assert grew.get("serving/prefill/window", 0) == windows
+    assert not [k for k in grew if k.startswith("serving/prepare/")
+                or k.endswith("/serving/prepare")]
+    # the timeline events it covers are still each request's
+    for r in reqs:
+        marks = [e["event"] for e in eng.debug_request(
+            r.request_id)["events"]]
+        assert marks.index("admitted") < marks.index("prefill_start")
 
 
 def test_the_unread_decode_seconds_are_gone_from_stats_and_metrics(tiny):

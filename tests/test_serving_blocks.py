@@ -178,6 +178,35 @@ def test_a_stream_is_fed_a_block_at_a_time(sdar):
     assert stream is not None and req.tokens == list(req.tokens)
 
 
+def test_a_blocks_tokens_reach_the_socket_in_one_wakeup(sdar):
+    """A commit publishes a block's tokens together, so the handler
+    thread's account (docs/streaming.md "Observability") reads more
+    than one token delivered a wake-up: 9 tokens in the three commits
+    above."""
+    from fengshen_tpu.api.main import _engine_stream
+
+    class Pipe:
+        encode = staticmethod(lambda text: [int(t) for t in text.split()])
+        decode = staticmethod(lambda ids: " ".join(map(str, ids)))
+
+    eng = engine_of(sdar, denoise_steps=2)
+    eng.start()
+    try:
+        code, _, frames = _engine_stream(
+            eng, Pipe, {"input_text": "1 2 3 4 5 6", "max_new_tokens": 9},
+            60.0)
+        assert code == 200
+        sent = list(frames)
+    finally:
+        eng.stop()
+    assert len(sent) == 9 + 1               # a frame a token, and `done`
+    count = {k: eng.metrics.registry.get(f"fstpu_stream_{k}_total").value()
+             for k in ("tokens", "tokens_delivered", "wakeups")}
+    assert count["tokens_delivered"] == 9 == count["tokens"]
+    assert 1 <= count["wakeups"] <= 3
+    assert count["tokens_delivered"] / count["wakeups"] >= 3.0
+
+
 @pytest.mark.parametrize("kw, why", [
     ({"spec_mode": "prompt_lookup"}, "draft window is causal"),
     ({"do_sample": True}, "sampled reveal is not built"),
