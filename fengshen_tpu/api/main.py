@@ -65,7 +65,7 @@ import importlib
 import json
 import threading
 import time
-from typing import Optional
+from typing import NamedTuple, Optional
 
 
 @dataclasses.dataclass
@@ -428,29 +428,28 @@ def _engine_generate(engine, pipeline, req: dict, timeout_s: float,
                        "finish_reason": request.finish_reason})
 
 
-#: delivered tokens between two credits of a stream's account: a
-#: window over streams that live for a minute then reads steady state,
-#: not only the streams that ended inside it
+#: delivered tokens between two credits of a PULL reader's account
+#: (`_engine_stream`'s frames; the server's delivery thread credits a
+#: wake-up)
 _CREDIT_EVERY = 64
-#: and between two readings of the thread's CPU clock, whose seconds
-#: ride on the next credit: a system call that holds the GIL, 15 us on
-#: a sandboxed host after a pause as short as 150 us and more after a
-#: longer one, so one in 512 tokens and not one in 64 (with 64 streams
-#: that was a call a tick)
-_CLOCK_EVERY = 512
 
 
-def _engine_stream(engine, pipeline, req: dict, timeout_s: float,
-                   cpu_start: Optional[float] = None):
-    """`POST /api/<task>/stream` (docs/streaming.md): submit (or
-    reattach to) a request and return its live SSE frame iterator.
+class _Admitted(NamedTuple):
+    """A stream request past admission: what delivery starts from."""
+    stream: object          # the request's `TokenStream`
+    start: int              # index of the first token to deliver
+    request_id: str
+    arrived: float          # `time.perf_counter()` at the POST's entry
+    cpu: float              # the handler's CPU clock once admitted
 
-    Returns `(code, payload, None)` for refusals — the SAME
-    backpressure → HTTP map as `_engine_generate`, answered as plain
-    JSON before any stream byte is written — or `(200, None, frames)`
-    where `frames` yields ready-to-write SSE byte chunks: one `token`
-    event per committed token (event id = token index), then exactly
-    one terminal `done` / `evacuated` / `timeout` event.
+
+def _admit_stream(engine, pipeline, req: dict,
+                  cpu_start: Optional[float] = None):
+    """Admission of `POST /api/<task>/stream` (docs/streaming.md):
+    submit, or reattach to, a request. Returns `(code, payload, None)`
+    for refusals — the SAME backpressure → HTTP map as
+    `_engine_generate`, answered as plain JSON before any stream byte
+    is written — or `(200, None, _Admitted)`.
 
     A body carrying `request_id` + `last_event_id` is the reconnect
     path (`Last-Event-ID`, lifted into the body by the server layer):
@@ -458,22 +457,12 @@ def _engine_stream(engine, pipeline, req: dict, timeout_s: float,
     token `last_event_id + 1` and continues live. On `evacuated`, the
     client re-POSTs the same body to the named adopter.
 
-    The calling thread's account (docs/streaming.md "Observability"),
-    all on `engine.metrics`: its CPU seconds from `cpu_start` (the
-    POST's entry; this call's own where none is given) to the return
-    of `submit()` as admission, from there to the terminal event as
-    delivery; the batches it woke for, the tokens it flushed and
-    their lag behind the commit that brought them. Kept in locals and
-    credited every `_CREDIT_EVERY` tokens and at the stream's end (the
-    CPU seconds every `_CLOCK_EVERY`): no span, no per-token metric
-    call. `frames` resumes after each
-    `yield` only once the caller has written and flushed the frame,
-    which is where a token counts as delivered; a caller that stops
-    early closes `frames`, and what was sent is credited then."""
+    The calling thread's CPU seconds from `cpu_start` (the POST's
+    entry; this call's own where none is given) to the return of
+    `submit()` are credited as admission, a refused request's too."""
     from fengshen_tpu.observability import parse_traceparent, thread_times
     from fengshen_tpu.serving import (Draining, DuplicateRequest,
                                       PromptTooLong, QueueFull)
-    from fengshen_tpu.streaming import format_event
     if engine is None or not hasattr(engine, "attach_stream"):
         return 501, {"error": "streaming requires the continuous "
                               "batching engine"}, None
@@ -481,18 +470,15 @@ def _engine_stream(engine, pipeline, req: dict, timeout_s: float,
     if cpu_start is None:
         cpu_start = cpu_entry
     rid = req.get("request_id")
-    if rid is not None and req.get("last_event_id") is not None:
-        stream = engine.attach_stream(str(rid))
-        if stream is None:
-            return 404, {"error": f"unknown request_id {rid!r}"}, None
-        engine.metrics.record_stream_reconnect()
-        start = int(req["last_event_id"]) + 1
-        request_id = str(rid)
-        cpu_admitted = thread_times()[1]
-        engine.metrics.record_handler_admit_cpu(cpu_admitted - cpu_start)
-    else:
-        ctx = parse_traceparent(req.get("traceparent"))
-        try:
+    try:
+        if rid is not None and req.get("last_event_id") is not None:
+            stream = engine.attach_stream(str(rid))
+            if stream is None:
+                return 404, {"error": f"unknown request_id {rid!r}"}, None
+            engine.metrics.record_stream_reconnect()
+            start, request_id = int(req["last_event_id"]) + 1, str(rid)
+        else:
+            ctx = parse_traceparent(req.get("traceparent"))
             request = engine.submit(
                 pipeline.encode(req["input_text"]),
                 max_new_tokens=req.get("max_new_tokens"),
@@ -502,84 +488,105 @@ def _engine_stream(engine, pipeline, req: dict, timeout_s: float,
                 resume_tokens=req.get("resume_tokens"),
                 resume_source=req.get("resume_source"),
                 seed=req.get("seed"), stream=True)
-        except Draining as e:
-            return 503, {"error": str(e), "reason": "draining"}, None
-        except DuplicateRequest as e:
-            return 409, {"error": str(e)}, None
-        except QueueFull as e:
-            return 429, {"error": str(e)}, None
-        except PromptTooLong as e:
-            return 413, {"error": str(e)}, None
-        except (ValueError, TypeError) as e:
-            return 422, {"error": str(e)}, None
-        finally:
-            cpu_admitted = thread_times()[1]
-            engine.metrics.record_handler_admit_cpu(
-                cpu_admitted - cpu_start)
-        stream = engine.streams.get(request.request_id)
-        start = 0
-        request_id = request.request_id
+            stream = engine.streams.get(request.request_id)
+            start, request_id = 0, request.request_id
+    except Draining as e:
+        return 503, {"error": str(e), "reason": "draining"}, None
+    except DuplicateRequest as e:
+        return 409, {"error": str(e)}, None
+    except QueueFull as e:
+        return 429, {"error": str(e)}, None
+    except PromptTooLong as e:
+        return 413, {"error": str(e)}, None
+    except (ValueError, TypeError) as e:
+        return 422, {"error": str(e)}, None
+    finally:
+        cpu_admitted = thread_times()[1]
+        engine.metrics.record_handler_admit_cpu(cpu_admitted - cpu_start)
+    return 200, None, _Admitted(stream, start, request_id, t0,
+                                cpu_admitted)
+
+
+def _terminal_frame(pipeline, adm: _Admitted, kind: str, idx: int,
+                    payload, timeout_s: float) -> bytes:
+    """The ONE SSE frame that ends a stream, from a reader's terminal
+    event (`TokenStream.terminal`, or `timeout`): the pull reader's and
+    the parked handler's alike."""
+    from fengshen_tpu.streaming import format_event
+    data = {"request_id": adm.request_id}
+    if kind == "evacuated":
+        # the lane moved mid-generation: the terminal event names the
+        # adopter; re-POST the same body there with last_event_id to
+        # continue gaplessly
+        data["target"] = payload
+    elif kind == "timeout":
+        data["error"] = f"no stream event within {timeout_s}s"
+    else:   # done
+        data["finish_reason"] = payload
+        if payload in ("eos", "length"):
+            data["result"] = pipeline.decode(adm.stream.tokens())
+    return format_event(kind, data, event_id=idx)
+
+
+def _engine_stream(engine, pipeline, req: dict, timeout_s: float,
+                   cpu_start: Optional[float] = None):
+    """`_admit_stream`, then the stream as a PULL reader's iterator:
+    `(200, None, frames)` where `frames` yields ready-to-write SSE byte
+    chunks: one `token` event per committed token (event id = token
+    index), then exactly one terminal `done` / `evacuated` / `timeout`
+    event. The server itself delivers through its delivery thread
+    (`streaming/delivery.py`); this is the same stream, frame for
+    frame, for a caller that writes it itself (the tests, a tool).
+
+    The calling thread keeps the delivery account (docs/streaming.md
+    "Observability") in locals: the batches it woke for, the tokens it
+    flushed and their lag behind their commit, credited every
+    `_CREDIT_EVERY` tokens; its CPU seconds since admission at the
+    stream's end. `frames` resumes after each `yield` only once the
+    caller has written the frame, which is where a token counts as
+    delivered; a caller that stops early closes `frames`, and what was
+    sent is credited then."""
+    from fengshen_tpu.observability import thread_times
+    from fengshen_tpu.streaming import token_frame
+    code, body, adm = _admit_stream(engine, pipeline, req, cpu_start)
+    if adm is None:
+        return code, body, None
 
     def frames():
         clock, metrics = time.perf_counter, engine.metrics
-        cpu, wakeups, tokens, lag = cpu_admitted, 0, 0, 0.0
-        unclocked = 0       # tokens credited since `cpu` was read
+        wakeups, tokens, lag = 0, 0, 0.0
         first = True
         try:
-            for kind, idx, payload in stream.batches(start,
-                                                     timeout=timeout_s):
+            for kind, idx, payload in adm.stream.batches(
+                    adm.start, timeout=timeout_s):
                 if first:
                     # delivery-layer TTFB: received-to-first-byte (the
                     # engine's ttft_seconds keeps its commit-time
                     # meaning)
-                    metrics.record_stream_ttfb(clock() - t0)
+                    metrics.record_stream_ttfb(clock() - adm.arrived)
                     first = False
-                if kind == "tokens":
-                    batch, stamps = payload
-                    wakeups += 1
-                    i = 0
-                    for tok in batch:
-                        yield format_event("token", {"token": tok},
-                                           event_id=idx + i)
-                        # a token committed before this reader came (a
-                        # reconnect's replay, a resumed prefix) lags
-                        # behind nothing the server did
-                        stamp = stamps[i]
-                        if stamp >= t0:
-                            lag += clock() - stamp
-                        i += 1
-                    tokens += i
-                    if tokens >= _CREDIT_EVERY:
-                        unclocked += tokens
-                        spent = 0.0
-                        if unclocked >= _CLOCK_EVERY:
-                            now = thread_times()[1]
-                            spent, cpu, unclocked = now - cpu, now, 0
-                        metrics.record_delivery(spent, wakeups, tokens,
-                                                lag)
-                        wakeups, tokens, lag = 0, 0, 0.0
-                elif kind == "evacuated":
-                    # the lane moved mid-generation: the terminal event
-                    # names the adopter; re-POST the same body there
-                    # with last_event_id to continue gaplessly
-                    yield format_event(
-                        "evacuated",
-                        {"request_id": request_id, "target": payload},
-                        event_id=idx)
-                elif kind == "timeout":
-                    yield format_event(
-                        "timeout",
-                        {"request_id": request_id,
-                         "error": f"no stream event within {timeout_s}s"},
-                        event_id=idx)
-                else:   # done
-                    data = {"request_id": request_id,
-                            "finish_reason": payload}
-                    if payload in ("eos", "length"):
-                        data["result"] = pipeline.decode(stream.tokens())
-                    yield format_event("done", data, event_id=idx)
+                if kind != "tokens":
+                    yield _terminal_frame(pipeline, adm, kind, idx,
+                                          payload, timeout_s)
+                    continue
+                batch, stamps = payload
+                wakeups += 1
+                i = 0
+                for tok in batch:
+                    yield token_frame(idx + i, tok)
+                    # a token committed before this reader came (a
+                    # reconnect's replay, a resumed prefix) lags
+                    # behind nothing the server did
+                    stamp = stamps[i]
+                    if stamp >= adm.arrived:
+                        lag += clock() - stamp
+                    i += 1
+                tokens += i
+                if tokens >= _CREDIT_EVERY:
+                    metrics.record_delivery(0.0, wakeups, tokens, lag)
+                    wakeups, tokens, lag = 0, 0, 0.0
         finally:
-            metrics.record_delivery(thread_times()[1] - cpu, wakeups,
+            metrics.record_delivery(thread_times()[1] - adm.cpu, wakeups,
                                     tokens, lag)
 
     return 200, None, frames()
@@ -646,15 +653,22 @@ def build_stdlib_server(server_cfg: ServerConfig,
     (`PUT/GET/DELETE /kv/<id>`, docs/disaggregation.md). The returned
     server tracks its in-flight generate requests
     (`server.in_flight()`) so the SIGTERM drain handler can wait them
-    out (docs/fleet.md)."""
+    out (docs/fleet.md). Over an engine that streams it also starts
+    the server's one delivery thread, which `server_close()` stops
+    (docs/streaming.md "Delivery")."""
     import http.server
     import threading
 
     from fengshen_tpu.observability import thread_times
+    from fengshen_tpu.streaming import Delivery, Subscription
 
     if pipeline is None:
         pipeline = _resolve_pipeline(pipeline_cfg)
     route = f"/api/{pipeline_cfg.task}"
+    # the ONE thread that delivers every stream of this server
+    # (docs/streaming.md "Delivery"); none where nothing can stream
+    delivery = Delivery(engine.streams, engine.metrics) \
+        if hasattr(engine, "attach_stream") else None
     inflight_lock = threading.Lock()
     inflight = [0]
 
@@ -681,13 +695,15 @@ def build_stdlib_server(server_cfg: ServerConfig,
                 code, json.dumps(payload, ensure_ascii=False).encode(),
                 "application/json")
 
-        def _send_stream(self, frames) -> None:
+        def _send_stream(self, adm: _Admitted) -> None:
             """SSE response: bypasses `_send_bytes` (no Content-Length
-            — the body length is unknown until the stream ends), writes
-            each frame as it arrives and flushes so tokens reach the
-            client at commit time, then closes the connection (the
-            `Connection: close` EOF is the stream terminator HTTP/1.0
-            clients understand without chunked framing)."""
+            — the body length is unknown until the stream ends). This
+            thread writes the headers, hands the connection to the
+            server's delivery thread, which writes the tokens as they
+            are committed, and PARKS until the stream is over; woken,
+            it writes the terminal event and closes the connection
+            (the `Connection: close` EOF is the stream terminator
+            HTTP/1.0 clients understand without chunked framing)."""
             label = _classify_route(self.path, route)
             _count_http(label, 200)
             self.send_response(200)
@@ -696,18 +712,27 @@ def build_stdlib_server(server_cfg: ServerConfig,
             self.send_header("Access-Control-Allow-Origin", "*")
             self.send_header("Connection", "close")
             self.end_headers()
-            try:
-                for chunk in frames:
-                    self.wfile.write(chunk)
-                    self.wfile.flush()
-            except (BrokenPipeError, ConnectionResetError):
-                # the client went away mid-stream; its tokens stay in
-                # the journal + stream buffer for a Last-Event-ID
-                # reconnect — nothing to clean up here
-                pass
-            finally:
-                # on this thread, now: what was sent is credited
-                frames.close()
+            timeout_s = server_cfg.request_timeout_s
+            sub = Subscription(adm.stream, adm.start, self.connection,
+                               timeout_s, adm.arrived)
+            delivery.subscribe(sub)
+            sub.over.wait()
+            kind, idx, payload = sub.end
+            if kind != "dropped":
+                # a client that takes nothing holds this thread no
+                # longer than it would have held its subscription
+                self.connection.settimeout(timeout_s)
+                try:
+                    self.wfile.write(_terminal_frame(
+                        pipeline, adm, kind, idx, payload, timeout_s))
+                except OSError:
+                    # the client went away before the last frame; its
+                    # tokens stay in the journal + stream buffer for a
+                    # Last-Event-ID reconnect — nothing to clean up
+                    pass
+            # what this thread spent beside the delivery thread's own
+            engine.metrics.record_delivery(
+                thread_times()[1] - adm.cpu, 0, 0, 0.0)
             t0 = getattr(self, "_t_start", None)
             if t0 is not None:
                 _observe_http(label, time.perf_counter() - t0)
@@ -874,14 +899,12 @@ def build_stdlib_server(server_cfg: ServerConfig,
             with inflight_lock:
                 inflight[0] += 1
             try:
-                code, body, frames = _engine_stream(
-                    engine, pipeline, req,
-                    server_cfg.request_timeout_s,
-                    cpu_start=self._cpu_start)
-                if frames is None:
+                code, body, adm = _admit_stream(
+                    engine, pipeline, req, cpu_start=self._cpu_start)
+                if adm is None:
                     self._send(code, body)
                 else:
-                    self._send_stream(frames)
+                    self._send_stream(adm)
             except Exception as e:  # noqa: BLE001 — surface, don't die
                 self._send(500, {"error": str(e)[:500]})
             finally:
@@ -929,9 +952,16 @@ def build_stdlib_server(server_cfg: ServerConfig,
             code, body = disagg.handle_delete(rid)
             self._send(code, body)
 
-    server = http.server.ThreadingHTTPServer(
-        (server_cfg.host, server_cfg.port), Handler)
+    class Server(http.server.ThreadingHTTPServer):
+        def server_close(self):
+            super().server_close()
+            if delivery is not None:
+                delivery.stop()
+
+    server = Server((server_cfg.host, server_cfg.port), Handler)
     server.in_flight = lambda: inflight[0]
+    if delivery is not None:
+        delivery.start()
     return server
 
 

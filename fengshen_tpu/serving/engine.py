@@ -1788,8 +1788,10 @@ class ContinuousBatchingEngine:
         else:
             tokens = len(live) if not self.spec else \
                 int(tick.host[0][tick.lanes].sum()) + len(tick.lanes)
+        # the per-lane loops append to each lane's stream; the readers
+        # are woken once, at the end (docs/streaming.md "Delivery")
         with span("serving/commit", lanes=len(tick.lanes),
-                  tokens=tokens) as s:
+                  tokens=tokens) as s, self.streams.one_signal():
             if self._block_len:
                 self._commit_blocks(tick, deliver)
             elif self.spec:

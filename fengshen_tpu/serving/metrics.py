@@ -270,7 +270,8 @@ class EngineMetrics:
             window=window)
         self._g_streams = r.gauge(
             "fstpu_streams_active", "open (unclosed) SSE token streams")
-        # the handler threads' account (docs/serving.md "Threading"):
+        # the handler and delivery threads' account (docs/serving.md
+        # "Threading"):
         # sums over many streams mean something, one stream's reading
         # does not (the CPU clock steps by 10 ms on some hosts)
         self._handler_admit_cpu = r.counter(
@@ -280,20 +281,21 @@ class EngineMetrics:
             "itself (a refused request's too)")
         self._handler_stream_cpu = r.counter(
             "fstpu_serving_handler_stream_cpu_seconds_total",
-            "handler threads' CPU seconds from there to a stream's "
-            "terminal event: wake-ups, framing, socket writes")
+            "the delivery thread's CPU seconds (wake-ups, framing, "
+            "socket writes) plus the parked handlers' from submit() to "
+            "their stream's terminal frame")
         self._stream_wakeups = r.counter(
             "fstpu_stream_wakeups_total",
-            "batches stream readers took from a return of their "
-            "condition wait with something to deliver")
+            "returns of the delivery thread (or a pull reader) from "
+            "its wait that delivered at least one token")
         self._stream_delivered = r.counter(
             "fstpu_stream_tokens_delivered_total",
-            "tokens whose SSE frame's flush() returned: the socket's "
+            "tokens whose SSE frame's send() returned: the socket's "
             "side of stream_tokens_total (over stream_wakeups_total: "
             "tokens a wake-up)")
         self._stream_lag = r.counter(
             "fstpu_stream_delivery_lag_seconds_total",
-            "summed over delivered tokens, flush() returned less the "
+            "summed over delivered tokens, send() returned less the "
             "commit that brought the token (over "
             "stream_tokens_delivered_total: the mean lag; a replayed "
             "token adds none)")
@@ -443,9 +445,10 @@ class EngineMetrics:
 
     def record_delivery(self, cpu: float, wakeups: int, tokens: int,
                         lag: float) -> None:
-        """What one stream's handler thread did since its last credit
-        (every 64 delivered tokens and at the stream's end): the
-        batches it woke for, the tokens it flushed, their summed
+        """What a delivering thread did since its last credit (the
+        server's delivery thread once a wake-up, a parked handler at
+        its stream's end, a pull reader every 64 tokens): the wake-ups
+        that delivered, the tokens the socket took, their summed
         delivery lag, and its CPU seconds since it last read that
         clock (every 512 tokens and at the end; 0.0 in between)."""
         self._handler_stream_cpu.inc(cpu)

@@ -33,6 +33,14 @@ def format_event(event: str, data: dict,
     return ("\n".join(lines) + "\n\n").encode("utf-8")
 
 
+def token_frame(index: int, token: int) -> bytes:
+    """`format_event("token", {"token": token}, event_id=index)`, byte
+    for byte, without the JSON encoder: the one frame a stream is made
+    of, built once a delivered token by whoever delivers it (the
+    server's delivery thread, a pull reader)."""
+    return b'id: %d\nevent: token\ndata: {"token":%d}\n\n' % (index, token)
+
+
 def iter_sse(fp) -> Iterator[dict]:
     """Parse an SSE byte stream (a file-like yielding lines) into
     `{"event": str, "id": Optional[int], "data": dict}` frames.

@@ -1,24 +1,33 @@
-"""Live token streams between the engine's scheduler thread and API
-worker threads (docs/streaming.md).
+"""Live token streams between the engine's scheduler thread and the
+threads that deliver them (docs/streaming.md).
 
 Design constraints, in order:
 
 - the SCHEDULER must never block on a slow client: `publish`/`sync`
-  only append to a list and notify under a per-stream condition —
-  delivery happens on the reader's thread, and a reader that never
-  drains costs the engine nothing but the list's memory (bounded by
-  `max_new_tokens`, which admission already caps);
+  only append to a list under the book's one condition — delivery is
+  the server's delivery thread's (`streaming/delivery.py`), and a
+  reader that never drains costs the engine nothing but the list's
+  memory (bounded by `max_new_tokens`, which admission already caps);
+- ONE signal a commit: every stream of a book shares its condition
+  `news`; a commit's per-lane loop appends inside
+  `StreamBook.one_signal()` and the readers are woken once at its end,
+  not once a lane. A sync outside a commit (finish, cancel,
+  evacuation, `open`'s seed) wakes them itself. A pull reader
+  (`batches` / `events`) waits on the same condition and goes back to
+  sleep when the news was another stream's;
 - readers must be able to (re)enter at ANY index: a `Last-Event-ID`
   reconnect or a router resuming after a replica death replays from
   token k out of the stream's own buffer — the committed-token list IS
   the replay log, the same journal contract `partial()` serves;
-- lock order is one-way: engine `_cv` → `StreamBook._lock` →
-  `TokenStream._cond`. The engine syncs streams while holding its own
-  lock, so nothing here may ever call back into the engine.
+- lock order is one-way: engine `_cv` → `StreamBook._lock` → `news`.
+  The engine syncs streams while holding its own lock, so nothing here
+  may ever call back into the engine, and a reader holds `news` only
+  to look and to wait: it frames and sends outside it.
 """
 
 from __future__ import annotations
 
+import contextlib
 import threading
 from collections import OrderedDict
 from typing import Iterator, Optional
@@ -33,15 +42,17 @@ class TokenStream:
     """One request's live token feed.
 
     The writer (scheduler thread) calls `publish` with the request's
-    full committed-token snapshot; the reader iterates `events`, which
-    yields each token exactly once from its chosen start index and then
-    ONE terminal event. Tokens are append-only: `publish` never
-    truncates, so concurrent readers at different offsets stay
+    full committed-token snapshot; a reader takes what is committed
+    past its own cursor (`since`, or the blocking `batches` / `events`)
+    and then ONE terminal event. Tokens are append-only: `publish`
+    never truncates, so concurrent readers at different offsets stay
     consistent.
     """
 
-    def __init__(self) -> None:
-        self._cond = threading.Condition()
+    def __init__(self, news: Optional[threading.Condition] = None) -> None:
+        #: what readers wait on: the book's `news`, one for all its
+        #: streams; a stream of no book has its own
+        self._cond = news if news is not None else threading.Condition()
         self._tokens: list = []
         #: beside each token, the `time.perf_counter()` of the commit
         #: that brought it (0.0: no commit did, `StreamBook.open`
@@ -53,10 +64,12 @@ class TokenStream:
 
     def publish(self, tokens, finish_reason: Optional[str] = None,
                 evac_target: Optional[str] = None,
-                stamp: float = 0.0) -> int:
+                stamp: float = 0.0, wake: bool = True) -> int:
         """Append any tokens past the current length, record terminal
-        state, wake readers. Returns the number of NEW tokens (0 when
-        the snapshot brings nothing — the common non-commit sync).
+        state, wake readers unless the caller will (`wake=False`: a
+        commit's loop, which signals once at its end). Returns the
+        number of NEW tokens (0 when the snapshot brings nothing — the
+        common non-commit sync).
         `stamp` is the committing tick's ONE `time.perf_counter()`
         reading, the same for every stream it publishes to: a reader
         measures its delivery lag from it."""
@@ -71,7 +84,7 @@ class TokenStream:
             if finish_reason is not None and not self.closed:
                 self.finish_reason = finish_reason
                 self.closed = True
-            if new > 0 or self.closed:
+            if wake and (new > 0 or self.closed):
                 self._cond.notify_all()
             return max(new, 0)
 
@@ -80,47 +93,54 @@ class TokenStream:
         with self._cond:
             return list(self._tokens)
 
+    def since(self, pos: int) -> tuple:
+        """`(token_ids, stamps, closed)` committed from index `pos` on,
+        without waiting. The caller holds the stream's condition (the
+        book's `news`)."""
+        return self._tokens[pos:], self._stamps[pos:], self.closed
+
+    def terminal(self, pos: int) -> tuple:
+        """The ONE terminal event of a closed stream for a reader at
+        `pos`: `("evacuated", pos, target)` — the lane moved to another
+        replica mid-generation, reconnect THERE with `Last-Event-ID =
+        pos - 1` — or `("done", pos, finish_reason)`."""
+        if self.evac_target is not None and self.finish_reason in (
+                "evacuated", "handed_off"):
+            return ("evacuated", pos, self.evac_target)
+        return ("done", pos, self.finish_reason)
+
     def batches(self, start: int = 0,
                 timeout: Optional[float] = None) -> Iterator[tuple]:
-        """The reader's side, one item a wake-up: `("tokens", index,
-        (token_ids, stamps))` for everything a return from the
-        condition wait found committed from `index` on (one token where
-        every tick wakes the reader in time, a block where a commit
-        delivers one, more where the reader fell behind), then exactly
-        one terminal event:
+        """The pull reader's side, one item a wake-up that found news:
+        `("tokens", index, (token_ids, stamps))` for everything
+        committed from `index` on (one token where every tick wakes
+        the reader in time, a block where a commit delivers one, more
+        where the reader fell behind), then exactly one terminal event:
+        `terminal`'s, or `("timeout", next_index, None)` — no event
+        within `timeout` seconds (the reader's keep-alive/deadline
+        surface; the stream itself stays open).
 
-        - `("evacuated", next_index, target)` — the lane moved to
-          another replica mid-generation; reconnect THERE with
-          `Last-Event-ID = next_index - 1`;
-        - `("done", next_index, finish_reason)` — normal end;
-        - `("timeout", next_index, None)` — no event within `timeout`
-          seconds (the reader's keep-alive/deadline surface; the
-          stream itself stays open).
-
-        Items are yielded OUTSIDE the condition so a stalled socket
-        write never holds the lock against the scheduler's publish.
+        Items are yielded OUTSIDE the condition so a stalled consumer
+        never holds it against the scheduler's publish.
         """
         pos = max(int(start), 0)
         while True:
             with self._cond:
-                while len(self._tokens) <= pos and not self.closed:
-                    if not self._cond.wait(timeout=timeout):
-                        yield ("timeout", pos, None)
-                        return
-                batch = self._tokens[pos:]
-                stamps = self._stamps[pos:]
-                closed = self.closed
-                reason = self.finish_reason
-                target = self.evac_target
+                # `wait_for`: the condition is every stream's of the
+                # book, and a wake-up for another stream's news must
+                # not restart the clock
+                news = self._cond.wait_for(
+                    lambda: len(self._tokens) > pos or self.closed,
+                    timeout)
+                batch, stamps, closed = self.since(pos)
+            if not news:
+                yield ("timeout", pos, None)
+                return
             if batch:
                 yield ("tokens", pos, (batch, stamps))
                 pos += len(batch)
             if closed:
-                if target is not None and reason in (
-                        "evacuated", "handed_off"):
-                    yield ("evacuated", pos, target)
-                else:
-                    yield ("done", pos, reason)
+                yield self.terminal(pos)
                 return
 
     def events(self, start: int = 0,
@@ -140,11 +160,19 @@ class StreamBook:
     """The engine's registry of live `TokenStream`s, keyed by
     request_id. `sync` is the scheduler-side hot path: when no stream
     was EVER opened it is one attribute read, and per synced request it
-    is one dict probe — a non-streaming engine pays nothing."""
+    is one dict probe — a non-streaming engine pays nothing. Every
+    `open`, `sync` and `one_signal` runs under the engine's lock: the
+    hold below has one writer at a time."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
         self._streams: "OrderedDict[str, TokenStream]" = OrderedDict()
+        #: the ONE condition every stream of the book appends under and
+        #: every reader waits on (the delivery thread, a pull reader)
+        self.news = threading.Condition()
+        #: inside `one_signal`: syncs append and leave the wake-up to
+        #: its end; whether one of them reached a stream
+        self._held = self._unsignalled = False
         #: flips true at the first open() and never back — the /stats
         #: gate that keeps never-streaming payloads shape-identical
         self.ever_opened = False
@@ -158,7 +186,7 @@ class StreamBook:
             self.ever_opened = True
             stream = self._streams.get(req.request_id)
             if stream is None:
-                stream = TokenStream()
+                stream = TokenStream(self.news)
                 self._streams[req.request_id] = stream
                 self._evict_closed_locked()
         self._publish(stream, req)
@@ -177,15 +205,32 @@ class StreamBook:
             return 0
         return self._publish(stream, req, stamp)
 
-    @staticmethod
-    def _publish(stream: TokenStream, req, stamp: float = 0.0) -> int:
+    @contextlib.contextmanager
+    def one_signal(self):
+        """Around a commit's per-lane loop: the syncs inside it append
+        and wake nobody, and the readers are woken ONCE at its end —
+        one signal a commit, not one a lane."""
+        self._held = True
+        try:
+            yield
+        finally:
+            self._held = False
+            if self._unsignalled:
+                self._unsignalled = False
+                with self.news:
+                    self.news.notify_all()
+
+    def _publish(self, stream: TokenStream, req, stamp: float = 0.0) -> int:
         # finish_reason doubles as the terminal marker: the engine sets
         # it exactly once per request (finish/reject/detach), and
         # detach_lane stamps evac_target first, so the terminal event
         # can point the reader at the adopter
+        if self._held:
+            self._unsignalled = True
         return stream.publish(req.tokens,
                               finish_reason=req.finish_reason,
-                              evac_target=req.evac_target, stamp=stamp)
+                              evac_target=req.evac_target, stamp=stamp,
+                              wake=not self._held)
 
     def get(self, request_id: str) -> Optional[TokenStream]:
         with self._lock:

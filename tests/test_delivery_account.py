@@ -1,10 +1,10 @@
-"""The handler threads' account (ISSUE 50, docs/streaming.md
-"Observability"): a stream through the real stdlib server and a tiny
-engine closes it. Delivered tokens are the socket's side of
-`fstpu_stream_tokens_total`, a wake-up delivers at least a token, the
-lag is summed from the commit's one stamp, the handlers' CPU is split
-at the return of `submit()`, and the process's whole CPU stands beside
-the scheduler's. All unlabelled counters on the ENGINE's registry,
+"""The delivery account (ISSUE 50; the delivery thread's since ISSUE
+51; docs/streaming.md "Observability"): a stream through the real
+stdlib server and a tiny engine closes it. Delivered tokens are the
+socket's side of `fstpu_stream_tokens_total`, a wake-up delivers at
+least a token, the lag is summed from the commit's one stamp, the
+handlers' CPU is split at the return of `submit()`, and the process's
+whole CPU stands beside the scheduler's. All unlabelled counters on the ENGINE's registry,
 which is what the benchmark reads at a window's edges."""
 
 import json
